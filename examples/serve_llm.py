@@ -9,7 +9,8 @@ Two flavors:
   prompts, per-token SSE streaming; requests carrying the system prompt's
   `serve_prefix_hash` header route to the replica holding its KV blocks.
 
-On TPU the replica pins a chip (@serve.deployment(num_tpus=1)).
+On TPU the replica pins a chip:
+@serve.deployment(ray_actor_options={"num_tpus": 1}).
 
 Run: python examples/serve_llm.py
 """

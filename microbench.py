@@ -22,7 +22,6 @@ import sys
 import time
 
 os.environ.setdefault("JAX_PLATFORMS", "cpu")
-os.environ.setdefault("RAY_TPU_JAX_CONFIG_PLATFORMS", "cpu")
 os.environ.setdefault("RAY_TPU_NUM_TPUS", "0")
 
 

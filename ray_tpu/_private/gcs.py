@@ -182,6 +182,11 @@ class GcsServer:
             # availability) stamp nothing — the delta reply stays empty.
             node["resources_available"] = avail
             self._bump_view(req["node_id"])
+            # Freed capacity may make parked placement groups feasible: a
+            # TPU gang requested while the previous holder of the chips was
+            # still exiting would otherwise wait for a node JOIN forever.
+            if any(pg.get("state") == "PENDING" for pg in self.placement_groups.values()):
+                asyncio.ensure_future(self._retry_pending_pgs())
         node["store_usage"] = req.get("store_usage", node["store_usage"])
         node["load"] = req.get("load", [])
         node["num_active_workers"] = req.get("num_active_workers", 0)
@@ -290,9 +295,21 @@ class GcsServer:
 
     async def _health_check_loop(self):
         # Reference: GcsHealthCheckManager (gcs_health_check_manager.h:39).
+        interval = self.cfg.heartbeat_interval_s
+        woke = time.monotonic()
         while True:
-            await asyncio.sleep(self.cfg.heartbeat_interval_s)
+            await asyncio.sleep(interval)
             now = time.monotonic()
+            # A checker that overslept was not listening: this process, or
+            # the whole host, was stalled (a TPU runtime starting or stopping
+            # stalled every process on the v5e host for 3-7 s), and the
+            # heartbeats sent meanwhile are still in socket buffers. Time
+            # the GCS was deaf does not count against the nodes.
+            deaf = now - woke - interval
+            woke = now
+            if deaf > interval:
+                for node in self.nodes.values():
+                    node["last_heartbeat"] += deaf
             for node_id, node in list(self.nodes.items()):
                 if node["state"] != "ALIVE":
                     continue

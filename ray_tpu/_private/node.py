@@ -13,7 +13,8 @@ trick, python/ray/cluster_utils.py:99) work the same way; worker processes are
 real subprocesses either way. `gcs.py`/`raylet.py` keep standalone `main()`s
 for out-of-process deployment.
 
-TPU detection reads /dev/accel* (TPU chips appear as accelerator devices) —
+TPU detection counts the chips' device nodes (/dev/accel<n>, or
+/dev/vfio/<n> on hosts that pass chips through VFIO, v5e among them) —
 deliberately without importing jax, because initialising the TPU runtime in
 the driver would take the host's TPU client lock and starve worker processes
 (see SURVEY.md §7 hard part 5).
@@ -33,7 +34,15 @@ from ray_tpu._private.raylet import Raylet
 def detect_tpu_chips() -> int:
     if os.environ.get("RAY_TPU_NUM_TPUS"):
         return int(os.environ["RAY_TPU_NUM_TPUS"])
-    return len(glob.glob("/dev/accel*"))
+    return len(glob.glob("/dev/accel*")) or sum(
+        os.path.basename(p).isdigit() for p in glob.glob("/dev/vfio/*")
+    )
+
+
+def pinned_jax_platform() -> str:
+    """The platform the environment's JAX_PLATFORMS puts jax on ("" when it is
+    not set) — like the chip count, learnt without importing jax."""
+    return os.environ.get("JAX_PLATFORMS", "").split(",")[0].strip().lower()
 
 
 def detect_tpu_labels() -> dict:

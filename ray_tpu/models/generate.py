@@ -8,8 +8,7 @@ wrap HF pipelines; SURVEY.md §5.7) — this is the TPU-native equivalent:
   masked by position, so one compiled prefill + one compiled decode step
   serve every request length (no per-length recompiles);
 - the whole generation loop is a ``lax.scan`` under one jit — no
-  host→device round trip per token (under a remote-TPU tunnel that RTT
-  would dominate decode latency);
+  host→device round trip per token;
 - prefill attends densely over the prompt rows only (MXU-bound, masked for
   causality + per-row padding; the unwritten generation region of the
   cache is never scored), decode attends one query row against the cache

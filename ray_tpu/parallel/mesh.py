@@ -133,6 +133,19 @@ def shard_pytree(tree, mesh, spec_fn):
     return jax.tree_util.tree_map_with_path(place, tree)
 
 
+def shard_by_logical_axes(tree, logical_axes, mesh, rules: dict | None = None):
+    """device_put a pytree whose leaves a parallel tree of logical-axis tuples
+    names (models' ``param_logical_axes``), each by ``logical_to_spec``."""
+
+    def spec_for(path, _leaf):
+        node = logical_axes
+        for p in path:
+            node = node[p.key]
+        return logical_to_spec(node, rules)
+
+    return shard_pytree(tree, mesh, spec_for)
+
+
 def replicate_pytree(tree, mesh):
     import jax
     from jax.sharding import NamedSharding, PartitionSpec as P

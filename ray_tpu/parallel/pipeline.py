@@ -16,12 +16,6 @@ from jax import lax
 from jax.sharding import PartitionSpec as P
 
 
-def _shard_map():
-    from ray_tpu.util.jax_compat import shard_map
-
-    return shard_map()
-
-
 def pipeline_apply(
     stage_fn,
     stacked_params,
@@ -87,7 +81,7 @@ def pipeline_apply(
         )
         return outputs
 
-    fn = _shard_map()(
+    fn = jax.shard_map(
         local_fn,
         mesh=mesh,
         in_specs=(P(axis_name), P()),

@@ -22,12 +22,6 @@ from jax.sharding import PartitionSpec as P
 from ray_tpu.ops.attention import flash_attention
 
 
-def _shard_map():
-    from ray_tpu.util.jax_compat import shard_map
-
-    return shard_map()
-
-
 def ulysses_attention(
     q,
     k,
@@ -63,7 +57,7 @@ def ulysses_attention(
         return heads_to_seq(out)
 
     spec = P(None, axis_name, None, None)
-    fn = _shard_map()(
+    fn = jax.shard_map(
         local_fn,
         mesh=mesh,
         in_specs=(spec, spec, spec),

@@ -55,9 +55,9 @@ class LLMDeployment:
         from ray_tpu.models.transformer import TransformerConfig, init_params
 
         model_config = dict(model_config)
-        dtype = model_config.get("dtype")
-        if isinstance(dtype, str):  # JSON-friendly configs
-            model_config["dtype"] = jnp.dtype(dtype).type
+        for key in ("dtype", "param_dtype"):
+            if isinstance(model_config.get(key), str):  # JSON-friendly configs
+                model_config[key] = jnp.dtype(model_config[key]).type
         self.cfg = TransformerConfig(**model_config)
         if params is None:
             params = init_params(jax.random.PRNGKey(init_seed), self.cfg)
@@ -165,8 +165,11 @@ class LLMDeployment:
         }
 
     def get_stats(self) -> dict:
-        """Engine snapshot (handle-callable; used by tests and benches)."""
-        return self.engine.stats()
+        """Engine snapshot plus the device this replica runs on
+        (handle-callable; used by tests and benches)."""
+        from ray_tpu.util.device_report import device_report
+
+        return {**self.engine.stats(), "device": device_report()}
 
     def check_health(self):
         self.engine.check_health()

@@ -59,12 +59,6 @@ def _routable_ip() -> str:
         return "127.0.0.1"
 
 
-def _shard_map():
-    from ray_tpu.util.jax_compat import shard_map
-
-    return shard_map()
-
-
 class TpuCollectiveGroup:
     """One member's view of an XLA collective world."""
 
@@ -264,8 +258,6 @@ class TpuCollectiveGroup:
             return x
 
         def build():
-            shard_map = _shard_map()
-
             def body(a):
                 # a: (1, *shape) — this proc's copy.
                 if op == ReduceOp.SUM:
@@ -283,7 +275,7 @@ class TpuCollectiveGroup:
                 return r
 
             return jax.jit(
-                shard_map(
+                jax.shard_map(
                     body,
                     mesh=self.mesh,
                     in_specs=P("proc"),
@@ -308,13 +300,11 @@ class TpuCollectiveGroup:
             return x[None]
 
         def build():
-            shard_map = _shard_map()
-
             def body(a):
                 return lax.all_gather(a, "proc", axis=0, tiled=True)
 
             return jax.jit(
-                shard_map(
+                jax.shard_map(
                     body, mesh=self.mesh, in_specs=P("proc"), out_specs=P(), check_vma=False
                 )
             )
@@ -337,15 +327,13 @@ class TpuCollectiveGroup:
             return x[0]
 
         def build():
-            shard_map = _shard_map()
-
             def body(a):
                 # a: (1, world, chunk...) per proc.
                 r = lax.psum_scatter(a[0], "proc", scatter_dimension=0, tiled=False)
                 return r[None]
 
             return jax.jit(
-                shard_map(
+                jax.shard_map(
                     body, mesh=self.mesh, in_specs=P("proc"), out_specs=P("proc"), check_vma=False
                 )
             )
@@ -366,8 +354,6 @@ class TpuCollectiveGroup:
             return x
 
         def build():
-            shard_map = _shard_map()
-
             def body(a):
                 # Select src's copy on every proc: sum of masked copies.
                 idx = lax.axis_index("proc")
@@ -375,7 +361,7 @@ class TpuCollectiveGroup:
                 return lax.psum(a * mask, "proc")
 
             return jax.jit(
-                shard_map(
+                jax.shard_map(
                     body, mesh=self.mesh, in_specs=P("proc"), out_specs=P(), check_vma=False
                 )
             )
@@ -411,13 +397,11 @@ class TpuCollectiveGroup:
         perm_t = tuple(tuple(p) for p in perm)
 
         def build():
-            shard_map = _shard_map()
-
             def body(a):
                 return lax.ppermute(a, "proc", perm=perm_t)
 
             return jax.jit(
-                shard_map(
+                jax.shard_map(
                     body, mesh=self.mesh, in_specs=P("proc"), out_specs=P("proc"), check_vma=False
                 )
             )

@@ -1,0 +1,26 @@
+"""Where JAX's persistent compilation cache lives.
+
+JAX reads ``JAX_COMPILATION_CACHE_DIR`` when it is imported, and worker
+processes inherit the driver's environment (raylet ``_popen_worker``), so the
+entry points (``chip_smoke.py``, ``bench.py``) place the cache once, before the
+cluster starts, and no other code sets a cache directory.
+"""
+
+from __future__ import annotations
+
+import os
+
+ENV_VAR = "JAX_COMPILATION_CACHE_DIR"
+
+
+def export_compile_cache_dir(entry_file: str) -> str:
+    """Make sure ``JAX_COMPILATION_CACHE_DIR`` is set and return it. A
+    directory the caller set is left alone. Otherwise the cache goes to
+    ``.jax_cache`` beside ``entry_file``: the path is part of the cache's
+    key, so it must be the same on every run of the same checkout — never
+    the working directory, a temp dir, a pid or a timestamp."""
+    if not os.environ.get(ENV_VAR):
+        os.environ[ENV_VAR] = os.path.join(
+            os.path.dirname(os.path.abspath(entry_file)), ".jax_cache"
+        )
+    return os.environ[ENV_VAR]

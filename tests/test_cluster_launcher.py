@@ -35,8 +35,7 @@ available_node_types:
     max_workers: 2
 """
     )
-    env = dict(os.environ, PYTHONPATH=REPO, JAX_PLATFORMS="cpu",
-               RAY_TPU_JAX_CONFIG_PLATFORMS="cpu", RAY_TPU_NUM_TPUS="0")
+    env = dict(os.environ, PYTHONPATH=REPO, JAX_PLATFORMS="cpu", RAY_TPU_NUM_TPUS="0")
     up = subprocess.run(
         [sys.executable, "-m", "ray_tpu.scripts.scripts", "up", str(cfg)],
         env=env, capture_output=True, text=True, timeout=120,

@@ -31,7 +31,6 @@ def test_example_runs(script, args, expect):
     env = dict(os.environ)
     env.update(
         JAX_PLATFORMS="cpu",
-        RAY_TPU_JAX_CONFIG_PLATFORMS="cpu",
         RAY_TPU_NUM_TPUS="0",
         PYTHONPATH=REPO + os.pathsep + env.get("PYTHONPATH", ""),
     )

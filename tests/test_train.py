@@ -6,7 +6,6 @@ import numpy as np
 import pytest
 
 import ray_tpu
-from conftest import skip_without_multiprocess_collectives
 from ray_tpu.air import session
 from ray_tpu.air.checkpoint import Checkpoint
 from ray_tpu.air.config import CheckpointConfig, RunConfig, ScalingConfig
@@ -91,7 +90,6 @@ def dp_loop(config):
         session.report({"step": step_i, "w0": float(w[0]), "rank": rank})
 
 
-@skip_without_multiprocess_collectives
 def test_jax_trainer_multi_worker_dp(ray_start_regular):
     trainer = JaxTrainer(
         dp_loop,
