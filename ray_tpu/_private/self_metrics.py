@@ -254,13 +254,29 @@ def instruments() -> dict:
             ),
             "serve_llm_ttft": m.Histogram(
                 "ray_tpu_serve_llm_ttft_s",
-                "Time to first token: submit -> first token emitted.",
+                "Time to first token: submit -> first token emitted "
+                "(folded at flush from the engine's request ring).",
                 boundaries=_LATENCY_BOUNDS,
             ),
             "serve_llm_tpot": m.Histogram(
                 "ray_tpu_serve_llm_time_per_output_token_s",
-                "Per-request mean inter-token latency (first -> last token).",
+                "Per-request mean inter-token latency (first -> last token; "
+                "folded at flush from the engine's request ring).",
                 boundaries=_LATENCY_BOUNDS,
+            ),
+            "serve_llm_loop_seconds": m.Counter(
+                "ray_tpu_serve_llm_loop_seconds_total",
+                "Scheduler-loop seconds by span (llm.iteration is the whole "
+                "pass, the others its phases); rate() of it is that phase's "
+                "share of wall time.",
+                tag_keys=("phase",),
+            ),
+            "serve_llm_iterations": m.Counter(
+                "ray_tpu_serve_llm_iterations_total",
+                "Scheduler-loop passes that dispatched a program, by kind: "
+                "decode (a decode step only), prefill (a prefill chunk "
+                "only), mixed (both).",
+                tag_keys=("kind",),
             ),
             # --- Data executor (data/_internal/) ---
             "data_rows": m.Counter(
@@ -440,19 +456,22 @@ def instruments() -> dict:
 _folded: dict = {}
 
 
+def _fold_value(key: tuple, cur: int, counter, tags, scale: float = 1) -> None:
+    """Fold one monotonic plain int into a Counter: the growth since the
+    last flush, times ``scale`` (1e-9 turns nanoseconds into seconds)."""
+    delta = cur - _folded.get(key, 0)
+    if delta > 0:
+        _folded[key] = cur
+        counter.inc(delta * scale, tags=tags)
+
+
 def _fold(source_key: str, stats_obj, pairs) -> None:
     """Fold monotonic plain-int attrs of a hot-path stats object into
     Counters. ``pairs`` = [(attr, counter, tags-or-None)]."""
-    inst = _instruments
-    if inst is None:
+    if _instruments is None:
         return
     for attr, counter, tags in pairs:
-        cur = getattr(stats_obj, attr)
-        key = (source_key, attr)
-        delta = cur - _folded.get(key, 0)
-        if delta > 0:
-            _folded[key] = cur
-            counter.inc(delta, tags=tags)
+        _fold_value((source_key, attr), getattr(stats_obj, attr), counter, tags)
 
 
 def _collect_wire_stats():
@@ -603,11 +622,38 @@ def _collect_collective_stats():
 
 
 def _collect_serve_llm_stats():
-    from ray_tpu.serve.llm.stats import ENGINES, LLM
+    from ray_tpu.serve.llm.stats import (
+        ENGINES,
+        ITERATION_KINDS,
+        LLM,
+        RECORDERS,
+        REQUEST_FIELDS,
+        SPAN_NAMES,
+    )
 
     inst = _instruments
     if inst is None:
         return
+    for phase, ns in zip(SPAN_NAMES, LLM.span_ns):
+        _fold_value(
+            ("serve_llm_span_ns", phase), ns, inst["serve_llm_loop_seconds"], {"phase": phase}, 1e-9
+        )
+    for kind, n in zip(ITERATION_KINDS, LLM.iterations):
+        _fold_value(("serve_llm_iterations", kind), n, inst["serve_llm_iterations"], {"kind": kind})
+    # TTFT / TPOT: observed here, from the requests that ended since the last
+    # flush, so that nothing takes an instrument lock on the token path.
+    col = {name: i for i, name in enumerate(REQUEST_FIELDS)}
+    for rec in list(RECORDERS):
+        ended = rec.requests.since(rec.requests_folded)
+        rec.requests_folded += len(ended)
+        for r in ended:
+            t_submit, t_first = r[col["t_submit_ns"]], r[col["t_first_ns"]]
+            if not t_first:
+                continue
+            inst["serve_llm_ttft"].observe((t_first - t_submit) / 1e9)
+            n = r[col["generated"]]
+            if r[col["outcome"]] == "finished" and n > 1:
+                inst["serve_llm_tpot"].observe((r[col["t_done_ns"]] - t_first) / 1e9 / (n - 1))
     _fold("serve_llm", LLM, [
         ("prefix_hit_blocks", inst["serve_llm_prefix_hits"], None),
         ("prefix_miss_blocks", inst["serve_llm_prefix_misses"], None),

@@ -28,9 +28,11 @@ import json
 import logging
 import threading
 import time
+import uuid
 from urllib.parse import parse_qsl, urlencode
 
 from ray_tpu._private.concurrency import any_thread, blocking
+from ray_tpu.serve._private.common import RECV_STAMP_HEADER, REQUEST_ID_HEADER
 
 logger = logging.getLogger(__name__)
 
@@ -216,6 +218,12 @@ class ProxyASGIApp:
         headers = {
             k.decode("latin-1"): v.decode("latin-1") for k, v in scope.get("headers", [])
         }
+        # The request is whole: stamp it once, here, so that every dispatch
+        # path (direct, prefill leg, migration replay of these same headers)
+        # carries the stamp and one identifier to the replica.
+        headers[RECV_STAMP_HEADER] = str(time.monotonic_ns())
+        if not headers.get(REQUEST_ID_HEADER):
+            headers[REQUEST_ID_HEADER] = uuid.uuid4().hex[:16]
         loop = asyncio.get_running_loop()
         import ray_tpu
 

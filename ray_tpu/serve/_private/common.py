@@ -75,6 +75,13 @@ MULTIPLEXED_MODEL_ID_HEADER = "serve_multiplexed_model_id"
 # blocks, falling back to least queue depth.
 PREFIX_HINT_HEADER = "serve_prefix_hash"
 
+# The proxy's identifier of a request (the client's, if it sent one) and its
+# CLOCK_MONOTONIC nanosecond stamp of receiving it. Both ride the headers the
+# proxy forwards on every dispatch path, so that a deployment can tell how
+# long a request took to reach it (serve.llm's request records do).
+REQUEST_ID_HEADER = "x-request-id"
+RECV_STAMP_HEADER = "x-serve-recv-monotonic-ns"
+
 # Naming convention pairing disaggregated LLM pools (ISSUE 20): the proxy
 # discovers the prefill pool as f"{decode_deployment}{PREFILL_SUFFIX}" in
 # its routing table. Lives here (not serve.llm.deployment, which re-exports

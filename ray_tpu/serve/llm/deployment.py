@@ -37,7 +37,13 @@ from __future__ import annotations
 
 import json
 
-from ray_tpu.serve._private.common import PREFILL_SUFFIX  # noqa: F401
+import time
+
+from ray_tpu.serve._private.common import (  # noqa: F401
+    PREFILL_SUFFIX,
+    RECV_STAMP_HEADER,
+    REQUEST_ID_HEADER,
+)
 from ray_tpu.serve.llm.engine import LLMEngine, prefix_route_hint  # noqa: F401
 
 
@@ -49,19 +55,32 @@ class LLMDeployment:
         init_seed: int = 0,
         params=None,
     ):
+        # Seconds of each stage of the replica's start, beside the engine's
+        # own (get_stats()["spans"]["setup"]).
+        t0 = time.monotonic()
         import jax
         import jax.numpy as jnp
 
         from ray_tpu.models.transformer import TransformerConfig, init_params
+        from ray_tpu.serve.llm.stats import listen_for_compiles
 
+        listen_for_compiles()  # before the first program: the draw's compiles count
+        t1 = time.monotonic()
+        jax.devices()
+        t2 = time.monotonic()
+        setup = {"jax_import_s": t1 - t0, "backend_s": t2 - t1}
         model_config = dict(model_config)
         for key in ("dtype", "param_dtype"):
             if isinstance(model_config.get(key), str):  # JSON-friendly configs
                 model_config[key] = jnp.dtype(model_config[key]).type
         self.cfg = TransformerConfig(**model_config)
         if params is None:
-            params = init_params(jax.random.PRNGKey(init_seed), self.cfg)
+            params = jax.block_until_ready(
+                init_params(jax.random.PRNGKey(init_seed), self.cfg)
+            )
+            setup["params_s"] = time.monotonic() - t2
         self.engine = LLMEngine(params, self.cfg, **(engine_config or {}))
+        self.engine.spans.setup.update(setup)
 
     def __call__(self, request):
         from ray_tpu.serve.api import StreamingResponse
@@ -75,6 +94,7 @@ class LLMDeployment:
             seed=int(body.get("seed", 0)),
             resume_tokens=body.get("resume_tokens"),
             kv_import=body.get("kv_import"),
+            **_proxy_stamps(getattr(request, "headers", None)),
         )
         # Resume tokens a migrated/handed-off request already owns but the
         # CLIENT has not seen yet (the handoff descriptor's first sampled
@@ -165,11 +185,17 @@ class LLMDeployment:
         }
 
     def get_stats(self) -> dict:
-        """Engine snapshot plus the device this replica runs on
-        (handle-callable; used by tests and benches)."""
+        """Engine snapshot plus the device this replica runs on and, under
+        ``"spans"``, the engine's iteration, request and compile records
+        (``stats.EngineSpans.export``) (handle-callable; used by tests and
+        benches)."""
         from ray_tpu.util.device_report import device_report
 
-        return {**self.engine.stats(), "device": device_report()}
+        return {
+            **self.engine.stats(),
+            "device": device_report(),
+            "spans": self.engine.spans.export(),
+        }
 
     def check_health(self):
         self.engine.check_health()
@@ -181,6 +207,18 @@ class LLMDeployment:
 
     def prepare_for_shutdown(self):
         self.engine.shutdown()
+
+
+def _proxy_stamps(headers) -> dict:
+    """What the proxy put into the headers it forwards: its identifier of
+    the request and its CLOCK_MONOTONIC stamp of receiving it."""
+    if not headers:
+        return {}
+    stamp = headers.get(RECV_STAMP_HEADER, "")
+    return {
+        "request_id": headers.get(REQUEST_ID_HEADER, ""),
+        "t_recv_ns": int(stamp) if stamp.isdigit() else 0,
+    }
 
 
 def disaggregated_llm_app(

@@ -54,7 +54,8 @@ import numpy as np
 
 from ray_tpu._private import flight_recorder as _flight
 from ray_tpu._private.concurrency import any_thread, blocking
-from ray_tpu.serve.llm.stats import ENGINES, LLM
+from ray_tpu.serve.llm.stats import ENGINES, LLM, EngineSpans, listen_for_compiles
+from ray_tpu.util import tracing
 
 
 # Terminal-error sentinel for a DELIBERATE engine teardown (replica
@@ -84,6 +85,12 @@ class LLMRequest:
 
     def __init__(self, rid, prompt, max_new_tokens, temperature, top_k, seed):
         self.id = rid
+        # What the request's record (stats.REQUEST_FIELDS) keeps beside the
+        # stamps: the proxy's identifier, the task span of the replica call
+        # (under RAY_TPU_TRACING=1) that is the parent of the engine's stages.
+        self.request_id = ""
+        self.trace_id = ""
+        self.span_id = ""
         self.prompt = list(prompt)
         self.max_new_tokens = int(max_new_tokens)
         self.temperature = float(temperature)
@@ -101,11 +108,15 @@ class LLMRequest:
         # (dict) a decode-pool replica continues from; None on engines that
         # decode their own requests.
         self.handoff: Optional[dict] = None
+        self.t_recv: Optional[float] = None  # the proxy's stamp, same clock
         self.t_submit = time.monotonic()
+        self.t_admit: Optional[float] = None  # first admission
         self.t_first: Optional[float] = None
         self.t_done: Optional[float] = None
         self._q: _queue.Queue = _queue.Queue()
         self._finished = False  # scheduler-side guard: one terminal event
+        self.cached_tokens = 0  # prompt tokens the first admission did not recompute
+        self.preemptions = 0
         # --- scheduler-owned ---
         self._sched_generated: list[int] = []
         self._sched_state = "waiting"  # waiting | prefill | decode | done
@@ -296,7 +307,15 @@ class LLMEngine:
         self.num_blocks = int(num_blocks or self.num_slots * self.n_max + 1)
         self.prefill_chunk = int(prefill_chunk)
         self.serial_batch = bool(serial_batch)
-        self._cache = init_paged_cache(cfg, self.num_blocks, self.block_size)
+        listen_for_compiles()
+        self.spans = EngineSpans()
+        t0 = time.monotonic()
+        import jax
+
+        self._cache = jax.block_until_ready(
+            init_paged_cache(cfg, self.num_blocks, self.block_size)
+        )
+        self.spans.setup["pool_s"] = time.monotonic() - t0
         # Block 0 is the reserved null block — never handed out.
         self._free: list[int] = list(range(self.num_blocks - 1, 0, -1))
         self._prefix: dict[bytes, _PrefixEntry] = {}
@@ -332,13 +351,9 @@ class LLMEngine:
             "prefix_import_misses": 0,
             "prefix_import_errors": 0,
         }
+        t0 = time.monotonic()
         self._decode_fn, self._prefill_fn = _compiled_fns(cfg)
-        try:
-            from ray_tpu._private import self_metrics
-
-            self._metrics = self_metrics.instruments()
-        except Exception:
-            self._metrics = None
+        self.spans.setup["jit_build_s"] = time.monotonic() - t0
         self._thread = threading.Thread(
             target=self._loop, name="llm-engine", daemon=True
         )
@@ -363,8 +378,14 @@ class LLMEngine:
         seed: int = 0,
         resume_tokens=None,
         kv_import=None,
+        request_id: str = "",
+        t_recv_ns: int = 0,
     ) -> LLMRequest:
-        """``resume_tokens``: tokens this request ALREADY emitted on a
+        """``request_id`` / ``t_recv_ns``: the proxy's identifier of this
+        request and its CLOCK_MONOTONIC stamp of receiving it (both ride the
+        headers it forwards); they go into the request's record.
+
+        ``resume_tokens``: tokens this request ALREADY emitted on a
         replica that died mid-stream. They are teacher-forced through
         chunked prefill exactly like recompute preemption re-admission
         (they pre-seed the generated list, so admission's target covers
@@ -405,6 +426,11 @@ class LLMEngine:
             f"llm-{next(self._rid)}", tokens, max_new_tokens, temperature, top_k, seed
         )
         req._sched_generated = resume
+        req.request_id = str(request_id or "")
+        req.t_recv = int(t_recv_ns) / 1e9 if t_recv_ns else None
+        ctx = tracing.get_current_span_context()
+        if ctx is not None:
+            req.trace_id, req.span_id = ctx["trace_id"] or "", ctx["span_id"] or ""
         # Reuse applies to blocks fully inside tokens[:-1]: at least one
         # prompt token always runs through prefill so admission has logits
         # to sample the first output from.
@@ -417,6 +443,7 @@ class LLMEngine:
             req._sched_state = "done"
             req.t_done = time.monotonic()
             req._q.put(("done", "complete"))
+            self.spans.end_request(req, "finished")
             return req
         if kv_import is not None:
             self._attach_handoff_import(req, kv_import)
@@ -476,6 +503,7 @@ class LLMEngine:
             "published_prefixes": len(self._published),
             "pending_exports": len(self._exports),
             **self._counts,
+            **self.spans.totals(),
         }
 
     @any_thread
@@ -626,13 +654,13 @@ class LLMEngine:
         req._sched_pos = kv_pos
         self._register_prefix_blocks(req)
 
-    def _try_handoff(self, req: LLMRequest, logits_row: np.ndarray) -> bool:
-        """Prefill-role completion: sample the first output token, seal the
-        prompt's KV blocks as a transient device object, and finish the
-        request with the ~300B handoff descriptor. Returns False when
-        sealing is impossible (bare engine, seal error) — the caller then
-        decodes locally, bit-identically (the counter-based RNG draws the
-        same token at position 0 either way)."""
+    def _try_handoff(self, req: LLMRequest, tok: int) -> bool:
+        """Prefill-role completion: seal the prompt's KV blocks as a
+        transient device object, and finish the request with the ~300B
+        handoff descriptor, which carries ``tok``, the first output token.
+        Returns False when sealing is impossible (bare engine, seal error) —
+        the caller then decodes locally, bit-identically (the counter-based
+        RNG has drawn the same token at position 0 either way)."""
         from ray_tpu.serve.llm import kv_transfer
 
         n_exp = -(-len(req.prompt) // self.block_size)
@@ -648,7 +676,6 @@ class LLMEngine:
             desc = None
         if desc is None:
             return False
-        tok = self._sample(req, logits_row)
         req._sched_generated.append(tok)
         self._exports[desc["oid"]] = time.monotonic() + self.handoff_ttl_s
         LLM.handoff_exports += 1
@@ -761,12 +788,20 @@ class LLMEngine:
     @blocking
     def _loop(self):
         try:
+            spans = self.spans
             while not self._stop.is_set():
-                self._sweep_cancelled()
-                self._reap_exports()
-                self._admit()
-                busy = self._prefill_tick()
-                busy = self._decode_tick() or busy
+                it = spans.begin(
+                    len(self._waiting), sum(r is not None for r in self._slots)
+                )
+                try:
+                    with spans.span("llm.admit") as sp:
+                        self._sweep_cancelled()
+                        self._reap_exports()
+                        sp.set(admitted=self._admit(), waiting=len(self._waiting))
+                    busy = self._prefill_tick()
+                    busy = self._decode_tick() or busy
+                finally:
+                    spans.end(it)
                 if not busy:
                     if any(r is not None for r in self._slots) or self._waiting:
                         self._wake.wait(0.02)
@@ -848,17 +883,19 @@ class LLMEngine:
 
     # --- admission ---
 
-    def _admit(self):
+    def _admit(self) -> int:
+        """Returns how many requests it admitted."""
+        admitted = 0
         if self.serial_batch and any(r is not None for r in self._slots):
-            return
+            return admitted
         while True:
             try:
                 slot = self._slots.index(None)
             except ValueError:
-                return
+                return admitted
             with self._lock:
                 if not self._waiting:
-                    return
+                    return admitted
                 req = self._waiting[0]
             # Teacher-forced target: original prompt plus anything already
             # emitted before a preemption.
@@ -878,7 +915,7 @@ class LLMEngine:
                 1 for h in hit_hashes if h in self._lru
             )
             if len(self._free) + evictable < need:
-                return  # head-of-line waits for blocks (FIFO fairness)
+                return admitted  # head-of-line waits for blocks (FIFO fairness)
             with self._lock:
                 self._waiting.popleft()
             table: list[int] = []
@@ -906,6 +943,10 @@ class LLMEngine:
             req._sched_target = target
             if req._sched_kv_import is not None:
                 self._scatter_import(req, cached)
+            if req.t_admit is None:
+                req.t_admit = now
+                req.cached_tokens = req._sched_pos
+            admitted += 1
             req._sched_state = "prefill"
             req._sched_slot = slot
             req._sched_admit_seq = next(self._admit_seq)
@@ -939,25 +980,30 @@ class LLMEngine:
             return False
         import jax.numpy as jnp
 
+        spans = self.spans
         q = self.prefill_chunk
         pos0 = req._sched_pos
-        seq = req.prompt + req._sched_generated
-        piece = seq[pos0 : pos0 + q]
-        fed = piece + [0] * (q - len(piece))
-        table = np.zeros((1, self.n_max), np.int32)
-        table[0, : len(req._sched_table)] = req._sched_table
-        # Row of the prompt's LAST real token within this chunk — only
-        # meaningful (and only consumed) on the final chunk.
-        row = min(max(req._sched_target - 1 - pos0, 0), q - 1)
-        logits, self._cache = self._prefill_fn(
-            self.params,
-            jnp.asarray([fed], jnp.int32),
-            self._cache,
-            jnp.asarray(table),
-            jnp.asarray([pos0], jnp.int32),
-            jnp.asarray([req._sched_target], jnp.int32),
-            jnp.int32(row),
-        )
+        with spans.span("llm.prefill.build", rid=req.id, pos=pos0):
+            seq = req.prompt + req._sched_generated
+            piece = seq[pos0 : pos0 + q]
+            fed = piece + [0] * (q - len(piece))
+            table = np.zeros((1, self.n_max), np.int32)
+            table[0, : len(req._sched_table)] = req._sched_table
+            # Row of the prompt's LAST real token within this chunk — only
+            # meaningful (and only consumed) on the final chunk.
+            row = min(max(req._sched_target - 1 - pos0, 0), q - 1)
+            inputs = (
+                jnp.asarray([fed], jnp.int32),
+                jnp.asarray(table),
+                jnp.asarray([pos0], jnp.int32),
+                jnp.asarray([req._sched_target], jnp.int32),
+                jnp.int32(row),
+            )
+        spans.carried(prefill_tokens=len(piece))
+        with spans.span("llm.prefill.dispatch", rid=req.id):
+            logits, self._cache = self._prefill_fn(
+                self.params, inputs[0], self._cache, *inputs[1:]
+            )
         req._sched_pos = min(pos0 + q, req._sched_target)
         self._register_prefix_blocks(req)
         if req._sched_pos >= req._sched_target:
@@ -965,10 +1011,15 @@ class LLMEngine:
             # the request's still-allocated block table.
             if self.cluster_prefix:
                 self._publish_prefix(req)
-            row_logits = np.asarray(logits)[0]
-            if self.role == "prefill" and self._try_handoff(req, row_logits):
-                return True
-            self._emit_token(req, row_logits)
+            with spans.span("llm.prefill.fetch", rid=req.id):
+                row_logits = np.asarray(logits)[0]
+            # The first token is drawn before the handoff is tried: the draw
+            # is keyed by (seed, position), the same token either way.
+            tok = self._sample_rows([req], [row_logits])[0]
+            with spans.span("llm.emit", tokens=1, rid=req.id) as sp:
+                if not (self.role == "prefill" and self._try_handoff(req, tok)):
+                    self._emit_token(req, tok)
+                sp.set(finished=int(req._finished))
         return True
 
     def _register_prefix_blocks(self, req: LLMRequest):
@@ -997,70 +1048,90 @@ class LLMEngine:
         active = [r for r in self._slots if r is not None and r._sched_state == "decode"]
         if not active:
             return False
-        # Every active sequence needs its next write position backed by a
-        # physical block before the step; exhaustion preempts the youngest.
-        for req in list(active):
-            if req._sched_slot is None or self._slots[req._sched_slot] is not req:
-                continue  # preempted by an earlier needy sequence this tick
-            while req._sched_pos // self.block_size >= len(req._sched_table):
-                bid = self._alloc_block()
-                if bid is not None:
-                    req._sched_table.append(bid)
-                    continue
-                # Youngest-victim policy over ALL running sequences — the
-                # needy one included: when req itself is the youngest it is
-                # the one preempted (minimal recompute), not an older
-                # sequence carrying more progress.
-                running = [r for r in self._slots if r is not None]
-                victim = max(running, key=lambda r: r._sched_admit_seq)
-                if victim is req:
-                    if len(running) == 1:
-                        # Nobody else holds blocks: preempting req would just
-                        # readmit it into the same dry pool forever.
-                        self._finish(
-                            req,
-                            error=(
-                                "KV block pool exhausted with a single "
-                                "running sequence; raise num_blocks"
-                            ),
-                        )
-                    else:
-                        self._preempt(req)
-                    break  # req left its slot; its alloc loop is moot
-                self._preempt(victim)
-        # Re-derive the step batch: preemption/failure above may have
-        # removed sequences from their slots.
-        active = [
-            r
-            for r in self._slots
-            if r is not None
-            and r._sched_state == "decode"
-            and r._sched_pos // self.block_size < len(r._sched_table)
-        ]
-        if not active:
-            return True
-        import jax.numpy as jnp
+        spans = self.spans
+        with spans.span("llm.decode.build") as sp:
+            # Every active sequence needs its next write position backed by a
+            # physical block before the step; exhaustion preempts the youngest.
+            for req in list(active):
+                if req._sched_slot is None or self._slots[req._sched_slot] is not req:
+                    continue  # preempted by an earlier needy sequence this tick
+                while req._sched_pos // self.block_size >= len(req._sched_table):
+                    bid = self._alloc_block()
+                    if bid is not None:
+                        req._sched_table.append(bid)
+                        continue
+                    # Youngest-victim policy over ALL running sequences — the
+                    # needy one included: when req itself is the youngest it is
+                    # the one preempted (minimal recompute), not an older
+                    # sequence carrying more progress.
+                    running = [r for r in self._slots if r is not None]
+                    victim = max(running, key=lambda r: r._sched_admit_seq)
+                    if victim is req:
+                        if len(running) == 1:
+                            # Nobody else holds blocks: preempting req would just
+                            # readmit it into the same dry pool forever.
+                            self._finish(
+                                req,
+                                error=(
+                                    "KV block pool exhausted with a single "
+                                    "running sequence; raise num_blocks"
+                                ),
+                            )
+                        else:
+                            self._preempt(req)
+                        break  # req left its slot; its alloc loop is moot
+                    self._preempt(victim)
+            # Re-derive the step batch: preemption/failure above may have
+            # removed sequences from their slots.
+            active = [
+                r
+                for r in self._slots
+                if r is not None
+                and r._sched_state == "decode"
+                and r._sched_pos // self.block_size < len(r._sched_table)
+            ]
+            sp.set(rows=len(active))
+            if not active:
+                return True
+            import jax.numpy as jnp
 
-        toks = np.zeros((self.num_slots,), np.int32)
-        pos = np.zeros((self.num_slots,), np.int32)
-        tables = np.zeros((self.num_slots, self.n_max), np.int32)
-        for req in active:
-            s = req._sched_slot
-            toks[s] = req._sched_generated[-1]
-            pos[s] = req._sched_pos
-            tables[s, : len(req._sched_table)] = req._sched_table
-        logits, self._cache = self._decode_fn(
-            self.params,
-            jnp.asarray(toks),
-            self._cache,
-            jnp.asarray(tables),
-            jnp.asarray(pos),
-        )
-        logits = np.asarray(logits)
-        for req in active:
-            req._sched_pos += 1
-            self._emit_token(req, logits[req._sched_slot])
+            toks = np.zeros((self.num_slots,), np.int32)
+            pos = np.zeros((self.num_slots,), np.int32)
+            tables = np.zeros((self.num_slots, self.n_max), np.int32)
+            for req in active:
+                s = req._sched_slot
+                toks[s] = req._sched_generated[-1]
+                pos[s] = req._sched_pos
+                tables[s, : len(req._sched_table)] = req._sched_table
+            toks, tables, pos = jnp.asarray(toks), jnp.asarray(tables), jnp.asarray(pos)
+        spans.carried(rows=len(active))
+        with spans.span("llm.decode.dispatch"):
+            logits, self._cache = self._decode_fn(
+                self.params, toks, self._cache, tables, pos
+            )
+        with spans.span("llm.decode.fetch"):
+            # Waits for the device (and for this pass's prefill chunk, which
+            # runs ahead of the step), then copies the logits to the host.
+            logits = np.asarray(logits)
+        drawn = self._sample_rows(active, [logits[r._sched_slot] for r in active])
+        with spans.span("llm.emit", tokens=len(active)) as sp:
+            for req, tok in zip(active, drawn):
+                req._sched_pos += 1
+                self._emit_token(req, tok)
+            sp.set(finished=sum(r._finished for r in active))
         return True
+
+    def _sample_rows(self, reqs: list, rows: list) -> list[int]:
+        """One ``llm.sample`` span over every row of a step. Rows are drawn
+        independently (each draw is keyed by its request's seed and
+        position), so drawing them all before any is emitted changes no token."""
+        with self.spans.span(
+            "llm.sample",
+            rows=len(reqs),
+            sampled=sum(r.temperature > 0.0 for r in reqs),
+            top_k=sum(r.temperature > 0.0 and r.top_k > 0 for r in reqs),
+        ):
+            return [self._sample(req, row) for req, row in zip(reqs, rows)]
 
     def _sample(self, req: LLMRequest, row: np.ndarray) -> int:
         if req.temperature <= 0.0:
@@ -1078,18 +1149,11 @@ class LLMEngine:
         rng = np.random.default_rng((req.seed, len(req._sched_generated)))
         return int(rng.choice(len(p), p=p))
 
-    def _emit_token(self, req: LLMRequest, logits_row: np.ndarray):
-        tok = self._sample(req, logits_row)
+    def _emit_token(self, req: LLMRequest, tok: int):
         req._sched_generated.append(tok)
         req._sched_state = "decode"
-        now = time.monotonic()
         if req.t_first is None:
-            req.t_first = now
-            if self._metrics is not None:
-                try:
-                    self._metrics["serve_llm_ttft"].observe(now - req.t_submit)
-                except Exception:
-                    pass
+            req.t_first = time.monotonic()
         req._q.put(("token", tok))
         if len(req._sched_generated) >= req.max_new_tokens:
             self._finish(req)
@@ -1099,6 +1163,7 @@ class LLMEngine:
     def _preempt(self, victim: LLMRequest):
         LLM.preemptions += 1
         self._counts["preemptions"] += 1
+        victim.preemptions += 1
         _flight.record(
             "llm_preempt", f"{victim.id}:n{len(victim._sched_generated)}"
         )
@@ -1128,28 +1193,24 @@ class LLMEngine:
         req._sched_state = "done"
         req.t_done = time.monotonic()
         if handoff is not None:
+            outcome = "handoff"
             LLM.finished += 1
             self._counts["finished"] += 1
             req._q.put(("handoff", handoff))
         elif cancelled:
+            outcome = "cancelled"
             LLM.cancelled += 1
             self._counts["cancelled"] += 1
             req._q.put(("done", "cancelled"))
         elif error is not None:
+            outcome = "error"
             LLM.finished += 1
             self._counts["finished"] += 1
             req.error = error
             req._q.put(("error", error))
         else:
+            outcome = "finished"
             LLM.finished += 1
             self._counts["finished"] += 1
             req._q.put(("done", "complete"))
-            if self._metrics is not None and req.t_first is not None:
-                n = len(req._sched_generated)
-                if n > 1:
-                    try:
-                        self._metrics["serve_llm_tpot"].observe(
-                            (req.t_done - req.t_first) / (n - 1)
-                        )
-                    except Exception:
-                        pass
+        self.spans.end_request(req, outcome)

@@ -9,15 +9,61 @@ collector computes it at flush time by summing over ``ENGINES``, the
 registry of engines whose scheduler loop is still running, so several
 engines in one process fold into one honest series and the gauges drop to
 zero when the last engine exits instead of freezing at their final values.
+
+Spans (ISSUE 24) say where an iteration's and a request's time goes. Always
+on and fixed size, the same bargain as the plain ints. One ``with
+spans.span(name)`` per phase of the scheduler loop does both things a span
+needs: it enters a ``jax.profiler.TraceAnnotation`` (inert unless a profile
+is being taken; then the span lands in the ``/host:CPU`` plane of the same
+``.xplane.pb`` as the device's programs, on the profiler's clock, and there
+is no other way onto that clock) and it stamps ``time.monotonic_ns()`` at
+both ends into the iteration's record, which leaves the process through
+``LLMDeployment.get_stats()["spans"]``. OBSERVABILITY.md, "serve.llm spans",
+has the table of names and arguments.
 """
 
 from __future__ import annotations
 
+import itertools
+import threading
+import time
 import weakref
 
 # Engines register here at construction; the scheduler loop's exit (stop or
 # crash) withdraws them. WeakSet so an abandoned engine can't pin itself.
 ENGINES: "weakref.WeakSet" = weakref.WeakSet()
+
+SPAN_NAMES = (
+    "llm.iteration",
+    "llm.admit",
+    "llm.prefill.build",
+    "llm.prefill.dispatch",
+    "llm.prefill.fetch",
+    "llm.decode.build",
+    "llm.decode.dispatch",
+    "llm.decode.fetch",
+    "llm.sample",
+    "llm.emit",
+)
+ITERATION_KINDS = ("decode", "prefill", "mixed")
+# One record per pass of the scheduler loop that dispatched a program: the
+# start stamp, what the pass carried, and the nanoseconds of each span.
+ITERATION_FIELDS = ("t_start_ns", "rows", "prefill_tokens", "waiting", "running") + SPAN_NAMES
+_T_START, _ROWS, _PREFILL_TOKENS, _WAITING, _RUNNING = range(5)
+_FIRST_SPAN = len(ITERATION_FIELDS) - len(SPAN_NAMES)
+_SPAN_FIELD = {name: _FIRST_SPAN + i for i, name in enumerate(SPAN_NAMES)}
+# One record per request that ended; stamps are CLOCK_MONOTONIC nanoseconds
+# (0 = never reached), a clock every process of a host shares.
+REQUEST_FIELDS = (
+    "rid", "request_id", "trace_id", "span_id",
+    "t_recv_ns", "t_submit_ns", "t_admit_ns", "t_first_ns", "t_done_ns",
+    "prompt_tokens", "cached_tokens", "generated", "preemptions", "outcome",
+)
+COMPILE_FIELDS = ("t_end_ns", "duration_ns", "event", "program")
+ITERATION_RING, REQUEST_RING, COMPILE_RING = 2048, 512, 256
+# A stamp further than this from its neighbour is another host's clock
+# (util.tracing.hop_trace_events draws the same line).
+FOREIGN_STAMP_S = 600.0
 
 
 class _LLMStats:
@@ -40,11 +86,216 @@ class _LLMStats:
         "prefix_import_hits",
         "prefix_import_misses",
         "prefix_import_errors",
+        # Scheduler-loop nanoseconds by span and iterations by kind: lists
+        # of plain ints, indexed like SPAN_NAMES / ITERATION_KINDS.
+        "span_ns",
+        "iterations",
     )
 
     def __init__(self):
         for name in self.__slots__:
             setattr(self, name, 0)
+        self.span_ns = [0] * len(SPAN_NAMES)
+        self.iterations = [0] * len(ITERATION_KINDS)
 
 
 LLM = _LLMStats()
+
+
+class Ring:
+    """Overwrite-oldest ring of fixed size. One writer at a time; a reader
+    takes a best-effort snapshot (a slot store is atomic, so it never sees
+    a torn record, at worst one the writer has just replaced)."""
+
+    __slots__ = ("slots", "n")
+
+    def __init__(self, size: int):
+        self.slots: list = [None] * size
+        self.n = 0  # records ever pushed
+
+    def push(self, rec):
+        self.slots[self.n % len(self.slots)] = rec
+        self.n += 1
+
+    def since(self, cursor: int = 0) -> list:
+        """Records pushed at positions >= ``cursor`` that are still held,
+        oldest first."""
+        n, size = self.n, len(self.slots)
+        return [self.slots[i % size] for i in range(max(cursor, n - size), n)]
+
+
+# -- compilations: the listener is the process's, like the jax registry it
+# hangs on, so every engine of a process reports the same ring ------------
+
+_COMPILE_EVENTS = {
+    "/jax/core/compile/backend_compile_duration": "backend_compile",
+    "/jax/compilation_cache/cache_retrieval_time_sec": "cache_retrieval",
+}
+COMPILES = Ring(COMPILE_RING)
+_compile_lock = threading.Lock()  # any thread may compile
+_listening = False
+
+
+def _on_compile_event(event: str, duration_secs: float, **kwargs):
+    kind = _COMPILE_EVENTS.get(event)
+    if kind is not None:
+        rec = (time.monotonic_ns(), int(duration_secs * 1e9), kind, str(kwargs.get("fun_name", "")))
+        with _compile_lock:
+            COMPILES.push(rec)
+
+
+def listen_for_compiles():
+    """Once per process, before its first program is built: every program
+    the backend builds (``backend_compile``; a persistent-cache hit raises it
+    too, with the read's duration) and every read of the persistent cache
+    (``cache_retrieval``), stamped as it ends. The counter behind "which step
+    recompiled" and "compilations inside the window: there should be none"."""
+    global _listening
+    with _compile_lock:
+        if _listening:
+            return
+        _listening = True
+    import jax
+
+    jax.monitoring.register_event_duration_secs_listener(_on_compile_event)
+
+
+def compile_records() -> list:
+    with _compile_lock:
+        return [list(r) for r in COMPILES.since()]
+
+
+# -- iterations and requests: one recorder per engine ----------------------
+
+# Recorders outlive their scheduler loop (ENGINES does not): the /metrics
+# collector still folds the requests that ended just before an engine stopped.
+RECORDERS: "weakref.WeakSet" = weakref.WeakSet()
+
+
+class _Span:
+    __slots__ = ("_rec", "_field", "_ann", "t0")
+
+    def __init__(self, rec: list, field: int, ann):
+        self._rec, self._field, self._ann = rec, field, ann
+
+    def __enter__(self):
+        self._ann.__enter__()
+        self.t0 = time.monotonic_ns()
+        return self
+
+    def set(self, **args):
+        """Arguments known only once the phase has run."""
+        self._ann.set_metadata(**args)
+
+    def __exit__(self, *exc):
+        self._rec[self._field] += time.monotonic_ns() - self.t0
+        self._ann.__exit__(*exc)
+        return False
+
+
+class EngineSpans:
+    """One engine's iteration and request records. The scheduler thread is
+    the only writer of the iteration ring and the totals."""
+
+    def __init__(self):
+        from jax.profiler import TraceAnnotation
+
+        self._annotation = TraceAnnotation
+        self.iterations = Ring(ITERATION_RING)
+        self.requests = Ring(REQUEST_RING)
+        # A request complete on arrival ends on its submitting thread.
+        self._request_lock = threading.Lock()
+        self._cur = [0] * len(ITERATION_FIELDS)
+        # Cumulative plain ints, for stats(); LLM carries the process's.
+        self.span_ns = [0] * len(SPAN_NAMES)
+        self.span_counts = [0] * len(SPAN_NAMES)
+        self.kinds = [0] * len(ITERATION_KINDS)
+        self.requests_folded = 0  # the /metrics collector's cursor into ``requests``
+        self.setup: dict = {}  # seconds of each stage of building the engine
+        RECORDERS.add(self)
+
+    # -- one iteration (scheduler thread) -------------------------------
+
+    def begin(self, waiting: int, running: int) -> _Span:
+        """Opens ``llm.iteration``, the parent of the pass's other spans;
+        ``end`` closes it."""
+        cur = self._cur
+        cur[:] = [0] * len(cur)
+        cur[_WAITING], cur[_RUNNING] = waiting, running
+        it = _Span(cur, _SPAN_FIELD["llm.iteration"], self._annotation("llm.iteration"))
+        it.__enter__()
+        cur[_T_START] = it.t0
+        return it
+
+    def span(self, name: str, **args) -> _Span:
+        return _Span(self._cur, _SPAN_FIELD[name], self._annotation(name, **args))
+
+    def carried(self, rows: int = 0, prefill_tokens: int = 0):
+        """What this pass dispatched: decode rows, prompt tokens of its chunk."""
+        self._cur[_ROWS] += rows
+        self._cur[_PREFILL_TOKENS] += prefill_tokens
+
+    def end(self, it: _Span):
+        """Closes the iteration. A pass that dispatched no program leaves no
+        record: the idle wait is the time between records."""
+        cur = self._cur
+        rows, chunk = cur[_ROWS], cur[_PREFILL_TOKENS]
+        it.set(rows=rows, prefill_tokens=chunk)
+        it.__exit__(None, None, None)
+        if not (rows or chunk):
+            return
+        self.iterations.push(tuple(cur))
+        kind = ITERATION_KINDS.index("mixed" if rows and chunk else "decode" if rows else "prefill")
+        self.kinds[kind] += 1
+        LLM.iterations[kind] += 1
+        for i in range(len(SPAN_NAMES)):
+            ns = cur[_FIRST_SPAN + i]
+            if ns:
+                self.span_ns[i] += ns
+                self.span_counts[i] += 1
+                LLM.span_ns[i] += ns
+
+    # -- one request ------------------------------------------------------
+
+    def end_request(self, req, outcome: str):
+        def ns(t):
+            return int(t * 1e9) if t else 0
+
+        t_recv, t_submit = ns(req.t_recv), ns(req.t_submit)
+        if abs(t_recv - t_submit) > FOREIGN_STAMP_S * 1e9:
+            t_recv = 0  # a proxy on another host: not this clock
+        rec = (
+            req.id, req.request_id, req.trace_id, req.span_id,
+            t_recv, t_submit, ns(req.t_admit), ns(req.t_first), ns(req.t_done),
+            len(req.prompt), req.cached_tokens, len(req._sched_generated), req.preemptions, outcome,
+        )
+        with self._request_lock:
+            self.requests.push(rec)
+
+    # -- how it leaves the process -----------------------------------------
+
+    def totals(self) -> dict:
+        """For ``LLMEngine.stats()``: the cumulative plain ints only."""
+        return {
+            "span_ns": dict(zip(SPAN_NAMES, self.span_ns)),
+            "span_counts": dict(zip(SPAN_NAMES, self.span_counts)),
+            "iterations": dict(zip(ITERATION_KINDS, self.kinds)),
+        }
+
+    def export(self) -> dict:
+        """For ``LLMDeployment.get_stats()["spans"]``: the rings as plain
+        lists, oldest first, with the names of their columns. The iteration
+        ring goes as ONE flat list of ints, row after row (a row is
+        ``len(fields["iterations"])`` wide): two thousand small lists cost
+        the 1 Hz pollers more to build, to pickle and to read (PERF.md, PR 24)."""
+        return {
+            "iterations": list(itertools.chain.from_iterable(self.iterations.since())),
+            "requests": [list(r) for r in self.requests.since()],
+            "compiles": compile_records(),
+            "setup": dict(self.setup),
+            "fields": {
+                "iterations": list(ITERATION_FIELDS),
+                "requests": list(REQUEST_FIELDS),
+                "compiles": list(COMPILE_FIELDS),
+            },
+        }

@@ -232,21 +232,11 @@ def format_hop_table(summary: dict) -> str:
     return "\n".join(lines)
 
 
-def collect_hop_records() -> list[dict]:
-    """Hop records from the connected core worker (empty when hop timing is
-    off or nothing has completed)."""
-    from ray_tpu._private import worker_context
-
-    cw = worker_context.get_core_worker_if_initialized()
-    if cw is None:
-        return []
-    return cw.hop_records()
-
-
 def drain_hop_records() -> list[dict]:
-    """collect_hop_records() + clear — use between measurement phases so an
-    earlier phase's records can't be evicted from the bounded ring buffer
-    by a later, faster phase."""
+    """Hop records from the connected core worker (empty when hop timing is
+    off or nothing has completed), cleared as they are read — use between
+    measurement phases so an earlier phase's records can't be evicted from
+    the bounded ring buffer by a later, faster phase."""
     from ray_tpu._private import worker_context
 
     cw = worker_context.get_core_worker_if_initialized()
