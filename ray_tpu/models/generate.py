@@ -289,16 +289,22 @@ def _paged_decode_chunk_hidden(
         writable = positions < jnp.asarray(valid_to, jnp.int32)[:, None]
         blk_phys = jnp.where(writable, blk_phys, 0)
 
-    def body(x, layer):
-        lp, ck_slot, cv_slot = layer  # [N, Bs, KV, Dh]
+    # The whole pool rides the layer scan as its CARRY (never xs -> ys, which
+    # are distinct buffers of the loop): each layer scatters its rows and
+    # gathers its logical view with the layer index in the same indexing op,
+    # so no [N, Bs, KV, Dh] layer slice is materialised and a caller that
+    # donates ``cache`` gets the pool updated in place.
+    def body(carry, layer):
+        x, ck, cv = carry  # pools [L, N, Bs, KV, Dh]
+        lp, l = layer
         qh, k, v = _project_qkv(lp, x, positions, cfg)
-        ck = ck_slot.at[blk_phys, row_off].set(k)
-        cv = cv_slot.at[blk_phys, row_off].set(v)
+        ck = ck.at[l, blk_phys, row_off].set(k)
+        cv = cv.at[l, blk_phys, row_off].set(v)
         # Gather each row's logical cache view through its block table,
         # then attend exactly like the dense path. Masked (p == 0) entries
         # contribute nothing, so null-block garbage stays invisible.
-        ck_g = ck[block_tables].reshape(B, S, cfg.n_kv_heads, cfg.head_dim)
-        cv_g = cv[block_tables].reshape(B, S, cfg.n_kv_heads, cfg.head_dim)
+        ck_g = ck[l, block_tables].reshape(B, S, cfg.n_kv_heads, cfg.head_dim)
+        cv_g = cv[l, block_tables].reshape(B, S, cfg.n_kv_heads, cfg.head_dim)
         k_pos = jnp.arange(S, dtype=jnp.int32)
         mask = k_pos[None, None, :] <= positions[:, :, None]
         if cfg.sliding_window:
@@ -306,9 +312,12 @@ def _paged_decode_chunk_hidden(
         o = _cache_attention(qh, ck_g, cv_g, mask, cfg)
         x = x + o.reshape(B, q, -1) @ lp["wo"].astype(o.dtype)
         x = _mlp(lp, x, cfg)
-        return x, (ck, cv)
+        return (x, ck, cv), None
 
-    x, (ks, vs) = lax.scan(body, x, (params["layers"], cache["k"], cache["v"]))
+    layer_ids = jnp.arange(cache["k"].shape[0], dtype=jnp.int32)
+    (x, ks, vs), _ = lax.scan(
+        body, (x, cache["k"], cache["v"]), (params["layers"], layer_ids)
+    )
     return _rms_norm(x, params["norm_f"], cfg.norm_eps), {"k": ks, "v": vs}
 
 

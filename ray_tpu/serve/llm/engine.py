@@ -11,7 +11,9 @@ vLLM-lineage iteration-level scheduler on top of the paged KV cache:
   and enough KV blocks free up — mid-decode, not between batches.
 - **paged KV cache**: ``init_paged_cache`` block pool + per-sequence block
   tables with a host-side free-list. Block 0 is the reserved null block
-  (inactive slots and write-masked padding rows land there).
+  (inactive slots and write-masked padding rows land there). The pool is
+  DONATED to the decode and prefill programs, which update it in place
+  (``_run_donated``): one pool lives on the device, never a second copy.
 - **chunked prefill interleaved with decode**: at most one fixed-shape
   prefill chunk runs per scheduler iteration between decode steps, so a
   long admitted prompt cannot stall tokens for running streams.
@@ -234,11 +236,17 @@ def _compiled_fns(cfg):
                 )[:, 0]
                 return (last @ _head(p).astype(last.dtype)).astype(jnp.float32), c
 
+            # The pool (argument 2) is DONATED to both programs; the layer
+            # scan carries it, so a step updates it in place (the caller's
+            # side of the bargain: ``LLMEngine._run_donated``). The names (a
+            # lambda, prefill_chunk_row) are how the benchmark's
+            # trace_programs patterns find the programs in a trace: keep them.
             fns = (
                 jax.jit(
-                    lambda p, t, c, bt, pos: paged_decode_step(p, t, c, bt, pos, cfg)
+                    lambda p, t, c, bt, pos: paged_decode_step(p, t, c, bt, pos, cfg),
+                    donate_argnums=2,
                 ),
-                jax.jit(prefill_chunk_row),
+                jax.jit(prefill_chunk_row, donate_argnums=2),
             )
             _JIT_CACHE[cfg] = fns
         return fns
@@ -350,6 +358,9 @@ class LLMEngine:
             "prefix_import_hits": 0,
             "prefix_import_misses": 0,
             "prefix_import_errors": 0,
+            # Dispatches after which the pool passed in was still alive: the
+            # program copied the pool instead of updating it in place. 0 is right.
+            "kv_pool_not_donated": 0,
         }
         t0 = time.monotonic()
         self._decode_fn, self._prefill_fn = _compiled_fns(cfg)
@@ -1001,9 +1012,7 @@ class LLMEngine:
             )
         spans.carried(prefill_tokens=len(piece))
         with spans.span("llm.prefill.dispatch", rid=req.id):
-            logits, self._cache = self._prefill_fn(
-                self.params, inputs[0], self._cache, *inputs[1:]
-            )
+            logits = self._run_donated(self._prefill_fn, *inputs)
         req._sched_pos = min(pos0 + q, req._sched_target)
         self._register_prefix_blocks(req)
         if req._sched_pos >= req._sched_target:
@@ -1021,6 +1030,18 @@ class LLMEngine:
                     self._emit_token(req, tok)
                 sp.set(finished=int(req._finished))
         return True
+
+    def _run_donated(self, fn, tokens, *rest):
+        """Dispatch one pool-updating program. The pool is donated: the
+        arrays passed in are deleted and ``self._cache`` is rebound to the
+        result in the same statement, so nothing reads a donated buffer. A
+        program that raises after donating leaves no pool; the exception
+        ends ``_loop``, which marks the engine crashed."""
+        pool = self._cache
+        logits, self._cache = fn(self.params, tokens, pool, *rest)
+        if not (pool["k"].is_deleted() and pool["v"].is_deleted()):
+            self._counts["kv_pool_not_donated"] += 1
+        return logits
 
     def _register_prefix_blocks(self, req: LLMRequest):
         """Publish freshly-WRITTEN full prompt blocks for reuse. Done as
@@ -1106,9 +1127,7 @@ class LLMEngine:
             toks, tables, pos = jnp.asarray(toks), jnp.asarray(tables), jnp.asarray(pos)
         spans.carried(rows=len(active))
         with spans.span("llm.decode.dispatch"):
-            logits, self._cache = self._decode_fn(
-                self.params, toks, self._cache, tables, pos
-            )
+            logits = self._run_donated(self._decode_fn, toks, tables, pos)
         with spans.span("llm.decode.fetch"):
             # Waits for the device (and for this pass's prefill chunk, which
             # runs ahead of the step), then copies the logits to the host.

@@ -217,3 +217,93 @@ def test_paged_chunk_matches_dense_chunk_with_window():
     np.testing.assert_allclose(
         np.asarray(paged), np.asarray(dense), rtol=2e-4, atol=2e-5
     )
+
+
+# ---------------------------------------------------------------------------
+# The pool updated in place: jitted with the cache DONATED (what the serving
+# engine does), each paged entry point gives the same logits and the same
+# pool as the un-donated call, in the buffer it was handed.
+# ---------------------------------------------------------------------------
+
+
+def _dense_after_prompt(params, cfg, toks, fed):
+    """Dense-cache oracle: logits [len(fed), V] of feeding ``fed`` after the
+    prompt ``toks`` (an empty prompt: ``fed`` starts at position 0)."""
+    dcache, pos = init_cache(cfg, 1, 32), jnp.int32(0)
+    if toks:
+        _, dcache, pos = prefill(params, jnp.asarray([toks], jnp.int32), dcache, cfg)
+    logits, _ = decode_chunk(params, jnp.asarray([fed], jnp.int32), dcache, pos, cfg)
+    return np.asarray(logits)[0]
+
+
+def _case_step_inactive_slot():
+    """Decode step over three slots at different positions, one INACTIVE."""
+    cfg = _cfg(n_kv_heads=2)
+    params = init_params(jax.random.PRNGKey(0), cfg)
+    seqs = [[7, 3, 9, 1, 5], [2, 8, 6, 4, 11, 13, 17, 19, 23]]
+    tables = [[1, 2, 3], [6, 4, 5], [0, 0, 0]]
+    cache = init_paged_cache(cfg, num_blocks=8, block_size=4)
+    for toks, table in zip(seqs, tables):
+        _, cache = _paged_prefill(params, toks, cache, table, cfg)
+    step = [31, 37, 0]
+    call = lambda c: paged_decode_step(  # noqa: E731
+        params, jnp.asarray(step, jnp.int32), c, jnp.asarray(tables, jnp.int32),
+        jnp.asarray([len(seqs[0]), len(seqs[1]), 0], jnp.int32), cfg,
+    )
+    want = {
+        (slot,): _dense_after_prompt(params, cfg, seqs[slot], [step[slot]])[0]
+        for slot in (0, 1)
+    }
+    return call, cache, want
+
+
+def _case_chunk_valid_to():
+    """A padded prefill chunk (5 real tokens of 8): masked rows go to the null block."""
+    cfg = _cfg()
+    params = init_params(jax.random.PRNGKey(0), cfg)
+    toks = [7, 3, 9, 1, 5]
+    cache = init_paged_cache(cfg, num_blocks=6, block_size=4)
+    call = lambda c: paged_decode_chunk(  # noqa: E731
+        params, jnp.asarray([toks + [0] * 3], jnp.int32), c,
+        jnp.asarray([[1, 2, 3]], jnp.int32), jnp.asarray([0], jnp.int32), cfg,
+        valid_to=jnp.asarray([5], jnp.int32),
+    )
+    dense = _dense_after_prompt(params, cfg, [], toks)
+    return call, cache, {(0, row): dense[row] for row in range(5)}
+
+
+def _case_chunk_window():
+    """Sliding window on: a three-token chunk after a six-token prompt."""
+    cfg = _cfg(sliding_window=6, n_kv_heads=2)
+    params = init_params(jax.random.PRNGKey(0), cfg)
+    toks, extra, table = [9, 4, 7, 1, 3, 8], [5, 2, 6], [3, 1, 2, 4]
+    cache = init_paged_cache(cfg, num_blocks=5, block_size=4)
+    _, cache = _paged_prefill(params, toks, cache, table, cfg, chunk=3)
+    call = lambda c: paged_decode_chunk(  # noqa: E731
+        params, jnp.asarray([extra], jnp.int32), c, jnp.asarray([table], jnp.int32),
+        jnp.asarray([6], jnp.int32), cfg,
+    )
+    dense = _dense_after_prompt(params, cfg, toks, extra)
+    return call, cache, {(0, row): dense[row] for row in range(3)}
+
+
+@pytest.mark.parametrize(
+    "case", [_case_step_inactive_slot, _case_chunk_valid_to, _case_chunk_window]
+)
+def test_donated_jit_matches_undonated_call_and_dense_oracle(case):
+    call, cache, want = case()
+    ref_logits, ref_pool = call(cache)  # plain call: the caller's pool survives
+    assert not any(a.is_deleted() for a in cache.values())
+    given = jax.tree.map(jnp.copy, cache)
+    ptrs = {name: a.unsafe_buffer_pointer() for name, a in given.items()}
+    logits, pool = jax.jit(call, donate_argnums=0)(given)
+    assert all(a.is_deleted() for a in given.values())
+    for name in ("k", "v"):
+        assert pool[name].unsafe_buffer_pointer() == ptrs[name]
+        np.testing.assert_array_equal(np.asarray(pool[name]), np.asarray(ref_pool[name]))
+    np.testing.assert_array_equal(np.asarray(logits), np.asarray(ref_logits))
+    assert np.isfinite(np.asarray(logits)).all()
+    for idx, dense in want.items():
+        got = np.asarray(logits)[idx]
+        assert got.argmax() == dense.argmax()
+        np.testing.assert_allclose(got, dense, rtol=2e-4, atol=2e-5)

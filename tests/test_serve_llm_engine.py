@@ -7,7 +7,10 @@ engine's process-level jit cache); the end-to-end HTTP tests share ONE
 module-scoped cluster; the concurrency sweep is marked `slow`.
 """
 
+import functools
 import json
+import os
+import re
 import threading
 import time
 import urllib.request
@@ -166,6 +169,19 @@ def test_prefix_eviction_under_pressure(model):
         eng.shutdown()
 
 
+def _stopped_engine(model, **kw):
+    """White-box: an engine whose scheduler thread has exited (idle, so the
+    loop's exit sweep had nothing to finalize), re-opened for submits so a
+    test can drive ``_admit`` and the two ticks by hand, deterministically."""
+    from ray_tpu.serve.llm import LLMEngine
+
+    params, cfg = model
+    eng = LLMEngine(params, cfg, block_size=4, prefill_chunk=4, **kw)
+    eng.shutdown()
+    eng._crashed = None
+    return eng
+
+
 def test_admission_does_not_double_count_cached_hits_as_evictable(model):
     """Regression: with the free list EMPTY and the only refs-0 cached
     blocks being the request's own prefix hits, admission must wait — not
@@ -175,14 +191,10 @@ def test_admission_does_not_double_count_cached_hits_as_evictable(model):
     The race state (every non-hit block held by running sequences) is built
     by hand with the scheduler thread STOPPED, and _admit() driven directly
     — the only deterministic way to pin this admission-time invariant."""
-    from ray_tpu.serve.llm import LLMEngine, block_hashes
+    from ray_tpu.serve.llm import block_hashes
     from ray_tpu.serve.llm.engine import _PrefixEntry
 
-    params, cfg = model
-    eng = LLMEngine(params, cfg, num_slots=2, block_size=4,
-                    max_model_len=24, num_blocks=7, prefill_chunk=4)
-    eng.shutdown()  # idle: the loop's exit sweep has nothing to finalize
-    eng._crashed = None  # white-box: re-open submits to drive _admit by hand
+    eng = _stopped_engine(model, num_slots=2, max_model_len=24, num_blocks=7)
     prompt = _rand_prompt(41, 9)  # 3 blocks: 2 hashable + 1 tail
     hashes = block_hashes(prompt, 4)[:2]
     b1, b2 = eng._free.pop(), eng._free.pop()
@@ -265,6 +277,142 @@ def test_submit_after_scheduler_crash_raises(model):
         eng.check_health()
 
 
+def test_pool_is_donated_and_updated_in_place(model):
+    """Every prefill and decode dispatch consumes the pool it is handed (the
+    arrays passed in are deleted) and hands back a pool in the same device
+    buffers; ``kv_pool_not_donated`` stays 0 and the tokens are the oracle's."""
+    params, cfg = model
+    eng = _stopped_engine(model, num_slots=2, max_model_len=32)
+    prompt = _rand_prompt(7, 6)  # two chunks of 4; the second emits token 0
+    req = eng.submit(prompt, max_new_tokens=4)
+    assert eng._admit() == 1
+    for tick in (eng._prefill_tick, eng._prefill_tick, eng._decode_tick):
+        given = dict(eng._cache)
+        ptrs = {n: a.unsafe_buffer_pointer() for n, a in given.items()}
+        assert tick()
+        assert all(a.is_deleted() for a in given.values())
+        assert {n: a.unsafe_buffer_pointer() for n, a in eng._cache.items()} == ptrs
+    while not req._finished:
+        assert eng._decode_tick()
+    assert eng.stats()["kv_pool_not_donated"] == 0
+    assert req.result(timeout=5) == _dense(params, cfg, prompt, 4)
+
+
+def test_kv_pool_not_donated_counts_a_program_that_copies(model):
+    """The counter reads non-zero when a program leaves its input pool alive
+    (here: the same step jitted without donation), so its 0 means something."""
+    import jax
+
+    from ray_tpu.models.generate import paged_decode_step
+
+    _, cfg = model
+    eng = _stopped_engine(model, num_slots=1, max_model_len=32)
+    eng._decode_fn = jax.jit(
+        lambda p, t, c, bt, pos: paged_decode_step(p, t, c, bt, pos, cfg)
+    )
+    req = eng.submit([1, 2, 3], max_new_tokens=3)
+    eng._admit()
+    assert eng._prefill_tick()
+    assert eng.stats()["kv_pool_not_donated"] == 0
+    while not req._finished:
+        assert eng._decode_tick()
+    assert eng.stats()["kv_pool_not_donated"] == 2  # one per decode step
+
+
+@functools.lru_cache(maxsize=None)
+def _engine_program(cfg, kind):
+    """One engine program compiled at an engine's shapes: (compiled text,
+    the pool's shape, flat index of the pool's k among the arguments)."""
+    import jax
+    import jax.numpy as jnp
+
+    from ray_tpu.models.generate import init_paged_cache
+    from ray_tpu.models.transformer import init_params
+    from ray_tpu.serve.llm.engine import _compiled_fns
+
+    slots, n_max, chunk = 3, 8, 4
+    params = jax.eval_shape(lambda: init_params(jax.random.PRNGKey(0), cfg))
+    cache = jax.eval_shape(lambda: init_paged_cache(cfg, slots * n_max + 1, 4))
+    i32 = lambda *shape: jax.ShapeDtypeStruct(shape, jnp.int32)  # noqa: E731
+    decode, prefill = _compiled_fns(cfg)
+    if kind == "decode":
+        lowered = decode.lower(params, i32(slots), cache, i32(slots, n_max), i32(slots))
+    else:
+        lowered = prefill.lower(
+            params, i32(1, chunk), cache, i32(1, n_max), i32(1), i32(1), i32()
+        )
+    k_arg = len(jax.tree.leaves(params)) + 1  # params, the tokens, then k and v
+    return lowered.compile().as_text(), cache["k"].shape, k_arg
+
+
+@pytest.mark.parametrize("kind", ["decode", "prefill"])
+def test_engine_programs_alias_the_pool_and_never_copy_it(model, kind):
+    """The compiled text of each engine program aliases both pool arguments
+    to outputs, and no ``copy`` / ``dynamic-update-slice`` in it produces an
+    array of the pool's shape or of one layer of it (the xs -> ys form of the
+    layer scan produced both, once a layer)."""
+    text, pool, k_arg = _engine_program(model[1], kind)
+    alias = re.search(r"input_output_alias=\{(.*?)\}, entry", text)
+    assert alias, "no input_output_alias in the compiled module"
+    aliased = {int(m) for m in re.findall(r"\((\d+), \{\}", alias.group(1))}
+    assert {k_arg, k_arg + 1} <= aliased, alias.group(0)
+    for dims in (pool, pool[1:]):
+        shape = re.escape("f32[" + ",".join(map(str, dims)) + "]")
+        hits = re.findall(rf"= {shape}\S* (?:copy|dynamic-update-slice)\(.*", text)
+        assert not hits, hits[:3]
+
+
+@pytest.mark.parametrize("kind", ["decode", "prefill"])
+def test_engine_program_names_match_the_benchmark_patterns(model, kind):
+    """The benchmark finds the two programs in a device trace by the
+    ``trace_programs`` patterns of its serving configuration: a renamed
+    callable would read as no ``decode_step_ms`` on the chip, so it fails here."""
+    root = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
+    path = os.path.join(root, "benchmarks", "configs", "mistral-7b-v0.1-serve16.json")
+    with open(path) as f:
+        patterns = json.load(f)["trace_programs"]
+    module = re.search(r"HloModule (\S+?),", _engine_program(model[1], kind)[0]).group(1)
+    assert re.search(patterns[kind], module), (module, patterns[kind])
+    other = patterns["prefill" if kind == "decode" else "decode"]
+    assert not re.search(other, module), (module, other)
+
+
+@pytest.mark.filterwarnings(
+    "ignore::pytest.PytestUnhandledThreadExceptionWarning"
+)
+def test_crash_after_donation_ends_engine_without_touching_dead_pool(model):
+    """A decode program that raises AFTER its input pool was donated leaves
+    the engine no pool at all: it must end as crashed with every open
+    request failed (running and waiting), and never dispatch again."""
+    from ray_tpu.serve.llm import LLMEngine
+
+    params, cfg = model
+    eng = LLMEngine(params, cfg, num_slots=1, block_size=4,
+                    max_model_len=32, prefill_chunk=4)
+    real, calls = eng._decode_fn, []
+
+    def donate_then_raise(p, t, c, bt, pos):
+        calls.append(c)
+        real(p, t, c, bt, pos)
+        raise RuntimeError("boom after donation")
+
+    eng._decode_fn = donate_then_raise
+    running = eng.submit([1, 2, 3], max_new_tokens=4)
+    waiting = eng.submit([4, 5, 6], max_new_tokens=4)  # one slot: stays queued
+    for req in (running, waiting):
+        with pytest.raises(RuntimeError, match="boom after donation"):
+            req.result(timeout=30)
+    eng._thread.join(timeout=10)
+    assert not eng._thread.is_alive()
+    assert len(calls) == 1 and all(a.is_deleted() for a in calls[0].values())
+    assert all(a.is_deleted() for a in eng._cache.values())  # no pool is left
+    with pytest.raises(RuntimeError, match="scheduler died"):
+        eng.submit([7, 8, 9], max_new_tokens=2)
+    with pytest.raises(RuntimeError):
+        eng.check_health()
+    assert len(calls) == 1  # nothing stepped again on the deleted pool
+
+
 def test_engine_registry_tracks_live_schedulers(model):
     """stats.ENGINES holds exactly the engines whose scheduler loop is
     running — the flush-time gauge sums drop an engine at shutdown instead
@@ -305,13 +453,7 @@ def test_preemption_victim_is_youngest_even_when_needy(model):
     """Youngest-victim policy holds when the block-needing sequence IS the
     youngest: it preempts itself (minimal recompute) — an older sequence
     carrying more progress is never sacrificed for it."""
-    from ray_tpu.serve.llm import LLMEngine
-
-    params, cfg = model
-    eng = LLMEngine(params, cfg, num_slots=2, block_size=4,
-                    max_model_len=40, prefill_chunk=4)
-    eng.shutdown()  # idle: drive the scheduler by hand, deterministically
-    eng._crashed = None  # white-box: re-open submits
+    eng = _stopped_engine(model, num_slots=2, max_model_len=40)
     ra = eng.submit([3] * 6, max_new_tokens=20)
     rb = eng.submit([9] * 6, max_new_tokens=20)
     eng._admit()
