@@ -370,6 +370,64 @@ def paged_decode_step(params, token, cache, block_tables, pos, cfg: TransformerC
     return logits[:, 0], cache
 
 
+def _kth_largest(x, k):
+    """Per row of ``x`` [B, V] float32, its ``k[b]``-th largest value
+    (``k`` [B] int32 in 1..V), exactly and for any k, without a sort: floats
+    map to uint32 keys in the same order, and the largest key that at least k
+    entries reach is built bit by bit, 32 counting passes over the row."""
+    u = lax.bitcast_convert_type(x, jnp.uint32)
+    keys = jnp.where(u >> 31 == 1, ~u, u | jnp.uint32(1 << 31))
+
+    def settle_bit(i, t):
+        cand = t | lax.shift_left(jnp.uint32(1), (31 - i).astype(jnp.uint32))
+        reached = jnp.sum(keys >= cand[:, None], axis=-1, dtype=jnp.int32)
+        return jnp.where(reached >= k, cand, t)
+
+    t = lax.fori_loop(0, 32, settle_bit, jnp.zeros(x.shape[:1], jnp.uint32))
+    u = jnp.where(t >> 31 == 1, t ^ jnp.uint32(1 << 31), ~t)
+    return lax.bitcast_convert_type(u, jnp.float32)
+
+
+def draw_tokens(logits, temperature, top_k, seed, counter):
+    """The next token of each row, drawn inside the program that computed
+    ``logits`` [B, V] float32, every row by its own rule (all traced, so one
+    compiled program serves every mix of rows):
+
+    - ``temperature[b] <= 0``: ``argmax`` of the raw logits, first index on ties.
+    - otherwise a draw from ``softmax(logits / temperature)``, restricted for
+      ``top_k[b] > 0`` to the entries not under the row's ``top_k``-th largest
+      value (ties at the threshold stay, a ``top_k`` of V or more cuts
+      nothing), as ``argmax(logits / T + Gumbel noise)`` in float32. The
+      threshold search sits under a ``lax.cond`` on "some row of this batch
+      has a ``top_k``": a batch without one does not pay for it.
+
+    The noise of row b comes from ``fold_in(key(seed[b]), counter[b])`` alone,
+    where ``seed`` [B, 2] uint32 holds the (low, high) halves of the request's
+    64-bit seed, which ARE the threefry key, and ``counter`` [B] int32 is the
+    index of the token drawn. Nothing is carried or split, so a row draws the
+    same token alone or in a batch, in any row, from any program, on any host.
+    Returns [B] int32."""
+    greedy = jnp.argmax(logits, axis=-1).astype(jnp.int32)
+    sampled = temperature > 0.0
+    scaled = logits / jnp.where(sampled, temperature, 1.0)[:, None]
+    capped = sampled & (top_k > 0)
+    kth = lax.cond(
+        jnp.any(capped),
+        lambda: jnp.where(
+            capped, _kth_largest(scaled, jnp.clip(top_k, 1, logits.shape[-1])), -jnp.inf
+        ),
+        lambda: jnp.full(top_k.shape, -jnp.inf, jnp.float32),
+    )
+    scaled = jnp.where(scaled < kth[:, None], -jnp.inf, scaled)
+
+    def draw_row(halves, n, row):
+        key = jax.random.wrap_key_data(halves[::-1], impl="threefry2x32")
+        return jax.random.categorical(jax.random.fold_in(key, n), row)
+
+    drawn = jax.vmap(draw_row)(seed, counter, scaled).astype(jnp.int32)
+    return jnp.where(sampled, drawn, greedy)
+
+
 def _sample(logits, key, temperature: float, top_k: int):
     if temperature == 0.0:
         return logits.argmax(axis=-1).astype(jnp.int32)

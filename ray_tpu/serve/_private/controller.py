@@ -77,6 +77,11 @@ class ServeController:
         # retires IMMEDIATELY; the drain thread yields to it.
         self._draining: dict[str, dict] = {}
         self._lock = threading.RLock()
+        # deploy / delete / shutdown (RPC threads) and the reconcile thread
+        # all reconcile, and counting replicas then starting the missing
+        # ones is two critical sections of _lock: two passes at once would
+        # both start them (3 replicas for a target of 2 until scaled back).
+        self._reconcile_mutex = threading.Lock()
         self._epoch = 0
         self._epoch_cv = threading.Condition(self._lock)
         self._shutdown = False
@@ -550,6 +555,10 @@ class ServeController:
         return desired
 
     def _reconcile_once(self):
+        with self._reconcile_mutex:
+            self._reconcile_pass()
+
+    def _reconcile_pass(self):
         with self._lock:
             targets = dict(self._deployments)
         changed = False

@@ -27,6 +27,15 @@ vLLM-lineage iteration-level scheduler on top of the paged KV cache:
   and it re-enters the wait queue; on re-admission its already-emitted
   tokens are teacher-forced through prefill (bit-identical continuation,
   nothing is ever re-emitted, the request's RNG stream is untouched).
+- **the draw is inside the programs**: the decode and the prefill program
+  end in ``models/generate.py::draw_tokens`` and hand back token ids, so a
+  step copies ``[num_slots]`` int32 to the host and never ``[num_slots, V]``
+  logits. A row's noise comes from ``fold_in(key(seed), counter)`` alone:
+  the key is the request's 64-bit seed (its two uint32 halves are the
+  threefry key), the counter is the index of the token drawn. Nothing is
+  carried from draw to draw, so a token does not depend on the slot, the
+  rows beside it, the program (a prompt's last chunk, a teacher-forced
+  tail, a decode step) or the replica.
 - **streaming**: each request carries a queue the scheduler feeds token by
   token; ``LLMRequest`` iterates it — the replica's ``StreamingResponse``
   pump drains that iterator straight onto the HTTP socket.
@@ -98,12 +107,25 @@ class LLMRequest:
         self.temperature = float(temperature)
         self.top_k = int(top_k)
         # The request's sampling randomness is a COUNTER-BASED stream: token
-        # i is drawn from default_rng((seed, i)), never from mutable RNG
-        # state. That makes the stream position-addressable, so a request
-        # resumed on ANOTHER replica with resume_tokens= (mid-stream
-        # migration) continues bit-identically — exactly like recompute
-        # preemption, which never left the process.
+        # i is drawn inside the program from
+        # fold_in(threefry key (seed >> 32, seed & 0xFFFFFFFF), i), never from
+        # carried or split RNG state. That makes the stream
+        # position-addressable, so a request resumed on ANOTHER replica with
+        # resume_tokens= (mid-stream migration) continues bit-identically:
+        # exactly like recompute preemption, which never left the process.
         self.seed = int(seed) & 0xFFFFFFFFFFFFFFFF
+        # What every dispatch carries of this request's draw, as the int32
+        # columns _ROW_DRAW of a program's row: temperature's float32 bits,
+        # top_k, the seed's low and high half (the counter is the fifth).
+        self._sched_draw = np.array(
+            [
+                np.float32(self.temperature).view(np.uint32),
+                min(max(self.top_k, 0), 2**31 - 1),
+                self.seed & 0xFFFFFFFF,
+                self.seed >> 32,
+            ],
+            np.uint32,
+        ).view(np.int32)
         self.cancelled = threading.Event()
         self.error: Optional[str] = None
         # Prefill-role terminal state: the sealed-KV handoff descriptor
@@ -204,6 +226,34 @@ def prefix_route_hint(tokens, block_size: int = 16) -> str:
     return hs[0].hex() if hs else ""
 
 
+# One int32 row per sequence is everything a program is told about it beside
+# the chunk's tokens: ONE host array a dispatch (each `jnp.asarray` is ~0.1 ms
+# of the gap between two steps), the block table behind a fixed head.
+_ROW_TOKEN = 0  # decode: the token fed
+_ROW_VALID_TO = 0  # prefill (its tokens are an argument of their own): the teacher-forced target
+_ROW_POS = 1  # position of the first token fed
+_ROW_DRAW = slice(2, 6)  # LLMRequest._sched_draw
+_ROW_COUNTER = 6  # index of the token this dispatch draws
+_ROW_TABLE = 7  # the block table, n_max wide, from here on
+
+
+def _draw_row_tokens(logits, rows):
+    """``draw_tokens`` from the draw columns of the programs' int32 rows."""
+    import jax.numpy as jnp
+    from jax import lax
+
+    from ray_tpu.models.generate import draw_tokens
+
+    draw = rows[:, _ROW_DRAW]
+    return draw_tokens(
+        logits,
+        lax.bitcast_convert_type(draw[:, 0], jnp.float32),
+        draw[:, 1],
+        lax.bitcast_convert_type(draw[:, 2:4], jnp.uint32),
+        rows[:, _ROW_COUNTER],
+    )
+
+
 # Process-level compiled-program cache: engines with the same model config
 # share the jitted decode/prefill callables, so jax's own shape-keyed cache
 # applies across engine instances (tests and replica reconfigures would
@@ -213,6 +263,9 @@ _JIT_LOCK = threading.Lock()
 
 
 def _compiled_fns(cfg):
+    """(decode, prefill): ``decode(params, rows [num_slots, 7 + n_max], pool)``
+    and ``prefill(params, tokens [1, q], pool, rows [1, 7 + n_max])``, both
+    ``-> (token ids int32, one a row, pool)``."""
     with _JIT_LOCK:
         fns = _JIT_CACHE.get(cfg)
         if fns is None:
@@ -225,16 +278,25 @@ def _compiled_fns(cfg):
             )
             from ray_tpu.models.transformer import _head
 
-            def prefill_chunk_row(p, t, c, bt, pos, vt, row):
+            def decode_rows(p, rows, c):
+                logits, c = paged_decode_step(
+                    p, rows[:, _ROW_TOKEN], c, rows[:, _ROW_TABLE:], rows[:, _ROW_POS], cfg
+                )
+                return _draw_row_tokens(logits, rows), c
+
+            def prefill_chunk_row(p, t, c, rows):
                 # Chunked prefill consumes logits for at most ONE row (the
                 # prompt's last real token, on its final chunk) — project
                 # just that row instead of paying the [1, q, V] head matmul
-                # per chunk (`row` is traced: no recompile per position).
-                x, c = _paged_decode_chunk_hidden(p, t, c, bt, pos, cfg, valid_to=vt)
-                last = jnp.take_along_axis(
-                    x, jnp.reshape(row, (1, 1, 1)).astype(jnp.int32), axis=1
-                )[:, 0]
-                return (last @ _head(p).astype(last.dtype)).astype(jnp.float32), c
+                # per chunk (the row is traced: no recompile per position).
+                pos, valid_to = rows[:, _ROW_POS], rows[:, _ROW_VALID_TO]
+                x, c = _paged_decode_chunk_hidden(
+                    p, t, c, rows[:, _ROW_TABLE:], pos, cfg, valid_to=valid_to
+                )
+                row = jnp.clip(valid_to - 1 - pos, 0, t.shape[1] - 1)
+                last = jnp.take_along_axis(x, row[:, None, None], axis=1)[:, 0]
+                logits = (last @ _head(p).astype(last.dtype)).astype(jnp.float32)
+                return _draw_row_tokens(logits, rows), c
 
             # The pool (argument 2) is DONATED to both programs; the layer
             # scan carries it, so a step updates it in place (the caller's
@@ -242,10 +304,7 @@ def _compiled_fns(cfg):
             # lambda, prefill_chunk_row) are how the benchmark's
             # trace_programs patterns find the programs in a trace: keep them.
             fns = (
-                jax.jit(
-                    lambda p, t, c, bt, pos: paged_decode_step(p, t, c, bt, pos, cfg),
-                    donate_argnums=2,
-                ),
+                jax.jit(lambda p, rows, c: decode_rows(p, rows, c), donate_argnums=2),
                 jax.jit(prefill_chunk_row, donate_argnums=2),
             )
             _JIT_CACHE[cfg] = fns
@@ -361,6 +420,9 @@ class LLMEngine:
             # Dispatches after which the pool passed in was still alive: the
             # program copied the pool instead of updating it in place. 0 is right.
             "kv_pool_not_donated": 0,
+            # Rows of [., V] logits a program handed to the host instead of
+            # token ids (the draw is inside the programs). 0 is right.
+            "host_logit_rows": 0,
         }
         t0 = time.monotonic()
         self._decode_fn, self._prefill_fn = _compiled_fns(cfg)
@@ -997,22 +1059,19 @@ class LLMEngine:
         with spans.span("llm.prefill.build", rid=req.id, pos=pos0):
             seq = req.prompt + req._sched_generated
             piece = seq[pos0 : pos0 + q]
-            fed = piece + [0] * (q - len(piece))
-            table = np.zeros((1, self.n_max), np.int32)
-            table[0, : len(req._sched_table)] = req._sched_table
-            # Row of the prompt's LAST real token within this chunk — only
-            # meaningful (and only consumed) on the final chunk.
-            row = min(max(req._sched_target - 1 - pos0, 0), q - 1)
-            inputs = (
-                jnp.asarray([fed], jnp.int32),
-                jnp.asarray(table),
-                jnp.asarray([pos0], jnp.int32),
-                jnp.asarray([req._sched_target], jnp.int32),
-                jnp.int32(row),
-            )
+            # NumPy first: `jnp.asarray` of a list is a program of its own
+            # (a convert_element_type dispatch), of an int32 array a copy.
+            fed = np.zeros((1, q), np.int32)
+            fed[0, : len(piece)] = piece
+            # The program draws from the row of the prompt's LAST real token
+            # within this chunk: only meaningful (and only fetched) on the
+            # final chunk.
+            rows = self._program_rows(1)
+            self._fill_row(rows[0], req, req._sched_target, pos0)
+            inputs = (jnp.asarray(fed), jnp.asarray(rows))
         spans.carried(prefill_tokens=len(piece))
         with spans.span("llm.prefill.dispatch", rid=req.id):
-            logits = self._run_donated(self._prefill_fn, *inputs)
+            drawn = self._run_donated(self._prefill_fn, *inputs)
         req._sched_pos = min(pos0 + q, req._sched_target)
         self._register_prefix_blocks(req)
         if req._sched_pos >= req._sched_target:
@@ -1021,15 +1080,29 @@ class LLMEngine:
             if self.cluster_prefix:
                 self._publish_prefix(req)
             with spans.span("llm.prefill.fetch", rid=req.id):
-                row_logits = np.asarray(logits)[0]
+                drawn = self._fetch_ids(drawn)
             # The first token is drawn before the handoff is tried: the draw
             # is keyed by (seed, position), the same token either way.
-            tok = self._sample_rows([req], [row_logits])[0]
+            tok = self._drawn_tokens([req], drawn, [0])[0]
             with spans.span("llm.emit", tokens=1, rid=req.id) as sp:
                 if not (self.role == "prefill" and self._try_handoff(req, tok)):
                     self._emit_token(req, tok)
                 sp.set(finished=int(req._finished))
         return True
+
+    def _program_rows(self, n: int) -> np.ndarray:
+        """``n`` all-zero rows of a program's int32 input: an inactive slot
+        (token 0 at position 0 of the null block, drawn greedily)."""
+        return np.zeros((n, _ROW_TABLE + self.n_max), np.int32)
+
+    @staticmethod
+    def _fill_row(row: np.ndarray, req: LLMRequest, first: int, pos: int):
+        """``first``: column 0, the token fed (decode) or valid_to (prefill)."""
+        row[_ROW_TOKEN] = first
+        row[_ROW_POS] = pos
+        row[_ROW_DRAW] = req._sched_draw
+        row[_ROW_COUNTER] = len(req._sched_generated)
+        row[_ROW_TABLE : _ROW_TABLE + len(req._sched_table)] = req._sched_table
 
     def _run_donated(self, fn, tokens, *rest):
         """Dispatch one pool-updating program. The pool is donated: the
@@ -1038,10 +1111,10 @@ class LLMEngine:
         program that raises after donating leaves no pool; the exception
         ends ``_loop``, which marks the engine crashed."""
         pool = self._cache
-        logits, self._cache = fn(self.params, tokens, pool, *rest)
+        drawn, self._cache = fn(self.params, tokens, pool, *rest)
         if not (pool["k"].is_deleted() and pool["v"].is_deleted()):
             self._counts["kv_pool_not_donated"] += 1
-        return logits
+        return drawn
 
     def _register_prefix_blocks(self, req: LLMRequest):
         """Publish freshly-WRITTEN full prompt blocks for reuse. Done as
@@ -1116,23 +1189,20 @@ class LLMEngine:
                 return True
             import jax.numpy as jnp
 
-            toks = np.zeros((self.num_slots,), np.int32)
-            pos = np.zeros((self.num_slots,), np.int32)
-            tables = np.zeros((self.num_slots, self.n_max), np.int32)
+            rows = self._program_rows(self.num_slots)
             for req in active:
-                s = req._sched_slot
-                toks[s] = req._sched_generated[-1]
-                pos[s] = req._sched_pos
-                tables[s, : len(req._sched_table)] = req._sched_table
-            toks, tables, pos = jnp.asarray(toks), jnp.asarray(tables), jnp.asarray(pos)
+                self._fill_row(
+                    rows[req._sched_slot], req, req._sched_generated[-1], req._sched_pos
+                )
+            rows = jnp.asarray(rows)
         spans.carried(rows=len(active))
         with spans.span("llm.decode.dispatch"):
-            logits = self._run_donated(self._decode_fn, toks, tables, pos)
+            drawn = self._run_donated(self._decode_fn, rows)
         with spans.span("llm.decode.fetch"):
             # Waits for the device (and for this pass's prefill chunk, which
-            # runs ahead of the step), then copies the logits to the host.
-            logits = np.asarray(logits)
-        drawn = self._sample_rows(active, [logits[r._sched_slot] for r in active])
+            # runs ahead of the step), then copies [num_slots] ids to the host.
+            drawn = self._fetch_ids(drawn)
+        drawn = self._drawn_tokens(active, drawn, [r._sched_slot for r in active])
         with spans.span("llm.emit", tokens=len(active)) as sp:
             for req, tok in zip(active, drawn):
                 req._sched_pos += 1
@@ -1140,33 +1210,31 @@ class LLMEngine:
             sp.set(finished=sum(r._finished for r in active))
         return True
 
-    def _sample_rows(self, reqs: list, rows: list) -> list[int]:
-        """One ``llm.sample`` span over every row of a step. Rows are drawn
-        independently (each draw is keyed by its request's seed and
-        position), so drawing them all before any is emitted changes no token."""
+    def _fetch_ids(self, drawn) -> np.ndarray:
+        """A program's token ids on the host, one int32 a row. The draw
+        belongs inside the program: one that hands back ``[rows, V]`` logits
+        is counted in ``host_logit_rows`` and refused, there being nothing
+        left on the host to draw from them."""
+        ids = np.asarray(drawn)
+        if ids.ndim != 1:
+            self._counts["host_logit_rows"] += ids.shape[0]
+            raise TypeError(
+                f"program returned {ids.dtype}{list(ids.shape)}, not one token id a row"
+            )
+        return ids
+
+    def _drawn_tokens(self, reqs: list, ids: np.ndarray, at: list) -> list[int]:
+        """One ``llm.sample`` span a step over the host's share of its draws:
+        the fetched ids of ``reqs`` (row ``at[i]`` of ``ids``) as Python ints.
+        The draw itself ran inside the program (``draw_tokens``); ``top_k``
+        counts the rows that engaged its conditional threshold search."""
         with self.spans.span(
             "llm.sample",
             rows=len(reqs),
             sampled=sum(r.temperature > 0.0 for r in reqs),
             top_k=sum(r.temperature > 0.0 and r.top_k > 0 for r in reqs),
         ):
-            return [self._sample(req, row) for req, row in zip(reqs, rows)]
-
-    def _sample(self, req: LLMRequest, row: np.ndarray) -> int:
-        if req.temperature <= 0.0:
-            return int(row.argmax())
-        logits = row.astype(np.float64) / req.temperature
-        if req.top_k > 0:
-            kth = np.sort(logits)[-req.top_k]
-            logits = np.where(logits < kth, -np.inf, logits)
-        logits -= logits.max()
-        p = np.exp(logits)
-        p /= p.sum()
-        # Counter-based draw: (seed, position) fully determines the token,
-        # so a resumed request samples position k identically on any
-        # replica (the migration bit-exactness contract).
-        rng = np.random.default_rng((req.seed, len(req._sched_generated)))
-        return int(rng.choice(len(p), p=p))
+            return ids[at].tolist()
 
     def _emit_token(self, req: LLMRequest, tok: int):
         req._sched_generated.append(tok)

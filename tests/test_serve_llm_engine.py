@@ -304,12 +304,17 @@ def test_kv_pool_not_donated_counts_a_program_that_copies(model):
     import jax
 
     from ray_tpu.models.generate import paged_decode_step
+    from ray_tpu.serve.llm.engine import _ROW_POS, _ROW_TABLE, _ROW_TOKEN
 
     _, cfg = model
     eng = _stopped_engine(model, num_slots=1, max_model_len=32)
-    eng._decode_fn = jax.jit(
-        lambda p, t, c, bt, pos: paged_decode_step(p, t, c, bt, pos, cfg)
-    )
+    def greedy_step(p, rows, c):
+        logits, c = paged_decode_step(
+            p, rows[:, _ROW_TOKEN], c, rows[:, _ROW_TABLE:], rows[:, _ROW_POS], cfg
+        )
+        return logits.argmax(-1).astype("int32"), c
+
+    eng._decode_fn = jax.jit(greedy_step)  # the engine's step without donate_argnums
     req = eng.submit([1, 2, 3], max_new_tokens=3)
     eng._admit()
     assert eng._prefill_tick()
@@ -328,7 +333,7 @@ def _engine_program(cfg, kind):
 
     from ray_tpu.models.generate import init_paged_cache
     from ray_tpu.models.transformer import init_params
-    from ray_tpu.serve.llm.engine import _compiled_fns
+    from ray_tpu.serve.llm.engine import _ROW_TABLE, _compiled_fns
 
     slots, n_max, chunk = 3, 8, 4
     params = jax.eval_shape(lambda: init_params(jax.random.PRNGKey(0), cfg))
@@ -336,11 +341,9 @@ def _engine_program(cfg, kind):
     i32 = lambda *shape: jax.ShapeDtypeStruct(shape, jnp.int32)  # noqa: E731
     decode, prefill = _compiled_fns(cfg)
     if kind == "decode":
-        lowered = decode.lower(params, i32(slots), cache, i32(slots, n_max), i32(slots))
+        lowered = decode.lower(params, i32(slots, _ROW_TABLE + n_max), cache)
     else:
-        lowered = prefill.lower(
-            params, i32(1, chunk), cache, i32(1, n_max), i32(1), i32(1), i32()
-        )
+        lowered = prefill.lower(params, i32(1, chunk), cache, i32(1, _ROW_TABLE + n_max))
     k_arg = len(jax.tree.leaves(params)) + 1  # params, the tokens, then k and v
     return lowered.compile().as_text(), cache["k"].shape, k_arg
 
@@ -391,9 +394,9 @@ def test_crash_after_donation_ends_engine_without_touching_dead_pool(model):
                     max_model_len=32, prefill_chunk=4)
     real, calls = eng._decode_fn, []
 
-    def donate_then_raise(p, t, c, bt, pos):
+    def donate_then_raise(p, rows, c):
         calls.append(c)
-        real(p, t, c, bt, pos)
+        real(p, rows, c)
         raise RuntimeError("boom after donation")
 
     eng._decode_fn = donate_then_raise
