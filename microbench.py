@@ -1624,7 +1624,8 @@ def transfer_suite(results, quick=False):
 
 
 def serve_llm_suite(results, quick=False):
-    """--serve: the ISSUE 11 continuous-batching A/B (SERVEBENCH_r{N}.json).
+    """--serve: the ISSUE 11 continuous-batching load test (SERVEBENCH_r{N}.json;
+    r11 also holds a serial-batching arm, which went with its engine option).
 
     A closed-loop load generator drives the serve.llm engine directly (the
     scheduler IS the claim; the HTTP/SSE envelope above it is exercised by
@@ -1632,22 +1633,11 @@ def serve_llm_suite(results, quick=False):
     with a shared 32-token system prompt + random suffix and a heavy-tailed
     (geometric — realistic output-length distribution) max_new_tokens,
     reading its token stream to completion, then immediately submitting the
-    next. Two arms on the SAME model/params/slots:
+    next: slot-level admission mid-decode + chunked prefill interleave +
+    prefix-cache reuse.
 
-    - serial:     `serial_batch=True` — the pre-engine behavior (admit only
-                  into an idle engine, batch decodes in lockstep, slots idle
-                  while the longest sequence drains, arrivals wait out the
-                  whole batch). This is what a replica wrapping generate()
-                  gives you.
-    - continuous: slot-level admission mid-decode + chunked prefill
-                  interleave + prefix-cache reuse.
-
-    Metrics per arm: p50/p99 TTFT, mean time-per-output-token, aggregate
-    tokens/s over the measurement window. Why continuous wins tokens/s:
-    decode step latency is dominated by per-step fixed cost (weight
-    streaming on TPU, dispatch on this CPU box), nearly flat in batch
-    occupancy — so tokens/s tracks slot utilization, which serial batching
-    caps at mean(len)/max(len) per batch."""
+    Metrics: p50/p99 TTFT, mean time-per-output-token, aggregate tokens/s
+    over the measurement window (keys ``serve_continuous_*``)."""
     import statistics
     import threading
 
@@ -1665,8 +1655,7 @@ def serve_llm_suite(results, quick=False):
     params = init_params(jax.random.PRNGKey(0), cfg)
     # Oversubscribed offered load (streams > slots): the admission queue is
     # never empty, which is exactly the regime continuous batching targets —
-    # a request arriving mid-decode queues behind the WHOLE draining batch
-    # in the serial arm but takes the first freed slot in the continuous one.
+    # a request arriving mid-decode takes the first freed slot.
     streams = 4 if quick else 12
     slots = 8
     duration = 3.0 if quick else 25.0
@@ -1677,10 +1666,10 @@ def serve_llm_suite(results, quick=False):
     results["serve_block_size"] = block_size
     results["serve_prefix_hint"] = prefix_route_hint(system, block_size)[:12]
 
-    def run_arm(serial: bool) -> dict:
+    def run_arm() -> dict:
         engine = LLMEngine(
             params, cfg, num_slots=slots, block_size=block_size,
-            max_model_len=192, prefill_chunk=32, serial_batch=serial,
+            max_model_len=192, prefill_chunk=32,
         )
         try:
             # Warm both compiled programs outside the window.
@@ -1696,8 +1685,7 @@ def serve_llm_suite(results, quick=False):
                     suffix = rng.integers(0, 256, int(rng.integers(8, 33))).tolist()
                     # Heavy-tailed output length (geometric, mean ~24, tail
                     # to 128 = max_model_len - longest prompt): realistic
-                    # LLM completions — and exactly the shape that makes
-                    # lockstep batches idle their short-sequence slots.
+                    # LLM completions.
                     n_new = int(min(128, max(4, rng.geometric(1.0 / 24))))
                     t0 = time.perf_counter()
                     req = engine.submit(system + suffix, max_new_tokens=n_new)
@@ -1750,22 +1738,10 @@ def serve_llm_suite(results, quick=False):
         finally:
             engine.shutdown()
 
-    for label, serial in (("serial", True), ("continuous", False)):
-        arm = run_arm(serial)
-        for k, v in arm.items():
-            results[f"serve_{label}_{k}"] = v
-        print(f"serve[{label}]: {arm}")
-    results["serve_tokens_speedup"] = round(
-        results["serve_continuous_tokens_per_s"]
-        / max(results["serve_serial_tokens_per_s"], 1e-9),
-        2,
-    )
-    if results.get("serve_serial_ttft_p99_ms") and results.get("serve_continuous_ttft_p99_ms"):
-        results["serve_ttft_p99_reduction_pct"] = round(
-            (1 - results["serve_continuous_ttft_p99_ms"] / results["serve_serial_ttft_p99_ms"])
-            * 100.0,
-            1,
-        )
+    arm = run_arm()
+    for k, v in arm.items():
+        results[f"serve_continuous_{k}"] = v
+    print(f"serve[continuous]: {arm}")
 
 
 def serve_ft_suite(results, quick=False):
@@ -2719,10 +2695,10 @@ def main():
     ap.add_argument(
         "--serve",
         action="store_true",
-        help="continuous-batching LLM serving A/B (ISSUE 11): closed-loop "
-        "load generator at N concurrent streams, continuous-batching engine "
-        "vs serial-batch baseline — p50/p99 TTFT, time-per-output-token, "
-        "aggregate tokens/s; records SERVEBENCH_r{N}.json",
+        help="continuous-batching LLM serving (ISSUE 11): closed-loop load "
+        "generator at N concurrent streams against the engine — p50/p99 "
+        "TTFT, time-per-output-token, aggregate tokens/s; records "
+        "SERVEBENCH_r{N}.json",
     )
     ap.add_argument(
         "--serve-ft",

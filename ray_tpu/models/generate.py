@@ -16,9 +16,11 @@ wrap HF pipelines; SURVEY.md §5.7) — this is the TPU-native equivalent:
   caches are read at KV width via grouped einsums — never repeated to H;
 - bf16 cache, f32 logits/sampling; greedy, temperature, and top-k.
 
-Layer math intentionally mirrors transformer._attention_block/_mlp_block on
-the same param pytree — decode diverges (cache writes, single-row masking)
-enough that sharing one function would tangle the training hot path. MoE
+The layer over a cache is stated once (``_cached_layers``); the dense cache
+and the block pool differ only in how a chunk's rows are written and which
+rows are viewed. Its math intentionally mirrors transformer._attention_block/
+_mlp_block on the same param pytree — decode diverges (cache writes, position
+masking) enough that sharing one function would tangle the training hot path. MoE
 configs decode through the same parallel/moe.moe_layer dispatch the
 training block uses (T=1: each row's token rides its top-1 expert's slot).
 """
@@ -33,7 +35,7 @@ from jax import lax
 
 from ray_tpu.models.transformer import (
     TransformerConfig,
-    _head,
+    _logits,
     _rms_norm,
     _rope,
 )
@@ -103,6 +105,80 @@ def _cache_attention(q, ck, cv, pos_mask, cfg):
     return o.astype(q.dtype)
 
 
+def _embed_chunk(params, tokens, pos, cfg):
+    """tokens [B, q] fed at positions pos[b].. (``pos`` [B] or a scalar):
+    embeddings [B, q, D] and positions [B, q]."""
+    B, q = tokens.shape
+    pos_b = jnp.broadcast_to(pos, (B,))
+    x = params["embed"].astype(cfg.dtype)[tokens]
+    offs = jnp.arange(q, dtype=jnp.int32)
+    return x, pos_b[:, None] + offs[None, :]
+
+
+def _cache_mask(positions, n_keys: int, window: int, key_len=None):
+    """[B, q, n_keys], True = attend. Causal against the cache: the query at
+    positions[b, j] sees rows at positions <= its own — under ``key_len[b]``
+    where given (a ragged prompt's padding) and within the sliding window."""
+    k_pos = jnp.arange(n_keys, dtype=jnp.int32)
+    mask = k_pos[None, None, :] <= positions[:, :, None]
+    if key_len is not None:
+        mask = mask & (k_pos[None, None, :] < key_len[:, None, None])
+    if window:
+        mask &= positions[:, :, None] - k_pos[None, None, :] < window
+    return mask
+
+
+def _cached_layers(params, x, cache, positions, write, view, cfg, key_len=None):
+    """THE layer stack over a KV cache, dense or paged: x [B, q, D] at
+    ``positions`` [B, q] -> (final normed hidden states, cache).
+
+    The whole cache rides the layer scan as its CARRY (never xs -> ys, which
+    are distinct buffers of the loop) and a layer reaches its part through
+    the layer index: ``write(c, l, rows)`` puts this chunk's k or v rows
+    [B, q, KV, Dh] into layer l, ``view(c, l)`` takes the rows [B, S, KV, Dh]
+    to attend over. A caller that donates ``cache`` gets it updated in place.
+    Masked (p == 0) entries contribute nothing, so stale rows past a
+    position, padding and null-block garbage stay invisible."""
+    B, q = positions.shape
+
+    def body(carry, layer):
+        x, ck, cv = carry
+        lp, l = layer
+        qh, k, v = _project_qkv(lp, x, positions, cfg)
+        ck = write(ck, l, k)
+        cv = write(cv, l, v)
+        ck_l, cv_l = view(ck, l), view(cv, l)
+        mask = _cache_mask(positions, ck_l.shape[1], cfg.sliding_window, key_len)
+        o = _cache_attention(qh, ck_l, cv_l, mask, cfg)
+        x = x + o.reshape(B, q, -1) @ lp["wo"].astype(o.dtype)
+        x = _mlp(lp, x, cfg)
+        return (x, ck, cv), None
+
+    layer_ids = jnp.arange(cache["k"].shape[0], dtype=jnp.int32)
+    (x, ks, vs), _ = lax.scan(
+        body, (x, cache["k"], cache["v"]), (params["layers"], layer_ids)
+    )
+    return _rms_norm(x, params["norm_f"], cfg.norm_eps), {"k": ks, "v": vs}
+
+
+def last_row_logits(params, x, row):
+    """Logits [B, V] f32 of ONE row ``row[b]`` (traced: no recompile per
+    position) of hidden states x [B, q, D]: a prompt needs its last real
+    token's only, not the [B, q, V] head matmul."""
+    return _logits(params, jnp.take_along_axis(x, row[:, None, None], axis=1)[:, 0])
+
+
+def _dense_write(pos, positions):
+    """Into a dense cache [L, B, S, KV, Dh] at row ``pos``: a scalar (aligned
+    batch) is one dynamic_update_slice, ``pos`` [B] one scatter of every fed
+    row to its own ``positions`` [B, q]. Its view is the layer, ``c[l]``
+    (prefill: the first T rows of it)."""
+    if pos.ndim == 0:
+        return lambda c, l, rows: lax.dynamic_update_slice(c, rows[None], (l, 0, pos, 0, 0))
+    batch = jnp.arange(positions.shape[0], dtype=jnp.int32)[:, None]
+    return lambda c, l, rows: c.at[l, batch, positions].set(rows)
+
+
 def prefill(params, tokens, cache, cfg: TransformerConfig, prompt_lens=None):
     """Run the prompt through the model, filling cache[:, :, :T].
 
@@ -121,76 +197,25 @@ def prefill(params, tokens, cache, cfg: TransformerConfig, prompt_lens=None):
         # -1); clamp to 1 so a stray len-0 row behaves as "prompt is
         # tokens[b, :1]" instead of silently poisoning the whole batch.
         prompt_lens = jnp.maximum(jnp.asarray(prompt_lens, jnp.int32), 1)
-    x = params["embed"].astype(cfg.dtype)[tokens]
-    positions = jnp.broadcast_to(jnp.arange(T, dtype=jnp.int32)[None], (B, T))
-
-    def body(x, layer):
-        lp, ck_slot, cv_slot = layer
-        q, k, v = _project_qkv(lp, x, positions, cfg)
-        ck = lax.dynamic_update_slice_in_dim(ck_slot, k, 0, axis=1)  # [B,S,KV,Dh]
-        cv = lax.dynamic_update_slice_in_dim(cv_slot, v, 0, axis=1)
-        # Attend only over the prompt's T rows — the generation region of
-        # the cache is not written yet; scoring it would waste S/T the
-        # FLOPs/HBM. Causal within the prompt; per-row padding invisible.
-        k_pos = jnp.arange(T, dtype=jnp.int32)
-        mask = (
-            (k_pos[None, None, :] <= positions[:, :, None])
-            & (k_pos[None, None, :] < prompt_lens[:, None, None])
-        )
-        if cfg.sliding_window:
-            mask &= positions[:, :, None] - k_pos[None, None, :] < cfg.sliding_window
-        o = _cache_attention(q, ck[:, :T], cv[:, :T], mask, cfg)
-        x = x + o.reshape(B, T, -1) @ lp["wo"].astype(o.dtype)
-        x = _mlp(lp, x, cfg)
-        return x, (ck, cv)
-
-    x, (ks, vs) = lax.scan(body, x, (params["layers"], cache["k"], cache["v"]))
-    x = _rms_norm(x, params["norm_f"], cfg.norm_eps)
-    last = jnp.take_along_axis(x, (prompt_lens - 1)[:, None, None], axis=1)[:, 0]
-    logits = (last @ _head(params).astype(x.dtype)).astype(jnp.float32)
-    return logits, {"k": ks, "v": vs}, prompt_lens
+    pos = jnp.int32(0)
+    x, positions = _embed_chunk(params, tokens, pos, cfg)
+    # Attend only over the prompt's T rows — the generation region of the
+    # cache is not written yet; scoring it would waste S/T the FLOPs/HBM.
+    # Causal within the prompt; per-row padding invisible.
+    write, view = _dense_write(pos, positions), lambda c, l: c[l][:, :T]
+    x, cache = _cached_layers(params, x, cache, positions, write, view, cfg, key_len=prompt_lens)
+    return last_row_logits(params, x, prompt_lens - 1), cache, prompt_lens
 
 
 def _decode_chunk_hidden(params, tokens, cache, pos, cfg: TransformerConfig):
     """decode_chunk without the head projection: returns the final normed
     hidden states [B, q, D] + cache. Callers that need logits for only a
     subset of rows (chunked prefill needs just the final one) project
-    themselves instead of paying [B, q, V]."""
-    B, q = tokens.shape
+    themselves (``last_row_logits``) instead of paying [B, q, V]."""
     pos = jnp.asarray(pos, jnp.int32)
-    aligned = pos.ndim == 0
-    pos_b = jnp.broadcast_to(pos, (B,))
-    x = params["embed"].astype(cfg.dtype)[tokens]  # [B, q, D]
-    offs = jnp.arange(q, dtype=jnp.int32)
-    positions = pos_b[:, None] + offs[None, :]  # [B, q]
-    S = cache["k"].shape[2]
-
-    def write_rows(slot, kv, p):
-        # slot [S, KV, Dh], kv [q, KV, Dh] at row position p
-        return lax.dynamic_update_slice(slot, kv, (p, 0, 0))
-
-    def body(x, layer):
-        lp, ck_slot, cv_slot = layer
-        qh, k, v = _project_qkv(lp, x, positions, cfg)
-        if aligned:
-            ck = lax.dynamic_update_slice(ck_slot, k, (0, pos, 0, 0))
-            cv = lax.dynamic_update_slice(cv_slot, v, (0, pos, 0, 0))
-        else:
-            ck = jax.vmap(write_rows)(ck_slot, k, pos_b)
-            cv = jax.vmap(write_rows)(cv_slot, v, pos_b)
-        k_pos = jnp.arange(S, dtype=jnp.int32)
-        # Causal against the cache: row j of the chunk sees positions
-        # <= pos[b] + j (its own and everything before it).
-        mask = k_pos[None, None, :] <= positions[:, :, None]
-        if cfg.sliding_window:
-            mask &= positions[:, :, None] - k_pos[None, None, :] < cfg.sliding_window
-        o = _cache_attention(qh, ck, cv, mask, cfg)
-        x = x + o.reshape(B, q, -1) @ lp["wo"].astype(o.dtype)
-        x = _mlp(lp, x, cfg)
-        return x, (ck, cv)
-
-    x, (ks, vs) = lax.scan(body, x, (params["layers"], cache["k"], cache["v"]))
-    return _rms_norm(x, params["norm_f"], cfg.norm_eps), {"k": ks, "v": vs}
+    x, positions = _embed_chunk(params, tokens, pos, cfg)
+    write = _dense_write(pos, positions)
+    return _cached_layers(params, x, cache, positions, write, lambda c, l: c[l], cfg)
 
 
 def decode_chunk(params, tokens, cache, pos, cfg: TransformerConfig):
@@ -203,8 +228,7 @@ def decode_chunk(params, tokens, cache, pos, cfg: TransformerConfig):
     (speculative decoding rejects; chunked prefill) without a cache rewind.
     """
     x, cache = _decode_chunk_hidden(params, tokens, cache, pos, cfg)
-    logits = (x @ _head(params).astype(x.dtype)).astype(jnp.float32)
-    return logits, cache
+    return _logits(params, x), cache
 
 
 def prefill_chunked(params, tokens, cache, cfg: TransformerConfig, chunk: int = 512):
@@ -228,10 +252,10 @@ def prefill_chunked(params, tokens, cache, cfg: TransformerConfig, chunk: int = 
         # logits would waste head FLOPs on a path whose point is bounding
         # memory — only the final row's logits are needed.
         x, cache = _decode_chunk_hidden(params, tok, cache, pos, cfg)
-        return (cache, pos + chunk), x[:, -1]
+        return (cache, pos + chunk), x[:, -1:]
 
     (cache, pos), last = lax.scan(body, (cache, jnp.int32(0)), tok_chunks)
-    logits = (last[-1] @ _head(params).astype(last.dtype)).astype(jnp.float32)
+    logits = last_row_logits(params, last[-1], jnp.zeros((B,), jnp.int32))
     return logits, cache, jnp.full((B,), T, jnp.int32)
 
 
@@ -256,79 +280,44 @@ def init_paged_cache(cfg: TransformerConfig, num_blocks: int, block_size: int):
     }
 
 
-def _paged_decode_chunk_hidden(
-    params,
-    tokens,
-    cache,
-    block_tables,
-    pos,
-    cfg: TransformerConfig,
-    valid_to=None,
-):
-    """``paged_decode_chunk`` without the head projection: returns the final
-    normed hidden states [B, q, D] + cache. Chunked prefill consumes logits
-    for at most ONE row per prompt — callers project that row themselves
-    instead of paying [B, q, V] (the `_decode_chunk_hidden` pattern)."""
-    B, q = tokens.shape
-    n_max = block_tables.shape[1]
-    block_size = cache["k"].shape[2]
-    S = n_max * block_size
-    pos = jnp.asarray(pos, jnp.int32)
-    pos_b = jnp.broadcast_to(pos, (B,))
-    block_tables = jnp.asarray(block_tables, jnp.int32)
-    x = params["embed"].astype(cfg.dtype)[tokens]  # [B, q, D]
-    offs = jnp.arange(q, dtype=jnp.int32)
-    positions = pos_b[:, None] + offs[None, :]  # [B, q]
-    # Physical write coordinates for every fed row (computed once, reused
-    # per layer). Out-of-table positions clamp to the last entry; engines
-    # validate lengths so this only guards compiler-visible bounds.
-    blk_idx = jnp.minimum(positions // block_size, n_max - 1)
+def _paged_write(block_tables, positions, valid_to, block_size: int):
+    """Into a block pool [L, N, Bs, KV, Dh] through the physical write
+    coordinates of every fed row (computed once, reused per layer).
+    Out-of-table positions clamp to the last entry; engines validate lengths
+    so this only guards compiler-visible bounds."""
+    blk_idx = jnp.minimum(positions // block_size, block_tables.shape[1] - 1)
     blk_phys = jnp.take_along_axis(block_tables, blk_idx, axis=1)  # [B, q]
     row_off = positions % block_size
     if valid_to is not None:
         writable = positions < jnp.asarray(valid_to, jnp.int32)[:, None]
         blk_phys = jnp.where(writable, blk_phys, 0)
+    return lambda c, l, rows: c.at[l, blk_phys, row_off].set(rows)
 
-    # The whole pool rides the layer scan as its CARRY (never xs -> ys, which
-    # are distinct buffers of the loop): each layer scatters its rows and
-    # gathers its logical view with the layer index in the same indexing op,
-    # so no [N, Bs, KV, Dh] layer slice is materialised and a caller that
-    # donates ``cache`` gets the pool updated in place.
-    def body(carry, layer):
-        x, ck, cv = carry  # pools [L, N, Bs, KV, Dh]
-        lp, l = layer
-        qh, k, v = _project_qkv(lp, x, positions, cfg)
-        ck = ck.at[l, blk_phys, row_off].set(k)
-        cv = cv.at[l, blk_phys, row_off].set(v)
-        # Gather each row's logical cache view through its block table,
-        # then attend exactly like the dense path. Masked (p == 0) entries
-        # contribute nothing, so null-block garbage stays invisible.
-        ck_g = ck[l, block_tables].reshape(B, S, cfg.n_kv_heads, cfg.head_dim)
-        cv_g = cv[l, block_tables].reshape(B, S, cfg.n_kv_heads, cfg.head_dim)
-        k_pos = jnp.arange(S, dtype=jnp.int32)
-        mask = k_pos[None, None, :] <= positions[:, :, None]
-        if cfg.sliding_window:
-            mask &= positions[:, :, None] - k_pos[None, None, :] < cfg.sliding_window
-        o = _cache_attention(qh, ck_g, cv_g, mask, cfg)
-        x = x + o.reshape(B, q, -1) @ lp["wo"].astype(o.dtype)
-        x = _mlp(lp, x, cfg)
-        return (x, ck, cv), None
 
-    layer_ids = jnp.arange(cache["k"].shape[0], dtype=jnp.int32)
-    (x, ks, vs), _ = lax.scan(
-        body, (x, cache["k"], cache["v"]), (params["layers"], layer_ids)
-    )
-    return _rms_norm(x, params["norm_f"], cfg.norm_eps), {"k": ks, "v": vs}
+def _paged_view(block_tables):
+    """Each row's logical cache [B, n_max * Bs, KV, Dh], gathered through its
+    block table with the layer index in the same indexing op: no
+    [N, Bs, KV, Dh] layer slice is materialised."""
+    B, n_max = block_tables.shape
+    return lambda c, l: c[l, block_tables].reshape(B, n_max * c.shape[2], *c.shape[3:])
+
+
+def paged_decode_chunk_hidden(
+    params, tokens, cache, block_tables, pos, cfg: TransformerConfig, valid_to=None
+):
+    """``paged_decode_chunk`` without the head projection: returns the final
+    normed hidden states [B, q, D] + cache. Chunked prefill consumes logits
+    for at most ONE row per prompt — callers project that row themselves
+    (``last_row_logits``) instead of paying [B, q, V]."""
+    pos = jnp.asarray(pos, jnp.int32)
+    block_tables = jnp.asarray(block_tables, jnp.int32)
+    x, positions = _embed_chunk(params, tokens, pos, cfg)
+    write = _paged_write(block_tables, positions, valid_to, cache["k"].shape[2])
+    return _cached_layers(params, x, cache, positions, write, _paged_view(block_tables), cfg)
 
 
 def paged_decode_chunk(
-    params,
-    tokens,
-    cache,
-    block_tables,
-    pos,
-    cfg: TransformerConfig,
-    valid_to=None,
+    params, tokens, cache, block_tables, pos, cfg: TransformerConfig, valid_to=None
 ):
     """``decode_chunk`` over a PAGED cache: tokens [B, q] written at per-row
     positions pos[b]..pos[b]+q-1, where logical position p of row b lives in
@@ -352,11 +341,10 @@ def paged_decode_chunk(
     dense ``_cache_attention`` over the GATHERED logical view, so outputs
     match the dense-cache path row for row (the serving oracle).
     """
-    x, cache = _paged_decode_chunk_hidden(
+    x, cache = paged_decode_chunk_hidden(
         params, tokens, cache, block_tables, pos, cfg, valid_to=valid_to
     )
-    logits = (x @ _head(params).astype(x.dtype)).astype(jnp.float32)
-    return logits, cache
+    return _logits(params, x), cache
 
 
 def paged_decode_step(params, token, cache, block_tables, pos, cfg: TransformerConfig):

@@ -2,7 +2,7 @@
 
 Pure-JAX with explicit parameter pytrees and per-leaf logical sharding axes —
 the flagship for every parallelism strategy in parallel/ (dp/fsdp/tp/pp/sp/ep)
-and the model behind __graft_entry__.py and bench.py.
+and the model behind __graft_entry__.py and the benchmark's cells.
 
 TPU-first choices:
 - layer parameters are *stacked* [L, ...] so the layer loop is a lax.scan
@@ -51,13 +51,6 @@ class TransformerConfig:
     expert_capacity_factor: float = 1.25
     remat: bool = True
     tie_embeddings: bool = False
-    # lax.scan unroll factor over the layer stack. 1 (default) compiles one
-    # rolled loop body — smallest compile, required shape for pipeline
-    # parallelism's per-stage scheduling. Full unroll (= n_layers) lets XLA
-    # schedule ACROSS layer boundaries, overlapping one layer's epilogue
-    # with the next's prologue: +12% train throughput on the single-chip
-    # v5e bench (79.3k -> 88.7k tok/s). Unroll only without pp sharding.
-    scan_unroll: int = 1
     # Mistral-style sliding-window causal attention (0 = full causal):
     # row i attends keys (i-sliding_window, i]. Rides the flash kernel's
     # k-block pruning in training and the decode position mask at
@@ -220,33 +213,13 @@ def _rope(x, positions, theta):
 
 
 def _attention_block(lp, x, rope_cs, cfg: TransformerConfig, mesh, attn_impl: str):
-    import os
-
     B, T, D = x.shape
     H, KV, Dh = cfg.n_heads, cfg.n_kv_heads, cfg.head_dim
     h = _rms_norm(x, lp["attn_norm"], cfg.norm_eps)
-    if os.environ.get("RAY_TPU_FUSED_QKV", "0") == "1":
-        # One [D, (H+2KV)·Dh] matmul instead of three: fewer MXU launches
-        # at identical FLOPs (the weight concat is folded by XLA). A/B knob,
-        # read at trace time.
-        wqkv = jnp.concatenate(
-            [lp["wq"], lp["wk"], lp["wv"]], axis=-1
-        ).astype(h.dtype)
-        qkv = h @ wqkv
-        q = qkv[..., : H * Dh].reshape(B, T, H, Dh)
-        k = qkv[..., H * Dh : (H + KV) * Dh].reshape(B, T, KV, Dh)
-        v = qkv[..., (H + KV) * Dh :].reshape(B, T, KV, Dh)
-    else:
-        q = (h @ lp["wq"].astype(h.dtype)).reshape(B, T, H, Dh)
-        k = (h @ lp["wk"].astype(h.dtype)).reshape(B, T, KV, Dh)
-        v = (h @ lp["wv"].astype(h.dtype)).reshape(B, T, KV, Dh)
-    if isinstance(rope_cs, tuple):
-        cos, sin = rope_cs
-    else:
-        # A/B fallback (RAY_TPU_ROPE_PER_LAYER=1): rope_cs is the raw
-        # positions array; recompute tables in-layer — measures whether
-        # XLA's CSE already hoists them from the scan.
-        cos, sin = _rope_tables(rope_cs, Dh, cfg.rope_theta)
+    q = (h @ lp["wq"].astype(h.dtype)).reshape(B, T, H, Dh)
+    k = (h @ lp["wk"].astype(h.dtype)).reshape(B, T, KV, Dh)
+    v = (h @ lp["wv"].astype(h.dtype)).reshape(B, T, KV, Dh)
+    cos, sin = rope_cs
     q = _rope_apply(q, cos, sin)
     k = _rope_apply(k, cos, sin)
     if KV != H:  # GQA: repeat kv heads
@@ -261,12 +234,6 @@ def _attention_block(lp, x, rope_cs, cfg: TransformerConfig, mesh, attn_impl: st
         from ray_tpu.parallel.ring_attention import ring_attention
 
         o = ring_attention(q, k, v, mesh, causal=True)
-    elif attn_impl == "ulysses" and mesh is not None and mesh.shape.get("sp", 1) > 1:
-        if cfg.sliding_window:
-            raise NotImplementedError("sliding_window + Ulysses attention not supported")
-        from ray_tpu.parallel.ulysses import ulysses_attention
-
-        o = ulysses_attention(q, k, v, mesh, causal=True)
     else:
         attn = partial(flash_attention, causal=True, window=cfg.sliding_window)
         if mesh is not None and mesh.size > 1:
@@ -333,12 +300,7 @@ def forward_hidden(
     positions = jnp.broadcast_to(jnp.arange(T, dtype=jnp.int32)[None], (B, T))
     # Rope tables are layer-invariant: one sin+cos sweep per step, shared by
     # every layer's q and k (vs 2·n_layers recomputations inside the scan).
-    import os
-
-    if os.environ.get("RAY_TPU_ROPE_PER_LAYER", "0") == "1":
-        rope_cs = positions  # recomputed per layer (A/B fallback)
-    else:
-        rope_cs = _rope_tables(positions, cfg.head_dim, cfg.rope_theta)
+    rope_cs = _rope_tables(positions, cfg.head_dim, cfg.rope_theta)
 
     layer_fn = partial(_layer, cfg=cfg, mesh=mesh, attn_impl=attn_impl)
     if cfg.remat:
@@ -349,13 +311,17 @@ def forward_hidden(
         x, a = layer_fn(lp, x, rope_cs)
         return (x, aux + a), None
 
-    unroll = max(1, min(int(cfg.scan_unroll or 1), cfg.n_layers))
-    (x, aux), _ = lax.scan(scan_body, (x, 0.0), params["layers"], unroll=unroll)
+    (x, aux), _ = lax.scan(scan_body, (x, 0.0), params["layers"])
     return _rms_norm(x, params["norm_f"], cfg.norm_eps), aux
 
 
 def _head(params):
     return params["lm_head"] if "lm_head" in params else params["embed"].T
+
+
+def _logits(params, x):
+    """Hidden states x [..., D] -> f32 logits [..., V] through the head."""
+    return (x @ _head(params).astype(x.dtype)).astype(jnp.float32)
 
 
 def forward(
@@ -367,8 +333,7 @@ def forward(
 ):
     """tokens [B, T] int32 -> logits [B, T, V] (f32)."""
     x, aux = forward_hidden(params, tokens, cfg, mesh=mesh, attn_impl=attn_impl)
-    logits = (x @ _head(params).astype(x.dtype)).astype(jnp.float32)
-    return logits, aux
+    return _logits(params, x), aux
 
 
 def loss_fn(params, batch, cfg: TransformerConfig, mesh=None, attn_impl: str = "auto"):
@@ -386,12 +351,14 @@ def loss_fn(params, batch, cfg: TransformerConfig, mesh=None, attn_impl: str = "
     return nll.mean() + 0.01 * aux
 
 
-def make_train_step(cfg: TransformerConfig, optimizer, mesh=None, attn_impl: str = "auto", donate: bool = True):
+def make_train_step(cfg: TransformerConfig, optimizer, mesh=None, attn_impl: str = "auto"):
     """Returns train_step(params, opt_state, batch) -> (params, opt_state, loss).
 
-    Pure function — callers jit it with in/out shardings (see
-    train/jax/ and __graft_entry__.py). Gradients are averaged over the batch;
-    under a dp/fsdp-sharded batch pjit inserts the psum automatically.
+    Pure function — callers jit it with in/out shardings, and with
+    ``donate_argnums=(0, 1)`` where they rebind parameters and optimizer
+    state to the result (see train/jax/ and __graft_entry__.py). Gradients
+    are averaged over the batch; under a dp/fsdp-sharded batch pjit inserts
+    the psum automatically.
     """
 
     def train_step(params, opt_state, batch):

@@ -53,6 +53,18 @@ def _xla_attention(q, k, v, causal: bool, sm_scale: float, bias=None, window: in
 # (jax/experimental/pallas/ops/tpu/flash_attention.py, MIN_BLOCK_SIZE).
 _LSE_LANES = 128
 
+# Preferred block edge of the forward kernel and of the two backward kernels
+# (``_fit_block`` clamps both to the sequence, so short sequences degrade to
+# block == seq). Forward: bigger blocks mean fewer grid steps and less
+# per-block overhead, (128,128) << (256,512) < (1024,1024) in a full train
+# step (v5e, B8/H8/T1024/D128); a (1024, 1024) f32 score tile is 4 MiB of
+# the ~16 MiB of VMEM, leaving room for the q/k/v/o tiles at head_dim <= 256.
+# Backward: 512 > 1024 > 256 there (smaller blocks also PRUNE more of a
+# causal or windowed loop). Both A/Bs are in PERF_NOTES.md, at a 168M toy's
+# shapes; the benchmark's cells have run only these values.
+_FWD_BLOCK = 1024
+_BWD_BLOCK = 512
+
 
 def _flash_kernel(q_ref, k_ref, v_ref, o_ref, lse_ref=None, *, block_k: int, causal: bool, sm_scale: float, seq_k: int, block_q: int, window: int = 0):
     from jax.experimental import pallas as pl
@@ -459,15 +471,10 @@ def _pallas_bwd_impl(q, k, v, out, lse, dout, causal, sm_scale, block_q, block_k
 
 
 def _pallas_flash_bwd(causal, sm_scale, block_q, block_k, interpret, window, res, dout):
-    import os
-
     q, k, v, out, lse = res
     Tq, Tk = q.shape[1], k.shape[1]
-    want = int(os.environ.get("RAY_TPU_FLASH_BWD_BLOCK", "512"))
-    bq, bk = _fit_block(want, Tq), _fit_block(want, Tk)
-    use_pallas = (_on_tpu() or interpret) and os.environ.get(
-        "RAY_TPU_FLASH_XLA_BWD", "0"
-    ) != "1" and Tq % bq == 0 and Tk % bk == 0
+    bq, bk = _fit_block(_BWD_BLOCK, Tq), _fit_block(_BWD_BLOCK, Tk)
+    use_pallas = (_on_tpu() or interpret) and Tq % bq == 0 and Tk % bk == 0
     if not use_pallas:
         return _xla_blockwise_bwd(causal, sm_scale, block_q, block_k, window, (q, k, v, out, lse), dout)
     return _pallas_bwd_impl(q, k, v, out, lse, dout, causal, sm_scale, bq, bk, interpret, window)
@@ -487,17 +494,7 @@ def flash_attention(
     *,
     causal: bool = False,
     sm_scale: float | None = None,
-    # Default block size: env RAY_TPU_FLASH_FWD_BLOCK (read at trace time),
-    # else 1024. Measured on v5e at bench shapes (r4: B8/H8/T1024/D128, full
-    # train step): (128,128) << (256,512) < (1024,1024) for the UNsplit
-    # causal loop — bigger blocks mean fewer grid steps and less per-block
-    # overhead, and _fit_block clamps them to the sequence, so short
-    # sequences degrade gracefully to block == seq.
-    # VMEM bound: a (1024, 1024) fp32 score tile is 4 MiB of the ~16 MiB
-    # budget, leaving room for the q/k/v/o tiles at head_dim <= 256.
-    # With the split-at-the-diagonal mask loop, smaller blocks also PRUNE:
-    # at (512,512) causal T=1024 skips 1/4 of the score tiles entirely.
-    block_q: int | None = None,
+    block_q: int | None = None,  # None: _FWD_BLOCK
     block_k: int | None = None,
     bias=None,
     force_pallas: bool | None = None,
@@ -516,12 +513,8 @@ def flash_attention(
         raise ValueError("sliding window requires causal=True")
     if sm_scale is None:
         sm_scale = 1.0 / math.sqrt(q.shape[-1])
-    if block_q is None or block_k is None:
-        import os
-
-        dflt = int(os.environ.get("RAY_TPU_FLASH_FWD_BLOCK", "1024"))
-        block_q = dflt if block_q is None else block_q
-        block_k = dflt if block_k is None else block_k
+    block_q = _FWD_BLOCK if block_q is None else block_q
+    block_k = _FWD_BLOCK if block_k is None else block_k
     use_pallas = force_pallas if force_pallas is not None else (_on_tpu() or interpret)
     Tq, Tk = q.shape[1], k.shape[1]
     bq = _fit_block(block_q, Tq)

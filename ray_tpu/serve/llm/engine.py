@@ -40,11 +40,6 @@ vLLM-lineage iteration-level scheduler on top of the paged KV cache:
   token; ``LLMRequest`` iterates it — the replica's ``StreamingResponse``
   pump drains that iterator straight onto the HTTP socket.
 
-``serial_batch=True`` degrades the scheduler to the pre-engine behavior
-(admit only into an idle engine, decode only after every admitted prompt
-finished prefill, slots idle until the whole batch drains) — the honest
-baseline arm for ``microbench.py --serve``.
-
 Concurrency contract: all cache/free-list/slot state is owned by the
 scheduler thread; ``submit``/``cancel`` only touch the wait queue under
 ``_lock`` and set the wake event (annotated ``@any_thread``); consumers
@@ -273,10 +268,10 @@ def _compiled_fns(cfg):
             import jax.numpy as jnp
 
             from ray_tpu.models.generate import (
-                _paged_decode_chunk_hidden,
+                last_row_logits,
+                paged_decode_chunk_hidden,
                 paged_decode_step,
             )
-            from ray_tpu.models.transformer import _head
 
             def decode_rows(p, rows, c):
                 logits, c = paged_decode_step(
@@ -290,13 +285,11 @@ def _compiled_fns(cfg):
                 # just that row instead of paying the [1, q, V] head matmul
                 # per chunk (the row is traced: no recompile per position).
                 pos, valid_to = rows[:, _ROW_POS], rows[:, _ROW_VALID_TO]
-                x, c = _paged_decode_chunk_hidden(
+                x, c = paged_decode_chunk_hidden(
                     p, t, c, rows[:, _ROW_TABLE:], pos, cfg, valid_to=valid_to
                 )
                 row = jnp.clip(valid_to - 1 - pos, 0, t.shape[1] - 1)
-                last = jnp.take_along_axis(x, row[:, None, None], axis=1)[:, 0]
-                logits = (last @ _head(p).astype(last.dtype)).astype(jnp.float32)
-                return _draw_row_tokens(logits, rows), c
+                return _draw_row_tokens(last_row_logits(p, x, row), rows), c
 
             # The pool (argument 2) is DONATED to both programs; the layer
             # scan carries it, so a step updates it in place (the caller's
@@ -331,7 +324,6 @@ class LLMEngine:
         max_model_len: Optional[int] = None,
         num_blocks: Optional[int] = None,
         prefill_chunk: int = 32,
-        serial_batch: bool = False,
         role: str = "both",
         cluster_prefix: bool = False,
         cluster_prefix_max: int = 16,
@@ -373,7 +365,6 @@ class LLMEngine:
         # — preemption-free unless the caller sizes the pool down.
         self.num_blocks = int(num_blocks or self.num_slots * self.n_max + 1)
         self.prefill_chunk = int(prefill_chunk)
-        self.serial_batch = bool(serial_batch)
         listen_for_compiles()
         self.spans = EngineSpans()
         t0 = time.monotonic()
@@ -959,8 +950,6 @@ class LLMEngine:
     def _admit(self) -> int:
         """Returns how many requests it admitted."""
         admitted = 0
-        if self.serial_batch and any(r is not None for r in self._slots):
-            return admitted
         while True:
             try:
                 slot = self._slots.index(None)
@@ -1135,10 +1124,6 @@ class LLMEngine:
     # --- decode ---
 
     def _decode_tick(self) -> bool:
-        if self.serial_batch and any(
-            r is not None and r._sched_state == "prefill" for r in self._slots
-        ):
-            return False  # serial baseline: the batch decodes in lockstep
         active = [r for r in self._slots if r is not None and r._sched_state == "decode"]
         if not active:
             return False

@@ -2,8 +2,8 @@
 
 JAX reads ``JAX_COMPILATION_CACHE_DIR`` when it is imported, and worker
 processes inherit the driver's environment (raylet ``_popen_worker``), so the
-entry points (``chip_smoke.py``, ``bench.py``) place the cache once, before the
-cluster starts, and no other code sets a cache directory.
+entry points (``chip_smoke.py``, ``benchmarks/run.py``) place the cache once,
+before the cluster starts, and no other code sets a cache directory.
 """
 
 from __future__ import annotations

@@ -518,14 +518,26 @@ def run_cell(ctx, workload: str, fault: str, seed: int,
                 # worker spawned after the push would run unarmed — the
                 # cell would pass with zero injections, which the subset
                 # rightly rejects.
+                # One warm task PINNED to each node: unpinned, the tasks are
+                # so short that one node's worker can serve them all, and the
+                # workload then lands on a node whose worker was spawned for
+                # it, unarmed.
                 import ray_tpu
+                from ray_tpu.util.scheduling_strategies import (
+                    NodeAffinitySchedulingStrategy,
+                )
 
                 @ray_tpu.remote
                 def _warm_pool():
                     return 1
 
                 ray_tpu.get(
-                    [_warm_pool.remote() for _ in range(len(ctx["nodes"]))],
+                    [
+                        _warm_pool.options(
+                            scheduling_strategy=NodeAffinitySchedulingStrategy(n.node_id)
+                        ).remote()
+                        for n in ctx["nodes"]
+                    ],
                     timeout=60,
                 )
                 pushed_kill = _push_plan_to_workers(ctx, spec, seed)

@@ -4,8 +4,8 @@ tear, and the pinning regression tests for the recovery bugs the matrix
 exposed.
 
 Layout (tier-1 budget): ONE module-scoped 3-node cluster hosts the matrix
-cells; the full 7x5 sweep is marked `slow` and a 3-cell deterministic
-subset (<30s) runs in tier-1. The partition/rejoin test builds its own
+cells; the full sweep is marked `slow` and a 4-cell deterministic
+subset runs in tier-1. The partition/rejoin test builds its own
 tiny cluster (it deliberately drives a node through declared-dead, which
 must not pollute the shared cluster's GCS state).
 """
@@ -70,7 +70,7 @@ def chaos_cluster():
 
 
 # ---------------------------------------------------------------------------
-# tier-1 deterministic subset (<45s): four cells, four fault kinds —
+# tier-1 deterministic subset: four cells, four fault kinds —
 # including ONE crash cell (LLM stream x kill: a seeded plan makes the
 # streaming worker SIGKILL itself mid-stream; retry completes the stream).
 # ---------------------------------------------------------------------------
@@ -85,10 +85,15 @@ _SUBSET = [
 
 @pytest.mark.parametrize("workload,fault", _SUBSET, ids=[f"{w}x{f}" for w, f in _SUBSET])
 def test_matrix_subset(chaos_cluster, workload, fault):
-    # Kill cells pay for worker respawn + jax re-import per crash (up to
-    # one per armed worker when retries land on armed peers), which is
-    # load-sensitive on this 1-CPU box — wider budget, same contract.
-    budget = 60.0 if fault == "kill" else 30.0
+    # The kill cell pays worker respawn, a jax import and the engine's two
+    # compiles per crash (up to one crash per armed worker when retries land
+    # on armed peers): ~30 s on a quiet box, all of it CPU-bound, so beside
+    # the five other xdist workers of a tier-1 run its clock reads the box's
+    # load, not the recovery. Here the cell is held to EVENTS: the stream ends
+    # with every token (or a typed error), a seeded kill fired, nothing
+    # leaked; the clock only catches a hang. test_matrix_full holds the same
+    # cell to its 60 s on a quiet box.
+    budget = 300.0 if fault == "kill" else 30.0
     res = run_cell(chaos_cluster, workload, fault, seed=13, budget_s=budget)
     assert_cell(res, budget_s=budget)
     if fault != "partition":
@@ -103,7 +108,7 @@ _FULL = [
     (w, f)
     for w in WORKLOAD_NAMES
     for f in FAULTS
-    if (w, f) not in _SUBSET  # already covered in tier-1
+    if (w, f) not in _SUBSET or f == "kill"  # tier-1 has the rest, on the clock too
 ]
 
 

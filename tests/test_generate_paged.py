@@ -287,8 +287,28 @@ def _case_chunk_window():
     return call, cache, {(0, row): dense[row] for row in range(3)}
 
 
+def _case_dense_chunk_ragged():
+    """The DENSE cache, carried by the same scan: a two-token chunk at per-row
+    positions after a ragged prefill, sliding window on. A donated
+    ``decode_chunk`` updates the cache it was handed."""
+    cfg = _cfg(sliding_window=6, n_kv_heads=2)
+    params = init_params(jax.random.PRNGKey(0), cfg)
+    seqs, fed = [[7, 3, 9, 1, 5, 2, 8], [4, 6, 11]], [[13, 17], [19, 23]]
+    prompt = jnp.asarray([seqs[0], seqs[1] + [0] * 4], jnp.int32)
+    _, cache, pos = prefill(
+        params, prompt, init_cache(cfg, 2, 32), cfg, prompt_lens=jnp.asarray([7, 3], jnp.int32)
+    )
+    call = lambda c: decode_chunk(params, jnp.asarray(fed, jnp.int32), c, pos, cfg)  # noqa: E731
+    want = {
+        (b, row): _dense_after_prompt(params, cfg, seqs[b], fed[b])[row]
+        for b in (0, 1) for row in (0, 1)
+    }
+    return call, cache, want
+
+
 @pytest.mark.parametrize(
-    "case", [_case_step_inactive_slot, _case_chunk_valid_to, _case_chunk_window]
+    "case",
+    [_case_step_inactive_slot, _case_chunk_valid_to, _case_chunk_window, _case_dense_chunk_ragged],
 )
 def test_donated_jit_matches_undonated_call_and_dense_oracle(case):
     call, cache, want = case()

@@ -91,12 +91,10 @@ def test_transfer_smoke(tmp_path):
 
 def test_serve_llm_smoke(tmp_path):
     """<30s --serve --quick pass (ISSUE 11): the closed-loop generator runs
-    both arms (serial-batch baseline + continuous batching) against the
-    serve.llm engine and produces nonzero TTFT/tokens-per-second numbers
-    with prefix-cache hits. Perf certification (>=2x tokens/s, p99 TTFT
-    reduced at 8 streams) lives in the committed SERVEBENCH_r11.json; this
-    exists so engine/scheduler breakage fails pytest instead of the next
-    bench round — the quick arms are too short/noisy to re-certify ratios."""
+    against the serve.llm engine and produces nonzero TTFT/tokens-per-second
+    numbers with prefix-cache hits. This exists so engine/scheduler breakage
+    fails pytest instead of the next bench round; speed is the benchmark's
+    (``benchmarks/run.py``), on the chip."""
     out = tmp_path / "servebench.json"
     env = dict(os.environ, JAX_PLATFORMS="cpu", RAY_TPU_NUM_TPUS="0")
     proc = subprocess.run(
@@ -122,9 +120,7 @@ def test_serve_llm_smoke(tmp_path):
     )
     data = json.loads(out.read_text())
     for key in (
-        "serve_serial_tokens_per_s",
         "serve_continuous_tokens_per_s",
-        "serve_serial_ttft_p99_ms",
         "serve_continuous_ttft_p99_ms",
         "serve_continuous_tpot_mean_ms",
     ):

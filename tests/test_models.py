@@ -212,12 +212,14 @@ def test_pallas_backward_kernels_vs_oracle(monkeypatch):
     causal/window loop pruning) must match the XLA attention's autodiff
     exactly — including the Tq != Tk bottom-right alignment and the
     sliding-window mask, at block sizes that exercise multi-block loops."""
-    monkeypatch.setenv("RAY_TPU_FLASH_BWD_BLOCK", "128")
     import jax
     import jax.numpy as jnp
     import numpy as np
 
+    from ray_tpu.ops import attention
     from ray_tpu.ops.attention import _xla_attention, flash_attention
+
+    monkeypatch.setattr(attention, "_BWD_BLOCK", 128)
 
     rng = np.random.default_rng(7)
     cases = [

@@ -388,6 +388,11 @@ def test_get_stats_carries_the_records_the_setup_and_the_proxys_stamps():
         while not dep.get_stats()["spans"]["requests"] and time.monotonic() < deadline:
             time.sleep(0.01)
         got = dep.get_stats()
+        # The pass that ended the request counts itself after it pushed the
+        # request's record: wait for it, so that the two reads below agree.
+        while got["iterations"] != dep.engine.stats()["iterations"] and time.monotonic() < deadline:
+            time.sleep(0.01)
+            got = dep.get_stats()
     finally:
         dep.prepare_for_shutdown()
     spans = got["spans"]

@@ -2,8 +2,8 @@
 
 Round-1 lesson (VERDICT.md Weak #1): every kernel test ran interpret=True on
 CPU, so the suite stayed green while the TPU lowering was broken (the LSE
-BlockSpec violated the (8, 128) tile constraint and bench.py crashed on
-hardware). This test compiles the kernels for the real TPU backend — no
+BlockSpec violated the (8, 128) tile constraint and the train step crashed
+on hardware). This test compiles the kernels for the real TPU backend — no
 interpret — so a Mosaic lowering regression fails CI whenever a TPU is
 reachable.
 
