@@ -6,9 +6,16 @@ whole batch to drain. This engine is the batching brain in between — the
 vLLM-lineage iteration-level scheduler on top of the paged KV cache:
 
 - **slots**: a fixed number of decode lanes (static [num_slots] shapes, so
-  XLA compiles the decode step ONCE); a sequence occupies a slot from
-  admission to completion, and a new prompt is admitted the moment a slot
-  and enough KV blocks free up — mid-decode, not between batches.
+  XLA compiles the decode step once per block-table width, below); a
+  sequence occupies a slot from admission to completion, and a new prompt is
+  admitted the moment a slot and enough KV blocks free up — mid-decode, not
+  between batches.
+- **a decode step is as wide as its longest row**: the decode program
+  gathers and attends over the block table it is handed, whatever the rows
+  hold, so a step's table is cut to the smallest rung of ``_view_rungs``
+  (16, 32, 64, ... blocks, then ``n_max``) that covers the longest running
+  row. One program a rung, every one built in ``__init__``: a replica that
+  reports ready compiles nothing more. The prefill chunk keeps ``n_max``.
 - **paged KV cache**: ``init_paged_cache`` block pool + per-sequence block
   tables with a host-side free-list. Block 0 is the reserved null block
   (inactive slots and write-masked padding rows land there). The pool is
@@ -229,7 +236,21 @@ _ROW_VALID_TO = 0  # prefill (its tokens are an argument of their own): the teac
 _ROW_POS = 1  # position of the first token fed
 _ROW_DRAW = slice(2, 6)  # LLMRequest._sched_draw
 _ROW_COUNTER = 6  # index of the token this dispatch draws
-_ROW_TABLE = 7  # the block table, n_max wide, from here on
+_ROW_TABLE = 7  # the block table from here on: n_max wide (prefill), a rung of _view_rungs (decode)
+
+# The narrowest block table a decode step is given, in blocks. An engine whose
+# n_max is no wider has one decode program.
+_MIN_VIEW_BLOCKS = 16
+
+
+def _view_rungs(n_max: int) -> tuple:
+    """Widths in blocks of the decode step's block table, ascending:
+    ``_MIN_VIEW_BLOCKS`` doubled while below ``n_max``, then ``n_max``."""
+    rungs, w = [], _MIN_VIEW_BLOCKS
+    while w < n_max:
+        rungs.append(w)
+        w *= 2
+    return (*rungs, n_max)
 
 
 def _draw_row_tokens(logits, rows):
@@ -258,8 +279,9 @@ _JIT_LOCK = threading.Lock()
 
 
 def _compiled_fns(cfg):
-    """(decode, prefill): ``decode(params, rows [num_slots, 7 + n_max], pool)``
-    and ``prefill(params, tokens [1, q], pool, rows [1, 7 + n_max])``, both
+    """(decode, prefill): ``decode(params, rows [num_slots, 7 + w], pool)``,
+    ``w`` a rung of ``_view_rungs`` (one compiled program each), and
+    ``prefill(params, tokens [1, q], pool, rows [1, 7 + n_max])``, both
     ``-> (token ids int32, one a row, pool)``."""
     with _JIT_LOCK:
         fns = _JIT_CACHE.get(cfg)
@@ -361,6 +383,9 @@ class LLMEngine:
         self.block_size = int(block_size)
         self.max_model_len = int(max_model_len or cfg.max_seq_len)
         self.n_max = -(-self.max_model_len // self.block_size)  # blocks/seq
+        self._view_rungs = _view_rungs(self.n_max)
+        # Decode steps run at each width, for stats()["decode_width_steps"].
+        self._width_steps = {w: 0 for w in self._view_rungs}
         # Default pool: every slot can run to max_model_len (+1 null block)
         # — preemption-free unless the caller sizes the pool down.
         self.num_blocks = int(num_blocks or self.num_slots * self.n_max + 1)
@@ -418,6 +443,9 @@ class LLMEngine:
         t0 = time.monotonic()
         self._decode_fn, self._prefill_fn = _compiled_fns(cfg)
         self.spans.setup["jit_build_s"] = time.monotonic() - t0
+        t0 = time.monotonic()
+        self._build_decode_rungs()
+        self.spans.setup["decode_build_s"] = time.monotonic() - t0
         self._thread = threading.Thread(
             target=self._loop, name="llm-engine", daemon=True
         )
@@ -567,6 +595,7 @@ class LLMEngine:
             "published_prefixes": len(self._published),
             "pending_exports": len(self._exports),
             **self._counts,
+            "decode_width_steps": dict(self._width_steps),
             **self.spans.totals(),
         }
 
@@ -1055,7 +1084,7 @@ class LLMEngine:
             # The program draws from the row of the prompt's LAST real token
             # within this chunk: only meaningful (and only fetched) on the
             # final chunk.
-            rows = self._program_rows(1)
+            rows = self._program_rows(1, self.n_max)
             self._fill_row(rows[0], req, req._sched_target, pos0)
             inputs = (jnp.asarray(fed), jnp.asarray(rows))
         spans.carried(prefill_tokens=len(piece))
@@ -1079,10 +1108,12 @@ class LLMEngine:
                 sp.set(finished=int(req._finished))
         return True
 
-    def _program_rows(self, n: int) -> np.ndarray:
-        """``n`` all-zero rows of a program's int32 input: an inactive slot
-        (token 0 at position 0 of the null block, drawn greedily)."""
-        return np.zeros((n, _ROW_TABLE + self.n_max), np.int32)
+    @staticmethod
+    def _program_rows(n: int, width: int) -> np.ndarray:
+        """``n`` all-zero rows of a program's int32 input, their block table
+        ``width`` blocks wide: an inactive slot (token 0 at position 0 of the
+        null block, drawn greedily)."""
+        return np.zeros((n, _ROW_TABLE + width), np.int32)
 
     @staticmethod
     def _fill_row(row: np.ndarray, req: LLMRequest, first: int, pos: int):
@@ -1104,6 +1135,27 @@ class LLMEngine:
         if not (pool["k"].is_deleted() and pool["v"].is_deleted()):
             self._counts["kv_pool_not_donated"] += 1
         return drawn
+
+    def _build_decode_rungs(self):
+        """Build the decode program at every rung before the scheduler starts:
+        one dispatch of all-inactive rows each, which writes row 0 of the null
+        block and nothing else. A width first met while serving would compile
+        for seconds inside a stream."""
+        import jax
+        import jax.numpy as jnp
+
+        for width in self._view_rungs:
+            rows = jnp.asarray(self._program_rows(self.num_slots, width))
+            # Traced, lowered and compiled apart from the call, and all three
+            # held until it returns: the call then finds the trace and the
+            # lowering in jit's caches, which keep them only while these
+            # objects live. In a v5e replica that is 0.6 s a rung where the
+            # call alone takes 0.94 (PERF.md, PR 31).
+            traced = self._decode_fn.trace(self.params, rows, self._cache)
+            lowered = traced.lower()
+            held = (traced, lowered, lowered.compile())
+            jax.block_until_ready(self._run_donated(self._decode_fn, rows))
+            del held
 
     def _register_prefix_blocks(self, req: LLMRequest):
         """Publish freshly-WRITTEN full prompt blocks for reuse. Done as
@@ -1174,13 +1226,18 @@ class LLMEngine:
                 return True
             import jax.numpy as jnp
 
-            rows = self._program_rows(self.num_slots)
+            # The step gathers and attends over the table it is handed: the
+            # smallest rung that covers the longest running row.
+            longest = max(len(r._sched_table) for r in active)
+            width = next(w for w in self._view_rungs if w >= longest)
+            rows = self._program_rows(self.num_slots, width)
             for req in active:
                 self._fill_row(
                     rows[req._sched_slot], req, req._sched_generated[-1], req._sched_pos
                 )
             rows = jnp.asarray(rows)
-        spans.carried(rows=len(active))
+        spans.carried(rows=len(active), view_blocks=width)
+        self._width_steps[width] += 1
         with spans.span("llm.decode.dispatch"):
             drawn = self._run_donated(self._decode_fn, rows)
         with spans.span("llm.decode.fetch"):

@@ -48,8 +48,10 @@ SPAN_NAMES = (
 ITERATION_KINDS = ("decode", "prefill", "mixed")
 # One record per pass of the scheduler loop that dispatched a program: the
 # start stamp, what the pass carried, and the nanoseconds of each span.
-ITERATION_FIELDS = ("t_start_ns", "rows", "prefill_tokens", "waiting", "running") + SPAN_NAMES
-_T_START, _ROWS, _PREFILL_TOKENS, _WAITING, _RUNNING = range(5)
+ITERATION_FIELDS = (
+    "t_start_ns", "rows", "prefill_tokens", "waiting", "running", "view_blocks",
+) + SPAN_NAMES
+_T_START, _ROWS, _PREFILL_TOKENS, _WAITING, _RUNNING, _VIEW_BLOCKS = range(6)
 _FIRST_SPAN = len(ITERATION_FIELDS) - len(SPAN_NAMES)
 _SPAN_FIELD = {name: _FIRST_SPAN + i for i, name in enumerate(SPAN_NAMES)}
 # One record per request that ended; stamps are CLOCK_MONOTONIC nanoseconds
@@ -230,10 +232,12 @@ class EngineSpans:
     def span(self, name: str, **args) -> _Span:
         return _Span(self._cur, _SPAN_FIELD[name], self._annotation(name, **args))
 
-    def carried(self, rows: int = 0, prefill_tokens: int = 0):
-        """What this pass dispatched: decode rows, prompt tokens of its chunk."""
+    def carried(self, rows: int = 0, prefill_tokens: int = 0, view_blocks: int = 0):
+        """What this pass dispatched: decode rows and the width in blocks of
+        their step's block table, prompt tokens of its chunk."""
         self._cur[_ROWS] += rows
         self._cur[_PREFILL_TOKENS] += prefill_tokens
+        self._cur[_VIEW_BLOCKS] += view_blocks
 
     def end(self, it: _Span):
         """Closes the iteration. A pass that dispatched no program leaves no

@@ -40,16 +40,22 @@ def test_no_device_nodes_exits_nonzero(monkeypatch):
 
 
 def test_compile_cache_dir_is_the_callers_or_a_fixed_path_in_the_checkout(monkeypatch):
-    from ray_tpu.util.compile_cache import ENV_VAR, export_compile_cache_dir
+    from ray_tpu.util.compile_cache import (
+        ENV_VAR, MIN_COMPILE_ENV_VAR, MIN_COMPILE_SECS, export_compile_cache_dir,
+    )
 
     monkeypatch.setenv(ENV_VAR, "/somewhere/else")
+    monkeypatch.setenv(MIN_COMPILE_ENV_VAR, "2")
     assert export_compile_cache_dir(chip_smoke.__file__) == "/somewhere/else"
-    assert os.environ[ENV_VAR] == "/somewhere/else"
+    assert os.environ[ENV_VAR] == "/somewhere/else" and os.environ[MIN_COMPILE_ENV_VAR] == "2"
     monkeypatch.delenv(ENV_VAR)
+    monkeypatch.delenv(MIN_COMPILE_ENV_VAR)
     monkeypatch.chdir("/")  # not derived from the working directory
     # Exactly this: no pid, time, temp or session component.
     assert export_compile_cache_dir(chip_smoke.__file__) == os.path.join(REPO, ".jax_cache")
     assert os.environ[ENV_VAR] == os.path.join(REPO, ".jax_cache")
+    # Programs that compile in about a second (the engine's decode rungs) are persisted too.
+    assert os.environ[MIN_COMPILE_ENV_VAR] == MIN_COMPILE_SECS and 0 < float(MIN_COMPILE_SECS) < 0.9
 
 
 def test_a_stalled_host_is_not_a_dead_node():

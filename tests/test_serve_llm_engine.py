@@ -617,6 +617,176 @@ def test_flight_events_recorded(model, tmp_path):
 
 
 # ---------------------------------------------------------------------------
+# the decode step's width (ISSUE 31): a ladder of block-table widths
+# ---------------------------------------------------------------------------
+
+# block_size 4 x max_model_len 256: n_max 64 blocks, rungs 16 / 32 / 64 blocks
+# (64 / 128 / 256 tokens). A 50-token prompt with 90 new tokens crosses both
+# inner boundaries mid-generation.
+LADDER = dict(num_slots=3, block_size=4, max_model_len=256, prefill_chunk=8)
+LONG_PROMPT, LONG_NEW = 50, 90
+
+
+@pytest.fixture(scope="module")
+def ladder_engine(model):
+    from ray_tpu.serve.llm import LLMEngine
+
+    params, cfg = model
+    eng = LLMEngine(params, cfg, **LADDER)
+    # Outside the decode ladder: the prefill program, built by the first chunk.
+    eng.submit([1, 2, 3], max_new_tokens=2).result(60)
+    yield eng
+    eng.shutdown()
+
+
+def _backend_compiles(cursor):
+    """Names of the programs the backend built since ``COMPILES.n`` was ``cursor``."""
+    from ray_tpu.serve.llm.stats import COMPILES
+
+    return [r[3] for r in COMPILES.since(cursor) if r[2] == "backend_compile"]
+
+
+def _widths_run(eng, before):
+    """Rungs at which ``eng`` ran a decode step since ``before``, a copy of its ``_width_steps``."""
+    return {w for w, n in eng._width_steps.items() if n > before[w]}
+
+
+def test_ladder_is_a_constant_of_n_max():
+    from ray_tpu.serve.llm.engine import _view_rungs
+
+    assert _view_rungs(160) == (16, 32, 64, 128, 160)  # the benchmark's engine
+    assert _view_rungs(64) == (16, 32, 64)
+    assert _view_rungs(17) == (16, 17)
+    for n_max in (1, 8, 16):
+        assert _view_rungs(n_max) == (n_max,)  # every other CPU test's engine: today's one program
+
+
+@pytest.mark.parametrize(
+    "sampling",
+    [dict(temperature=0.0), dict(temperature=0.9, top_k=16, seed=7), dict(temperature=1.0, seed=11)],
+    ids=["greedy", "sampled_top_k", "sampled"],
+)
+def test_streams_across_rungs_equal_the_full_width_streams(model, ladder_engine, monkeypatch, sampling):
+    """A stream that crosses two rung boundaries mid-generation is, token for
+    token, the stream of the same engine held to its one full-width rung
+    (today's program), and greedy it is ``generate()``'s: a masked key weighs
+    exactly 0, so a view that ends at the rung loses nothing."""
+    params, cfg = model
+    eng = ladder_engine
+    prompt = _rand_prompt(61, LONG_PROMPT)
+    before = dict(eng._width_steps)
+    laddered = eng.submit(prompt, max_new_tokens=LONG_NEW, **sampling).result(120)
+    assert _widths_run(eng, before) == {16, 32, 64}
+    monkeypatch.setattr(eng, "_view_rungs", (eng.n_max,))
+    before = dict(eng._width_steps)
+    full = eng.submit(prompt, max_new_tokens=LONG_NEW, **sampling).result(120)
+    assert _widths_run(eng, before) == {eng.n_max}
+    assert laddered == full
+    if not sampling["temperature"]:
+        assert laddered == _dense(params, cfg, prompt, LONG_NEW)
+
+
+def test_a_run_over_every_rung_compiles_nothing(ladder_engine):
+    """Every rung's program was built in ``__init__``: a run that visits all
+    three adds no ``backend_compile`` to the process's compile ring."""
+    from ray_tpu.serve.llm.stats import COMPILES
+
+    eng = ladder_engine
+    cursor = COMPILES.n
+    before = dict(eng._width_steps)
+    reqs = [eng.submit(_rand_prompt(70 + i, LONG_PROMPT - 20 * i), max_new_tokens=LONG_NEW)
+            for i in range(2)]
+    for r in reqs:
+        assert len(r.result(120)) == LONG_NEW
+    assert _widths_run(eng, before) == {16, 32, 64}
+    assert _backend_compiles(cursor) == []
+
+
+@pytest.mark.parametrize(
+    "max_model_len,d_ff,rungs",
+    [(64, 72, (16,)), (256, 80, (16, 32, 64))],
+    ids=["n_max_16_one_program", "n_max_64_three_programs"],
+)
+def test_construction_builds_one_decode_program_a_rung(max_model_len, d_ff, rungs):
+    """``n_max`` <= 16 blocks: one rung and one decode program, as before the
+    ladder. A wider engine builds one program a rung, all before it is ready:
+    nothing was donated in vain and no step has been counted."""
+    import jax
+    import jax.numpy as jnp
+
+    from ray_tpu.models.transformer import TransformerConfig, init_params
+    from ray_tpu.serve.llm import LLMEngine
+    from ray_tpu.serve.llm.stats import COMPILES, listen_for_compiles
+
+    # A configuration no other test uses: its programs are in no jit cache yet.
+    cfg = TransformerConfig(**dict(MODEL, d_ff=d_ff, dtype=jnp.dtype(MODEL["dtype"]).type))
+    params = init_params(jax.random.PRNGKey(0), cfg)
+    listen_for_compiles()
+    since = COMPILES.n
+    eng = LLMEngine(params, cfg, num_slots=2, block_size=4, max_model_len=max_model_len,
+                    prefill_chunk=4)
+    try:
+        built = [name for name in _backend_compiles(since) if "lambda" in name]
+        assert eng._view_rungs == rungs and len(built) == len(rungs), built
+        s = eng.stats()
+        assert s["decode_width_steps"] == {w: 0 for w in rungs}
+        assert s["kv_pool_not_donated"] == 0
+        assert eng.spans.setup["decode_build_s"] > 0
+        assert eng.spans.export()["iterations"] == []  # a build is no pass of the scheduler
+    finally:
+        eng.shutdown()
+
+
+@pytest.mark.parametrize("num_blocks", [None, 26], ids=["roomy_pool", "preempting_pool"])
+def test_step_width_is_the_smallest_rung_over_the_longest_active_table(model, num_blocks):
+    """Driven by hand: each decode step's width is the smallest rung >= the
+    longest table among the rows that decode (rows in prefill not counted). It
+    goes up when a row crosses a rung and comes back down when the longest row
+    finishes (roomy pool) or is preempted (a pool of 25 blocks: the long row
+    is the youngest, loses its blocks at 64 tokens and comes back).
+    ``decode_width_steps`` sums to the decode steps run."""
+    kw = dict(num_slots=2, max_model_len=256)
+    if num_blocks:
+        kw["num_blocks"] = num_blocks
+    eng = _stopped_engine(model, **kw)
+    bs, rungs = eng.block_size, eng._view_rungs
+    specs = [(_rand_prompt(81, 58), 14), (_rand_prompt(82, 9), 40)]  # 15 -> 18 blocks; 3 -> 13 blocks
+    if num_blocks:
+        specs.reverse()  # the long row is admitted last: the youngest is the victim
+    reqs = [eng.submit(p, max_new_tokens=n) for p, n in specs]
+    seen = []
+    for _ in range(600):
+        if all(r._finished for r in reqs):
+            break
+        eng._admit()
+        eng._prefill_tick()
+        decoding = [r for r in eng._slots if r is not None and r._sched_state == "decode"]
+        before = dict(eng._width_steps)
+        eng._decode_tick()
+        ran = sorted(_widths_run(eng, before))
+        if not ran:
+            continue
+        # A row that decoded advanced by one (a finished row keeps its
+        # position; a preempted one is back at 0): it wrote at _sched_pos - 1.
+        stepped = [r for r in decoding if r._sched_pos > 0]
+        longest = max((r._sched_pos - 1) // bs + 1 for r in stepped)
+        assert ran == [min(w for w in rungs if w >= longest)], (ran, longest)
+        seen.append(ran[0])
+    assert all(r._finished for r in reqs)
+    for r, (p, n) in zip(reqs, specs):
+        assert r.result(5) == _dense(*model, p, n)
+    assert set(seen) == {16, 32}  # 72 tokens at most: the 64-block rung is never needed
+    assert seen[0] == 16 and (16, 32) in zip(seen, seen[1:]) and (32, 16) in zip(seen, seen[1:])
+    s = eng.stats()
+    assert sum(s["decode_width_steps"].values()) == len(seen)
+    assert s["decode_width_steps"] == {w: seen.count(w) for w in rungs}
+    assert s["kv_pool_not_donated"] == 0
+    assert s["preemptions"] == (1 if num_blocks else 0), s
+    if not num_blocks:
+        assert seen[-1] == 16  # the long row finished first: the width came back down
+
+
+# ---------------------------------------------------------------------------
 # replica stream hygiene (no cluster: Replica driven directly)
 # ---------------------------------------------------------------------------
 

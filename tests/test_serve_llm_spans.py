@@ -167,6 +167,7 @@ def test_iteration_phases_and_rows(model, streams):
         assert 0 <= r[IT["rows"]] <= streams and r[IT["running"]] <= streams
         assert r[IT["rows"]] or r[IT["prefill_tokens"]]  # a pass that dispatched nothing leaves none
         assert (r[IT["llm.decode.fetch"]] > 0) == (r[IT["rows"]] > 0)
+        assert r[IT["view_blocks"]] == (eng.n_max if r[IT["rows"]] else 0)  # n_max 8: one rung
     assert sum(r[IT["rows"]] for r in its) == streams * (16 - 1)
     assert sum(r[IT["prefill_tokens"]] for r in its) == streams * 9
     assert max(r[IT["rows"]] for r in its) == streams
@@ -177,6 +178,26 @@ def test_iteration_phases_and_rows(model, streams):
     for name in stats.SPAN_NAMES:
         assert totals["span_ns"][name] == sum(r[IT[name]] for r in its)
     assert totals["span_counts"]["llm.iteration"] == len(its)
+
+
+def test_view_blocks_is_the_width_of_the_pass_s_decode_step(model):
+    """``view_blocks``: the rung of the step's block table, 0 on a pass without
+    a decode step. With ``n_max`` 32 blocks the rungs are 16 and 32: a stream
+    that passes 64 tokens moves from one to the other, and the ring's counts
+    are ``stats()["decode_width_steps"]``."""
+    eng = _engine(model, max_model_len=128, prefill_chunk=8)
+    try:
+        assert len(eng.submit(_prompt(45, 50), max_new_tokens=30).result(timeout=60)) == 30
+    finally:
+        eng.shutdown()
+    its = _iterations(eng.spans.export())
+    widths = [r[IT["view_blocks"]] for r in its]
+    assert all((w > 0) == (r[IT["rows"]] > 0) for w, r in zip(widths, its))
+    stepped = [w for w in widths if w]
+    assert sorted(set(stepped)) == [16, 32] and stepped == sorted(stepped)  # up once, at 64 tokens
+    assert stepped.count(16) == 64 - 50  # write positions 50..63 sit in the first 16 blocks
+    assert eng.stats()["decode_width_steps"] == {16: stepped.count(16), 32: stepped.count(32)}
+    assert any(r[IT["prefill_tokens"]] and not r[IT["view_blocks"]] for r in its)  # a chunk alone
 
 
 # ---------------------------------------------------------------------------
@@ -397,7 +418,9 @@ def test_get_stats_carries_the_records_the_setup_and_the_proxys_stamps():
         dep.prepare_for_shutdown()
     spans = got["spans"]
     assert set(spans) == {"iterations", "requests", "compiles", "setup", "fields"}
-    assert set(spans["setup"]) == {"jax_import_s", "backend_s", "params_s", "pool_s", "jit_build_s"}
+    assert set(spans["setup"]) == {
+        "jax_import_s", "backend_s", "params_s", "pool_s", "jit_build_s", "decode_build_s",
+    }
     assert all(isinstance(v, float) and v >= 0 for v in spans["setup"].values())
     (rec,) = spans["requests"]
     rec = dict(zip(spans["fields"]["requests"], rec))
