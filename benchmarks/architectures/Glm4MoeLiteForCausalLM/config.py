@@ -1,0 +1,76 @@
+"""The published keys of a ``glm4_moe_lite`` ``config.json`` (GLM-4.7-Flash)
+under the names the program's ``TransformerConfig`` takes.
+
+What the program does not compute is refused here, not passed over: grouped
+routing (``n_group`` / ``topk_group`` other than 1), un-normalised top-k
+weights, a rope scaling, a partial rotary factor, attention biases. The
+multi-token-prediction layer (``num_nextn_predict_layers``) is a draft head
+beyond ``num_hidden_layers``; a deployment without self-drafting does not run
+it, and nothing of it is built (the configuration's ``left_out`` says so).
+
+A program whose ``TransformerConfig`` lacks a field this architecture needs (a
+commit from before latent attention and routed experts) is refused in the
+driver process, at once, instead of inside a replica that Serve would start
+again and again: the fields are read from the source of
+``ray_tpu/models/transformer.py``, because this process must never import jax.
+"""
+
+from __future__ import annotations
+
+import ast
+import os
+
+FIXED = {
+    "n_group": 1, "topk_group": 1, "norm_topk_prob": True, "topk_method": "noaux_tc",
+    "rope_scaling": None, "partial_rotary_factor": 1, "attention_bias": False, "hidden_act": "silu",
+}
+
+
+def _program_fields() -> set:
+    import ray_tpu
+
+    path = os.path.join(os.path.dirname(os.path.abspath(ray_tpu.__file__)), "models", "transformer.py")
+    with open(path) as f:
+        tree = ast.parse(f.read())
+    for node in tree.body:
+        if isinstance(node, ast.ClassDef) and node.name == "TransformerConfig":
+            return {s.target.id for s in node.body if isinstance(s, ast.AnnAssign)}
+    raise ValueError(f"{path} defines no TransformerConfig")
+
+
+def model_config(cfg: dict, max_seq_len: int, param_dtype: str) -> dict:
+    for key, value in FIXED.items():
+        if cfg[key] != value:
+            raise ValueError(f"{key} = {cfg[key]!r}: the program computes {value!r} only")
+    model = dict(
+        vocab_size=cfg["vocab_size"],
+        d_model=cfg["hidden_size"],
+        n_layers=cfg["num_hidden_layers"],
+        n_heads=cfg["num_attention_heads"],
+        n_kv_heads=cfg["num_key_value_heads"],
+        d_ff=cfg["intermediate_size"],
+        rope_theta=float(cfg["rope_theta"]),
+        norm_eps=cfg["rms_norm_eps"],
+        tie_embeddings=cfg["tie_word_embeddings"],
+        dtype=cfg["torch_dtype"],
+        param_dtype=param_dtype,
+        max_seq_len=max_seq_len,
+        q_lora_rank=cfg["q_lora_rank"],
+        kv_lora_rank=cfg["kv_lora_rank"],
+        qk_nope_head_dim=cfg["qk_nope_head_dim"],
+        qk_rope_head_dim=cfg["qk_rope_head_dim"],
+        v_head_dim=cfg["v_head_dim"],
+        num_experts=cfg["n_routed_experts"],
+        experts_per_token=cfg["num_experts_per_tok"],
+        d_expert=cfg["moe_intermediate_size"],
+        num_shared_experts=cfg["n_shared_experts"],
+        routed_scaling_factor=cfg["routed_scaling_factor"],
+        first_dense_layers=cfg["first_k_dense_replace"],
+    )
+    lacking = sorted(set(model) - _program_fields())
+    if lacking:
+        raise NotImplementedError(
+            f"this program's TransformerConfig has no {', '.join(lacking)}: it cannot run "
+            "latent attention and routed experts"
+        )
+    return model

@@ -18,11 +18,19 @@ wrap HF pipelines; SURVEY.md §5.7) — this is the TPU-native equivalent:
 
 The layer over a cache is stated once (``_cached_layers``); the dense cache
 and the block pool differ only in how a chunk's rows are written and which
-rows are viewed. Its math intentionally mirrors transformer._attention_block/
-_mlp_block on the same param pytree — decode diverges (cache writes, position
-masking) enough that sharing one function would tangle the training hot path. MoE
-configs decode through the same parallel/moe.moe_layer dispatch the
-training block uses (T=1: each row's token rides its top-1 expert's slot).
+rows are viewed, and an attention kind in what it writes and how it attends
+over the view: keys and values per KV head (GQA: ``_project_qkv``,
+``_cache_attention``), or one latent and one rotary key a token (MLA:
+``_project_latent``, ``_latent_attention``, in absorbed form: the cache is
+attended over as it lies, never expanded to per-head keys and values). Its
+math intentionally mirrors transformer._attention_block/_mlp_block on the same
+param pytree — decode diverges (cache writes, position masking) enough that
+sharing one function would tangle the training hot path. A layer's MLP is what
+its leaves say: dense SwiGLU; dropless routed experts with a shared expert
+(``parallel/moe.routed_experts``: top-k of sigmoid scores, grouped matmuls, no
+capacity, behind ``first_dense_layers`` dense layers stacked apart); or the
+Switch layer the training tests keep (``parallel/moe.moe_layer``, top-1 with a
+capacity, run lossless here).
 """
 
 from __future__ import annotations
@@ -31,6 +39,7 @@ from functools import partial
 
 import jax
 import jax.numpy as jnp
+import numpy as np
 from jax import lax
 
 from ray_tpu.models.transformer import (
@@ -41,43 +50,144 @@ from ray_tpu.models.transformer import (
 )
 
 
+_LANES = 128
+
+
+def _latent_row_width(cfg: TransformerConfig) -> int:
+    """A cached latent row: ``kv_lora_rank + qk_rope_head_dim`` values, then
+    zeros up to a multiple of the TPU's 128 lanes. The tiled layout pads the
+    minor axis to that anyway; a leaf whose minor axis is NOT such a multiple
+    (576) is laid out with another axis minor-most, and every program that
+    carries the pool through its layer scan then copies the whole pool in and
+    out (two pool-sized copies a decode step; seen in the compiled decode
+    program of GLM-4.7-Flash, PR 32). The padding is never written or read as
+    anything but zeros: queries are zero there."""
+    return -(-(cfg.kv_lora_rank + cfg.qk_rope_head_dim) // _LANES) * _LANES
+
+
+def _cache_rows(cfg: TransformerConfig) -> dict:
+    """What one token leaves in one layer of a cache, leaf name -> trailing
+    shape: keys and values per KV head, or (latent attention) one leaf holding
+    the normed latent followed by the rotary key, as the absorbed form reads them."""
+    if cfg.latent_attention:
+        return {"ckv": (_latent_row_width(cfg),)}
+    return {"k": (cfg.n_kv_heads, cfg.head_dim), "v": (cfg.n_kv_heads, cfg.head_dim)}
+
+
 def init_cache(cfg: TransformerConfig, batch: int, max_len: int):
-    """Preallocated KV cache: k/v of shape [L, B, max_len, KV, Dh] (bf16 on
+    """Preallocated cache, every leaf [L, B, max_len, ...] (``_cache_rows``):
+    k/v [.., KV, Dh], or ckv [.., the latent and the rotary key] (bf16 on
     TPU — cache reads are the decode bandwidth bill)."""
-    shape = (cfg.n_layers, batch, max_len, cfg.n_kv_heads, cfg.head_dim)
     return {
-        "k": jnp.zeros(shape, cfg.dtype),
-        "v": jnp.zeros(shape, cfg.dtype),
+        name: jnp.zeros((cfg.n_layers, batch, max_len, *row), cfg.dtype)
+        for name, row in _cache_rows(cfg).items()
     }
 
 
 def _project_qkv(lp, x, positions, cfg):
+    """GQA: (q [B, T, H, Dh], the rows to cache {"k", "v"}: [B, T, KV, Dh])."""
     B, T, _ = x.shape
     H, KV, Dh = cfg.n_heads, cfg.n_kv_heads, cfg.head_dim
     h = _rms_norm(x, lp["attn_norm"], cfg.norm_eps)
     q = (h @ lp["wq"].astype(h.dtype)).reshape(B, T, H, Dh)
     k = (h @ lp["wk"].astype(h.dtype)).reshape(B, T, KV, Dh)
     v = (h @ lp["wv"].astype(h.dtype)).reshape(B, T, KV, Dh)
-    return _rope(q, positions, cfg.rope_theta), _rope(k, positions, cfg.rope_theta), v
+    return _rope(q, positions, cfg.rope_theta), {"k": _rope(k, positions, cfg.rope_theta), "v": v}
 
 
-def _mlp(lp, x, cfg):
+def _latent_kv_up(lp, cfg):
+    """``wkv_b`` by head: (W_uk [R, H, nope], W_uv [R, H, v])."""
+    w = lp["wkv_b"].reshape(cfg.kv_lora_rank, cfg.n_heads, -1)
+    return w[..., : cfg.qk_nope_head_dim], w[..., cfg.qk_nope_head_dim :]
+
+
+def _project_latent(lp, x, positions, cfg):
+    """MLA: (q in absorbed form [B, T, H, W], the row to cache {"ckv": [B, T,
+    W]}), W the padded row width (``_latent_row_width``): ``[c, k_r, 0..]``
+    against ``[q~, q_rope, 0..]``. ``q_nope`` is carried through W_uk into the
+    latent's space here, once a query, so that a score is one dot product
+    with the cached row: ``q~ . c + q_rope . k_r``."""
+    B, T, _ = x.shape
+    H, R, P = cfg.n_heads, cfg.kv_lora_rank, cfg.qk_rope_head_dim
+    h = _rms_norm(x, lp["attn_norm"], cfg.norm_eps)
+    c_q = _rms_norm(h @ lp["wq_a"].astype(h.dtype), lp["q_norm"], cfg.norm_eps)
+    q = (c_q @ lp["wq_b"].astype(h.dtype)).reshape(B, T, H, -1)
+    q_nope, q_rope = q[..., : cfg.qk_nope_head_dim], q[..., cfg.qk_nope_head_dim :]
+    kv = h @ lp["wkv_a"].astype(h.dtype)
+    c = _rms_norm(kv[..., :R], lp["kv_norm"], cfg.norm_eps)
+    k_rope = _rope(kv[..., None, R:], positions, cfg.rope_theta)[:, :, 0]
+    w_uk, _ = _latent_kv_up(lp, cfg)
+    q_abs = jnp.einsum("bthn,rhn->bthr", q_nope, w_uk.astype(h.dtype))
+    pad = _latent_row_width(cfg) - R - P
+    q_full = jnp.concatenate(
+        [q_abs, _rope(q_rope, positions, cfg.rope_theta), jnp.zeros((B, T, H, pad), q.dtype)], axis=-1
+    )
+    return q_full, {"ckv": jnp.concatenate([c, k_rope, jnp.zeros((B, T, pad), c.dtype)], axis=-1)}
+
+
+def _latent_attention(lp, q, view, pos_mask, cfg):
+    """Absorbed-form attention over cached latents: q [B, T, H, W]
+    against ``view["ckv"]`` [B, S, W] -> [B, T, H * v]. The weighted
+    sum is taken over the latents (R wide) and only then carried through
+    W_uv, so no per-head key or value of a cached token is ever formed."""
+    ckv = view["ckv"]
+    R = cfg.kv_lora_rank
+    scale = (cfg.qk_nope_head_dim + cfg.qk_rope_head_dim) ** -0.5
+    s = jnp.einsum("bqhr,bkr->bhqk", q, ckv, preferred_element_type=jnp.float32)
+    s = jnp.where(pos_mask[:, None], s * scale, -jnp.inf)
+    p = jax.nn.softmax(s, axis=-1)
+    # Over the whole row, the rotary key and the padding with it, and the
+    # latent's columns cut from the small result: a slice of the view would
+    # be one more copy of it.
+    o = jnp.einsum("bhqk,bkr->bhqr", p.astype(ckv.dtype), ckv,
+                   preferred_element_type=jnp.float32)[..., :R].astype(q.dtype)
+    _, w_uv = _latent_kv_up(lp, cfg)
+    o = jnp.einsum("bhqr,rhv->bqhv", o, w_uv.astype(o.dtype))
+    return o.reshape(*o.shape[:2], -1)
+
+
+def _swiglu(h, wg, wi, wo):
+    return (jax.nn.silu(h @ wg.astype(h.dtype)) * (h @ wi.astype(h.dtype))) @ wo.astype(h.dtype)
+
+
+# The leaves of a routed-expert stack that the layer scan does not slice.
+_EXPERT_STACKS = ("wg_e", "wi_e", "wo_e")
+
+
+def _mlp(lp, x, cfg, valid=None, layer=None):
+    """(x + MLP(norm(x)), the MLP this layer's leaves hold; with routed
+    experts the tokens sent to each expert [E] int32 and each row's experts
+    [B, q, k] int32, else None and None).
+    ``valid`` [B, q] marks the rows that are real tokens (routed experts
+    only: padding reaches no expert and is not counted). ``layer``: the
+    expert leaves of ``lp`` are whole stacks and this is the layer to run
+    (``routed_experts``)."""
     h = _rms_norm(x, lp["mlp_norm"], cfg.norm_eps)
-    if cfg.num_experts > 0:
-        from ray_tpu.models.transformer import _moe_mlp
+    if "gate" not in lp:
+        return x + _swiglu(h, lp["wg"], lp["wi"], lp["wo_mlp"]), None, None
+    if cfg.routed_experts:
+        from ray_tpu.parallel.moe import routed_experts
 
-        # LOSSLESS dispatch at inference: capacity_factor=E gives every
-        # token a slot (capacity == T), so routing is per-token and
-        # independent of batch padding — ragged rows behave exactly like
-        # solo rows, and prefill agrees with T=1 decode. Training's
-        # capacity drops (expert_capacity_factor) are an efficiency
-        # approximation that inference deliberately does not replicate.
-        # Aux loss is meaningless at inference and discarded.
-        out, _aux = _moe_mlp(lp, h, float(cfg.num_experts))
-        return x + out
-    gate = jax.nn.silu(h @ lp["wg"].astype(h.dtype))
-    up = h @ lp["wi"].astype(h.dtype)
-    return x + (gate * up) @ lp["wo_mlp"].astype(h.dtype)
+        B, q, D = h.shape
+        out, sent, chosen = routed_experts(
+            lp, h.reshape(B * q, D), k=cfg.experts_per_token, scale=cfg.routed_scaling_factor,
+            valid=None if valid is None else valid.reshape(B * q), layer=layer,
+        )
+        out = out.reshape(B, q, D)
+        if "wg_s" in lp:
+            out = out + _swiglu(h, lp["wg_s"], lp["wi_s"], lp["wo_s"])
+        return x + out, sent, chosen.reshape(B, q, -1)
+    from ray_tpu.models.transformer import _moe_mlp
+
+    # LOSSLESS dispatch at inference: capacity_factor=E gives every
+    # token a slot (capacity == T), so routing is per-token and
+    # independent of batch padding — ragged rows behave exactly like
+    # solo rows, and prefill agrees with T=1 decode. Training's
+    # capacity drops (expert_capacity_factor) are an efficiency
+    # approximation that inference deliberately does not replicate.
+    # Aux loss is meaningless at inference and discarded.
+    out, _aux = _moe_mlp(lp, h, float(cfg.num_experts))
+    return x + out, None, None
 
 
 def _cache_attention(q, ck, cv, pos_mask, cfg):
@@ -128,37 +238,120 @@ def _cache_mask(positions, n_keys: int, window: int, key_len=None):
     return mask
 
 
-def _cached_layers(params, x, cache, positions, write, view, cfg, key_len=None):
-    """THE layer stack over a KV cache, dense or paged: x [B, q, D] at
+# A cache may carry, beside its pool leaves, this one: int32 [2, expert layers,
+# E + 3] counters that the layer stack adds each call's routing to, so that a
+# serving engine reads them when asked and not a step. Axis 0: calls that fed
+# one token a row (decode steps), then all others (prefill chunks). Columns
+# 0..E-1: tokens sent to each expert; E: experts touched; E + 1: tokens on the
+# fullest expert; E + 2: calls that routed any token.
+MOE_COUNTS = "moe_counts"
+# And this one: int32 [expert layers, B, S] or [expert layers, num_blocks,
+# block_size], beside each cached token's row the experts that token took in
+# each expert layer, written where its row is written and kept as long (a
+# block found again in a prefix cache brings its tokens' choices with it). One
+# word a token a layer, the k expert ids in ``_expert_bits`` bits each
+# (``unpack_experts``): a minor axis of k would be padded to the TPU's 128
+# lanes. What a rollout hands its trainer for routing replay, and what the
+# benchmark's float32 reference is held to where two scores nearly tie.
+MOE_CHOICE = "moe_choice"
+
+
+def _pool_leaves(cache: dict) -> dict:
+    """The cache without its counters and its record of expert choices: the
+    leaves that hold what attention reads."""
+    return {name: leaf for name, leaf in cache.items() if name not in (MOE_COUNTS, MOE_CHOICE)}
+
+
+def init_moe_counts(cfg: TransformerConfig):
+    return jnp.zeros((2, cfg.n_layers - cfg.first_dense_layers, cfg.num_experts + 3), jnp.int32)
+
+
+def _expert_bits(cfg: TransformerConfig) -> int:
+    bits = max(1, (cfg.num_experts - 1).bit_length())
+    if bits * cfg.experts_per_token > 31:
+        raise ValueError(
+            f"{cfg.experts_per_token} experts a token of {cfg.num_experts} do not fit one int32 word of {MOE_CHOICE}"
+        )
+    return bits
+
+
+def init_moe_choice(cfg: TransformerConfig, *rows: int):
+    """``rows``: (batch, max_len) beside ``init_cache``, (num_blocks,
+    block_size) beside ``init_paged_cache``."""
+    _expert_bits(cfg)
+    return jnp.zeros((cfg.n_layers - cfg.first_dense_layers, *rows), jnp.int32)
+
+
+def unpack_experts(words, cfg: TransformerConfig):
+    """Words [...] of a ``MOE_CHOICE`` leaf -> expert ids [..., k] (NumPy or jax)."""
+    bits = _expert_bits(cfg)
+    return (words[..., None] >> (bits * np.arange(cfg.experts_per_token))) & ((1 << bits) - 1)
+
+
+def _cached_layers(params, x, cache, positions, write, view, cfg, key_len=None, valid=None):
+    """THE layer stack over a cache, dense or paged: x [B, q, D] at
     ``positions`` [B, q] -> (final normed hidden states, cache).
 
     The whole cache rides the layer scan as its CARRY (never xs -> ys, which
     are distinct buffers of the loop) and a layer reaches its part through
-    the layer index: ``write(c, l, rows)`` puts this chunk's k or v rows
-    [B, q, KV, Dh] into layer l, ``view(c, l)`` takes the rows [B, S, KV, Dh]
-    to attend over. A caller that donates ``cache`` gets it updated in place.
+    the layer index: ``write(c, l, rows)`` puts this chunk's rows [B, q, ...]
+    of one leaf into layer l, ``view(c, l)`` takes the rows [B, S, ...] to
+    attend over. A caller that donates ``cache`` gets it updated in place.
     Masked (p == 0) entries contribute nothing, so stale rows past a
-    position, padding and null-block garbage stay invisible."""
+    position, padding and null-block garbage stay invisible. The leading
+    dense layers (``params["dense_layers"]``) run first, in a scan of their
+    own, then ``params["layers"]``: a layer's index into the cache counts
+    through both. ``valid`` [B, q]: the rows that are real tokens (read by
+    routed experts only)."""
     B, q = positions.shape
+    counts = cache.get(MOE_COUNTS)
+    pool = {name: leaf for name, leaf in cache.items() if name != MOE_COUNTS}
+    latent = cfg.latent_attention
 
-    def body(carry, layer):
-        x, ck, cv = carry
+    def body(first, held, carry, layer):
+        x, pool = carry
         lp, l = layer
-        qh, k, v = _project_qkv(lp, x, positions, cfg)
-        ck = write(ck, l, k)
-        cv = write(cv, l, v)
-        ck_l, cv_l = view(ck, l), view(cv, l)
-        mask = _cache_mask(positions, ck_l.shape[1], cfg.sliding_window, key_len)
-        o = _cache_attention(qh, ck_l, cv_l, mask, cfg)
-        x = x + o.reshape(B, q, -1) @ lp["wo"].astype(o.dtype)
-        x = _mlp(lp, x, cfg)
-        return (x, ck, cv), None
+        lp = {**lp, **held}
+        qh, rows = (_project_latent if latent else _project_qkv)(lp, x, positions, cfg)
+        pool = {**pool, **{name: write(pool[name], l, row) for name, row in rows.items()}}
+        with jax.named_scope("cache_attention"):
+            seen = {name: view(pool[name], l) for name in rows}
+            n_keys = next(iter(seen.values())).shape[1]
+            mask = _cache_mask(positions, n_keys, cfg.sliding_window, key_len)
+            if latent:
+                o = _latent_attention(lp, qh, seen, mask, cfg)
+            else:
+                o = _cache_attention(qh, seen["k"], seen["v"], mask, cfg).reshape(B, q, -1)
+        x = x + o @ lp["wo"].astype(o.dtype)
+        x, sent, chosen = _mlp(lp, x, cfg, valid, layer=l - first if held else None)
+        if chosen is not None and MOE_CHOICE in pool:
+            words = jnp.sum(chosen << (_expert_bits(cfg) * jnp.arange(chosen.shape[-1])), axis=-1)
+            pool = {**pool, MOE_CHOICE: write(pool[MOE_CHOICE], l - first, words)}
+        return (x, pool), sent
 
-    layer_ids = jnp.arange(cache["k"].shape[0], dtype=jnp.int32)
-    (x, ks, vs), _ = lax.scan(
-        body, (x, cache["k"], cache["v"]), (params["layers"], layer_ids)
-    )
-    return _rms_norm(x, params["norm_f"], cfg.norm_eps), {"k": ks, "v": vs}
+    first, sent = 0, None
+    for name in ("dense_layers", "layers"):
+        if name in params:
+            stack = params[name]
+            depth = stack["attn_norm"].shape[0]
+            # Routed experts' matrices stay whole, outside the scanned leaves:
+            # the TPU's compiler copies a scan's slice of them before the
+            # grouped matmul reads it, a layer's 64 experts every layer of
+            # every step (tests/test_tpu_lowering.py holds both halves: the
+            # slice is copied, the whole stack is not; when the first fails,
+            # ``held`` and ``routed_experts(layer=)`` can go).
+            held = {n: stack[n] for n in _EXPERT_STACKS if cfg.routed_experts and n in stack}
+            sliced = {n: leaf for n, leaf in stack.items() if n not in held} if held else stack
+            layer_ids = jnp.arange(first, first + depth, dtype=jnp.int32)
+            (x, pool), sent = lax.scan(partial(body, first, held), (x, pool), (sliced, layer_ids))
+            first += depth
+    if counts is not None:  # sent [expert layers, E]: the routed stack's, which runs last
+        touched = jnp.sum(sent > 0, axis=-1, keepdims=True)
+        step = jnp.concatenate(
+            [sent, touched, jnp.max(sent, axis=-1, keepdims=True), jnp.minimum(touched, 1)], axis=-1
+        )
+        pool[MOE_COUNTS] = counts.at[0 if q == 1 else 1].add(step.astype(counts.dtype))
+    return _rms_norm(x, params["norm_f"], cfg.norm_eps), pool
 
 
 def last_row_logits(params, x, row):
@@ -169,12 +362,14 @@ def last_row_logits(params, x, row):
 
 
 def _dense_write(pos, positions):
-    """Into a dense cache [L, B, S, KV, Dh] at row ``pos``: a scalar (aligned
+    """Into a dense cache leaf [L, B, S, ...] at row ``pos``: a scalar (aligned
     batch) is one dynamic_update_slice, ``pos`` [B] one scatter of every fed
     row to its own ``positions`` [B, q]. Its view is the layer, ``c[l]``
     (prefill: the first T rows of it)."""
     if pos.ndim == 0:
-        return lambda c, l, rows: lax.dynamic_update_slice(c, rows[None], (l, 0, pos, 0, 0))
+        return lambda c, l, rows: lax.dynamic_update_slice(
+            c, rows[None], (l, 0, pos) + (0,) * (c.ndim - 3)
+        )
     batch = jnp.arange(positions.shape[0], dtype=jnp.int32)[:, None]
     return lambda c, l, rows: c.at[l, batch, positions].set(rows)
 
@@ -203,7 +398,10 @@ def prefill(params, tokens, cache, cfg: TransformerConfig, prompt_lens=None):
     # cache is not written yet; scoring it would waste S/T the FLOPs/HBM.
     # Causal within the prompt; per-row padding invisible.
     write, view = _dense_write(pos, positions), lambda c, l: c[l][:, :T]
-    x, cache = _cached_layers(params, x, cache, positions, write, view, cfg, key_len=prompt_lens)
+    x, cache = _cached_layers(
+        params, x, cache, positions, write, view, cfg, key_len=prompt_lens,
+        valid=positions < prompt_lens[:, None] if cfg.routed_experts else None,
+    )
     return last_row_logits(params, x, prompt_lens - 1), cache, prompt_lens
 
 
@@ -268,20 +466,21 @@ def decode_step(params, token, cache, pos, cfg: TransformerConfig):
 
 
 def init_paged_cache(cfg: TransformerConfig, num_blocks: int, block_size: int):
-    """Block-pool KV cache for continuous-batching serving: k/v of shape
-    [L, num_blocks, block_size, KV, Dh]. Physical block 0 is RESERVED as the
-    null block — allocators must never hand it out. Inactive decode slots and
-    write-masked prefill padding rows are routed there, so the compiled step
-    never needs a dynamic shape or a conditional write."""
-    shape = (cfg.n_layers, num_blocks, block_size, cfg.n_kv_heads, cfg.head_dim)
+    """Block-pool cache for continuous-batching serving, every leaf
+    [L, num_blocks, block_size, ...] (``_cache_rows``): k/v [.., KV, Dh], or with
+    latent attention the one leaf ckv [.., the latent and the rotary key].
+    Physical block 0 is RESERVED as the null block — allocators must never
+    hand it out. Inactive decode slots and write-masked prefill padding rows
+    are routed there, so the compiled step never needs a dynamic shape or a
+    conditional write."""
     return {
-        "k": jnp.zeros(shape, cfg.dtype),
-        "v": jnp.zeros(shape, cfg.dtype),
+        name: jnp.zeros((cfg.n_layers, num_blocks, block_size, *row), cfg.dtype)
+        for name, row in _cache_rows(cfg).items()
     }
 
 
 def _paged_write(block_tables, positions, valid_to, block_size: int):
-    """Into a block pool [L, N, Bs, KV, Dh] through the physical write
+    """Into a block pool leaf [L, N, Bs, ...] through the physical write
     coordinates of every fed row (computed once, reused per layer).
     Out-of-table positions clamp to the last entry; engines validate lengths
     so this only guards compiler-visible bounds."""
@@ -295,9 +494,9 @@ def _paged_write(block_tables, positions, valid_to, block_size: int):
 
 
 def _paged_view(block_tables):
-    """Each row's logical cache [B, n_max * Bs, KV, Dh], gathered through its
+    """Each row's logical cache [B, n_max * Bs, ...], gathered through its
     block table with the layer index in the same indexing op: no
-    [N, Bs, KV, Dh] layer slice is materialised."""
+    [N, Bs, ...] layer slice is materialised."""
     B, n_max = block_tables.shape
     return lambda c, l: c[l, block_tables].reshape(B, n_max * c.shape[2], *c.shape[3:])
 
@@ -312,8 +511,18 @@ def paged_decode_chunk_hidden(
     pos = jnp.asarray(pos, jnp.int32)
     block_tables = jnp.asarray(block_tables, jnp.int32)
     x, positions = _embed_chunk(params, tokens, pos, cfg)
-    write = _paged_write(block_tables, positions, valid_to, cache["k"].shape[2])
-    return _cached_layers(params, x, cache, positions, write, _paged_view(block_tables), cfg)
+    block_size = next(iter(_pool_leaves(cache).values())).shape[2]
+    write = _paged_write(block_tables, positions, valid_to, block_size)
+    valid = None
+    if cfg.routed_experts:
+        # A live row's table starts at a real block; an inactive slot's, and
+        # nothing else's, at the null block.
+        valid = jnp.broadcast_to(block_tables[:, :1] != 0, positions.shape)
+        if valid_to is not None:
+            valid &= positions < jnp.asarray(valid_to, jnp.int32)[:, None]
+    return _cached_layers(
+        params, x, cache, positions, write, _paged_view(block_tables), cfg, valid=valid
+    )
 
 
 def paged_decode_chunk(

@@ -26,7 +26,7 @@ from __future__ import annotations
 import dataclasses
 import math
 from functools import partial
-from typing import Any
+from typing import Any, NamedTuple
 
 import jax
 import jax.numpy as jnp
@@ -46,9 +46,33 @@ class TransformerConfig:
     norm_eps: float = 1e-5
     dtype: Any = jnp.bfloat16
     param_dtype: Any = jnp.float32
-    # MoE: 0 = dense MLP; >0 = experts sharded over ep.
+    # MoE: 0 = dense MLP; >0 = experts sharded over ep. Without
+    # ``experts_per_token`` it is the Switch layer (top-1, two-matrix GELU
+    # experts with a capacity: parallel/moe.moe_layer), which trains.
     num_experts: int = 0
     expert_capacity_factor: float = 1.25
+    # Dropless routed experts (parallel/moe.routed_experts; inference only):
+    # ``experts_per_token`` of ``num_experts`` SwiGLU experts of width
+    # ``d_expert`` a token, chosen by sigmoid scores plus a bias that chooses
+    # and does not weigh, their weights normalised and scaled by
+    # ``routed_scaling_factor``; ``num_shared_experts`` more of that width
+    # see every token. The first ``first_dense_layers`` layers keep the dense
+    # MLP of width ``d_ff`` and are stacked apart (``params["dense_layers"]``).
+    experts_per_token: int = 0
+    d_expert: int = 0
+    num_shared_experts: int = 0
+    routed_scaling_factor: float = 1.0
+    first_dense_layers: int = 0
+    # Latent attention (MLA; inference only), selected by ``kv_lora_rank`` > 0:
+    # queries through a ``q_lora_rank`` bottleneck; keys and values expanded
+    # per head from one normed latent of ``kv_lora_rank`` a token, beside one
+    # rotary key of ``qk_rope_head_dim`` shared by all heads. The cache holds
+    # the latent and the rotary key, not keys and values (models/generate.py).
+    q_lora_rank: int = 0
+    kv_lora_rank: int = 0
+    qk_nope_head_dim: int = 0
+    qk_rope_head_dim: int = 0
+    v_head_dim: int = 0
     remat: bool = True
     tie_embeddings: bool = False
     # Mistral-style sliding-window causal attention (0 = full causal):
@@ -66,9 +90,32 @@ class TransformerConfig:
     def head_dim(self) -> int:
         return self.d_model // self.n_heads
 
+    @property
+    def latent_attention(self) -> bool:
+        return self.kv_lora_rank > 0
 
-# Elements _draw_normal makes per loop iteration.
+    @property
+    def routed_experts(self) -> bool:
+        return self.experts_per_token > 0
+
+    @property
+    def inference_only(self) -> str:
+        """What of this configuration the training path lacks ('' if nothing)."""
+        missing = []
+        if self.latent_attention:
+            missing.append("latent attention (kv_lora_rank > 0) has no training block")
+        if self.routed_experts:
+            missing.append(
+                "dropless routed experts (experts_per_token > 0) have no backward "
+                "pass, balance loss or ep sharding"
+            )
+        return "; ".join(missing)
+
+
+# Elements _draw_normal makes per loop iteration, and the largest slice it
+# draws in one piece.
 _DRAW_ELEMENTS = 1 << 22
+_DRAW_SLICE_MAX = 1 << 27
 
 
 @partial(jax.jit, static_argnames=("shape", "scale", "dtype"))
@@ -79,105 +126,164 @@ def _draw_normal(key, shape, scale, dtype):
     (draw, scaled copy), beside the weights already made. Drawn whole under
     jit it fits, but each such program takes the TPU compiler 7-14 s (v5e,
     PR 21: 116 s for the nine leaves of 16 Mistral-7B layers, which then run
-    in 0.03 s each); the loop body compiles in about a second at any depth."""
-    rest = shape[1:]
+    in 0.03 s each); the loop body compiles in about a second at any depth.
+
+    Two things keep that second a second (PR 32, timed with the TPU's compiler
+    off the chip): small slices are drawn several at a time, and that number
+    DIVIDES the number of slices, because a remainder is a second loop body
+    (the [4096, 32000] head compiled for 16 s with 131 at a time, for 0.5 s
+    with 128; the values are the same, each slice has its own key); and a leaf
+    whose slices are larger than ``_DRAW_SLICE_MAX`` elements ([7, 64, 1536,
+    2048]: 7 s) is drawn over its leading axes together."""
+    lead = 1
+    while len(shape) - lead > 2 and math.prod(shape[lead:]) > _DRAW_SLICE_MAX:
+        lead += 1
+    n, rest = math.prod(shape[:lead]), shape[lead:]
 
     def draw(k):
         return (jax.random.normal(k, rest) * scale).astype(dtype)
 
-    return lax.map(
-        draw,
-        jax.random.split(key, shape[0]),
-        batch_size=max(1, _DRAW_ELEMENTS // math.prod(rest)),
+    fit = min(n, max(1, _DRAW_ELEMENTS // math.prod(rest)))
+    at_a_time = next(b for b in range(fit, 0, -1) if n % b == 0)
+    return lax.map(draw, jax.random.split(key, n), batch_size=at_a_time).reshape(shape)
+
+
+class _Leaf(NamedTuple):
+    """How one leaf of a layer is made and sharded."""
+
+    key: Any  # index into the stack's keys; None: a norm weight, ones
+    shape: tuple
+    scale: Any  # of the normal draw; None with ``key``
+    axes: tuple  # logical axis names (parallel/mesh.logical_to_spec)
+    dtype: Any = None  # None: the configuration's ``param_dtype``
+
+
+def _layer_leaves(cfg: TransformerConfig, mlp: str) -> dict:
+    """One layer's leaves by name. ``mlp`` is ``"dense"``, ``"switch"`` or
+    ``"routed"``. Stacked, every leaf gains a leading layer axis."""
+    D, H, KV, Dh, F, E = (
+        cfg.d_model, cfg.n_heads, cfg.n_kv_heads, cfg.head_dim, cfg.d_ff, cfg.num_experts
     )
+    s = D**-0.5
+    out = (2 * cfg.n_layers) ** -0.5  # residual branches shrink with depth
+    leaves = {
+        "attn_norm": _Leaf(None, (D,), None, (None,)),
+        "mlp_norm": _Leaf(None, (D,), None, (None,)),
+    }
+    if cfg.latent_attention:
+        R, Rq = cfg.kv_lora_rank, cfg.q_lora_rank
+        N, P, Vd = cfg.qk_nope_head_dim, cfg.qk_rope_head_dim, cfg.v_head_dim
+        leaves.update(
+            {
+                "wq_a": _Leaf(0, (D, Rq), s, ("embed", None)),
+                "q_norm": _Leaf(None, (Rq,), None, (None,)),
+                "wq_b": _Leaf(1, (Rq, H * (N + P)), Rq**-0.5, (None, "heads")),
+                "wkv_a": _Leaf(2, (D, R + P), s, ("embed", None)),
+                "kv_norm": _Leaf(None, (R,), None, (None,)),
+                "wkv_b": _Leaf(8, (R, H * (N + Vd)), R**-0.5, (None, "heads")),
+                "wo": _Leaf(3, (H * Vd, D), (H * Vd) ** -0.5 * out, ("heads", "embed")),
+            }
+        )
+    else:
+        leaves.update(
+            {
+                "wq": _Leaf(0, (D, H * Dh), s, ("embed", "heads")),
+                "wk": _Leaf(1, (D, KV * Dh), s, ("embed", "kv")),
+                "wv": _Leaf(2, (D, KV * Dh), s, ("embed", "kv")),
+                "wo": _Leaf(3, (H * Dh, D), s * out, ("heads", "embed")),
+            }
+        )
+    if mlp == "dense":
+        leaves.update(
+            {
+                "wi": _Leaf(5, (D, F), s, ("embed", "mlp")),
+                "wg": _Leaf(6, (D, F), s, ("embed", "mlp")),
+                "wo_mlp": _Leaf(7, (F, D), F**-0.5 * out, ("mlp", "embed")),
+            }
+        )
+        return leaves
+    if mlp == "routed":
+        F = cfg.d_expert
+        Fs = cfg.num_shared_experts * F
+        # The router stays float32 whatever the weights' dtype, as published:
+        # a near-tie between two experts must not turn on a rounding.
+        leaves.update(
+            {
+                "gate": _Leaf(4, (D, E), s, ("embed", None), jnp.float32),
+                "gate_bias": _Leaf(9, (E,), 0.01, (None,), jnp.float32),
+            }
+        )
+        if Fs:
+            leaves.update(
+                {
+                    "wi_s": _Leaf(10, (D, Fs), s, ("embed", "mlp")),
+                    "wg_s": _Leaf(11, (D, Fs), s, ("embed", "mlp")),
+                    "wo_s": _Leaf(12, (Fs, D), Fs**-0.5 * out, ("mlp", "embed")),
+                }
+            )
+    else:
+        leaves["gate"] = _Leaf(4, (D, E), s, ("embed", None))
+    leaves.update(
+        {
+            "wi_e": _Leaf(5, (E, D, F), s, ("expert", "embed", "mlp")),
+            "wg_e": _Leaf(6, (E, D, F), s, ("expert", "embed", "mlp")),
+            "wo_e": _Leaf(7, (E, F, D), F**-0.5 * out, ("expert", "mlp", "embed")),
+        }
+    )
+    return leaves
+
+
+def _layer_stacks(cfg: TransformerConfig) -> dict:
+    """``params`` key -> (depth, kind of MLP) of each stack of layers, in the
+    order they run: the leading dense layers (where the configuration has
+    any), then ``"layers"``."""
+    mlp = "routed" if cfg.routed_experts else "switch" if cfg.num_experts > 0 else "dense"
+    n_dense = cfg.first_dense_layers
+    stacks = {"dense_layers": (n_dense, "dense")} if n_dense else {}
+    stacks["layers"] = (cfg.n_layers - n_dense, mlp)
+    return stacks
 
 
 def init_params(key, cfg: TransformerConfig) -> dict:
     ks = jax.random.split(key, 10)
-    D, H, KV, Dh, F, L, V = (
-        cfg.d_model,
-        cfg.n_heads,
-        cfg.n_kv_heads,
-        cfg.head_dim,
-        cfg.d_ff,
-        cfg.n_layers,
-        cfg.vocab_size,
-    )
-    dt = cfg.param_dtype
-    s = D**-0.5
+    D, V, dt = cfg.d_model, cfg.vocab_size, cfg.param_dtype
+    # Two key schedules, because one cannot serve both: ``ks`` has ten keys,
+    # eight of them the leaves of the one GQA / Switch stack, and every seeded
+    # value in the tests and the benchmark's accepted cells comes from it (a
+    # seed's weights are the same before and after this change: checked value
+    # for value against the parent's). A latent or routed stack has up to
+    # sixteen leaves and there may be two stacks, more than ``ks`` holds: each
+    # stack then splits sixteen keys of its own from ``fold_in(key, stack)``.
+    own_keys = cfg.latent_attention or cfg.routed_experts or cfg.first_dense_layers > 0
 
-    def norm(k, shape, scale):
-        return _draw_normal(k, shape, scale, dt)
+    def stack(i, L, mlp):
+        keys = jax.random.split(jax.random.fold_in(key, i), 16) if own_keys else ks
+        return {
+            name: jnp.ones((L, *leaf.shape), leaf.dtype or dt)
+            if leaf.key is None
+            else _draw_normal(keys[leaf.key], (L, *leaf.shape), leaf.scale, leaf.dtype or dt)
+            for name, leaf in _layer_leaves(cfg, mlp).items()
+        }
 
-    layers = {
-        "attn_norm": jnp.ones((L, D), dt),
-        "wq": norm(ks[0], (L, D, H * Dh), s),
-        "wk": norm(ks[1], (L, D, KV * Dh), s),
-        "wv": norm(ks[2], (L, D, KV * Dh), s),
-        "wo": norm(ks[3], (L, H * Dh, D), s * (2 * L) ** -0.5),
-        "mlp_norm": jnp.ones((L, D), dt),
-    }
-    if cfg.num_experts > 0:
-        E = cfg.num_experts
-        layers.update(
-            {
-                "gate": norm(ks[4], (L, D, E), s),
-                "wi_e": norm(ks[5], (L, E, D, F), s),
-                "wg_e": norm(ks[6], (L, E, D, F), s),
-                "wo_e": norm(ks[7], (L, E, F, D), F**-0.5 * (2 * L) ** -0.5),
-            }
-        )
-    else:
-        layers.update(
-            {
-                "wi": norm(ks[5], (L, D, F), s),
-                "wg": norm(ks[6], (L, D, F), s),
-                "wo_mlp": norm(ks[7], (L, F, D), F**-0.5 * (2 * L) ** -0.5),
-            }
-        )
     params = {
-        "embed": norm(ks[8], (V, D), 1.0),
-        "layers": layers,
+        "embed": _draw_normal(ks[8], (V, D), 1.0, dt),
         "norm_f": jnp.ones((D,), dt),
     }
+    for i, (name, (L, mlp)) in enumerate(_layer_stacks(cfg).items()):
+        params[name] = stack(i, L, mlp)
     if not cfg.tie_embeddings:
-        params["lm_head"] = norm(ks[9], (D, V), s)
+        params["lm_head"] = _draw_normal(ks[9], (D, V), D**-0.5, dt)
     return params
 
 
 def param_logical_axes(cfg: TransformerConfig) -> dict:
     """Per-leaf logical axis names (mapped to mesh axes by
     parallel/mesh.logical_to_spec)."""
-    layers = {
-        "attn_norm": ("layers", None),
-        "wq": ("layers", "embed", "heads"),
-        "wk": ("layers", "embed", "kv"),
-        "wv": ("layers", "embed", "kv"),
-        "wo": ("layers", "heads", "embed"),
-        "mlp_norm": ("layers", None),
-    }
-    if cfg.num_experts > 0:
-        layers.update(
-            {
-                "gate": ("layers", "embed", None),
-                "wi_e": ("layers", "expert", "embed", "mlp"),
-                "wg_e": ("layers", "expert", "embed", "mlp"),
-                "wo_e": ("layers", "expert", "mlp", "embed"),
-            }
-        )
-    else:
-        layers.update(
-            {
-                "wi": ("layers", "embed", "mlp"),
-                "wg": ("layers", "embed", "mlp"),
-                "wo_mlp": ("layers", "mlp", "embed"),
-            }
-        )
-    axes = {
-        "embed": ("vocab", "embed"),
-        "layers": layers,
-        "norm_f": (None,),
-    }
+    axes = {"embed": ("vocab", "embed"), "norm_f": (None,)}
+    for name, (_, mlp) in _layer_stacks(cfg).items():
+        axes[name] = {
+            name: ("layers", *leaf.axes) for name, leaf in _layer_leaves(cfg, mlp).items()
+        }
     if not cfg.tie_embeddings:
         axes["lm_head"] = ("embed", "vocab")
     return axes
@@ -287,6 +393,14 @@ def _layer(lp, x, rope_cs, cfg: TransformerConfig, mesh, attn_impl: str):
     return x, aux
 
 
+def _refuse_inference_only(cfg: TransformerConfig, what: str):
+    if cfg.inference_only:
+        raise NotImplementedError(
+            f"{what} cannot run this configuration: {cfg.inference_only}. "
+            "It is served through models/generate.py (prefill and decode over a cache) only."
+        )
+
+
 def forward_hidden(
     params: dict,
     tokens,
@@ -295,6 +409,7 @@ def forward_hidden(
     attn_impl: str = "auto",
 ):
     """tokens [B, T] int32 -> (final hidden [B, T, D], moe aux)."""
+    _refuse_inference_only(cfg, "forward_hidden")
     B, T = tokens.shape
     x = params["embed"].astype(cfg.dtype)[tokens]
     positions = jnp.broadcast_to(jnp.arange(T, dtype=jnp.int32)[None], (B, T))
@@ -360,6 +475,7 @@ def make_train_step(cfg: TransformerConfig, optimizer, mesh=None, attn_impl: str
     are averaged over the batch; under a dp/fsdp-sharded batch pjit inserts
     the psum automatically.
     """
+    _refuse_inference_only(cfg, "make_train_step")
 
     def train_step(params, opt_state, batch):
         loss, grads = jax.value_and_grad(loss_fn)(params, batch, cfg, mesh, attn_impl)

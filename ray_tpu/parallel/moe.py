@@ -76,3 +76,58 @@ def init_moe_params(key, d_model: int, d_ff: int, num_experts: int, dtype=jnp.fl
         "wi": jax.random.normal(k2, (num_experts, d_model, d_ff), dtype) * scale,
         "wo": jax.random.normal(k3, (num_experts, d_ff, d_model), dtype) * (d_ff**-0.5),
     }
+
+
+def routed_experts(params, x, *, k: int, scale: float = 1.0, valid=None, layer=None):
+    """Dropless top-``k`` routing over SwiGLU experts: every token reaches its
+    ``k`` experts, whatever the load. No capacity, so no ``[tokens, E, C]``
+    tensor: assignments are sorted by expert and the three matmuls run
+    grouped (``jax.lax.ragged_dot``), each touched expert's weights read once.
+
+    params: ``gate`` [D, E] and ``gate_bias`` [E] (float32), ``wg_e`` / ``wi_e``
+    [E, D, F], ``wo_e`` [E, F, D]. x: [N, D]. The router runs in float32:
+    ``s = sigmoid(x gate)``; the ``k`` experts with the largest ``s + gate_bias``
+    are chosen (the bias chooses, it does not weigh), weighted
+    ``scale * s / sum(s over the chosen)``. ``valid`` [N] bool (optional):
+    rows that are padding; they reach no expert, add zeros, and are not counted.
+
+    ``layer`` (a traced int32, optional): the three expert leaves are then
+    whole STACKS ``[L, E, ...]`` and this call runs layer ``layer`` of them, as
+    groups ``layer * E .. layer * E + E - 1`` of ``L * E`` (the others empty).
+    A layer scan passes its expert stacks this way instead of slicing them:
+    a slice that feeds a grouped matmul is materialised, all E experts of the
+    layer copied every step for a kernel that reads the few it touches.
+
+    Returns (out [N, D] in x's dtype, assignments [E] int32: tokens sent to
+    each expert, chosen [N, k] int32: each row's experts, padding rows' too)."""
+    N, D = x.shape
+    E = params["gate"].shape[-1]
+    s = jax.nn.sigmoid(x.astype(jnp.float32) @ params["gate"].astype(jnp.float32))  # [N, E]
+    _, chosen = jax.lax.top_k(s + params["gate_bias"].astype(jnp.float32), k)  # [N, k]
+    w = jnp.take_along_axis(s, chosen, axis=-1)
+    w = scale * w / jnp.sum(w, axis=-1, keepdims=True)
+    expert = chosen.reshape(N * k)
+    if valid is not None:
+        # Past the last expert: sorted behind every group, in none of them.
+        expert = jnp.where(jnp.repeat(valid, k), expert, E)
+    order = jnp.argsort(expert)  # stable: assignment ids grouped by expert
+    sizes = jnp.zeros((E,), jnp.int32).at[expert].add(1, mode="drop")
+    xs = x[order // k]  # [N * k, D]
+    groups = sizes
+    if layer is not None:
+        stacked = params["wg_e"].shape[0]
+        groups = jax.lax.dynamic_update_slice(jnp.zeros((stacked * E,), jnp.int32), sizes, (layer * E,))
+
+    def grouped(a, w_e):
+        w_e = w_e.reshape(-1, *w_e.shape[-2:])  # [L, E, in, out] -> [L * E, in, out]: no copy
+        return jax.lax.ragged_dot(a, w_e.astype(a.dtype), groups)
+
+    with jax.named_scope("moe_experts"):
+        h = jax.nn.silu(grouped(xs, params["wg_e"])) * grouped(xs, params["wi_e"])
+        ys = grouped(h, params["wo_e"])  # [N * k, D], rows of no group are zero
+    # Un-sort by gather (assignment a sits at sorted row inverse[a]), combine in float32.
+    inverse = jnp.zeros((N * k,), jnp.int32).at[order].set(jnp.arange(N * k, dtype=jnp.int32))
+    y = ys[inverse].reshape(N, k, D).astype(jnp.float32)
+    if valid is not None:
+        w = jnp.where(valid[:, None], w, 0.0)
+    return jnp.einsum("nk,nkd->nd", w, y).astype(x.dtype), sizes, chosen

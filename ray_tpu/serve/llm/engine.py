@@ -134,6 +134,9 @@ class LLMRequest:
         # (dict) a decode-pool replica continues from; None on engines that
         # decode their own requests.
         self.handoff: Optional[dict] = None
+        # submit(return_routed_experts=True): filled as the request completes.
+        self.return_routed_experts = False
+        self.routed_experts: Optional[np.ndarray] = None
         self.t_recv: Optional[float] = None  # the proxy's stamp, same clock
         self.t_submit = time.monotonic()
         self.t_admit: Optional[float] = None  # first admission
@@ -326,6 +329,14 @@ def _compiled_fns(cfg):
         return fns
 
 
+# The transfer plane's payload is [2, L, blocks, Bs, KV, Dh]: keys and values
+# (kv_transfer.seal_kv_payload, LLMEngine._scatter_import; ROADMAP D5).
+_LATENT_POOL_KV_PAYLOAD = (
+    "{what} needs the KV transfer plane, whose payload layout is keys and values; "
+    "a latent-attention pool holds one latent row a token (kv_transfer.py, ROADMAP D5)"
+)
+
+
 class _PrefixEntry:
     __slots__ = ("bid", "refs", "stamp")
 
@@ -351,10 +362,22 @@ class LLMEngine:
         cluster_prefix_max: int = 16,
         handoff_ttl_s: float = 120.0,
     ):
-        from ray_tpu.models.generate import init_paged_cache
+        from ray_tpu.models.generate import (
+            MOE_CHOICE,
+            MOE_COUNTS,
+            init_moe_choice,
+            init_moe_counts,
+            init_paged_cache,
+        )
 
         if role not in ("both", "prefill", "decode"):
             raise ValueError(f"role must be both|prefill|decode, got {role!r}")
+        if cfg.latent_attention and (role != "both" or cluster_prefix):
+            raise ValueError(
+                _LATENT_POOL_KV_PAYLOAD.format(
+                    what=f"role={role!r}" if role != "both" else "cluster_prefix=True"
+                )
+            )
         self.params = params
         self.cfg = cfg
         # Disaggregation role (ISSUE 20). "prefill": requests terminate at
@@ -395,9 +418,22 @@ class LLMEngine:
         t0 = time.monotonic()
         import jax
 
-        self._cache = jax.block_until_ready(
-            init_paged_cache(cfg, self.num_blocks, self.block_size)
+        pool = init_paged_cache(cfg, self.num_blocks, self.block_size)
+        # Bytes one token of context holds in the pool, all layers.
+        self.kv_token_bytes = sum(
+            leaf.nbytes // (self.num_blocks * self.block_size) for leaf in pool.values()
         )
+        if cfg.routed_experts:
+            # Expert counters ride the pool through both programs, donated
+            # with it and updated on the device; ``stats()`` reads them.
+            pool[MOE_COUNTS] = init_moe_counts(cfg)
+            # And beside each cached token the experts it took (one word a
+            # token a layer), for ``submit(return_routed_experts=True)``.
+            pool[MOE_CHOICE] = init_moe_choice(cfg, self.num_blocks, self.block_size)
+        self._cache = jax.block_until_ready(pool)
+        self._moe_wanted: Optional[threading.Event] = None
+        self._moe_asking = threading.Lock()  # one asker at a time
+        self._moe_read = None  # the counters as last read, a NumPy array
         self.spans.setup["pool_s"] = time.monotonic() - t0
         # Block 0 is the reserved null block — never handed out.
         self._free: list[int] = list(range(self.num_blocks - 1, 0, -1))
@@ -472,8 +508,15 @@ class LLMEngine:
         kv_import=None,
         request_id: str = "",
         t_recv_ns: int = 0,
+        return_routed_experts: bool = False,
     ) -> LLMRequest:
-        """``request_id`` / ``t_recv_ns``: the proxy's identifier of this
+        """``return_routed_experts`` (routed-expert models): a request that
+        completes leaves in ``req.routed_experts`` the experts each token fed
+        to the model took, int [prompt + generated - 1, expert layers, k] (the
+        last token drawn is never fed): what a rollout hands its trainer to
+        replay the routing, read from the pool once, as the request ends.
+
+        ``request_id`` / ``t_recv_ns``: the proxy's identifier of this
         request and its CLOCK_MONOTONIC stamp of receiving it (both ride the
         headers it forwards); they go into the request's record.
 
@@ -518,6 +561,9 @@ class LLMEngine:
             f"llm-{next(self._rid)}", tokens, max_new_tokens, temperature, top_k, seed
         )
         req._sched_generated = resume
+        if return_routed_experts and not self.cfg.routed_experts:
+            raise ValueError("return_routed_experts needs a model with routed experts")
+        req.return_routed_experts = bool(return_routed_experts)
         req.request_id = str(request_id or "")
         req.t_recv = int(t_recv_ns) / 1e9 if t_recv_ns else None
         ctx = tracing.get_current_span_context()
@@ -538,6 +584,8 @@ class LLMEngine:
             self.spans.end_request(req, "finished")
             return req
         if kv_import is not None:
+            if self.cfg.latent_attention:
+                raise ValueError(_LATENT_POOL_KV_PAYLOAD.format(what="kv_import"))
             self._attach_handoff_import(req, kv_import)
         elif self.cluster_prefix and not resume and req._sched_hashes:
             self._attach_cluster_prefix(req)
@@ -584,7 +632,10 @@ class LLMEngine:
     @any_thread
     def stats(self) -> dict:
         """Best-effort snapshot (plain-int reads) for tests and benches."""
+        moe = self._moe_stats()
         return {
+            **({"moe": moe} if moe else {}),
+            "kv_token_bytes": self.kv_token_bytes,
             "num_blocks": self.num_blocks - 1,
             "free_blocks": len(self._free),
             "cached_blocks": len(self._prefix),
@@ -598,6 +649,50 @@ class LLMEngine:
             "decode_width_steps": dict(self._width_steps),
             **self.spans.totals(),
         }
+
+    @any_thread
+    def _moe_stats(self) -> Optional[dict]:
+        """The device-side expert counters, fetched now: for ``"decode"``
+        steps and ``"prefill"`` chunks apart, ``steps`` (those that routed a
+        token), per expert layer ``assignments`` [E] (tokens sent to each
+        expert), and the sums over those steps of ``experts_touched`` and of
+        ``fullest_expert_load`` (over ``steps``: a layer's mean a step). Only
+        the scheduler thread may read the pool, which every dispatch donates:
+        it is asked, and answers between two passes."""
+        if not self.cfg.routed_experts:
+            return None
+        with self._moe_asking:
+            if self._thread.is_alive():  # a stopped scheduler answers nothing: the last reading stands
+                asked = threading.Event()
+                self._moe_wanted = asked
+                self._wake.set()
+                asked.wait(1.0)
+            read = self._moe_read
+        if read is None:
+            return None
+        E = self.cfg.num_experts
+        return {
+            kind: {
+                "steps": int(of[0, E + 2]),
+                "assignments": of[:, :E].tolist(),
+                "experts_touched": of[:, E].tolist(),
+                "fullest_expert_load": of[:, E + 1].tolist(),
+            }
+            for kind, of in zip(("decode", "prefill"), read)
+        }
+
+    def _answer_moe_stats(self):
+        asked, self._moe_wanted = self._moe_wanted, None
+        if asked is not None:
+            import jax.numpy as jnp
+
+            from ray_tpu.models.generate import MOE_COUNTS
+
+            # Of a copy made on the device: the host's view of a buffer (on a
+            # CPU backend it IS the buffer) would keep the next dispatch from
+            # donating it.
+            self._moe_read = np.asarray(jnp.copy(self._cache[MOE_COUNTS]))
+            asked.set()
 
     @any_thread
     def shutdown(self, timeout: float = 10.0):
@@ -895,6 +990,7 @@ class LLMEngine:
                     busy = self._decode_tick() or busy
                 finally:
                     spans.end(it)
+                self._answer_moe_stats()
                 if not busy:
                     if any(r is not None for r in self._slots) or self._waiting:
                         self._wake.wait(0.02)
@@ -1132,7 +1228,7 @@ class LLMEngine:
         ends ``_loop``, which marks the engine crashed."""
         pool = self._cache
         drawn, self._cache = fn(self.params, tokens, pool, *rest)
-        if not (pool["k"].is_deleted() and pool["v"].is_deleted()):
+        if not all(leaf.is_deleted() for leaf in pool.values()):
             self._counts["kv_pool_not_donated"] += 1
         return drawn
 
@@ -1236,7 +1332,10 @@ class LLMEngine:
                     rows[req._sched_slot], req, req._sched_generated[-1], req._sched_pos
                 )
             rows = jnp.asarray(rows)
-        spans.carried(rows=len(active), view_blocks=width)
+        spans.carried(
+            rows=len(active), view_blocks=width,
+            context_tokens=sum(r._sched_pos + 1 for r in active),
+        )
         self._width_steps[width] += 1
         with spans.span("llm.decode.dispatch"):
             drawn = self._run_donated(self._decode_fn, rows)
@@ -1278,6 +1377,16 @@ class LLMEngine:
         ):
             return ids[at].tolist()
 
+    def _routed_experts(self, req: LLMRequest) -> np.ndarray:
+        """The experts of every token ``req`` fed, from the words beside its
+        blocks' rows: [tokens fed, expert layers, k]."""
+        from ray_tpu.models.generate import MOE_CHOICE, unpack_experts
+
+        fed = len(req.prompt) + len(req._sched_generated) - 1
+        table = np.asarray(req._sched_table[: -(-fed // self.block_size)], np.int32)
+        words = np.asarray(self._cache[MOE_CHOICE][:, table])  # [expert layers, blocks, Bs]
+        return unpack_experts(words.reshape(words.shape[0], -1)[:, :fed].T, self.cfg)
+
     def _emit_token(self, req: LLMRequest, tok: int):
         req._sched_generated.append(tok)
         req._sched_state = "decode"
@@ -1315,6 +1424,8 @@ class LLMEngine:
         if req._finished:
             return
         req._finished = True
+        if req.return_routed_experts and error is None and not cancelled and handoff is None:
+            req.routed_experts = self._routed_experts(req)
         self._release_blocks(req)
         if req._sched_slot is not None and self._slots[req._sched_slot] is req:
             self._slots[req._sched_slot] = None

@@ -39,6 +39,42 @@ def pytest_configure(config):
     )
 
 
+# tests/benchmark/ belongs to the benchmark (BENCHMARK.json "paths"): a PR that
+# adds a configuration may add files there and may not edit one. A test there
+# that a second architecture makes wrong is marked here until a `benchmark` PR
+# rewords it (PERF.md section 7), and its intent is tested in a new file. The
+# marks are strict: a test that is reworded and passes fails its mark, so that
+# the mark goes with the rewording and cannot outlive it.
+_WRONG_SINCE_A_SECOND_ARCHITECTURE = {
+    "test_bench_costs.py::test_the_configuration_files_hold_the_published_widths": (
+        "holds EVERY configuration of BENCHMARK.json to Mistral-7B's widths; since PR 32 one is "
+        "GLM-4.7-Flash. test_bench_glm.py::test_each_configuration_holds_its_own_published_widths "
+        "holds each to its own"
+    ),
+    "test_bench_span_metrics.py::test_expected_metrics_lists_each_span_metric_for_exactly_its_cells": (
+        "holds that BENCHMARK.json's per_layer ENDS with PR 26's twelve span metrics and that two "
+        "cells report them; PR 32 appends six metrics and two cells. "
+        "test_bench_glm.py::test_the_span_metrics_stand_and_new_cells_are_only_appended stands in"
+    ),
+    "test_bench_span_metrics.py::test_benchmark_json_declares_them_and_the_parked_copy_is_gone": (
+        "holds each span metric's workloads to exactly PR 26's cells; PR 32 appends its two. "
+        "test_bench_glm.py::test_the_span_metrics_stand_and_new_cells_are_only_appended stands in"
+    ),
+    "test_bench_traffic.py::test_two_seeds_offer_the_same_token_load[rollout-long]": (
+        "holds every serving mix under 2560 tokens a request, one configuration's max_model_len; "
+        "rollout-long runs under 4096. test_bench_glm.py::test_every_mix_fits_the_configurations_"
+        "that_run_it holds each mix to its own cells' limits, and the seeds' equal load there too"
+    ),
+}
+
+
+def pytest_collection_modifyitems(config, items):
+    for item in items:
+        for name, why in _WRONG_SINCE_A_SECOND_ARCHITECTURE.items():
+            if item.nodeid.endswith(name):
+                item.add_marker(pytest.mark.xfail(reason=why, strict=True))
+
+
 @pytest.fixture
 def ray_start_regular():
     import ray_tpu

@@ -151,3 +151,108 @@ def test_sharded_train_step_lowers_for_tpu_from_cpu():
     text = lowered.as_text()
     for kernel in ("_flash_kernel", "_flash_bwd_dkv_kernel", "_flash_bwd_dq_kernel"):
         assert f'kernel_name = "{kernel}"' in text
+
+
+@pytest.fixture(scope="module")
+def one_v5e_chip():
+    """One chip of a v5e host, described and not attached: the TPU's compiler
+    compiles for it here (the on-chip-measurement guide, section 2)."""
+    os.environ.setdefault("TPU_LOG_DIR", "disabled")
+    from jax.experimental import topologies
+    from jax.sharding import SingleDeviceSharding
+
+    try:
+        topo = topologies.get_topology_desc(platform="tpu", topology_name="v5e:2x2")
+    except Exception as e:  # noqa: BLE001 — no TPU compiler in this installation
+        pytest.skip(f"no v5e:2x2 topology can be described here: {e}")
+    return SingleDeviceSharding(topo.devices[0])
+
+
+def test_the_latent_decode_step_copies_neither_the_pool_nor_the_expert_stacks(one_v5e_chip):
+    """GLM-4.7-Flash's decode program at the benchmark's widths, compiled for
+    the v5e (PR 32). Two things the compiler did to its first versions, each
+    worth milliseconds a step and invisible on the CPU: with a 576-wide row the
+    pool got a layout of its own and was copied in and out of the layer scan
+    (the row is padded to 640 for that); and the scan's slice of a layer's
+    [64, 2048, 1536] expert matrices was materialised before the grouped
+    matmul (the stacks stay whole for that, a layer is a group offset)."""
+    import re
+
+    import jax
+    import jax.numpy as jnp
+
+    from benchmarks.harness import registry
+    from ray_tpu.models.generate import MOE_CHOICE, MOE_COUNTS, init_moe_choice, init_moe_counts, init_paged_cache
+    from ray_tpu.models.transformer import TransformerConfig, init_params
+    from ray_tpu.serve.llm.engine import _ROW_TABLE, _compiled_fns
+
+    cell = registry.load_cell(registry.load_manifest(), "glm8.rollout-long")
+    engine = cell["config"]["deployment"]["engine"]
+    model = registry.load_architecture(cell, "config").model_config(
+        cell["config"], engine["max_model_len"], "bfloat16"
+    )
+    model.update(dtype=jnp.bfloat16, param_dtype=jnp.bfloat16)
+    cfg = TransformerConfig(**model)
+
+    def described(tree):
+        return jax.tree.map(lambda a: jax.ShapeDtypeStruct(a.shape, a.dtype, sharding=one_v5e_chip), tree)
+
+    def pool():
+        blocks = engine["num_blocks"], engine["block_size"]
+        return {**init_paged_cache(cfg, *blocks), MOE_COUNTS: init_moe_counts(cfg), MOE_CHOICE: init_moe_choice(cfg, *blocks)}
+
+    params = described(jax.eval_shape(lambda: init_params(jax.random.PRNGKey(0), cfg)))
+    rows = jax.ShapeDtypeStruct((engine["num_slots"], _ROW_TABLE + 64), jnp.int32, sharding=one_v5e_chip)
+    compiled = _compiled_fns(cfg)[0].lower(params, rows, described(jax.eval_shape(pool))).compile()
+    text = compiled.as_text()
+    assert "ragged-dot" in text  # the grouped matmul is the TPU's own, not a dense fallback
+    pool_shape = re.escape("bf16[8,8193,16,640]")
+    assert not re.search(rf"= {pool_shape}\S* copy\(", text)
+    assert not re.search(r"= bf16\[64,(2048,1536|1536,2048)\]\S* fusion\(", text)
+    assert not re.search(r"= s32\[7,8193,16\]\S* copy\(", text)  # nor the words of the experts taken
+    # 32 rows at the 1024-token rung: the view (42 MB) and little else, not a pool (1.34 GB) or an expert matrix (0.4 GB)
+    stats = compiled.memory_analysis()
+    assert stats.temp_size_in_bytes < 150e6, stats.temp_size_in_bytes
+    assert stats.alias_size_in_bytes >= 8 * 8193 * 16 * 640 * 2 + 7 * 8193 * 16 * 4  # the pool is updated in place
+
+
+def test_a_scanned_slice_of_an_expert_stack_is_copied_and_a_whole_stack_is_not(one_v5e_chip):
+    """Why ``generate._cached_layers`` keeps the routed experts' ``[L, E, in, out]``
+    stacks out of the leaves its layer scan slices, and ``moe.routed_experts``
+    takes a layer index (PR 32): the TPU's compiler materialises a scan's slice
+    of a stack before ``ragged_dot`` reads it, a layer's experts copied every
+    layer of every step, and reads a whole stack in place. The day the first
+    half fails, the ``held`` leaves and ``layer=`` can go."""
+    import re
+
+    import jax
+    import jax.numpy as jnp
+    from jax import lax
+
+    L, E, D, N = 3, 64, 1024, 128
+
+    def sliced(x, stack, sizes):
+        return lax.scan(lambda x, w: (x + lax.ragged_dot(x, w, sizes), None), x, stack)[0]
+
+    def whole(x, stack, sizes):
+        flat = stack.reshape(L * E, D, D)
+
+        def layer(x, l):
+            groups = lax.dynamic_update_slice(jnp.zeros((L * E,), jnp.int32), sizes, (l * E,))
+            return x + lax.ragged_dot(x, flat, groups), None
+
+        return lax.scan(layer, x, jnp.arange(L))[0]
+
+    def described(shape, dtype):
+        return jax.ShapeDtypeStruct(shape, dtype, sharding=one_v5e_chip)
+
+    args = described((N, D), jnp.bfloat16), described((L, E, D, D), jnp.bfloat16), described((E,), jnp.int32)
+    one_layer = E * D * D * 2
+    copied = {}
+    for fn in (sliced, whole):
+        compiled = jax.jit(fn).lower(*args).compile()
+        copied[fn] = (
+            bool(re.search(rf"= bf16\[{E},{D},{D}\]\S* fusion\(", compiled.as_text())),
+            compiled.memory_analysis().temp_size_in_bytes >= one_layer,
+        )
+    assert copied[sliced] == (True, True) and copied[whole] == (False, False)
