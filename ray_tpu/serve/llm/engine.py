@@ -43,6 +43,18 @@ vLLM-lineage iteration-level scheduler on top of the paged KV cache:
   carried from draw to draw, so a token does not depend on the slot, the
   rows beside it, the program (a prompt's last chunk, a teacher-forced
   tail, a decode step) or the replica.
+- **the decode loop runs one step ahead**: step N+1 is built and dispatched
+  while step N is still on the device, its token column taken from step N's
+  ids on the device, and only then are N's ids fetched and emitted
+  (``_loop``, ``_decode_tick``). A request ends by count, so the host knows
+  everything else of a row (position, counter, block table, whether the row
+  exists) before N returns. One loop: with nothing in flight the tick
+  dispatches from the host's tokens. What the spans cover now:
+  ``llm.decode.build`` / ``llm.decode.dispatch`` the step a pass launches,
+  ``llm.decode.fetch`` / ``llm.sample`` / ``llm.emit`` and the record's
+  ``rows``, ``view_blocks``, ``context_tokens`` the step it lands;
+  ``stats()`` counts ``decode_steps``, ``decode_steps_run_ahead`` and
+  ``decode_rows_dropped``.
 - **streaming**: each request carries a queue the scheduler feeds token by
   token; ``LLMRequest`` iterates it — the replica's ``StreamingResponse``
   pump drains that iterator straight onto the HTTP socket.
@@ -234,12 +246,16 @@ def prefix_route_hint(tokens, block_size: int = 16) -> str:
 # One int32 row per sequence is everything a program is told about it beside
 # the chunk's tokens: ONE host array a dispatch (each `jnp.asarray` is ~0.1 ms
 # of the gap between two steps), the block table behind a fixed head.
-_ROW_TOKEN = 0  # decode: the token fed
+_ROW_TOKEN = 0  # decode: the token fed, or _ID_IN_FLIGHT
 _ROW_VALID_TO = 0  # prefill (its tokens are an argument of their own): the teacher-forced target
 _ROW_POS = 1  # position of the first token fed
 _ROW_DRAW = slice(2, 6)  # LLMRequest._sched_draw
 _ROW_COUNTER = 6  # index of the token this dispatch draws
 _ROW_TABLE = 7  # the block table from here on: n_max wide (prefill), a rung of _view_rungs (decode)
+
+# In a decode row's token column: the token is the id the step before drew for
+# this slot, still on the device (``decode``'s ``ids`` argument).
+_ID_IN_FLIGHT = -1
 
 # The narrowest block table a decode step is given, in blocks. An engine whose
 # n_max is no wider has one decode program.
@@ -282,10 +298,12 @@ _JIT_LOCK = threading.Lock()
 
 
 def _compiled_fns(cfg):
-    """(decode, prefill): ``decode(params, rows [num_slots, 7 + w], pool)``,
-    ``w`` a rung of ``_view_rungs`` (one compiled program each), and
-    ``prefill(params, tokens [1, q], pool, rows [1, 7 + n_max])``, both
-    ``-> (token ids int32, one a row, pool)``."""
+    """(decode, prefill): ``decode(params, rows [num_slots, 7 + w], pool,
+    ids [num_slots])``, ``w`` a rung of ``_view_rungs`` (one compiled program
+    each) and ``ids`` what the step before returned (a row whose token column
+    is ``_ID_IN_FLIGHT`` feeds its slot's), and ``prefill(params, tokens
+    [1, q], pool, rows [1, 7 + n_max])``, both ``-> (token ids int32, one a
+    row, pool)``."""
     with _JIT_LOCK:
         fns = _JIT_CACHE.get(cfg)
         if fns is None:
@@ -298,9 +316,11 @@ def _compiled_fns(cfg):
                 paged_decode_step,
             )
 
-            def decode_rows(p, rows, c):
+            def decode_rows(p, rows, c, ids):
+                fed = rows[:, _ROW_TOKEN]
+                fed = jnp.where(fed == _ID_IN_FLIGHT, ids, fed)
                 logits, c = paged_decode_step(
-                    p, rows[:, _ROW_TOKEN], c, rows[:, _ROW_TABLE:], rows[:, _ROW_POS], cfg
+                    p, fed, c, rows[:, _ROW_TABLE:], rows[:, _ROW_POS], cfg
                 )
                 return _draw_row_tokens(logits, rows), c
 
@@ -322,7 +342,7 @@ def _compiled_fns(cfg):
             # lambda, prefill_chunk_row) are how the benchmark's
             # trace_programs patterns find the programs in a trace: keep them.
             fns = (
-                jax.jit(lambda p, rows, c: decode_rows(p, rows, c), donate_argnums=2),
+                jax.jit(lambda p, rows, c, ids: decode_rows(p, rows, c, ids), donate_argnums=2),
                 jax.jit(prefill_chunk_row, donate_argnums=2),
             )
             _JIT_CACHE[cfg] = fns
@@ -344,6 +364,22 @@ class _PrefixEntry:
         self.bid = bid
         self.refs = refs
         self.stamp = stamp
+
+
+class _Step:
+    """A decode step that was dispatched and whose ids the host has not
+    fetched: what the program returned, the rows it carried and their slots
+    (a row's request may leave its slot before the fetch), and what the
+    iteration that emits its tokens reports of it."""
+
+    __slots__ = ("ids", "reqs", "slots", "width", "context_tokens")
+
+    def __init__(self, ids, reqs: list, width: int, context_tokens: int):
+        self.ids = ids
+        self.reqs = reqs
+        self.slots = [r._sched_slot for r in reqs]
+        self.width = width
+        self.context_tokens = context_tokens
 
 
 class LLMEngine:
@@ -433,7 +469,10 @@ class LLMEngine:
         self._cache = jax.block_until_ready(pool)
         self._moe_wanted: Optional[threading.Event] = None
         self._moe_asking = threading.Lock()  # one asker at a time
-        self._moe_read = None  # the counters as last read, a NumPy array
+        # The counters as last read, a NumPy array. Read once here, which
+        # builds the copy's program before the replica is ready: the first
+        # ``stats()`` of a serving engine compiles nothing inside a stream.
+        self._moe_read = np.asarray(self._copy_moe_counts()) if cfg.routed_experts else None
         self.spans.setup["pool_s"] = time.monotonic() - t0
         # Block 0 is the reserved null block — never handed out.
         self._free: list[int] = list(range(self.num_blocks - 1, 0, -1))
@@ -475,7 +514,16 @@ class LLMEngine:
             # Rows of [., V] logits a program handed to the host instead of
             # token ids (the draw is inside the programs). 0 is right.
             "host_logit_rows": 0,
+            # Decode steps dispatched; those of them dispatched while the step
+            # before was still unfetched (their carried rows took its ids on
+            # the device); rows whose id was dropped at its fetch because the
+            # request was cancelled or preempted while the step ran.
+            "decode_steps": 0,
+            "decode_steps_run_ahead": 0,
+            "decode_rows_dropped": 0,
         }
+        # The decode step in flight: dispatched, its ids not fetched.
+        self._inflight: Optional[_Step] = None
         t0 = time.monotonic()
         self._decode_fn, self._prefill_fn = _compiled_fns(cfg)
         self.spans.setup["jit_build_s"] = time.monotonic() - t0
@@ -668,8 +716,6 @@ class LLMEngine:
                 self._wake.set()
                 asked.wait(1.0)
             read = self._moe_read
-        if read is None:
-            return None
         E = self.cfg.num_experts
         return {
             kind: {
@@ -681,18 +727,15 @@ class LLMEngine:
             for kind, of in zip(("decode", "prefill"), read)
         }
 
-    def _answer_moe_stats(self):
-        asked, self._moe_wanted = self._moe_wanted, None
-        if asked is not None:
-            import jax.numpy as jnp
+    def _copy_moe_counts(self):
+        """The expert counters, copied on the device: the host's view of the
+        buffer itself (on a CPU backend it IS the buffer) would keep the next
+        dispatch from donating it."""
+        import jax.numpy as jnp
 
-            from ray_tpu.models.generate import MOE_COUNTS
+        from ray_tpu.models.generate import MOE_COUNTS
 
-            # Of a copy made on the device: the host's view of a buffer (on a
-            # CPU backend it IS the buffer) would keep the next dispatch from
-            # donating it.
-            self._moe_read = np.asarray(jnp.copy(self._cache[MOE_COUNTS]))
-            asked.set()
+        return jnp.copy(self._cache[MOE_COUNTS])
 
     @any_thread
     def shutdown(self, timeout: float = 10.0):
@@ -975,22 +1018,50 @@ class LLMEngine:
 
     @blocking
     def _loop(self):
+        """One pass: admit and sweep cancels, one prefill chunk, then the
+        decode tick, which runs ONE STEP AHEAD: it dispatches step N+1 while
+        step N is still on the device, and only then fetches and emits N's
+        tokens. So round the loop the order is dispatch N+1 -> fetch N -> emit
+        N -> admit -> prefill chunk -> dispatch N+2 -> fetch N+1 ..., and the
+        host's whole share of a pass hides behind a step. One loop: with
+        nothing in flight (the first step, or no decoding row) the tick
+        dispatches from the host's tokens and goes on as above.
+
+        Device order is what makes that safe. The pool is donated from
+        program to program, so programs run in the order they were dispatched:
+        a prefill chunk dispatched after step N+1 sees its writes. Blocks that
+        a cancel, a finish or a preemption releases while a step is in flight
+        may be written once more by that step (its row still names them); their
+        next owner reads only positions it wrote itself, later in device
+        order, and a decode row never writes a block the prefix cache shares.
+        Whatever reads the pool from the host (``_routed_experts``,
+        ``_publish_prefix``, ``_try_handoff``, ``_scatter_import``, the expert
+        counters) reads ``self._cache``, the result of the step in flight:
+        the array it must read. Leaving the loop (shutdown, crash) drops the
+        step in flight unfetched; its requests end with the loop's error."""
         try:
             spans = self.spans
             while not self._stop.is_set():
                 it = spans.begin(
                     len(self._waiting), sum(r is not None for r in self._slots)
                 )
+                asked, self._moe_wanted = self._moe_wanted, None
                 try:
                     with spans.span("llm.admit") as sp:
                         self._sweep_cancelled()
                         self._reap_exports()
                         sp.set(admitted=self._admit(), waiting=len(self._waiting))
+                    # If ``_moe_stats`` asked: copied before the pass dispatches,
+                    # behind the step in flight and ahead of the next, so the
+                    # reading below waits for no step but the one just fetched.
+                    counts = self._copy_moe_counts() if asked is not None else None
                     busy = self._prefill_tick()
                     busy = self._decode_tick() or busy
                 finally:
                     spans.end(it)
-                self._answer_moe_stats()
+                if asked is not None:
+                    self._moe_read = np.asarray(counts)
+                    asked.set()
                 if not busy:
                     if any(r is not None for r in self._slots) or self._waiting:
                         self._wake.wait(0.02)
@@ -1011,6 +1082,7 @@ class LLMEngine:
             raise
         finally:
             ENGINES.discard(self)
+            self._inflight = None
             with self._lock:
                 if self._crashed is None:
                     self._crashed = "llm engine is shut down"
@@ -1194,7 +1266,8 @@ class LLMEngine:
             if self.cluster_prefix:
                 self._publish_prefix(req)
             with spans.span("llm.prefill.fetch", rid=req.id):
-                drawn = self._fetch_ids(drawn)
+                # Waits for the step in flight too: it runs ahead of the chunk.
+                drawn = np.asarray(drawn)
             # The first token is drawn before the handoff is tried: the draw
             # is keyed by (seed, position), the same token either way.
             tok = self._drawn_tokens([req], drawn, [0])[0]
@@ -1212,12 +1285,14 @@ class LLMEngine:
         return np.zeros((n, _ROW_TABLE + width), np.int32)
 
     @staticmethod
-    def _fill_row(row: np.ndarray, req: LLMRequest, first: int, pos: int):
-        """``first``: column 0, the token fed (decode) or valid_to (prefill)."""
+    def _fill_row(row: np.ndarray, req: LLMRequest, first: int, pos: int, ahead: int = 0):
+        """``first``: column 0, the token fed (decode) or valid_to (prefill);
+        ``ahead``: 1 if a step in flight draws a token of ``req`` before this
+        dispatch draws its own."""
         row[_ROW_TOKEN] = first
         row[_ROW_POS] = pos
         row[_ROW_DRAW] = req._sched_draw
-        row[_ROW_COUNTER] = len(req._sched_generated)
+        row[_ROW_COUNTER] = len(req._sched_generated) + ahead
         row[_ROW_TABLE : _ROW_TABLE + len(req._sched_table)] = req._sched_table
 
     def _run_donated(self, fn, tokens, *rest):
@@ -1230,6 +1305,14 @@ class LLMEngine:
         drawn, self._cache = fn(self.params, tokens, pool, *rest)
         if not all(leaf.is_deleted() for leaf in pool.values()):
             self._counts["kv_pool_not_donated"] += 1
+        if drawn.ndim != 1:
+            # The draw belongs inside the program: one that hands back
+            # ``[rows, V]`` logits is refused, there being nothing left on the
+            # host to draw from them (nor a token column for the next step).
+            self._counts["host_logit_rows"] += drawn.shape[0]
+            raise TypeError(
+                f"program returned {drawn.dtype}{list(drawn.shape)}, not one token id a row"
+            )
         return drawn
 
     def _build_decode_rungs(self):
@@ -1240,6 +1323,7 @@ class LLMEngine:
         import jax
         import jax.numpy as jnp
 
+        ids = jnp.zeros((self.num_slots,), jnp.int32)
         for width in self._view_rungs:
             rows = jnp.asarray(self._program_rows(self.num_slots, width))
             # Traced, lowered and compiled apart from the call, and all three
@@ -1247,11 +1331,14 @@ class LLMEngine:
             # lowering in jit's caches, which keep them only while these
             # objects live. In a v5e replica that is 0.6 s a rung where the
             # call alone takes 0.94 (PERF.md, PR 31).
-            traced = self._decode_fn.trace(self.params, rows, self._cache)
+            traced = self._decode_fn.trace(self.params, rows, self._cache, ids)
             lowered = traced.lower()
             held = (traced, lowered, lowered.compile())
-            jax.block_until_ready(self._run_donated(self._decode_fn, rows))
+            ids = jax.block_until_ready(self._run_donated(self._decode_fn, rows, ids))
             del held
+        # What a step with no step before it is given as ``ids`` (none of its
+        # rows reads them): a program's own output, like every other step's.
+        self._no_ids = ids
 
     def _register_prefix_blocks(self, req: LLMRequest):
         """Publish freshly-WRITTEN full prompt blocks for reuse. Done as
@@ -1272,17 +1359,53 @@ class LLMEngine:
     # --- decode ---
 
     def _decode_tick(self) -> bool:
-        active = [r for r in self._slots if r is not None and r._sched_state == "decode"]
+        """Dispatch the step after the one in flight, then fetch and emit the
+        one in flight. With none in flight, dispatch one first."""
+        step, self._inflight = self._inflight, None
+        busy = step is not None or any(
+            r is not None and r._sched_state == "decode" for r in self._slots
+        )
+        if step is None:
+            step = self._launch_step(None)
+        if step is not None:
+            self._inflight = self._launch_step(step)
+            self._land_step(step)
+        return busy
+
+    def _launch_step(self, ahead_of: Optional[_Step]) -> Optional[_Step]:
+        """Build and dispatch one decode step over every row that decodes;
+        None if there is none. ``ahead_of`` is the step in flight, if any: a
+        row it carries (``riding``) is one token and one position further on
+        than the host has seen, feeds the id that step draws for its slot
+        (``_ID_IN_FLIGHT``), and has no row here if that id is its last: a
+        request ends by count. Every other row (fresh from prefill, back from
+        a preemption) feeds its last token from the host, so a slot that
+        changed hands never reads the tenant before's id."""
+        riding = set(ahead_of.reqs) if ahead_of is not None else ()
+        bs = self.block_size
+
+        def decodes(r):
+            return (
+                r is not None
+                and r._sched_state == "decode"
+                and len(r._sched_generated) + (r in riding) < r.max_new_tokens
+            )
+
+        def writes_at(r):
+            return r._sched_pos + (r in riding)
+
+        active = [r for r in self._slots if decodes(r)]
         if not active:
-            return False
+            return None
         spans = self.spans
         with spans.span("llm.decode.build") as sp:
             # Every active sequence needs its next write position backed by a
-            # physical block before the step; exhaustion preempts the youngest.
-            for req in list(active):
+            # physical block before the step; exhaustion preempts the youngest
+            # (the id a victim has in flight is dropped at its fetch).
+            for req in active:
                 if req._sched_slot is None or self._slots[req._sched_slot] is not req:
                     continue  # preempted by an earlier needy sequence this tick
-                while req._sched_pos // self.block_size >= len(req._sched_table):
+                while writes_at(req) // bs >= len(req._sched_table):
                     bid = self._alloc_block()
                     if bid is not None:
                         req._sched_table.append(bid)
@@ -1313,56 +1436,64 @@ class LLMEngine:
             active = [
                 r
                 for r in self._slots
-                if r is not None
-                and r._sched_state == "decode"
-                and r._sched_pos // self.block_size < len(r._sched_table)
+                if decodes(r) and writes_at(r) // bs < len(r._sched_table)
             ]
             sp.set(rows=len(active))
             if not active:
-                return True
+                return None
             import jax.numpy as jnp
 
             # The step gathers and attends over the table it is handed: the
-            # smallest rung that covers the longest running row.
+            # smallest rung that covers the longest of ITS rows' tables, as
+            # the host has just extended them.
             longest = max(len(r._sched_table) for r in active)
             width = next(w for w in self._view_rungs if w >= longest)
             rows = self._program_rows(self.num_slots, width)
+            context_tokens = 0
             for req in active:
+                ahead = req in riding
                 self._fill_row(
-                    rows[req._sched_slot], req, req._sched_generated[-1], req._sched_pos
+                    rows[req._sched_slot],
+                    req,
+                    _ID_IN_FLIGHT if ahead else req._sched_generated[-1],
+                    writes_at(req),
+                    ahead,
                 )
+                context_tokens += writes_at(req) + 1
             rows = jnp.asarray(rows)
-        spans.carried(
-            rows=len(active), view_blocks=width,
-            context_tokens=sum(r._sched_pos + 1 for r in active),
-        )
         self._width_steps[width] += 1
+        self._counts["decode_steps"] += 1
+        self._counts["decode_steps_run_ahead"] += ahead_of is not None
         with spans.span("llm.decode.dispatch"):
-            drawn = self._run_donated(self._decode_fn, rows)
+            ids = self._run_donated(
+                self._decode_fn, rows, ahead_of.ids if ahead_of is not None else self._no_ids
+            )
+        return _Step(ids, active, width, context_tokens)
+
+    def _land_step(self, step: _Step):
+        """Fetch a dispatched step's ids and emit them, each to the request
+        that still holds the slot its row was built for: a request cancelled
+        or preempted since the dispatch has left it, and its id is dropped."""
+        spans = self.spans
+        spans.carried(
+            rows=len(step.reqs), view_blocks=step.width, context_tokens=step.context_tokens
+        )
         with spans.span("llm.decode.fetch"):
-            # Waits for the device (and for this pass's prefill chunk, which
-            # runs ahead of the step), then copies [num_slots] ids to the host.
-            drawn = self._fetch_ids(drawn)
-        drawn = self._drawn_tokens(active, drawn, [r._sched_slot for r in active])
-        with spans.span("llm.emit", tokens=len(active)) as sp:
-            for req, tok in zip(active, drawn):
+            # Waits for this step (the next is already queued behind it, and
+            # behind this pass's prefill chunk), then copies [num_slots] ids.
+            ids = np.asarray(step.ids)
+        toks = self._drawn_tokens(step.reqs, ids, step.slots)
+        live = [
+            (req, tok)
+            for req, slot, tok in zip(step.reqs, step.slots, toks)
+            if self._slots[slot] is req
+        ]
+        self._counts["decode_rows_dropped"] += len(toks) - len(live)
+        with spans.span("llm.emit", tokens=len(live)) as sp:
+            for req, tok in live:
                 req._sched_pos += 1
                 self._emit_token(req, tok)
-            sp.set(finished=sum(r._finished for r in active))
-        return True
-
-    def _fetch_ids(self, drawn) -> np.ndarray:
-        """A program's token ids on the host, one int32 a row. The draw
-        belongs inside the program: one that hands back ``[rows, V]`` logits
-        is counted in ``host_logit_rows`` and refused, there being nothing
-        left on the host to draw from them."""
-        ids = np.asarray(drawn)
-        if ids.ndim != 1:
-            self._counts["host_logit_rows"] += ids.shape[0]
-            raise TypeError(
-                f"program returned {ids.dtype}{list(ids.shape)}, not one token id a row"
-            )
-        return ids
+            sp.set(finished=sum(req._finished for req, _ in live))
 
     def _drawn_tokens(self, reqs: list, ids: np.ndarray, at: list) -> list[int]:
         """One ``llm.sample`` span a step over the host's share of its draws:

@@ -230,7 +230,7 @@ def test_host_logit_rows_counts_a_program_that_returns_logits(model):
     params, cfg = model
     eng = LLMEngine(params, cfg, **ENGINE)
     eng._decode_fn = jax.jit(
-        lambda p, rows, c: paged_decode_step(
+        lambda p, rows, c, ids: paged_decode_step(
             p, rows[:, _ROW_TOKEN], c, rows[:, _ROW_TABLE:], rows[:, _ROW_POS], cfg
         ),
         donate_argnums=2,

@@ -62,6 +62,59 @@ def _dense(params, cfg, prompt, n):
     )[0].tolist()
 
 
+@functools.lru_cache(maxsize=None)
+def _oracle_fns(cfg):
+    """``generate()``'s two halves, the dense-cache prefill and decode step,
+    each followed by the serving programs' draw."""
+    import jax
+
+    from ray_tpu.models.generate import decode_step, draw_tokens, prefill
+
+    def first(params, tokens, cache, draw):
+        logits, cache, _ = prefill(params, tokens, cache, cfg)
+        return draw_tokens(logits, *draw), cache
+
+    def then(params, token, cache, pos, draw):
+        logits, cache = decode_step(params, token, cache, pos, cfg)
+        return draw_tokens(logits, *draw), cache
+
+    return jax.jit(first), jax.jit(then)
+
+
+def _oracle(params, cfg, prompt, n, temperature=0.0, top_k=0, seed=0):
+    """The stream of one request alone, by the dense cache: ``generate()``'s
+    for a greedy request, and for a sampled one ``generate()``'s prefill and
+    decode step with ``draw_tokens`` keyed by (seed, index of the token) in
+    place of its carried key."""
+    if not temperature:
+        return _dense(params, cfg, prompt, n)
+    import jax.numpy as jnp
+
+    from ray_tpu.models.generate import init_cache
+
+    first, then = _oracle_fns(cfg)
+    halves = jnp.asarray([[seed & 0xFFFFFFFF, seed >> 32]], jnp.uint32)
+
+    def draw(i):
+        return (jnp.full((1,), temperature, jnp.float32), jnp.full((1,), top_k, jnp.int32),
+                halves, jnp.full((1,), i, jnp.int32))
+
+    cache = init_cache(cfg, 1, len(prompt) + n)
+    tok, cache = first(params, jnp.asarray([prompt], jnp.int32), cache, draw(0))
+    out = [int(tok[0])]
+    for i in range(1, n):
+        tok, cache = then(params, tok, cache, jnp.int32(len(prompt) + i - 1), draw(i))
+        out.append(int(tok[0]))
+    return out
+
+
+SAMPLINGS = pytest.mark.parametrize(
+    "sampling",
+    [dict(temperature=0.0), dict(temperature=0.9, top_k=16, seed=7), dict(temperature=1.0, seed=11)],
+    ids=["greedy", "sampled_top_k", "sampled"],
+)
+
+
 def _rand_prompt(seed, n, vocab=128):
     return np.random.default_rng(seed).integers(0, vocab, n).tolist()
 
@@ -302,16 +355,16 @@ def test_kv_pool_not_donated_counts_a_program_that_copies(model):
     """The counter reads non-zero when a program leaves its input pool alive
     (here: the same step jitted without donation), so its 0 means something."""
     import jax
+    import jax.numpy as jnp
 
     from ray_tpu.models.generate import paged_decode_step
-    from ray_tpu.serve.llm.engine import _ROW_POS, _ROW_TABLE, _ROW_TOKEN
+    from ray_tpu.serve.llm.engine import _ID_IN_FLIGHT, _ROW_POS, _ROW_TABLE, _ROW_TOKEN
 
     _, cfg = model
     eng = _stopped_engine(model, num_slots=1, max_model_len=32)
-    def greedy_step(p, rows, c):
-        logits, c = paged_decode_step(
-            p, rows[:, _ROW_TOKEN], c, rows[:, _ROW_TABLE:], rows[:, _ROW_POS], cfg
-        )
+    def greedy_step(p, rows, c, ids):
+        fed = jnp.where(rows[:, _ROW_TOKEN] == _ID_IN_FLIGHT, ids, rows[:, _ROW_TOKEN])
+        logits, c = paged_decode_step(p, fed, c, rows[:, _ROW_TABLE:], rows[:, _ROW_POS], cfg)
         return logits.argmax(-1).astype("int32"), c
 
     eng._decode_fn = jax.jit(greedy_step)  # the engine's step without donate_argnums
@@ -341,7 +394,7 @@ def _engine_program(cfg, kind):
     i32 = lambda *shape: jax.ShapeDtypeStruct(shape, jnp.int32)  # noqa: E731
     decode, prefill = _compiled_fns(cfg)
     if kind == "decode":
-        lowered = decode.lower(params, i32(slots, _ROW_TABLE + n_max), cache)
+        lowered = decode.lower(params, i32(slots, _ROW_TABLE + n_max), cache, i32(slots))
     else:
         lowered = prefill.lower(params, i32(1, chunk), cache, i32(1, _ROW_TABLE + n_max))
     k_arg = len(jax.tree.leaves(params)) + 1  # params, the tokens, then k and v
@@ -394,9 +447,9 @@ def test_crash_after_donation_ends_engine_without_touching_dead_pool(model):
                     max_model_len=32, prefill_chunk=4)
     real, calls = eng._decode_fn, []
 
-    def donate_then_raise(p, rows, c):
+    def donate_then_raise(p, rows, c, ids):
         calls.append(c)
-        real(p, rows, c)
+        real(p, rows, c, ids)
         raise RuntimeError("boom after donation")
 
     eng._decode_fn = donate_then_raise
@@ -661,29 +714,29 @@ def test_ladder_is_a_constant_of_n_max():
         assert _view_rungs(n_max) == (n_max,)  # every other CPU test's engine: today's one program
 
 
-@pytest.mark.parametrize(
-    "sampling",
-    [dict(temperature=0.0), dict(temperature=0.9, top_k=16, seed=7), dict(temperature=1.0, seed=11)],
-    ids=["greedy", "sampled_top_k", "sampled"],
-)
+@SAMPLINGS
 def test_streams_across_rungs_equal_the_full_width_streams(model, ladder_engine, monkeypatch, sampling):
     """A stream that crosses two rung boundaries mid-generation is, token for
     token, the stream of the same engine held to its one full-width rung
-    (today's program), and greedy it is ``generate()``'s: a masked key weighs
-    exactly 0, so a view that ends at the rung loses nothing."""
+    (today's program) and the dense-cache oracle's, greedy (``generate()``)
+    and sampled: a masked key weighs exactly 0, so a view that ends at the
+    rung loses nothing, and every step but the first was dispatched while the
+    step before was unfetched, at the rung ITS rows need."""
     params, cfg = model
     eng = ladder_engine
     prompt = _rand_prompt(61, LONG_PROMPT)
-    before = dict(eng._width_steps)
+    before, counts = dict(eng._width_steps), dict(eng._counts)
     laddered = eng.submit(prompt, max_new_tokens=LONG_NEW, **sampling).result(120)
     assert _widths_run(eng, before) == {16, 32, 64}
+    steps = eng._counts["decode_steps"] - counts["decode_steps"]
+    assert steps == LONG_NEW - 1  # one a token after the first: none for a request that has ended
+    assert eng._counts["decode_steps_run_ahead"] - counts["decode_steps_run_ahead"] == steps - 1
+    assert eng._counts["decode_rows_dropped"] == counts["decode_rows_dropped"]
     monkeypatch.setattr(eng, "_view_rungs", (eng.n_max,))
     before = dict(eng._width_steps)
     full = eng.submit(prompt, max_new_tokens=LONG_NEW, **sampling).result(120)
     assert _widths_run(eng, before) == {eng.n_max}
-    assert laddered == full
-    if not sampling["temperature"]:
-        assert laddered == _dense(params, cfg, prompt, LONG_NEW)
+    assert laddered == full == _oracle(params, cfg, prompt, LONG_NEW, **sampling)
 
 
 def test_a_run_over_every_rung_compiles_nothing(ladder_engine):
@@ -740,11 +793,14 @@ def test_construction_builds_one_decode_program_a_rung(max_model_len, d_ff, rung
 @pytest.mark.parametrize("num_blocks", [None, 26], ids=["roomy_pool", "preempting_pool"])
 def test_step_width_is_the_smallest_rung_over_the_longest_active_table(model, num_blocks):
     """Driven by hand: each decode step's width is the smallest rung >= the
-    longest table among the rows that decode (rows in prefill not counted). It
-    goes up when a row crosses a rung and comes back down when the longest row
-    finishes (roomy pool) or is preempted (a pool of 25 blocks: the long row
-    is the youngest, loses its blocks at 64 tokens and comes back).
-    ``decode_width_steps`` sums to the decode steps run."""
+    longest table among ITS rows (rows in prefill, and rows whose last token
+    the step before draws, not counted), and a table is no longer than the
+    row's write position needs: the run-ahead step is one position further on
+    than the host has seen, not one rung. The width goes up when a row crosses
+    a rung and comes back down when the longest row finishes (roomy pool) or
+    is preempted (a pool of 25 blocks: the long row is the youngest, loses its
+    blocks at 64 tokens and comes back). ``decode_width_steps`` sums to the
+    decode steps run."""
     kw = dict(num_slots=2, max_model_len=256)
     if num_blocks:
         kw["num_blocks"] = num_blocks
@@ -754,36 +810,258 @@ def test_step_width_is_the_smallest_rung_over_the_longest_active_table(model, nu
     if num_blocks:
         specs.reverse()  # the long row is admitted last: the youngest is the victim
     reqs = [eng.submit(p, max_new_tokens=n) for p, n in specs]
-    seen = []
+    launch, seen = eng._launch_step, []
+
+    def launch_and_check(ahead_of):
+        step = launch(ahead_of)
+        if step is not None:
+            riding = ahead_of.reqs if ahead_of is not None else ()
+            # Where each row writes: a row the step in flight carries is one
+            # position past what the host has seen of it.
+            writes = [r._sched_pos + (r in riding) for r in step.reqs]
+            assert [len(r._sched_table) for r in step.reqs] == [w // bs + 1 for w in writes]
+            assert step.width == min(w for w in rungs if w >= max(writes) // bs + 1)
+            seen.append(step.width)
+        return step
+
+    eng._launch_step = launch_and_check
     for _ in range(600):
         if all(r._finished for r in reqs):
             break
         eng._admit()
         eng._prefill_tick()
-        decoding = [r for r in eng._slots if r is not None and r._sched_state == "decode"]
-        before = dict(eng._width_steps)
         eng._decode_tick()
-        ran = sorted(_widths_run(eng, before))
-        if not ran:
-            continue
-        # A row that decoded advanced by one (a finished row keeps its
-        # position; a preempted one is back at 0): it wrote at _sched_pos - 1.
-        stepped = [r for r in decoding if r._sched_pos > 0]
-        longest = max((r._sched_pos - 1) // bs + 1 for r in stepped)
-        assert ran == [min(w for w in rungs if w >= longest)], (ran, longest)
-        seen.append(ran[0])
-    assert all(r._finished for r in reqs)
+    assert all(r._finished for r in reqs) and eng._inflight is None
     for r, (p, n) in zip(reqs, specs):
         assert r.result(5) == _dense(*model, p, n)
     assert set(seen) == {16, 32}  # 72 tokens at most: the 64-block rung is never needed
     assert seen[0] == 16 and (16, 32) in zip(seen, seen[1:]) and (32, 16) in zip(seen, seen[1:])
     s = eng.stats()
-    assert sum(s["decode_width_steps"].values()) == len(seen)
+    assert s["decode_steps"] == sum(s["decode_width_steps"].values()) == len(seen)
     assert s["decode_width_steps"] == {w: seen.count(w) for w in rungs}
     assert s["kv_pool_not_donated"] == 0
     assert s["preemptions"] == (1 if num_blocks else 0), s
+    # No row is built for a request that ends by count: a step in vain would
+    # show as one more step than tokens. The preempted row's id in flight is
+    # the one id dropped.
+    assert s["decode_rows_dropped"] == s["preemptions"]
     if not num_blocks:
         assert seen[-1] == 16  # the long row finished first: the width came back down
+
+
+# ---------------------------------------------------------------------------
+# the decode loop runs one step ahead (ISSUE 34)
+# ---------------------------------------------------------------------------
+
+
+def _pass(eng):
+    """One pass of ``_loop``, by hand."""
+    eng._sweep_cancelled()
+    eng._admit()
+    eng._prefill_tick()
+    eng._decode_tick()
+
+
+def _spy_on_steps(eng):
+    """Every decode step a hand-driven engine launches from here on, as (the
+    step, the step in flight it was launched behind or None, the token column
+    of its rows), checked as it is launched: a row feeds the id in flight if
+    and only if the step in flight carries the same request in the same slot;
+    every other row feeds a token the host holds, and a slot without a row is
+    all zeros."""
+    from ray_tpu.serve.llm.engine import _ID_IN_FLIGHT, _ROW_TOKEN
+
+    launched, columns = [], []
+    decode, launch = eng._decode_fn, eng._launch_step
+
+    def spy_decode(p, rows, c, ids):
+        columns.append(np.asarray(rows)[:, _ROW_TOKEN].tolist())
+        return decode(p, rows, c, ids)
+
+    def spy_launch(ahead_of):
+        step = launch(ahead_of)
+        if step is None:
+            return None
+        column = columns[-1]
+        carried = dict(zip(ahead_of.slots, ahead_of.reqs)) if ahead_of is not None else {}
+        rows = dict(zip(step.slots, step.reqs))
+        for slot in range(eng.num_slots):
+            req = rows.get(slot)
+            if req is None:
+                assert column[slot] == 0
+            elif carried.get(slot) is req:
+                assert column[slot] == _ID_IN_FLIGHT
+            else:
+                assert column[slot] == req._sched_generated[-1]
+        launched.append((step, ahead_of, column))
+        return step
+
+    eng._decode_fn, eng._launch_step = spy_decode, spy_launch
+    return launched
+
+
+def _drive(eng, reqs, passes=400):
+    for _ in range(passes):
+        if all(r._finished for r in reqs):
+            break
+        _pass(eng)
+    assert all(r._finished for r in reqs) and eng._inflight is None
+    s = eng.stats()
+    assert s["free_blocks"] + s["cached_blocks"] == s["num_blocks"], s
+    assert s["kv_pool_not_donated"] == 0
+    return s
+
+
+@SAMPLINGS
+def test_a_request_that_ends_by_count_has_no_row_in_the_next_step(model, sampling):
+    """A short request and a long one decode side by side with a third
+    waiting. The short one ends by count with step N's token: step N+1, built
+    before N was fetched, already has no row for it (no step and no row is
+    spent on a finished request, nothing is dropped), and the request that
+    takes its slot while the long one's step is in flight feeds its own first
+    token from the host, never an id the slot's row drew before. All three
+    streams are the oracle's."""
+    from ray_tpu.serve.llm.engine import _ID_IN_FLIGHT
+
+    params, cfg = model
+    eng = _stopped_engine(model, num_slots=2, max_model_len=48)
+    launched = _spy_on_steps(eng)
+    specs = [(_rand_prompt(101, 4), 4), (_rand_prompt(102, 4), 30), (_rand_prompt(103, 3), 6)]
+    reqs = [eng.submit(p, max_new_tokens=n, **sampling) for p, n in specs]
+    short, long_, successor = reqs
+    s = _drive(eng, reqs)
+    for r, (p, n) in zip(reqs, specs):
+        assert r.result(5) == _oracle(params, cfg, p, n, **sampling)
+    # Every token but a request's first came from one row of one step.
+    assert sum(len(step.reqs) for step, _, _ in launched) == sum(n - 1 for _, n in specs)
+    assert s["decode_steps"] == len(launched) and s["decode_rows_dropped"] == 0
+    # The batch never empties between the short request's first step and the
+    # long one's last: every step but the one that primed the pipeline rode.
+    assert s["decode_steps_run_ahead"] == len(launched) - 1
+    assert [ahead_of is None for _, ahead_of, _ in launched] == [True] + [False] * (len(launched) - 1)
+    # The short request's last row is in the step that drew its 4th token ...
+    last = max(i for i, (step, _, _) in enumerate(launched) if short in step.reqs)
+    assert sum(short in step.reqs for step, _, _ in launched) == 4 - 1
+    # ... and the successor took that slot while the long row's steps went on.
+    first = min(i for i, (step, _, _) in enumerate(launched) if successor in step.reqs)
+    step, ahead_of, column = launched[first]
+    slot = step.slots[step.reqs.index(successor)]
+    assert first > last and slot == launched[last][0].slots[launched[last][0].reqs.index(short)]
+    assert ahead_of is not None and long_ in ahead_of.reqs and successor not in ahead_of.reqs
+    assert column[slot] != _ID_IN_FLIGHT and column[1 - slot] == _ID_IN_FLIGHT
+
+
+@SAMPLINGS
+def test_a_cancel_that_lands_while_a_step_is_in_flight_drops_its_id(model, sampling):
+    """Two requests decode, a third waits. One is cancelled while a step that
+    carries its row is in flight: the sweep frees its blocks at once (the step
+    in flight may still write its row into them), the waiting request takes
+    the slot AND those blocks and prefills into them behind that step in
+    device order, the id fetched for the cancelled row is dropped and counted,
+    never emitted, and the successor's and the survivor's streams are the
+    oracle's."""
+    params, cfg = model
+    eng = _stopped_engine(model, num_slots=2, max_model_len=48, num_blocks=17)
+    launched = _spy_on_steps(eng)
+    specs = [(_rand_prompt(111, 6), 20), (_rand_prompt(112, 5), 20), (_rand_prompt(113, 7), 12)]
+    reqs = [eng.submit(p, max_new_tokens=n, **sampling) for p, n in specs]
+    doomed, survivor, successor = reqs
+    while len(doomed._sched_generated) < 5:
+        _pass(eng)
+    step = eng._inflight
+    assert step is not None and doomed in step.reqs and survivor in step.reqs
+    emitted, blocks = list(doomed._sched_generated), set(doomed._sched_table)
+    free = len(eng._free) + len(eng._lru)  # its one full prompt block is the prefix cache's: evictable, not free
+    eng.cancel(doomed)
+    eng._sweep_cancelled()
+    # Freed at once, the step that carries its row still in flight.
+    assert doomed._finished and len(eng._free) + len(eng._lru) == free + len(blocks)
+    assert eng._inflight is step
+    _pass(eng)  # admits the successor into the freed slot and blocks, then lands the step
+    assert successor._sched_slot == step.slots[step.reqs.index(doomed)]
+    assert set(successor._sched_table) <= blocks
+    assert eng.stats()["decode_rows_dropped"] == 1
+    s = _drive(eng, [survivor, successor])
+    assert s["decode_rows_dropped"] == 1 and s["cancelled"] == 1
+    assert doomed._sched_generated == emitted == list(doomed)  # the queue ends after what was emitted before the cancel
+    for r, (p, n) in list(zip(reqs, specs))[1:]:
+        assert r.result(5) == _oracle(params, cfg, p, n, **sampling)
+    at = [st for st, _, _ in launched].index(step)
+    assert all(doomed not in st.reqs for st, _, _ in launched[at + 1:])
+
+
+@SAMPLINGS
+def test_a_preemption_that_lands_while_a_step_is_in_flight_drops_its_id(model, sampling):
+    """A pool too small for two long rows: the younger is preempted while
+    building step N+1, with its row of step N in flight. That id is dropped
+    at its fetch and counted; readmitted, the request draws the same token
+    again at the same index, so both streams are still the oracle's."""
+    params, cfg = model
+    eng = _stopped_engine(model, num_slots=2, max_model_len=40, num_blocks=13)
+    _spy_on_steps(eng)
+    specs = [(_rand_prompt(121, 6), 20), (_rand_prompt(122, 6), 20)]
+    reqs = [eng.submit(p, max_new_tokens=n, **sampling) for p, n in specs]
+    s = _drive(eng, reqs)
+    assert s["preemptions"] >= 1 and s["decode_rows_dropped"] == s["preemptions"]
+    assert reqs[1].preemptions >= 1 and reqs[0].preemptions == 0  # the youngest is the victim
+    for r, (p, n) in zip(reqs, specs):
+        assert r.result(5) == _oracle(params, cfg, p, n, **sampling)
+
+
+@pytest.mark.parametrize("how", ["drain", "shutdown"])
+def test_leaving_with_a_step_in_flight(model, how):
+    """``drain()`` with a step in flight: the accepted request decodes to its
+    end, run-ahead all the way, and its stream is the oracle's.
+    ``shutdown()`` with a step in flight (the scheduler is held inside its
+    6th dispatch until the stop is set): the 5th step is still fetched and
+    emitted, the 6th is dropped unfetched, the request ends with the typed
+    shutdown error after exactly six of the oracle's tokens, nothing stays in
+    flight and every block is back."""
+    from ray_tpu.exceptions import ReplicaDrainingError
+    from ray_tpu.serve.llm import LLMEngine
+
+    params, cfg = model
+    eng = LLMEngine(params, cfg, num_slots=2, block_size=4, max_model_len=64, prefill_chunk=4)
+    prompt, n = _rand_prompt(131, 6), 50
+    want = _oracle(params, cfg, prompt, n, temperature=0.8, seed=5)
+    decode, dispatched = eng._decode_fn, []
+    sixth, stop_is_set = threading.Event(), threading.Event()
+
+    def held_at_the_sixth(*args):
+        dispatched.append(1)
+        if how == "shutdown" and len(dispatched) == 6:
+            sixth.set()
+            assert stop_is_set.wait(30)
+        return decode(*args)
+
+    eng._decode_fn = held_at_the_sixth
+    try:
+        req = eng.submit(prompt, max_new_tokens=n, temperature=0.8, seed=5)
+        if how == "drain":
+            it = iter(req)
+            got = [next(it), next(it)]  # decode underway: from here on a step is in flight
+            eng.drain()
+            assert got + list(it) == want
+            s = eng.stats()
+            assert s["decode_steps"] == n - 1 and s["decode_steps_run_ahead"] == n - 2
+        else:
+            assert sixth.wait(60)
+            eng._stop.set()
+            stop_is_set.set()
+            eng.shutdown()
+            assert not eng._thread.is_alive() and eng._inflight is None
+            with pytest.raises(ReplicaDrainingError):
+                req.result(5)
+            assert req._sched_generated == want[:6]  # the first by the prefill, one a fetched step
+            s = eng.stats()
+            assert s["decode_steps"] == 6
+            with pytest.raises(RuntimeError, match="shut down"):
+                eng.submit(prompt, max_new_tokens=2)
+        assert s["decode_rows_dropped"] == 0  # a step never fetched drops nothing: its requests ended with the loop
+        assert s["running"] == 0 and s["free_blocks"] + s["cached_blocks"] == s["num_blocks"], s
+    finally:
+        stop_is_set.set()
+        eng.shutdown()
 
 
 # ---------------------------------------------------------------------------

@@ -200,6 +200,82 @@ def test_view_blocks_is_the_width_of_the_pass_s_decode_step(model):
     assert any(r[IT["prefill_tokens"]] and not r[IT["view_blocks"]] for r in its)  # a chunk alone
 
 
+def test_a_decode_iteration_with_a_step_in_flight_has_one_span_of_each_name(model):
+    """Driven by hand, pass by pass as ``_loop`` does: a pass that finds a step
+    in flight and runs no chunk opens exactly one ``llm.decode.build`` and one
+    ``llm.decode.dispatch`` (of the step it launches), one ``llm.decode.fetch``,
+    one ``llm.sample`` and one ``llm.emit`` (of the step it lands), and its
+    record carries the rows, the width and the context of the step whose
+    tokens it emits, not of the one it launched."""
+    eng = _engine(model, max_model_len=128, prefill_chunk=8)
+    eng.shutdown()
+    eng._crashed = None
+    reqs = [eng.submit(_prompt(46 + i, 40 + 9 * i), max_new_tokens=60 - 20 * i) for i in range(3)]
+    opened, span = [], eng.spans.span
+    eng.spans.span = lambda name, **args: (opened.append(name), span(name, **args))[1]
+    checked = 0
+    for _ in range(200):
+        if all(r._finished for r in reqs):
+            break
+        landing, before = eng._inflight, eng.spans.iterations.n
+        if landing is not None:
+            rows = len(landing.reqs)
+            context = sum(r._sched_pos + 1 for r in landing.reqs)  # each row's length, the token fed included
+        del opened[:]
+        it = eng.spans.begin(len(eng._waiting), sum(r is not None for r in eng._slots))
+        with eng.spans.span("llm.admit") as sp:
+            sp.set(admitted=eng._admit(), waiting=len(eng._waiting))
+        eng._prefill_tick()
+        eng._decode_tick()
+        eng.spans.end(it)
+        if landing is None or "llm.prefill.dispatch" in opened:
+            continue
+        assert sorted(opened) == sorted(
+            ["llm.admit", "llm.decode.fetch", "llm.sample", "llm.emit"]
+            + ["llm.decode.build", "llm.decode.dispatch"] * (eng._inflight is not None)
+        ), opened
+        (rec,) = eng.spans.iterations.since(before)
+        assert rec[IT["rows"]] == rows and rec[IT["view_blocks"]] == landing.width
+        assert rec[IT["context_tokens"]] == context
+        assert all(rec[IT[name]] > 0 for name in opened)
+        checked += 1
+    assert all(r._finished for r in reqs) and eng._inflight is None
+    assert checked >= 20
+    widths = {r[IT["view_blocks"]] for r in eng.spans.iterations.since()}
+    assert widths == {0, 16, 32}  # the 58-token prompt crosses 64 tokens: both rungs were landed
+
+
+def test_stats_count_how_often_the_loop_runs_ahead(model):
+    """``stats()`` has the three counters as plain ints (the benchmark logs a
+    run's integer counters), and with every slot full all but the priming step
+    are dispatched while their predecessor is unfetched."""
+    eng = _engine(model)
+    try:
+        reqs = [eng.submit(_prompt(47 + i, 6), max_new_tokens=26) for i in range(ENGINE["num_slots"])]
+        for r in reqs:
+            assert len(r.result(timeout=60)) == 26
+        late = eng.submit(_prompt(55, 6), max_new_tokens=26)
+        next(iter(late))
+        eng.cancel(late)
+        deadline = time.monotonic() + 10
+        while eng.stats()["running"] and time.monotonic() < deadline:
+            time.sleep(0.01)
+    finally:
+        eng.shutdown()
+    s = eng.stats()
+    for name in ("decode_steps", "decode_steps_run_ahead", "decode_rows_dropped"):
+        assert type(s[name]) is int, (name, s[name])
+    assert s["decode_steps"] == sum(s["decode_width_steps"].values()) >= 25 + 2
+    assert s["decode_steps_run_ahead"] / s["decode_steps"] > 0.9
+    # The cancelled stream was decoding when the sweep found it (unless it had
+    # ended already): the row its step in flight carried was dropped, and a
+    # landed row is an emitted token or a dropped id.
+    assert s["decode_rows_dropped"] == s["cancelled"] <= 1
+    assert sum(r[IT["rows"]] for r in _iterations(eng.spans.export())) == (
+        ENGINE["num_slots"] * 25 + late.num_generated - 1 + s["decode_rows_dropped"]
+    )
+
+
 # ---------------------------------------------------------------------------
 # the rings hold their size
 # ---------------------------------------------------------------------------
@@ -284,10 +360,12 @@ def host_plane(model, tmp_path_factory):
         options.python_tracer_level = 0
         jax.profiler.start_trace(log_dir, profiler_options=options)
         try:
-            reqs = [eng.submit(_prompt(51 + i, 6), max_new_tokens=4, temperature=0.8 * i,
+            # Eight tokens each: the first stream runs a step ahead of what it
+            # has emitted, and is still decoding when the second joins it.
+            reqs = [eng.submit(_prompt(51 + i, 6), max_new_tokens=8, temperature=0.8 * i,
                                top_k=5 * i, seed=i) for i in range(2)]
             for r in reqs:
-                assert len(r.result(timeout=60)) == 4
+                assert len(r.result(timeout=60)) == 8
             time.sleep(0.2)  # the scheduler closes its last pass after the consumer has its tokens
         finally:
             jax.profiler.stop_trace()

@@ -203,7 +203,8 @@ def test_the_latent_decode_step_copies_neither_the_pool_nor_the_expert_stacks(on
 
     params = described(jax.eval_shape(lambda: init_params(jax.random.PRNGKey(0), cfg)))
     rows = jax.ShapeDtypeStruct((engine["num_slots"], _ROW_TABLE + 64), jnp.int32, sharding=one_v5e_chip)
-    compiled = _compiled_fns(cfg)[0].lower(params, rows, described(jax.eval_shape(pool))).compile()
+    ids = jax.ShapeDtypeStruct((engine["num_slots"],), jnp.int32, sharding=one_v5e_chip)
+    compiled = _compiled_fns(cfg)[0].lower(params, rows, described(jax.eval_shape(pool)), ids).compile()
     text = compiled.as_text()
     assert "ragged-dot" in text  # the grouped matmul is the TPU's own, not a dense fallback
     pool_shape = re.escape("bf16[8,8193,16,640]")
