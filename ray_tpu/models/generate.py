@@ -35,7 +35,9 @@ capacity, run lossless here).
 
 from __future__ import annotations
 
+import math
 from functools import partial
+from typing import Any, NamedTuple
 
 import jax
 import jax.numpy as jnp
@@ -74,24 +76,58 @@ def _cache_rows(cfg: TransformerConfig) -> dict:
     return {"k": (cfg.n_kv_heads, cfg.head_dim), "v": (cfg.n_kv_heads, cfg.head_dim)}
 
 
+_WINDOW, _FULL = "window", "full"
+
+
+def _group_suffix(kind) -> str:
+    """A pool leaf of the window layers' group is named ``k_win`` / ``v_win``;
+    the full layers', and every layer's without a pattern, ``k`` / ``v``."""
+    return "_win" if kind == _WINDOW else ""
+
+
+def _cache_groups(cfg: TransformerConfig) -> dict:
+    """Kind -> layers of each group of pool leaves: one group (kind None) of
+    all layers, or under a layer pattern the full layers and the window
+    layers apart, each indexed by a layer's rank among its kind."""
+    if not cfg.layer_kinds:
+        return {None: cfg.n_layers}
+    return {kind: cfg.layer_kinds.count(kind) for kind in (_FULL, _WINDOW)}
+
+
+def cache_token_bytes(cfg: TransformerConfig) -> dict:
+    """Bytes one token holds in each group of cache leaves, all the group's
+    layers: ``{"full": n}``, and under a layer pattern ``"window"`` beside it."""
+    row = sum(math.prod(shape) for shape in _cache_rows(cfg).values()) * jnp.dtype(cfg.dtype).itemsize
+    return {kind or _FULL: layers * row for kind, layers in _cache_groups(cfg).items()}
+
+
 def init_cache(cfg: TransformerConfig, batch: int, max_len: int):
     """Preallocated cache, every leaf [L, B, max_len, ...] (``_cache_rows``):
     k/v [.., KV, Dh], or ckv [.., the latent and the rotary key] (bf16 on
-    TPU — cache reads are the decode bandwidth bill)."""
+    TPU — cache reads are the decode bandwidth bill). Under a layer pattern
+    the leaves of each kind's layers (``_cache_groups``); a dense cache keeps
+    ``max_len`` rows for a window layer too and masks them."""
     return {
-        name: jnp.zeros((cfg.n_layers, batch, max_len, *row), cfg.dtype)
+        name + _group_suffix(kind): jnp.zeros((layers, batch, max_len, *row), cfg.dtype)
+        for kind, layers in _cache_groups(cfg).items()
         for name, row in _cache_rows(cfg).items()
     }
 
 
-def _project_qkv(lp, x, positions, cfg):
-    """GQA: (q [B, T, H, Dh], the rows to cache {"k", "v"}: [B, T, KV, Dh])."""
+def _project_qkv(lp, x, positions, cfg, rope: bool = True):
+    """GQA: (q [B, T, H, Dh], the rows to cache {"k", "v"}: [B, T, KV, Dh]).
+    ``cfg.qk_norm``: queries and keys normed over the head's width first.
+    ``rope`` False: no positional encoding (a full layer of a layer pattern)."""
     B, T, _ = x.shape
     H, KV, Dh = cfg.n_heads, cfg.n_kv_heads, cfg.head_dim
     h = _rms_norm(x, lp["attn_norm"], cfg.norm_eps)
     q = (h @ lp["wq"].astype(h.dtype)).reshape(B, T, H, Dh)
     k = (h @ lp["wk"].astype(h.dtype)).reshape(B, T, KV, Dh)
     v = (h @ lp["wv"].astype(h.dtype)).reshape(B, T, KV, Dh)
+    if cfg.qk_norm:
+        q, k = _rms_norm(q, lp["q_norm"], cfg.norm_eps), _rms_norm(k, lp["k_norm"], cfg.norm_eps)
+    if not rope:
+        return q, {"k": k, "v": v}
     return _rope(q, positions, cfg.rope_theta), {"k": _rope(k, positions, cfg.rope_theta), "v": v}
 
 
@@ -163,8 +199,12 @@ def _mlp(lp, x, cfg, valid=None, layer=None):
     expert leaves of ``lp`` are whole stacks and this is the layer to run
     (``routed_experts``)."""
     h = _rms_norm(x, lp["mlp_norm"], cfg.norm_eps)
+
+    def add(out):  # ``cfg.post_norms``: the branch is normed once more before it joins the residual
+        return x + (_rms_norm(out, lp["mlp_post_norm"], cfg.norm_eps) if cfg.post_norms else out)
+
     if "gate" not in lp:
-        return x + _swiglu(h, lp["wg"], lp["wi"], lp["wo_mlp"]), None, None
+        return add(_swiglu(h, lp["wg"], lp["wi"], lp["wo_mlp"])), None, None
     if cfg.routed_experts:
         from ray_tpu.parallel.moe import routed_experts
 
@@ -176,7 +216,7 @@ def _mlp(lp, x, cfg, valid=None, layer=None):
         out = out.reshape(B, q, D)
         if "wg_s" in lp:
             out = out + _swiglu(h, lp["wg_s"], lp["wi_s"], lp["wo_s"])
-        return x + out, sent, chosen.reshape(B, q, -1)
+        return add(out), sent, chosen.reshape(B, q, -1)
     from ray_tpu.models.transformer import _moe_mlp
 
     # LOSSLESS dispatch at inference: capacity_factor=E gives every
@@ -187,7 +227,7 @@ def _mlp(lp, x, cfg, valid=None, layer=None):
     # approximation that inference deliberately does not replicate.
     # Aux loss is meaningless at inference and discarded.
     out, _aux = _moe_mlp(lp, h, float(cfg.num_experts))
-    return x + out, None, None
+    return add(out), None, None
 
 
 def _cache_attention(q, ck, cv, pos_mask, cfg):
@@ -221,20 +261,30 @@ def _embed_chunk(params, tokens, pos, cfg):
     B, q = tokens.shape
     pos_b = jnp.broadcast_to(pos, (B,))
     x = params["embed"].astype(cfg.dtype)[tokens]
+    if cfg.embed_multiplier != 1.0:
+        x = x * cfg.embed_multiplier
     offs = jnp.arange(q, dtype=jnp.int32)
     return x, pos_b[:, None] + offs[None, :]
 
 
-def _cache_mask(positions, n_keys: int, window: int, key_len=None):
+def _cache_mask(positions, n_keys: int, window: int, key_len=None, key_pos=None):
     """[B, q, n_keys], True = attend. Causal against the cache: the query at
     positions[b, j] sees rows at positions <= its own — under ``key_len[b]``
-    where given (a ragged prompt's padding) and within the sliding window."""
-    k_pos = jnp.arange(n_keys, dtype=jnp.int32)
-    mask = k_pos[None, None, :] <= positions[:, :, None]
+    where given (a ragged prompt's padding) and within the sliding window.
+    View row i holds position i, or ``key_pos[b, i]`` where given (a ring's
+    rows, ``_ring_access``; negative: a row nothing was written to yet)."""
+    if key_pos is None:
+        k_pos = jnp.arange(n_keys, dtype=jnp.int32)
+        keys = lambda: k_pos[None, None, :]  # noqa: E731
+    else:
+        keys = lambda: key_pos[:, None, :]  # noqa: E731
+    mask = keys() <= positions[:, :, None]
     if key_len is not None:
-        mask = mask & (k_pos[None, None, :] < key_len[:, None, None])
+        mask = mask & (keys() < key_len[:, None, None])
     if window:
-        mask &= positions[:, :, None] - k_pos[None, None, :] < window
+        mask &= positions[:, :, None] - keys() < window
+    if key_pos is not None:
+        mask &= keys() >= 0
     return mask
 
 
@@ -251,8 +301,11 @@ MOE_COUNTS = "moe_counts"
 # block found again in a prefix cache brings its tokens' choices with it). One
 # word a token a layer, the k expert ids in ``_expert_bits`` bits each
 # (``unpack_experts``): a minor axis of k would be padded to the TPU's 128
-# lanes. What a rollout hands its trainer for routing replay, and what the
-# benchmark's float32 reference is held to where two scores nearly tie.
+# lanes. A choice that one word cannot hold (8 of 128: 56 bits) takes as many
+# words as it needs, on a LEADING axis: [words, expert layers, ...]. Under a
+# layer pattern the words lie beside the full layers' blocks, which hold every
+# token of a row. What a rollout hands its trainer for routing replay, and what
+# the benchmark's float32 reference is held to where two scores nearly tie.
 MOE_CHOICE = "moe_choice"
 
 
@@ -267,67 +320,153 @@ def init_moe_counts(cfg: TransformerConfig):
 
 
 def _expert_bits(cfg: TransformerConfig) -> int:
-    bits = max(1, (cfg.num_experts - 1).bit_length())
-    if bits * cfg.experts_per_token > 31:
-        raise ValueError(
-            f"{cfg.experts_per_token} experts a token of {cfg.num_experts} do not fit one int32 word of {MOE_CHOICE}"
-        )
-    return bits
+    return max(1, (cfg.num_experts - 1).bit_length())
+
+
+def _choice_words(cfg: TransformerConfig) -> tuple:
+    """(int32 words a token a layer of ``MOE_CHOICE``, expert ids a word)."""
+    per_word = min(cfg.experts_per_token, 31 // _expert_bits(cfg))
+    return -(-cfg.experts_per_token // per_word), per_word
 
 
 def init_moe_choice(cfg: TransformerConfig, *rows: int):
     """``rows``: (batch, max_len) beside ``init_cache``, (num_blocks,
     block_size) beside ``init_paged_cache``."""
-    _expert_bits(cfg)
-    return jnp.zeros((cfg.n_layers - cfg.first_dense_layers, *rows), jnp.int32)
+    words, _ = _choice_words(cfg)
+    lead = (words,) if words > 1 else ()
+    return jnp.zeros((*lead, cfg.n_layers - cfg.first_dense_layers, *rows), jnp.int32)
 
 
 def unpack_experts(words, cfg: TransformerConfig):
-    """Words [...] of a ``MOE_CHOICE`` leaf -> expert ids [..., k] (NumPy or jax)."""
+    """Words of a ``MOE_CHOICE`` leaf, [...] or (a choice of several words)
+    [words, ...] -> expert ids [..., k] (NumPy or jax)."""
     bits = _expert_bits(cfg)
-    return (words[..., None] >> (bits * np.arange(cfg.experts_per_token))) & ((1 << bits) - 1)
+    n_words, per_word = _choice_words(cfg)
+    if n_words == 1:
+        return (words[..., None] >> (bits * np.arange(cfg.experts_per_token))) & ((1 << bits) - 1)
+    ids = (words[..., None] >> (bits * np.arange(per_word))) & ((1 << bits) - 1)  # [words, ..., per word]
+    ids = np.moveaxis(np.asarray(ids), 0, -2)
+    return ids.reshape(*ids.shape[:-2], -1)[..., : cfg.experts_per_token]
 
 
-def _cached_layers(params, x, cache, positions, write, view, cfg, key_len=None, valid=None):
+class _Access(NamedTuple):
+    """How the layers of one group reach their part of a cache:
+    ``write(c, l, rows)`` puts a chunk's rows [B, q, ...] of one leaf into
+    layer l of the group, ``view(c, l)`` takes the rows [B, S, ...] to attend
+    over, ``key_pos`` [B, S] is the position each view row holds (None: row i
+    holds position i)."""
+
+    write: Any
+    view: Any
+    key_pos: Any = None
+
+
+def _period(kinds: tuple) -> int:
+    """The shortest p with ``kinds[i] == kinds[i - p]`` throughout."""
+    return next(p for p in range(1, len(kinds) + 1) if all(kinds[i] == kinds[i - p] for i in range(p, len(kinds))))
+
+
+def _cached_layers(params, x, cache, positions, access, cfg, key_len=None, valid=None):
     """THE layer stack over a cache, dense or paged: x [B, q, D] at
     ``positions`` [B, q] -> (final normed hidden states, cache).
 
     The whole cache rides the layer scan as its CARRY (never xs -> ys, which
     are distinct buffers of the loop) and a layer reaches its part through
-    the layer index: ``write(c, l, rows)`` puts this chunk's rows [B, q, ...]
-    of one leaf into layer l, ``view(c, l)`` takes the rows [B, S, ...] to
-    attend over. A caller that donates ``cache`` gets it updated in place.
+    its index within its group of leaves (``_cache_groups``) and the group's
+    ``access`` (``_Access``; under a layer pattern one a kind, by kind). A
+    caller that donates ``cache`` gets it updated in place.
     Masked (p == 0) entries contribute nothing, so stale rows past a
     position, padding and null-block garbage stay invisible. The leading
     dense layers (``params["dense_layers"]``) run first, in a scan of their
     own, then ``params["layers"]``: a layer's index into the cache counts
     through both. ``valid`` [B, q]: the rows that are real tokens (read by
-    routed experts only)."""
+    routed experts only).
+
+    Without a pattern a stack is one homogeneous scan over its layers. With
+    one, a stack is scanned over the PERIODS of its kinds (``_period``): the
+    kind of a layer, which decides its mask, its rotary and its group, is
+    static inside the body, which runs one period; what is left of a stack
+    past its last whole period is unrolled behind the scan."""
     B, q = positions.shape
     counts = cache.get(MOE_COUNTS)
     pool = {name: leaf for name, leaf in cache.items() if name != MOE_COUNTS}
     latent = cfg.latent_attention
+    n_words, per_word = _choice_words(cfg) if cfg.routed_experts else (1, 0)
 
-    def body(first, held, carry, layer):
-        x, pool = carry
-        lp, l = layer
-        lp = {**lp, **held}
-        qh, rows = (_project_latent if latent else _project_qkv)(lp, x, positions, cfg)
-        pool = {**pool, **{name: write(pool[name], l, row) for name, row in rows.items()}}
-        with jax.named_scope("cache_attention"):
-            seen = {name: view(pool[name], l) for name in rows}
+    def run_layer(x, pool, lp, kind, at, l, first, held):
+        """One layer of kind ``kind`` (None: no pattern), layer ``at`` of its
+        group, layer ``l - first`` of its stack."""
+        acc = access[kind] if kind else access
+        sfx = _group_suffix(kind)
+        project = _project_latent if latent else _project_qkv if kind != _FULL else partial(_project_qkv, rope=False)
+        qh, rows = project(lp, x, positions, cfg)
+        pool = {**pool, **{name + sfx: acc.write(pool[name + sfx], at, row) for name, row in rows.items()}}
+        with jax.named_scope("cache_attention" + (f"_{kind}" if kind else "")):
+            seen = {name: acc.view(pool[name + sfx], at) for name in rows}
             n_keys = next(iter(seen.values())).shape[1]
-            mask = _cache_mask(positions, n_keys, cfg.sliding_window, key_len)
+            window = 0 if kind == _FULL else cfg.sliding_window
+            mask = _cache_mask(positions, n_keys, window, key_len, acc.key_pos)
             if latent:
                 o = _latent_attention(lp, qh, seen, mask, cfg)
             else:
                 o = _cache_attention(qh, seen["k"], seen["v"], mask, cfg).reshape(B, q, -1)
-        x = x + o @ lp["wo"].astype(o.dtype)
+        if cfg.attn_gate:
+            gate = _rms_norm(x, lp["attn_norm"], cfg.norm_eps) @ lp["wg_attn"].astype(x.dtype)
+            o = o * jax.nn.sigmoid(gate)
+        a = o @ lp["wo"].astype(o.dtype)
+        x = x + (_rms_norm(a, lp["attn_post_norm"], cfg.norm_eps) if cfg.post_norms else a)
         x, sent, chosen = _mlp(lp, x, cfg, valid, layer=l - first if held else None)
         if chosen is not None and MOE_CHOICE in pool:
-            words = jnp.sum(chosen << (_expert_bits(cfg) * jnp.arange(chosen.shape[-1])), axis=-1)
-            pool = {**pool, MOE_CHOICE: write(pool[MOE_CHOICE], l - first, words)}
+            put = (access[_FULL] if kind else access).write
+            if n_words == 1:
+                words = jnp.sum(chosen << (_expert_bits(cfg) * jnp.arange(chosen.shape[-1])), axis=-1)
+                pool = {**pool, MOE_CHOICE: put(pool[MOE_CHOICE], l - first, words)}
+            else:  # [words, expert layers, ...] written as [words * expert layers, ...]
+                leaf = pool[MOE_CHOICE]
+                flat = leaf.reshape(-1, *leaf.shape[2:])
+                shifts = _expert_bits(cfg) * jnp.arange(per_word)
+                for w in range(n_words):
+                    ids = chosen[..., w * per_word : (w + 1) * per_word]
+                    word = jnp.sum(ids << shifts[: ids.shape[-1]], axis=-1)
+                    flat = put(flat, w * leaf.shape[1] + (l - first), word)
+                pool = {**pool, MOE_CHOICE: flat.reshape(leaf.shape)}
+        return x, pool, sent
+
+    def body(first, held, carry, layer):
+        x, pool = carry
+        lp, l = layer
+        x, pool, sent = run_layer(x, pool, {**lp, **held}, None, l, l, first, held)
         return (x, pool), sent
+
+    def scan_periods(first, held, sliced, kinds, x, pool):
+        """A stack under a layer pattern: ``kinds`` of its layers, which are
+        layers ``first..`` of the model."""
+        P = _period(kinds)
+        before = {kind: cfg.layer_kinds[:first].count(kind) for kind in (_WINDOW, _FULL)}
+
+        def layer_at(x, pool, period, j):
+            # ``period`` traced (the scan's) or static (the remainder's)
+            kind, s = kinds[j], period * P + j
+            lp = {n: lax.dynamic_index_in_dim(leaf, s, 0, keepdims=False) for n, leaf in sliced.items()}
+            at = before[kind] + period * kinds[:P].count(kind) + kinds[:j].count(kind)
+            return run_layer(x, pool, {**lp, **held}, kind, at, first + s, first, held)
+
+        def one_period(carry, period):
+            x, pool = carry
+            sent = []
+            for j in range(P):
+                x, pool, s = layer_at(x, pool, period, j)
+                sent.append(s)
+            return (x, pool), None if sent[0] is None else jnp.stack(sent)
+
+        whole = len(kinds) // P
+        (x, pool), sent = lax.scan(one_period, (x, pool), jnp.arange(whole, dtype=jnp.int32))
+        sent = [] if sent is None else [sent.reshape(whole * P, -1)]
+        for j in range(len(kinds) % P):
+            x, pool, s = layer_at(x, pool, whole, j)
+            if s is not None:
+                sent.append(s[None])
+        return x, pool, jnp.concatenate(sent) if sent else None
 
     first, sent = 0, None
     for name in ("dense_layers", "layers"):
@@ -342,8 +481,11 @@ def _cached_layers(params, x, cache, positions, write, view, cfg, key_len=None, 
             # ``held`` and ``routed_experts(layer=)`` can go).
             held = {n: stack[n] for n in _EXPERT_STACKS if cfg.routed_experts and n in stack}
             sliced = {n: leaf for n, leaf in stack.items() if n not in held} if held else stack
-            layer_ids = jnp.arange(first, first + depth, dtype=jnp.int32)
-            (x, pool), sent = lax.scan(partial(body, first, held), (x, pool), (sliced, layer_ids))
+            if cfg.layer_kinds:
+                x, pool, sent = scan_periods(first, held, sliced, cfg.layer_kinds[first : first + depth], x, pool)
+            else:
+                layer_ids = jnp.arange(first, first + depth, dtype=jnp.int32)
+                (x, pool), sent = lax.scan(partial(body, first, held), (x, pool), (sliced, layer_ids))
             first += depth
     if counts is not None:  # sent [expert layers, E]: the routed stack's, which runs last
         touched = jnp.sum(sent > 0, axis=-1, keepdims=True)
@@ -374,6 +516,13 @@ def _dense_write(pos, positions):
     return lambda c, l, rows: c.at[l, batch, positions].set(rows)
 
 
+def _dense_access(cfg, write, view):
+    """A dense cache is reached the same way whatever a layer's kind: a window
+    layer keeps every row and its mask leaves out those behind the window."""
+    access = _Access(write, view)
+    return {kind: access for kind in (_WINDOW, _FULL)} if cfg.layer_kinds else access
+
+
 def prefill(params, tokens, cache, cfg: TransformerConfig, prompt_lens=None):
     """Run the prompt through the model, filling cache[:, :, :T].
 
@@ -397,9 +546,9 @@ def prefill(params, tokens, cache, cfg: TransformerConfig, prompt_lens=None):
     # Attend only over the prompt's T rows — the generation region of the
     # cache is not written yet; scoring it would waste S/T the FLOPs/HBM.
     # Causal within the prompt; per-row padding invisible.
-    write, view = _dense_write(pos, positions), lambda c, l: c[l][:, :T]
+    access = _dense_access(cfg, _dense_write(pos, positions), lambda c, l: c[l][:, :T])
     x, cache = _cached_layers(
-        params, x, cache, positions, write, view, cfg, key_len=prompt_lens,
+        params, x, cache, positions, access, cfg, key_len=prompt_lens,
         valid=positions < prompt_lens[:, None] if cfg.routed_experts else None,
     )
     return last_row_logits(params, x, prompt_lens - 1), cache, prompt_lens
@@ -412,8 +561,8 @@ def _decode_chunk_hidden(params, tokens, cache, pos, cfg: TransformerConfig):
     themselves (``last_row_logits``) instead of paying [B, q, V]."""
     pos = jnp.asarray(pos, jnp.int32)
     x, positions = _embed_chunk(params, tokens, pos, cfg)
-    write = _dense_write(pos, positions)
-    return _cached_layers(params, x, cache, positions, write, lambda c, l: c[l], cfg)
+    access = _dense_access(cfg, _dense_write(pos, positions), lambda c, l: c[l])
+    return _cached_layers(params, x, cache, positions, access, cfg)
 
 
 def decode_chunk(params, tokens, cache, pos, cfg: TransformerConfig):
@@ -465,16 +614,24 @@ def decode_step(params, token, cache, pos, cfg: TransformerConfig):
     return logits[:, 0], cache
 
 
-def init_paged_cache(cfg: TransformerConfig, num_blocks: int, block_size: int):
+def init_paged_cache(cfg: TransformerConfig, num_blocks: int, block_size: int, window_blocks: int = 0):
     """Block-pool cache for continuous-batching serving, every leaf
     [L, num_blocks, block_size, ...] (``_cache_rows``): k/v [.., KV, Dh], or with
     latent attention the one leaf ckv [.., the latent and the rotary key].
     Physical block 0 is RESERVED as the null block — allocators must never
     hand it out. Inactive decode slots and write-masked prefill padding rows
     are routed there, so the compiled step never needs a dynamic shape or a
-    conditional write."""
+    conditional write.
+
+    Under a layer pattern there are two groups of leaves (``_cache_groups``),
+    each with a null block of its own: the full layers' ``[full layers,
+    num_blocks, ...]``, reached through a row's block table as ever, and the
+    window layers' ``[window layers, window_blocks, ...]``, reached through a
+    row's RING (``_ring_access``): ``window_blocks`` is 1 + rings x blocks a ring."""
+    blocks = {None: num_blocks, _FULL: num_blocks, _WINDOW: window_blocks}
     return {
-        name: jnp.zeros((cfg.n_layers, num_blocks, block_size, *row), cfg.dtype)
+        name + _group_suffix(kind): jnp.zeros((layers, blocks[kind], block_size, *row), cfg.dtype)
+        for kind, layers in _cache_groups(cfg).items()
         for name, row in _cache_rows(cfg).items()
     }
 
@@ -501,18 +658,58 @@ def _paged_view(block_tables):
     return lambda c, l: c[l, block_tables].reshape(B, n_max * c.shape[2], *c.shape[3:])
 
 
+def ring_blocks(window: int, chunk: int, block_size: int) -> int:
+    """Blocks of a window layer's ring: the window and the widest chunk fed
+    at once, rounded up to blocks, and one more because neither starts on a
+    block's edge. Then no row a query of the chunk may still read (behind it
+    by less than the window) lies a whole ring behind the chunk's last row,
+    which is what overwrites it."""
+    return -(-(window + chunk) // block_size) + 1
+
+
+def _ring_access(ring_tables, positions, valid_to, block_size: int, n_view: int) -> _Access:
+    """The window layers' way into their group of a paged cache: a row's
+    ``ring_tables[b]`` names the R physical blocks of its ring, and logical
+    block j of the row lies at ring index ``j mod R``. Rows are written
+    through that; the view is the ring's first ``n_view`` blocks (all of it,
+    or as many as the step's longest row has touched where that is fewer),
+    and ring index i holds the newest logical block <= the last one written
+    that is congruent to i: ``key_pos`` says so to the mask, by position. A
+    part of a ring block not yet overwritten in this turn of the ring gets
+    the position it will hold: ahead of every query, so causality masks it."""
+    B, R = ring_tables.shape
+    ring_idx = (positions // block_size) % R
+    blk_phys = jnp.take_along_axis(ring_tables, ring_idx, axis=1)  # [B, q]
+    row_off = positions % block_size
+    if valid_to is not None:
+        blk_phys = jnp.where(positions < jnp.asarray(valid_to, jnp.int32)[:, None], blk_phys, 0)
+    newest = positions[:, -1:] // block_size  # [B, 1]: the logical block of the last row fed
+    held = newest - (newest - jnp.arange(n_view, dtype=jnp.int32)[None, :]) % R  # [B, n_view] logical blocks
+    key_pos = held[:, :, None] * block_size + jnp.arange(block_size, dtype=jnp.int32)[None, None, :]
+    return _Access(
+        lambda c, l, rows: c.at[l, blk_phys, row_off].set(rows),
+        _paged_view(ring_tables[:, :n_view]),
+        key_pos.reshape(B, n_view * block_size),
+    )
+
+
 def paged_decode_chunk_hidden(
-    params, tokens, cache, block_tables, pos, cfg: TransformerConfig, valid_to=None
+    params, tokens, cache, block_tables, pos, cfg: TransformerConfig, valid_to=None, ring_tables=None
 ):
     """``paged_decode_chunk`` without the head projection: returns the final
     normed hidden states [B, q, D] + cache. Chunked prefill consumes logits
     for at most ONE row per prompt — callers project that row themselves
-    (``last_row_logits``) instead of paying [B, q, V]."""
+    (``last_row_logits``) instead of paying [B, q, V]. ``ring_tables`` [B, R]
+    (a layer pattern only): each row's ring in the window layers' group."""
     pos = jnp.asarray(pos, jnp.int32)
     block_tables = jnp.asarray(block_tables, jnp.int32)
     x, positions = _embed_chunk(params, tokens, pos, cfg)
     block_size = next(iter(_pool_leaves(cache).values())).shape[2]
-    write = _paged_write(block_tables, positions, valid_to, block_size)
+    access = _Access(_paged_write(block_tables, positions, valid_to, block_size), _paged_view(block_tables))
+    if cfg.layer_kinds:
+        ring_tables = jnp.asarray(ring_tables, jnp.int32)
+        n_view = min(ring_tables.shape[1], block_tables.shape[1])
+        access = {_FULL: access, _WINDOW: _ring_access(ring_tables, positions, valid_to, block_size, n_view)}
     valid = None
     if cfg.routed_experts:
         # A live row's table starts at a real block; an inactive slot's, and
@@ -520,13 +717,11 @@ def paged_decode_chunk_hidden(
         valid = jnp.broadcast_to(block_tables[:, :1] != 0, positions.shape)
         if valid_to is not None:
             valid &= positions < jnp.asarray(valid_to, jnp.int32)[:, None]
-    return _cached_layers(
-        params, x, cache, positions, write, _paged_view(block_tables), cfg, valid=valid
-    )
+    return _cached_layers(params, x, cache, positions, access, cfg, valid=valid)
 
 
 def paged_decode_chunk(
-    params, tokens, cache, block_tables, pos, cfg: TransformerConfig, valid_to=None
+    params, tokens, cache, block_tables, pos, cfg: TransformerConfig, valid_to=None, ring_tables=None
 ):
     """``decode_chunk`` over a PAGED cache: tokens [B, q] written at per-row
     positions pos[b]..pos[b]+q-1, where logical position p of row b lives in
@@ -551,18 +746,18 @@ def paged_decode_chunk(
     match the dense-cache path row for row (the serving oracle).
     """
     x, cache = paged_decode_chunk_hidden(
-        params, tokens, cache, block_tables, pos, cfg, valid_to=valid_to
+        params, tokens, cache, block_tables, pos, cfg, valid_to=valid_to, ring_tables=ring_tables
     )
     return _logits(params, x), cache
 
 
-def paged_decode_step(params, token, cache, block_tables, pos, cfg: TransformerConfig):
+def paged_decode_step(params, token, cache, block_tables, pos, cfg: TransformerConfig, ring_tables=None):
     """One token per slot against the paged cache: token [B] int32 at
     per-slot positions ``pos`` [B]. The q=1 case of ``paged_decode_chunk``
     — the continuous-batching decode hot loop. Returns (logits [B, V] f32,
     updated cache)."""
     logits, cache = paged_decode_chunk(
-        params, token[:, None], cache, block_tables, pos, cfg
+        params, token[:, None], cache, block_tables, pos, cfg, ring_tables=ring_tables
     )
     return logits[:, 0], cache
 

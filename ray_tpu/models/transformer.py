@@ -80,15 +80,49 @@ class TransformerConfig:
     # k-block pruning in training and the decode position mask at
     # inference; not combinable with ring/Ulysses sequence parallelism.
     sliding_window: int = 0
+    # A layer pattern (inference only): one of ``"window"`` / ``"full"`` a layer,
+    # ``n_layers`` of them, or empty for a model whose layers are all alike. A
+    # window layer attends within ``sliding_window`` and ropes its queries and
+    # keys; a full layer attends over the whole context and carries NO
+    # positional encoding (the published ``afmoe`` layer). A paged cache then
+    # holds the two kinds apart: the full layers' blocks grow with the row, a
+    # window layer holds a ring no longer than the window and a prefill chunk
+    # (models/generate.py, serve/llm/engine.py).
+    layer_kinds: tuple = ()
+    # Width of a head; 0: ``d_model // n_heads`` (``__post_init__`` fills it in).
+    head_dim: int = 0
+    # What the ``afmoe`` layer adds to the GQA block (inference only), each a
+    # flag with its leaves in ``_layer_leaves``: the attention output is
+    # multiplied by ``sigmoid(h wg_attn)`` before ``wo``; queries and keys are
+    # RMSNorm-ed over the head's width with a learned weight before the rotary;
+    # each branch is RMSNorm-ed once more before it is added to the residual.
+    attn_gate: bool = False
+    qk_norm: bool = False
+    post_norms: bool = False
+    # Embeddings are multiplied by this as they are read (muP: sqrt(d_model));
+    # the table is drawn that much smaller, so that a layer's branch weighs as
+    # much beside the residual as without it.
+    embed_multiplier: float = 1.0
     # Fuse the LM-head projection into a chunked cross-entropy
     # (ops/losses.fused_lm_loss) so the [B*T, V] f32 logits tensor never
     # hits HBM — loss_fn only; forward() still returns full logits for
     # generation/eval paths.
     fused_loss: bool = True
 
-    @property
-    def head_dim(self) -> int:
-        return self.d_model // self.n_heads
+    def __post_init__(self):
+        # Frozen: derived values are set past the freeze, once. A list from a
+        # JSON configuration becomes the tuple a static jit argument must be.
+        if not self.head_dim:
+            object.__setattr__(self, "head_dim", self.d_model // self.n_heads)
+        kinds = tuple(self.layer_kinds)
+        object.__setattr__(self, "layer_kinds", kinds)
+        if kinds and (len(kinds) != self.n_layers or set(kinds) - {"window", "full"}):
+            raise ValueError(
+                f"layer_kinds names {len(kinds)} layers {sorted(set(kinds))}: need "
+                f"n_layers = {self.n_layers} of 'window' / 'full'"
+            )
+        if "window" in kinds and not self.sliding_window:
+            raise ValueError("layer_kinds has window layers and sliding_window is 0")
 
     @property
     def latent_attention(self) -> bool:
@@ -109,6 +143,18 @@ class TransformerConfig:
                 "dropless routed experts (experts_per_token > 0) have no backward "
                 "pass, balance loss or ep sharding"
             )
+        for field, what in (
+            ("layer_kinds", "a layer pattern (layer_kinds)"),
+            ("attn_gate", "gated attention (attn_gate)"),
+            ("qk_norm", "per-head query and key norms (qk_norm)"),
+            ("post_norms", "post-branch norms (post_norms)"),
+        ):
+            if getattr(self, field):
+                missing.append(f"{what} has no training block")
+        if self.head_dim * self.n_heads != self.d_model:
+            missing.append("a head_dim other than d_model // n_heads has no training block")
+        if self.embed_multiplier != 1.0:
+            missing.append("an embedding multiplier (embed_multiplier) has no training block")
         return "; ".join(missing)
 
 
@@ -190,9 +236,17 @@ def _layer_leaves(cfg: TransformerConfig, mlp: str) -> dict:
                 "wq": _Leaf(0, (D, H * Dh), s, ("embed", "heads")),
                 "wk": _Leaf(1, (D, KV * Dh), s, ("embed", "kv")),
                 "wv": _Leaf(2, (D, KV * Dh), s, ("embed", "kv")),
-                "wo": _Leaf(3, (H * Dh, D), s * out, ("heads", "embed")),
+                "wo": _Leaf(3, (H * Dh, D), (H * Dh) ** -0.5 * out, ("heads", "embed")),
             }
         )
+        if cfg.attn_gate:
+            leaves["wg_attn"] = _Leaf(13, (D, H * Dh), s, ("embed", "heads"))
+        if cfg.qk_norm:
+            leaves["q_norm"] = _Leaf(None, (Dh,), None, (None,))
+            leaves["k_norm"] = _Leaf(None, (Dh,), None, (None,))
+    if cfg.post_norms:
+        leaves["attn_post_norm"] = _Leaf(None, (D,), None, (None,))
+        leaves["mlp_post_norm"] = _Leaf(None, (D,), None, (None,))
     if mlp == "dense":
         leaves.update(
             {
@@ -254,26 +308,67 @@ def init_params(key, cfg: TransformerConfig) -> dict:
     # for value against the parent's). A latent or routed stack has up to
     # sixteen leaves and there may be two stacks, more than ``ks`` holds: each
     # stack then splits sixteen keys of its own from ``fold_in(key, stack)``.
-    own_keys = cfg.latent_attention or cfg.routed_experts or cfg.first_dense_layers > 0
+    own_keys = cfg.latent_attention or cfg.routed_experts or cfg.first_dense_layers > 0 or cfg.attn_gate
+
+    # Every drawn leaf is a program of its own to compile (about a second each
+    # on the TPU, PR 32) and there are up to forty: they are drawn side by side
+    # (the compiler runs outside the GIL), the values those of one after
+    # another, each leaf having its own key. Cold, an expert model's replica
+    # spent 34 of its 73 s here (v5e, PR 35), under a Serve that gives it 90.
+    drawn = _Drawn(key)
 
     def stack(i, L, mlp):
         keys = jax.random.split(jax.random.fold_in(key, i), 16) if own_keys else ks
         return {
             name: jnp.ones((L, *leaf.shape), leaf.dtype or dt)
             if leaf.key is None
-            else _draw_normal(keys[leaf.key], (L, *leaf.shape), leaf.scale, leaf.dtype or dt)
+            else drawn.later(keys[leaf.key], (L, *leaf.shape), leaf.scale, leaf.dtype or dt)
             for name, leaf in _layer_leaves(cfg, mlp).items()
         }
 
     params = {
-        "embed": _draw_normal(ks[8], (V, D), 1.0, dt),
+        "embed": drawn.later(ks[8], (V, D), 1.0 / cfg.embed_multiplier, dt),
         "norm_f": jnp.ones((D,), dt),
     }
     for i, (name, (L, mlp)) in enumerate(_layer_stacks(cfg).items()):
         params[name] = stack(i, L, mlp)
     if not cfg.tie_embeddings:
-        params["lm_head"] = _draw_normal(ks[9], (D, V), D**-0.5, dt)
-    return params
+        params["lm_head"] = drawn.later(ks[9], (D, V), D**-0.5, dt)
+    return drawn.now(params)
+
+
+class _Drawn:
+    """The draws of a parameter tree, asked for leaf by leaf (``later`` leaves
+    a placeholder in the tree) and made together (``now`` fills them in)."""
+
+    def __init__(self, key):
+        # Under a trace (``jax.eval_shape(init_params)``) the key belongs to the
+        # tracing thread: the draws are then made where they are asked for.
+        self.traced = isinstance(key, jax.core.Tracer)
+        self.jobs: list = []
+
+    def later(self, *draw):
+        if self.traced:
+            return _draw_normal(*draw)
+        self.jobs.append(draw)
+        return _Pending(len(self.jobs) - 1)
+
+    def now(self, tree):
+        if not self.jobs:
+            return tree
+        from concurrent.futures import ThreadPoolExecutor
+
+        with ThreadPoolExecutor(max_workers=8) as pool:
+            leaves = list(pool.map(lambda draw: _draw_normal(*draw), self.jobs))
+        return jax.tree.map(lambda leaf: leaves[leaf.job] if isinstance(leaf, _Pending) else leaf, tree)
+
+
+class _Pending:
+    """Stands in a parameter tree for the leaf that draw ``job`` will give
+    (a plain object: a leaf to ``jax.tree.map``, which a tuple is not)."""
+
+    def __init__(self, job: int):
+        self.job = job
 
 
 def param_logical_axes(cfg: TransformerConfig) -> dict:

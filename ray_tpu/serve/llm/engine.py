@@ -251,7 +251,10 @@ _ROW_VALID_TO = 0  # prefill (its tokens are an argument of their own): the teac
 _ROW_POS = 1  # position of the first token fed
 _ROW_DRAW = slice(2, 6)  # LLMRequest._sched_draw
 _ROW_COUNTER = 6  # index of the token this dispatch draws
-_ROW_TABLE = 7  # the block table from here on: n_max wide (prefill), a rung of _view_rungs (decode)
+# The block table from here on: n_max wide (prefill), a rung of _view_rungs
+# (decode). Under a layer pattern the row's ring in the window layers' group
+# comes first (``LLMEngine.ring_blocks`` wide), then the table.
+_ROW_TABLE = 7
 
 # In a decode row's token column: the token is the id the step before drew for
 # this slot, still on the device (``decode``'s ``ids`` argument).
@@ -297,15 +300,16 @@ _JIT_CACHE: dict = {}
 _JIT_LOCK = threading.Lock()
 
 
-def _compiled_fns(cfg):
+def _compiled_fns(cfg, ring: int = 0):
     """(decode, prefill): ``decode(params, rows [num_slots, 7 + w], pool,
     ids [num_slots])``, ``w`` a rung of ``_view_rungs`` (one compiled program
     each) and ``ids`` what the step before returned (a row whose token column
     is ``_ID_IN_FLIGHT`` feeds its slot's), and ``prefill(params, tokens
     [1, q], pool, rows [1, 7 + n_max])``, both ``-> (token ids int32, one a
-    row, pool)``."""
+    row, pool)``. ``ring`` (a layer pattern only): blocks of a row's ring,
+    which a row carries ahead of its table (``7 + ring + w`` columns)."""
     with _JIT_LOCK:
-        fns = _JIT_CACHE.get(cfg)
+        fns = _JIT_CACHE.get((cfg, ring))
         if fns is None:
             import jax
             import jax.numpy as jnp
@@ -316,12 +320,18 @@ def _compiled_fns(cfg):
                 paged_decode_step,
             )
 
+            def tables(rows):
+                if not ring:
+                    return dict(block_tables=rows[:, _ROW_TABLE:])
+                return dict(
+                    block_tables=rows[:, _ROW_TABLE + ring :],
+                    ring_tables=rows[:, _ROW_TABLE : _ROW_TABLE + ring],
+                )
+
             def decode_rows(p, rows, c, ids):
                 fed = rows[:, _ROW_TOKEN]
                 fed = jnp.where(fed == _ID_IN_FLIGHT, ids, fed)
-                logits, c = paged_decode_step(
-                    p, fed, c, rows[:, _ROW_TABLE:], rows[:, _ROW_POS], cfg
-                )
+                logits, c = paged_decode_step(p, fed, c, **tables(rows), pos=rows[:, _ROW_POS], cfg=cfg)
                 return _draw_row_tokens(logits, rows), c
 
             def prefill_chunk_row(p, t, c, rows):
@@ -330,9 +340,7 @@ def _compiled_fns(cfg):
                 # just that row instead of paying the [1, q, V] head matmul
                 # per chunk (the row is traced: no recompile per position).
                 pos, valid_to = rows[:, _ROW_POS], rows[:, _ROW_VALID_TO]
-                x, c = paged_decode_chunk_hidden(
-                    p, t, c, rows[:, _ROW_TABLE:], pos, cfg, valid_to=valid_to
-                )
+                x, c = paged_decode_chunk_hidden(p, t, c, pos=pos, cfg=cfg, valid_to=valid_to, **tables(rows))
                 row = jnp.clip(valid_to - 1 - pos, 0, t.shape[1] - 1)
                 return _draw_row_tokens(last_row_logits(p, x, row), rows), c
 
@@ -345,7 +353,7 @@ def _compiled_fns(cfg):
                 jax.jit(lambda p, rows, c, ids: decode_rows(p, rows, c, ids), donate_argnums=2),
                 jax.jit(prefill_chunk_row, donate_argnums=2),
             )
-            _JIT_CACHE[cfg] = fns
+            _JIT_CACHE[(cfg, ring)] = fns
         return fns
 
 
@@ -354,6 +362,14 @@ def _compiled_fns(cfg):
 _LATENT_POOL_KV_PAYLOAD = (
     "{what} needs the KV transfer plane, whose payload layout is keys and values; "
     "a latent-attention pool holds one latent row a token (kv_transfer.py, ROADMAP D5)"
+)
+# And of ONE group of layers. Under a layer pattern a prefix is more than its
+# blocks: the window layers' rows of its last ``sliding_window`` tokens live in
+# the ring of the request that computed them and go when it ends.
+_PATTERN_POOL_KV_PAYLOAD = (
+    "{what} needs the KV transfer plane, whose payload is one group of layers' blocks; "
+    "under a layer pattern (layer_kinds) the window layers keep a ring a request, "
+    "which no block table names (kv_transfer.py, ROADMAP R3)"
 )
 
 
@@ -372,14 +388,15 @@ class _Step:
     (a row's request may leave its slot before the fetch), and what the
     iteration that emits its tokens reports of it."""
 
-    __slots__ = ("ids", "reqs", "slots", "width", "context_tokens")
+    __slots__ = ("ids", "reqs", "slots", "width", "context_tokens", "window_tokens")
 
-    def __init__(self, ids, reqs: list, width: int, context_tokens: int):
+    def __init__(self, ids, reqs: list, width: int, context_tokens: int, window_tokens: int):
         self.ids = ids
         self.reqs = reqs
         self.slots = [r._sched_slot for r in reqs]
         self.width = width
         self.context_tokens = context_tokens
+        self.window_tokens = window_tokens
 
 
 class LLMEngine:
@@ -401,18 +418,19 @@ class LLMEngine:
         from ray_tpu.models.generate import (
             MOE_CHOICE,
             MOE_COUNTS,
+            cache_token_bytes,
             init_moe_choice,
             init_moe_counts,
             init_paged_cache,
+            ring_blocks,
         )
 
         if role not in ("both", "prefill", "decode"):
             raise ValueError(f"role must be both|prefill|decode, got {role!r}")
-        if cfg.latent_attention and (role != "both" or cluster_prefix):
+        if (cfg.latent_attention or cfg.layer_kinds) and (role != "both" or cluster_prefix):
+            refusal = _LATENT_POOL_KV_PAYLOAD if cfg.latent_attention else _PATTERN_POOL_KV_PAYLOAD
             raise ValueError(
-                _LATENT_POOL_KV_PAYLOAD.format(
-                    what=f"role={role!r}" if role != "both" else "cluster_prefix=True"
-                )
+                refusal.format(what=f"role={role!r}" if role != "both" else "cluster_prefix=True")
             )
         self.params = params
         self.cfg = cfg
@@ -454,11 +472,24 @@ class LLMEngine:
         t0 = time.monotonic()
         import jax
 
-        pool = init_paged_cache(cfg, self.num_blocks, self.block_size)
-        # Bytes one token of context holds in the pool, all layers.
-        self.kv_token_bytes = sum(
-            leaf.nbytes // (self.num_blocks * self.block_size) for leaf in pool.values()
+        # Under a layer pattern the window layers have a group of pool leaves
+        # of their own, in which every slot owns one RING of ``ring_blocks``
+        # blocks for good (slot s: blocks 1 + s * ring_blocks ..): a request
+        # has it whole from admission to its end, nothing of it is allocated
+        # or freed, and however long the row grows a window layer holds and
+        # gathers no more. The full layers keep the block tables above.
+        self.ring_blocks = (
+            ring_blocks(cfg.sliding_window, self.prefill_chunk, self.block_size) if cfg.layer_kinds else 0
         )
+        self.num_window_blocks = self.num_slots * self.ring_blocks + 1 if cfg.layer_kinds else 0
+        self._rings = 1 + np.arange(self.num_slots * self.ring_blocks, dtype=np.int32).reshape(
+            self.num_slots, self.ring_blocks
+        )
+        pool = init_paged_cache(cfg, self.num_blocks, self.block_size, self.num_window_blocks)
+        # Bytes one token holds in each group of pool leaves, all its layers,
+        # and in the pool at large.
+        self._group_token_bytes = cache_token_bytes(cfg)
+        self.kv_token_bytes = sum(self._group_token_bytes.values())
         if cfg.routed_experts:
             # Expert counters ride the pool through both programs, donated
             # with it and updated on the device; ``stats()`` reads them.
@@ -525,7 +556,7 @@ class LLMEngine:
         # The decode step in flight: dispatched, its ids not fetched.
         self._inflight: Optional[_Step] = None
         t0 = time.monotonic()
-        self._decode_fn, self._prefill_fn = _compiled_fns(cfg)
+        self._decode_fn, self._prefill_fn = _compiled_fns(cfg, self.ring_blocks)
         self.spans.setup["jit_build_s"] = time.monotonic() - t0
         t0 = time.monotonic()
         self._build_decode_rungs()
@@ -621,7 +652,11 @@ class LLMEngine:
         # prompt token always runs through prefill so admission has logits
         # to sample the first output from.
         n_hashable = (len(tokens) - 1) // self.block_size
-        req._sched_hashes = block_hashes(tokens, self.block_size)[:n_hashable]
+        # Under a layer pattern no block is registered and no hit taken: a hit
+        # would also need the window layers' rows of the prefix's last
+        # ``sliding_window`` tokens, which went with the ring that held them.
+        if not self.cfg.layer_kinds:
+            req._sched_hashes = block_hashes(tokens, self.block_size)[:n_hashable]
         if len(resume) >= int(max_new_tokens):
             # Already complete on arrival (the dead replica emitted the last
             # token but not the terminal event): nothing to generate.
@@ -634,6 +669,8 @@ class LLMEngine:
         if kv_import is not None:
             if self.cfg.latent_attention:
                 raise ValueError(_LATENT_POOL_KV_PAYLOAD.format(what="kv_import"))
+            if self.cfg.layer_kinds:
+                raise ValueError(_PATTERN_POOL_KV_PAYLOAD.format(what="kv_import"))
             self._attach_handoff_import(req, kv_import)
         elif self.cluster_prefix and not resume and req._sched_hashes:
             self._attach_cluster_prefix(req)
@@ -684,6 +721,7 @@ class LLMEngine:
         return {
             **({"moe": moe} if moe else {}),
             "kv_token_bytes": self.kv_token_bytes,
+            "kv_groups": self._kv_groups(),
             "num_blocks": self.num_blocks - 1,
             "free_blocks": len(self._free),
             "cached_blocks": len(self._prefix),
@@ -697,6 +735,27 @@ class LLMEngine:
             "decode_width_steps": dict(self._width_steps),
             **self.spans.totals(),
         }
+
+    def _kv_groups(self) -> dict:
+        """Per group of pool leaves: its layers' bytes a token, its blocks and
+        those in use. ``"full"``: the layers whose blocks grow with a row (all
+        of them without a layer pattern). ``"window"``: the window layers of a
+        pattern, ``ring_blocks`` a running request whatever its length."""
+        groups = {
+            "full": {
+                "kv_token_bytes": self._group_token_bytes["full"],
+                "num_blocks": self.num_blocks - 1,
+                "blocks_in_use": self.num_blocks - 1 - len(self._free),
+            }
+        }
+        if self.cfg.layer_kinds:
+            groups["window"] = {
+                "kv_token_bytes": self._group_token_bytes["window"],
+                "num_blocks": self.num_window_blocks - 1,
+                "blocks_in_use": self.ring_blocks * sum(r is not None for r in self._slots),
+                "ring_blocks": self.ring_blocks,
+            }
+        return groups
 
     @any_thread
     def _moe_stats(self) -> Optional[dict]:
@@ -1277,15 +1336,13 @@ class LLMEngine:
                 sp.set(finished=int(req._finished))
         return True
 
-    @staticmethod
-    def _program_rows(n: int, width: int) -> np.ndarray:
+    def _program_rows(self, n: int, width: int) -> np.ndarray:
         """``n`` all-zero rows of a program's int32 input, their block table
-        ``width`` blocks wide: an inactive slot (token 0 at position 0 of the
-        null block, drawn greedily)."""
-        return np.zeros((n, _ROW_TABLE + width), np.int32)
+        ``width`` blocks wide (behind the ring, under a layer pattern): an
+        inactive slot (token 0 at position 0 of the null block, drawn greedily)."""
+        return np.zeros((n, _ROW_TABLE + self.ring_blocks + width), np.int32)
 
-    @staticmethod
-    def _fill_row(row: np.ndarray, req: LLMRequest, first: int, pos: int, ahead: int = 0):
+    def _fill_row(self, row: np.ndarray, req: LLMRequest, first: int, pos: int, ahead: int = 0):
         """``first``: column 0, the token fed (decode) or valid_to (prefill);
         ``ahead``: 1 if a step in flight draws a token of ``req`` before this
         dispatch draws its own."""
@@ -1293,7 +1350,9 @@ class LLMEngine:
         row[_ROW_POS] = pos
         row[_ROW_DRAW] = req._sched_draw
         row[_ROW_COUNTER] = len(req._sched_generated) + ahead
-        row[_ROW_TABLE : _ROW_TABLE + len(req._sched_table)] = req._sched_table
+        table = _ROW_TABLE + self.ring_blocks
+        row[_ROW_TABLE:table] = self._rings[req._sched_slot]
+        row[table : table + len(req._sched_table)] = req._sched_table
 
     def _run_donated(self, fn, tokens, *rest):
         """Dispatch one pool-updating program. The pool is donated: the
@@ -1320,22 +1379,30 @@ class LLMEngine:
         one dispatch of all-inactive rows each, which writes row 0 of the null
         block and nothing else. A width first met while serving would compile
         for seconds inside a stream."""
+        from concurrent.futures import ThreadPoolExecutor
+
         import jax
         import jax.numpy as jnp
 
         ids = jnp.zeros((self.num_slots,), jnp.int32)
-        for width in self._view_rungs:
-            rows = jnp.asarray(self._program_rows(self.num_slots, width))
-            # Traced, lowered and compiled apart from the call, and all three
-            # held until it returns: the call then finds the trace and the
-            # lowering in jit's caches, which keep them only while these
-            # objects live. In a v5e replica that is 0.6 s a rung where the
-            # call alone takes 0.94 (PERF.md, PR 31).
+        # Traced, lowered and compiled apart from the call, and all three
+        # held until it returns: the call then finds the trace and the
+        # lowering in jit's caches, which keep them only while these
+        # objects live. In a v5e replica that is 0.6 s a rung where the
+        # call alone takes 0.94 (PERF.md, PR 31). The rungs are compiled
+        # side by side (the compiler runs outside the GIL): cold, a rung of a
+        # five-layer expert model takes it 8 s, seven of them in a row more
+        # than Serve gives a replica to become ready (PERF.md, PR 35).
+        rungs = [jnp.asarray(self._program_rows(self.num_slots, width)) for width in self._view_rungs]
+        lowered = []
+        for rows in rungs:
             traced = self._decode_fn.trace(self.params, rows, self._cache, ids)
-            lowered = traced.lower()
-            held = (traced, lowered, lowered.compile())
+            lowered.append((traced, traced.lower()))
+        with ThreadPoolExecutor(max_workers=len(rungs)) as pool:
+            held = lowered, list(pool.map(lambda pair: pair[1].compile(), lowered))
+        for rows in rungs:
             ids = jax.block_until_ready(self._run_donated(self._decode_fn, rows, ids))
-            del held
+        del held
         # What a step with no step before it is given as ``ids`` (none of its
         # rows reads them): a program's own output, like every other step's.
         self._no_ids = ids
@@ -1449,7 +1516,8 @@ class LLMEngine:
             longest = max(len(r._sched_table) for r in active)
             width = next(w for w in self._view_rungs if w >= longest)
             rows = self._program_rows(self.num_slots, width)
-            context_tokens = 0
+            context_tokens = window_tokens = 0
+            window = self.cfg.sliding_window or self.max_model_len
             for req in active:
                 ahead = req in riding
                 self._fill_row(
@@ -1460,6 +1528,7 @@ class LLMEngine:
                     ahead,
                 )
                 context_tokens += writes_at(req) + 1
+                window_tokens += min(writes_at(req) + 1, window)
             rows = jnp.asarray(rows)
         self._width_steps[width] += 1
         self._counts["decode_steps"] += 1
@@ -1468,7 +1537,7 @@ class LLMEngine:
             ids = self._run_donated(
                 self._decode_fn, rows, ahead_of.ids if ahead_of is not None else self._no_ids
             )
-        return _Step(ids, active, width, context_tokens)
+        return _Step(ids, active, width, context_tokens, window_tokens)
 
     def _land_step(self, step: _Step):
         """Fetch a dispatched step's ids and emit them, each to the request
@@ -1476,7 +1545,8 @@ class LLMEngine:
         or preempted since the dispatch has left it, and its id is dropped."""
         spans = self.spans
         spans.carried(
-            rows=len(step.reqs), view_blocks=step.width, context_tokens=step.context_tokens
+            rows=len(step.reqs), view_blocks=step.width, context_tokens=step.context_tokens,
+            window_tokens=step.window_tokens,
         )
         with spans.span("llm.decode.fetch"):
             # Waits for this step (the next is already queued behind it, and
@@ -1515,8 +1585,9 @@ class LLMEngine:
 
         fed = len(req.prompt) + len(req._sched_generated) - 1
         table = np.asarray(req._sched_table[: -(-fed // self.block_size)], np.int32)
-        words = np.asarray(self._cache[MOE_CHOICE][:, table])  # [expert layers, blocks, Bs]
-        return unpack_experts(words.reshape(words.shape[0], -1)[:, :fed].T, self.cfg)
+        words = np.asarray(self._cache[MOE_CHOICE][..., table, :])  # [(words,) expert layers, blocks, Bs]
+        words = words.reshape(*words.shape[:-2], -1)[..., :fed]
+        return unpack_experts(np.swapaxes(words, -1, -2), self.cfg)  # [fed, expert layers, k]
 
     def _emit_token(self, req: LLMRequest, tok: int):
         req._sched_generated.append(tok)

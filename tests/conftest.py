@@ -45,7 +45,7 @@ def pytest_configure(config):
 # rewords it (PERF.md section 7), and its intent is tested in a new file. The
 # marks are strict: a test that is reworded and passes fails its mark, so that
 # the mark goes with the rewording and cannot outlive it.
-_WRONG_SINCE_A_SECOND_ARCHITECTURE = {
+_WRONG_SINCE_A_SECOND_ARCHITECTURE = {  # the first four (PR 32)
     "test_bench_costs.py::test_the_configuration_files_hold_the_published_widths": (
         "holds EVERY configuration of BENCHMARK.json to Mistral-7B's widths; since PR 32 one is "
         "GLM-4.7-Flash. test_bench_glm.py::test_each_configuration_holds_its_own_published_widths "
@@ -64,6 +64,18 @@ _WRONG_SINCE_A_SECOND_ARCHITECTURE = {
         "holds every serving mix under 2560 tokens a request, one configuration's max_model_len; "
         "rollout-long runs under 4096. test_bench_glm.py::test_every_mix_fits_the_configurations_"
         "that_run_it holds each mix to its own cells' limits, and the seeds' equal load there too"
+    ),
+    # And since a third (PR 35):
+    "test_bench_glm.py::test_each_configuration_holds_its_own_published_widths": (
+        "looks every configuration's architecture up in a table of two and holds every `reduced` to "
+        "['num_hidden_layers']; since PR 35 one is Trinity-Mini, whose cut of depth takes its leading dense "
+        "layers and its list of layer types with it. test_bench_trinity.py::"
+        "test_each_configuration_holds_its_own_published_keys holds each to its own, by architecture"
+    ),
+    "test_bench_traffic.py::test_two_seeds_offer_the_same_token_load[rollout-longctx]": (
+        "holds every serving mix under 2560 tokens a request, one configuration's max_model_len; "
+        "rollout-longctx runs under 9216. test_bench_trinity.py::test_every_mix_fits_the_cells_that_send_it "
+        "holds each mix to its own cells' limits, a session's later turns too, and the seeds' equal load"
     ),
 }
 

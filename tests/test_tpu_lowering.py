@@ -257,3 +257,102 @@ def test_a_scanned_slice_of_an_expert_stack_is_copied_and_a_whole_stack_is_not(o
             compiled.memory_analysis().temp_size_in_bytes >= one_layer,
         )
     assert copied[sliced] == (True, True) and copied[whole] == (False, False)
+
+
+def _cell_programs(cell_name: str):
+    """(decode, prefill, their arguments as shapes) of a serving cell of the
+    benchmark at its published widths: ``args(width)`` for the decode program
+    at a rung, ``args(None)`` for the prefill chunk."""
+    import jax
+    import jax.numpy as jnp
+
+    from benchmarks.harness import registry
+    from ray_tpu.models.generate import (
+        MOE_CHOICE, MOE_COUNTS, init_moe_choice, init_moe_counts, init_paged_cache, ring_blocks,
+    )
+    from ray_tpu.models.transformer import TransformerConfig, init_params
+    from ray_tpu.serve.llm.engine import _ROW_TABLE, _compiled_fns
+
+    cell = registry.load_cell(registry.load_manifest(), cell_name)
+    engine = cell["config"]["deployment"]["engine"]
+    model = registry.load_architecture(cell, "config").model_config(
+        cell["config"], engine["max_model_len"], "bfloat16"
+    )
+    model.update(dtype=jnp.bfloat16, param_dtype=jnp.bfloat16)
+    cfg = TransformerConfig(**model)
+    slots, chunk, bs = engine["num_slots"], engine.get("prefill_chunk", 32), engine["block_size"]
+    ring = ring_blocks(cfg.sliding_window, chunk, bs) if cfg.layer_kinds else 0
+
+    def pool():
+        blocks = engine["num_blocks"], bs
+        leaves = init_paged_cache(cfg, *blocks, window_blocks=slots * ring + 1 if ring else 0)
+        if cfg.routed_experts:
+            leaves.update({MOE_COUNTS: init_moe_counts(cfg), MOE_CHOICE: init_moe_choice(cfg, *blocks)})
+        return leaves
+
+    params = jax.eval_shape(lambda: init_params(jax.random.PRNGKey(0), cfg))
+    ints = lambda *shape: jax.ShapeDtypeStruct(shape, jnp.int32)  # noqa: E731
+    n_max = -(-engine["max_model_len"] // bs)
+
+    def args(width):
+        if width is None:
+            return params, ints(1, chunk), jax.eval_shape(pool), ints(1, _ROW_TABLE + ring + n_max)
+        return params, ints(slots, _ROW_TABLE + ring + width), jax.eval_shape(pool), ints(slots)
+
+    return (*_compiled_fns(cfg, ring), args)
+
+
+# sha1 of the StableHLO text of the decode program (at the 64-block rung) and of
+# the prefill chunk, lowered for the TPU at the benchmark's widths, as PR 34's
+# tree gives them (computed from a copy of that commit, PR 35).
+_PROGRAMS_OF_PR_34 = {
+    "serve16.chat-open": ("80f55ef50a78c761acd079a445c783f338c74f07", "12bea586c90179b8fe8ea8526dc82e3f9d61ecfa"),
+    "glm8.rollout-long": ("cba2cf83a22dbe9cf5dcbe722bd4d53900cfa782", "70387e17a53cdd651ef0cd0d932cd22eff73366e"),
+}
+
+
+@pytest.mark.parametrize("cell_name", sorted(_PROGRAMS_OF_PR_34))
+def test_a_configuration_without_a_layer_pattern_keeps_the_programs_it_had(cell_name):
+    """Runs everywhere: lowering FOR the TPU needs no TPU. PR 35 gave the cached
+    layer a second kind, the pool a second group and the program rows a ring; a
+    configuration without ``layer_kinds`` (Mistral's three cells, GLM's one)
+    must get none of it: operation for operation the programs PR 34 built. A PR
+    that changes them on purpose computes the new digests and says what moved."""
+    import hashlib
+
+    decode, prefill, args = _cell_programs(cell_name)
+    texts = (
+        decode.trace(*args(64)).lower(lowering_platforms=("tpu",)).as_text(),
+        prefill.trace(*args(None)).lower(lowering_platforms=("tpu",)).as_text(),
+    )
+    assert tuple(hashlib.sha1(t.encode()).hexdigest() for t in texts) == _PROGRAMS_OF_PR_34[cell_name]
+
+
+def test_the_pattern_decode_step_gathers_rings_and_copies_neither_pools_nor_experts(one_v5e_chip):
+    """Trinity-Mini's decode program at the benchmark's widths and the cell's
+    rung (8192 tokens), compiled for the v5e (PR 35): a window layer's view is
+    its ring (161 blocks a row), never the rung's 512; both groups of the pool
+    are updated in place; the layers are scanned by period with their weights
+    indexed inside the body, which must not copy a layer's expert matrices."""
+    import re
+
+    import jax
+
+    decode, _, args = _cell_programs("trinity5.rollout-longctx")
+    described = jax.tree.map(lambda a: jax.ShapeDtypeStruct(a.shape, a.dtype, sharding=one_v5e_chip), args(512))
+    compiled = decode.lower(*described).compile()
+    text = compiled.as_text()
+    assert "ragged-dot" in text
+    gathered = re.findall(r"= bf16\[(\d+),16,4,128\]\S* fusion\(", text)
+    # keys and values: four window layers through 32 rings of 161 blocks, one full layer through 32 tables of 512
+    assert gathered.count("5152") == 8 and gathered.count("16384") == 2, gathered
+    # whatever else has a block's shape is a pool leaf's own scatter, in place: no wider view of either group
+    assert set(gathered) <= {"5152", "16384", "18433", "5153"}, gathered
+    for pool_shape in ("bf16[1,18433,16,4,128]", "bf16[4,5153,16,4,128]", "s32[2,4,18433,16]", "s32[8,18433,16]"):
+        assert not re.search(rf"= {re.escape(pool_shape)}\S* copy\(", text), pool_shape
+    assert not re.search(r"= bf16\[128,(2048,1024|1024,2048)\]\S* (fusion|copy)\(", text)
+    stats = compiled.memory_analysis()
+    pools = 2 * (18433 + 4 * 5153) * 16 * 4 * 128 * 2 + 2 * 4 * 18433 * 16 * 4
+    assert stats.alias_size_in_bytes >= pools  # 1.29 GB updated in place
+    # the full layer's view of 32 x 8192 tokens (0.27 GB, keys then values) and little else
+    assert stats.temp_size_in_bytes < 450e6, stats.temp_size_in_bytes
