@@ -554,7 +554,7 @@ def loss_fn(params, batch, cfg: TransformerConfig, mesh=None, attn_impl: str = "
         from ray_tpu.ops.losses import fused_lm_loss
 
         x, aux = forward_hidden(params, inputs, cfg, mesh=mesh, attn_impl=attn_impl)
-        return fused_lm_loss(x, _head(params), targets) + 0.01 * aux
+        return fused_lm_loss(x, _head(params), targets, mesh=mesh) + 0.01 * aux
     logits, aux = forward(params, inputs, cfg, mesh=mesh, attn_impl=attn_impl)
     logp = jax.nn.log_softmax(logits, axis=-1)
     nll = -jnp.take_along_axis(logp, targets[..., None], axis=-1)[..., 0]
@@ -567,8 +567,12 @@ def make_train_step(cfg: TransformerConfig, optimizer, mesh=None, attn_impl: str
     Pure function — callers jit it with in/out shardings, and with
     ``donate_argnums=(0, 1)`` where they rebind parameters and optimizer
     state to the result (see train/jax/ and __graft_entry__.py). Gradients
-    are averaged over the batch; under a dp/fsdp-sharded batch pjit inserts
-    the psum automatically.
+    are averaged over the batch; under a dp/fsdp-sharded batch the
+    partitioner inserts their all-reduce. The loss is not left to it: given
+    a ``mesh`` that splits the tokens, the fused loss scans each device's own
+    rows inside a shard_map and sums one scalar and the head's gradient
+    across devices (ops/losses.py; a mesh with ``tp`` > 1 keeps the
+    partitioner's path), as the flash call runs on each device's own rows.
     """
     _refuse_inference_only(cfg, "make_train_step")
 

@@ -1,20 +1,33 @@
 """Loss kernels.
 
 ``fused_lm_loss``: next-token cross-entropy fused with the LM-head matmul,
-computed over sequence chunks so the full ``[B*T, V]`` f32 logits tensor is
-never materialized. At bench shapes (B8 T1024 V32k) the unfused loss writes
-~1 GiB of f32 logits + log-softmax intermediates to HBM in the forward and
-reads them back in the backward — pure bandwidth, no MXU work. The chunked
-form keeps one ``[chunk, V]`` tile live at a time (64 MiB at chunk=512) and
-recomputes it in the backward: classic flash-style trade of FLOPs for HBM,
-the same rematerialisation XLA cannot do on its own across the
-matmul+softmax+gather boundary.
+computed over chunks of tokens so the full ``[B*T, V]`` f32 logits tensor is
+never materialized. The unfused loss writes those logits and their
+log-softmax to HBM in the forward and reads them back in the backward (at
+Mistral's training widths, T 4096 and V 32000, 0.5 GB a sequence each) —
+pure bandwidth, no MXU work. The chunked form keeps one ``[chunk, V]`` tile
+live at a time (64 MiB at chunk=512) and recomputes it in the backward:
+classic flash-style trade of FLOPs for HBM, the same rematerialisation XLA
+cannot do on its own across the matmul+softmax+gather boundary.
 
 Forward per chunk: ``logits = x_c @ head; lse = logsumexp(logits);
 nll_c = lse - logits[target]``. Backward per chunk:
 ``p = exp(logits - lse); p[target] -= 1; dx_c = g/N * (p @ head^T);
 dhead += x_c^T @ (g/N * p)`` — the standard softmax-CE gradient, rebuilt
 blockwise from the saved (tiny) ``lse`` rather than saved logits.
+
+Under a mesh that splits the tokens (``dp``, ``fsdp``, ``sp``: the axes of
+the ``batch`` and ``seq`` rules) each device runs those scans over its own
+rows inside a ``jax.shard_map``. Left to the partitioner, the scan's leading
+axis ``[N // chunk]`` is the sharded one and a scan walks it an index at a
+time: the hidden states of the global batch were all-gathered, twice, and
+every device computed the head and its backward for all of them (PR 36).
+Across devices go the f32 sum of the log-likelihoods and, once after the
+backward scan, the head's gradient in the compute dtype; hidden states and
+targets never do. A mesh that splits the vocabulary (``tp`` > 1) keeps the
+partitioner's path: a replicated head would be gathered and its matmul
+repeated on every ``tp`` member, and a vocabulary-parallel loss is not
+written yet.
 
 (The reference delegates LM losses to torch/HF — SURVEY.md §5.7; this is
 the TPU-native hot-path equivalent, same role as ops/attention.py.)
@@ -23,6 +36,7 @@ the TPU-native hot-path equivalent, same role as ops/attention.py.)
 from __future__ import annotations
 
 import functools
+import math
 
 import jax
 import jax.numpy as jnp
@@ -94,6 +108,25 @@ def _fused_lm_loss_bwd(chunk, res, g):
 _fused_lm_loss_sum.defvjp(_fused_lm_loss_fwd, _fused_lm_loss_bwd)
 
 
+def _token_axes(mesh, shape):
+    """The mesh axes that split a ``[B, T, ...]`` array's tokens, or None
+    where the loss stays the partitioner's: no mesh, a split vocabulary,
+    tokens that are not split or do not divide."""
+    if mesh is None:
+        return None
+    from ray_tpu.parallel.mesh import DEFAULT_RULES
+
+    def size(axes):
+        return math.prod(mesh.shape.get(a, 1) for a in axes)
+
+    batch, seq = DEFAULT_RULES["batch"], DEFAULT_RULES["seq"]
+    if size(DEFAULT_RULES["vocab"]) > 1 or size(batch) * size(seq) == 1:
+        return None
+    if shape[0] % size(batch) or shape[1] % size(seq):
+        return None
+    return batch + seq
+
+
 def fused_lm_loss(
     x,
     head,
@@ -101,6 +134,7 @@ def fused_lm_loss(
     *,
     chunk_size: int = 512,
     mean: bool = True,
+    mesh=None,
 ):
     """Cross-entropy LM loss fused with the head projection.
 
@@ -108,12 +142,39 @@ def fused_lm_loss(
     accumulates f32); head: [D, V]; targets: [B, T] or [N] int32.
     Numerically identical (f32 accumulation, logsumexp-stable) to
     ``log_softmax(x @ head)`` gathering, without ever holding [N, V].
+
+    ``mesh``: the mesh x is sharded over, if any. Where it splits the tokens
+    of a [B, T, D] x and not the vocabulary, each device scans its own rows
+    (module docstring); any other mesh, and none, run the one program the
+    partitioner is handed.
     """
-    if x.ndim == 3:
-        B, T, D = x.shape
-        x = x.reshape(B * T, D)
-        targets = targets.reshape(B * T)
-    N = x.shape[0]
-    chunk = _pick_chunk(N, chunk_size)
-    total = _fused_lm_loss_sum(x, head.astype(x.dtype), targets, chunk)
-    return total / N if mean else total
+    axes = _token_axes(mesh, x.shape) if x.ndim == 3 else None
+
+    def total_nll(x, head, targets):
+        x, targets = x.reshape(-1, x.shape[-1]), targets.reshape(-1)
+        chunk = _pick_chunk(x.shape[0], chunk_size)
+        total = _fused_lm_loss_sum(x, head.astype(x.dtype), targets, chunk)
+        return total if axes is None else lax.psum(total, axes)
+
+    if axes is not None:
+        from jax.sharding import PartitionSpec as P
+
+        from ray_tpu.parallel.mesh import logical_to_spec
+
+        # Cast outside, so that the head's gradient is summed across devices
+        # in the compute dtype, as the layers' gradients are.
+        head = head.astype(x.dtype)
+        # check_vma=False: the forward scan's carry starts as a constant and
+        # comes back varying over the token axes, which the check refuses;
+        # the flash call's shard_map passes the same for its Mosaic kernels.
+        total_nll = jax.shard_map(
+            total_nll,
+            mesh=mesh,
+            in_specs=(
+                logical_to_spec(("batch", "seq", None)), P(), logical_to_spec(("batch", "seq"))
+            ),
+            out_specs=P(),
+            check_vma=False,
+        )
+    total = total_nll(x, head, targets)
+    return total / targets.size if mean else total

@@ -86,6 +86,87 @@ def test_fused_under_jit_and_mesh():
     np.testing.assert_allclose(float(loss), float(naive), rtol=1e-5)
 
 
+_TOY = dict(
+    vocab_size=512, d_model=64, n_layers=2, n_heads=4, n_kv_heads=2,
+    d_ff=128, max_seq_len=1024, dtype=jnp.float32, remat=False,
+)
+
+
+def _mesh(**axes):
+    from ray_tpu.parallel.mesh import MeshConfig, create_mesh
+
+    n = int(np.prod(list(axes.values())))
+    return create_mesh(MeshConfig(**axes), devices=jax.devices()[:n])
+
+
+def _placed(params, tokens, cfg, mesh):
+    from jax.sharding import NamedSharding
+
+    from ray_tpu.models.transformer import param_logical_axes
+    from ray_tpu.parallel.mesh import logical_to_spec, shard_by_logical_axes
+
+    spec = NamedSharding(mesh, logical_to_spec(("batch", None)))
+    return shard_by_logical_axes(params, param_logical_axes(cfg), mesh), {"tokens": jax.device_put(tokens, spec)}
+
+
+@pytest.mark.parametrize(
+    "axes, rows, seq",
+    [
+        (dict(dp=4), 4, 1024),  # one row a device, two chunks of 512
+        (dict(dp=4), 8, 1024),  # two rows a device
+        (dict(dp=2, fsdp=2), 4, 1024),  # the head enters gathered over fsdp
+        (dict(dp=2, sp=2), 4, 1024),  # a split of the sequence is as good as one of the batch
+        (dict(dp=2, pp=2), 4, 64),  # an axis no token rule names: its members repeat the loss
+        (dict(dp=2, tp=2), 4, 64),  # a split vocabulary keeps the partitioner's path
+    ],
+    ids=["dp4", "dp4-2rows", "dp2xfsdp2", "dp2xsp2", "dp2xpp2", "dp2xtp2"],
+)
+def test_loss_and_grads_under_a_mesh_match_no_mesh(axes, rows, seq):
+    """Each device scans its own rows inside a shard_map (PR 36): the same
+    loss and the same gradient on every leaf as the unsharded program."""
+    cfg = TransformerConfig(**_TOY)
+    params = init_params(jax.random.PRNGKey(0), cfg)
+    tokens = jax.random.randint(jax.random.PRNGKey(1), (rows, seq + 1), 0, cfg.vocab_size)
+    want_loss, want = jax.jit(jax.value_and_grad(lambda p, b: loss_fn(p, b, cfg)))(params, {"tokens": tokens})
+
+    mesh = _mesh(**axes)
+    step = jax.jit(jax.value_and_grad(lambda p, b: loss_fn(p, b, cfg, mesh)))
+    got_loss, got = step(*_placed(params, tokens, cfg, mesh))
+    np.testing.assert_allclose(float(got_loss), float(want_loss), rtol=1e-5)
+    for (path, a), b in zip(jax.tree_util.tree_leaves_with_path(got), jax.tree.leaves(want)):
+        scale = float(jnp.abs(b).max())
+        np.testing.assert_allclose(
+            np.asarray(a), np.asarray(b), rtol=1e-5, atol=1e-5 * scale, err_msg=jax.tree_util.keystr(path)
+        )
+    sharded = "shard_map" in str(jax.make_jaxpr(lambda h, t: fused_lm_loss(
+        h, params["lm_head"], t, mesh=mesh))(jnp.zeros((rows, seq, cfg.d_model)), tokens[:, 1:]))
+    assert sharded == (axes.get("tp", 1) == 1)
+
+
+def test_a_mesh_of_one_device_is_the_program_without_a_mesh():
+    """`train2.dense-4k` runs under the trainer's mesh of one chip: not a
+    character of its lowered step may depend on that mesh."""
+    cfg = TransformerConfig(**{**_TOY, "dtype": jnp.bfloat16, "remat": True})
+    params = init_params(jax.random.PRNGKey(0), cfg)
+    batch = {"tokens": jnp.zeros((2, 1025), jnp.int32)}
+
+    def lowered(mesh):
+        return jax.jit(jax.value_and_grad(lambda p, b: loss_fn(p, b, cfg, mesh))).lower(params, batch).as_text()
+
+    assert lowered(_mesh(dp=1)) == lowered(None)
+
+
+def test_rows_that_do_not_divide_keep_the_partitioners_path():
+    mesh = _mesh(dp=4)
+    x = jax.random.normal(jax.random.PRNGKey(0), (6, 32, 16), jnp.float32)
+    head = jax.random.normal(jax.random.PRNGKey(1), (16, 64), jnp.float32)
+    targets = jax.random.randint(jax.random.PRNGKey(2), (6, 32), 0, 64)
+    np.testing.assert_allclose(
+        float(jax.jit(lambda x, h: fused_lm_loss(x, h, targets, mesh=mesh))(x, head)),
+        float(_naive(x, head, targets)), rtol=1e-5,
+    )
+
+
 def test_sliding_window_train_step_runs_and_differs():
     """Training path with sliding_window: loss_fn is finite, grads flow,
     and the window genuinely changes the loss vs full attention."""

@@ -153,19 +153,133 @@ def test_sharded_train_step_lowers_for_tpu_from_cpu():
         assert f'kernel_name = "{kernel}"' in text
 
 
+def _compiled_dp4_train_step(cfg, devices, rows_a_device, flash_kernels):
+    """``make_train_step`` compiled for a ``dp`` mesh of four ``devices``, which
+    may be described and not attached (every argument is a shape): parameters
+    and optimizer state replicated, the batch split. ``flash_kernels`` takes
+    the attention's TPU path, whatever backend this process has."""
+    from unittest import mock
+
+    import jax
+    import jax.numpy as jnp
+    import optax
+    from jax.sharding import NamedSharding
+
+    from ray_tpu.models.transformer import init_params, make_train_step
+    from ray_tpu.ops import attention
+    from ray_tpu.parallel.mesh import logical_to_spec, single_axis_mesh
+
+    mesh = single_axis_mesh("dp", devices=devices)
+    opt = optax.adamw(1e-4)
+
+    def placed(tree, axes=()):
+        sharding = NamedSharding(mesh, logical_to_spec(axes))
+        return jax.tree.map(lambda a: jax.ShapeDtypeStruct(a.shape, a.dtype, sharding=sharding), tree)
+
+    params = placed(jax.eval_shape(lambda: init_params(jax.random.PRNGKey(0), cfg)))
+    opt_state = placed(jax.eval_shape(opt.init, params))
+    tokens = jax.ShapeDtypeStruct((4 * rows_a_device, cfg.max_seq_len + 1), jnp.int32)
+    with mock.patch.object(attention, "_on_tpu", lambda: flash_kernels):
+        return (
+            jax.jit(make_train_step(cfg, opt, mesh=mesh), donate_argnums=(0, 1))
+            .lower(params, opt_state, {"tokens": placed(tokens, ("batch", None))})
+            .compile()
+        )
+
+
+def _collectives(compiled):
+    """Result shapes of a compiled module's collectives, by operation, but for
+    those whose every group is one device: a shard_map sums its inputs'
+    cotangents over the mesh axes its specs do not name, here all of size 1."""
+    import re
+
+    found = {"all-gather": [], "all-reduce": []}
+    line = r"= (\(.*?\)|\S+) (all-gather|all-reduce)(?:-start)?\(.*?replica_groups=(\{\{.*?\}\}|\[\d+,(\d+)\])"
+    for shapes, op, groups, iota_size in re.findall(line, compiled.as_text()):
+        if iota_size == "1" or (not iota_size and not re.search(r"\d,\d", groups)):
+            continue
+        found[op] += re.findall(r"\w+\[[\d,]*\]", shapes)
+    return found
+
+
+def _assert_the_loss_crosses_no_rows(compiled, cfg):
+    """Parameters replicated, batch split: there is nothing to gather, and what
+    is reduced is gradients and the loss. Left to the partitioner (until PR 36)
+    the fused loss's scans all-gathered the global batch's chunked hidden
+    states ``[N // chunk, chunk, D]`` and targets, forward and backward, and
+    every device computed the head for every row."""
+    found = _collectives(compiled)
+    assert found["all-gather"] == [], found
+    assert found["all-reduce"], found
+    chunks = [s for s in found["all-reduce"] if s.endswith(f",512,{cfg.d_model}]")]
+    assert chunks == [], chunks
+
+
+@pytest.mark.parametrize("rows_a_device", [1, 2])
+def test_the_dp4_train_step_gathers_nothing(rows_a_device):
+    """Runs everywhere, compiled for four of the suite's CPU devices at toy
+    widths, two or four chunks of 512 tokens a device."""
+    import jax
+    import jax.numpy as jnp
+
+    from ray_tpu.models.transformer import TransformerConfig
+
+    cfg = TransformerConfig(
+        vocab_size=512, d_model=256, n_layers=2, n_heads=2, n_kv_heads=1, d_ff=256,
+        max_seq_len=1024, sliding_window=1024, dtype=jnp.bfloat16, remat=True,
+    )
+    compiled = _compiled_dp4_train_step(cfg, jax.devices()[:4], rows_a_device, flash_kernels=False)
+    _assert_the_loss_crosses_no_rows(compiled, cfg)
+
+
 @pytest.fixture(scope="module")
-def one_v5e_chip():
-    """One chip of a v5e host, described and not attached: the TPU's compiler
-    compiles for it here (the on-chip-measurement guide, section 2)."""
+def v5e_host():
+    """The four chips of a v5e host, described and not attached: the TPU's
+    compiler compiles for them here (the on-chip-measurement guide, section 2)."""
     os.environ.setdefault("TPU_LOG_DIR", "disabled")
     from jax.experimental import topologies
-    from jax.sharding import SingleDeviceSharding
 
     try:
-        topo = topologies.get_topology_desc(platform="tpu", topology_name="v5e:2x2")
+        return topologies.get_topology_desc(platform="tpu", topology_name="v5e:2x2").devices
     except Exception as e:  # noqa: BLE001 — no TPU compiler in this installation
         pytest.skip(f"no v5e:2x2 topology can be described here: {e}")
-    return SingleDeviceSharding(topo.devices[0])
+
+
+@pytest.fixture(scope="module")
+def one_v5e_chip(v5e_host):
+    from jax.sharding import SingleDeviceSharding
+
+    return SingleDeviceSharding(v5e_host[0])
+
+
+def test_the_dp4_cell_train_step_gathers_nothing_on_the_v5e_host(v5e_host):
+    """``train2.dp4-4k``'s step at the cell's widths, compiled for the four
+    chips of a v5e host (PR 36): each chip runs one chip's loss, 8 chunks of 512
+    of its own sequence, and the step still fits beside what is resident."""
+    import jax.numpy as jnp
+
+    from benchmarks.harness import registry
+    from ray_tpu.models.transformer import TransformerConfig
+
+    cell = registry.load_cell(registry.load_manifest(), "train2.dp4-4k")
+    dep = cell["config"]["deployment"]
+    model = registry.load_architecture(cell, "config").model_config(
+        cell["config"], cell["traffic"]["seq_len"], dep["param_dtype"]
+    )
+    for key in ("dtype", "param_dtype"):
+        model[key] = jnp.dtype(model[key]).type
+    cfg = TransformerConfig(**model, remat=dep["remat"], fused_loss=dep["fused_loss"])
+    compiled = _compiled_dp4_train_step(
+        cfg, v5e_host, cell["traffic"]["batch_per_chip"], flash_kernels=True
+    )
+    _assert_the_loss_crosses_no_rows(compiled, cfg)
+    assert "tpu_custom_call" in compiled.as_text()  # the flash kernels, not the attention's fallback
+    stats = compiled.memory_analysis()
+    in_use = (
+        stats.argument_size_in_bytes + stats.output_size_in_bytes
+        - stats.alias_size_in_bytes + stats.temp_size_in_bytes
+    )
+    assert in_use < 14.3e9, in_use  # 14.242 GB, as before PR 36: 8.38 resident + 5.86 of temporaries
 
 
 def test_the_latent_decode_step_copies_neither_the_pool_nor_the_expert_stacks(one_v5e_chip):
