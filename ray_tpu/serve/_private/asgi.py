@@ -423,15 +423,23 @@ class ProxyASGIApp:
         # but a double decrement would steal a count from another stream
         # still assigned to the same replica).
         held = True
+        # The proxy's three of a chunk's stamps (replica.py::CHUNK_STAMPS), on
+        # the clock of ``t_recv_ns``: when a poll was handed to the executor
+        # rides with that poll; when its batch came back to this loop and when
+        # each chunk's send returned ride with the NEXT one, all in the id's argument.
+        t_got_ns, wrote_ns = 0, []
         try:
             while True:
                 try:
+                    t_asked_ns = time.monotonic_ns()
                     batch = await loop.run_in_executor(
                         self._pool,
                         lambda: ray_tpu.get(
-                            actor.next_stream_chunk.remote(sid), timeout=120
+                            actor.next_stream_chunk.remote((sid, t_asked_ns, t_got_ns, wrote_ns)),
+                            timeout=120,
                         ),
                     )
+                    t_got_ns, wrote_ns = time.monotonic_ns(), []
                 except Exception as e:
                     if (
                         parser is None
@@ -462,6 +470,7 @@ class ProxyASGIApp:
                     if parser is not None:
                         parser.feed(chunk)
                     await send({"type": "http.response.body", "body": chunk, "more_body": True})
+                    wrote_ns.append(time.monotonic_ns())
                 if batch["done"]:
                     finished = True
                     break

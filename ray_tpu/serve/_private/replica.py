@@ -9,10 +9,31 @@ depth reported to the controller for autoscaling.
 
 from __future__ import annotations
 
+import contextlib
 import pickle
 import queue as _queue
+import sys
 import threading
 import time
+
+# What is stamped on every chunk of a streamed response, in the order taken
+# (``time.monotonic_ns()``, 0 = not taken): by the pump thread as the chunk's
+# bytes are about to be queued; by the proxy as it hands the poll that will
+# fetch the chunk to its executor (an argument of that poll); by
+# ``next_stream_chunk`` on its first line and as it returns the batch that
+# holds the chunk; by the proxy as the batch reaches its event loop and as the
+# chunk's ``send`` returns. The proxy's two last ride back on the stream's
+# NEXT poll, so a stream's last batch never gets them. A response that gives
+# ``StreamingResponse.on_delivered`` is handed these, one tuple a chunk.
+CHUNK_STAMPS = ("t_yield_ns", "t_asked_ns", "t_enter_ns", "t_sweep_ns", "t_got_ns", "t_wrote_ns")
+
+
+def _annotation(name: str):
+    """A ``jax.profiler.TraceAnnotation`` where this process has loaded jax
+    (only then can a profile of it be taken; the span then lies in its
+    ``/host:CPU`` plane), inert until one is; nothing elsewhere."""
+    jax = sys.modules.get("jax")
+    return jax.profiler.TraceAnnotation(name) if jax is not None else contextlib.nullcontext()
 
 
 class _StreamPump:
@@ -22,10 +43,11 @@ class _StreamPump:
     its generator cannot head-of-line-block the replica's task slots (and a
     disconnected client's pump dies on cancel, not the 5-minute reap)."""
 
-    def __init__(self, gen, model_id: str, on_cancel=None):
+    def __init__(self, gen, model_id: str, on_cancel=None, on_delivered=None):
         self.gen = gen
         self.model_id = model_id
         self.on_cancel = on_cancel
+        self.on_delivered = on_delivered
         self.q: _queue.Queue = _queue.Queue(maxsize=8)  # backpressure bound
         self.cancelled = threading.Event()
         self.last_pump = time.time()
@@ -49,12 +71,13 @@ class _StreamPump:
         _set_multiplexed_model_id(self.model_id)
         try:
             for item in self.gen:
-                if not self._put(("chunk", _encode_chunk(item))):
+                chunk = _encode_chunk(item)
+                if not self._put(("chunk", chunk, time.monotonic_ns())):  # t_yield_ns
                     break
             else:
-                self._put(("done", None))
+                self._put(("done", None, 0))
         except BaseException as e:  # delivered to the consumer, then re-raised
-            self._put(("error", e))
+            self._put(("error", e, 0))
         finally:
             try:
                 self.gen.close()
@@ -75,6 +98,30 @@ class _StreamPump:
                 cb()
             except Exception:
                 pass
+        self.delivered()  # the batch swept last gets no next poll
+
+    def swept(self, yields: list, t_asked_ns: int, t_enter_ns: int):
+        """A poll is about to return the chunks stamped ``yields``: kept
+        until the proxy's stamps of the batch come back (``delivered``)."""
+        if self.on_delivered is not None and yields:
+            self._swept = (yields, t_asked_ns, t_enter_ns, time.monotonic_ns())
+
+    def delivered(self, t_got_ns: int = 0, wrote_ns=()):
+        """Hands the response's owner the ``CHUNK_STAMPS`` of the batch swept
+        last, one tuple a chunk in order: with the proxy's two last where the
+        stream's next poll brought them, with 0 where none will come."""
+        batch = self.__dict__.pop("_swept", None)  # GIL-atomic, as in cancel
+        if batch is None:
+            return
+        yields, t_asked_ns, t_enter_ns, t_sweep_ns = batch
+        if len(wrote_ns) != len(yields):  # no stamps, or not this batch's
+            t_got_ns, wrote_ns = 0, [0] * len(yields)
+        try:
+            self.on_delivered(
+                [(y, t_asked_ns, t_enter_ns, t_sweep_ns, t_got_ns, w) for y, w in zip(yields, wrote_ns)]
+            )
+        except Exception:
+            pass
 
 
 class Replica:
@@ -220,17 +267,18 @@ class Replica:
                 status = getattr(result, "status", 200)
                 extra = getattr(result, "headers", None) or {}
                 on_cancel = getattr(result, "on_disconnect", None)
+                on_delivered = getattr(result, "on_delivered", None)
                 resume = getattr(result, "resume", None)
             else:
                 gen, ctype = result, "application/octet-stream"
                 status, extra = 200, {}
-                on_cancel = resume = None
+                on_cancel = on_delivered = resume = None
             with self._lock:
                 self._reap_idle_streams_locked()
                 self._stream_counter += 1
                 sid = str(self._stream_counter)
                 self._streams[sid] = _StreamPump(
-                    gen, multiplexed_model_id, on_cancel=on_cancel
+                    gen, multiplexed_model_id, on_cancel=on_cancel, on_delivered=on_delivered
                 )
             envelope = {
                 "__serve_stream__": sid,
@@ -271,30 +319,56 @@ class Replica:
                 self._streams.pop(sid, None)
                 pump.cancel()
 
-    def next_stream_chunk(self, sid: str):
+    def next_stream_chunk(self, sid):
         """Drain the stream's prefetch queue: block briefly for the first
         chunk (one-item latency for time-to-first-byte), then sweep whatever
         else is already buffered into the same response. Returns
         {"chunks": [bytes], "done": bool} — empty chunks + done=False means
-        "nothing yet, poll again" — or None for unknown streams."""
+        "nothing yet, poll again" — or None for unknown streams.
+
+        ``sid`` is the stream's id, or, from a proxy that stamps its polls,
+        the id with the proxy's of ``CHUNK_STAMPS`` behind it, ``(sid,
+        t_asked_ns, t_got_ns, wrote_ns)``: when it handed THIS poll to its
+        executor, and when the batch the poll before returned reached its loop
+        and each of its chunks was written. They share the id's argument
+        because every argument of an actor call is serialized by itself, in
+        the proxy, whose polls decide the streams' gaps: as a second argument
+        they cost 32 streams 2.3 ms of their p95 gap, here 0.4 (PERF.md, PR
+        38). A caller that passes the bare id gets the same batches."""
+        t_enter_ns = time.monotonic_ns()
+        t_asked_ns, t_got_ns, wrote_ns = 0, 0, ()
+        if isinstance(sid, tuple):
+            sid, t_asked_ns, t_got_ns, wrote_ns = sid
         with self._lock:
             pump = self._streams.get(sid)
             if pump is not None:
                 pump.last_pump = time.time()
         if pump is None:
             return None
+        pump.delivered(t_got_ns, wrote_ns)
+        with _annotation("serve.stream.sweep"):
+            batch, yields = self._sweep_stream(sid, pump)
+        pump.swept(yields, t_asked_ns, t_enter_ns)
+        if batch["done"]:
+            pump.delivered()  # no next poll will bring the proxy's stamps
+        return batch
+
+    def _sweep_stream(self, sid: str, pump: _StreamPump):
+        """(the batch, the ``t_yield_ns`` of each of its chunks)."""
         chunks: list[bytes] = []
+        yields: list[int] = []
         done = False
         error = None
         block = True
         while True:
             try:
-                kind, payload = pump.q.get(timeout=0.5) if block else pump.q.get_nowait()
+                kind, payload, t_yield_ns = pump.q.get(timeout=0.5) if block else pump.q.get_nowait()
             except _queue.Empty:
                 break
             block = False
             if kind == "chunk":
                 chunks.append(payload)
+                yields.append(t_yield_ns)
             elif kind == "done":
                 done = True
                 break
@@ -304,14 +378,14 @@ class Replica:
         if error is not None and chunks:
             # Deliver what the producer yielded BEFORE it raised; the error
             # surfaces on the next poll (parity with the old per-item pump).
-            pump.q.put(("error", error))
-            return {"chunks": chunks, "done": False}
+            pump.q.put(("error", error, 0))
+            return {"chunks": chunks, "done": False}, yields
         if done or error is not None:
             with self._lock:
                 self._streams.pop(sid, None)
         if error is not None:
             raise error
-        return {"chunks": chunks, "done": done}
+        return {"chunks": chunks, "done": done}, yields
 
     def cancel_stream(self, sid: str):
         """Proxy-initiated teardown on client disconnect (reference: ASGI

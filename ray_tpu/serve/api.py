@@ -383,6 +383,7 @@ class StreamingResponse:
         headers: Optional[dict] = None,
         on_disconnect: Optional[Callable[[], None]] = None,
         resume: Optional[dict] = None,
+        on_delivered: Optional[Callable[[list], None]] = None,
     ):
         self.iterator = iterator
         self.content_type = content_type
@@ -400,6 +401,15 @@ class StreamingResponse:
         # forwarded) to another replica instead of dropping the stream.
         # None (the default) = the stream is not migratable.
         self.resume = resume
+        # Called on a replica actor-call thread with the stamps of each batch
+        # of chunks once the proxy has written it: a list, one tuple of
+        # ``_private/replica.py::CHUNK_STAMPS`` a chunk, in the order the
+        # iterator yielded them (monotonic nanoseconds from the pump thread,
+        # the replica's ``next_stream_chunk`` and the proxy; the proxy's two
+        # last are 0 for the stream's last batch). For a producer that keeps
+        # a record of its items' way to the socket, as ``serve.llm`` does of
+        # its tokens. None (the default): a chunk costs one clock read.
+        self.on_delivered = on_delivered
 
 
 def ingress(asgi_app):

@@ -35,8 +35,8 @@ app with :func:`disaggregated_llm_app`.
 
 from __future__ import annotations
 
+import collections
 import json
-
 import time
 
 from ray_tpu.serve._private.common import (  # noqa: F401
@@ -117,19 +117,43 @@ class LLMDeployment:
                 self.engine.cancel(req)
                 raise
         engine = self.engine
+        annotation = engine.spans.annotation
+        # A token's way back (stats.DELIVERY_FIELDS): the pump thread notes
+        # each event's ``t_emit_ns`` as it yields it (0: an event that is no
+        # token of this engine's), the replica hands back the events' stamps
+        # batch by batch and in the same order, and the two are one record.
+        emitted: collections.deque = collections.deque()
+        number = int(req.id.rpartition("-")[2])
+        n_recorded = 0
 
         def sse():
             try:
                 for tok in echo:
+                    emitted.append(0)
                     yield f"data: {json.dumps({'token': tok})}\n\n"
-                for tok in req:
-                    yield f"data: {json.dumps({'token': tok})}\n\n"
+                for tok, t_emit_ns in req.stamped():
+                    # Around building the event and, through the yield, the
+                    # pump's queueing of it.
+                    with annotation("llm.sse.event"):
+                        emitted.append(t_emit_ns)
+                        yield f"data: {json.dumps({'token': tok})}\n\n"
+                emitted.append(0)
                 yield "data: [DONE]\n\n"
             finally:
                 # Belt: normal completion makes this a no-op; an aborted
                 # generator (pump saw `cancelled` at a yield) frees the
                 # request even if on_disconnect never fired.
                 engine.cancel(req)
+
+        def delivered(batch: list):
+            nonlocal n_recorded
+            recs = []
+            for stamps in batch:
+                t_emit_ns = emitted.popleft()
+                if t_emit_ns:
+                    recs.append((number, n_recorded, t_emit_ns) + stamps)
+                    n_recorded += 1
+            engine.spans.deliveries.push(recs)
 
         return StreamingResponse(
             sse(),
@@ -138,6 +162,7 @@ class LLMDeployment:
             # reaper, so the decode slot and KV blocks free immediately
             # even while the generator is parked waiting for a token.
             on_disconnect=lambda: engine.cancel(req),
+            on_delivered=delivered,
             # Migration descriptor: if THIS replica dies mid-stream, the
             # proxy resubmits the original body to another replica with
             # resume_tokens= the tokens it already forwarded; "sse_tokens"
@@ -186,9 +211,9 @@ class LLMDeployment:
 
     def get_stats(self) -> dict:
         """Engine snapshot plus the device this replica runs on and, under
-        ``"spans"``, the engine's iteration, request and compile records
-        (``stats.EngineSpans.export``) (handle-callable; used by tests and
-        benches)."""
+        ``"spans"``, the engine's iteration, request, compile, delivery and
+        collector records (``stats.EngineSpans.export``) (handle-callable;
+        used by tests and benches)."""
         from ray_tpu.util.device_report import device_report
 
         return {

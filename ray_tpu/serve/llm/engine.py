@@ -57,7 +57,10 @@ vLLM-lineage iteration-level scheduler on top of the paged KV cache:
   ``decode_rows_dropped``.
 - **streaming**: each request carries a queue the scheduler feeds token by
   token; ``LLMRequest`` iterates it — the replica's ``StreamingResponse``
-  pump drains that iterator straight onto the HTTP socket.
+  pump drains that iterator straight onto the HTTP socket. Each id carries
+  the start of the ``llm.emit`` span that emitted it
+  (``LLMRequest.stamped``), the first of a token's seven stamps on its way
+  to the socket (``stats.DELIVERY_FIELDS``).
 
 Concurrency contract: all cache/free-list/slot state is owned by the
 scheduler thread; ``submit``/``cancel`` only touch the wait queue under
@@ -79,7 +82,7 @@ import numpy as np
 
 from ray_tpu._private import flight_recorder as _flight
 from ray_tpu._private.concurrency import any_thread, blocking
-from ray_tpu.serve.llm.stats import ENGINES, LLM, EngineSpans, listen_for_compiles
+from ray_tpu.serve.llm.stats import ENGINES, LLM, EngineSpans, listen_for_compiles, listen_for_gc
 from ray_tpu.util import tracing
 
 
@@ -181,6 +184,14 @@ class LLMRequest:
 
     @blocking
     def __iter__(self):
+        for tok, _ in self.stamped():
+            yield tok
+
+    @blocking
+    def stamped(self):
+        """The stream as ``(token, t_emit_ns)``: each id with the start of the
+        ``llm.emit`` span that emitted it, the scheduler's one stamp a pass
+        (``time.monotonic_ns()``; stats.DELIVERY_FIELDS)."""
         while True:
             kind, val = self._q.get()
             if kind == "token":
@@ -207,7 +218,7 @@ class LLMRequest:
             except _queue.Empty:
                 continue
             if kind == "token":
-                out.append(val)
+                out.append(val[0])
             elif kind == "done":
                 return out
             elif kind == "handoff":
@@ -468,6 +479,7 @@ class LLMEngine:
         self.num_blocks = int(num_blocks or self.num_slots * self.n_max + 1)
         self.prefill_chunk = int(prefill_chunk)
         listen_for_compiles()
+        listen_for_gc()
         self.spans = EngineSpans()
         t0 = time.monotonic()
         import jax
@@ -1332,7 +1344,7 @@ class LLMEngine:
             tok = self._drawn_tokens([req], drawn, [0])[0]
             with spans.span("llm.emit", tokens=1, rid=req.id) as sp:
                 if not (self.role == "prefill" and self._try_handoff(req, tok)):
-                    self._emit_token(req, tok)
+                    self._emit_token(req, tok, sp.t0)
                 sp.set(finished=int(req._finished))
         return True
 
@@ -1562,7 +1574,7 @@ class LLMEngine:
         with spans.span("llm.emit", tokens=len(live)) as sp:
             for req, tok in live:
                 req._sched_pos += 1
-                self._emit_token(req, tok)
+                self._emit_token(req, tok, sp.t0)
             sp.set(finished=sum(req._finished for req, _ in live))
 
     def _drawn_tokens(self, reqs: list, ids: np.ndarray, at: list) -> list[int]:
@@ -1589,12 +1601,15 @@ class LLMEngine:
         words = words.reshape(*words.shape[:-2], -1)[..., :fed]
         return unpack_experts(np.swapaxes(words, -1, -2), self.cfg)  # [fed, expert layers, k]
 
-    def _emit_token(self, req: LLMRequest, tok: int):
+    def _emit_token(self, req: LLMRequest, tok: int, t_emit_ns: int):
+        """``t_emit_ns``: the start of the ``llm.emit`` span this runs in; it
+        travels with the id, so a token's way back costs the scheduler no
+        clock read of its own."""
         req._sched_generated.append(tok)
         req._sched_state = "decode"
         if req.t_first is None:
             req.t_first = time.monotonic()
-        req._q.put(("token", tok))
+        req._q.put(("token", (tok, t_emit_ns)))
         if len(req._sched_generated) >= req.max_new_tokens:
             self._finish(req)
 

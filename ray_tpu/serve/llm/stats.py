@@ -20,10 +20,24 @@ is no other way onto that clock) and it stamps ``time.monotonic_ns()`` at
 both ends into the iteration's record, which leaves the process through
 ``LLMDeployment.get_stats()["spans"]``. OBSERVABILITY.md, "serve.llm spans",
 has the table of names and arguments.
+
+A token's way back (ISSUE 38) is on the same clock. The scheduler's ``llm.emit``
+span gives every token it emits its own start as ``t_emit_ns`` (one clock read
+a pass, carried with the id through the request's queue); the stream's pump
+thread, the replica's ``next_stream_chunk`` and the proxy's event loop stamp the
+CHUNK that carries it (``serve/_private/replica.py::CHUNK_STAMPS``; the proxy's
+two last stamps ride back on the stream's next poll), and ``LLMDeployment``
+joins the two into one ``DELIVERY_FIELDS`` record a token, in the
+``deliveries`` ring. Collector pauses, which stall every stream at once, are
+on the record too: one ``gc.callbacks`` hook a process (``listen_for_gc``)
+keeps the generation-2 collections in a ring and the younger ones as plain
+ints. Both leave through ``get_stats()["spans"]`` only.
 """
 
 from __future__ import annotations
 
+import array
+import gc
 import itertools
 import threading
 import time
@@ -63,10 +77,26 @@ REQUEST_FIELDS = (
     "prompt_tokens", "cached_tokens", "generated", "preemptions", "outcome",
 )
 COMPILE_FIELDS = ("t_end_ns", "duration_ns", "event", "program")
-ITERATION_RING, REQUEST_RING, COMPILE_RING = 2048, 512, 256
+# One record per streamed token, all ints: the number N of the request ring's
+# ``rid`` "llm-N", the token's index among those the request streamed, then
+# seven CLOCK_MONOTONIC stamps in the order they are taken on a token's way
+# from the scheduler to the socket (0 = not taken: the proxy's two last stamps
+# of a stream's last batch, every proxy stamp of a proxy on another host).
+DELIVERY_FIELDS = (
+    "rid", "index",
+    "t_emit_ns", "t_yield_ns", "t_asked_ns", "t_enter_ns", "t_sweep_ns", "t_got_ns", "t_wrote_ns",
+)
+_D_SWEEP = DELIVERY_FIELDS.index("t_sweep_ns")
+_D_PROXY = tuple(DELIVERY_FIELDS.index(f) for f in ("t_asked_ns", "t_got_ns", "t_wrote_ns"))
+# One record per generation-2 collection of this process.
+GC_FIELDS = ("t_start_ns", "duration_ns", "collected")
+ITERATION_RING, REQUEST_RING, COMPILE_RING, GC_RING = 2048, 512, 256, 256
+# 20 s of the fastest cell's 1600 tokens a second; 2.4 MB of int64.
+DELIVERY_RING = 32768
 # A stamp further than this from its neighbour is another host's clock
 # (util.tracing.hop_trace_events draws the same line).
 FOREIGN_STAMP_S = 600.0
+_FOREIGN_NS = int(FOREIGN_STAMP_S * 1e9)
 
 
 class _LLMStats:
@@ -168,6 +198,107 @@ def compile_records() -> list:
         return [list(r) for r in COMPILES.since()]
 
 
+# -- collector pauses: the hook is the process's, like the collector. A
+# collection holds the GIL from whichever thread tripped it and stalls the
+# scheduler and every stream's delivery at once -----------------------------
+
+GC_PAUSES = Ring(GC_RING)  # generation 2, GC_FIELDS
+# Generations 0 and 1, from the hook's installation: how many, and their nanoseconds.
+GC_YOUNGER = {"collections": [0, 0], "ns": [0, 0]}
+_gc_annotation = None  # jax.profiler.TraceAnnotation once the hook is installed
+_gc_open = None  # (t_start_ns, annotation or None) of the collection that is running
+
+
+def _on_gc(phase: str, info: dict):
+    # One collection runs at a time, start and stop on one thread with the
+    # GIL held between them: a global carries the start, and the ring has
+    # one writer.
+    global _gc_open
+    if phase == "start":
+        ann = None
+        if info["generation"] == 2:
+            ann = _gc_annotation("gc.gen2")
+            ann.__enter__()
+        _gc_open = (time.monotonic_ns(), ann)
+    elif _gc_open is not None:
+        (t0, ann), _gc_open = _gc_open, None
+        ns = time.monotonic_ns() - t0
+        if ann is not None:
+            ann.__exit__(None, None, None)
+            GC_PAUSES.push((t0, ns, info["collected"]))
+        else:
+            GC_YOUNGER["collections"][info["generation"]] += 1
+            GC_YOUNGER["ns"][info["generation"]] += ns
+
+
+def listen_for_gc():
+    """Once per process, beside ``listen_for_compiles``: every generation-2
+    collection into ``GC_PAUSES``, under a ``gc.gen2`` annotation from its
+    start to its stop (in a profile it lies in the ``/host:CPU`` plane on
+    the thread that tripped it), the younger generations into plain ints."""
+    global _gc_annotation
+    with _compile_lock:
+        if _gc_annotation is not None:
+            return
+        from jax.profiler import TraceAnnotation
+
+        _gc_annotation = TraceAnnotation
+    gc.callbacks.append(_on_gc)
+
+
+def gc_records() -> list:
+    return [list(r) for r in GC_PAUSES.since()]
+
+
+# -- deliveries: one record a streamed token ----------------------------------
+
+
+class DeliveryRing:
+    """Overwrite-oldest ring of ``DELIVERY_FIELDS`` records, packed into one
+    array of int64: a record costs no object that outlives its push, and the
+    export is a copy of bytes, whatever the ring holds (a list of 300,000 ints
+    would cost the 1 Hz pollers tens of milliseconds to build and to pickle,
+    and its allocations provoke the collections this module counts). Records
+    arrive on the replica's actor-call threads, hence the lock."""
+
+    WIDTH = len(DELIVERY_FIELDS)
+
+    def __init__(self, size: int = DELIVERY_RING):
+        self.size = size
+        self.n = 0  # records ever pushed
+        self._slots = array.array("q", bytes(8 * self.WIDTH * size))
+        self._lock = threading.Lock()
+
+    def push(self, recs: list):
+        """``recs``: tuples of ``DELIVERY_FIELDS``, in order of emission. A
+        proxy stamp further than ``FOREIGN_STAMP_S`` from the replica's own is
+        another host's clock: that record keeps no proxy stamp."""
+        width, size, slots = self.WIDTH, self.size, self._slots
+        packed = []
+        for rec in recs:
+            sweep = rec[_D_SWEEP]
+            for i in _D_PROXY:
+                if rec[i] and abs(rec[i] - sweep) > _FOREIGN_NS:
+                    rec = [0 if j in _D_PROXY else v for j, v in enumerate(rec)]
+                    break
+            packed.append(array.array("q", rec))
+        with self._lock:
+            for rec in packed:
+                at = (self.n % size) * width
+                slots[at:at + width] = rec
+                self.n += 1
+
+    def export(self) -> bytes:
+        """The records still held, oldest first, as native int64, ``WIDTH`` to
+        a record: ``array.array("q").frombytes`` reads them back."""
+        row = 8 * self.WIDTH
+        with self._lock, memoryview(self._slots).cast("B") as held:  # one copy, under the lock
+            if self.n <= self.size:
+                return bytes(held[: self.n * row])
+            at = (self.n % self.size) * row
+            return b"".join((held[at:], held[:at]))
+
+
 # -- iterations and requests: one recorder per engine ----------------------
 
 # Recorders outlive their scheduler loop (ENGINES does not): the /metrics
@@ -197,21 +328,22 @@ class _Span:
 
 
 class EngineSpans:
-    """One engine's iteration and request records. The scheduler thread is
-    the only writer of the iteration ring and the totals."""
+    """One engine's iteration, request and delivery records. The scheduler
+    thread is the only writer of the iteration ring and the totals; the
+    delivery ring takes its records from the replica's actor-call threads."""
 
     def __init__(self):
         from jax.profiler import TraceAnnotation
 
-        self._annotation = TraceAnnotation
+        self.annotation = TraceAnnotation
         self.iterations = Ring(ITERATION_RING)
         self.requests = Ring(REQUEST_RING)
+        self.deliveries = DeliveryRing()
         # A request complete on arrival ends on its submitting thread.
         self._request_lock = threading.Lock()
         self._cur = [0] * len(ITERATION_FIELDS)
         # Cumulative plain ints, for stats(); LLM carries the process's.
         self.span_ns = [0] * len(SPAN_NAMES)
-        self.span_counts = [0] * len(SPAN_NAMES)
         self.kinds = [0] * len(ITERATION_KINDS)
         self.requests_folded = 0  # the /metrics collector's cursor into ``requests``
         self.setup: dict = {}  # seconds of each stage of building the engine
@@ -225,13 +357,13 @@ class EngineSpans:
         cur = self._cur
         cur[:] = [0] * len(cur)
         cur[_WAITING], cur[_RUNNING] = waiting, running
-        it = _Span(cur, _SPAN_FIELD["llm.iteration"], self._annotation("llm.iteration"))
+        it = _Span(cur, _SPAN_FIELD["llm.iteration"], self.annotation("llm.iteration"))
         it.__enter__()
         cur[_T_START] = it.t0
         return it
 
     def span(self, name: str, **args) -> _Span:
-        return _Span(self._cur, _SPAN_FIELD[name], self._annotation(name, **args))
+        return _Span(self._cur, _SPAN_FIELD[name], self.annotation(name, **args))
 
     def carried(self, rows: int = 0, prefill_tokens: int = 0, view_blocks: int = 0,
                 context_tokens: int = 0, window_tokens: int = 0):
@@ -263,14 +395,13 @@ class EngineSpans:
             ns = cur[_FIRST_SPAN + i]
             if ns:
                 self.span_ns[i] += ns
-                self.span_counts[i] += 1
                 LLM.span_ns[i] += ns
 
     # -- one request ------------------------------------------------------
 
     def end_request(self, req, outcome: str):
         def ns(t):
-            return int(t * 1e9) if t else 0
+            return round(t * 1e9) if t else 0  # int() cuts a proxy's stamp a nanosecond short now and then
 
         t_recv, t_submit = ns(req.t_recv), ns(req.t_submit)
         if abs(t_recv - t_submit) > FOREIGN_STAMP_S * 1e9:
@@ -289,7 +420,6 @@ class EngineSpans:
         """For ``LLMEngine.stats()``: the cumulative plain ints only."""
         return {
             "span_ns": dict(zip(SPAN_NAMES, self.span_ns)),
-            "span_counts": dict(zip(SPAN_NAMES, self.span_counts)),
             "iterations": dict(zip(ITERATION_KINDS, self.kinds)),
         }
 
@@ -298,15 +428,21 @@ class EngineSpans:
         lists, oldest first, with the names of their columns. The iteration
         ring goes as ONE flat list of ints, row after row (a row is
         ``len(fields["iterations"])`` wide): two thousand small lists cost
-        the 1 Hz pollers more to build, to pickle and to read (PERF.md, PR 24)."""
+        the 1 Hz pollers more to build, to pickle and to read (PERF.md, PR 24).
+        The delivery ring goes as packed bytes (``DeliveryRing.export``)."""
         return {
             "iterations": list(itertools.chain.from_iterable(self.iterations.since())),
             "requests": [list(r) for r in self.requests.since()],
             "compiles": compile_records(),
+            "deliveries": self.deliveries.export(),
+            "gc": gc_records(),
+            "gc_younger": {k: list(v) for k, v in GC_YOUNGER.items()},
             "setup": dict(self.setup),
             "fields": {
                 "iterations": list(ITERATION_FIELDS),
                 "requests": list(REQUEST_FIELDS),
                 "compiles": list(COMPILE_FIELDS),
+                "deliveries": list(DELIVERY_FIELDS),
+                "gc": list(GC_FIELDS),
             },
         }

@@ -214,7 +214,8 @@ def test_no_logits_reach_the_host(engine):
     assert resumed.result(timeout=60) == full[3:]
     s = engine.stats()
     assert s["host_logit_rows"] == 0 and s["kv_pool_not_donated"] == 0
-    assert s["span_counts"]["llm.decode.fetch"] > 0 and s["span_counts"]["llm.prefill.fetch"] > 0
+    assert s["span_ns"]["llm.decode.fetch"] > 0 and s["span_ns"]["llm.prefill.fetch"] > 0
+    assert s["iterations"]["decode"] > 0 and s["iterations"]["prefill"] + s["iterations"]["mixed"] > 0
 
 
 @pytest.mark.filterwarnings("ignore::pytest.PytestUnhandledThreadExceptionWarning")

@@ -177,7 +177,8 @@ def test_iteration_phases_and_rows(model, streams):
     assert sum(totals["iterations"].values()) == len(its)
     for name in stats.SPAN_NAMES:
         assert totals["span_ns"][name] == sum(r[IT[name]] for r in its)
-    assert totals["span_counts"]["llm.iteration"] == len(its)
+    kinds = ["mixed" if r[IT["rows"]] and r[IT["prefill_tokens"]] else "decode" if r[IT["rows"]] else "prefill" for r in its]
+    assert totals["iterations"] == {kind: kinds.count(kind) for kind in stats.ITERATION_KINDS}
 
 
 def test_view_blocks_is_the_width_of_the_pass_s_decode_step(model):
@@ -495,7 +496,7 @@ def test_get_stats_carries_the_records_the_setup_and_the_proxys_stamps():
     finally:
         dep.prepare_for_shutdown()
     spans = got["spans"]
-    assert set(spans) == {"iterations", "requests", "compiles", "setup", "fields"}
+    assert set(spans) == {"iterations", "requests", "compiles", "deliveries", "gc", "gc_younger", "setup", "fields"}
     assert set(spans["setup"]) == {
         "jax_import_s", "backend_s", "params_s", "pool_s", "jit_build_s", "decode_build_s",
     }
@@ -505,7 +506,9 @@ def test_get_stats_carries_the_records_the_setup_and_the_proxys_stamps():
     assert rec["request_id"] == "req-7" and rec["t_recv_ns"] == t_recv
     assert rec["t_recv_ns"] <= rec["t_submit_ns"] <= rec["t_admit_ns"] <= rec["t_first_ns"]
     assert any(c[2] == "backend_compile" for c in spans["compiles"])
-    json.dumps(got["spans"])  # plain lists, strings and numbers: nothing of NumPy crosses the RPC
+    # plain lists, strings and numbers: nothing of NumPy crosses the RPC; the delivery ring as packed bytes
+    assert spans["deliveries"] == b""  # nothing was streamed
+    json.dumps({ring: recs for ring, recs in spans.items() if ring != "deliveries"})
     assert got["iterations"] == dep.engine.stats()["iterations"]
 
 
