@@ -361,14 +361,31 @@ class _Access(NamedTuple):
     key_pos: Any = None
 
 
+class _Part(NamedTuple):
+    """One of several batches that share a call of ``_cached_layers``: rows
+    ``start : start + B * q`` of its flat input are this part's [B, q] tokens at
+    ``positions`` [B, q], reaching the cache through ``access``."""
+
+    start: int
+    positions: Any
+    access: _Access
+
+
 def _period(kinds: tuple) -> int:
     """The shortest p with ``kinds[i] == kinds[i - p]`` throughout."""
     return next(p for p in range(1, len(kinds) + 1) if all(kinds[i] == kinds[i - p] for i in range(p, len(kinds))))
 
 
-def _cached_layers(params, x, cache, positions, access, cfg, key_len=None, valid=None):
+def _cached_layers(params, x, cache, positions, access, cfg, key_len=None, valid=None, parts=None):
     """THE layer stack over a cache, dense or paged: x [B, q, D] at
     ``positions`` [B, q] -> (final normed hidden states, cache).
+
+    ``parts`` (in place of ``access``; ``paged_decode_step_with_chunk``): x is
+    [1, n, D], several batches of different shapes laid side by side
+    (``_Part``). The matmuls of a layer, which read the weights, run once over
+    all n rows; only the cache's write, view, mask and attention run a part
+    at a time, each as its own [B, q], and their outputs are laid side by
+    side again. Carried for one group of key and value leaves only.
 
     The whole cache rides the layer scan as its CARRY (never xs -> ys, which
     are distinct buffers of the loop) and a layer reaches its part through
@@ -393,6 +410,24 @@ def _cached_layers(params, x, cache, positions, access, cfg, key_len=None, valid
     latent = cfg.latent_attention
     n_words, per_word = _choice_words(cfg) if cfg.routed_experts else (1, 0)
 
+    def attend_parts(pool, qh, rows, at):
+        """Every part's rows written, then every part attended over its own
+        view: (attention output [1, n, H * Dh], pool)."""
+
+        def of(part, flat):  # the part's rows of a [1, n, ...] array, as [B, q, ...]
+            n = math.prod(part.positions.shape)
+            return flat[0, part.start : part.start + n].reshape(*part.positions.shape, *flat.shape[2:])
+
+        for part in parts:
+            pool = {**pool, **{name: part.access.write(pool[name], at, of(part, row)) for name, row in rows.items()}}
+        out = []
+        with jax.named_scope("cache_attention"):
+            for part in parts:
+                ck, cv = (part.access.view(pool[name], at) for name in ("k", "v"))
+                mask = _cache_mask(part.positions, ck.shape[1], cfg.sliding_window, None, part.access.key_pos)
+                out.append(_cache_attention(of(part, qh), ck, cv, mask, cfg).reshape(1, -1, qh.shape[2] * qh.shape[3]))
+        return jnp.concatenate(out, axis=1), pool
+
     def run_layer(x, pool, lp, kind, at, l, first, held):
         """One layer of kind ``kind`` (None: no pattern), layer ``at`` of its
         group, layer ``l - first`` of its stack."""
@@ -400,16 +435,19 @@ def _cached_layers(params, x, cache, positions, access, cfg, key_len=None, valid
         sfx = _group_suffix(kind)
         project = _project_latent if latent else _project_qkv if kind != _FULL else partial(_project_qkv, rope=False)
         qh, rows = project(lp, x, positions, cfg)
-        pool = {**pool, **{name + sfx: acc.write(pool[name + sfx], at, row) for name, row in rows.items()}}
-        with jax.named_scope("cache_attention" + (f"_{kind}" if kind else "")):
-            seen = {name: acc.view(pool[name + sfx], at) for name in rows}
-            n_keys = next(iter(seen.values())).shape[1]
-            window = 0 if kind == _FULL else cfg.sliding_window
-            mask = _cache_mask(positions, n_keys, window, key_len, acc.key_pos)
-            if latent:
-                o = _latent_attention(lp, qh, seen, mask, cfg)
-            else:
-                o = _cache_attention(qh, seen["k"], seen["v"], mask, cfg).reshape(B, q, -1)
+        if parts is not None:
+            o, pool = attend_parts(pool, qh, rows, at)
+        else:
+            pool = {**pool, **{name + sfx: acc.write(pool[name + sfx], at, row) for name, row in rows.items()}}
+            with jax.named_scope("cache_attention" + (f"_{kind}" if kind else "")):
+                seen = {name: acc.view(pool[name + sfx], at) for name in rows}
+                n_keys = next(iter(seen.values())).shape[1]
+                window = 0 if kind == _FULL else cfg.sliding_window
+                mask = _cache_mask(positions, n_keys, window, key_len, acc.key_pos)
+                if latent:
+                    o = _latent_attention(lp, qh, seen, mask, cfg)
+                else:
+                    o = _cache_attention(qh, seen["k"], seen["v"], mask, cfg).reshape(B, q, -1)
         if cfg.attn_gate:
             gate = _rms_norm(x, lp["attn_norm"], cfg.norm_eps) @ lp["wg_attn"].astype(x.dtype)
             o = o * jax.nn.sigmoid(gate)
@@ -760,6 +798,40 @@ def paged_decode_step(params, token, cache, block_tables, pos, cfg: TransformerC
         params, token[:, None], cache, block_tables, pos, cfg, ring_tables=ring_tables
     )
     return logits[:, 0], cache
+
+
+def paged_decode_step_with_chunk(
+    params, token, chunk_tokens, cache, block_tables, pos, chunk_tables, chunk_pos, valid_to, cfg: TransformerConfig
+):
+    """``paged_decode_step`` of ``token`` [S] at ``pos`` [S] over
+    ``block_tables`` [S, w] AND ``paged_decode_chunk_hidden`` of
+    ``chunk_tokens`` [1, q] at ``chunk_pos`` [1].. over ``chunk_tables``
+    [1, w], writable below ``valid_to`` [1], as ONE pass over the layers: the
+    S + q rows go through each layer's matmuls together, so the weights are
+    read once where the two calls read them twice. Each part writes and views
+    the pool through its own table and is masked by its own positions
+    (``_cached_layers``' ``parts``), so a row's arithmetic is that of the call
+    it would have been in. Returns (final normed hidden states [S + q, D],
+    the S decode rows first, and the cache).
+
+    For a cache of one group of key and value leaves. Not carried: a latent
+    pool, the two groups of a layer pattern (rings), and routed experts, whose
+    counters keep decode steps and chunks apart where such a pass is both."""
+    if cfg.latent_attention or cfg.layer_kinds or cfg.routed_experts:
+        raise NotImplementedError("a decode step carries a chunk over one group of key and value leaves only")
+    S = token.shape[0]
+    block_size = next(iter(cache.values())).shape[2]
+    x_step, at_step = _embed_chunk(params, token[:, None], jnp.asarray(pos, jnp.int32), cfg)
+    x_chunk, at_chunk = _embed_chunk(params, chunk_tokens, jnp.asarray(chunk_pos, jnp.int32), cfg)
+    block_tables, chunk_tables = jnp.asarray(block_tables, jnp.int32), jnp.asarray(chunk_tables, jnp.int32)
+    parts = (
+        _Part(0, at_step, _Access(_paged_write(block_tables, at_step, None, block_size), _paged_view(block_tables))),
+        _Part(S, at_chunk, _Access(_paged_write(chunk_tables, at_chunk, valid_to, block_size), _paged_view(chunk_tables))),
+    )
+    x = jnp.concatenate([x_step.reshape(1, S, -1), x_chunk], axis=1)
+    positions = jnp.concatenate([at_step.reshape(1, S), at_chunk], axis=1)
+    x, cache = _cached_layers(params, x, cache, positions, None, cfg, parts=parts)
+    return x[0], cache
 
 
 def _kth_largest(x, k):

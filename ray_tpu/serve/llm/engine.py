@@ -271,9 +271,21 @@ _ROW_TABLE = 7
 # this slot, still on the device (``decode``'s ``ids`` argument).
 _ID_IN_FLIGHT = -1
 
+# In a decode row's token column of a step that carries a prompt's LAST chunk,
+# on the (otherwise inactive) row of the prefilling request's slot: the id the
+# step returns for this slot is the one drawn from the chunk, the request's
+# first, so that the step after feeds it from the device like any other.
+_ID_FROM_CHUNK = -2
+
 # The narrowest block table a decode step is given, in blocks. An engine whose
 # n_max is no wider has one decode program.
 _MIN_VIEW_BLOCKS = 16
+
+# Rows of one tile of the matrix unit. A decode step carries the pass's prefill
+# chunk (``_compiled_fns``' third program) only where its rows and the chunk's
+# fill no more than one: that is where the chip showed the chunk's rows to
+# ride for nothing on the step's read of the weights (PERF.md, PR 39 and 40).
+_MXU_TILE_ROWS = 128
 
 
 def _view_rungs(n_max: int) -> tuple:
@@ -312,13 +324,28 @@ _JIT_LOCK = threading.Lock()
 
 
 def _compiled_fns(cfg, ring: int = 0):
-    """(decode, prefill): ``decode(params, rows [num_slots, 7 + w], pool,
-    ids [num_slots])``, ``w`` a rung of ``_view_rungs`` (one compiled program
-    each) and ``ids`` what the step before returned (a row whose token column
-    is ``_ID_IN_FLIGHT`` feeds its slot's), and ``prefill(params, tokens
-    [1, q], pool, rows [1, 7 + n_max])``, both ``-> (token ids int32, one a
-    row, pool)``. ``ring`` (a layer pattern only): blocks of a row's ring,
-    which a row carries ahead of its table (``7 + ring + w`` columns)."""
+    """(decode, prefill, decode_with_chunk): ``decode(params, rows
+    [num_slots, 7 + w], pool, ids [num_slots])``, ``w`` a rung of
+    ``_view_rungs`` (one compiled program each) and ``ids`` what the step
+    before returned (a row whose token column is ``_ID_IN_FLIGHT`` feeds its
+    slot's), and ``prefill(params, tokens [1, q], pool, rows [1, 7 + n_max])``,
+    both ``-> (token ids int32, one a row, pool)``. ``ring`` (a layer pattern
+    only): blocks of a row's ring, which a row carries ahead of its table
+    (``7 + ring + w`` columns).
+
+    ``decode_with_chunk(params, rows [num_slots, 7 + w], pool, ids [num_slots],
+    tokens [1, q], chunk_rows [1, 7 + w]) -> (ids [num_slots], pool)`` is the
+    decode step that carries the pass's prefill chunk: both go through the
+    layers as ``num_slots + q`` rows of one matmul chain
+    (``generate.paged_decode_step_with_chunk``), each part over its own table
+    (the chunk's cut to the step's rung), the head is projected for
+    ``num_slots + 1`` rows and all of them drawn in the program. The chunk's
+    id is the first token of its request if the chunk is the prompt's last: it
+    is returned in the slot of the row whose token column says
+    ``_ID_FROM_CHUNK`` (the request's own, inactive as a decode row until then),
+    so the ids have the decode program's shape and the next step feeds them
+    the same way. For a pool of one group of key and value leaves; the engine
+    builds it for no other, and at one rung (``LLMEngine._shape_fuses``)."""
     with _JIT_LOCK:
         fns = _JIT_CACHE.get((cfg, ring))
         if fns is None:
@@ -329,7 +356,9 @@ def _compiled_fns(cfg, ring: int = 0):
                 last_row_logits,
                 paged_decode_chunk_hidden,
                 paged_decode_step,
+                paged_decode_step_with_chunk,
             )
+            from ray_tpu.models.transformer import _logits
 
             def tables(rows):
                 if not ring:
@@ -355,14 +384,32 @@ def _compiled_fns(cfg, ring: int = 0):
                 row = jnp.clip(valid_to - 1 - pos, 0, t.shape[1] - 1)
                 return _draw_row_tokens(last_row_logits(p, x, row), rows), c
 
-            # The pool (argument 2) is DONATED to both programs; the layer
+            def decode_rows_with_chunk(p, rows, c, ids, t, chunk_rows):
+                S = rows.shape[0]
+                fed = rows[:, _ROW_TOKEN]
+                from_chunk = fed == _ID_FROM_CHUNK
+                fed = jnp.where(fed == _ID_IN_FLIGHT, ids, jnp.where(from_chunk, 0, fed))
+                pos, valid_to = chunk_rows[:, _ROW_POS], chunk_rows[:, _ROW_VALID_TO]
+                x, c = paged_decode_step_with_chunk(
+                    p, fed, t, c, rows[:, _ROW_TABLE:], rows[:, _ROW_POS],
+                    chunk_rows[:, _ROW_TABLE:], pos, valid_to, cfg,
+                )
+                last = S + jnp.clip(valid_to - 1 - pos, 0, t.shape[1] - 1)
+                heads = jnp.concatenate([x[:S], x[last]])  # the head for num_slots + 1 rows, not + q
+                drawn = _draw_row_tokens(_logits(p, heads), jnp.concatenate([rows, chunk_rows]))
+                return jnp.where(from_chunk, drawn[S], drawn[:S]), c
+
+            # The pool (argument 2) is DONATED to every program; the layer
             # scan carries it, so a step updates it in place (the caller's
             # side of the bargain: ``LLMEngine._run_donated``). The names (a
             # lambda, prefill_chunk_row) are how the benchmark's
             # trace_programs patterns find the programs in a trace: keep them.
+            # The step that carries a chunk is a lambda too: it IS a decode
+            # step, and in a trace it is found and timed as one.
             fns = (
                 jax.jit(lambda p, rows, c, ids: decode_rows(p, rows, c, ids), donate_argnums=2),
                 jax.jit(prefill_chunk_row, donate_argnums=2),
+                jax.jit(lambda p, rows, c, ids, t, chunk: decode_rows_with_chunk(p, rows, c, ids, t, chunk), donate_argnums=2),
             )
             _JIT_CACHE[(cfg, ring)] = fns
         return fns
@@ -395,16 +442,20 @@ class _PrefixEntry:
 
 class _Step:
     """A decode step that was dispatched and whose ids the host has not
-    fetched: what the program returned, the rows it carried and their slots
-    (a row's request may leave its slot before the fetch), and what the
-    iteration that emits its tokens reports of it."""
+    fetched: what the program returned, the requests it draws a token for and
+    their slots (a request may leave its slot before the fetch), and what the
+    iteration that emits its tokens reports of it. ``reqs`` are the ``rows``
+    requests of its decode rows and then, if the step carried the LAST chunk
+    of a prompt, that request: its last token's row rode in the step too, and
+    the step draws its first token into its slot's id."""
 
-    __slots__ = ("ids", "reqs", "slots", "width", "context_tokens", "window_tokens")
+    __slots__ = ("ids", "reqs", "slots", "rows", "width", "context_tokens", "window_tokens")
 
-    def __init__(self, ids, reqs: list, width: int, context_tokens: int, window_tokens: int):
+    def __init__(self, ids, active: list, first, width: int, context_tokens: int, window_tokens: int):
         self.ids = ids
-        self.reqs = reqs
-        self.slots = [r._sched_slot for r in reqs]
+        self.reqs = active + [first] if first is not None else active
+        self.slots = [r._sched_slot for r in self.reqs]
+        self.rows = len(active)
         self.width = width
         self.context_tokens = context_tokens
         self.window_tokens = window_tokens
@@ -443,7 +494,6 @@ class LLMEngine:
             raise ValueError(
                 refusal.format(what=f"role={role!r}" if role != "both" else "cluster_prefix=True")
             )
-        self.params = params
         self.cfg = cfg
         # Disaggregation role (ISSUE 20). "prefill": requests terminate at
         # prefill completion with a sealed-KV handoff descriptor instead of
@@ -481,7 +531,6 @@ class LLMEngine:
         listen_for_compiles()
         listen_for_gc()
         self.spans = EngineSpans()
-        t0 = time.monotonic()
         import jax
 
         # Under a layer pattern the window layers have a group of pool leaves
@@ -497,6 +546,8 @@ class LLMEngine:
         self._rings = 1 + np.arange(self.num_slots * self.ring_blocks, dtype=np.int32).reshape(
             self.num_slots, self.ring_blocks
         )
+        self.params = params
+        t0 = time.monotonic()
         pool = init_paged_cache(cfg, self.num_blocks, self.block_size, self.num_window_blocks)
         # Bytes one token holds in each group of pool leaves, all its layers,
         # and in the pool at large.
@@ -510,6 +561,8 @@ class LLMEngine:
             # token a layer), for ``submit(return_routed_experts=True)``.
             pool[MOE_CHOICE] = init_moe_choice(cfg, self.num_blocks, self.block_size)
         self._cache = jax.block_until_ready(pool)
+        # The widest rung but one (of a ladder of one rung, that one): ``_shape_fuses``.
+        self._fused_rungs = (self._view_rungs[-2:-1] or self._view_rungs) if self._shape_fuses(self._cache) else ()
         self._moe_wanted: Optional[threading.Event] = None
         self._moe_asking = threading.Lock()  # one asker at a time
         # The counters as last read, a NumPy array. Read once here, which
@@ -563,16 +616,17 @@ class LLMEngine:
             # request was cancelled or preempted while the step ran.
             "decode_steps": 0,
             "decode_steps_run_ahead": 0,
+            # Those of them that carried the pass's prefill chunk in their
+            # program (``_shape_fuses``): one read of the weights, not two.
+            "decode_steps_with_chunk": 0,
             "decode_rows_dropped": 0,
         }
         # The decode step in flight: dispatched, its ids not fetched.
         self._inflight: Optional[_Step] = None
         t0 = time.monotonic()
-        self._decode_fn, self._prefill_fn = _compiled_fns(cfg, self.ring_blocks)
+        self._decode_fn, self._prefill_fn, self._fused_fn = _compiled_fns(cfg, self.ring_blocks)
         self.spans.setup["jit_build_s"] = time.monotonic() - t0
-        t0 = time.monotonic()
-        self._build_decode_rungs()
-        self.spans.setup["decode_build_s"] = time.monotonic() - t0
+        self._build_programs()
         self._thread = threading.Thread(
             target=self._loop, name="llm-engine", daemon=True
         )
@@ -1089,22 +1143,45 @@ class LLMEngine:
 
     @blocking
     def _loop(self):
-        """One pass: admit and sweep cancels, one prefill chunk, then the
-        decode tick, which runs ONE STEP AHEAD: it dispatches step N+1 while
-        step N is still on the device, and only then fetches and emits N's
-        tokens. So round the loop the order is dispatch N+1 -> fetch N -> emit
-        N -> admit -> prefill chunk -> dispatch N+2 -> fetch N+1 ..., and the
-        host's whole share of a pass hides behind a step. One loop: with
-        nothing in flight (the first step, or no decoding row) the tick
-        dispatches from the host's tokens and goes on as above.
+        """One pass: admit and sweep cancels, pick the prefilling request whose
+        chunk is next, then the decode tick, which runs ONE STEP AHEAD: it
+        dispatches step N+1 while step N is still on the device, and only then
+        fetches and emits N's tokens. **Where the pass has a chunk AND rows
+        that decode, the engine's shape fuses (``_shape_fuses``) and the step
+        with a chunk is built wide enough for them (``_rides``), the chunk
+        rides inside step N+1**: one program, one read of the weights
+        (``_launch_step``). Otherwise (no row decodes, no chunk, a shape that
+        does not fuse, rows past the rung that program is built at) the chunk
+        is a program of its own ahead of the step, as ever. So round the loop the order is dispatch N+1 (with this
+        pass's chunk) -> fetch N -> emit N -> admit -> dispatch N+2 (with the
+        next chunk) -> fetch N+1 ..., or, unfused, dispatch N+1 -> fetch N ->
+        emit N -> admit -> prefill chunk -> dispatch N+2 ..., and the host's
+        whole share of a pass hides behind a step. One loop: with nothing in
+        flight (the first step, or no decoding row) the tick dispatches from
+        the host's tokens and goes on as above.
+
+        A prompt's LAST chunk that rides a step makes its request one of the
+        step's (``_Step.reqs``): the first token is drawn in the program,
+        lands with the step's ids a pass later (no ``llm.prefill.fetch``), and
+        the step after already feeds it from the device, as it does every
+        carried row's. A chunk alone still fetches its own id, and a
+        ``role == "prefill"`` engine, which never decodes, never fuses: the
+        hand-off needs the first token on the host.
 
         Device order is what makes that safe. The pool is donated from
         program to program, so programs run in the order they were dispatched:
-        a prefill chunk dispatched after step N+1 sees its writes. Blocks that
-        a cancel, a finish or a preemption releases while a step is in flight
-        may be written once more by that step (its row still names them); their
-        next owner reads only positions it wrote itself, later in device
-        order, and a decode row never writes a block the prefix cache shares.
+        a prefill chunk dispatched after step N+1 sees its writes, and a chunk
+        inside a step writes blocks no decode row of that step names. Prefix
+        blocks are registered, and a prefix published, when their chunk is
+        DISPATCHED, alone or in a step: whoever reads them is dispatched
+        later. Blocks that a cancel, a finish or a preemption releases while
+        a step is in flight may be written once more by that step (a row of
+        it, or its chunk, still names them); their next owner reads only
+        positions it wrote itself, later in device order, and neither a decode
+        row nor a chunk writes a block the prefix cache shares. A request
+        cancelled or preempted while its last chunk is in flight has left its
+        slot when the step lands, and the id is dropped like a decode row's
+        (``decode_rows_dropped``).
         Whatever reads the pool from the host (``_routed_experts``,
         ``_publish_prefix``, ``_try_handoff``, ``_scatter_import``, the expert
         counters) reads ``self._cache``, the result of the step in flight:
@@ -1126,8 +1203,7 @@ class LLMEngine:
                     # behind the step in flight and ahead of the next, so the
                     # reading below waits for no step but the one just fetched.
                     counts = self._copy_moe_counts() if asked is not None else None
-                    busy = self._prefill_tick()
-                    busy = self._decode_tick() or busy
+                    busy = self._ticks()
                 finally:
                     spans.end(it)
                 if asked is not None:
@@ -1162,6 +1238,33 @@ class LLMEngine:
                 if req is not None:
                     self._finish(req, error=SHUTDOWN_ERROR)
             self._teardown_cluster_tier()
+
+    def _ticks(self) -> bool:
+        """A pass's programs: the chunk inside the decode step where the pass
+        has both and a step with a chunk is built wide enough for them
+        (``_rides``), else the chunk, then the step."""
+        chunk = self._next_prefill()
+        if chunk is not None and self._rides(chunk):
+            return self._decode_tick(chunk)
+        busy = self._prefill_tick(chunk)
+        return self._decode_tick() or busy
+
+    def _rides(self, chunk: LLMRequest) -> bool:
+        """Whether ``chunk``'s next chunk goes inside this pass's decode step:
+        the shape fuses, a row decodes, and the blocks they need between them
+        (each row's table with the block its next token may open, and what the
+        chunk has filled and fills) fit the widest rung the step with a chunk
+        is built at. Past it the pass keeps the two programs."""
+        riding = self._inflight.reqs if self._inflight is not None else ()
+        rows = self._decoding(self._inflight) if self._fused_rungs else ()
+        if not rows:
+            return False
+        bs = self.block_size
+        need = max(
+            -(-self._chunk_end(chunk) // bs),
+            *(max(len(r._sched_table), (r._sched_pos + (r in riding)) // bs + 1) for r in rows),
+        )
+        return need <= self._fused_rungs[-1]
 
     def _sweep_cancelled(self):
         for req in self._slots:
@@ -1290,7 +1393,8 @@ class LLMEngine:
 
     # --- prefill (one fixed-shape chunk per tick, interleaved with decode) ---
 
-    def _prefill_tick(self) -> bool:
+    def _next_prefill(self) -> Optional[LLMRequest]:
+        """The prefilling request whose chunk this pass runs, if any."""
         if self.role == "prefill":
             # Prefill-only pool: shortest-remaining-first. There is no
             # decode fairness to protect here, so a short prompt jumps the
@@ -1301,41 +1405,64 @@ class LLMEngine:
             key = lambda r: (r._sched_target - r._sched_pos, r._sched_admit_seq)  # noqa: E731
         else:
             key = lambda r: r._sched_admit_seq  # noqa: E731
-        req = min(
+        return min(
             (r for r in self._slots if r is not None and r._sched_state == "prefill"),
             key=key,
             default=None,
         )
+
+    def _chunk_inputs(self, req: LLMRequest, width: int):
+        """(tokens [1, q], rows [1, 7 + width]) of ``req``'s next chunk as
+        NumPy arrays, and how many of the tokens are real."""
+        q = self.prefill_chunk
+        pos0 = req._sched_pos
+        seq = req.prompt + req._sched_generated
+        piece = seq[pos0 : pos0 + q]
+        # NumPy first: `jnp.asarray` of a list is a program of its own
+        # (a convert_element_type dispatch), of an int32 array a copy.
+        fed = np.zeros((1, q), np.int32)
+        fed[0, : len(piece)] = piece
+        # The program draws from the row of the prompt's LAST real token
+        # within this chunk: only meaningful (and only fetched) on the
+        # final chunk.
+        rows = self._program_rows(1, width)
+        self._fill_row(rows[0], req, req._sched_target, pos0, width=width)
+        return fed, rows, len(piece)
+
+    def _chunk_end(self, req: LLMRequest) -> int:
+        """The position ``req``'s next chunk fills up to: its target if the
+        chunk is the prompt's last."""
+        return min(req._sched_pos + self.prefill_chunk, req._sched_target)
+
+    def _chunk_dispatched(self, req: LLMRequest) -> bool:
+        """``req``'s next chunk is on the device: move on, register what it
+        wrote. True if it was the prompt's last."""
+        req._sched_pos = self._chunk_end(req)
+        self._register_prefix_blocks(req)
+        if req._sched_pos < req._sched_target:
+            return False
+        # Publish BEFORE any terminal transition: sealing gathers from
+        # the request's still-allocated block table.
+        if self.cluster_prefix:
+            self._publish_prefix(req)
+        return True
+
+    def _prefill_tick(self, req: Optional[LLMRequest] = None) -> bool:
+        """One chunk of ``req`` (default: ``_next_prefill``'s) as a program of
+        its own; False if nothing prefills."""
+        req = req or self._next_prefill()
         if req is None:
             return False
         import jax.numpy as jnp
 
         spans = self.spans
-        q = self.prefill_chunk
-        pos0 = req._sched_pos
-        with spans.span("llm.prefill.build", rid=req.id, pos=pos0):
-            seq = req.prompt + req._sched_generated
-            piece = seq[pos0 : pos0 + q]
-            # NumPy first: `jnp.asarray` of a list is a program of its own
-            # (a convert_element_type dispatch), of an int32 array a copy.
-            fed = np.zeros((1, q), np.int32)
-            fed[0, : len(piece)] = piece
-            # The program draws from the row of the prompt's LAST real token
-            # within this chunk: only meaningful (and only fetched) on the
-            # final chunk.
-            rows = self._program_rows(1, self.n_max)
-            self._fill_row(rows[0], req, req._sched_target, pos0)
+        with spans.span("llm.prefill.build", rid=req.id, pos=req._sched_pos):
+            fed, rows, n = self._chunk_inputs(req, self.n_max)
             inputs = (jnp.asarray(fed), jnp.asarray(rows))
-        spans.carried(prefill_tokens=len(piece))
+        spans.carried(prefill_tokens=n)
         with spans.span("llm.prefill.dispatch", rid=req.id):
             drawn = self._run_donated(self._prefill_fn, *inputs)
-        req._sched_pos = min(pos0 + q, req._sched_target)
-        self._register_prefix_blocks(req)
-        if req._sched_pos >= req._sched_target:
-            # Publish BEFORE any terminal transition: sealing gathers from
-            # the request's still-allocated block table.
-            if self.cluster_prefix:
-                self._publish_prefix(req)
+        if self._chunk_dispatched(req):
             with spans.span("llm.prefill.fetch", rid=req.id):
                 # Waits for the step in flight too: it runs ahead of the chunk.
                 drawn = np.asarray(drawn)
@@ -1354,17 +1481,20 @@ class LLMEngine:
         inactive slot (token 0 at position 0 of the null block, drawn greedily)."""
         return np.zeros((n, _ROW_TABLE + self.ring_blocks + width), np.int32)
 
-    def _fill_row(self, row: np.ndarray, req: LLMRequest, first: int, pos: int, ahead: int = 0):
+    def _fill_row(self, row: np.ndarray, req: LLMRequest, first: int, pos: int, ahead: int = 0, width: int = 0):
         """``first``: column 0, the token fed (decode) or valid_to (prefill);
         ``ahead``: 1 if a step in flight draws a token of ``req`` before this
-        dispatch draws its own."""
+        dispatch draws its own; ``width``: the row's table in blocks where
+        that may be narrower than the request's (a chunk inside a step: the
+        blocks behind the rung are the prompt's that later chunks fill)."""
         row[_ROW_TOKEN] = first
         row[_ROW_POS] = pos
         row[_ROW_DRAW] = req._sched_draw
         row[_ROW_COUNTER] = len(req._sched_generated) + ahead
         table = _ROW_TABLE + self.ring_blocks
         row[_ROW_TABLE:table] = self._rings[req._sched_slot]
-        row[table : table + len(req._sched_table)] = req._sched_table
+        blocks = req._sched_table[:width] if width else req._sched_table
+        row[table : table + len(blocks)] = blocks
 
     def _run_donated(self, fn, tokens, *rest):
         """Dispatch one pool-updating program. The pool is donated: the
@@ -1386,38 +1516,90 @@ class LLMEngine:
             )
         return drawn
 
-    def _build_decode_rungs(self):
-        """Build the decode program at every rung before the scheduler starts:
-        one dispatch of all-inactive rows each, which writes row 0 of the null
-        block and nothing else. A width first met while serving would compile
-        for seconds inside a stream."""
+    def _shape_fuses(self, pool) -> bool:
+        """Whether a pass with a chunk AND decode rows may run as one program
+        (``_compiled_fns``' ``decode_with_chunk``), by what the engine can see
+        of itself: its rows and the chunk's fill no more than one tile of the
+        matrix unit, the pool is one group of key and value leaves (a latent
+        pool, a layer pattern's two groups with their rings and the experts'
+        counters keep the two programs: ``generate.paged_decode_step_with_chunk``
+        carries none of them), and the engine decodes at all.
+
+        Such an engine builds the step with a chunk at ONE rung
+        (``_fused_rungs``): the widest but one, the last of the ladder's
+        doublings (2048 tokens of Mistral-16's 16 / 32 / 64 / 128 / 160
+        blocks; a ladder of one rung: that one). Every program more is ~1 s of
+        EVERY warm start, its trace and lowering, which no thread hides (the
+        interpreter is one: PERF.md, PR 40), while a pass rounded up to a
+        wider view pays only that view's gather, always less than the second
+        read of the weights it saves. Past that rung (``_rides``) a pass
+        keeps the two programs."""
+        return (
+            self.role != "prefill"
+            and self.num_slots + self.prefill_chunk <= _MXU_TILE_ROWS
+            and set(pool) == {"k", "v"}
+        )
+
+    def _build_programs(self):
+        """Build the decode program at every rung before the scheduler starts
+        and, for an engine whose shape fuses, the step with a chunk at its one
+        rung (``_shape_fuses``) and the prefill program: one dispatch of
+        all-inactive rows each, which writes row 0 of the null block and
+        nothing else. A width first met while serving would compile for
+        seconds inside a stream; an engine that adds programs to its start
+        pays for them where it can (built here the prefill program costs a
+        warm Mistral-16 replica 1.0 s where its first request built it in
+        1.6), and one that adds none keeps the start it had, its prefill
+        program built by its first request: a cold five-layer expert replica
+        has 17 s of the 90 Serve gives it to spare (PERF.md, PR 35 and 40).
+        Stamps ``fused_build_s``, the trace and lowering of the step with a
+        chunk, and ``decode_build_s``, all the rest of it."""
         from concurrent.futures import ThreadPoolExecutor
 
         import jax
         import jax.numpy as jnp
 
+        t0 = time.monotonic()
         ids = jnp.zeros((self.num_slots,), jnp.int32)
+        chunk = jnp.zeros((1, self.prefill_chunk), jnp.int32)
         # Traced, lowered and compiled apart from the call, and all three
         # held until it returns: the call then finds the trace and the
         # lowering in jit's caches, which keep them only while these
         # objects live. In a v5e replica that is 0.6 s a rung where the
-        # call alone takes 0.94 (PERF.md, PR 31). The rungs are compiled
-        # side by side (the compiler runs outside the GIL): cold, a rung of a
-        # five-layer expert model takes it 8 s, seven of them in a row more
-        # than Serve gives a replica to become ready (PERF.md, PR 35).
-        rungs = [jnp.asarray(self._program_rows(self.num_slots, width)) for width in self._view_rungs]
-        lowered = []
-        for rows in rungs:
-            traced = self._decode_fn.trace(self.params, rows, self._cache, ids)
+        # call alone takes 0.94 (PERF.md, PR 31). Traced and lowered one
+        # after another: that is Python, and threads only take the
+        # interpreter from one another (from the parameter draw too, which
+        # is host work as well: PERF.md, PR 40). Compiled side by side (the
+        # compiler runs outside the GIL): cold, a rung of a five-layer expert
+        # model takes it 8 s, seven of them in a row more than Serve gives a
+        # replica to become ready (PERF.md, PR 35).
+        calls = [(self._decode_fn, jnp.asarray(self._program_rows(self.num_slots, w)), ids) for w in self._view_rungs]
+        if self._fused_rungs:
+            calls.append((self._prefill_fn, chunk, jnp.asarray(self._program_rows(1, self.n_max))))
+        fused_from = len(calls)
+        for w in self._fused_rungs:
+            calls.append(
+                (self._fused_fn, jnp.asarray(self._program_rows(self.num_slots, w)), ids, chunk,
+                 jnp.asarray(self._program_rows(1, w)))
+            )
+        lowered, fused_s = [], 0.0
+        for i, (fn, *args) in enumerate(calls):
+            t = time.monotonic()
+            traced = fn.trace(self.params, args[0], self._cache, *args[1:])
             lowered.append((traced, traced.lower()))
-        with ThreadPoolExecutor(max_workers=len(rungs)) as pool:
+            fused_s += time.monotonic() - t if i >= fused_from else 0.0
+        with ThreadPoolExecutor(max_workers=len(calls)) as pool:
             held = lowered, list(pool.map(lambda pair: pair[1].compile(), lowered))
-        for rows in rungs:
-            ids = jax.block_until_ready(self._run_donated(self._decode_fn, rows, ids))
+        for fn, *args in calls:
+            drawn = jax.block_until_ready(self._run_donated(fn, *args))
+            ids = drawn if fn is not self._prefill_fn else ids
         del held
         # What a step with no step before it is given as ``ids`` (none of its
         # rows reads them): a program's own output, like every other step's.
         self._no_ids = ids
+        self.spans.setup["decode_build_s"] = time.monotonic() - t0 - fused_s
+        if self._fused_rungs:
+            self.spans.setup["fused_build_s"] = fused_s
 
     def _register_prefix_blocks(self, req: LLMRequest):
         """Publish freshly-WRITTEN full prompt blocks for reuse. Done as
@@ -1437,43 +1619,55 @@ class LLMEngine:
 
     # --- decode ---
 
-    def _decode_tick(self) -> bool:
+    def _decode_tick(self, chunk: Optional[LLMRequest] = None) -> bool:
         """Dispatch the step after the one in flight, then fetch and emit the
-        one in flight. With none in flight, dispatch one first."""
+        one in flight. With none in flight, dispatch one first. ``chunk``: the
+        request whose next chunk rides inside the first step dispatched."""
         step, self._inflight = self._inflight, None
-        busy = step is not None or any(
-            r is not None and r._sched_state == "decode" for r in self._slots
-        )
+        busy = step is not None or chunk is not None or bool(self._decoding(None))
         if step is None:
-            step = self._launch_step(None)
+            step, chunk = self._launch_step(None, chunk), None
         if step is not None:
-            self._inflight = self._launch_step(step)
+            self._inflight = self._launch_step(step, chunk)
             self._land_step(step)
         return busy
 
-    def _launch_step(self, ahead_of: Optional[_Step]) -> Optional[_Step]:
+    def _decoding(self, ahead_of: Optional[_Step]) -> list:
+        """The requests a step dispatched now would carry a decode row for.
+        ``ahead_of`` is the step in flight, if any: a request of it is one
+        token further on than the host has seen, and has no row if that token
+        is its last: a request ends by count."""
+        riding = ahead_of.reqs if ahead_of is not None else ()
+        return [
+            r
+            for r in self._slots
+            if r is not None
+            and r._sched_state == "decode"
+            and len(r._sched_generated) + (r in riding) < r.max_new_tokens
+        ]
+
+    def _launch_step(self, ahead_of: Optional[_Step], chunk: Optional[LLMRequest] = None) -> Optional[_Step]:
         """Build and dispatch one decode step over every row that decodes;
         None if there is none. ``ahead_of`` is the step in flight, if any: a
-        row it carries (``riding``) is one token and one position further on
-        than the host has seen, feeds the id that step draws for its slot
-        (``_ID_IN_FLIGHT``), and has no row here if that id is its last: a
-        request ends by count. Every other row (fresh from prefill, back from
-        a preemption) feeds its last token from the host, so a slot that
-        changed hands never reads the tenant before's id."""
-        riding = set(ahead_of.reqs) if ahead_of is not None else ()
-        bs = self.block_size
+        request it draws a token for (``riding``) is one token and one
+        position further on than the host has seen, feeds the id that step
+        draws for its slot (``_ID_IN_FLIGHT``), and has no row here if that id
+        is its last. Every other row (fresh from a chunk that ran alone, back
+        from a preemption) feeds its last token from the host, so a slot that
+        changed hands never reads the tenant before's id.
 
-        def decodes(r):
-            return (
-                r is not None
-                and r._sched_state == "decode"
-                and len(r._sched_generated) + (r in riding) < r.max_new_tokens
-            )
+        ``chunk``: the request whose next chunk goes into this step's program
+        (``decode_with_chunk``, at the rung the wider of rows and chunk
+        needs), if it still prefills once the rows have their blocks (a
+        preemption for them may have taken it). With no row to decode nothing
+        is dispatched and the chunk waits for the next pass."""
+        riding = ahead_of.reqs if ahead_of is not None else ()
+        bs = self.block_size
 
         def writes_at(r):
             return r._sched_pos + (r in riding)
 
-        active = [r for r in self._slots if decodes(r)]
+        active = self._decoding(ahead_of)
         if not active:
             return None
         spans = self.spans
@@ -1512,21 +1706,24 @@ class LLMEngine:
                     self._preempt(victim)
             # Re-derive the step batch: preemption/failure above may have
             # removed sequences from their slots.
-            active = [
-                r
-                for r in self._slots
-                if decodes(r) and writes_at(r) // bs < len(r._sched_table)
-            ]
+            active = [r for r in self._decoding(ahead_of) if writes_at(r) // bs < len(r._sched_table)]
             sp.set(rows=len(active))
             if not active:
                 return None
+            if chunk is not None and chunk._sched_state != "prefill":
+                chunk = None
             import jax.numpy as jnp
 
             # The step gathers and attends over the table it is handed: the
             # smallest rung that covers the longest of ITS rows' tables, as
-            # the host has just extended them.
+            # the host has just extended them, and the blocks its chunk, if
+            # it has one, has filled and fills: the smallest such rung at
+            # which the step with a chunk is built (``_rides`` saw to it that
+            # there is one).
             longest = max(len(r._sched_table) for r in active)
-            width = next(w for w in self._view_rungs if w >= longest)
+            if chunk is not None:
+                longest = max(longest, -(-self._chunk_end(chunk) // bs))
+            width = next(w for w in (self._view_rungs if chunk is None else self._fused_rungs) if w >= longest)
             rows = self._program_rows(self.num_slots, width)
             context_tokens = window_tokens = 0
             window = self.cfg.sliding_window or self.max_model_len
@@ -1541,15 +1738,29 @@ class LLMEngine:
                 )
                 context_tokens += writes_at(req) + 1
                 window_tokens += min(writes_at(req) + 1, window)
-            rows = jnp.asarray(rows)
+            # A prompt's LAST chunk makes its request one of the step's: the
+            # id drawn from the chunk comes back in the request's own slot.
+            first = chunk if chunk is not None and self._chunk_end(chunk) == chunk._sched_target else None
+            if first is not None:
+                rows[first._sched_slot, _ROW_TOKEN] = _ID_FROM_CHUNK
+            inputs = [jnp.asarray(rows), ahead_of.ids if ahead_of is not None else self._no_ids]
+        if chunk is not None:
+            with spans.span("llm.prefill.build", rid=chunk.id, pos=chunk._sched_pos):
+                fed, chunk_rows, n = self._chunk_inputs(chunk, width)
+                inputs += [jnp.asarray(fed), jnp.asarray(chunk_rows)]
+            spans.carried(prefill_tokens=n, chunk_tokens=n)
+            self._counts["decode_steps_with_chunk"] += 1
         self._width_steps[width] += 1
         self._counts["decode_steps"] += 1
         self._counts["decode_steps_run_ahead"] += ahead_of is not None
         with spans.span("llm.decode.dispatch"):
-            ids = self._run_donated(
-                self._decode_fn, rows, ahead_of.ids if ahead_of is not None else self._no_ids
-            )
-        return _Step(ids, active, width, context_tokens, window_tokens)
+            ids = self._run_donated(self._decode_fn if chunk is None else self._fused_fn, *inputs)
+        if chunk is not None and self._chunk_dispatched(chunk):
+            # Its last token's row was in this step, which draws its first
+            # token: from here on it is a request of the step like the rows'.
+            chunk._sched_pos -= 1
+            chunk._sched_state = "decode"
+        return _Step(ids, active, first, width, context_tokens, window_tokens)
 
     def _land_step(self, step: _Step):
         """Fetch a dispatched step's ids and emit them, each to the request
@@ -1557,7 +1768,7 @@ class LLMEngine:
         or preempted since the dispatch has left it, and its id is dropped."""
         spans = self.spans
         spans.carried(
-            rows=len(step.reqs), view_blocks=step.width, context_tokens=step.context_tokens,
+            rows=step.rows, view_blocks=step.width, context_tokens=step.context_tokens,
             window_tokens=step.window_tokens,
         )
         with spans.span("llm.decode.fetch"):

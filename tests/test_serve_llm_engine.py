@@ -392,16 +392,20 @@ def _engine_program(cfg, kind):
     params = jax.eval_shape(lambda: init_params(jax.random.PRNGKey(0), cfg))
     cache = jax.eval_shape(lambda: init_paged_cache(cfg, slots * n_max + 1, 4))
     i32 = lambda *shape: jax.ShapeDtypeStruct(shape, jnp.int32)  # noqa: E731
-    decode, prefill = _compiled_fns(cfg)
+    decode, prefill, with_chunk = _compiled_fns(cfg)
     if kind == "decode":
         lowered = decode.lower(params, i32(slots, _ROW_TABLE + n_max), cache, i32(slots))
+    elif kind == "decode_with_chunk":
+        lowered = with_chunk.lower(
+            params, i32(slots, _ROW_TABLE + n_max), cache, i32(slots), i32(1, chunk), i32(1, _ROW_TABLE + n_max)
+        )
     else:
         lowered = prefill.lower(params, i32(1, chunk), cache, i32(1, _ROW_TABLE + n_max))
     k_arg = len(jax.tree.leaves(params)) + 1  # params, the tokens, then k and v
     return lowered.compile().as_text(), cache["k"].shape, k_arg
 
 
-@pytest.mark.parametrize("kind", ["decode", "prefill"])
+@pytest.mark.parametrize("kind", ["decode", "prefill", "decode_with_chunk"])
 def test_engine_programs_alias_the_pool_and_never_copy_it(model, kind):
     """The compiled text of each engine program aliases both pool arguments
     to outputs, and no ``copy`` / ``dynamic-update-slice`` in it produces an
@@ -418,18 +422,21 @@ def test_engine_programs_alias_the_pool_and_never_copy_it(model, kind):
         assert not hits, hits[:3]
 
 
-@pytest.mark.parametrize("kind", ["decode", "prefill"])
+@pytest.mark.parametrize("kind", ["decode", "prefill", "decode_with_chunk"])
 def test_engine_program_names_match_the_benchmark_patterns(model, kind):
-    """The benchmark finds the two programs in a device trace by the
+    """The benchmark finds the programs in a device trace by the
     ``trace_programs`` patterns of its serving configuration: a renamed
-    callable would read as no ``decode_step_ms`` on the chip, so it fails here."""
+    callable would read as no ``decode_step_ms`` on the chip, so it fails
+    here. The step that carries a chunk is found as a decode step: where every
+    step carries one, nothing else would be."""
     root = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
     path = os.path.join(root, "benchmarks", "configs", "mistral-7b-v0.1-serve16.json")
     with open(path) as f:
         patterns = json.load(f)["trace_programs"]
     module = re.search(r"HloModule (\S+?),", _engine_program(model[1], kind)[0]).group(1)
-    assert re.search(patterns[kind], module), (module, patterns[kind])
-    other = patterns["prefill" if kind == "decode" else "decode"]
+    found_as = "prefill" if kind == "prefill" else "decode"
+    assert re.search(patterns[found_as], module), (module, patterns[found_as])
+    other = patterns["prefill" if found_as == "decode" else "decode"]
     assert not re.search(other, module), (module, other)
 
 
@@ -779,8 +786,10 @@ def test_construction_builds_one_decode_program_a_rung(max_model_len, d_ff, rung
     eng = LLMEngine(params, cfg, num_slots=2, block_size=4, max_model_len=max_model_len,
                     prefill_chunk=4)
     try:
+        # A shape that fuses: the decode step a rung, and the step with a chunk at one of them.
         built = [name for name in _backend_compiles(since) if "lambda" in name]
-        assert eng._view_rungs == rungs and len(built) == len(rungs), built
+        assert eng._view_rungs == rungs and len(built) == len(rungs) + 1, built
+        assert eng._fused_rungs == (rungs[-2:-1] or rungs)
         s = eng.stats()
         assert s["decode_width_steps"] == {w: 0 for w in rungs}
         assert s["kv_pool_not_donated"] == 0
@@ -812,8 +821,8 @@ def test_step_width_is_the_smallest_rung_over_the_longest_active_table(model, nu
     reqs = [eng.submit(p, max_new_tokens=n) for p, n in specs]
     launch, seen = eng._launch_step, []
 
-    def launch_and_check(ahead_of):
-        step = launch(ahead_of)
+    def launch_and_check(ahead_of, chunk=None):
+        step = launch(ahead_of, chunk)
         if step is not None:
             riding = ahead_of.reqs if ahead_of is not None else ()
             # Where each row writes: a row the step in flight carries is one
@@ -878,8 +887,8 @@ def _spy_on_steps(eng):
         columns.append(np.asarray(rows)[:, _ROW_TOKEN].tolist())
         return decode(p, rows, c, ids)
 
-    def spy_launch(ahead_of):
-        step = launch(ahead_of)
+    def spy_launch(ahead_of, chunk=None):
+        step = launch(ahead_of, chunk)
         if step is None:
             return None
         column = columns[-1]

@@ -498,7 +498,7 @@ def test_get_stats_carries_the_records_the_setup_and_the_proxys_stamps():
     spans = got["spans"]
     assert set(spans) == {"iterations", "requests", "compiles", "deliveries", "gc", "gc_younger", "setup", "fields"}
     assert set(spans["setup"]) == {
-        "jax_import_s", "backend_s", "params_s", "pool_s", "jit_build_s", "decode_build_s",
+        "jax_import_s", "backend_s", "params_s", "pool_s", "jit_build_s", "decode_build_s", "fused_build_s",
     }
     assert all(isinstance(v, float) and v >= 0 for v in spans["setup"].values())
     (rec,) = spans["requests"]

@@ -408,12 +408,13 @@ def _cell_programs(cell_name: str):
     ints = lambda *shape: jax.ShapeDtypeStruct(shape, jnp.int32)  # noqa: E731
     n_max = -(-engine["max_model_len"] // bs)
 
-    def args(width):
+    def args(width, with_chunk=False):
         if width is None:
             return params, ints(1, chunk), jax.eval_shape(pool), ints(1, _ROW_TABLE + ring + n_max)
-        return params, ints(slots, _ROW_TABLE + ring + width), jax.eval_shape(pool), ints(slots)
+        step = params, ints(slots, _ROW_TABLE + ring + width), jax.eval_shape(pool), ints(slots)
+        return (*step, ints(1, chunk), ints(1, _ROW_TABLE + ring + width)) if with_chunk else step
 
-    return (*_compiled_fns(cfg, ring), args)
+    return (*_compiled_fns(cfg, ring)[:2], args)
 
 
 # sha1 of the StableHLO text of the decode program (at the 64-block rung) and of
@@ -422,6 +423,8 @@ def _cell_programs(cell_name: str):
 _PROGRAMS_OF_PR_34 = {
     "serve16.chat-open": ("80f55ef50a78c761acd079a445c783f338c74f07", "12bea586c90179b8fe8ea8526dc82e3f9d61ecfa"),
     "glm8.rollout-long": ("cba2cf83a22dbe9cf5dcbe722bd4d53900cfa782", "70387e17a53cdd651ef0cd0d932cd22eff73366e"),
+    # The configuration with a layer pattern, as PR 38's tree gives it (computed from a copy of that commit, PR 40).
+    "trinity5.rollout-longctx": ("297552fcc1973bcb0eef322c5e9ae63a440b5d59", "8fcb09071f66108e85f14215daff025c5708d073"),
 }
 
 
@@ -430,8 +433,11 @@ def test_a_configuration_without_a_layer_pattern_keeps_the_programs_it_had(cell_
     """Runs everywhere: lowering FOR the TPU needs no TPU. PR 35 gave the cached
     layer a second kind, the pool a second group and the program rows a ring; a
     configuration without ``layer_kinds`` (Mistral's three cells, GLM's one)
-    must get none of it: operation for operation the programs PR 34 built. A PR
-    that changes them on purpose computes the new digests and says what moved."""
+    must get none of it: operation for operation the programs PR 34 built. PR 40
+    gave the cached layer ``parts`` and the engine a step that carries a chunk:
+    the two programs every configuration had, Trinity's too, are still the
+    parent's operation for operation. A PR that changes them on purpose
+    computes the new digests and says what moved."""
     import hashlib
 
     decode, prefill, args = _cell_programs(cell_name)
@@ -440,6 +446,37 @@ def test_a_configuration_without_a_layer_pattern_keeps_the_programs_it_had(cell_
         prefill.trace(*args(None)).lower(lowering_platforms=("tpu",)).as_text(),
     )
     assert tuple(hashlib.sha1(t.encode()).hexdigest() for t in texts) == _PROGRAMS_OF_PR_34[cell_name]
+
+
+def test_the_step_with_a_chunk_reads_the_weights_once_and_updates_the_pool_in_place(one_v5e_chip):
+    """Mistral-16's decode step that carries a prefill chunk (PR 40), at the
+    benchmark's widths and the 2048-token rung, compiled for the v5e: the
+    d_ff matmuls run once over 16 + 32 = 48 rows (none at 16 or at 32), the
+    pool is aliased to the output and never copied, each part gathers its own
+    view (16 tables and 1 table of 128 blocks), and the step's temporaries
+    stay tens of megabytes. In a trace it is found as a decode step."""
+    import re
+
+    import jax
+
+    from ray_tpu.serve.llm.engine import _JIT_CACHE
+
+    _, _, args = _cell_programs("serve16.chat-open")
+    ((cfg, ring),) = [key for key in _JIT_CACHE if key[0].d_ff == 14336 and key[0].n_layers == 16]
+    with_chunk = _JIT_CACHE[cfg, ring][2]
+    described = jax.tree.map(
+        lambda a: jax.ShapeDtypeStruct(a.shape, a.dtype, sharding=one_v5e_chip), args(128, with_chunk=True)
+    )
+    compiled = with_chunk.lower(*described).compile()
+    text = compiled.as_text()
+    assert re.search(r"HloModule (\S+?),", text).group(1).startswith("jit__lambda")
+    assert "bf16[48,14336]" in text and "bf16[16,14336]" not in text and "bf16[32,14336]" not in text
+    assert not re.search(rf"= {re.escape('bf16[16,2561,16,8,128]')}\S* copy\(", text)
+    gathered = set(re.findall(r"= bf16\[(\d+),16,8,128\]\S* fusion\(", text))
+    assert {"2048", "128"} <= gathered, gathered  # 16 x 128 blocks and 1 x 128 blocks
+    stats = compiled.memory_analysis()
+    assert stats.alias_size_in_bytes >= 2 * 16 * 2561 * 16 * 8 * 128 * 2  # 2.69 GB updated in place
+    assert stats.temp_size_in_bytes < 60e6, stats.temp_size_in_bytes  # 35 MB; the bare step's 3.4
 
 
 def test_the_pattern_decode_step_gathers_rings_and_copies_neither_pools_nor_experts(one_v5e_chip):
