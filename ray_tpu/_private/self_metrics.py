@@ -252,6 +252,28 @@ def instruments() -> dict:
                 "the fetch failed).",
                 tag_keys=("outcome",),
             ),
+            "serve_llm_chunk_tokens": m.Counter(
+                "ray_tpu_serve_llm_prefill_chunk_tokens_total",
+                "Tokens the fixed-shape prefill chunks carried, by kind: valid "
+                "(a prompt's own) and padded (behind a prompt's last tokens: "
+                "what the fixed chunk wastes).",
+                tag_keys=("kind",),
+            ),
+            "serve_llm_state_resets": m.Counter(
+                "ray_tpu_serve_llm_state_resets_total",
+                "Admissions that made a slot's recurrent state start from zero "
+                "(linear-attention layers; re-admissions after a preemption too).",
+            ),
+            "serve_llm_state_slots": m.Gauge(
+                "ray_tpu_serve_llm_state_slots_in_use",
+                "Slots whose recurrent state (linear-attention layers) belongs "
+                "to a running request.",
+            ),
+            "serve_llm_state_bytes": m.Gauge(
+                "ray_tpu_serve_llm_state_bytes",
+                "Bytes the linear-attention layers' state group holds on the "
+                "device for all slots, whatever the requests' lengths.",
+            ),
             "serve_llm_ttft": m.Histogram(
                 "ray_tpu_serve_llm_ttft_s",
                 "Time to first token: submit -> first token emitted "
@@ -663,6 +685,9 @@ def _collect_serve_llm_stats():
         ("prefix_import_hits", inst["serve_llm_prefix_imports"], {"outcome": "hit"}),
         ("prefix_import_misses", inst["serve_llm_prefix_imports"], {"outcome": "miss"}),
         ("prefix_import_errors", inst["serve_llm_prefix_imports"], {"outcome": "error"}),
+        ("chunk_tokens_valid", inst["serve_llm_chunk_tokens"], {"kind": "valid"}),
+        ("chunk_tokens_padded", inst["serve_llm_chunk_tokens"], {"kind": "padded"}),
+        ("state_resets", inst["serve_llm_state_resets"], None),
     ])
     engines = list(ENGINES)
     if not engines and not LLM.admitted:
@@ -671,15 +696,20 @@ def _collect_serve_llm_stats():
     # plain-int reads, like LLMEngine.stats()): several engines fold into
     # one series, and once the last scheduler exits the sums — and the
     # exported gauges — honestly drop to zero instead of going stale.
-    running = waiting = used = total = 0
+    running = waiting = used = total = state_slots = state_bytes = 0
     for eng in engines:
         running += sum(r is not None for r in eng._slots)
+        if eng.state_slot_bytes:
+            state_slots += sum(r is not None for r in eng._slots)
+            state_bytes += eng.state_slot_bytes * eng.num_slots
         waiting += len(eng._waiting)
         used += (eng.num_blocks - 1) - len(eng._free)
         total += eng.num_blocks - 1
     inst["serve_llm_running"].set(running)
     inst["serve_llm_waiting"].set(waiting)
     inst["serve_llm_kv_util"].set(used / total if total else 0.0)
+    inst["serve_llm_state_slots"].set(state_slots)
+    inst["serve_llm_state_bytes"].set(state_bytes)
 
 
 def _collect_lease_stats():

@@ -45,8 +45,10 @@ import numpy as np
 from jax import lax
 
 from ray_tpu.models.transformer import (
+    LINEAR_LAYERS,
     TransformerConfig,
     _logits,
+    _period,
     _rms_norm,
     _rope,
 )
@@ -67,16 +69,36 @@ def _latent_row_width(cfg: TransformerConfig) -> int:
     return -(-(cfg.kv_lora_rank + cfg.qk_rope_head_dim) // _LANES) * _LANES
 
 
+_SUBLANES = 8
+
+
+def _cache_heads(cfg: TransformerConfig) -> int:
+    """KV heads of a cached row. Plain multi-head attention over more than 8
+    heads that are not a multiple of 8 (30) caches zero heads up to the next
+    multiple (32): the TPU tiles a leaf's two minor axes (8, 128), the padded
+    tiles are allocated either way, and with a head axis that does not fill
+    them the compiler lays the leaf out with the block's rows inside the heads
+    for the gather and the other way round for the scatter, and copies the
+    whole pool between the two, twice a layer a step (seen in the compiled
+    decode program of Olmo-Hybrid's full layers, PR 41: 2 GB a copy; the same
+    trap as ``_latent_row_width``). Queries get zero heads to match
+    (``_project_qkv``) and their outputs are dropped (``_cache_attention``)."""
+    KV = cfg.n_kv_heads
+    if KV == cfg.n_heads and KV > _SUBLANES and KV % _SUBLANES:
+        return -(-KV // _SUBLANES) * _SUBLANES
+    return KV
+
+
 def _cache_rows(cfg: TransformerConfig) -> dict:
     """What one token leaves in one layer of a cache, leaf name -> trailing
     shape: keys and values per KV head, or (latent attention) one leaf holding
     the normed latent followed by the rotary key, as the absorbed form reads them."""
     if cfg.latent_attention:
         return {"ckv": (_latent_row_width(cfg),)}
-    return {"k": (cfg.n_kv_heads, cfg.head_dim), "v": (cfg.n_kv_heads, cfg.head_dim)}
+    return {"k": (_cache_heads(cfg), cfg.head_dim), "v": (_cache_heads(cfg), cfg.head_dim)}
 
 
-_WINDOW, _FULL = "window", "full"
+_WINDOW, _FULL, _LINEAR = "window", "full", "linear"
 
 
 def _group_suffix(kind) -> str:
@@ -91,7 +113,31 @@ def _cache_groups(cfg: TransformerConfig) -> dict:
     layers apart, each indexed by a layer's rank among its kind."""
     if not cfg.layer_kinds:
         return {None: cfg.n_layers}
-    return {kind: cfg.layer_kinds.count(kind) for kind in (_FULL, _WINDOW)}
+    # Beside linear layers (which hold no token's rows: ``state_rows``) only full layers run.
+    kinds = (_FULL,) if _LINEAR in cfg.layer_kinds else (_FULL, _WINDOW)
+    return {kind: cfg.layer_kinds.count(kind) for kind in kinds}
+
+
+def state_rows(cfg: TransformerConfig) -> dict:
+    """What one SLOT holds in one linear-attention layer, whatever its row's
+    length, leaf name -> (trailing shape, dtype): the gated delta rule's state a
+    head in float32 (it is summed into for a whole context), and the last
+    ``linear_conv - 1`` rows of the query / key / value projection, which the
+    next token's convolution reads. Empty without linear layers."""
+    if _LINEAR not in cfg.layer_kinds:
+        return {}
+    Hl, dk, dv = cfg.linear_heads, cfg.linear_key_dim, cfg.linear_value_dim
+    return {
+        "state": ((Hl, dk, dv), jnp.float32),
+        "conv": ((cfg.linear_conv - 1, Hl * (2 * dk + dv)), cfg.dtype),
+    }
+
+
+def state_slot_bytes(cfg: TransformerConfig) -> int:
+    """Bytes one slot holds in the linear layers' group of cache leaves, all
+    its layers: beside ``cache_token_bytes``, which grows with a row, this does not."""
+    row = sum(math.prod(shape) * jnp.dtype(dtype).itemsize for shape, dtype in state_rows(cfg).values())
+    return cfg.layer_kinds.count(_LINEAR) * row
 
 
 def cache_token_bytes(cfg: TransformerConfig) -> dict:
@@ -106,7 +152,16 @@ def init_cache(cfg: TransformerConfig, batch: int, max_len: int):
     k/v [.., KV, Dh], or ckv [.., the latent and the rotary key] (bf16 on
     TPU — cache reads are the decode bandwidth bill). Under a layer pattern
     the leaves of each kind's layers (``_cache_groups``); a dense cache keeps
-    ``max_len`` rows for a window layer too and masks them."""
+    ``max_len`` rows for a window layer too and masks them. Linear-attention
+    layers are refused: their state cannot be rewound to a position, which
+    ``speculative_generate`` and ``decode_chunk``'s callers count on, and they
+    are served through the paged cache only (``init_paged_cache``)."""
+    if _LINEAR in cfg.layer_kinds:
+        raise NotImplementedError(
+            "a dense cache (init_cache: prefill / decode_step / generate / speculative_generate) cannot hold "
+            "linear-attention layers (layer_kinds has 'linear'): their recurrent state is kept a slot of the "
+            "paged cache (init_paged_cache, serve/llm/engine.py)"
+        )
     return {
         name + _group_suffix(kind): jnp.zeros((layers, batch, max_len, *row), cfg.dtype)
         for kind, layers in _cache_groups(cfg).items()
@@ -120,12 +175,19 @@ def _project_qkv(lp, x, positions, cfg, rope: bool = True):
     ``rope`` False: no positional encoding (a full layer of a layer pattern)."""
     B, T, _ = x.shape
     H, KV, Dh = cfg.n_heads, cfg.n_kv_heads, cfg.head_dim
-    h = _rms_norm(x, lp["attn_norm"], cfg.norm_eps)
-    q = (h @ lp["wq"].astype(h.dtype)).reshape(B, T, H, Dh)
-    k = (h @ lp["wk"].astype(h.dtype)).reshape(B, T, KV, Dh)
-    v = (h @ lp["wv"].astype(h.dtype)).reshape(B, T, KV, Dh)
+    h = _rms_norm(x, lp["attn_norm"], cfg.norm_eps) if cfg.pre_norms else x
+
+    def heads(w, norm, n):  # ``cfg.qk_norm_whole``: normed over the whole projection, before it is split into heads
+        y = h @ lp[w].astype(h.dtype)
+        if norm and cfg.qk_norm_whole:
+            y = _rms_norm(y, lp[norm], cfg.norm_eps)
+        return y.reshape(B, T, n, Dh)
+
+    q, k, v = heads("wq", "q_norm", H), heads("wk", "k_norm", KV), heads("wv", None, KV)
     if cfg.qk_norm:
         q, k = _rms_norm(q, lp["q_norm"], cfg.norm_eps), _rms_norm(k, lp["k_norm"], cfg.norm_eps)
+    if _cache_heads(cfg) != KV:  # zero heads up to what a cached row holds
+        q, k, v = (jnp.pad(a, ((0, 0), (0, 0), (0, _cache_heads(cfg) - KV), (0, 0))) for a in (q, k, v))
     if not rope:
         return q, {"k": k, "v": v}
     return _rope(q, positions, cfg.rope_theta), {"k": _rope(k, positions, cfg.rope_theta), "v": v}
@@ -198,7 +260,7 @@ def _mlp(lp, x, cfg, valid=None, layer=None):
     only: padding reaches no expert and is not counted). ``layer``: the
     expert leaves of ``lp`` are whole stacks and this is the layer to run
     (``routed_experts``)."""
-    h = _rms_norm(x, lp["mlp_norm"], cfg.norm_eps)
+    h = _rms_norm(x, lp["mlp_norm"], cfg.norm_eps) if cfg.pre_norms else x
 
     def add(out):  # ``cfg.post_norms``: the branch is normed once more before it joins the residual
         return x + (_rms_norm(out, lp["mlp_post_norm"], cfg.norm_eps) if cfg.post_norms else out)
@@ -247,12 +309,28 @@ def _cache_attention(q, ck, cv, pos_mask, cfg):
         o = jnp.einsum("bgrqk,bkgd->bqgrd", p.astype(cv.dtype), cv,
                        preferred_element_type=jnp.float32)
         return o.reshape(B, T, H, Dh).astype(q.dtype)
+    # Plain multi-head attention (a cached head a query head), one formulation
+    # whatever the configuration. One query row a head is no matmul to the
+    # TPU's compiler: it casts the whole view to float32, writes it out and
+    # multiplies and sums on the vector unit (1 GB a layer for keys, 1 GB for
+    # values at 8 x 8192 tokens x 32 heads, PR 41). Eight rows, the added ones
+    # zeros, go to the matrix unit in bfloat16 as a grouped query's rows do.
+    if T < _SUBLANES:
+        q = jnp.pad(q, ((0, 0), (0, _SUBLANES - T), (0, 0), (0, 0)))
+        pos_mask = jnp.pad(pos_mask, ((0, 0), (0, _SUBLANES - T), (0, 0)), constant_values=True)
     s = jnp.einsum("bqhd,bkhd->bhqk", q, ck, preferred_element_type=jnp.float32)
     s = jnp.where(pos_mask[:, None], s * scale, -jnp.inf)
-    p = jax.nn.softmax(s, axis=-1)
-    o = jnp.einsum("bhqk,bkhd->bqhd", p.astype(cv.dtype), cv,
-                   preferred_element_type=jnp.float32)
-    return o.astype(q.dtype)
+    # Normalised AFTER the weighted sum, a [B, q, H] division where ``softmax``
+    # divides [B, H, q, S]: the TPU's compiler turns that division by a
+    # broadcast sum into a reduce-window over all S keys (160 of a 238 ms
+    # prefill chunk at 512 queries x 8192 keys, v5e, PR 41), and the scores are
+    # written once less. The weights go to the matrix unit in the cache's dtype
+    # either way; their sum is float32.
+    e = jnp.exp(s - jnp.max(s, axis=-1, keepdims=True))
+    o = jnp.einsum("bhqk,bkhd->bqhd", e.astype(cv.dtype), cv, preferred_element_type=jnp.float32)
+    o = o / jnp.moveaxis(jnp.sum(e, axis=-1), 1, 2)[..., None]
+    # Without the added rows, and without the zero heads a cached row may carry (``_cache_heads``).
+    return o[:, :T, : cfg.n_heads].astype(q.dtype)
 
 
 def _embed_chunk(params, tokens, pos, cfg):
@@ -371,9 +449,76 @@ class _Part(NamedTuple):
     access: _Access
 
 
-def _period(kinds: tuple) -> int:
-    """The shortest p with ``kinds[i] == kinds[i - p]`` throughout."""
-    return next(p for p in range(1, len(kinds) + 1) if all(kinds[i] == kinds[i - p] for i in range(p, len(kinds))))
+class _StateAccess(NamedTuple):
+    """How the linear layers reach their group of a cache: no positions and no
+    table. Row b of the call is slot ``slots[b]`` of the group's leaves (None:
+    row b IS slot b, and the call has a row for every slot: a decode step);
+    ``fresh[b]``: the row's state starts from zero (a request's first chunk,
+    whoever held the slot before); ``n_valid[b]``: how many of the row's q
+    tokens are real, from the first. The others move neither the state nor the
+    convolution's carried rows (a padded last chunk, an inactive slot)."""
+
+    slots: Any
+    fresh: Any
+    n_valid: Any
+
+
+def _linear_mixer(lp, x, pool, at, acc: _StateAccess, cfg):
+    """A linear-attention layer's mixer over layer ``at`` of the state group:
+    x [B, q, D] -> (the gated, normed heads [B, q, Hl * dv] that ``wo`` takes,
+    the pool with the rows' state and carried convolution rows moved on).
+
+    ``u = x w_qkv``; each column through a causal convolution over the last
+    ``linear_conv`` tokens (those before the chunk are the slot's carried rows,
+    zeros ahead of a request's first token) and SiLU; per head ``q / |q| *
+    dk^-1/2``, ``k / |k|``; ``beta = sigmoid(x w_b)`` (twice that under
+    ``linear_neg_eigval``), ``g = -exp(A_log) softplus(x w_a + dt_bias)``; the
+    gated delta rule (ops/linear_attention.py: the chunked form for q > 1, the
+    step for q == 1); ``RMSNorm_dv(o) * silu(x wg_lin)``. From the convolution
+    on everything is float32."""
+    from ray_tpu.ops.linear_attention import gated_delta_chunk, gated_delta_step
+
+    B, q, _ = x.shape
+    Hl, dk, dv, K = cfg.linear_heads, cfg.linear_key_dim, cfg.linear_value_dim, cfg.linear_conv
+    h = _rms_norm(x, lp["attn_norm"], cfg.norm_eps) if cfg.pre_norms else x
+
+    def held(name):
+        leaf = pool[name]
+        rows = lax.dynamic_index_in_dim(leaf, at, 0, keepdims=False) if acc.slots is None else leaf[at, acc.slots]
+        return jnp.where(acc.fresh.reshape(B, *[1] * (rows.ndim - 1)), jnp.zeros_like(rows), rows)
+
+    def put(name, rows):
+        leaf = pool[name]
+        if acc.slots is None:
+            return lax.dynamic_update_index_in_dim(leaf, rows.astype(leaf.dtype), at, 0)
+        return leaf.at[at, acc.slots].set(rows.astype(leaf.dtype))
+
+    u = h @ lp["w_qkv"].astype(h.dtype)
+    f32 = jnp.float32
+    gates = jnp.einsum("bqd,dh->bqh", h, jnp.concatenate([lp["w_a"], lp["w_b"]], axis=1).astype(h.dtype),
+                       preferred_element_type=f32)
+    g = -jnp.exp(lp["A_log"].astype(f32)) * jax.nn.softplus(gates[..., :Hl] + lp["dt_bias"].astype(f32))
+    beta = jax.nn.sigmoid(gates[..., Hl:]) * (2.0 if cfg.linear_neg_eigval else 1.0)
+    with jax.named_scope("linear_attention_step" if q == 1 else "linear_attention_scan"):
+        seen = jnp.concatenate([held("conv"), u], axis=1)  # [B, K - 1 + q, C]: row j + K - 1 is token j
+        taps = lp["conv_w"].astype(f32)
+        y = jax.nn.silu(sum(seen[:, i : i + q].astype(f32) * taps[i] for i in range(K)))
+        qh, kh, vh = (
+            part.reshape(B, q, Hl, -1) for part in jnp.split(y, [Hl * dk, 2 * Hl * dk], axis=-1)
+        )
+        qh = qh * lax.rsqrt(jnp.sum(qh * qh, axis=-1, keepdims=True) + 1e-6) * dk**-0.5
+        kh = kh * lax.rsqrt(jnp.sum(kh * kh, axis=-1, keepdims=True) + 1e-6)
+        if q == 1:
+            o, S = gated_delta_step(qh[:, 0], kh[:, 0], vh[:, 0], g[:, 0], beta[:, 0], held("state"), acc.n_valid > 0)
+            o = o[:, None]
+        else:
+            o, S = gated_delta_chunk(qh, kh, vh, g, beta, held("state"), acc.n_valid)
+        # The K - 1 rows before the first token that is not real: the chunk's
+        # last real ones, or (none real) the rows carried in.
+        tail = jax.vmap(lambda rows, n: lax.dynamic_slice_in_dim(rows, n, K - 1, axis=0))(seen, acc.n_valid)
+        pool = {**pool, "state": put("state", S), "conv": put("conv", tail)}
+    o = _rms_norm(o, lp["o_norm"], cfg.norm_eps).astype(x.dtype).reshape(B, q, Hl * dv)
+    return o * jax.nn.silu(h @ lp["wg_lin"].astype(h.dtype)), pool
 
 
 def _cached_layers(params, x, cache, positions, access, cfg, key_len=None, valid=None, parts=None):
@@ -425,7 +570,8 @@ def _cached_layers(params, x, cache, positions, access, cfg, key_len=None, valid
             for part in parts:
                 ck, cv = (part.access.view(pool[name], at) for name in ("k", "v"))
                 mask = _cache_mask(part.positions, ck.shape[1], cfg.sliding_window, None, part.access.key_pos)
-                out.append(_cache_attention(of(part, qh), ck, cv, mask, cfg).reshape(1, -1, qh.shape[2] * qh.shape[3]))
+                o = _cache_attention(of(part, qh), ck, cv, mask, cfg)  # the query heads: not the zero heads a cached row may carry
+                out.append(o.reshape(1, -1, o.shape[2] * o.shape[3]))
         return jnp.concatenate(out, axis=1), pool
 
     def run_layer(x, pool, lp, kind, at, l, first, held):
@@ -433,6 +579,11 @@ def _cached_layers(params, x, cache, positions, access, cfg, key_len=None, valid
         group, layer ``l - first`` of its stack."""
         acc = access[kind] if kind else access
         sfx = _group_suffix(kind)
+        if kind == _LINEAR:
+            o, pool = _linear_mixer(lp, x, pool, at, acc, cfg)
+            a = o @ lp["wo"].astype(o.dtype)
+            x = x + (_rms_norm(a, lp["attn_post_norm"], cfg.norm_eps) if cfg.post_norms else a)
+            return _mlp(lp, x, cfg)[0], pool, None
         project = _project_latent if latent else _project_qkv if kind != _FULL else partial(_project_qkv, rope=False)
         qh, rows = project(lp, x, positions, cfg)
         if parts is not None:
@@ -476,26 +627,62 @@ def _cached_layers(params, x, cache, positions, access, cfg, key_len=None, valid
         x, pool, sent = run_layer(x, pool, {**lp, **held}, None, l, l, first, held)
         return (x, pool), sent
 
-    def scan_periods(first, held, sliced, kinds, x, pool):
-        """A stack under a layer pattern: ``kinds`` of its layers, which are
-        layers ``first..`` of the model."""
+    def scan_periods(first, held, stacks, kinds, x, pool):
+        """Layers ``first..`` of the model under a layer pattern, of ``kinds``.
+        ``stacks``: one stack of leaves that holds them all, a layer at its
+        position, or (a dict by kind; ``first`` 0) one stack a kind, a layer at
+        its rank among its kind, which is its index into its group of cache
+        leaves too. One scan over the PERIODS; inside a period a run of layers
+        of one kind that lie in a stack of their own is a scan of its own
+        (three linear layers: one body, not three), any other layer a call (a
+        shared stack's program is as it was measured: Trinity's lowered text is
+        the guard, PR 41). Every body indexes the WHOLE stacks,
+        which the scans close over: handed to the outer scan as xs, a period's
+        slice of every matrix is copied out before an inner scan may read it
+        (15 ms of a 46 ms decode step at Olmo-Hybrid's widths, v5e, PR 41)."""
         P = _period(kinds)
-        before = {kind: cfg.layer_kinds[:first].count(kind) for kind in (_WINDOW, _FULL)}
+        by_kind = isinstance(next(iter(stacks.values())), dict)
+        before = {kind: cfg.layer_kinds[:first].count(kind) for kind in set(kinds)}
+        a_period = {kind: kinds[:P].count(kind) for kind in set(kinds)}
+        runs, j = [], 0  # (first layer, layers) of each run of one kind through one period
+        while j < P:
+            n = next((i for i in range(j, P) if kinds[i] != kinds[j]), P) - j if by_kind else 1
+            runs.append((j, n))
+            j += n
 
-        def layer_at(x, pool, period, j):
-            # ``period`` traced (the scan's) or static (the remainder's)
-            kind, s = kinds[j], period * P + j
-            lp = {n: lax.dynamic_index_in_dim(leaf, s, 0, keepdims=False) for n, leaf in sliced.items()}
-            at = before[kind] + period * kinds[:P].count(kind) + kinds[:j].count(kind)
+        def take(stack, index):
+            return {n: lax.dynamic_index_in_dim(leaf, index, 0, keepdims=False) for n, leaf in stack.items()}
+
+        def layer_at(x, pool, period, j, i=0):
+            # Layer ``j`` of period ``period``, or (``i``, traced: a run's scan)
+            # the i-th layer of the run of one kind that starts there.
+            # ``period`` traced (the scan's) or static (the remainder's).
+            kind = kinds[j]
+            rank = lambda: before[kind] + period * a_period[kind] + kinds[:j].count(kind)  # noqa: E731
+            if by_kind:  # at its rank among its kind, in its stack as in its group of cache leaves
+                at = s = rank() + i
+                lp = take(stacks[kind], at)
+            else:  # at its position in the stack; a shared stack's runs are not scanned (i is 0)
+                s = period * P + j
+                lp = take(stacks, s)
+                at = rank()
             return run_layer(x, pool, {**lp, **held}, kind, at, first + s, first, held)
 
         def one_period(carry, period):
-            x, pool = carry
             sent = []
-            for j in range(P):
-                x, pool, s = layer_at(x, pool, period, j)
+            for j, n in runs:
+                if n == 1:
+                    *carry, s = layer_at(*carry, period, j)
+                else:
+                    def one(c, i, j=j):
+                        *c, s = layer_at(*c, period, j, i)
+                        return tuple(c), s
+
+                    carry, s = lax.scan(one, tuple(carry), jnp.arange(n, dtype=jnp.int32))
                 sent.append(s)
-            return (x, pool), None if sent[0] is None else jnp.stack(sent)
+            if sent[0] is None:
+                return tuple(carry), None
+            return tuple(carry), jnp.concatenate([s if n > 1 else jnp.expand_dims(s, 0) for s, (_, n) in zip(sent, runs)])
 
         whole = len(kinds) // P
         (x, pool), sent = lax.scan(one_period, (x, pool), jnp.arange(whole, dtype=jnp.int32))
@@ -505,6 +692,11 @@ def _cached_layers(params, x, cache, positions, access, cfg, key_len=None, valid
             if s is not None:
                 sent.append(s[None])
         return x, pool, jnp.concatenate(sent) if sent else None
+
+    if _LINEAR in cfg.layer_kinds:  # two stacks by kind (``transformer._layer_stacks``), and no routed experts
+        stacks = {_LINEAR: params[LINEAR_LAYERS], _FULL: params["layers"]}
+        x, pool, _ = scan_periods(0, {}, stacks, cfg.layer_kinds, x, pool)
+        return _rms_norm(x, params["norm_f"], cfg.norm_eps), pool
 
     first, sent = 0, None
     for name in ("dense_layers", "layers"):
@@ -652,7 +844,9 @@ def decode_step(params, token, cache, pos, cfg: TransformerConfig):
     return logits[:, 0], cache
 
 
-def init_paged_cache(cfg: TransformerConfig, num_blocks: int, block_size: int, window_blocks: int = 0):
+def init_paged_cache(
+    cfg: TransformerConfig, num_blocks: int, block_size: int, window_blocks: int = 0, state_slots: int = 0
+):
     """Block-pool cache for continuous-batching serving, every leaf
     [L, num_blocks, block_size, ...] (``_cache_rows``): k/v [.., KV, Dh], or with
     latent attention the one leaf ckv [.., the latent and the rotary key].
@@ -665,13 +859,21 @@ def init_paged_cache(cfg: TransformerConfig, num_blocks: int, block_size: int, w
     each with a null block of its own: the full layers' ``[full layers,
     num_blocks, ...]``, reached through a row's block table as ever, and the
     window layers' ``[window layers, window_blocks, ...]``, reached through a
-    row's RING (``_ring_access``): ``window_blocks`` is 1 + rings x blocks a ring."""
+    row's RING (``_ring_access``): ``window_blocks`` is 1 + rings x blocks a ring.
+
+    Linear-attention layers have a third group, which holds no token's rows
+    and has no blocks: ``[linear layers, state_slots, ...]`` (``state_rows``),
+    what each of ``state_slots`` serving slots carries from program to
+    program, reached by the slot's index (``_StateAccess``)."""
     blocks = {None: num_blocks, _FULL: num_blocks, _WINDOW: window_blocks}
-    return {
+    pool = {
         name + _group_suffix(kind): jnp.zeros((layers, blocks[kind], block_size, *row), cfg.dtype)
         for kind, layers in _cache_groups(cfg).items()
         for name, row in _cache_rows(cfg).items()
     }
+    for name, (shape, dtype) in state_rows(cfg).items():
+        pool[name] = jnp.zeros((cfg.layer_kinds.count(_LINEAR), state_slots, *shape), dtype)
+    return pool
 
 
 def _paged_write(block_tables, positions, valid_to, block_size: int):
@@ -732,19 +934,31 @@ def _ring_access(ring_tables, positions, valid_to, block_size: int, n_view: int)
 
 
 def paged_decode_chunk_hidden(
-    params, tokens, cache, block_tables, pos, cfg: TransformerConfig, valid_to=None, ring_tables=None
+    params, tokens, cache, block_tables, pos, cfg: TransformerConfig, valid_to=None, ring_tables=None,
+    state_slots=None, state_fresh=None,
 ):
     """``paged_decode_chunk`` without the head projection: returns the final
     normed hidden states [B, q, D] + cache. Chunked prefill consumes logits
     for at most ONE row per prompt — callers project that row themselves
     (``last_row_logits``) instead of paying [B, q, V]. ``ring_tables`` [B, R]
-    (a layer pattern only): each row's ring in the window layers' group."""
+    (a layer pattern only): each row's ring in the window layers' group.
+    Linear-attention layers only: ``state_slots`` [B] int32, each row's slot in
+    their group (None: row b is slot b, B the group's slots), and
+    ``state_fresh`` [B] bool, the rows whose state starts from zero (None:
+    none). A row moves its slot's state by the tokens that are real: those
+    under ``valid_to``, of a live row (one whose table starts at a real block)."""
     pos = jnp.asarray(pos, jnp.int32)
     block_tables = jnp.asarray(block_tables, jnp.int32)
     x, positions = _embed_chunk(params, tokens, pos, cfg)
-    block_size = next(iter(_pool_leaves(cache).values())).shape[2]
+    block_size = cache["k" if "k" in cache else "ckv"].shape[2]
     access = _Access(_paged_write(block_tables, positions, valid_to, block_size), _paged_view(block_tables))
-    if cfg.layer_kinds:
+    if _LINEAR in cfg.layer_kinds:
+        B, q = positions.shape
+        real = q if valid_to is None else jnp.clip(jnp.asarray(valid_to, jnp.int32) - pos, 0, q)
+        live = block_tables[:, 0] != 0
+        fresh = jnp.zeros((B,), bool) if state_fresh is None else jnp.asarray(state_fresh, bool)
+        access = {_FULL: access, _LINEAR: _StateAccess(state_slots, fresh, jnp.where(live, real, 0).astype(jnp.int32))}
+    elif cfg.layer_kinds:
         ring_tables = jnp.asarray(ring_tables, jnp.int32)
         n_view = min(ring_tables.shape[1], block_tables.shape[1])
         access = {_FULL: access, _WINDOW: _ring_access(ring_tables, positions, valid_to, block_size, n_view)}
@@ -759,7 +973,8 @@ def paged_decode_chunk_hidden(
 
 
 def paged_decode_chunk(
-    params, tokens, cache, block_tables, pos, cfg: TransformerConfig, valid_to=None, ring_tables=None
+    params, tokens, cache, block_tables, pos, cfg: TransformerConfig, valid_to=None, ring_tables=None,
+    state_slots=None, state_fresh=None,
 ):
     """``decode_chunk`` over a PAGED cache: tokens [B, q] written at per-row
     positions pos[b]..pos[b]+q-1, where logical position p of row b lives in
@@ -784,7 +999,8 @@ def paged_decode_chunk(
     match the dense-cache path row for row (the serving oracle).
     """
     x, cache = paged_decode_chunk_hidden(
-        params, tokens, cache, block_tables, pos, cfg, valid_to=valid_to, ring_tables=ring_tables
+        params, tokens, cache, block_tables, pos, cfg, valid_to=valid_to, ring_tables=ring_tables,
+        state_slots=state_slots, state_fresh=state_fresh,
     )
     return _logits(params, x), cache
 

@@ -99,6 +99,27 @@ class TransformerConfig:
     attn_gate: bool = False
     qk_norm: bool = False
     post_norms: bool = False
+    # The ``olmo`` family's block (inference only): ``pre_norms`` False takes the
+    # norm off each branch's INPUT (with ``post_norms`` the layer is ``x +
+    # norm(mixer(x))``, ``h + norm(mlp(h))``); ``qk_norm_whole`` norms queries
+    # and keys over the whole projection, a learned [H * Dh] and [KV * Dh]
+    # weight, before the split into heads.
+    pre_norms: bool = True
+    qk_norm_whole: bool = False
+    # Linear-attention layers (inference only): the kind ``"linear"`` of
+    # ``layer_kinds``, a gated delta rule (ops/linear_attention.py) over
+    # ``linear_heads`` heads, keys and queries ``linear_key_dim`` wide, values
+    # ``linear_value_dim``, each behind a causal depthwise convolution over
+    # ``linear_conv`` tokens. ``linear_neg_eigval``: the write strength runs over
+    # (0, 2), not (0, 1). Such a layer keeps no positions: a serving slot holds
+    # its state (models/generate.py, serve/llm/engine.py). Its leaves are its
+    # own, so the layers of a pattern with it are stacked by kind
+    # (``_layer_stacks``), and the pattern is whole periods of it.
+    linear_heads: int = 0
+    linear_key_dim: int = 0
+    linear_value_dim: int = 0
+    linear_conv: int = 4
+    linear_neg_eigval: bool = False
     # Embeddings are multiplied by this as they are read (muP: sqrt(d_model));
     # the table is drawn that much smaller, so that a layer's branch weighs as
     # much beside the residual as without it.
@@ -116,13 +137,31 @@ class TransformerConfig:
             object.__setattr__(self, "head_dim", self.d_model // self.n_heads)
         kinds = tuple(self.layer_kinds)
         object.__setattr__(self, "layer_kinds", kinds)
-        if kinds and (len(kinds) != self.n_layers or set(kinds) - {"window", "full"}):
+        if kinds and (len(kinds) != self.n_layers or set(kinds) - {"window", "full", "linear"}):
             raise ValueError(
                 f"layer_kinds names {len(kinds)} layers {sorted(set(kinds))}: need "
-                f"n_layers = {self.n_layers} of 'window' / 'full'"
+                f"n_layers = {self.n_layers} of 'window' / 'full' / 'linear'"
             )
         if "window" in kinds and not self.sliding_window:
             raise ValueError("layer_kinds has window layers and sliding_window is 0")
+        if "linear" in kinds:
+            if not (self.linear_heads and self.linear_key_dim and self.linear_value_dim and self.linear_conv > 1):
+                raise ValueError(
+                    "layer_kinds has linear layers: linear_heads, linear_key_dim and linear_value_dim "
+                    "must be set, and linear_conv at least 2"
+                )
+            if len(kinds) % _period(kinds):
+                raise ValueError(
+                    f"layer_kinds with linear layers must be whole periods: {len(kinds)} layers, period {_period(kinds)}"
+                )
+            for field, what in (
+                ("window" in kinds, "window layers beside linear layers (layer_kinds)"),
+                (self.latent_attention, "latent attention (kv_lora_rank > 0) beside linear layers"),
+                (self.num_experts > 0, "experts (num_experts > 0) in a pattern with linear layers"),
+                (self.first_dense_layers > 0, "leading dense layers (first_dense_layers) before linear layers"),
+            ):
+                if field:
+                    raise ValueError(f"{what}: has not run and is not built")
 
     @property
     def latent_attention(self) -> bool:
@@ -148,14 +187,28 @@ class TransformerConfig:
             ("attn_gate", "gated attention (attn_gate)"),
             ("qk_norm", "per-head query and key norms (qk_norm)"),
             ("post_norms", "post-branch norms (post_norms)"),
+            ("qk_norm_whole", "query and key norms over the whole projection (qk_norm_whole)"),
+            ("linear_heads", "linear-attention layers (linear_heads)"),
+            ("linear_key_dim", "linear-attention layers (linear_key_dim)"),
+            ("linear_value_dim", "linear-attention layers (linear_value_dim)"),
+            ("linear_neg_eigval", "a write strength over (0, 2) (linear_neg_eigval)"),
         ):
             if getattr(self, field):
                 missing.append(f"{what} has no training block")
+        if not self.pre_norms:
+            missing.append("a block without input norms (pre_norms) has no training block")
+        if self.linear_conv != 4:
+            missing.append("a linear layer's convolution (linear_conv) has no training block")
         if self.head_dim * self.n_heads != self.d_model:
             missing.append("a head_dim other than d_model // n_heads has no training block")
         if self.embed_multiplier != 1.0:
             missing.append("an embedding multiplier (embed_multiplier) has no training block")
         return "; ".join(missing)
+
+
+def _period(kinds: tuple) -> int:
+    """The shortest p with ``kinds[i] == kinds[i - p]`` throughout."""
+    return next(p for p in range(1, len(kinds) + 1) if all(kinds[i] == kinds[i - p] for i in range(p, len(kinds))))
 
 
 # Elements _draw_normal makes per loop iteration, and the largest slice it
@@ -197,26 +250,53 @@ def _draw_normal(key, shape, scale, dtype):
 class _Leaf(NamedTuple):
     """How one leaf of a layer is made and sharded."""
 
-    key: Any  # index into the stack's keys; None: a norm weight, ones
+    key: Any  # index into the stack's keys; None: a constant (a norm weight: ones)
     shape: tuple
-    scale: Any  # of the normal draw; None with ``key``
+    scale: Any  # of the normal draw; with ``key`` None the constant, None for 1
     axes: tuple  # logical axis names (parallel/mesh.logical_to_spec)
     dtype: Any = None  # None: the configuration's ``param_dtype``
 
 
-def _layer_leaves(cfg: TransformerConfig, mlp: str) -> dict:
+def _layer_leaves(cfg: TransformerConfig, mlp: str, linear: bool = False) -> dict:
     """One layer's leaves by name. ``mlp`` is ``"dense"``, ``"switch"`` or
-    ``"routed"``. Stacked, every leaf gains a leading layer axis."""
+    ``"routed"``; ``linear``: a linear-attention layer's mixer in place of
+    attention's. Stacked, every leaf gains a leading layer axis."""
     D, H, KV, Dh, F, E = (
         cfg.d_model, cfg.n_heads, cfg.n_kv_heads, cfg.head_dim, cfg.d_ff, cfg.num_experts
     )
     s = D**-0.5
     out = (2 * cfg.n_layers) ** -0.5  # residual branches shrink with depth
-    leaves = {
-        "attn_norm": _Leaf(None, (D,), None, (None,)),
-        "mlp_norm": _Leaf(None, (D,), None, (None,)),
-    }
-    if cfg.latent_attention:
+    leaves = {}
+    if cfg.pre_norms:
+        leaves = {
+            "attn_norm": _Leaf(None, (D,), None, (None,)),
+            "mlp_norm": _Leaf(None, (D,), None, (None,)),
+        }
+    if linear:
+        Hl, dk, dv = cfg.linear_heads, cfg.linear_key_dim, cfg.linear_value_dim
+        # Queries, keys and values are one matrix: the convolution runs over
+        # its 2 Hl dk + Hl dv columns as they lie, and a slot carries the last
+        # rows of the product. The two gates' projections are drawn small and
+        # ``dt_bias`` low, because a branch's input is not normed here and the
+        # residual grows with depth: a decay exp(-exp(A_log) softplus(x w_a +
+        # dt_bias)) that is neither 0 nor 1 and a write strength that does not
+        # saturate, at every depth (benchmarks/configs/olmo-hybrid-7b-serve16.json,
+        # ``assumed``, has the range). ``A_log`` and ``dt_bias`` stay float32
+        # whatever the weights' dtype, like a router: a decay is exponentiated twice.
+        leaves.update(
+            {
+                "w_qkv": _Leaf(0, (D, Hl * (2 * dk + dv)), s, ("embed", "heads")),
+                "conv_w": _Leaf(1, (cfg.linear_conv, Hl * (2 * dk + dv)), cfg.linear_conv**-0.5, (None, "heads")),
+                "w_a": _Leaf(2, (D, Hl), 0.2 * s, ("embed", None)),
+                "w_b": _Leaf(8, (D, Hl), 0.5 * s, ("embed", None)),
+                "A_log": _Leaf(9, (Hl,), 0.5, (None,), jnp.float32),
+                "dt_bias": _Leaf(None, (Hl,), -4.0, (None,), jnp.float32),
+                "wg_lin": _Leaf(13, (D, Hl * dv), s, ("embed", "heads")),
+                "o_norm": _Leaf(None, (dv,), None, (None,)),
+                "wo": _Leaf(3, (Hl * dv, D), (Hl * dv) ** -0.5 * out, ("heads", "embed")),
+            }
+        )
+    elif cfg.latent_attention:
         R, Rq = cfg.kv_lora_rank, cfg.q_lora_rank
         N, P, Vd = cfg.qk_nope_head_dim, cfg.qk_rope_head_dim, cfg.v_head_dim
         leaves.update(
@@ -244,6 +324,9 @@ def _layer_leaves(cfg: TransformerConfig, mlp: str) -> dict:
         if cfg.qk_norm:
             leaves["q_norm"] = _Leaf(None, (Dh,), None, (None,))
             leaves["k_norm"] = _Leaf(None, (Dh,), None, (None,))
+        if cfg.qk_norm_whole:
+            leaves["q_norm"] = _Leaf(None, (H * Dh,), None, (None,))
+            leaves["k_norm"] = _Leaf(None, (KV * Dh,), None, (None,))
     if cfg.post_norms:
         leaves["attn_post_norm"] = _Leaf(None, (D,), None, (None,))
         leaves["mlp_post_norm"] = _Leaf(None, (D,), None, (None,))
@@ -287,14 +370,23 @@ def _layer_leaves(cfg: TransformerConfig, mlp: str) -> dict:
     return leaves
 
 
+LINEAR_LAYERS = "linear_layers"
+
+
 def _layer_stacks(cfg: TransformerConfig) -> dict:
     """``params`` key -> (depth, kind of MLP) of each stack of layers, in the
     order they run: the leading dense layers (where the configuration has
-    any), then ``"layers"``."""
+    any), then ``"layers"``. A pattern with linear-attention layers, whose
+    leaves are not the attention layers', is stacked by kind: its linear layers
+    in ``"linear_layers"`` and the others in ``"layers"``, each in the order
+    its kind's layers come in the model, which runs them interleaved."""
     mlp = "routed" if cfg.routed_experts else "switch" if cfg.num_experts > 0 else "dense"
     n_dense = cfg.first_dense_layers
+    n_linear = cfg.layer_kinds.count("linear")
     stacks = {"dense_layers": (n_dense, "dense")} if n_dense else {}
-    stacks["layers"] = (cfg.n_layers - n_dense, mlp)
+    if n_linear:
+        stacks[LINEAR_LAYERS] = (n_linear, mlp)
+    stacks["layers"] = (cfg.n_layers - n_dense - n_linear, mlp)
     return stacks
 
 
@@ -308,7 +400,10 @@ def init_params(key, cfg: TransformerConfig) -> dict:
     # for value against the parent's). A latent or routed stack has up to
     # sixteen leaves and there may be two stacks, more than ``ks`` holds: each
     # stack then splits sixteen keys of its own from ``fold_in(key, stack)``.
-    own_keys = cfg.latent_attention or cfg.routed_experts or cfg.first_dense_layers > 0 or cfg.attn_gate
+    own_keys = (
+        cfg.latent_attention or cfg.routed_experts or cfg.first_dense_layers > 0 or cfg.attn_gate
+        or "linear" in cfg.layer_kinds
+    )
 
     # Every drawn leaf is a program of its own to compile (about a second each
     # on the TPU, PR 32) and there are up to forty: they are drawn side by side
@@ -317,13 +412,13 @@ def init_params(key, cfg: TransformerConfig) -> dict:
     # spent 34 of its 73 s here (v5e, PR 35), under a Serve that gives it 90.
     drawn = _Drawn(key)
 
-    def stack(i, L, mlp):
+    def stack(i, stack_name, L, mlp):
         keys = jax.random.split(jax.random.fold_in(key, i), 16) if own_keys else ks
         return {
-            name: jnp.ones((L, *leaf.shape), leaf.dtype or dt)
+            name: jnp.full((L, *leaf.shape), 1.0 if leaf.scale is None else leaf.scale, leaf.dtype or dt)
             if leaf.key is None
             else drawn.later(keys[leaf.key], (L, *leaf.shape), leaf.scale, leaf.dtype or dt)
-            for name, leaf in _layer_leaves(cfg, mlp).items()
+            for name, leaf in _layer_leaves(cfg, mlp, linear=stack_name == LINEAR_LAYERS).items()
         }
 
     params = {
@@ -331,7 +426,7 @@ def init_params(key, cfg: TransformerConfig) -> dict:
         "norm_f": jnp.ones((D,), dt),
     }
     for i, (name, (L, mlp)) in enumerate(_layer_stacks(cfg).items()):
-        params[name] = stack(i, L, mlp)
+        params[name] = stack(i, name, L, mlp)
     if not cfg.tie_embeddings:
         params["lm_head"] = drawn.later(ks[9], (D, V), D**-0.5, dt)
     return drawn.now(params)
@@ -375,9 +470,10 @@ def param_logical_axes(cfg: TransformerConfig) -> dict:
     """Per-leaf logical axis names (mapped to mesh axes by
     parallel/mesh.logical_to_spec)."""
     axes = {"embed": ("vocab", "embed"), "norm_f": (None,)}
-    for name, (_, mlp) in _layer_stacks(cfg).items():
-        axes[name] = {
-            name: ("layers", *leaf.axes) for name, leaf in _layer_leaves(cfg, mlp).items()
+    for stack, (_, mlp) in _layer_stacks(cfg).items():
+        axes[stack] = {
+            name: ("layers", *leaf.axes)
+            for name, leaf in _layer_leaves(cfg, mlp, linear=stack == LINEAR_LAYERS).items()
         }
     if not cfg.tie_embeddings:
         axes["lm_head"] = ("embed", "vocab")

@@ -152,6 +152,9 @@ class LLMRequest:
         # submit(return_routed_experts=True): filled as the request completes.
         self.return_routed_experts = False
         self.routed_experts: Optional[np.ndarray] = None
+        # submit(return_state=True): filled as the request completes.
+        self.return_state = False
+        self.state: Optional[np.ndarray] = None
         self.t_recv: Optional[float] = None  # the proxy's stamp, same clock
         self.t_submit = time.monotonic()
         self.t_admit: Optional[float] = None  # first admission
@@ -264,8 +267,12 @@ _ROW_DRAW = slice(2, 6)  # LLMRequest._sched_draw
 _ROW_COUNTER = 6  # index of the token this dispatch draws
 # The block table from here on: n_max wide (prefill), a rung of _view_rungs
 # (decode). Under a layer pattern the row's ring in the window layers' group
-# comes first (``LLMEngine.ring_blocks`` wide), then the table.
+# comes first (``LLMEngine.ring_blocks`` wide); with linear-attention layers
+# ``_STATE_COLS`` columns, a prefill row's slot in their group and whether its
+# state starts from zero (a decode row IS its slot's: row b, slot b); then the
+# table.
 _ROW_TABLE = 7
+_STATE_COLS = 2
 
 # In a decode row's token column: the token is the id the step before drew for
 # this slot, still on the device (``decode``'s ``ids`` argument).
@@ -331,7 +338,9 @@ def _compiled_fns(cfg, ring: int = 0):
     slot's), and ``prefill(params, tokens [1, q], pool, rows [1, 7 + n_max])``,
     both ``-> (token ids int32, one a row, pool)``. ``ring`` (a layer pattern
     only): blocks of a row's ring, which a row carries ahead of its table
-    (``7 + ring + w`` columns).
+    (``7 + ring + w`` columns). With linear-attention layers ``_STATE_COLS``
+    columns, a row's slot and whether its state starts from zero, also lie
+    ahead of the table; only the prefill program reads them.
 
     ``decode_with_chunk(params, rows [num_slots, 7 + w], pool, ids [num_slots],
     tokens [1, q], chunk_rows [1, 7 + w]) -> (ids [num_slots], pool)`` is the
@@ -360,7 +369,12 @@ def _compiled_fns(cfg, ring: int = 0):
             )
             from ray_tpu.models.transformer import _logits
 
-            def tables(rows):
+            state = _STATE_COLS if "linear" in cfg.layer_kinds else 0
+
+            def tables(rows, chunk=False):
+                if state:  # never beside a ring: window layers do not come beside linear ones
+                    own = dict(state_slots=rows[:, _ROW_TABLE], state_fresh=rows[:, _ROW_TABLE + 1] != 0)
+                    return dict(block_tables=rows[:, _ROW_TABLE + state :], **(own if chunk else {}))
                 if not ring:
                     return dict(block_tables=rows[:, _ROW_TABLE:])
                 return dict(
@@ -380,7 +394,7 @@ def _compiled_fns(cfg, ring: int = 0):
                 # just that row instead of paying the [1, q, V] head matmul
                 # per chunk (the row is traced: no recompile per position).
                 pos, valid_to = rows[:, _ROW_POS], rows[:, _ROW_VALID_TO]
-                x, c = paged_decode_chunk_hidden(p, t, c, pos=pos, cfg=cfg, valid_to=valid_to, **tables(rows))
+                x, c = paged_decode_chunk_hidden(p, t, c, pos=pos, cfg=cfg, valid_to=valid_to, **tables(rows, chunk=True))
                 row = jnp.clip(valid_to - 1 - pos, 0, t.shape[1] - 1)
                 return _draw_row_tokens(last_row_logits(p, x, row), rows), c
 
@@ -429,6 +443,23 @@ _PATTERN_POOL_KV_PAYLOAD = (
     "under a layer pattern (layer_kinds) the window layers keep a ring a request, "
     "which no block table names (kv_transfer.py, ROADMAP R3)"
 )
+# And of layers that hold a token's rows at all. A linear-attention layer keeps
+# one recurrent state a slot: a prefix is its blocks AND the state after its
+# last token, which nothing snapshots.
+_STATE_POOL_KV_PAYLOAD = (
+    "{what} needs the KV transfer plane, whose payload is blocks of keys and values; "
+    "linear-attention layers (layer_kinds has 'linear') keep a recurrent state a slot, "
+    "which no block holds and nothing snapshots at a block's boundary (kv_transfer.py, ROADMAP R5)"
+)
+
+
+def _kv_payload_refusal(cfg) -> Optional[str]:
+    """Why this configuration's pool cannot feed the KV transfer plane (None: it can)."""
+    if cfg.latent_attention:
+        return _LATENT_POOL_KV_PAYLOAD
+    if "linear" in cfg.layer_kinds:
+        return _STATE_POOL_KV_PAYLOAD
+    return _PATTERN_POOL_KV_PAYLOAD if cfg.layer_kinds else None
 
 
 class _PrefixEntry:
@@ -485,12 +516,13 @@ class LLMEngine:
             init_moe_counts,
             init_paged_cache,
             ring_blocks,
+            state_slot_bytes,
         )
 
         if role not in ("both", "prefill", "decode"):
             raise ValueError(f"role must be both|prefill|decode, got {role!r}")
-        if (cfg.latent_attention or cfg.layer_kinds) and (role != "both" or cluster_prefix):
-            refusal = _LATENT_POOL_KV_PAYLOAD if cfg.latent_attention else _PATTERN_POOL_KV_PAYLOAD
+        refusal = _kv_payload_refusal(cfg)
+        if refusal and (role != "both" or cluster_prefix):
             raise ValueError(
                 refusal.format(what=f"role={role!r}" if role != "both" else "cluster_prefix=True")
             )
@@ -539,16 +571,30 @@ class LLMEngine:
         # has it whole from admission to its end, nothing of it is allocated
         # or freed, and however long the row grows a window layer holds and
         # gathers no more. The full layers keep the block tables above.
-        self.ring_blocks = (
-            ring_blocks(cfg.sliding_window, self.prefill_chunk, self.block_size) if cfg.layer_kinds else 0
-        )
-        self.num_window_blocks = self.num_slots * self.ring_blocks + 1 if cfg.layer_kinds else 0
+        windowed = "window" in cfg.layer_kinds
+        self.ring_blocks = ring_blocks(cfg.sliding_window, self.prefill_chunk, self.block_size) if windowed else 0
+        self.num_window_blocks = self.num_slots * self.ring_blocks + 1 if windowed else 0
+        # Linear-attention layers have a group of their own too, with no
+        # blocks at all: slot s owns row s of its leaves for good (the gated
+        # delta rule's state and the convolution's carried rows, a layer),
+        # whatever its request's length. Nothing resets it on the host: the
+        # first chunk of an admitted request, fresh or back from a preemption,
+        # says in its row that the state starts from zero (``_chunk_inputs``),
+        # and a decode row of an inactive slot moves nothing
+        # (``generate._StateAccess``). So a run-ahead row that was dropped, or
+        # a step's last write for a request that ended, leaves a state that
+        # the slot's next tenant never reads.
+        self.state_slot_bytes = state_slot_bytes(cfg)
+        self._state_cols = _STATE_COLS if self.state_slot_bytes else 0
         self._rings = 1 + np.arange(self.num_slots * self.ring_blocks, dtype=np.int32).reshape(
             self.num_slots, self.ring_blocks
         )
         self.params = params
         t0 = time.monotonic()
-        pool = init_paged_cache(cfg, self.num_blocks, self.block_size, self.num_window_blocks)
+        pool = init_paged_cache(
+            cfg, self.num_blocks, self.block_size, self.num_window_blocks,
+            state_slots=self.num_slots if self.state_slot_bytes else 0,
+        )
         # Bytes one token holds in each group of pool leaves, all its layers,
         # and in the pool at large.
         self._group_token_bytes = cache_token_bytes(cfg)
@@ -620,6 +666,13 @@ class LLMEngine:
             # program (``_shape_fuses``): one read of the weights, not two.
             "decode_steps_with_chunk": 0,
             "decode_rows_dropped": 0,
+            # Tokens the prefill chunks carried that were real, and the
+            # padding behind a prompt's last ones: what the fixed chunk wastes.
+            "chunk_tokens_valid": 0,
+            "chunk_tokens_padded": 0,
+            # Admissions that made a slot's recurrent state start from zero
+            # (linear-attention layers: every admission, a re-admission too).
+            "state_resets": 0,
         }
         # The decode step in flight: dispatched, its ids not fetched.
         self._inflight: Optional[_Step] = None
@@ -654,8 +707,17 @@ class LLMEngine:
         request_id: str = "",
         t_recv_ns: int = 0,
         return_routed_experts: bool = False,
+        return_state: bool = False,
     ) -> LLMRequest:
-        """``return_routed_experts`` (routed-expert models): a request that
+        """``return_state`` (linear-attention layers): a request that completes
+        leaves in ``req.state`` what its slot's recurrent state is after the
+        last token fed to the model (prompt + generated - 1: a request ends by
+        count and the last token drawn is never fed), [linear layers, heads,
+        dk, dv] in the pool's dtype, read from the pool once, as the request
+        ends: what the benchmark's check holds to the float32 recurrence, and
+        what a prefix cache or a handoff of such a model would have to carry.
+
+        ``return_routed_experts`` (routed-expert models): a request that
         completes leaves in ``req.routed_experts`` the experts each token fed
         to the model took, int [prompt + generated - 1, expert layers, k] (the
         last token drawn is never fed): what a rollout hands its trainer to
@@ -709,6 +771,9 @@ class LLMEngine:
         if return_routed_experts and not self.cfg.routed_experts:
             raise ValueError("return_routed_experts needs a model with routed experts")
         req.return_routed_experts = bool(return_routed_experts)
+        if return_state and not self.state_slot_bytes:
+            raise ValueError("return_state needs a model with linear-attention layers")
+        req.return_state = bool(return_state)
         req.request_id = str(request_id or "")
         req.t_recv = int(t_recv_ns) / 1e9 if t_recv_ns else None
         ctx = tracing.get_current_span_context()
@@ -720,7 +785,9 @@ class LLMEngine:
         n_hashable = (len(tokens) - 1) // self.block_size
         # Under a layer pattern no block is registered and no hit taken: a hit
         # would also need the window layers' rows of the prefix's last
-        # ``sliding_window`` tokens, which went with the ring that held them.
+        # ``sliding_window`` tokens, which went with the ring that held them,
+        # or the linear layers' state after the prefix's last token, which
+        # nothing kept.
         if not self.cfg.layer_kinds:
             req._sched_hashes = block_hashes(tokens, self.block_size)[:n_hashable]
         if len(resume) >= int(max_new_tokens):
@@ -733,10 +800,9 @@ class LLMEngine:
             self.spans.end_request(req, "finished")
             return req
         if kv_import is not None:
-            if self.cfg.latent_attention:
-                raise ValueError(_LATENT_POOL_KV_PAYLOAD.format(what="kv_import"))
-            if self.cfg.layer_kinds:
-                raise ValueError(_PATTERN_POOL_KV_PAYLOAD.format(what="kv_import"))
+            refusal = _kv_payload_refusal(self.cfg)
+            if refusal:
+                raise ValueError(refusal.format(what="kv_import"))
             self._attach_handoff_import(req, kv_import)
         elif self.cluster_prefix and not resume and req._sched_hashes:
             self._attach_cluster_prefix(req)
@@ -806,7 +872,9 @@ class LLMEngine:
         """Per group of pool leaves: its layers' bytes a token, its blocks and
         those in use. ``"full"``: the layers whose blocks grow with a row (all
         of them without a layer pattern). ``"window"``: the window layers of a
-        pattern, ``ring_blocks`` a running request whatever its length."""
+        pattern, ``ring_blocks`` a running request whatever its length.
+        ``"state"``: the linear-attention layers, ``bytes_per_slot`` for each of
+        ``num_slots`` for good, of which ``slots_in_use`` hold a request's."""
         groups = {
             "full": {
                 "kv_token_bytes": self._group_token_bytes["full"],
@@ -814,7 +882,13 @@ class LLMEngine:
                 "blocks_in_use": self.num_blocks - 1 - len(self._free),
             }
         }
-        if self.cfg.layer_kinds:
+        if self.state_slot_bytes:
+            groups["state"] = {
+                "bytes_per_slot": self.state_slot_bytes,
+                "num_slots": self.num_slots,
+                "slots_in_use": sum(r is not None for r in self._slots),
+            }
+        if self.ring_blocks:
             groups["window"] = {
                 "kv_token_bytes": self._group_token_bytes["window"],
                 "num_blocks": self.num_window_blocks - 1,
@@ -1386,6 +1460,9 @@ class LLMEngine:
             self._slots[slot] = req
             LLM.admitted += 1
             self._counts["admitted"] += 1
+            if self.state_slot_bytes:  # its first chunk starts at position 0: ``_chunk_inputs`` says so to the program
+                LLM.state_resets += 1
+                self._counts["state_resets"] += 1
             _flight.record(
                 "llm_admit",
                 f"{req.id}:T{len(req.prompt)}:hit{cached}:slot{slot}",
@@ -1427,6 +1504,17 @@ class LLMEngine:
         # final chunk.
         rows = self._program_rows(1, width)
         self._fill_row(rows[0], req, req._sched_target, pos0, width=width)
+        if self._state_cols:
+            # A chunk that starts a sequence starts its slot's state: admission
+            # put the request at position 0 (no prefix hit under a pattern),
+            # the first time and after a preemption.
+            rows[0, _ROW_TABLE + self.ring_blocks : _ROW_TABLE + self.ring_blocks + _STATE_COLS] = (
+                req._sched_slot, pos0 == 0,
+            )
+        LLM.chunk_tokens_valid += len(piece)
+        LLM.chunk_tokens_padded += q - len(piece)
+        self._counts["chunk_tokens_valid"] += len(piece)
+        self._counts["chunk_tokens_padded"] += q - len(piece)
         return fed, rows, len(piece)
 
     def _chunk_end(self, req: LLMRequest) -> int:
@@ -1479,7 +1567,7 @@ class LLMEngine:
         """``n`` all-zero rows of a program's int32 input, their block table
         ``width`` blocks wide (behind the ring, under a layer pattern): an
         inactive slot (token 0 at position 0 of the null block, drawn greedily)."""
-        return np.zeros((n, _ROW_TABLE + self.ring_blocks + width), np.int32)
+        return np.zeros((n, _ROW_TABLE + self.ring_blocks + self._state_cols + width), np.int32)
 
     def _fill_row(self, row: np.ndarray, req: LLMRequest, first: int, pos: int, ahead: int = 0, width: int = 0):
         """``first``: column 0, the token fed (decode) or valid_to (prefill);
@@ -1491,8 +1579,9 @@ class LLMEngine:
         row[_ROW_POS] = pos
         row[_ROW_DRAW] = req._sched_draw
         row[_ROW_COUNTER] = len(req._sched_generated) + ahead
-        table = _ROW_TABLE + self.ring_blocks
-        row[_ROW_TABLE:table] = self._rings[req._sched_slot]
+        rings = _ROW_TABLE + self.ring_blocks
+        row[_ROW_TABLE:rings] = self._rings[req._sched_slot]
+        table = rings + self._state_cols
         blocks = req._sched_table[:width] if width else req._sched_table
         row[table : table + len(blocks)] = blocks
 
@@ -1854,6 +1943,8 @@ class LLMEngine:
         req._finished = True
         if req.return_routed_experts and error is None and not cancelled and handoff is None:
             req.routed_experts = self._routed_experts(req)
+        if req.return_state and error is None and not cancelled and req._sched_slot is not None:
+            req.state = np.asarray(self._cache["state"][:, req._sched_slot])  # the step in flight has no row for it
         self._release_blocks(req)
         if req._sched_slot is not None and self._slots[req._sched_slot] is req:
             self._slots[req._sched_slot] = None

@@ -122,6 +122,12 @@ class _LLMStats:
         "prefix_import_hits",
         "prefix_import_misses",
         "prefix_import_errors",
+        # What the fixed prefill chunk carried, real tokens and the padding
+        # behind a prompt's last ones; admissions that made a slot's recurrent
+        # state start from zero (linear-attention layers).
+        "chunk_tokens_valid",
+        "chunk_tokens_padded",
+        "state_resets",
         # Scheduler-loop nanoseconds by span and iterations by kind: lists
         # of plain ints, indexed like SPAN_NAMES / ITERATION_KINDS.
         "span_ns",

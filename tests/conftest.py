@@ -85,6 +85,28 @@ _WRONG_SINCE_A_SECOND_ARCHITECTURE = {  # the first four (PR 32)
         "token's way back behind it. test_bench_delivery.py::test_trinitys_mix_is_the_issues_with_the_seven_"
         "behind_its_cache_pair holds the rest of it, and the pair at [-9:-7]"
     ),
+    # And since a fourth configuration, whose cell's requests are 8k tokens long (PR 41):
+    "test_bench_delivery.py::test_the_seven_are_declared_for_exactly_the_two_cells": (
+        "holds BENCHMARK.json's per_layer to END with PR 38's seven, to count 41 and the seven to two cells; PR 41 "
+        "appends its four behind them and its cell to the seven's lists. test_bench_olmo_hybrid.py::"
+        "test_the_new_entries_are_appended_behind_what_was_there holds the seven together behind the cache pair, "
+        "their two cells with PR 41's appended, and PR 41's four behind them, without a pin on the END"
+    ),
+    "test_bench_delivery.py::test_trinitys_mix_is_the_issues_with_the_seven_behind_its_cache_pair": (
+        "holds Trinity's cell and configuration to be the LAST, the cells to be seven and the cache pair to be "
+        "Trinity's alone; PR 41 appends a cell and a configuration, and its four full layers report the pair. "
+        "test_bench_olmo_hybrid.py::test_trinitys_mix_is_still_the_issues holds the rest of it"
+    ),
+    "test_bench_trinity.py::test_each_configuration_holds_its_own_published_keys": (
+        "looks every configuration's architecture up in a table of three and holds the set of configurations to "
+        "three models'; since PR 41 one is Olmo-Hybrid-7B. test_bench_olmo_hybrid.py::"
+        "test_each_configuration_holds_its_own_published_keys holds each to its own, by architecture"
+    ),
+    "test_bench_traffic.py::test_two_seeds_offer_the_same_token_load[longdoc-8k]": (
+        "holds every serving mix under 2560 tokens a request, one configuration's max_model_len; longdoc-8k runs "
+        "under 8192. test_bench_olmo_hybrid.py::test_every_mix_fits_the_cells_that_send_it holds each mix to its "
+        "own cells' limits, and the seeds' equal load there too"
+    ),
 }
 
 

@@ -130,6 +130,41 @@ def test_streams_submitted_together_are_each_what_it_is_alone(model, sampling):
         assert req.result(5) == alone and len(alone) == new
 
 
+def test_twelve_plain_heads_cached_as_sixteen_serve_the_full_forward_passes_tokens():
+    """Plain multi-head attention over 12 heads: a cached row holds 16, four
+    of them zeros (``generate._cache_heads``), queries get zero heads to match,
+    a decode row is padded to eight query rows, the softmax is normalised after
+    the weighted sum and the zero heads' outputs are dropped (PR 41: one
+    formulation for every plain multi-head model). Through the step that carries
+    a chunk and through the two programs alike, every greedy token is the full
+    forward pass's (training's attention: no cache, no padding, ``softmax``),
+    or lies within float32 rounding of its best."""
+    import importlib
+
+    import jax
+    import jax.numpy as jnp
+
+    from ray_tpu.models.transformer import forward, init_params
+
+    generate = importlib.import_module("ray_tpu.models.generate")
+    cfg = _config(n_heads=12, n_kv_heads=12, sliding_window=0)
+    assert generate._cache_heads(cfg) == 16 and generate._cache_rows(cfg)["k"] == (16, 4)
+    params = init_params(jax.random.PRNGKey(3), cfg)
+    logits = jax.jit(lambda tokens: forward(params, tokens, cfg)[0])
+    specs = [(51, 30, 7), (52, 13, 9), (53, 21, 6), (54, 5, 8)]
+    for fuse in (True, False):
+        eng = _stopped((params, cfg))
+        if not fuse:
+            eng._fused_rungs = ()
+        reqs = [eng.submit(_prompt(seed, n), max_new_tokens=new) for seed, n, new in specs]
+        s = _drive(eng, reqs)
+        assert (s["decode_steps_with_chunk"] > 0) == fuse
+        for req in reqs:
+            seq = req.prompt + req.result(5)
+            want = np.asarray(logits(jnp.asarray([seq])))[0, len(req.prompt) - 1 : -1]
+            assert (want.max(axis=-1) - want[np.arange(len(want)), seq[len(req.prompt) :]]).max() < 1e-4
+
+
 def test_the_pool_holds_what_the_two_programs_would_have_written(model):
     """The same five requests through an engine that fuses and one held to
     the two programs: what each request leaves in its blocks, read through its

@@ -385,7 +385,7 @@ def _cell_programs(cell_name: str):
         MOE_CHOICE, MOE_COUNTS, init_moe_choice, init_moe_counts, init_paged_cache, ring_blocks,
     )
     from ray_tpu.models.transformer import TransformerConfig, init_params
-    from ray_tpu.serve.llm.engine import _ROW_TABLE, _compiled_fns
+    from ray_tpu.serve.llm.engine import _ROW_TABLE, _STATE_COLS, _compiled_fns
 
     cell = registry.load_cell(registry.load_manifest(), cell_name)
     engine = cell["config"]["deployment"]["engine"]
@@ -395,11 +395,15 @@ def _cell_programs(cell_name: str):
     model.update(dtype=jnp.bfloat16, param_dtype=jnp.bfloat16)
     cfg = TransformerConfig(**model)
     slots, chunk, bs = engine["num_slots"], engine.get("prefill_chunk", 32), engine["block_size"]
-    ring = ring_blocks(cfg.sliding_window, chunk, bs) if cfg.layer_kinds else 0
+    linear = "linear" in cfg.layer_kinds  # a state group a slot, two columns of a program row for it, and no ring
+    ring = ring_blocks(cfg.sliding_window, chunk, bs) if "window" in cfg.layer_kinds else 0
+    lead = ring + (_STATE_COLS if linear else 0)
 
     def pool():
         blocks = engine["num_blocks"], bs
-        leaves = init_paged_cache(cfg, *blocks, window_blocks=slots * ring + 1 if ring else 0)
+        leaves = init_paged_cache(
+            cfg, *blocks, window_blocks=slots * ring + 1 if ring else 0, state_slots=slots if linear else 0
+        )
         if cfg.routed_experts:
             leaves.update({MOE_COUNTS: init_moe_counts(cfg), MOE_CHOICE: init_moe_choice(cfg, *blocks)})
         return leaves
@@ -410,9 +414,9 @@ def _cell_programs(cell_name: str):
 
     def args(width, with_chunk=False):
         if width is None:
-            return params, ints(1, chunk), jax.eval_shape(pool), ints(1, _ROW_TABLE + ring + n_max)
-        step = params, ints(slots, _ROW_TABLE + ring + width), jax.eval_shape(pool), ints(slots)
-        return (*step, ints(1, chunk), ints(1, _ROW_TABLE + ring + width)) if with_chunk else step
+            return params, ints(1, chunk), jax.eval_shape(pool), ints(1, _ROW_TABLE + lead + n_max)
+        step = params, ints(slots, _ROW_TABLE + lead + width), jax.eval_shape(pool), ints(slots)
+        return (*step, ints(1, chunk), ints(1, _ROW_TABLE + lead + width)) if with_chunk else step
 
     return (*_compiled_fns(cfg, ring)[:2], args)
 
@@ -507,3 +511,35 @@ def test_the_pattern_decode_step_gathers_rings_and_copies_neither_pools_nor_expe
     assert stats.alias_size_in_bytes >= pools  # 1.29 GB updated in place
     # the full layer's view of 32 x 8192 tokens (0.27 GB, keys then values) and little else
     assert stats.temp_size_in_bytes < 450e6, stats.temp_size_in_bytes
+
+
+def test_the_linear_pattern_programs_copy_neither_pool_nor_state_nor_a_periods_weights(one_v5e_chip):
+    """Olmo-Hybrid's decode program at the benchmark's widths and the cell's
+    rung (8192 tokens) and its prefill chunk, compiled for the v5e (PR 41). What
+    each assertion has seen fail on the way: with 30 cached heads a leaf the
+    compiler lays out two ways round and copies the whole pool between them,
+    2 GB a copy, and the step does not fit (``generate._cache_heads`` pads to
+    32); with a period's layers handed to the outer scan as xs, every matrix of
+    three linear layers is copied out before the inner scan reads it (254 MB a
+    matrix a period); one query row a head makes the compiler write the whole
+    view out in float32 (1 GB for keys, 1 GB for values, a full layer)."""
+    import re
+
+    import jax
+
+    decode, prefill, args = _cell_programs("olmo16.longdoc-8k")
+    describe = lambda a: jax.tree.map(lambda x: jax.ShapeDtypeStruct(x.shape, x.dtype, sharding=one_v5e_chip), a)  # noqa: E731
+    pool_bytes = 2 * 4 * 4097 * 16 * 32 * 128 * 2 + 12 * 8 * (30 * 96 * 192 * 4 + 3 * 11520 * 2)
+    for program, given in ((decode, args(512)), (prefill, args(None))):
+        compiled = program.lower(*describe(given)).compile()
+        text = compiled.as_text()
+        for leaf in ("bf16[4,4097,16,32,128]", "f32[12,8,30,96,192]"):
+            assert leaf in text and not re.search(rf"= {re.escape(leaf)}\S* copy\(", text), leaf
+        assert "bf16[4,4097,16,30,128]" not in text  # no leaf with a head axis that does not fill its tiles
+        assert not re.search(r"= bf16\[3,(3840|11008|5760),(11520|11008|3840|5760)\]\S* (fusion|copy)\(", text)  # a period's matrices
+        assert not re.search(r"= f32\[(4096,16|8,8192),32,128\]\S* (fusion|copy|convert)\(", text)  # the view in float32
+        stats = compiled.memory_analysis()
+        assert stats.alias_size_in_bytes >= pool_bytes  # 4.6 GB updated in place
+        # a full layer's view of 8 x 8192 tokens (0.54 GB, keys then values) or a chunk's scores (0.54 GB), and little else
+        assert stats.temp_size_in_bytes < 0.8e9, stats.temp_size_in_bytes
+        assert stats.temp_size_in_bytes + stats.argument_size_in_bytes < 15.75 * 2**30
