@@ -159,8 +159,10 @@ class Cluster:
     ) -> bool:
         """Push a chaos plan (None clears) into the worker PROCESS hosting
         the named actor — the seeded-kill lever for serve replicas: a
-        ``kill`` rule on e.g. ``("next_stream_chunk", side="resp")`` makes
-        the replica SIGKILL itself at the Nth streamed chunk."""
+        ``kill`` rule on ``("actor_call", side="resp")`` makes the
+        replica SIGKILL itself at its Nth answer, e.g. of a stream's
+        ``next_stream_chunks`` polls (a proxy takes the chunks of all its
+        streams on the replica in one such call: the kill fails them all)."""
         from ray_tpu._private.rpc import EventLoopThread
 
         found = self.find_actor_worker(actor_name)

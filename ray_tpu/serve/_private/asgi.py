@@ -24,6 +24,7 @@ which `ProxyASGIApp` translates back into ASGI send events.
 from __future__ import annotations
 
 import asyncio
+import itertools
 import json
 import logging
 import threading
@@ -169,6 +170,102 @@ class _SSETokenParser:
                 self.tokens.append(int(tok))
 
 
+class _ReplicaPoll:
+    """This proxy's streams on ONE replica, polled together: one
+    ``Replica.next_stream_chunks`` call in flight, whose reply is handed out
+    on the event loop to the ``_pump_stream`` coroutines that wait for it.
+
+    A stream is named in a poll only while its coroutine waits in
+    ``next_batch``, i.e. once it has written what it got before: a client that
+    does not read its socket keeps its stream out of the polls, holds nobody
+    else's chunks back, and piles up nothing here (the replica's queue of 8
+    bounds its producer). With one stream this is the poll a stream always
+    made. Lives on the proxy's loop; only the actor call runs in the pool."""
+
+    def __init__(self, app: "ProxyASGIApp", name: str, actor):
+        self._app = app
+        self._name = name
+        self._actor = actor
+        # sid -> (the future its coroutine awaits, t_got_ns, wrote_ns of its last batch)
+        self._waiting: dict = {}
+        self._task = None
+        self._in_flight = 0  # the number of the poll that is out, 0 for none
+        self._kicked = False
+
+    async def next_batch(self, sid, t_got_ns: int, wrote_ns: list):
+        """(the stream's next batch, when the reply that held it reached this
+        loop); the batch is None for a stream the replica no longer has.
+        Raises what the poll raised (a replica gone fails every stream its
+        poll named) or what this stream's producer raised. ``t_got_ns`` and
+        ``wrote_ns`` are the proxy's stamps of the batch returned last."""
+        loop = asyncio.get_running_loop()
+        fut = loop.create_future()
+        self._waiting[sid] = (fut, t_got_ns, wrote_ns)
+        if self._task is None:
+            self._task = loop.create_task(self._run(loop))
+        elif self._in_flight and not self._kicked:
+            # The poll that is out does not name this stream and may wait half
+            # a second for the others: have it return now (a stream just
+            # opened, a slow client that caught up; not the steady state,
+            # where the streams ask again before the next poll leaves).
+            self._kicked = True
+            try:
+                self._actor.wake_stream_poll.remote((self._app._poller, self._in_flight))
+            except Exception:
+                pass
+        try:
+            return await fut
+        finally:
+            if self._waiting.get(sid, (None,))[0] is fut:  # cancelled while it waited
+                del self._waiting[sid]
+
+    async def _run(self, loop):
+        import ray_tpu
+
+        app, actor = self._app, self._actor
+        while self._waiting:
+            asked, self._waiting = self._waiting, {}
+            self._in_flight, self._kicked = next(app._poll_numbers), False
+            # The proxy's three of a chunk's stamps (replica.py::CHUNK_STAMPS),
+            # on the clock of ``t_recv_ns``: when the poll was handed to the
+            # executor rides with that poll; when a batch came back to this
+            # loop and when each chunk's send returned ride with the NEXT poll
+            # that names the stream, all in the call's one argument.
+            poll = (
+                app._poller,
+                self._in_flight,
+                time.monotonic_ns(),  # t_asked_ns
+                [(sid, t_got_ns, wrote_ns) for sid, (_, t_got_ns, wrote_ns) in asked.items()],
+            )
+            reply, error = {}, None
+            try:
+                reply = await loop.run_in_executor(
+                    app._pool,
+                    lambda: ray_tpu.get(actor.next_stream_chunks.remote(poll), timeout=120),
+                )
+            except Exception as e:
+                error = e
+            t_got_ns = time.monotonic_ns()
+            self._in_flight = 0
+            for sid, (fut, _, _) in asked.items():
+                if fut.done():  # its coroutine was cancelled: the client left
+                    continue
+                if error is not None:
+                    fut.set_exception(error)
+                elif sid not in reply:  # nothing yet: it stays for the next poll, its stamps handed over
+                    self._waiting.setdefault(sid, (fut, 0, ()))
+                elif reply[sid] is not None and "error" in reply[sid]:
+                    fut.set_exception(reply[sid]["error"])
+                else:
+                    fut.set_result((reply[sid], t_got_ns))
+            # The coroutines woken above are queued ahead of this one: they
+            # write their chunks and ask again before the next poll is made up.
+            await asyncio.sleep(0)
+        self._task = None
+        if app._polls.get(self._name) is self:
+            del app._polls[self._name]
+
+
 class ProxyASGIApp:
     """Serve's HTTP ingress as an ASGI-3 application.
 
@@ -182,6 +279,19 @@ class ProxyASGIApp:
     def __init__(self, router, pool):
         self._router = router
         self._pool = pool
+        # The shared polls of this proxy's streams, one a replica that holds
+        # any (by actor name); the name the replicas know this proxy's polls
+        # by, and the polls' numbers (``Replica.wake_stream_poll``).
+        self._polls: dict = {}
+        self._poller = uuid.uuid4().hex[:12]
+        self._poll_numbers = itertools.count(1)
+
+    def _poll_of(self, replica, actor) -> _ReplicaPoll:
+        name = replica["actor_name"]
+        poll = self._polls.get(name)
+        if poll is None:
+            poll = self._polls[name] = _ReplicaPoll(self, name, actor)
+        return poll
 
     async def __call__(self, scope, receive, send):
         if scope["type"] == "lifespan":
@@ -400,8 +510,12 @@ class ProxyASGIApp:
     _MAX_MIGRATIONS = 2
 
     async def _pump_stream(self, send, loop, deployment, replica, envelope):
-        import ray_tpu
-
+        """Relays one streamed response: start, then batch by batch what the
+        replica's pump yields, to the end, the client's leaving or the
+        replica's. The batches come from the poll this proxy shares among all
+        its streams on the replica (``_ReplicaPoll``); the start, the SSE
+        parser, the writes and their stamps, cancellation, the router's slot
+        and migration are the stream's own."""
         sid = envelope["__serve_stream__"]
         resume = envelope.get("__serve_resume__")
         parser = (
@@ -423,23 +537,17 @@ class ProxyASGIApp:
         # but a double decrement would steal a count from another stream
         # still assigned to the same replica).
         held = True
-        # The proxy's three of a chunk's stamps (replica.py::CHUNK_STAMPS), on
-        # the clock of ``t_recv_ns``: when a poll was handed to the executor
-        # rides with that poll; when its batch came back to this loop and when
-        # each chunk's send returned ride with the NEXT one, all in the id's argument.
+        # When the last batch came back to this loop and when each of its
+        # chunks' send returned (replica.py::CHUNK_STAMPS): they ride on the
+        # next poll that names this stream.
         t_got_ns, wrote_ns = 0, []
         try:
             while True:
                 try:
-                    t_asked_ns = time.monotonic_ns()
-                    batch = await loop.run_in_executor(
-                        self._pool,
-                        lambda: ray_tpu.get(
-                            actor.next_stream_chunk.remote((sid, t_asked_ns, t_got_ns, wrote_ns)),
-                            timeout=120,
-                        ),
+                    batch, t_got_ns = await self._poll_of(replica, actor).next_batch(
+                        sid, t_got_ns, wrote_ns
                     )
-                    t_got_ns, wrote_ns = time.monotonic_ns(), []
+                    wrote_ns = []
                 except Exception as e:
                     if (
                         parser is None
@@ -451,7 +559,8 @@ class ProxyASGIApp:
                     # original request to another replica with the tokens
                     # the client already received teacher-forced back in —
                     # the engine continues bit-identically from there and
-                    # re-emits nothing.
+                    # re-emits nothing. (The poll that failed fails every
+                    # stream it named; each migrates by itself.)
                     migrations += 1
                     dead.append(replica["actor_name"])
                     self._router.release(replica, deployment=deployment)
@@ -462,6 +571,7 @@ class ProxyASGIApp:
                         lambda: self._migrate_stream(deployment, resume, parser, dead),
                     )
                     held = True
+                    t_got_ns, wrote_ns = 0, []
                     continue
                 if batch is None:
                     finished = True

@@ -15,17 +15,32 @@ import queue as _queue
 import sys
 import threading
 import time
+import traceback
 
+# A streamed response leaves by POLLS, and the unit of a poll is "one proxy's
+# streams on this replica", not one stream (``Replica.next_stream_chunks``):
+# the call names every stream its proxy can take chunks for, waits until ANY
+# of them has something, sweeps each that has and returns the batches by
+# stream id. A proxy keeps one such call in flight, so the calls a second
+# follow the producers' passes and not passes x streams. One stream is the
+# one-element case (``next_stream_chunk``).
+#
 # What is stamped on every chunk of a streamed response, in the order taken
 # (``time.monotonic_ns()``, 0 = not taken): by the pump thread as the chunk's
 # bytes are about to be queued; by the proxy as it hands the poll that will
-# fetch the chunk to its executor (an argument of that poll); by
-# ``next_stream_chunk`` on its first line and as it returns the batch that
-# holds the chunk; by the proxy as the batch reaches its event loop and as the
-# chunk's ``send`` returns. The proxy's two last ride back on the stream's
-# NEXT poll, so a stream's last batch never gets them. A response that gives
+# fetch the chunk to its executor (part of that poll's argument, one stamp for
+# all the streams the poll names); by ``next_stream_chunks`` on its first line
+# (one stamp a poll: the chunks of every stream a poll carried share it, and
+# no other poll has it) and as it has swept the stream's batch, just before it
+# returns; by the proxy as the poll's reply reaches its event loop (one stamp
+# for the reply's batches) and as the chunk's ``send`` returns. The proxy's
+# two last ride back on the NEXT poll that names the stream, so a stream's
+# last batch never gets them. A response that gives
 # ``StreamingResponse.on_delivered`` is handed these, one tuple a chunk.
 CHUNK_STAMPS = ("t_yield_ns", "t_asked_ns", "t_enter_ns", "t_sweep_ns", "t_got_ns", "t_wrote_ns")
+
+# A poll that finds nothing waits this long for a chunk of any of its streams.
+_POLL_WAIT_S = 0.5
 
 
 def _annotation(name: str):
@@ -41,11 +56,14 @@ class _StreamPump:
     prefetching into a bounded queue. The replica's RPC surface only ever
     drains the queue with a short timeout, so a producer that stalls inside
     its generator cannot head-of-line-block the replica's task slots (and a
-    disconnected client's pump dies on cancel, not the 5-minute reap)."""
+    disconnected client's pump dies on cancel, not the 5-minute reap).
+    ``ready`` is the replica's condition: notified after every put, it wakes
+    the polls that wait for a chunk of any of their streams."""
 
-    def __init__(self, gen, model_id: str, on_cancel=None, on_delivered=None):
+    def __init__(self, gen, model_id: str, ready: threading.Condition, on_cancel=None, on_delivered=None):
         self.gen = gen
         self.model_id = model_id
+        self.ready = ready
         self.on_cancel = on_cancel
         self.on_delivered = on_delivered
         self.q: _queue.Queue = _queue.Queue(maxsize=8)  # backpressure bound
@@ -58,9 +76,11 @@ class _StreamPump:
         while not self.cancelled.is_set():
             try:
                 self.q.put(item, timeout=0.25)
-                return True
             except _queue.Full:
                 continue
+            with self.ready:
+                self.ready.notify_all()
+            return True
         return False
 
     def _run(self):
@@ -163,6 +183,12 @@ class Replica:
         self._lock = threading.Lock()
         self._streams: dict = {}
         self._stream_counter = 0
+        # The pumps notify it after every put; polls wait on it.
+        self._chunks_ready = threading.Condition()
+        # poller -> the newest of its polls that was told to return at once.
+        self._poll_kicks: dict = {}
+        # Polls that carried a chunk, and the chunks and streams they carried.
+        self._stream_polls = self._stream_poll_chunks = self._stream_poll_streams = 0
         self._draining = False
         self._deployment_name = deployment_name
         self._replica_id = replica_id
@@ -261,7 +287,7 @@ class Replica:
         if isinstance(result, StreamingResponse) or inspect.isgenerator(result):
             # Chunked/SSE responses (reference: serve streaming responses):
             # the generator stays alive here; the proxy pumps it via
-            # next_stream_chunk and writes chunks to the socket as produced.
+            # next_stream_chunks and writes chunks to the socket as produced.
             if isinstance(result, StreamingResponse):
                 gen, ctype = iter(result.iterator), result.content_type
                 status = getattr(result, "status", 200)
@@ -278,7 +304,8 @@ class Replica:
                 self._stream_counter += 1
                 sid = str(self._stream_counter)
                 self._streams[sid] = _StreamPump(
-                    gen, multiplexed_model_id, on_cancel=on_cancel, on_delivered=on_delivered
+                    gen, multiplexed_model_id, self._chunks_ready,
+                    on_cancel=on_cancel, on_delivered=on_delivered,
                 )
             envelope = {
                 "__serve_stream__": sid,
@@ -319,53 +346,120 @@ class Replica:
                 self._streams.pop(sid, None)
                 pump.cancel()
 
-    def next_stream_chunk(self, sid):
-        """Drain the stream's prefetch queue: block briefly for the first
-        chunk (one-item latency for time-to-first-byte), then sweep whatever
-        else is already buffered into the same response. Returns
-        {"chunks": [bytes], "done": bool} — empty chunks + done=False means
-        "nothing yet, poll again" — or None for unknown streams.
+    def next_stream_chunks(self, poll):
+        """One proxy's poll for ALL the streams it can take chunks for: wait
+        (at most ``_POLL_WAIT_S``) until ANY of them has something, then sweep
+        every one that has. What is ready leaves: no timer, no wait for a
+        pass's end, no least batch. Returns ``{sid: batch}`` with a batch
+        ``{"chunks": [bytes], "done": bool}`` for each stream that had
+        something, ``None`` for a stream this replica does not know (gone:
+        finished, cancelled or reaped), and nothing for a stream that had
+        nothing yet: poll again. A stream whose producer raised gets the
+        chunks it yielded before, and on its next poll ``{"chunks": [],
+        "done": False, "error": TaskError}``: that stream's alone, the others'
+        batches leave beside it.
 
-        ``sid`` is the stream's id, or, from a proxy that stamps its polls,
-        the id with the proxy's of ``CHUNK_STAMPS`` behind it, ``(sid,
-        t_asked_ns, t_got_ns, wrote_ns)``: when it handed THIS poll to its
-        executor, and when the batch the poll before returned reached its loop
-        and each of its chunks was written. They share the id's argument
-        because every argument of an actor call is serialized by itself, in
-        the proxy, whose polls decide the streams' gaps: as a second argument
-        they cost 32 streams 2.3 ms of their p95 gap, here 0.4 (PERF.md, PR
-        38). A caller that passes the bare id gets the same batches."""
+        ``poll`` is ONE argument, ``(poller, n, t_asked_ns, [(sid, t_got_ns,
+        wrote_ns), ...])``, because every argument of an actor call is
+        serialized by itself, in the proxy, whose polls decide the streams'
+        gaps (a second argument cost 32 streams 2.3 ms of their p95 gap:
+        PERF.md, PR 38). ``poller`` and ``n`` name the proxy and number its
+        polls, for ``wake_stream_poll``. The rest are the proxy's of
+        ``CHUNK_STAMPS``: when it handed THIS poll to its executor, and for
+        each stream when the batch its last poll returned reached the proxy's
+        loop and each of its chunks was written (0 and () where there was
+        none)."""
         t_enter_ns = time.monotonic_ns()
+        poller, n, t_asked_ns, asked = poll
+        reply: dict = {}
+        pumps: dict = {}
+        with self._lock:
+            now = time.time()
+            for sid, _, _ in asked:
+                pump = self._streams.get(sid)
+                if pump is None:
+                    reply[sid] = None
+                else:
+                    pump.last_pump = now  # the idle reaper's, for every stream a poll names
+                    pumps[sid] = pump
+        for sid, t_got_ns, wrote_ns in asked:
+            if sid in pumps:
+                pumps[sid].delivered(t_got_ns, wrote_ns)
+        if not reply:  # a stream that is gone is news already
+            deadline = time.monotonic() + _POLL_WAIT_S
+            with self._chunks_ready:
+                while not (
+                    any(pump.q.qsize() for pump in pumps.values())
+                    or (n and self._poll_kicks.get(poller, 0) >= n)
+                ):
+                    left = deadline - time.monotonic()
+                    if left <= 0:
+                        break
+                    self._chunks_ready.wait(left)
+        chunks = streams = 0
+        with _annotation("serve.stream.sweep"):
+            for sid, pump in pumps.items():
+                if not pump.q.qsize():
+                    continue
+                batch, yields = self._sweep_stream(sid, pump)
+                pump.swept(yields, t_asked_ns, t_enter_ns)
+                if batch["done"] or "error" in batch:
+                    pump.delivered()  # no next poll will bring the proxy's stamps
+                reply[sid] = batch
+                chunks += len(yields)
+                streams += bool(yields)
+        if chunks:
+            with self._lock:
+                self._stream_polls += 1
+                self._stream_poll_chunks += chunks
+                self._stream_poll_streams += streams
+        return reply
+
+    def next_stream_chunk(self, sid):
+        """The one-element case of ``next_stream_chunks``, for a caller that
+        holds one stream: block briefly for the first chunk (one-item latency
+        for time-to-first-byte), then sweep whatever else is already buffered
+        into the same response. Returns {"chunks": [bytes], "done": bool} —
+        empty chunks + done=False means "nothing yet, poll again" — or None
+        for unknown streams; raises what the stream's producer raised.
+
+        ``sid`` is the stream's id, or the id with the proxy's of
+        ``CHUNK_STAMPS`` behind it, ``(sid, t_asked_ns, t_got_ns, wrote_ns)``.
+        A caller that passes the bare id gets the same batches."""
         t_asked_ns, t_got_ns, wrote_ns = 0, 0, ()
         if isinstance(sid, tuple):
             sid, t_asked_ns, t_got_ns, wrote_ns = sid
-        with self._lock:
-            pump = self._streams.get(sid)
-            if pump is not None:
-                pump.last_pump = time.time()
-        if pump is None:
-            return None
-        pump.delivered(t_got_ns, wrote_ns)
-        with _annotation("serve.stream.sweep"):
-            batch, yields = self._sweep_stream(sid, pump)
-        pump.swept(yields, t_asked_ns, t_enter_ns)
-        if batch["done"]:
-            pump.delivered()  # no next poll will bring the proxy's stamps
+        reply = self.next_stream_chunks(("", 0, t_asked_ns, [(sid, t_got_ns, wrote_ns)]))
+        batch = reply.get(sid, {"chunks": [], "done": False})
+        if batch and "error" in batch:
+            raise batch["error"].cause
         return batch
 
+    def wake_stream_poll(self, poll):
+        """``(poller, n)``: that proxy's poll ``n`` (and any before it) returns
+        at once with what it has, also if it has not arrived yet. A proxy
+        sends it when a stream starts to wait that its poll in flight does not
+        name (a stream just opened, a slow client that caught up), so that the
+        stream is in the next poll now and not after ``_POLL_WAIT_S``."""
+        poller, n = poll
+        with self._chunks_ready:
+            if n > self._poll_kicks.get(poller, 0):
+                self._poll_kicks[poller] = n
+            self._chunks_ready.notify_all()
+        return True
+
     def _sweep_stream(self, sid: str, pump: _StreamPump):
-        """(the batch, the ``t_yield_ns`` of each of its chunks)."""
+        """What the stream's queue holds now, without waiting: (the batch,
+        the ``t_yield_ns`` of each of its chunks)."""
         chunks: list[bytes] = []
         yields: list[int] = []
         done = False
         error = None
-        block = True
         while True:
             try:
-                kind, payload, t_yield_ns = pump.q.get(timeout=0.5) if block else pump.q.get_nowait()
+                kind, payload, t_yield_ns = pump.q.get_nowait()
             except _queue.Empty:
                 break
-            block = False
             if kind == "chunk":
                 chunks.append(payload)
                 yields.append(t_yield_ns)
@@ -378,13 +472,13 @@ class Replica:
         if error is not None and chunks:
             # Deliver what the producer yielded BEFORE it raised; the error
             # surfaces on the next poll (parity with the old per-item pump).
-            pump.q.put(("error", error, 0))
+            pump._put(("error", error, 0))
             return {"chunks": chunks, "done": False}, yields
         if done or error is not None:
             with self._lock:
                 self._streams.pop(sid, None)
         if error is not None:
-            raise error
+            return {"chunks": [], "done": False, "error": _shippable(error)}, yields
         return {"chunks": chunks, "done": done}, yields
 
     def cancel_stream(self, sid: str):
@@ -400,7 +494,16 @@ class Replica:
     def get_metrics(self) -> dict:
         """Queue stats for autoscaling (reference: autoscaling_metrics.py)."""
         with self._lock:
-            return {"ongoing": self._ongoing, "total": self._total, "ts": time.time()}
+            return {
+                "ongoing": self._ongoing,
+                "total": self._total,
+                "ts": time.time(),
+                # Polls that carried a chunk, the chunks and the streams with
+                # a chunk in them: chunks / polls is what one call moved.
+                "stream_polls": self._stream_polls,
+                "stream_poll_chunks": self._stream_poll_chunks,
+                "stream_poll_streams": self._stream_poll_streams,
+            }
 
     def drain(self) -> bool:
         """Enter drain mode (controller-initiated, deliberate retirement):
@@ -504,6 +607,23 @@ class HTTPRequest:
 
     def text(self) -> str:
         return (self.body or b"").decode()
+
+
+def _shippable(error: BaseException):
+    """A producer's exception as the ``TaskError`` a raising actor call rides
+    home in, so that the stream's owner in the proxy sees what it always saw;
+    one that does not pickle goes as its ``repr``, and cannot take the other
+    streams' batches of the same reply down with it."""
+    from ray_tpu._private import serialization
+    from ray_tpu.exceptions import TaskError
+
+    text = "".join(traceback.format_exception(type(error), error, error.__traceback__))
+    err = TaskError(cause=error, remote_traceback=text, task_name="next_stream_chunks")
+    try:
+        serialization.serialize(err)
+    except Exception:
+        err = TaskError(cause=RuntimeError(repr(error)), remote_traceback=text, task_name="next_stream_chunks")
+    return err
 
 
 def _encode_chunk(item) -> bytes:

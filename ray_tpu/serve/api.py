@@ -405,7 +405,7 @@ class StreamingResponse:
         # of chunks once the proxy has written it: a list, one tuple of
         # ``_private/replica.py::CHUNK_STAMPS`` a chunk, in the order the
         # iterator yielded them (monotonic nanoseconds from the pump thread,
-        # the replica's ``next_stream_chunk`` and the proxy; the proxy's two
+        # the replica's ``next_stream_chunks`` and the proxy; the proxy's two
         # last are 0 for the stream's last batch). For a producer that keeps
         # a record of its items' way to the socket, as ``serve.llm`` does of
         # its tokens. None (the default): a chunk costs one clock read.

@@ -216,8 +216,16 @@ class LLMDeployment:
         used by tests and benches)."""
         from ray_tpu.util.device_report import device_report
 
+        ring = self.engine.spans.deliveries
         return {
             **self.engine.stats(),
+            # The polls that carried a token of this engine's, and the tokens
+            # and streams they carried (what ``on_delivered`` was handed: a new
+            # ``t_enter_ns`` is a new poll): chunks / polls is the tokens a call
+            # of the proxy's moved, 1 with a poll a stream and token.
+            "stream_polls": ring.polls,
+            "stream_poll_chunks": ring.n,
+            "stream_poll_streams": ring.batches,
             "device": device_report(),
             "spans": self.engine.spans.export(),
         }

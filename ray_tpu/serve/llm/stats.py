@@ -24,11 +24,14 @@ has the table of names and arguments.
 A token's way back (ISSUE 38) is on the same clock. The scheduler's ``llm.emit``
 span gives every token it emits its own start as ``t_emit_ns`` (one clock read
 a pass, carried with the id through the request's queue); the stream's pump
-thread, the replica's ``next_stream_chunk`` and the proxy's event loop stamp the
+thread, the replica's ``next_stream_chunks`` and the proxy's event loop stamp the
 CHUNK that carries it (``serve/_private/replica.py::CHUNK_STAMPS``; the proxy's
-two last stamps ride back on the stream's next poll), and ``LLMDeployment``
-joins the two into one ``DELIVERY_FIELDS`` record a token, in the
-``deliveries`` ring. Collector pauses, which stall every stream at once, are
+two last stamps ride back on the next poll that names the stream), and
+``LLMDeployment`` joins the two into one ``DELIVERY_FIELDS`` record a token, in
+the ``deliveries`` ring. A poll fetches the chunks of all its proxy's streams
+at once and stamps ``t_enter_ns`` once, so the records that share a
+``t_enter_ns`` are the tokens one poll carried; the ring counts them
+(``polls``, ``batches``). Collector pauses, which stall every stream at once, are
 on the record too: one ``gc.callbacks`` hook a process (``listen_for_gc``)
 keeps the generation-2 collections in a ring and the younger ones as plain
 ints. Both leave through ``get_stats()["spans"]`` only.
@@ -37,6 +40,7 @@ ints. Both leave through ``get_stats()["spans"]`` only.
 from __future__ import annotations
 
 import array
+import collections
 import gc
 import itertools
 import threading
@@ -90,6 +94,7 @@ DELIVERY_FIELDS = (
     "t_emit_ns", "t_yield_ns", "t_asked_ns", "t_enter_ns", "t_sweep_ns", "t_got_ns", "t_wrote_ns",
 )
 _D_SWEEP = DELIVERY_FIELDS.index("t_sweep_ns")
+_D_ENTER = DELIVERY_FIELDS.index("t_enter_ns")
 _D_PROXY = tuple(DELIVERY_FIELDS.index(f) for f in ("t_asked_ns", "t_got_ns", "t_wrote_ns"))
 # One record per generation-2 collection of this process.
 GC_FIELDS = ("t_start_ns", "duration_ns", "collected")
@@ -268,13 +273,23 @@ class DeliveryRing:
     export is a copy of bytes, whatever the ring holds (a list of 300,000 ints
     would cost the 1 Hz pollers tens of milliseconds to build and to pickle,
     and its allocations provoke the collections this module counts). Records
-    arrive on the replica's actor-call threads, hence the lock."""
+    arrive on the replica's actor-call threads, hence the lock.
+
+    A push is one stream's batch of one poll, and a poll stamps its
+    ``t_enter_ns`` once for all the streams it carried: ``n / polls`` is the
+    tokens a poll carried, ``batches / polls`` the streams."""
 
     WIDTH = len(DELIVERY_FIELDS)
 
     def __init__(self, size: int = DELIVERY_RING):
         self.size = size
         self.n = 0  # records ever pushed
+        self.batches = 0  # pushes that held a record
+        self.polls = 0  # distinct t_enter_ns among them
+        # The polls seen last: a poll's batches arrive as its streams' next
+        # polls do, a few polls later at the most, and several proxies' polls
+        # interleave.
+        self._polls_seen: collections.deque = collections.deque(maxlen=64)
         self._slots = array.array("q", bytes(8 * self.WIDTH * size))
         self._lock = threading.Lock()
 
@@ -291,11 +306,18 @@ class DeliveryRing:
                     rec = [0 if j in _D_PROXY else v for j, v in enumerate(rec)]
                     break
             packed.append(array.array("q", rec))
+        if not packed:
+            return
+        t_enter_ns = packed[0][_D_ENTER]
         with self._lock:
             for rec in packed:
                 at = (self.n % size) * width
                 slots[at:at + width] = rec
                 self.n += 1
+            self.batches += 1
+            if t_enter_ns not in self._polls_seen:
+                self._polls_seen.append(t_enter_ns)
+                self.polls += 1
 
     def export(self) -> bytes:
         """The records still held, oldest first, as native int64, ``WIDTH`` to

@@ -214,6 +214,89 @@ def test_midstream_kill_migrates_greedy(llm_app):
     )
 
 
+class Counting:
+    """Streams ``n`` SSE tokens 0..n-1, one every 50 ms, and resumes after the
+    tokens a client already has, as ``LLMDeployment`` does."""
+
+    def __call__(self, request):
+        import os
+
+        from ray_tpu.serve.api import StreamingResponse
+
+        body = request if isinstance(request, dict) else request.json()
+        if body.get("pid"):
+            return os.getpid()
+        have = len(body.get("resume_tokens") or ())
+
+        def sse():
+            for tok in range(have, int(body["n"])):
+                time.sleep(0.05)
+                yield f"data: {json.dumps({'token': tok})}\n\n"
+            yield "data: [DONE]\n\n"
+
+        return StreamingResponse(
+            sse(), content_type="text/event-stream",
+            resume={"kind": "sse_tokens", "body": {"n": body["n"]}},
+        )
+
+
+def test_a_replica_killed_under_a_shared_poll_migrates_every_stream_it_carried(ft_cluster):
+    """The proxy polls its streams on a replica in ONE call: the call that
+    fails with the replica gone fails each of them, and each migrates by
+    itself, within ``_MAX_MIGRATIONS`` as ever, re-emitting and dropping
+    nothing."""
+    import os
+    import signal
+    import zlib
+
+    from ray_tpu.serve._private.asgi import ProxyASGIApp
+    from ray_tpu.serve._private.common import PREFIX_HINT_HEADER
+
+    assert ProxyASGIApp._MAX_MIGRATIONS == 2
+    serve.run(serve.deployment(num_replicas=2)(Counting).bind(), route_prefix="/count")
+    host, port = serve.http_address()
+    url = f"http://{host}:{port}/count"
+    controller = ray_tpu.get_actor(CONTROLLER_NAME)
+    deadline = time.monotonic() + 120
+    while time.monotonic() < deadline:
+        table = ray_tpu.get(controller.get_routing_table.remote(-2, 0.1))["table"]
+        actors = [r["actor_name"] for r in (table.get("Counting") or {}).get("replicas", [])]
+        if len(actors) == 2:
+            break
+        time.sleep(0.25)
+    assert len(actors) == 2, actors
+    hint = "one-replica-for-all"
+    victim = actors[zlib.crc32(hint.encode()) % len(actors)]
+    pid = ray_tpu.get(
+        ray_tpu.get_actor(victim).handle_request.remote("__call__", ({"pid": 1},), {}), timeout=60
+    )
+    t_wall0 = time.time()
+    n, streams = 60, 4
+    toks = [[] for _ in range(streams)]
+    done = [False] * streams
+
+    def read(i):
+        req = urllib.request.Request(
+            url, data=json.dumps({"n": n}).encode(), headers={PREFIX_HINT_HEADER: hint}
+        )
+        done[i] = _stream_sse_resp(urllib.request.urlopen(req, timeout=240), toks[i], [])
+
+    threads = [threading.Thread(target=read, args=(i,), daemon=True) for i in range(streams)]
+    for t in threads:
+        t.start()
+    deadline = time.monotonic() + 60
+    while min(len(t) for t in toks) < 5 and time.monotonic() < deadline:
+        time.sleep(0.01)
+    assert min(len(t) for t in toks) >= 5, toks  # all four stream from the victim, in one poll
+    os.kill(pid, signal.SIGKILL)
+    for t in threads:
+        t.join(timeout=240)
+    assert all(done), done
+    assert toks == [list(range(n))] * streams
+    assert len(_flight_events(ft_cluster, "llm_migrate", t_wall0)) >= streams
+    serve.delete("Counting")
+
+
 @pytest.mark.slow
 def test_midstream_kill_migrates_seeded_sampling(llm_app):
     """Sampled arm: the counter-based per-request RNG stream makes the

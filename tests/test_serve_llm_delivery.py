@@ -80,7 +80,8 @@ def served():
             t.join(timeout=180)
         deadline = time.monotonic() + 30
         while True:  # a stream's last batch is recorded as its last poll returns, a moment after the client's read
-            spans = ray_tpu.get(handle.get_stats.remote(), timeout=60)["spans"]
+            counters = ray_tpu.get(handle.get_stats.remote(), timeout=60)
+            spans = counters["spans"]
             if len(_rows(spans)) >= sum(WANT) or time.monotonic() > deadline:
                 break
             time.sleep(0.05)
@@ -94,7 +95,10 @@ def served():
         by_stream[int(rec["request_id"].rpartition("-")[2])] = sorted(
             (r for r in _rows(spans) if r[D["rid"]] == number), key=lambda r: r[D["index"]]
         )
-    return {"tokens": got, "spans": spans, "requests": requests, "by_stream": by_stream, "rows": _rows(spans)}
+    return {
+        "tokens": got, "spans": spans, "requests": requests, "by_stream": by_stream, "rows": _rows(spans),
+        "counters": {k: v for k, v in counters.items() if isinstance(v, int)},
+    }
 
 
 def test_every_streamed_token_has_a_record_and_its_index_runs_from_zero(served):
@@ -117,6 +121,37 @@ def test_the_stamps_are_in_order_wherever_taken(served, chain):
         taken = [r[D[name]] for name in chain if r[D[name]]]
         assert taken == sorted(taken), (chain, r)
         assert all(r[D[name]] > 0 for name in ("t_emit_ns", "t_yield_ns", "t_asked_ns", "t_enter_ns", "t_sweep_ns")), r
+
+
+def test_the_tokens_of_several_streams_leave_in_one_poll(served):
+    """A poll is the proxy's for ALL its streams on the replica: the records
+    that share a ``t_enter_ns`` are what it carried, and they share what is
+    stamped once a poll (the ask) and once a reply (its arrival at the loop)."""
+    by_poll: dict = {}
+    for r in served["rows"]:
+        by_poll.setdefault(r[D["t_enter_ns"]], []).append(r)
+    assert max(len({r[D["rid"]] for r in recs}) for recs in by_poll.values()) > 1
+    assert len(by_poll) < len(served["rows"])
+    for recs in by_poll.values():
+        assert len({r[D["t_asked_ns"]] for r in recs}) == 1
+        assert len({r[D["t_got_ns"]] for r in recs if r[D["t_got_ns"]]}) <= 1
+        for rid in {r[D["rid"]] for r in recs}:  # a stream's batch is swept at once, its chunks written in order
+            mine = [r for r in recs if r[D["rid"]] == rid]
+            assert len({r[D["t_sweep_ns"]] for r in mine}) == 1
+            assert [r[D["index"]] for r in mine] == list(range(mine[0][D["index"]], mine[0][D["index"]] + len(mine)))
+            wrote = [r[D["t_wrote_ns"]] for r in mine]
+            assert wrote == sorted(wrote)
+
+
+def test_the_engine_s_counters_of_the_polls_are_the_records_by_their_t_enter_ns(served):
+    """``get_stats()``'s three plain ints (the harness logs a run's integer
+    counters): the polls that carried a token, the tokens, and the streams'
+    batches; tokens over polls is what one call of the proxy's moved."""
+    rows, counters = served["rows"], served["counters"]
+    assert counters["stream_poll_chunks"] == sum(WANT) == len(rows)
+    assert counters["stream_polls"] == len({r[D["t_enter_ns"]] for r in rows})
+    assert counters["stream_poll_streams"] == len({(r[D["rid"]], r[D["t_enter_ns"]]) for r in rows})
+    assert counters["stream_polls"] < counters["stream_poll_streams"] <= counters["stream_poll_chunks"]
 
 
 def _only_the_last_batch_lacks(recs):
@@ -329,6 +364,19 @@ def test_the_ring_wraps_at_its_size(size):
     assert len(flat) == size * len(D) and ring.n == 2 * size + 5
     assert list(flat[D["index"]::len(D)]) == list(range(size + 5, 2 * size + 5))  # the newest, oldest first
     assert tuple(flat[-len(D):]) == rec(2 * size + 4)
+
+
+def test_the_ring_counts_a_poll_once_however_many_streams_and_however_late_their_batches():
+    ring = stats.DeliveryRing(64)
+    now = time.monotonic_ns()
+    rec = lambda rid, i, enter: (rid, i, now, now, now, enter, now, now, now)  # noqa: E731
+    assert len(rec(0, 0, 0)) == len(D) and D["t_enter_ns"] == 5
+    ring.push([rec(1, 0, now + 1), rec(1, 1, now + 1)])  # poll 1: two tokens of stream 1
+    ring.push([rec(2, 0, now + 1)])  # and one of stream 2
+    ring.push([])  # a batch without a token of the engine's is no batch
+    ring.push([rec(2, 1, now + 2)])  # poll 2
+    ring.push([rec(3, 0, now + 1)])  # poll 1's third stream, handed over after poll 2's
+    assert (ring.polls, ring.batches, ring.n) == (2, 4, 5)
 
 
 @pytest.mark.parametrize("generation", [2, 0])
