@@ -264,6 +264,14 @@ def instruments() -> dict:
                 "Admissions that made a slot's recurrent state start from zero "
                 "(linear-attention layers; re-admissions after a preemption too).",
             ),
+            "serve_llm_moe_assignments": m.Counter(
+                "ray_tpu_serve_llm_moe_assignments_total",
+                "Assignments of tokens to routed experts, as last read from the "
+                "device's counters (one flush behind), by held: true (to experts "
+                "the program holds) and false (to the others' of an expert-parallel "
+                "deployment, which this program computes nothing of).",
+                tag_keys=("held",),
+            ),
             "serve_llm_state_slots": m.Gauge(
                 "ray_tpu_serve_llm_state_slots_in_use",
                 "Slots whose recurrent state (linear-attention layers) belongs "
@@ -688,6 +696,8 @@ def _collect_serve_llm_stats():
         ("chunk_tokens_valid", inst["serve_llm_chunk_tokens"], {"kind": "valid"}),
         ("chunk_tokens_padded", inst["serve_llm_chunk_tokens"], {"kind": "padded"}),
         ("state_resets", inst["serve_llm_state_resets"], None),
+        ("moe_assignments_held", inst["serve_llm_moe_assignments"], {"held": "true"}),
+        ("moe_assignments_elsewhere", inst["serve_llm_moe_assignments"], {"held": "false"}),
     ])
     engines = list(ENGINES)
     if not engines and not LLM.admitted:
@@ -698,6 +708,7 @@ def _collect_serve_llm_stats():
     # exported gauges — honestly drop to zero instead of going stale.
     running = waiting = used = total = state_slots = state_bytes = 0
     for eng in engines:
+        eng.refresh_moe_counts()  # read at the scheduler's next pass, folded above at the next flush
         running += sum(r is not None for r in eng._slots)
         if eng.state_slot_bytes:
             state_slots += sum(r is not None for r in eng._slots)

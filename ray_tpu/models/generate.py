@@ -45,13 +45,16 @@ import numpy as np
 from jax import lax
 
 from ray_tpu.models.transformer import (
+    EXPERT_LAYERS,
     LINEAR_LAYERS,
+    MAMBA_LAYERS,
     TransformerConfig,
     _logits,
     _period,
     _rms_norm,
     _rope,
 )
+from ray_tpu.parallel.moe import grouped_matmul_tiles
 
 
 _LANES = 128
@@ -98,7 +101,13 @@ def _cache_rows(cfg: TransformerConfig) -> dict:
     return {"k": (_cache_heads(cfg), cfg.head_dim), "v": (_cache_heads(cfg), cfg.head_dim)}
 
 
-_WINDOW, _FULL, _LINEAR = "window", "full", "linear"
+_WINDOW, _FULL, _LINEAR, _MAMBA, _EXPERTS = "window", "full", "linear", "mamba", "experts"
+
+
+def state_kind(cfg: TransformerConfig):
+    """The kind of layer whose recurrent state a serving slot keeps
+    (``state_rows``): ``"linear"``, ``"mamba"``, or None without one."""
+    return next((kind for kind in (_LINEAR, _MAMBA) if kind in cfg.layer_kinds), None)
 
 
 def _group_suffix(kind) -> str:
@@ -113,8 +122,9 @@ def _cache_groups(cfg: TransformerConfig) -> dict:
     layers apart, each indexed by a layer's rank among its kind."""
     if not cfg.layer_kinds:
         return {None: cfg.n_layers}
-    # Beside linear layers (which hold no token's rows: ``state_rows``) only full layers run.
-    kinds = (_FULL,) if _LINEAR in cfg.layer_kinds else (_FULL, _WINDOW)
+    # Beside layers that keep a state (which hold no token's rows: ``state_rows``) and among
+    # single-mixer blocks only full layers hold any.
+    kinds = (_FULL,) if state_kind(cfg) or cfg.single_mixer else (_FULL, _WINDOW)
     return {kind: cfg.layer_kinds.count(kind) for kind in kinds}
 
 
@@ -123,7 +133,15 @@ def state_rows(cfg: TransformerConfig) -> dict:
     length, leaf name -> (trailing shape, dtype): the gated delta rule's state a
     head in float32 (it is summed into for a whole context), and the last
     ``linear_conv - 1`` rows of the query / key / value projection, which the
-    next token's convolution reads. Empty without linear layers."""
+    next token's convolution reads. A Mamba-2 block's: the state [heads, head
+    channels, ssm_state] in float32 and the last ``mamba_conv - 1`` rows of the
+    convolution's channels (x, B and C ahead of it). Empty without such layers."""
+    if _MAMBA in cfg.layer_kinds:
+        Hm, P, N = cfg.mamba_heads, cfg.mamba_head_dim, cfg.ssm_state
+        return {
+            "state": ((Hm, P, N), jnp.float32),
+            "conv": ((cfg.mamba_conv - 1, Hm * P + 2 * cfg.ssm_groups * N), cfg.dtype),
+        }
     if _LINEAR not in cfg.layer_kinds:
         return {}
     Hl, dk, dv = cfg.linear_heads, cfg.linear_key_dim, cfg.linear_value_dim
@@ -137,7 +155,7 @@ def state_slot_bytes(cfg: TransformerConfig) -> int:
     """Bytes one slot holds in the linear layers' group of cache leaves, all
     its layers: beside ``cache_token_bytes``, which grows with a row, this does not."""
     row = sum(math.prod(shape) * jnp.dtype(dtype).itemsize for shape, dtype in state_rows(cfg).values())
-    return cfg.layer_kinds.count(_LINEAR) * row
+    return cfg.layer_kinds.count(state_kind(cfg)) * row
 
 
 def cache_token_bytes(cfg: TransformerConfig) -> dict:
@@ -156,11 +174,17 @@ def init_cache(cfg: TransformerConfig, batch: int, max_len: int):
     layers are refused: their state cannot be rewound to a position, which
     ``speculative_generate`` and ``decode_chunk``'s callers count on, and they
     are served through the paged cache only (``init_paged_cache``)."""
-    if _LINEAR in cfg.layer_kinds:
+    if state_kind(cfg):
+        what = {_LINEAR: "linear-attention layers", _MAMBA: "Mamba-2 state-space blocks"}[state_kind(cfg)]
         raise NotImplementedError(
             "a dense cache (init_cache: prefill / decode_step / generate / speculative_generate) cannot hold "
-            "linear-attention layers (layer_kinds has 'linear'): their recurrent state is kept a slot of the "
+            f"{what} (layer_kinds has {state_kind(cfg)!r}): their recurrent state is kept a slot of the "
             "paged cache (init_paged_cache, serve/llm/engine.py)"
+        )
+    if cfg.single_mixer:
+        raise NotImplementedError(
+            "a dense cache (init_cache) cannot hold single-mixer blocks (layer_kinds has 'experts'): "
+            "they run over the paged cache only (init_paged_cache, serve/llm/engine.py)"
         )
     return {
         name + _group_suffix(kind): jnp.zeros((layers, batch, max_len, *row), cfg.dtype)
@@ -248,6 +272,11 @@ def _swiglu(h, wg, wi, wo):
     return (jax.nn.silu(h @ wg.astype(h.dtype)) * (h @ wi.astype(h.dtype))) @ wo.astype(h.dtype)
 
 
+def _relu2(h, wi, wo):
+    """An expert without a gate matrix: ``relu(h W_up)^2 W_down``."""
+    return jnp.square(jax.nn.relu(h @ wi.astype(h.dtype))) @ wo.astype(h.dtype)
+
+
 # The leaves of a routed-expert stack that the layer scan does not slice.
 _EXPERT_STACKS = ("wg_e", "wi_e", "wo_e")
 
@@ -273,11 +302,13 @@ def _mlp(lp, x, cfg, valid=None, layer=None):
         B, q, D = h.shape
         out, sent, chosen = routed_experts(
             lp, h.reshape(B * q, D), k=cfg.experts_per_token, scale=cfg.routed_scaling_factor,
-            valid=None if valid is None else valid.reshape(B * q), layer=layer,
+            valid=None if valid is None else valid.reshape(B * q), layer=layer, share=cfg.expert_share,
         )
         out = out.reshape(B, q, D)
         if "wg_s" in lp:
             out = out + _swiglu(h, lp["wg_s"], lp["wi_s"], lp["wo_s"])
+        elif "wi_s" in lp:
+            out = out + _relu2(h, lp["wi_s"], lp["wo_s"])
         return add(out), sent, chosen.reshape(B, q, -1)
     from ray_tpu.models.transformer import _moe_mlp
 
@@ -371,7 +402,10 @@ def _cache_mask(positions, n_keys: int, window: int, key_len=None, key_pos=None)
 # serving engine reads them when asked and not a step. Axis 0: calls that fed
 # one token a row (decode steps), then all others (prefill chunks). Columns
 # 0..E-1: tokens sent to each expert; E: experts touched; E + 1: tokens on the
-# fullest expert; E + 2: calls that routed any token.
+# fullest expert; E + 2: calls that routed any token. Where the program holds a
+# share of the experts (``cfg.expert_share``) E is the experts HELD, the columns
+# count assignments to them, and one more column, E + 3, counts every
+# assignment the router made, held or not.
 MOE_COUNTS = "moe_counts"
 # And this one: int32 [expert layers, B, S] or [expert layers, num_blocks,
 # block_size], beside each cached token's row the experts that token took in
@@ -393,8 +427,13 @@ def _pool_leaves(cache: dict) -> dict:
     return {name: leaf for name, leaf in cache.items() if name not in (MOE_COUNTS, MOE_CHOICE)}
 
 
+def expert_layers(cfg: TransformerConfig) -> int:
+    """Layers (or single-mixer blocks) with routed experts."""
+    return cfg.layer_kinds.count(_EXPERTS) if cfg.single_mixer else cfg.n_layers - cfg.first_dense_layers
+
+
 def init_moe_counts(cfg: TransformerConfig):
-    return jnp.zeros((2, cfg.n_layers - cfg.first_dense_layers, cfg.num_experts + 3), jnp.int32)
+    return jnp.zeros((2, expert_layers(cfg), cfg.held_experts + 3 + (cfg.expert_share[1] > 1)), jnp.int32)
 
 
 def _expert_bits(cfg: TransformerConfig) -> int:
@@ -412,7 +451,7 @@ def init_moe_choice(cfg: TransformerConfig, *rows: int):
     block_size) beside ``init_paged_cache``."""
     words, _ = _choice_words(cfg)
     lead = (words,) if words > 1 else ()
-    return jnp.zeros((*lead, cfg.n_layers - cfg.first_dense_layers, *rows), jnp.int32)
+    return jnp.zeros((*lead, expert_layers(cfg), *rows), jnp.int32)
 
 
 def unpack_experts(words, cfg: TransformerConfig):
@@ -463,6 +502,76 @@ class _StateAccess(NamedTuple):
     n_valid: Any
 
 
+def _slot_rows(pool, name, at, acc: _StateAccess, B):
+    """Leaf ``name`` of the state group, layer ``at``, the call's rows' slots:
+    zeros where a row's state starts afresh."""
+    leaf = pool[name]
+    rows = lax.dynamic_index_in_dim(leaf, at, 0, keepdims=False) if acc.slots is None else leaf[at, acc.slots]
+    return jnp.where(acc.fresh.reshape(B, *[1] * (rows.ndim - 1)), jnp.zeros_like(rows), rows)
+
+
+def _put_slot_rows(pool, name, at, acc: _StateAccess, rows):
+    leaf = pool[name]
+    if acc.slots is None:
+        return lax.dynamic_update_index_in_dim(leaf, rows.astype(leaf.dtype), at, 0)
+    return leaf.at[at, acc.slots].set(rows.astype(leaf.dtype))
+
+
+def _rows_to_carry(seen, n_valid, K):
+    """Of ``seen`` [B, K - 1 + q, C] (the rows a convolution over K tokens was
+    carried, then the call's own) the K - 1 before each row's first token that
+    is not real: the chunk's last real ones, or (none real) the rows carried in."""
+    return jax.vmap(lambda rows, n: lax.dynamic_slice_in_dim(rows, n, K - 1, axis=0))(seen, n_valid)
+
+
+def _mamba_mixer(lp, x, pool, at, acc: _StateAccess, cfg):
+    """A Mamba-2 block's mixer over layer ``at`` of the state group: x [B, q, D]
+    -> (the gated, normed heads [B, q, heads * head channels] that ``wo`` takes,
+    the pool with the rows' state and carried convolution rows moved on).
+
+    ``z = h w_z``, ``u = h w_xbc``, ``dt = softplus(h w_dt + dt_bias)`` of the
+    normed input h; each column of u through a causal convolution over the
+    last ``mamba_conv`` tokens (those before the chunk are the slot's carried
+    rows, zeros ahead of a request's first token), its bias and SiLU; ``[x | B
+    | C] = u`` (heads * channels, groups * state, groups * state); the
+    recurrence with ``A = -exp(A_log)`` and the skip ``D`` (ops/ssm.py: the
+    chunked form for q > 1, the step for q == 1); ``y * silu(z)``, then RMSNorm
+    over each GROUP's channels with a learned weight. From the convolution on
+    everything is float32."""
+    from ray_tpu.ops.ssm import mamba2_chunk, mamba2_step
+
+    B, q, _ = x.shape
+    Hm, P, N, G, K = cfg.mamba_heads, cfg.mamba_head_dim, cfg.ssm_state, cfg.ssm_groups, cfg.mamba_conv
+    inner = Hm * P
+    f32 = jnp.float32
+    h = _rms_norm(x, lp["attn_norm"], cfg.norm_eps)
+    z = h @ lp["w_z"].astype(h.dtype)
+    u = h @ lp["w_xbc"].astype(h.dtype)
+    # Rounded to its dtype HERE, whatever the compiler fuses the product into: the convolution must read of a
+    # token that is the call's own what it will read of it as a carried row of the slot, in the next call.
+    u = lax.reduce_precision(u, jnp.finfo(u.dtype).nexp, jnp.finfo(u.dtype).nmant)
+    dt = jnp.einsum("bqd,dh->bqh", h, lp["w_dt"].astype(h.dtype), preferred_element_type=f32)
+    dt = jax.nn.softplus(dt + lp["dt_bias"].astype(f32))
+    A = -jnp.exp(lp["A_log"].astype(f32))
+    with jax.named_scope("ssm_step" if q == 1 else "ssm_scan"):
+        seen = jnp.concatenate([_slot_rows(pool, "conv", at, acc, B), u], axis=1)  # [B, K - 1 + q, C]: row j + K - 1 is token j
+        taps = lp["conv_w"].astype(f32)
+        y = jax.nn.silu(sum(seen[:, i : i + q].astype(f32) * taps[i] for i in range(K)) + lp["conv_b"].astype(f32))
+        xs, Bm, Cm = jnp.split(y, [inner, inner + G * N], axis=-1)
+        xs, Bm, Cm = xs.reshape(B, q, Hm, P), Bm.reshape(B, q, G, N), Cm.reshape(B, q, G, N)
+        S = _slot_rows(pool, "state", at, acc, B)
+        if q == 1:
+            o, S = mamba2_step(xs[:, 0], dt[:, 0], A, Bm[:, 0], Cm[:, 0], lp["D"], S, acc.n_valid > 0)
+            o = o[:, None]
+        else:
+            o, S = mamba2_chunk(xs, dt, A, Bm, Cm, lp["D"], S, acc.n_valid)
+        tail = _rows_to_carry(seen, acc.n_valid, K)
+        pool = {**pool, "state": _put_slot_rows(pool, "state", at, acc, S), "conv": _put_slot_rows(pool, "conv", at, acc, tail)}
+        gated = (o.reshape(B, q, inner) * jax.nn.silu(z.astype(f32))).reshape(B, q, G, inner // G)
+        gated = gated * lax.rsqrt(jnp.mean(jnp.square(gated), axis=-1, keepdims=True) + cfg.norm_eps)
+    return (gated.reshape(B, q, inner) * lp["ssm_norm"].astype(f32)).astype(x.dtype), pool
+
+
 def _linear_mixer(lp, x, pool, at, acc: _StateAccess, cfg):
     """A linear-attention layer's mixer over layer ``at`` of the state group:
     x [B, q, D] -> (the gated, normed heads [B, q, Hl * dv] that ``wo`` takes,
@@ -482,16 +591,8 @@ def _linear_mixer(lp, x, pool, at, acc: _StateAccess, cfg):
     Hl, dk, dv, K = cfg.linear_heads, cfg.linear_key_dim, cfg.linear_value_dim, cfg.linear_conv
     h = _rms_norm(x, lp["attn_norm"], cfg.norm_eps) if cfg.pre_norms else x
 
-    def held(name):
-        leaf = pool[name]
-        rows = lax.dynamic_index_in_dim(leaf, at, 0, keepdims=False) if acc.slots is None else leaf[at, acc.slots]
-        return jnp.where(acc.fresh.reshape(B, *[1] * (rows.ndim - 1)), jnp.zeros_like(rows), rows)
-
-    def put(name, rows):
-        leaf = pool[name]
-        if acc.slots is None:
-            return lax.dynamic_update_index_in_dim(leaf, rows.astype(leaf.dtype), at, 0)
-        return leaf.at[at, acc.slots].set(rows.astype(leaf.dtype))
+    held = partial(_slot_rows, pool, at=at, acc=acc, B=B)
+    put = partial(_put_slot_rows, pool, at=at, acc=acc)
 
     u = h @ lp["w_qkv"].astype(h.dtype)
     f32 = jnp.float32
@@ -513,10 +614,8 @@ def _linear_mixer(lp, x, pool, at, acc: _StateAccess, cfg):
             o = o[:, None]
         else:
             o, S = gated_delta_chunk(qh, kh, vh, g, beta, held("state"), acc.n_valid)
-        # The K - 1 rows before the first token that is not real: the chunk's
-        # last real ones, or (none real) the rows carried in.
-        tail = jax.vmap(lambda rows, n: lax.dynamic_slice_in_dim(rows, n, K - 1, axis=0))(seen, acc.n_valid)
-        pool = {**pool, "state": put("state", S), "conv": put("conv", tail)}
+        tail = _rows_to_carry(seen, acc.n_valid, K)
+        pool = {**pool, "state": put("state", rows=S), "conv": put("conv", rows=tail)}
     o = _rms_norm(o, lp["o_norm"], cfg.norm_eps).astype(x.dtype).reshape(B, q, Hl * dv)
     return o * jax.nn.silu(h @ lp["wg_lin"].astype(h.dtype)), pool
 
@@ -574,11 +673,36 @@ def _cached_layers(params, x, cache, positions, access, cfg, key_len=None, valid
                 out.append(o.reshape(1, -1, o.shape[2] * o.shape[3]))
         return jnp.concatenate(out, axis=1), pool
 
+    def record_choice(pool, chosen, kind, l, first):
+        """The pool with the experts ``chosen`` [B, q, k] beside the rows' tokens
+        in expert layer ``l - first`` of ``MOE_CHOICE``, where the pool keeps them."""
+        if chosen is None or MOE_CHOICE not in pool:
+            return pool
+        put = (access[_FULL] if kind else access).write
+        if n_words == 1:
+            words = jnp.sum(chosen << (_expert_bits(cfg) * jnp.arange(chosen.shape[-1])), axis=-1)
+            return {**pool, MOE_CHOICE: put(pool[MOE_CHOICE], l - first, words)}
+        # [words, expert layers, ...] written as [words * expert layers, ...]
+        leaf = pool[MOE_CHOICE]
+        flat = leaf.reshape(-1, *leaf.shape[2:])
+        shifts = _expert_bits(cfg) * jnp.arange(per_word)
+        for w in range(n_words):
+            ids = chosen[..., w * per_word : (w + 1) * per_word]
+            word = jnp.sum(ids << shifts[: ids.shape[-1]], axis=-1)
+            flat = put(flat, w * leaf.shape[1] + (l - first), word)
+        return {**pool, MOE_CHOICE: flat.reshape(leaf.shape)}
+
     def run_layer(x, pool, lp, kind, at, l, first, held):
         """One layer of kind ``kind`` (None: no pattern), layer ``at`` of its
         group, layer ``l - first`` of its stack."""
-        acc = access[kind] if kind else access
+        acc = access.get(kind) if kind else access
         sfx = _group_suffix(kind)
+        if kind == _MAMBA:  # a block that is this mixer and nothing else
+            o, pool = _mamba_mixer(lp, x, pool, at, acc, cfg)
+            return x + o @ lp["wo"].astype(o.dtype), pool, None
+        if kind == _EXPERTS:  # a block that is its experts and nothing else
+            x, sent, chosen = _mlp(lp, x, cfg, valid, layer=l - first if held else None)
+            return x, record_choice(pool, chosen, kind, l, first), sent
         if kind == _LINEAR:
             o, pool = _linear_mixer(lp, x, pool, at, acc, cfg)
             a = o @ lp["wo"].astype(o.dtype)
@@ -604,22 +728,10 @@ def _cached_layers(params, x, cache, positions, access, cfg, key_len=None, valid
             o = o * jax.nn.sigmoid(gate)
         a = o @ lp["wo"].astype(o.dtype)
         x = x + (_rms_norm(a, lp["attn_post_norm"], cfg.norm_eps) if cfg.post_norms else a)
+        if cfg.single_mixer:  # an attention block: no MLP behind it
+            return x, pool, None
         x, sent, chosen = _mlp(lp, x, cfg, valid, layer=l - first if held else None)
-        if chosen is not None and MOE_CHOICE in pool:
-            put = (access[_FULL] if kind else access).write
-            if n_words == 1:
-                words = jnp.sum(chosen << (_expert_bits(cfg) * jnp.arange(chosen.shape[-1])), axis=-1)
-                pool = {**pool, MOE_CHOICE: put(pool[MOE_CHOICE], l - first, words)}
-            else:  # [words, expert layers, ...] written as [words * expert layers, ...]
-                leaf = pool[MOE_CHOICE]
-                flat = leaf.reshape(-1, *leaf.shape[2:])
-                shifts = _expert_bits(cfg) * jnp.arange(per_word)
-                for w in range(n_words):
-                    ids = chosen[..., w * per_word : (w + 1) * per_word]
-                    word = jnp.sum(ids << shifts[: ids.shape[-1]], axis=-1)
-                    flat = put(flat, w * leaf.shape[1] + (l - first), word)
-                pool = {**pool, MOE_CHOICE: flat.reshape(leaf.shape)}
-        return x, pool, sent
+        return x, record_choice(pool, chosen, kind, l, first), sent
 
     def body(first, held, carry, layer):
         x, pool = carry
@@ -680,13 +792,12 @@ def _cached_layers(params, x, cache, positions, access, cfg, key_len=None, valid
 
                     carry, s = lax.scan(one, tuple(carry), jnp.arange(n, dtype=jnp.int32))
                 sent.append(s)
-            if sent[0] is None:
-                return tuple(carry), None
-            return tuple(carry), jnp.concatenate([s if n > 1 else jnp.expand_dims(s, 0) for s, (_, n) in zip(sent, runs)])
+            sent = [s if n > 1 else jnp.expand_dims(s, 0) for s, (_, n) in zip(sent, runs) if s is not None]
+            return tuple(carry), jnp.concatenate(sent) if sent else None  # of the period's layers with experts, in order
 
         whole = len(kinds) // P
         (x, pool), sent = lax.scan(one_period, (x, pool), jnp.arange(whole, dtype=jnp.int32))
-        sent = [] if sent is None else [sent.reshape(whole * P, -1)]
+        sent = [] if sent is None else [sent.reshape(-1, sent.shape[-1])]
         for j in range(len(kinds) % P):
             x, pool, s = layer_at(x, pool, whole, j)
             if s is not None:
@@ -699,8 +810,17 @@ def _cached_layers(params, x, cache, positions, access, cfg, key_len=None, valid
         return _rms_norm(x, params["norm_f"], cfg.norm_eps), pool
 
     first, sent = 0, None
+    if cfg.single_mixer:  # three stacks by kind; the experts' matrices whole, as below, where their matmuls run grouped
+        experts = params.get(EXPERT_LAYERS, {})
+        held = {n: experts[n] for n in _EXPERT_STACKS if n in experts and grouped_matmul_tiles(cfg.d_model, cfg.d_expert)}
+        stacks = {
+            _MAMBA: params.get(MAMBA_LAYERS), _FULL: params.get("layers"),
+            _EXPERTS: {n: leaf for n, leaf in experts.items() if n not in held},
+        }
+        stacks = {kind: stack for kind, stack in stacks.items() if kind in cfg.layer_kinds}
+        x, pool, sent = scan_periods(0, held, stacks, cfg.layer_kinds, x, pool)
     for name in ("dense_layers", "layers"):
-        if name in params:
+        if name in params and not cfg.single_mixer:
             stack = params[name]
             depth = stack["attn_norm"].shape[0]
             # Routed experts' matrices stay whole, outside the scanned leaves:
@@ -719,9 +839,13 @@ def _cached_layers(params, x, cache, positions, access, cfg, key_len=None, valid
             first += depth
     if counts is not None:  # sent [expert layers, E]: the routed stack's, which runs last
         touched = jnp.sum(sent > 0, axis=-1, keepdims=True)
-        step = jnp.concatenate(
-            [sent, touched, jnp.max(sent, axis=-1, keepdims=True), jnp.minimum(touched, 1)], axis=-1
-        )
+        if cfg.expert_share[1] > 1:  # E the experts held; a call counts by what it routed, held or not, and says how much
+            routed = (B * q if valid is None else jnp.sum(valid)) * cfg.experts_per_token
+            routed = jnp.broadcast_to(routed, touched.shape).astype(sent.dtype)
+            columns = [sent, touched, jnp.max(sent, axis=-1, keepdims=True), jnp.minimum(routed, 1), routed]
+        else:
+            columns = [sent, touched, jnp.max(sent, axis=-1, keepdims=True), jnp.minimum(touched, 1)]
+        step = jnp.concatenate(columns, axis=-1)
         pool[MOE_COUNTS] = counts.at[0 if q == 1 else 1].add(step.astype(counts.dtype))
     return _rms_norm(x, params["norm_f"], cfg.norm_eps), pool
 
@@ -872,7 +996,7 @@ def init_paged_cache(
         for name, row in _cache_rows(cfg).items()
     }
     for name, (shape, dtype) in state_rows(cfg).items():
-        pool[name] = jnp.zeros((cfg.layer_kinds.count(_LINEAR), state_slots, *shape), dtype)
+        pool[name] = jnp.zeros((cfg.layer_kinds.count(state_kind(cfg)), state_slots, *shape), dtype)
     return pool
 
 
@@ -952,12 +1076,14 @@ def paged_decode_chunk_hidden(
     x, positions = _embed_chunk(params, tokens, pos, cfg)
     block_size = cache["k" if "k" in cache else "ckv"].shape[2]
     access = _Access(_paged_write(block_tables, positions, valid_to, block_size), _paged_view(block_tables))
-    if _LINEAR in cfg.layer_kinds:
+    if state_kind(cfg):
         B, q = positions.shape
         real = q if valid_to is None else jnp.clip(jnp.asarray(valid_to, jnp.int32) - pos, 0, q)
         live = block_tables[:, 0] != 0
         fresh = jnp.zeros((B,), bool) if state_fresh is None else jnp.asarray(state_fresh, bool)
-        access = {_FULL: access, _LINEAR: _StateAccess(state_slots, fresh, jnp.where(live, real, 0).astype(jnp.int32))}
+        access = {_FULL: access, state_kind(cfg): _StateAccess(state_slots, fresh, jnp.where(live, real, 0).astype(jnp.int32))}
+    elif cfg.single_mixer:  # attention and experts blocks only
+        access = {_FULL: access}
     elif cfg.layer_kinds:
         ring_tables = jnp.asarray(ring_tables, jnp.int32)
         n_view = min(ring_tables.shape[1], block_tables.shape[1])

@@ -120,6 +120,31 @@ class TransformerConfig:
     linear_value_dim: int = 0
     linear_conv: int = 4
     linear_neg_eigval: bool = False
+    # Blocks of ONE mixer (inference only; the ``nemotron_h`` family): under the
+    # kinds ``"mamba"`` and ``"experts"`` of ``layer_kinds`` every block is ``x +
+    # mixer(RMSNorm(x))``: a ``"mamba"`` block a Mamba-2 state-space mixer
+    # (ops/ssm.py) of ``mamba_heads`` heads of ``mamba_head_dim``, a state
+    # ``ssm_state`` wide a head channel, B and C shared by the heads of each of
+    # ``ssm_groups`` groups, behind a causal depthwise convolution over
+    # ``mamba_conv`` tokens with a bias; an ``"experts"`` block the routed
+    # experts and nothing else; a ``"full"`` block attention without rotary and
+    # without an MLP. A slot keeps a Mamba block's state as it keeps a linear
+    # layer's; the three kinds' leaves are stacked apart (``_layer_stacks``).
+    mamba_heads: int = 0
+    mamba_head_dim: int = 0
+    ssm_state: int = 0
+    ssm_groups: int = 1
+    mamba_conv: int = 4
+    # What an expert computes, routed and shared alike: ``"swiglu"`` (three
+    # matrices) or ``"relu2"``, ``relu(x W_up)^2 W_down``, with no gate matrix.
+    expert_activation: str = "swiglu"
+    # (index, of): the share of every expert block's routed experts that this
+    # program HOLDS, experts ``index * E / of ..`` of ``num_experts``, one chip's
+    # of an expert-parallel deployment over ``of`` chips. The router stays
+    # ``num_experts`` wide and chooses among all; an assignment to an expert
+    # not held adds nothing here (parallel/moe.routed_experts: the result is
+    # this chip's part of the sum), the shared expert is computed whole.
+    expert_share: tuple = (0, 1)
     # Embeddings are multiplied by this as they are read (muP: sqrt(d_model));
     # the table is drawn that much smaller, so that a layer's branch weighs as
     # much beside the residual as without it.
@@ -137,10 +162,11 @@ class TransformerConfig:
             object.__setattr__(self, "head_dim", self.d_model // self.n_heads)
         kinds = tuple(self.layer_kinds)
         object.__setattr__(self, "layer_kinds", kinds)
-        if kinds and (len(kinds) != self.n_layers or set(kinds) - {"window", "full", "linear"}):
+        object.__setattr__(self, "expert_share", tuple(self.expert_share))
+        if kinds and (len(kinds) != self.n_layers or set(kinds) - {"window", "full", "linear", "mamba", "experts"}):
             raise ValueError(
                 f"layer_kinds names {len(kinds)} layers {sorted(set(kinds))}: need "
-                f"n_layers = {self.n_layers} of 'window' / 'full' / 'linear'"
+                f"n_layers = {self.n_layers} of 'window' / 'full' / 'linear' / 'mamba' / 'experts'"
             )
         if "window" in kinds and not self.sliding_window:
             raise ValueError("layer_kinds has window layers and sliding_window is 0")
@@ -162,10 +188,54 @@ class TransformerConfig:
             ):
                 if field:
                     raise ValueError(f"{what}: has not run and is not built")
+        if self.single_mixer:
+            if "mamba" in kinds and not (
+                self.mamba_heads and self.mamba_head_dim and self.ssm_state and self.mamba_conv > 1
+                and self.mamba_heads % self.ssm_groups == 0
+            ):
+                raise ValueError(
+                    "layer_kinds has mamba blocks: mamba_heads, mamba_head_dim and ssm_state must be set, "
+                    "mamba_conv at least 2, and ssm_groups must divide mamba_heads"
+                )
+            if "experts" in kinds and not self.routed_experts:
+                raise ValueError("layer_kinds has experts blocks: experts_per_token (and num_experts, d_expert) must be set")
+            if len(kinds) % _period(kinds):
+                raise ValueError(
+                    f"layer_kinds of single-mixer blocks must be whole periods: {len(kinds)} blocks, period {_period(kinds)}"
+                )
+            for field, what in (
+                ("window" in kinds, "window layers beside single-mixer blocks (layer_kinds)"),
+                ("linear" in kinds, "linear-attention layers beside single-mixer blocks (layer_kinds)"),
+                (self.latent_attention, "latent attention (kv_lora_rank > 0) in a pattern of single-mixer blocks"),
+                (self.first_dense_layers > 0, "leading dense layers (first_dense_layers) before single-mixer blocks"),
+                (self.attn_gate or self.qk_norm or self.qk_norm_whole or self.post_norms or not self.pre_norms,
+                 "attn_gate, qk_norm, qk_norm_whole, post_norms or pre_norms=False in a single-mixer block"),
+            ):
+                if field:
+                    raise ValueError(f"{what}: has not run and is not built")
+        if self.expert_activation not in ("swiglu", "relu2"):
+            raise ValueError(f"expert_activation {self.expert_activation!r}: 'swiglu' or 'relu2'")
+        index, of = self.expert_share if len(self.expert_share) == 2 else (-1, 0)
+        if not (of >= 1 and 0 <= index < of) or (of > 1 and (not self.routed_experts or self.num_experts % of)):
+            raise ValueError(
+                f"expert_share {self.expert_share!r}: (index, of) with 0 <= index < of, of dividing "
+                f"num_experts = {self.num_experts} of a model with routed experts"
+            )
 
     @property
     def latent_attention(self) -> bool:
         return self.kv_lora_rank > 0
+
+    @property
+    def single_mixer(self) -> bool:
+        """Every block is one mixer behind one norm (a pattern with ``"mamba"``
+        or ``"experts"`` blocks), not a mixer and then an MLP."""
+        return "mamba" in self.layer_kinds or "experts" in self.layer_kinds
+
+    @property
+    def held_experts(self) -> int:
+        """Routed experts of a block whose weights this program holds (``expert_share``)."""
+        return self.num_experts // self.expert_share[1]
 
     @property
     def routed_experts(self) -> bool:
@@ -192,6 +262,9 @@ class TransformerConfig:
             ("linear_key_dim", "linear-attention layers (linear_key_dim)"),
             ("linear_value_dim", "linear-attention layers (linear_value_dim)"),
             ("linear_neg_eigval", "a write strength over (0, 2) (linear_neg_eigval)"),
+            ("mamba_heads", "Mamba-2 state-space blocks (mamba_heads)"),
+            ("mamba_head_dim", "Mamba-2 state-space blocks (mamba_head_dim)"),
+            ("ssm_state", "Mamba-2 state-space blocks (ssm_state)"),
         ):
             if getattr(self, field):
                 missing.append(f"{what} has no training block")
@@ -199,6 +272,14 @@ class TransformerConfig:
             missing.append("a block without input norms (pre_norms) has no training block")
         if self.linear_conv != 4:
             missing.append("a linear layer's convolution (linear_conv) has no training block")
+        if self.ssm_groups != 1:
+            missing.append("a state-space block's groups (ssm_groups) have no training block")
+        if self.mamba_conv != 4:
+            missing.append("a state-space block's convolution (mamba_conv) has no training block")
+        if self.expert_activation != "swiglu":
+            missing.append("experts without a gate matrix (expert_activation) have no training block")
+        if self.expert_share != (0, 1):
+            missing.append("a held share of the experts (expert_share) has no training block")
         if self.head_dim * self.n_heads != self.d_model:
             missing.append("a head_dim other than d_model // n_heads has no training block")
         if self.embed_multiplier != 1.0:
@@ -217,8 +298,8 @@ _DRAW_ELEMENTS = 1 << 22
 _DRAW_SLICE_MAX = 1 << 27
 
 
-@partial(jax.jit, static_argnames=("shape", "scale", "dtype"))
-def _draw_normal(key, shape, scale, dtype):
+@partial(jax.jit, static_argnames=("shape", "scale", "dtype", "post"))
+def _draw_normal(key, shape, scale, dtype, post=None):
     """One parameter leaf: normal(0, scale) of ``shape`` in ``dtype``, drawn a
     slice of the leading axis at a time inside one compiled loop. Drawn whole
     and eagerly, a stacked [16, 4096, 14336] leaf is 3.8 GB in f32, twice over
@@ -233,14 +314,19 @@ def _draw_normal(key, shape, scale, dtype):
     (the [4096, 32000] head compiled for 16 s with 131 at a time, for 0.5 s
     with 128; the values are the same, each slice has its own key); and a leaf
     whose slices are larger than ``_DRAW_SLICE_MAX`` elements ([7, 64, 1536,
-    2048]: 7 s) is drawn over its leading axes together."""
+    2048]: 7 s) is drawn over its leading axes together.
+
+    ``post`` (a function, optional): what the scaled normal values go through
+    before the cast, for a leaf whose published initialisation is no normal
+    (``_uniform_log_A``, ``_log_uniform_dt_bias``)."""
     lead = 1
     while len(shape) - lead > 2 and math.prod(shape[lead:]) > _DRAW_SLICE_MAX:
         lead += 1
     n, rest = math.prod(shape[:lead]), shape[lead:]
 
     def draw(k):
-        return (jax.random.normal(k, rest) * scale).astype(dtype)
+        values = jax.random.normal(k, rest) * scale
+        return (values if post is None else post(values)).astype(dtype)
 
     fit = min(n, max(1, _DRAW_ELEMENTS // math.prod(rest)))
     at_a_time = next(b for b in range(fit, 0, -1) if n % b == 0)
@@ -255,24 +341,75 @@ class _Leaf(NamedTuple):
     scale: Any  # of the normal draw; with ``key`` None the constant, None for 1
     axes: tuple  # logical axis names (parallel/mesh.logical_to_spec)
     dtype: Any = None  # None: the configuration's ``param_dtype``
+    post: Any = None  # ``_draw_normal``'s: what the drawn values go through
 
 
-def _layer_leaves(cfg: TransformerConfig, mlp: str, linear: bool = False) -> dict:
-    """One layer's leaves by name. ``mlp`` is ``"dense"``, ``"switch"`` or
-    ``"routed"``; ``linear``: a linear-attention layer's mixer in place of
-    attention's. Stacked, every leaf gains a leading layer axis."""
+# Mamba-2's published initialisation of a head's decay and time step (``A_init_
+# range``, ``time_step_min`` / ``_max`` / ``_floor`` of a ``nemotron_h``
+# configuration, the defaults of the Mamba-2 code): A uniform over (1, 16), the
+# time step log-uniform over (0.001, 0.1) and no smaller than 1e-4. With both a
+# head forgets between a fifth and a thousandth of its state a token.
+_MAMBA_A_RANGE = (1.0, 16.0)
+_MAMBA_DT_RANGE = (1e-3, 1e-1)
+_MAMBA_DT_FLOOR = 1e-4
+
+
+def _uniform_log_A(z):
+    """Unit normal values -> ``A_log``: log of a uniform draw over ``_MAMBA_A_RANGE``."""
+    lo, hi = _MAMBA_A_RANGE
+    return jnp.log(lo + (hi - lo) * jax.scipy.stats.norm.cdf(z))
+
+
+def _log_uniform_dt_bias(z):
+    """Unit normal values -> ``dt_bias``: the inverse softplus of a time step
+    drawn log-uniformly over ``_MAMBA_DT_RANGE``."""
+    lo, hi = (math.log(v) for v in _MAMBA_DT_RANGE)
+    dt = jnp.maximum(jnp.exp(lo + (hi - lo) * jax.scipy.stats.norm.cdf(z)), _MAMBA_DT_FLOOR)
+    return dt + jnp.log(-jnp.expm1(-dt))
+
+
+def _layer_leaves(cfg: TransformerConfig, mlp, mixer="attention") -> dict:
+    """One layer's leaves by name. ``mlp`` is ``"dense"``, ``"switch"``,
+    ``"routed"`` or None (a block that is a mixer alone); ``mixer`` is
+    ``"attention"``, ``"linear"`` (a linear-attention layer's), ``"mamba"`` (a
+    state-space block's) or None (a block that is its experts alone). Stacked,
+    every leaf gains a leading layer axis."""
     D, H, KV, Dh, F, E = (
         cfg.d_model, cfg.n_heads, cfg.n_kv_heads, cfg.head_dim, cfg.d_ff, cfg.num_experts
     )
     s = D**-0.5
-    out = (2 * cfg.n_layers) ** -0.5  # residual branches shrink with depth
+    # Residual branches shrink with depth: two a layer, or one a block.
+    out = (cfg.n_layers if cfg.single_mixer else 2 * cfg.n_layers) ** -0.5
     leaves = {}
     if cfg.pre_norms:
         leaves = {
-            "attn_norm": _Leaf(None, (D,), None, (None,)),
-            "mlp_norm": _Leaf(None, (D,), None, (None,)),
+            **({"attn_norm": _Leaf(None, (D,), None, (None,))} if mixer else {}),
+            **({"mlp_norm": _Leaf(None, (D,), None, (None,))} if mlp else {}),
         }
-    if linear:
+    if mixer == "mamba":
+        Hm, P, N, G, K = cfg.mamba_heads, cfg.mamba_head_dim, cfg.ssm_state, cfg.ssm_groups, cfg.mamba_conv
+        inner, channels = Hm * P, Hm * P + 2 * G * N
+        # The input projection is three leaves, the gate's, the convolution's
+        # channels' and the time step's: whole, its 2 Hm P + 2 G N + Hm columns
+        # (10,304 at the published widths) are no multiple of the TPU's 128
+        # lanes, and every slice of the product would start inside a tile.
+        # ``A_log``, ``dt_bias`` and ``D`` stay float32 like a router: a decay
+        # is exponentiated twice.
+        leaves.update(
+            {
+                "w_z": _Leaf(0, (D, inner), s, ("embed", "heads")),
+                "w_xbc": _Leaf(1, (D, channels), s, ("embed", "heads")),
+                "w_dt": _Leaf(2, (D, Hm), s, ("embed", None)),
+                "conv_w": _Leaf(8, (K, channels), K**-0.5, (None, "heads")),
+                "conv_b": _Leaf(9, (channels,), 0.1, ("heads",)),
+                "A_log": _Leaf(10, (Hm,), 1.0, (None,), jnp.float32, _uniform_log_A),
+                "dt_bias": _Leaf(11, (Hm,), 1.0, (None,), jnp.float32, _log_uniform_dt_bias),
+                "D": _Leaf(None, (Hm,), None, (None,), jnp.float32),
+                "ssm_norm": _Leaf(None, (inner,), None, (None,)),
+                "wo": _Leaf(3, (inner, D), inner**-0.5 * out, ("heads", "embed")),
+            }
+        )
+    elif mixer == "linear":
         Hl, dk, dv = cfg.linear_heads, cfg.linear_key_dim, cfg.linear_value_dim
         # Queries, keys and values are one matrix: the convolution runs over
         # its 2 Hl dk + Hl dv columns as they lie, and a slot carries the last
@@ -296,6 +433,8 @@ def _layer_leaves(cfg: TransformerConfig, mlp: str, linear: bool = False) -> dic
                 "wo": _Leaf(3, (Hl * dv, D), (Hl * dv) ** -0.5 * out, ("heads", "embed")),
             }
         )
+    elif mixer is None:
+        pass
     elif cfg.latent_attention:
         R, Rq = cfg.kv_lora_rank, cfg.q_lora_rank
         N, P, Vd = cfg.qk_nope_head_dim, cfg.qk_rope_head_dim, cfg.v_head_dim
@@ -339,6 +478,9 @@ def _layer_leaves(cfg: TransformerConfig, mlp: str, linear: bool = False) -> dic
             }
         )
         return leaves
+    if mlp is None:
+        return leaves
+    gated = cfg.expert_activation == "swiglu"  # else no gate matrix, routed or shared
     if mlp == "routed":
         F = cfg.d_expert
         Fs = cfg.num_shared_experts * F
@@ -354,16 +496,17 @@ def _layer_leaves(cfg: TransformerConfig, mlp: str, linear: bool = False) -> dic
             leaves.update(
                 {
                     "wi_s": _Leaf(10, (D, Fs), s, ("embed", "mlp")),
-                    "wg_s": _Leaf(11, (D, Fs), s, ("embed", "mlp")),
+                    **({"wg_s": _Leaf(11, (D, Fs), s, ("embed", "mlp"))} if gated else {}),
                     "wo_s": _Leaf(12, (Fs, D), Fs**-0.5 * out, ("mlp", "embed")),
                 }
             )
+        E = cfg.held_experts  # the router above is as wide as all the experts; the matrices below are those held
     else:
         leaves["gate"] = _Leaf(4, (D, E), s, ("embed", None))
     leaves.update(
         {
             "wi_e": _Leaf(5, (E, D, F), s, ("expert", "embed", "mlp")),
-            "wg_e": _Leaf(6, (E, D, F), s, ("expert", "embed", "mlp")),
+            **({"wg_e": _Leaf(6, (E, D, F), s, ("expert", "embed", "mlp"))} if gated else {}),
             "wo_e": _Leaf(7, (E, F, D), F**-0.5 * out, ("expert", "mlp", "embed")),
         }
     )
@@ -371,22 +514,32 @@ def _layer_leaves(cfg: TransformerConfig, mlp: str, linear: bool = False) -> dic
 
 
 LINEAR_LAYERS = "linear_layers"
+MAMBA_LAYERS = "mamba_layers"
+EXPERT_LAYERS = "expert_layers"
 
 
 def _layer_stacks(cfg: TransformerConfig) -> dict:
-    """``params`` key -> (depth, kind of MLP) of each stack of layers, in the
-    order they run: the leading dense layers (where the configuration has
-    any), then ``"layers"``. A pattern with linear-attention layers, whose
-    leaves are not the attention layers', is stacked by kind: its linear layers
-    in ``"linear_layers"`` and the others in ``"layers"``, each in the order
-    its kind's layers come in the model, which runs them interleaved."""
+    """``params`` key -> (depth, kind of MLP, kind of mixer) of each stack of
+    layers (``_layer_leaves``' arguments), in the order they run: the leading
+    dense layers (where the configuration has any), then ``"layers"``. A
+    pattern with linear-attention layers, whose leaves are not the attention
+    layers', is stacked by kind: its linear layers in ``"linear_layers"`` and
+    the others in ``"layers"``, each in the order its kind's layers come in the
+    model, which runs them interleaved. A pattern of single-mixer blocks is
+    three such stacks: the state-space blocks (``"mamba_layers"``), the
+    experts blocks (``"expert_layers"``) and the attention blocks (``"layers"``)."""
     mlp = "routed" if cfg.routed_experts else "switch" if cfg.num_experts > 0 else "dense"
+    if cfg.single_mixer:
+        count = cfg.layer_kinds.count
+        stacks = {MAMBA_LAYERS: (count("mamba"), None, "mamba"), EXPERT_LAYERS: (count("experts"), mlp, None),
+                  "layers": (count("full"), None, "attention")}
+        return {name: stack for name, stack in stacks.items() if stack[0]}
     n_dense = cfg.first_dense_layers
     n_linear = cfg.layer_kinds.count("linear")
-    stacks = {"dense_layers": (n_dense, "dense")} if n_dense else {}
+    stacks = {"dense_layers": (n_dense, "dense", "attention")} if n_dense else {}
     if n_linear:
-        stacks[LINEAR_LAYERS] = (n_linear, mlp)
-    stacks["layers"] = (cfg.n_layers - n_dense - n_linear, mlp)
+        stacks[LINEAR_LAYERS] = (n_linear, mlp, "linear")
+    stacks["layers"] = (cfg.n_layers - n_dense - n_linear, mlp, "attention")
     return stacks
 
 
@@ -402,7 +555,7 @@ def init_params(key, cfg: TransformerConfig) -> dict:
     # stack then splits sixteen keys of its own from ``fold_in(key, stack)``.
     own_keys = (
         cfg.latent_attention or cfg.routed_experts or cfg.first_dense_layers > 0 or cfg.attn_gate
-        or "linear" in cfg.layer_kinds
+        or "linear" in cfg.layer_kinds or cfg.single_mixer
     )
 
     # Every drawn leaf is a program of its own to compile (about a second each
@@ -412,21 +565,21 @@ def init_params(key, cfg: TransformerConfig) -> dict:
     # spent 34 of its 73 s here (v5e, PR 35), under a Serve that gives it 90.
     drawn = _Drawn(key)
 
-    def stack(i, stack_name, L, mlp):
+    def stack(i, L, mlp, mixer):
         keys = jax.random.split(jax.random.fold_in(key, i), 16) if own_keys else ks
         return {
             name: jnp.full((L, *leaf.shape), 1.0 if leaf.scale is None else leaf.scale, leaf.dtype or dt)
             if leaf.key is None
-            else drawn.later(keys[leaf.key], (L, *leaf.shape), leaf.scale, leaf.dtype or dt)
-            for name, leaf in _layer_leaves(cfg, mlp, linear=stack_name == LINEAR_LAYERS).items()
+            else drawn.later(keys[leaf.key], (L, *leaf.shape), leaf.scale, leaf.dtype or dt, leaf.post)
+            for name, leaf in _layer_leaves(cfg, mlp, mixer).items()
         }
 
     params = {
         "embed": drawn.later(ks[8], (V, D), 1.0 / cfg.embed_multiplier, dt),
         "norm_f": jnp.ones((D,), dt),
     }
-    for i, (name, (L, mlp)) in enumerate(_layer_stacks(cfg).items()):
-        params[name] = stack(i, name, L, mlp)
+    for i, (name, of) in enumerate(_layer_stacks(cfg).items()):
+        params[name] = stack(i, *of)
     if not cfg.tie_embeddings:
         params["lm_head"] = drawn.later(ks[9], (D, V), D**-0.5, dt)
     return drawn.now(params)
@@ -470,11 +623,8 @@ def param_logical_axes(cfg: TransformerConfig) -> dict:
     """Per-leaf logical axis names (mapped to mesh axes by
     parallel/mesh.logical_to_spec)."""
     axes = {"embed": ("vocab", "embed"), "norm_f": (None,)}
-    for stack, (_, mlp) in _layer_stacks(cfg).items():
-        axes[stack] = {
-            name: ("layers", *leaf.axes)
-            for name, leaf in _layer_leaves(cfg, mlp, linear=stack == LINEAR_LAYERS).items()
-        }
+    for stack, (_, mlp, mixer) in _layer_stacks(cfg).items():
+        axes[stack] = {name: ("layers", *leaf.axes) for name, leaf in _layer_leaves(cfg, mlp, mixer).items()}
     if not cfg.tie_embeddings:
         axes["lm_head"] = ("embed", "vocab")
     return axes

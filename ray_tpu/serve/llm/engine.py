@@ -267,7 +267,8 @@ _ROW_DRAW = slice(2, 6)  # LLMRequest._sched_draw
 _ROW_COUNTER = 6  # index of the token this dispatch draws
 # The block table from here on: n_max wide (prefill), a rung of _view_rungs
 # (decode). Under a layer pattern the row's ring in the window layers' group
-# comes first (``LLMEngine.ring_blocks`` wide); with linear-attention layers
+# comes first (``LLMEngine.ring_blocks`` wide); with layers that keep a recurrent
+# state a slot (linear attention, Mamba-2: ``generate.state_kind``)
 # ``_STATE_COLS`` columns, a prefill row's slot in their group and whether its
 # state starts from zero (a decode row IS its slot's: row b, slot b); then the
 # table.
@@ -338,7 +339,7 @@ def _compiled_fns(cfg, ring: int = 0):
     slot's), and ``prefill(params, tokens [1, q], pool, rows [1, 7 + n_max])``,
     both ``-> (token ids int32, one a row, pool)``. ``ring`` (a layer pattern
     only): blocks of a row's ring, which a row carries ahead of its table
-    (``7 + ring + w`` columns). With linear-attention layers ``_STATE_COLS``
+    (``7 + ring + w`` columns). With layers that keep a state a slot ``_STATE_COLS``
     columns, a row's slot and whether its state starts from zero, also lie
     ahead of the table; only the prefill program reads them.
 
@@ -366,13 +367,14 @@ def _compiled_fns(cfg, ring: int = 0):
                 paged_decode_chunk_hidden,
                 paged_decode_step,
                 paged_decode_step_with_chunk,
+                state_kind,
             )
             from ray_tpu.models.transformer import _logits
 
-            state = _STATE_COLS if "linear" in cfg.layer_kinds else 0
+            state = _STATE_COLS if state_kind(cfg) else 0
 
             def tables(rows, chunk=False):
-                if state:  # never beside a ring: window layers do not come beside linear ones
+                if state:  # never beside a ring: window layers do not come beside layers that keep a state
                     own = dict(state_slots=rows[:, _ROW_TABLE], state_fresh=rows[:, _ROW_TABLE + 1] != 0)
                     return dict(block_tables=rows[:, _ROW_TABLE + state :], **(own if chunk else {}))
                 if not ring:
@@ -453,12 +455,22 @@ _STATE_POOL_KV_PAYLOAD = (
 )
 
 
+# The same of a Mamba-2 block's state, and of the blocks around it.
+_SSM_POOL_KV_PAYLOAD = (
+    "{what} needs the KV transfer plane, whose payload is blocks of keys and values; "
+    "Mamba-2 state-space blocks (layer_kinds has 'mamba') keep a recurrent state a slot, "
+    "which no block holds and nothing snapshots at a block's boundary (kv_transfer.py, ROADMAP R5)"
+)
+
+
 def _kv_payload_refusal(cfg) -> Optional[str]:
     """Why this configuration's pool cannot feed the KV transfer plane (None: it can)."""
     if cfg.latent_attention:
         return _LATENT_POOL_KV_PAYLOAD
     if "linear" in cfg.layer_kinds:
         return _STATE_POOL_KV_PAYLOAD
+    if "mamba" in cfg.layer_kinds:
+        return _SSM_POOL_KV_PAYLOAD
     return _PATTERN_POOL_KV_PAYLOAD if cfg.layer_kinds else None
 
 
@@ -574,9 +586,9 @@ class LLMEngine:
         windowed = "window" in cfg.layer_kinds
         self.ring_blocks = ring_blocks(cfg.sliding_window, self.prefill_chunk, self.block_size) if windowed else 0
         self.num_window_blocks = self.num_slots * self.ring_blocks + 1 if windowed else 0
-        # Linear-attention layers have a group of their own too, with no
-        # blocks at all: slot s owns row s of its leaves for good (the gated
-        # delta rule's state and the convolution's carried rows, a layer),
+        # Layers that keep a recurrent state (linear attention, Mamba-2 blocks)
+        # have a group of their own too, with no blocks at all: slot s owns row
+        # s of its leaves for good (the state and the convolution's carried rows, a layer),
         # whatever its request's length. Nothing resets it on the host: the
         # first chunk of an admitted request, fresh or back from a preemption,
         # says in its row that the state starts from zero (``_chunk_inputs``),
@@ -615,6 +627,7 @@ class LLMEngine:
         # builds the copy's program before the replica is ready: the first
         # ``stats()`` of a serving engine compiles nothing inside a stream.
         self._moe_read = np.asarray(self._copy_moe_counts()) if cfg.routed_experts else None
+        self._moe_folded = (0, 0)  # assignments (held, all) of it that ``LLM`` has
         self.spans.setup["pool_s"] = time.monotonic() - t0
         # Block 0 is the reserved null block — never handed out.
         self._free: list[int] = list(range(self.num_blocks - 1, 0, -1))
@@ -772,7 +785,7 @@ class LLMEngine:
             raise ValueError("return_routed_experts needs a model with routed experts")
         req.return_routed_experts = bool(return_routed_experts)
         if return_state and not self.state_slot_bytes:
-            raise ValueError("return_state needs a model with linear-attention layers")
+            raise ValueError("return_state needs a model with linear-attention layers or Mamba-2 blocks (a recurrent state a slot)")
         req.return_state = bool(return_state)
         req.request_id = str(request_id or "")
         req.t_recv = int(t_recv_ns) / 1e9 if t_recv_ns else None
@@ -873,7 +886,7 @@ class LLMEngine:
         those in use. ``"full"``: the layers whose blocks grow with a row (all
         of them without a layer pattern). ``"window"``: the window layers of a
         pattern, ``ring_blocks`` a running request whatever its length.
-        ``"state"``: the linear-attention layers, ``bytes_per_slot`` for each of
+        ``"state"``: the layers that keep a recurrent state, ``bytes_per_slot`` for each of
         ``num_slots`` for good, of which ``slots_in_use`` hold a request's."""
         groups = {
             "full": {
@@ -903,9 +916,13 @@ class LLMEngine:
         steps and ``"prefill"`` chunks apart, ``steps`` (those that routed a
         token), per expert layer ``assignments`` [E] (tokens sent to each
         expert), and the sums over those steps of ``experts_touched`` and of
-        ``fullest_expert_load`` (over ``steps``: a layer's mean a step). Only
-        the scheduler thread may read the pool, which every dispatch donates:
-        it is asked, and answers between two passes."""
+        ``fullest_expert_load`` (over ``steps``: a layer's mean a step). Where
+        the program holds a share of the experts (``cfg.expert_share``) all of
+        these count the experts HELD, E of them, and ``assignments_all``, a
+        number an expert layer, every assignment the router made, held or
+        not (without a share: the sum of ``assignments``). Only the scheduler
+        thread may read the pool, which every dispatch donates: it is asked,
+        and answers between two passes."""
         if not self.cfg.routed_experts:
             return None
         with self._moe_asking:
@@ -915,16 +932,42 @@ class LLMEngine:
                 self._wake.set()
                 asked.wait(1.0)
             read = self._moe_read
-        E = self.cfg.num_experts
+        E = self.cfg.held_experts
         return {
             kind: {
                 "steps": int(of[0, E + 2]),
                 "assignments": of[:, :E].tolist(),
+                "assignments_all": self._moe_assignments_all(of).tolist(),
                 "experts_touched": of[:, E].tolist(),
                 "fullest_expert_load": of[:, E + 1].tolist(),
             }
             for kind, of in zip(("decode", "prefill"), read)
         }
+
+    def _moe_assignments_all(self, counts: np.ndarray) -> np.ndarray:
+        """Every assignment the router made, a number an expert layer, from
+        counters [..., expert layers, columns]: a column of its own where the
+        program holds a share of the experts, else the held ones' sum."""
+        E = self.cfg.held_experts
+        return counts[..., E + 3] if self.cfg.expert_share[1] > 1 else counts[..., :E].sum(axis=-1)
+
+    @any_thread
+    def refresh_moe_counts(self):
+        """Asks the scheduler to read the expert counters at its next pass and
+        waits for nothing: what ``/metrics`` does at a flush, so that the next
+        one finds them (``_fold_moe_read``)."""
+        if self.cfg.routed_experts and self._moe_wanted is None and self._thread.is_alive():
+            self._moe_wanted = threading.Event()
+            self._wake.set()
+
+    def _fold_moe_read(self):
+        """Adds what the counters grew by since they were last read to the
+        process's ``LLM`` stats (``ray_tpu_serve_llm_moe_assignments_total``)."""
+        held = int(self._moe_read[..., : self.cfg.held_experts].sum())
+        routed = int(self._moe_assignments_all(self._moe_read).sum())
+        LLM.moe_assignments_held += held - self._moe_folded[0]
+        LLM.moe_assignments_elsewhere += (routed - held) - (self._moe_folded[1] - self._moe_folded[0])
+        self._moe_folded = (held, routed)
 
     def _copy_moe_counts(self):
         """The expert counters, copied on the device: the host's view of the
@@ -1282,6 +1325,7 @@ class LLMEngine:
                     spans.end(it)
                 if asked is not None:
                     self._moe_read = np.asarray(counts)
+                    self._fold_moe_read()
                     asked.set()
                 if not busy:
                     if any(r is not None for r in self._slots) or self._waiting:

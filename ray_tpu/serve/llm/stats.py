@@ -133,6 +133,11 @@ class _LLMStats:
         "chunk_tokens_valid",
         "chunk_tokens_padded",
         "state_resets",
+        # Assignments of tokens to routed experts, as the engines last read
+        # them from the device: to experts the program holds, and (a program
+        # that holds a share of them: ``TransformerConfig.expert_share``) to the others.
+        "moe_assignments_held",
+        "moe_assignments_elsewhere",
         # Scheduler-loop nanoseconds by span and iterations by kind: lists
         # of plain ints, indexed like SPAN_NAMES / ITERATION_KINDS.
         "span_ns",

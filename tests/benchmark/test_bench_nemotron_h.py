@@ -1,0 +1,379 @@
+"""The fifth architecture of the benchmark (PR 43), ``NemotronHForCausalLM``
+(Nemotron-3-Nano-30B-A3B: Mamba-2 state-space blocks, squared-ReLU top-6-of-128
+experts blocks of which the chip holds 64, one GQA block in seven, every block
+a single mixer), and the cell PR 43 adds: the configuration against the
+catalog's row, the cut and the counts against hand arithmetic and against the
+parameter tree the program draws, the cell at a toy size through
+``run.measure`` on the CPU, the two new per-layer metrics' readers and the
+accepted ones that read this architecture's ``costs.py``, and what stands in
+for the tests of ``tests/benchmark/`` that a fifth configuration and a cell
+that reports the cache pair and the state's two of the linear four made wrong (``tests/conftest.py``
+marks those). Nothing here pins the END of a list that a later PR may append
+to: a new entry is held to come AFTER the ones it was appended behind."""
+
+import json
+import time
+
+import pytest
+from bench_toy import toy_cell
+
+from benchmarks import run as bench_run
+from benchmarks.harness import contract, registry, traffic
+
+NEMO, CELL, MIX = "nemotron-3-nano-30b-a3b-serve14", "nemo14.chat-churn", "chat-churn"
+ARCH = "NemotronHForCausalLM"
+CATALOG = "/opt/skills/guides/model-configs/architectures.jsonl"
+ROW = "NVIDIA-Nemotron-3-Nano-30B-A3B-BF16"
+PATTERN = "MEMEM*EMEMEM*EMEMEM*EMEMEM*EMEMEM*EMEMEMEM*EMEMEMEME"
+REDUCED = ["num_hidden_layers", "hybrid_override_pattern", "n_routed_experts", "vocab_size"]
+NEW_METRICS = {
+    "moe_held_share_pct": ("%", "higher", "program_counter", "model"),
+    "prefill_pass_share_pct": ("%", "lower", "program_span", "LLM engine"),
+}
+OLMO_CELL, OLMOS_FOUR = "olmo16.longdoc-8k", ["linear_state_ms", "linear_state_roofline", "linear_scan_ms", "linear_scan_roofline"]
+# The accepted per-layer metrics that are neither training's nor the latent cache's, nor move the time to first
+# token, which the cell does not report (its spread in the builder's two sets of six was over half the bound: ISSUE
+# 43's fallback): it is listed for all the others. The scan's two, which move ``ttft_p90_ms``, still read this
+# architecture's ``costs.py`` where a result carries its cell (``test_the_readers_on_a_result_written_by_hand``).
+NOT_THIS_CELLS = {
+    "trainer_first_step_s", "train_step_ms", "mfu_pct", "train_host_gap_ms", "collective_exposed_ms",
+    "device_idle_pct.train", "latent_attention_ms", "latent_attention_roofline",
+}
+MOVE_TTFT = {
+    "serve_path_overhead_ms", "prefill_chunk_ms", "serve_ingress_p90_ms", "queue_wait_p90_ms", "prefill_span_p90_ms",
+    "linear_scan_ms", "linear_scan_roofline",
+}
+
+
+def _config(manifest):
+    return registry.load_cell(manifest, CELL)["config"]
+
+
+def _part(part):
+    return registry.load_architecture({"name": "these tests", "architecture": ARCH, "bench_dir": registry.BENCH_DIR}, part)
+
+
+def test_the_configuration_holds_the_catalogs_row(manifest):
+    """Every number of the catalog row's ``config`` under the same key, but the
+    four that the cut changes, which ``reduced`` and ``published`` both name."""
+    cfg = _config(manifest)
+    entry = next(c for c in manifest["configs"] if c["name"] == NEMO)
+    assert cfg["reduced"] == entry["reduced"] == REDUCED and set(cfg["published"]) == set(REDUCED)
+    assert cfg["architectures"] == [ARCH] and cfg["path"] == "serve" and cfg["torch_dtype"] == "bfloat16"
+    assert cfg["published"] == {"num_hidden_layers": 52, "hybrid_override_pattern": PATTERN, "n_routed_experts": 128, "vocab_size": 131072}
+    assert (PATTERN.count("M"), PATTERN.count("E"), PATTERN.count("*"), len(PATTERN)) == (23, 23, 6, 52)
+    try:
+        with open(CATALOG) as f:
+            row = next(r for r in map(json.loads, f) if r["name"] == ROW)
+    except OSError:
+        pytest.skip("no catalog beside this installation")
+    assert row["source_url"] == cfg["source"] == entry["source"]
+    for key, value in row["config"].items():
+        if key in REDUCED:
+            assert cfg["published"][key] == value
+        else:
+            assert cfg[key] == value and type(cfg[key]) is type(value), key
+    for key in ("inner width", "no rotary", "split orders", "gated norm", "A_log, dt_bias, D", "state in float32", "residual"):
+        assert key in cfg["assumed"], key
+
+
+def test_the_cut_is_two_whole_periods_half_the_experts_and_half_the_vocabulary(manifest):
+    cfg = _config(manifest)
+    assert cfg["hybrid_override_pattern"] == PATTERN[:14] == "MEMEM*E" * 2 and cfg["num_hidden_layers"] == 14
+    assert PATTERN[:35] == "MEMEM*E" * 5  # the model's first 35 blocks are five such periods
+    ep = cfg["deployment"]["expert_parallel"]
+    assert ep == {"chips": 2, "index": 0} and cfg["n_routed_experts"] * ep["chips"] == 128
+    assert cfg["vocab_size"] * 2 == 131072 and cfg["num_experts_per_tok"] == 6
+    # the guide's floors: a whole period and four blocks more, eight experts a block, an eighth of the vocabulary
+    assert cfg["num_hidden_layers"] >= 7 + 4 and cfg["n_routed_experts"] >= 8 and cfg["vocab_size"] * 8 >= 131072
+    assert cfg["deployment"]["engine"] == dict(num_slots=64, block_size=16, max_model_len=2048, num_blocks=64 * 128 + 1, prefill_chunk=512)
+    model = _part("config").model_config(cfg, 2048, "bfloat16")
+    assert model["layer_kinds"] == ["mamba", "experts", "mamba", "experts", "mamba", "full", "experts"] * 2
+    assert (model["num_experts"], model["expert_share"], model["experts_per_token"], model["d_expert"]) == (128, [0, 2], 6, 1856)
+    assert (model["mamba_heads"], model["mamba_head_dim"], model["ssm_state"], model["ssm_groups"], model["mamba_conv"]) == (64, 64, 128, 8, 4)
+    assert (model["n_heads"], model["n_kv_heads"], model["head_dim"], model["d_model"], model["vocab_size"]) == (32, 2, 128, 2688, 65536)
+    assert model["num_shared_experts"] * model["d_expert"] == 3712 and model["expert_activation"] == "relu2"
+    assert model["routed_scaling_factor"] == 2.5 and model["norm_eps"] == 1e-5 and "rope_theta" not in model
+    check = cfg["check"]
+    assert check["prompt_lens"] == [200, 700, 1400] and check["new_tokens"] == 128
+    assert 0 < check["state_gap_tol"] < 0.05 and 0 < check["logit_gap_tol"] <= 0.5
+    for key in ("logit_gap_tol_why", "state_gap_tol_why"):
+        assert "TWO READINGS" in check[key]
+    assert "measured" in cfg["deployment"]["sizing"].lower() and "64 experts" in cfg["cut"]
+
+
+def test_the_counts_against_hand_arithmetic(manifest):
+    """ISSUE 43's arithmetic, block by block."""
+    cfg, costs = _config(manifest), _part("costs")
+    assert costs.mamba_block_params(cfg) == 2688 * 10304 + 4096 * 2688 + 6144 * 5 + 3 * 64 + 4096 + 2688 == 38_744_896
+    assert costs.attention_matmul_params(cfg) + 2688 == 2 * 2688 * 4096 + 2 * 2688 * 256 + 2688 == 23_399_040
+    assert costs.expert_params(cfg) == 2 * 2688 * 1856 == 9_977_856  # no gate matrix
+    assert costs.experts_block_params(cfg) == 64 * 9_977_856 + 2 * 2688 * 3712 + 2688 * 128 + 128 + 2688 == 658_885_376
+    assert costs.n_params(cfg) == 6 * 38_744_896 + 2 * 23_399_040 + 6 * 658_885_376 + 2 * 65536 * 2688 + 2688 == 4_584_903_936
+    assert 9.16e9 < costs.weight_bytes(cfg) < 9.18e9
+    whole = dict(cfg, num_hidden_layers=52, hybrid_override_pattern=PATTERN, n_routed_experts=128, vocab_size=131072)
+    assert round(costs.n_params(whole) / 1e9, 2) == 31.58  # the model as published
+    assert costs.state_bytes_per_slot(cfg) == 6 * (64 * 64 * 128 * 4 + 3 * 6144 * 2) == 6 * 2_134_016 == 12_804_096
+    assert costs.kv_bytes_per_token(cfg) == 2 * 2 * 2 * 128 * 2 == 2048
+    assert costs.linear_state_bytes(cfg, 64) == 64 * 6 * 2 * 2_134_016
+    assert costs.moe_experts_bytes(cfg, 61.0) == 6 * 61.0 * 9_977_856 * 2
+    assert costs.cache_attention_bytes(cfg, 21_000, 21_000) == 21_000 * 2048
+    assert 60.9 < costs.expected_experts_touched(cfg, 64) < 61.1 and costs.expected_experts_touched(cfg, 1) == pytest.approx(3.0)
+    assert costs.linear_scan_flops(cfg, 512) == 512 * 6 * 5 * 4096 * 128
+    assert costs.linear_scan_bytes(cfg, 512) == 6 * 512 * ((6144 + 4096) * 2 + 64 * 4) + 2 * 12_804_096
+    step = costs.decode_step_bytes(cfg, 64 * 336)
+    assert 9.9e9 < step < 10.4e9  # ~7.3 GB of held experts, ~1.2 GB of other matrices, 1.64 GB of state, the cache
+    alone = costs.moe_steps_alone(cfg, traced=True)
+    assert alone == {"steps": 2 * 3 * 127 + 10, "experts_touched": 3.0, "fullest_expert_load": 1 - 0.5**6}
+
+
+def test_the_counts_are_the_drawn_parameter_trees(manifest):
+    """``costs.py`` against what the program draws and holds: leaf for leaf at
+    a toy size, and by shape alone at the published widths."""
+    import jax
+    import jax.numpy as jnp
+
+    from ray_tpu.models.generate import cache_token_bytes, state_slot_bytes
+    from ray_tpu.models.transformer import TransformerConfig, init_params, num_params
+
+    costs, config = _part("costs"), _part("config")
+    for cfg in (toy_cell(manifest, CELL)["config"], _config(manifest)):
+        model = config.model_config(cfg, 256, "bfloat16")
+        model.update(dtype=jnp.bfloat16, param_dtype=jnp.bfloat16)
+        tc = TransformerConfig(**model)
+        tree = jax.eval_shape(lambda: init_params(jax.random.PRNGKey(0), tc))
+        assert num_params(tree) == costs.n_params(cfg)
+        assert sum(a.size * a.dtype.itemsize for a in jax.tree.leaves(tree)) == costs.weight_bytes(cfg)
+        assert tree["expert_layers"]["wi_e"].shape[1:] == (cfg["n_routed_experts"], cfg["hidden_size"], cfg["moe_intermediate_size"])
+        assert state_slot_bytes(tc) == costs.state_bytes_per_slot(cfg)
+        assert cache_token_bytes(tc) == {"full": costs.kv_bytes_per_token(cfg)}
+
+
+def test_the_configuration_is_refused_where_the_program_cannot_compute_it(manifest, monkeypatch):
+    cfg, config = _config(manifest), _part("config")
+    for key, value in (("n_group", 2), ("mlp_hidden_act", "silu"), ("use_conv_bias", False), ("mamba_proj_bias", True),
+                       ("time_step_max", 0.2), ("chunk_size", 256), ("norm_topk_prob", False)):
+        with pytest.raises(ValueError, match=f"{key} = .*the program computes"):
+            config.model_config(dict(cfg, **{key: value}), 2048, "bfloat16")
+    with pytest.raises(ValueError, match="of kinds the program has not: \\['-'\\]"):
+        config.model_config(dict(cfg, hybrid_override_pattern="ME-*" + cfg["hybrid_override_pattern"][4:]), 2048, "bfloat16")
+    with pytest.raises(ValueError, match="held on each of 2 chips is not the published 128"):
+        config.model_config(dict(cfg, n_routed_experts=32), 2048, "bfloat16")
+    # The parent of PR 43: its TransformerConfig lacks the fields; refused in the driver process, by name.
+    fields = config._program_fields()
+    assert {"mamba_heads", "ssm_state", "expert_share", "expert_activation"} <= fields
+    monkeypatch.setattr(config, "_program_fields", lambda: fields - {"mamba_heads", "ssm_groups", "expert_share"})
+    with pytest.raises(NotImplementedError, match="no expert_share, mamba_heads, ssm_groups: it cannot run Mamba-2"):
+        config.model_config(cfg, 2048, "bfloat16")
+
+
+def test_the_mix_is_the_issues_and_fits_the_cell(manifest):
+    cell = registry.load_cell(manifest, CELL)
+    mix, engine = cell["traffic"], cell["config"]["deployment"]["engine"]
+    assert mix["arrival"]["process"] == "closed" and mix["arrival"]["clients"] == engine["num_slots"] == 64
+    assert mix["prompt_len"] == {"dist": "lognormal", "median": 256, "sigma": 0.8, "min": 32, "max": 1536}
+    assert mix["output_len"] == {"dist": "lognormal", "median": 160, "sigma": 0.6, "min": 32, "max": 512}
+    assert mix["sampling"] == {"sampled_share": 0.5, "temperature": 0.7, "top_k": 50}
+    assert mix["stratified"] is True and mix["schedule_seed"] == 43
+    assert (mix["preroll_s"], mix["grace_s"], mix["trace_slice_s"]) == (10.0, 5.0, 3.0)
+    assert mix["prompt_len"]["max"] + mix["output_len"]["max"] <= engine["max_model_len"] == 2048
+    assert mix["prompt_len"]["max"] == 3 * engine["prefill_chunk"]  # one to three chunks
+    vocab = cell["config"]["vocab_size"]
+    plans = [traffic.schedule(mix, seed, 51.0, vocab) for seed in (1, 2**31 + 5)]
+    assert traffic.offered_tokens(plans[0]) == traffic.offered_tokens(plans[1])  # a pinned schedule: the same load
+    for plan in plans:
+        reqs = [r for client in plan["closed"] for r in client]
+        assert len(plan["closed"]) == 64 and all(len(c) == mix["arrival"]["requests_per_client"] for c in plan["closed"])
+        assert all(len(r["tokens"]) + r["max_new_tokens"] <= 2048 and max(r["tokens"]) < vocab for r in reqs)
+        assert sum(r["temperature"] > 0 for r in reqs) * 2 == len(reqs)
+        assert {r["top_k"] for r in reqs if r["temperature"] > 0} == {50}
+    # no client runs out: at ~10 requests a second (mean prompt + output ~ 530 tokens at ~5.5k tokens/s of both)
+    # a client ends one every ~6.5 s; 24 of them last 150 s, the run's pre-roll + window + grace 66
+    assert mix["arrival"]["requests_per_client"] >= 20
+
+
+def test_the_new_entries_are_appended_behind_what_was_there(manifest):
+    """Stands in for test_bench_olmo_hybrid.py::test_the_new_entries_are_appended_behind_what_was_there, which holds
+    Olmo-Hybrid's four metrics and the seven of a token's way back to the cells PR 41 knew, and is marked xfail
+    (strict) in tests/conftest.py since PR 43 appends its cell to their lists. The seven still stand together behind
+    the cache pair, Olmo-Hybrid's four behind them, PR 43's two behind those; cells and configurations in the order
+    they came; every list that names the new cell names it behind the cells that were there."""
+    import test_bench_delivery as delivery
+
+    names = [m["name"] for m in manifest["per_layer"]]
+    assert len(names) == len(set(names))
+    first = names.index("cache_attention_ms")
+    assert names[first : first + 13] == ["cache_attention_ms", "cache_attention_roofline", *delivery.SEVEN, *OLMOS_FOUR]
+    assert names[first + 13 : first + 15] == list(NEW_METRICS)  # appended, in ISSUE 43's order, behind the four
+    cells = [w["name"] for w in manifest["workloads"]]
+    assert cells.index(CELL) == cells.index(OLMO_CELL) + 1 == 8
+    configs = [c["name"] for c in manifest["configs"]]
+    assert configs.index(NEMO) == configs.index("olmo-hybrid-7b-serve16") + 1 == 5
+    entry = manifest["workloads"][cells.index(CELL)]
+    assert (entry["config"], entry["traffic"], entry["chips"]) == (NEMO, MIX, 1) and len(entry["why"]) <= 200
+    assert "attention sees twice its share of rows" in entry["why"]
+    declared = {m["name"]: m for m in manifest["per_layer"]}
+    for name, (unit, better, source, layer) in NEW_METRICS.items():
+        assert declared[name] == dict(name=name, unit=unit, better=better, source=source, layer=layer,
+                                      moves="itl_p95_ms", workloads=[CELL])
+    want = lambda cell, traced: set(contract.expected_metrics(manifest, cell, traced))  # noqa: E731
+    assert want(CELL, False) == {"itl_p95_ms", "serve_tokens_per_s", "setup_s"}  # as glm8.rollout-long: no ttft_p90_ms
+    assert MOVE_TTFT == {m["name"] for m in manifest["per_layer"] if m["moves"] == "ttft_p90_ms"}
+    accepted = set(names[: first + 13]) - NOT_THIS_CELLS - MOVE_TTFT
+    assert len(accepted) == 30 and want(CELL, True) == want(CELL, False) | accepted | set(NEW_METRICS)
+    for m in manifest["end_to_end"] + manifest["per_layer"]:
+        listed = m.get("workloads", ())
+        if CELL in listed:  # appended to each list: behind every cell that was there before it
+            assert all(listed.index(c) < listed.index(CELL) for c in listed if c in cells[:8]), m["name"]
+    # Olmo-Hybrid's four and the seven: the cells they had, and this one appended
+    for name in OLMOS_FOUR:
+        assert declared[name]["workloads"] == ([OLMO_CELL] if "scan" in name else [OLMO_CELL, CELL])
+        assert {k: v for k, v in declared[name].items() if k != "workloads"} == dict(
+            name=name, unit="%" if name.endswith("roofline") else "ms", better="higher" if name.endswith("roofline") else "lower",
+            source="device_trace", layer="model", moves="ttft_p90_ms" if "scan" in name else "itl_p95_ms")
+    for name in delivery.SEVEN:
+        assert declared[name]["workloads"] == [*delivery.CELLS, OLMO_CELL, CELL]
+    for w in manifest["workloads"]:
+        traced = want(w["name"], True)
+        assert (set(delivery.SEVEN) <= traced) == (w["name"] in [*delivery.CELLS, OLMO_CELL, CELL])
+        assert not set(delivery.SEVEN) & want(w["name"], False)
+        assert bool(set(OLMOS_FOUR) & traced) == (w["name"] in (OLMO_CELL, CELL))
+        assert bool(set(NEW_METRICS) & traced) == (w["name"] == CELL)
+
+
+def test_trinitys_mix_is_still_the_issues(manifest):
+    """Stands in for test_bench_olmo_hybrid.py::test_trinitys_mix_is_still_the_issues, which holds the cache pair to
+    two cells and is marked xfail (strict) in tests/conftest.py since the two attention blocks of PR 43's cut report
+    the pair too. Everything else it holds is held here."""
+    trinity, glm = "trinity5.rollout-longctx", "glm8.rollout-long"
+    rollout = registry.load_cell(manifest, trinity)["traffic"]
+    assert rollout["arrival"] == {"process": "closed", "clients": 32, "requests_per_client": 4}
+    assert rollout["sampling"] == {"sampled_share": 1.0, "temperature": 1.0, "top_k": 0}
+    assert rollout["stratified"] is True and rollout["schedule_seed"] == 35
+    assert (rollout["grace_s"], rollout["trace_slice_s"]) == (5.0, 3.0) and 16.0 <= rollout["preroll_s"] <= 24.0
+    lo, hi = rollout["prompt_len"]["min"], rollout["prompt_len"]["max"]
+    assert rollout["prompt_len"]["dist"] == rollout["output_len"]["dist"] == "uniform"
+    assert hi - lo == 512 and abs(lo - 3072) <= 512 and "lengths_why" in rollout
+    want = lambda cell, traced: set(contract.expected_metrics(manifest, cell, traced))  # noqa: E731
+    assert want(trinity, False) == {"itl_p95_ms", "serve_tokens_per_s", "setup_s"}
+    pair = {"cache_attention_ms", "cache_attention_roofline"}
+    assert want(trinity, True) == (want(glm, True) - {"latent_attention_ms", "latent_attention_roofline"}) | pair
+    # the pair reads the views of full layers: Trinity's, Olmo-Hybrid's four, and since PR 43 Nemotron's two blocks
+    for w in manifest["workloads"]:
+        assert bool(pair & want(w["name"], True)) == (w["name"] in (trinity, OLMO_CELL, CELL))
+    entry = next(w for w in manifest["workloads"] if w["name"] == trinity)
+    assert entry["chips"] == 1 and entry["config"] == "trinity-mini-serve5"
+
+
+def _result(manifest, **over):
+    """What the readers see of a traced run of the cell, by hand."""
+    cell = registry.load_cell(manifest, CELL)
+    cell["config"]["trace_ops"] = {
+        "moe_experts": r"^%ragged-dot-none\S* custom-call bf16\[384,", "linear_state": r"f32\[6,64,64,64,128\]",
+        "linear_scan": r"f32\[1,4,128,128,8,8\]", "cache_attention": r"bf16\[64,2048,2,128\]",
+    }
+    fields = ["t_start_ns", "rows", "prefill_tokens", "context_tokens", "window_tokens", "llm.iteration"]
+    iterations = [[10**9 * (i + 1), 64 if i != 2 else 0, 512 if i % 2 else 0, 21_000, 21_000, 10**6] for i in range(6)]
+    steps = 2000 + 772
+    moe = {"steps": steps, "assignments": [[steps * 3] * 64] * 6, "assignments_all": [steps * 3 * 128] * 6,
+           "experts_touched": [2000 * 60 + 772 * 3] * 6, "fullest_expert_load": [2000 * 9 + 760] * 6}
+    result = {
+        "cell": cell, "seconds": 51.0, "traced": True, "trace": None,
+        "device": {"platform": "tpu", "kind": "TPU v5 lite", "count": 1, "memory_peak_bytes": 1},
+        "counters": {"moe": {"decode": moe}, "running_polls": [64] * 50,
+                     "spans": {"fields": {"iterations": fields}, "iterations": [x for rec in iterations for x in rec]}},
+    }
+    result["trace"] = {"devices": [{
+        "programs": {"decode": [0.03] * 20, "prefill": [0.05] * 8},
+        "ops": [["%ragged-dot-none.3 custom-call bf16[384,1920]", 0.2], ["%fusion.37 fusion f32[6,64,64,64,128]", 0.08],
+                ["%fusion.71 fusion f32[1,4,128,128,8,8]", 0.04], ["%copy.168 copy bf16[64,2048,2,128]", 0.02],
+                ["%fusion.9 fusion bf16[64,2688]", 0.5]],
+    }]}
+    result.update(over)
+    return result
+
+
+def test_the_readers_on_a_result_written_by_hand(manifest):
+    """The new metrics, and the accepted ones whose readers take this
+    architecture's ``costs.py``: a share of a roofline stays under 100."""
+    result = _result(manifest)
+    read = lambda name: registry.load_metric("per_layer", name)(result)  # noqa: E731
+    assert read("moe_held_share_pct") == pytest.approx(50.0)
+    assert read("prefill_pass_share_pct") == pytest.approx(50.0)  # three of six passes, the one without decode rows too
+    assert read("moe_experts_touched_mean") == pytest.approx(60.0)  # the check's 772 lone rows taken out at 3 experts each
+    assert read("moe_experts_ms") == pytest.approx(10.0)
+    assert read("moe_experts_roofline") == pytest.approx(100 * 6 * 60 * 9_977_856 * 2 / 819e9 / 0.010)
+    assert read("linear_state_ms") == pytest.approx(4.0)
+    assert read("linear_state_roofline") == pytest.approx(100 * 64 * 6 * 2 * 2_134_016 / 819e9 / 0.004)
+    assert read("linear_scan_ms") == pytest.approx(5.0)
+    moved = (6 * 512 * ((6144 + 4096) * 2 + 256) + 2 * 12_804_096) / 819e9
+    assert read("linear_scan_roofline") == pytest.approx(100 * moved / 0.005)
+    assert read("cache_attention_ms") == pytest.approx(1.0)
+    assert read("cache_attention_roofline") == pytest.approx(100 * 21_000 * 2048 / 819e9 / 0.001)
+    mix = result["cell"]["traffic"]
+    context = int(64 * (traffic.mean_length(mix["prompt_len"]) + traffic.mean_length(mix["output_len"]) / 2))
+    least = _part("costs").decode_step_bytes(result["cell"]["config"], context) / 819e9
+    assert read("decode_roofline") == pytest.approx(100 * least / 0.03)
+    for name in ("moe_experts_roofline", "linear_state_roofline", "linear_scan_roofline", "cache_attention_roofline", "decode_roofline"):
+        assert 0 < read(name) <= 100, name
+
+
+@pytest.mark.parametrize("lacking", ["assignments_all", "moe", "spans", "another cell"])
+def test_a_new_reader_that_finds_nothing_to_read_gives_none(manifest, lacking):
+    """The parent of PR 43 under PR 43's benchmark files (its counters know no
+    ``assignments_all``), a program without expert counters or without spans,
+    and a cell whose program holds all its experts."""
+    result = _result(manifest)
+    silent = {"moe_held_share_pct"}
+    if lacking == "assignments_all":
+        del result["counters"]["moe"]["decode"]["assignments_all"]
+    elif lacking == "moe":
+        del result["counters"]["moe"]
+    elif lacking == "spans":
+        del result["counters"]["spans"]
+        silent = {"prefill_pass_share_pct"}
+    else:
+        glm = registry.load_cell(manifest, "glm8.rollout-long")
+        result = dict(result, cell=glm, counters={"moe": {"decode": {"steps": 5, "assignments": [[3] * 64] * 7}}, "spans": result["counters"]["spans"]})
+    for name in NEW_METRICS:
+        value = registry.load_metric("per_layer", name)(result)
+        assert (value is None) == (name in silent), (name, value)
+
+
+def test_the_new_cell_runs_at_a_toy_size_against_its_reference(manifest, fake_chips, tmp_path):
+    """``toy_cell`` through ``run.measure`` on the CPU: the replica, the check
+    against the architecture's own float32 reference through the state, the
+    experts taken and the cache, the mix, the line. The toy sizes state float32
+    activations over the bfloat16 weights, as Olmo-Hybrid's do: the state's gap
+    is then the order of the sums alone."""
+    cell = toy_cell(manifest, CELL)
+    assert cell["config"]["torch_dtype"] == "float32" and cell["config"]["deployment"]["param_dtype"] == "bfloat16"
+    cell["config"]["deployment"]["engine"]["prefill_chunk"] = 32
+    cell["traffic"]["arrival"]["clients"] = 4  # the toy engine has 4 slots: as many clients as slots, as in the cell
+    result = bench_run.measure(
+        cell, seed=2**31 + 43, seconds=3.0, traced=False, t_process=time.monotonic(),
+        scratch=str(tmp_path / "scratch"), platform="cpu",
+    )
+    assert result["correct"] and result["failed"] == 0 and result["attempted"] > 0
+    gaps = result["notes"]["reference_gaps"]
+    assert len(gaps) == 2 and all(g["finite"] and g["max_gap"] <= cell["config"]["check"]["logit_gap_tol"] for g in gaps)
+    line = bench_run.build_line(manifest, result)
+    contract.validate(line, manifest, CELL, traced=False, platform="cpu")
+    assert set(line["metrics"]) == {"itl_p95_ms", "serve_tokens_per_s", "setup_s"}
+    counters = result["counters"]
+    assert counters["kv_pool_not_donated"] == 0 and counters["host_logit_rows"] == 0
+    assert counters["decode_steps_with_chunk"] == 0 and counters["decode_steps"] > 0
+    toy, costs = cell["config"], _part("costs")
+    groups = counters["kv_groups"]
+    assert set(groups) == {"full", "state"}
+    # float32 activations at the toy size: four bytes a cached value and a carried row's
+    assert groups["full"]["kv_token_bytes"] == costs.kv_bytes_per_token(toy, itemsize=4) == counters["kv_token_bytes"]
+    assert groups["state"]["bytes_per_slot"] == costs.state_bytes_per_slot(toy, itemsize=4) and groups["state"]["num_slots"] == 4
+    assert counters["state_resets"] == counters["admitted"] > 0
+    assert (counters["prefix_hit_blocks"], counters["prefix_miss_blocks"], counters["cached_blocks"]) == (0, 0, 0)
+    moe = counters["moe"]["decode"]
+    assert len(moe["assignments"]) == len(moe["assignments_all"]) == 6 and len(moe["assignments"][0]) == 64
+    share = registry.load_metric("per_layer", "moe_held_share_pct")(result)
+    assert 35.0 < share < 65.0  # half the experts are held; a few hundred rows' routing is not even
+    passes = registry.load_metric("per_layer", "prefill_pass_share_pct")(result)
+    assert 0.0 < passes < 100.0

@@ -487,12 +487,15 @@ def test_get_stats_carries_the_records_the_setup_and_the_proxys_stamps():
         deadline = time.monotonic() + 10
         while not dep.get_stats()["spans"]["requests"] and time.monotonic() < deadline:
             time.sleep(0.01)
-        got = dep.get_stats()
         # The pass that ended the request counts itself after it pushed the
-        # request's record: wait for it, so that the two reads below agree.
-        while got["iterations"] != dep.engine.stats()["iterations"] and time.monotonic() < deadline:
-            time.sleep(0.01)
+        # request's record: wait for it, so that the two reads below agree. The
+        # engine's read comes a while after the deployment's: two reads taken
+        # together agree also when neither has the count yet.
+        while True:
             got = dep.get_stats()
+            time.sleep(0.1)
+            if got["iterations"] == dep.engine.stats()["iterations"] or time.monotonic() > deadline:
+                break
     finally:
         dep.prepare_for_shutdown()
     spans = got["spans"]

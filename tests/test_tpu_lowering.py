@@ -382,7 +382,7 @@ def _cell_programs(cell_name: str):
 
     from benchmarks.harness import registry
     from ray_tpu.models.generate import (
-        MOE_CHOICE, MOE_COUNTS, init_moe_choice, init_moe_counts, init_paged_cache, ring_blocks,
+        MOE_CHOICE, MOE_COUNTS, init_moe_choice, init_moe_counts, init_paged_cache, ring_blocks, state_kind,
     )
     from ray_tpu.models.transformer import TransformerConfig, init_params
     from ray_tpu.serve.llm.engine import _ROW_TABLE, _STATE_COLS, _compiled_fns
@@ -395,7 +395,7 @@ def _cell_programs(cell_name: str):
     model.update(dtype=jnp.bfloat16, param_dtype=jnp.bfloat16)
     cfg = TransformerConfig(**model)
     slots, chunk, bs = engine["num_slots"], engine.get("prefill_chunk", 32), engine["block_size"]
-    linear = "linear" in cfg.layer_kinds  # a state group a slot, two columns of a program row for it, and no ring
+    linear = state_kind(cfg) is not None  # a state group a slot, two columns of a program row for it, and no ring
     ring = ring_blocks(cfg.sliding_window, chunk, bs) if "window" in cfg.layer_kinds else 0
     lead = ring + (_STATE_COLS if linear else 0)
 
@@ -429,6 +429,8 @@ _PROGRAMS_OF_PR_34 = {
     "glm8.rollout-long": ("cba2cf83a22dbe9cf5dcbe722bd4d53900cfa782", "70387e17a53cdd651ef0cd0d932cd22eff73366e"),
     # The configuration with a layer pattern, as PR 38's tree gives it (computed from a copy of that commit, PR 40).
     "trinity5.rollout-longctx": ("297552fcc1973bcb0eef322c5e9ae63a440b5d59", "8fcb09071f66108e85f14215daff025c5708d073"),
+    # The configuration with linear-attention layers, as PR 42's tree gives it (computed from a copy of that commit, PR 43).
+    "olmo16.longdoc-8k": ("de78f60b73b654d4eadbb63811fa2ff4b68953d9", "0fda9676a9cf4a725d3b4724104b7306aaa6c29a"),
 }
 
 
@@ -440,8 +442,10 @@ def test_a_configuration_without_a_layer_pattern_keeps_the_programs_it_had(cell_
     must get none of it: operation for operation the programs PR 34 built. PR 40
     gave the cached layer ``parts`` and the engine a step that carries a chunk:
     the two programs every configuration had, Trinity's too, are still the
-    parent's operation for operation. A PR that changes them on purpose
-    computes the new digests and says what moved."""
+    parent's operation for operation. PR 43 gave the cached layer blocks of a
+    single mixer, a third stack by kind and the experts a held share: Olmo-Hybrid's
+    two programs, scanned by kind as the new ones are, joined the table. A PR
+    that changes them on purpose computes the new digests and says what moved."""
     import hashlib
 
     decode, prefill, args = _cell_programs(cell_name)
@@ -543,3 +547,36 @@ def test_the_linear_pattern_programs_copy_neither_pool_nor_state_nor_a_periods_w
         # a full layer's view of 8 x 8192 tokens (0.54 GB, keys then values) or a chunk's scores (0.54 GB), and little else
         assert stats.temp_size_in_bytes < 0.8e9, stats.temp_size_in_bytes
         assert stats.temp_size_in_bytes + stats.argument_size_in_bytes < 15.75 * 2**30
+
+
+def test_the_single_mixer_programs_copy_neither_pool_nor_state_nor_the_expert_stacks(one_v5e_chip):
+    """Nemotron-3-Nano's decode program at the benchmark's widths and the widest
+    rung (2048 tokens) and its prefill chunk, compiled for the v5e (PR 43). The
+    experts' widths (2688, 1856) are none that ``jax.lax.ragged_dot``'s kernel
+    tiles (``moe.grouped_matmul_tiles``; on the chip it took 7.5 ms a matmul
+    where the bytes take 0.8): every held expert runs over every row as batched
+    matmuls, which read a block's two [64, ...] stacks where they lie. What
+    has been seen to fail on the way: held as a whole stack for a grouped
+    matmul, [6, 64, 2688, 1856], whose minor axis fills no lanes, is laid out on
+    the device with the model width minor-most and was copied, 3.8 GB, into the
+    kernel's layout in every step. The cached head axis of 2 (tiled (2, 128),
+    nothing padded), the float32 state and the words of the experts taken are
+    updated in place."""
+    import re
+
+    import jax
+
+    decode, prefill, args = _cell_programs("nemo14.chat-churn")
+    describe = lambda a: jax.tree.map(lambda x: jax.ShapeDtypeStruct(x.shape, x.dtype, sharding=one_v5e_chip), a)  # noqa: E731
+    pool_bytes = 2 * 2 * 8193 * 16 * 2 * 128 * 2 + 6 * 64 * (64 * 64 * 128 * 4 + 3 * 6144 * 2) + 2 * 6 * 8193 * 16 * 4
+    for program, given in ((decode, args(128)), (prefill, args(None))):
+        compiled = program.lower(*describe(given)).compile()
+        text = compiled.as_text()
+        assert "ragged-dot" not in text  # no grouped matmul at these widths
+        for leaf in ("bf16[2,8193,16,2,128]", "f32[6,64,64,64,128]", "s32[2,6,8193,16]", "bf16[6,64,2688,1856]", "bf16[6,64,1856,2688]"):
+            assert leaf in text and not re.search(rf"= {re.escape(leaf)}\S* copy\(", text), leaf
+        stats = compiled.memory_analysis()
+        assert stats.alias_size_in_bytes >= pool_bytes  # 1.09 GB updated in place
+        # a chunk's 0.17 GB, a step's 0.04: no block's experts (0.64 GB a stack's slice) are written anywhere
+        assert stats.temp_size_in_bytes < 0.3e9, stats.temp_size_in_bytes
+        assert stats.temp_size_in_bytes + stats.argument_size_in_bytes < 11e9  # 10.27 GB of arguments: 61 % of the chip's 16.9
