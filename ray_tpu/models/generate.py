@@ -22,7 +22,9 @@ rows are viewed, and an attention kind in what it writes and how it attends
 over the view: keys and values per KV head (GQA: ``_project_qkv``,
 ``_cache_attention``), or one latent and one rotary key a token (MLA:
 ``_project_latent``, ``_latent_attention``, in absorbed form: the cache is
-attended over as it lies, never expanded to per-head keys and values). Its
+attended over as it lies, never expanded to per-head keys and values; a
+decode step over a paged latent pool on a TPU does not gather a view either:
+``latent_kernel_reads``, ``_latent_attention_in_place``). Its
 math intentionally mirrors transformer._attention_block/_mlp_block on the same
 param pytree — decode diverges (cache writes, position masking) enough that
 sharing one function would tangle the training hot path. A layer's MLP is what
@@ -54,6 +56,8 @@ from ray_tpu.models.transformer import (
     _rms_norm,
     _rope,
 )
+from ray_tpu.ops import attention as _attention_ops
+from ray_tpu.ops.latent_attention import paged_latent_attention
 from ray_tpu.parallel.moe import grouped_matmul_tiles
 
 
@@ -263,9 +267,38 @@ def _latent_attention(lp, q, view, pos_mask, cfg):
     # be one more copy of it.
     o = jnp.einsum("bhqk,bkr->bhqr", p.astype(ckv.dtype), ckv,
                    preferred_element_type=jnp.float32)[..., :R].astype(q.dtype)
+    return _latent_values(lp, o, cfg)
+
+
+def _latent_values(lp, o, cfg):
+    """The heads' weighted sums of latents o [B, H, q, R] through W_uv: [B, q, H * v]."""
     _, w_uv = _latent_kv_up(lp, cfg)
     o = jnp.einsum("bhqr,rhv->bqhv", o, w_uv.astype(o.dtype))
     return o.reshape(*o.shape[:2], -1)
+
+
+def latent_kernel_reads(cfg: TransformerConfig, paged: bool, q: int) -> bool:
+    """Whether a call of the layer stack reads its cache through
+    ``ops/latent_attention.py``'s kernel, by what the code can see: latent
+    attention, a PAGED pool, one query a row, a TPU backend. Such a call walks
+    each row's block table to its length, so its cost does not grow with the
+    table's width: ``_cached_layers`` asks for the program, ``LLMEngine`` for
+    the table it hands a decode step (one width, one program), and the two
+    cannot disagree. Everything else (a prefill chunk, the dense cache, any
+    CPU run) keeps ``_paged_view`` + ``_latent_attention``."""
+    return bool(cfg.latent_attention) and paged and q == 1 and _attention_ops._on_tpu()
+
+
+def _latent_attention_in_place(lp, q, ckv, at, tables, positions, cfg):
+    """``_latent_attention`` of one query a row, q [B, 1, H, W] at
+    ``positions`` [B, 1], over layer ``at`` of the pool leaf ``ckv`` [L, N,
+    Bs, W] through ``tables`` [B, n_max], with no view: the kernel returns
+    what the ``bhqk,bkr->bhqr`` product does. A row whose table starts at the
+    null block (an inactive slot) reads nothing and attends to zeros."""
+    lengths = jnp.where(tables[:, 0] != 0, positions[:, 0] + 1, 0)
+    scale = (cfg.qk_nope_head_dim + cfg.qk_rope_head_dim) ** -0.5
+    o = paged_latent_attention(q, ckv, at, tables, lengths, sm_scale=scale)
+    return _latent_values(lp, o[..., : cfg.kv_lora_rank], cfg)
 
 
 def _swiglu(h, wg, wi, wo):
@@ -471,11 +504,13 @@ class _Access(NamedTuple):
     ``write(c, l, rows)`` puts a chunk's rows [B, q, ...] of one leaf into
     layer l of the group, ``view(c, l)`` takes the rows [B, S, ...] to attend
     over, ``key_pos`` [B, S] is the position each view row holds (None: row i
-    holds position i)."""
+    holds position i), ``tables`` [B, n_max] the block tables the view gathers
+    through (a paged pool's; None: a dense cache, a ring)."""
 
     write: Any
     view: Any
     key_pos: Any = None
+    tables: Any = None
 
 
 class _Part(NamedTuple):
@@ -715,14 +750,17 @@ def _cached_layers(params, x, cache, positions, access, cfg, key_len=None, valid
         else:
             pool = {**pool, **{name + sfx: acc.write(pool[name + sfx], at, row) for name, row in rows.items()}}
             with jax.named_scope("cache_attention" + (f"_{kind}" if kind else "")):
-                seen = {name: acc.view(pool[name + sfx], at) for name in rows}
-                n_keys = next(iter(seen.values())).shape[1]
-                window = 0 if kind == _FULL else cfg.sliding_window
-                mask = _cache_mask(positions, n_keys, window, key_len, acc.key_pos)
-                if latent:
-                    o = _latent_attention(lp, qh, seen, mask, cfg)
+                if latent_kernel_reads(cfg, acc.tables is not None, q):  # the rows just written are read where they lie
+                    o = _latent_attention_in_place(lp, qh, pool["ckv"], at, acc.tables, positions, cfg)
                 else:
-                    o = _cache_attention(qh, seen["k"], seen["v"], mask, cfg).reshape(B, q, -1)
+                    seen = {name: acc.view(pool[name + sfx], at) for name in rows}
+                    n_keys = next(iter(seen.values())).shape[1]
+                    window = 0 if kind == _FULL else cfg.sliding_window
+                    mask = _cache_mask(positions, n_keys, window, key_len, acc.key_pos)
+                    if latent:
+                        o = _latent_attention(lp, qh, seen, mask, cfg)
+                    else:
+                        o = _cache_attention(qh, seen["k"], seen["v"], mask, cfg).reshape(B, q, -1)
         if cfg.attn_gate:
             gate = _rms_norm(x, lp["attn_norm"], cfg.norm_eps) @ lp["wg_attn"].astype(x.dtype)
             o = o * jax.nn.sigmoid(gate)
@@ -1075,7 +1113,9 @@ def paged_decode_chunk_hidden(
     block_tables = jnp.asarray(block_tables, jnp.int32)
     x, positions = _embed_chunk(params, tokens, pos, cfg)
     block_size = cache["k" if "k" in cache else "ckv"].shape[2]
-    access = _Access(_paged_write(block_tables, positions, valid_to, block_size), _paged_view(block_tables))
+    access = _Access(
+        _paged_write(block_tables, positions, valid_to, block_size), _paged_view(block_tables), tables=block_tables
+    )
     if state_kind(cfg):
         B, q = positions.shape
         real = q if valid_to is None else jnp.clip(jnp.asarray(valid_to, jnp.int32) - pos, 0, q)

@@ -282,14 +282,21 @@ def test_the_dp4_cell_train_step_gathers_nothing_on_the_v5e_host(v5e_host):
     assert in_use < 14.3e9, in_use  # 14.242 GB, as before PR 36: 8.38 resident + 5.86 of temporaries
 
 
-def test_the_latent_decode_step_copies_neither_the_pool_nor_the_expert_stacks(one_v5e_chip):
+@pytest.mark.parametrize("reads", ["kernel", "view"])
+def test_the_latent_decode_step_copies_neither_the_pool_nor_the_expert_stacks(one_v5e_chip, reads, monkeypatch):
     """GLM-4.7-Flash's decode program at the benchmark's widths, compiled for
     the v5e (PR 32). Two things the compiler did to its first versions, each
     worth milliseconds a step and invisible on the CPU: with a 576-wide row the
     pool got a layout of its own and was copied in and out of the layer scan
     (the row is padded to 640 for that); and the scan's slice of a layer's
     [64, 2048, 1536] expert matrices was materialised before the grouped
-    matmul (the stacks stay whole for that, a layer is a group offset)."""
+    matmul (the stacks stay whole for that, a layer is a group offset).
+
+    ``kernel`` (PR 44): the program a TPU backend gets, at the whole table's
+    width, the pool read in place by ``ops/latent_attention.py`` (here the
+    backend is the CPU's, so the test says what the predicate would see there);
+    ``view``: the program of every other backend, at the 1024-token rung."""
+    import importlib
     import re
 
     import jax
@@ -307,6 +314,12 @@ def test_the_latent_decode_step_copies_neither_the_pool_nor_the_expert_stacks(on
     )
     model.update(dtype=jnp.bfloat16, param_dtype=jnp.bfloat16)
     cfg = TransformerConfig(**model)
+    width = 64
+    if reads == "kernel":
+        for module in ("ray_tpu.ops.attention", "ray_tpu.ops.latent_attention"):  # the predicate's, and the kernel's "compiled, not interpreted"
+            monkeypatch.setattr(importlib.import_module(module), "_on_tpu", lambda: True)
+        monkeypatch.setattr(importlib.import_module("ray_tpu.serve.llm.engine"), "_JIT_CACHE", {})
+        width = -(-engine["max_model_len"] // engine["block_size"])
 
     def described(tree):
         return jax.tree.map(lambda a: jax.ShapeDtypeStruct(a.shape, a.dtype, sharding=one_v5e_chip), tree)
@@ -316,18 +329,23 @@ def test_the_latent_decode_step_copies_neither_the_pool_nor_the_expert_stacks(on
         return {**init_paged_cache(cfg, *blocks), MOE_COUNTS: init_moe_counts(cfg), MOE_CHOICE: init_moe_choice(cfg, *blocks)}
 
     params = described(jax.eval_shape(lambda: init_params(jax.random.PRNGKey(0), cfg)))
-    rows = jax.ShapeDtypeStruct((engine["num_slots"], _ROW_TABLE + 64), jnp.int32, sharding=one_v5e_chip)
+    rows = jax.ShapeDtypeStruct((engine["num_slots"], _ROW_TABLE + width), jnp.int32, sharding=one_v5e_chip)
     ids = jax.ShapeDtypeStruct((engine["num_slots"],), jnp.int32, sharding=one_v5e_chip)
     compiled = _compiled_fns(cfg)[0].lower(params, rows, described(jax.eval_shape(pool)), ids).compile()
     text = compiled.as_text()
     assert "ragged-dot" in text  # the grouped matmul is the TPU's own, not a dense fallback
+    # The kernel's one result is the array the weighted sum over the view returned
+    # (the benchmark's ``trace_ops.latent_attention`` finds either by that shape), and no view is gathered.
+    assert bool(re.search(r"= bf16\[32,20,1,640\]\S* custom-call\(.*tpu_custom_call", text)) == (reads == "kernel")
+    assert bool(re.search(r"= bf16\[\d+,16,640\]\S* fusion\(", text)) == (reads == "view")
     pool_shape = re.escape("bf16[8,8193,16,640]")
     assert not re.search(rf"= {pool_shape}\S* copy\(", text)
     assert not re.search(r"= bf16\[64,(2048,1536|1536,2048)\]\S* fusion\(", text)
     assert not re.search(r"= s32\[7,8193,16\]\S* copy\(", text)  # nor the words of the experts taken
-    # 32 rows at the 1024-token rung: the view (42 MB) and little else, not a pool (1.34 GB) or an expert matrix (0.4 GB)
+    # 32 rows at the 1024-token rung: the view (42 MB) and little else, not a pool (1.34 GB) or an expert matrix (0.4 GB);
+    # through the kernel not even the view
     stats = compiled.memory_analysis()
-    assert stats.temp_size_in_bytes < 150e6, stats.temp_size_in_bytes
+    assert stats.temp_size_in_bytes < (100e6 if reads == "kernel" else 150e6), stats.temp_size_in_bytes
     assert stats.alias_size_in_bytes >= 8 * 8193 * 16 * 640 * 2 + 7 * 8193 * 16 * 4  # the pool is updated in place
 
 
