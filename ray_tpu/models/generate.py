@@ -24,7 +24,9 @@ over the view: keys and values per KV head (GQA: ``_project_qkv``,
 ``_project_latent``, ``_latent_attention``, in absorbed form: the cache is
 attended over as it lies, never expanded to per-head keys and values; a
 decode step over a paged latent pool on a TPU does not gather a view either:
-``latent_kernel_reads``, ``_latent_attention_in_place``). Its
+``latent_kernel_reads``, ``_latent_attention_in_place``; nor does one over a
+paged pool of one group of keys and values: ``kv_kernel_reads``,
+``_cache_attention_in_place``). Its
 math intentionally mirrors transformer._attention_block/_mlp_block on the same
 param pytree — decode diverges (cache writes, position masking) enough that
 sharing one function would tangle the training hot path. A layer's MLP is what
@@ -58,6 +60,7 @@ from ray_tpu.models.transformer import (
 )
 from ray_tpu.ops import attention as _attention_ops
 from ray_tpu.ops.latent_attention import paged_latent_attention
+from ray_tpu.ops.paged_attention import paged_attention
 from ray_tpu.parallel.moe import grouped_matmul_tiles
 
 
@@ -277,6 +280,14 @@ def _latent_values(lp, o, cfg):
     return o.reshape(*o.shape[:2], -1)
 
 
+def _reads_in_place(paged: bool, q: int) -> bool:
+    """A PAGED pool, one query a row, a TPU backend: where a kernel can walk
+    each row's block table to the row's length (``latent_kernel_reads``,
+    ``kv_kernel_reads``). Everything else (a prefill chunk, the dense cache,
+    any CPU run) gathers ``_paged_view`` and attends over it."""
+    return paged and q == 1 and _attention_ops._on_tpu()
+
+
 def latent_kernel_reads(cfg: TransformerConfig, paged: bool, q: int) -> bool:
     """Whether a call of the layer stack reads its cache through
     ``ops/latent_attention.py``'s kernel, by what the code can see: latent
@@ -284,20 +295,32 @@ def latent_kernel_reads(cfg: TransformerConfig, paged: bool, q: int) -> bool:
     each row's block table to its length, so its cost does not grow with the
     table's width: ``_cached_layers`` asks for the program, ``LLMEngine`` for
     the table it hands a decode step (one width, one program), and the two
-    cannot disagree. Everything else (a prefill chunk, the dense cache, any
-    CPU run) keeps ``_paged_view`` + ``_latent_attention``."""
-    return bool(cfg.latent_attention) and paged and q == 1 and _attention_ops._on_tpu()
+    cannot disagree."""
+    return bool(cfg.latent_attention) and _reads_in_place(paged, q)
+
+
+def kv_kernel_reads(cfg: TransformerConfig, paged: bool, q: int) -> bool:
+    """The same of a pool of keys and values and ``ops/paged_attention.py``'s
+    kernel: standard attention over ONE group of ``k`` / ``v`` leaves (no layer
+    pattern: the condition ``paged_decode_step_with_chunk`` states too). A
+    pattern's full layers and rings keep the view."""
+    return not cfg.latent_attention and not cfg.layer_kinds and _reads_in_place(paged, q)
+
+
+def _decode_lengths(tables, positions):
+    """Rows a decode row's query at ``positions`` [B, 1] sees through
+    ``tables`` [B, n_max]: its own and those before it; of a row whose table
+    starts at the null block (an inactive slot) none, and it attends to zeros."""
+    return jnp.where(tables[:, 0] != 0, positions[:, 0] + 1, 0)
 
 
 def _latent_attention_in_place(lp, q, ckv, at, tables, positions, cfg):
     """``_latent_attention`` of one query a row, q [B, 1, H, W] at
     ``positions`` [B, 1], over layer ``at`` of the pool leaf ``ckv`` [L, N,
     Bs, W] through ``tables`` [B, n_max], with no view: the kernel returns
-    what the ``bhqk,bkr->bhqr`` product does. A row whose table starts at the
-    null block (an inactive slot) reads nothing and attends to zeros."""
-    lengths = jnp.where(tables[:, 0] != 0, positions[:, 0] + 1, 0)
+    what the ``bhqk,bkr->bhqr`` product does (``_decode_lengths``)."""
     scale = (cfg.qk_nope_head_dim + cfg.qk_rope_head_dim) ** -0.5
-    o = paged_latent_attention(q, ckv, at, tables, lengths, sm_scale=scale)
+    o = paged_latent_attention(q, ckv, at, tables, _decode_lengths(tables, positions), sm_scale=scale)
     return _latent_values(lp, o[..., : cfg.kv_lora_rank], cfg)
 
 
@@ -395,6 +418,19 @@ def _cache_attention(q, ck, cv, pos_mask, cfg):
     o = o / jnp.moveaxis(jnp.sum(e, axis=-1), 1, 2)[..., None]
     # Without the added rows, and without the zero heads a cached row may carry (``_cache_heads``).
     return o[:, :T, : cfg.n_heads].astype(q.dtype)
+
+
+def _cache_attention_in_place(q, k, v, at, tables, positions, cfg):
+    """``_cache_attention`` of one query a row, q [B, 1, H, Dh] at
+    ``positions`` [B, 1], over layer ``at`` of the pool leaves ``k``, ``v``
+    [L, N, Bs, KV, Dh] through ``tables`` [B, n_max], with no view and under
+    ``_cache_mask``'s mask (``_decode_lengths``; the sliding window, where a
+    row the table can hold may outgrow it: Mistral's 4096 over 2560 tokens is none)."""
+    window = cfg.sliding_window if cfg.sliding_window < tables.shape[1] * k.shape[2] else 0
+    o = paged_attention(
+        q, k, v, at, tables, _decode_lengths(tables, positions), sm_scale=cfg.head_dim ** -0.5, window=window
+    )
+    return o[:, :, : cfg.n_heads]  # without the zero heads a cached row may carry (``_cache_heads``)
 
 
 def _embed_chunk(params, tokens, pos, cfg):
@@ -664,7 +700,9 @@ def _cached_layers(params, x, cache, positions, access, cfg, key_len=None, valid
     (``_Part``). The matmuls of a layer, which read the weights, run once over
     all n rows; only the cache's write, view, mask and attention run a part
     at a time, each as its own [B, q], and their outputs are laid side by
-    side again. Carried for one group of key and value leaves only.
+    side again (every part's rows are written first; a part of decode rows
+    then reads the pool in place where ``kv_kernel_reads`` says so, the chunk
+    its own gathered view). Carried for one group of key and value leaves only.
 
     The whole cache rides the layer scan as its CARRY (never xs -> ys, which
     are distinct buffers of the loop) and a layer reaches its part through
@@ -702,9 +740,13 @@ def _cached_layers(params, x, cache, positions, access, cfg, key_len=None, valid
         out = []
         with jax.named_scope("cache_attention"):
             for part in parts:
-                ck, cv = (part.access.view(pool[name], at) for name in ("k", "v"))
-                mask = _cache_mask(part.positions, ck.shape[1], cfg.sliding_window, None, part.access.key_pos)
-                o = _cache_attention(of(part, qh), ck, cv, mask, cfg)  # the query heads: not the zero heads a cached row may carry
+                acc = part.access
+                if kv_kernel_reads(cfg, acc.tables is not None, part.positions.shape[1]):  # the decode rows, in place
+                    o = _cache_attention_in_place(of(part, qh), pool["k"], pool["v"], at, acc.tables, part.positions, cfg)
+                else:
+                    ck, cv = (acc.view(pool[name], at) for name in ("k", "v"))
+                    mask = _cache_mask(part.positions, ck.shape[1], cfg.sliding_window, None, acc.key_pos)
+                    o = _cache_attention(of(part, qh), ck, cv, mask, cfg)  # the query heads: not the zero heads a cached row may carry
                 out.append(o.reshape(1, -1, o.shape[2] * o.shape[3]))
         return jnp.concatenate(out, axis=1), pool
 
@@ -752,6 +794,8 @@ def _cached_layers(params, x, cache, positions, access, cfg, key_len=None, valid
             with jax.named_scope("cache_attention" + (f"_{kind}" if kind else "")):
                 if latent_kernel_reads(cfg, acc.tables is not None, q):  # the rows just written are read where they lie
                     o = _latent_attention_in_place(lp, qh, pool["ckv"], at, acc.tables, positions, cfg)
+                elif kv_kernel_reads(cfg, acc.tables is not None, q):
+                    o = _cache_attention_in_place(qh, pool["k"], pool["v"], at, acc.tables, positions, cfg).reshape(B, q, -1)
                 else:
                     seen = {name: acc.view(pool[name + sfx], at) for name in rows}
                     n_keys = next(iter(seen.values())).shape[1]
@@ -1193,7 +1237,8 @@ def paged_decode_step_with_chunk(
     read once where the two calls read them twice. Each part writes and views
     the pool through its own table and is masked by its own positions
     (``_cached_layers``' ``parts``), so a row's arithmetic is that of the call
-    it would have been in. Returns (final normed hidden states [S + q, D],
+    it would have been in (on a TPU the decode rows read the pool in place,
+    as ``paged_decode_step``'s do: ``kv_kernel_reads``). Returns (final normed hidden states [S + q, D],
     the S decode rows first, and the cache).
 
     For a cache of one group of key and value leaves. Not carried: a latent
@@ -1207,8 +1252,10 @@ def paged_decode_step_with_chunk(
     x_chunk, at_chunk = _embed_chunk(params, chunk_tokens, jnp.asarray(chunk_pos, jnp.int32), cfg)
     block_tables, chunk_tables = jnp.asarray(block_tables, jnp.int32), jnp.asarray(chunk_tables, jnp.int32)
     parts = (
-        _Part(0, at_step, _Access(_paged_write(block_tables, at_step, None, block_size), _paged_view(block_tables))),
-        _Part(S, at_chunk, _Access(_paged_write(chunk_tables, at_chunk, valid_to, block_size), _paged_view(chunk_tables))),
+        _Part(0, at_step, _Access(
+            _paged_write(block_tables, at_step, None, block_size), _paged_view(block_tables), tables=block_tables)),
+        _Part(S, at_chunk, _Access(
+            _paged_write(chunk_tables, at_chunk, valid_to, block_size), _paged_view(chunk_tables), tables=chunk_tables)),
     )
     x = jnp.concatenate([x_step.reshape(1, S, -1), x_chunk], axis=1)
     positions = jnp.concatenate([at_step.reshape(1, S), at_chunk], axis=1)

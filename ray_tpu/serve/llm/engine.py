@@ -16,10 +16,12 @@ vLLM-lineage iteration-level scheduler on top of the paged KV cache:
   (16, 32, 64, ... blocks, then ``n_max``) that covers the longest running
   row. One program a rung, every one built in ``__init__``: a replica that
   reports ready compiles nothing more. The prefill chunk keeps ``n_max``.
-  A latent pool on a TPU has no ladder: its decode step walks each row's
-  table to the row's length in a kernel (``ops/latent_attention.py``), so it
+  A latent pool on a TPU, and a pool of one group of key and value leaves,
+  has no ladder: its decode step walks each row's table to the row's length
+  in a kernel (``ops/latent_attention.py``, ``ops/paged_attention.py``), so it
   is handed the whole table and there is one decode program
-  (``generate.latent_kernel_reads``; ``stats()["latent_kernel_steps"]``).
+  (``generate.latent_kernel_reads`` / ``kv_kernel_reads``;
+  ``stats()["latent_kernel_steps"]`` / ``["kv_kernel_steps"]``).
 - **paged KV cache**: ``init_paged_cache`` block pool + per-sequence block
   tables with a host-side free-list. Block 0 is the reserved null block
   (inactive slots and write-masked padding rows land there). The pool is
@@ -531,6 +533,7 @@ class LLMEngine:
             init_moe_choice,
             init_moe_counts,
             init_paged_cache,
+            kv_kernel_reads,
             latent_kernel_reads,
             ring_blocks,
             state_slot_bytes,
@@ -570,11 +573,13 @@ class LLMEngine:
         self.block_size = int(block_size)
         self.max_model_len = int(max_model_len or cfg.max_seq_len)
         self.n_max = -(-self.max_model_len // self.block_size)  # blocks/seq
-        # A decode step that reads a latent pool through the kernel
-        # (``generate.latent_kernel_reads``) stops at each row's length
-        # whatever the table's width: the whole table, one program, no ladder.
+        # A decode step that reads its pool through a kernel (a latent pool:
+        # ``generate.latent_kernel_reads``; one group of key and value leaves:
+        # ``kv_kernel_reads``) stops at each row's length whatever the table's
+        # width: the whole table, one program, no ladder.
         self._latent_kernel = latent_kernel_reads(cfg, paged=True, q=1)
-        self._view_rungs = (self.n_max,) if self._latent_kernel else _view_rungs(self.n_max)
+        self._kv_kernel = kv_kernel_reads(cfg, paged=True, q=1)
+        self._view_rungs = (self.n_max,) if self._latent_kernel or self._kv_kernel else _view_rungs(self.n_max)
         # Decode steps run at each width, for stats()["decode_width_steps"].
         self._width_steps = {w: 0 for w in self._view_rungs}
         # Default pool: every slot can run to max_model_len (+1 null block)
@@ -690,7 +695,9 @@ class LLMEngine:
             "decode_rows_dropped": 0,
             # Those dispatched to the program that reads the latent pool
             # through its kernel (``_latent_kernel``): all of them or none.
+            # And the same of a pool of keys and values (``_kv_kernel``).
             "latent_kernel_steps": 0,
+            "kv_kernel_steps": 0,
             # Tokens the prefill chunks carried that were real, and the
             # padding behind a prompt's last ones: what the fixed chunk wastes.
             "chunk_tokens_valid": 0,
@@ -1678,7 +1685,11 @@ class LLMEngine:
         interpreter is one: PERF.md, PR 40), while a pass rounded up to a
         wider view pays only that view's gather, always less than the second
         read of the weights it saves. Past that rung (``_rides``) a pass
-        keeps the two programs."""
+        keeps the two programs. Where a kernel reads the decode rows' pool in
+        place (``_kv_kernel``) the ladder IS one rung, the whole table: the
+        rows' part costs what the rows hold, only the chunk's one table is
+        gathered (0.4 ms at Mistral-16's 2560 tokens, 0.1 more than at 2048),
+        and every pass with rows and a chunk rides (PERF.md, PR 45)."""
         return (
             self.role != "prefill"
             and self.num_slots + self.prefill_chunk <= _MXU_TILE_ROWS
@@ -1899,6 +1910,7 @@ class LLMEngine:
         self._counts["decode_steps"] += 1
         self._counts["decode_steps_run_ahead"] += ahead_of is not None
         self._counts["latent_kernel_steps"] += self._latent_kernel
+        self._counts["kv_kernel_steps"] += self._kv_kernel
         with spans.span("llm.decode.dispatch"):
             ids = self._run_donated(self._decode_fn if chunk is None else self._fused_fn, *inputs)
         if chunk is not None and self._chunk_dispatched(chunk):

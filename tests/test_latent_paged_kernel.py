@@ -164,15 +164,45 @@ def test_a_latent_pool_has_one_decode_program_where_the_kernel_reads_it(model, m
     assert stats["kv_pool_not_donated"] == 0
 
 
-def test_only_a_latent_pool_loses_its_ladder(monkeypatch):
-    """A K/V pool on a TPU keeps its rungs: the predicate is about the pool's kind."""
+@pytest.mark.parametrize("kind", ["one_group", "layer_pattern"])
+def test_only_a_latent_pool_loses_its_ladder(kind, monkeypatch):
+    """Restated in PR 45: on a TPU a pool of ONE group of key and value leaves
+    loses its ladder too (``kv_kernel_reads``, tests/test_paged_kv_kernel.py).
+    A layer pattern's pool keeps it: the predicates are about the pool's kind,
+    and its decode program, lowered for the TPU, is text for text the one it
+    gets where no kernel reads anything (the parent's: tests/test_tpu_lowering.py
+    holds the cells' to their hashes)."""
     import importlib
 
+    import jax
     import jax.numpy as jnp
 
-    from ray_tpu.models.transformer import TransformerConfig
+    from ray_tpu.models.generate import init_paged_cache, ring_blocks
+    from ray_tpu.models.transformer import TransformerConfig, init_params
 
     generate = importlib.import_module("ray_tpu.models.generate")
+    engine = importlib.import_module("ray_tpu.serve.llm.engine")
+    over = dict(n_layers=4, sliding_window=24, layer_kinds=("window", "window", "window", "full")) if kind == "layer_pattern" else {}
+    cfg = TransformerConfig(**{**dict(vocab_size=64, d_model=32, n_layers=1, n_heads=2, n_kv_heads=2, d_ff=64, max_seq_len=64, dtype=jnp.float32), **over})
+    slots, bs, chunk, n_max = 3, 4, 8, 16
+    ring = ring_blocks(cfg.sliding_window, chunk, bs) if cfg.layer_kinds else 0
+
+    def lowered():
+        monkeypatch.setattr(engine, "_JIT_CACHE", {})
+        pool = jax.eval_shape(lambda: init_paged_cache(cfg, 49, bs, window_blocks=slots * ring + 1 if ring else 0))
+        params = jax.eval_shape(lambda: init_params(jax.random.PRNGKey(0), cfg))
+        ints = lambda *shape: jax.ShapeDtypeStruct(shape, jnp.int32)  # noqa: E731
+        args = params, ints(slots, engine._ROW_TABLE + ring + n_max), pool, ints(slots)
+        return engine._compiled_fns(cfg, ring)[0].trace(*args).lower(lowering_platforms=("tpu",)).as_text()
+
+    view = lowered()
     monkeypatch.setattr(generate._attention_ops, "_on_tpu", lambda: True)
-    dense = TransformerConfig(vocab_size=64, d_model=32, n_layers=1, n_heads=2, n_kv_heads=2, d_ff=64, max_seq_len=64, dtype=jnp.float32)
-    assert not generate.latent_kernel_reads(dense, paged=True, q=1)
+    assert not generate.latent_kernel_reads(cfg, paged=True, q=1)
+    assert generate.kv_kernel_reads(cfg, paged=True, q=1) == (kind == "one_group")
+    assert (lowered() == view) == (kind == "layer_pattern")
+    params = init_params(jax.random.PRNGKey(0), cfg)
+    eng = engine.LLMEngine(params, cfg, num_slots=slots, block_size=bs, max_model_len=n_max * bs * 2, prefill_chunk=chunk)
+    try:
+        assert eng._view_rungs == ((2 * n_max,) if kind == "one_group" else (16, 2 * n_max))
+    finally:
+        eng.shutdown()

@@ -449,6 +449,8 @@ _PROGRAMS_OF_PR_34 = {
     "trinity5.rollout-longctx": ("297552fcc1973bcb0eef322c5e9ae63a440b5d59", "8fcb09071f66108e85f14215daff025c5708d073"),
     # The configuration with linear-attention layers, as PR 42's tree gives it (computed from a copy of that commit, PR 43).
     "olmo16.longdoc-8k": ("de78f60b73b654d4eadbb63811fa2ff4b68953d9", "0fda9676a9cf4a725d3b4724104b7306aaa6c29a"),
+    # The configuration of single-mixer blocks, as PR 44's tree gives it (computed from a copy of that commit, PR 45).
+    "nemo14.chat-churn": ("9c5e797dc713f995bdbfed486a2c0bd65e5c5c2b", "6523618bc968aac74fe8215876f17a2e71f772e0"),
 }
 
 
@@ -462,7 +464,11 @@ def test_a_configuration_without_a_layer_pattern_keeps_the_programs_it_had(cell_
     the two programs every configuration had, Trinity's too, are still the
     parent's operation for operation. PR 43 gave the cached layer blocks of a
     single mixer, a third stack by kind and the experts a held share: Olmo-Hybrid's
-    two programs, scanned by kind as the new ones are, joined the table. A PR
+    two programs, scanned by kind as the new ones are, joined the table. PR 45
+    gave a pool of one group of key and value leaves a kernel where a TPU
+    decodes: here (a CPU backend: the view) Mistral's programs are still PR
+    34's, and the pattern pools', Nemotron's joined to them, whatever the
+    backend (tests/test_latent_paged_kernel.py). A PR
     that changes them on purpose computes the new digests and says what moved."""
     import hashlib
 
@@ -503,6 +509,42 @@ def test_the_step_with_a_chunk_reads_the_weights_once_and_updates_the_pool_in_pl
     stats = compiled.memory_analysis()
     assert stats.alias_size_in_bytes >= 2 * 16 * 2561 * 16 * 8 * 128 * 2  # 2.69 GB updated in place
     assert stats.temp_size_in_bytes < 60e6, stats.temp_size_in_bytes  # 35 MB; the bare step's 3.4
+
+
+@pytest.mark.parametrize("program", ["step", "step_with_chunk"])
+def test_the_kv_decode_programs_read_the_pool_in_place_on_a_tpu(one_v5e_chip, program, monkeypatch):
+    """Mistral-16's two decode programs as a TPU backend gets them (PR 45), at
+    the benchmark's widths and the whole table (160 blocks), compiled for the
+    v5e: ``ops/paged_attention.py``'s kernel reads the decode rows' keys and
+    values where they lie (one custom call, its result ``[slots, KV, group,
+    Dh]``), no ``[rows, 16, 8, 128]`` view is gathered for them (the step with
+    a chunk gathers its chunk's one table and nothing else), the pool is
+    aliased and never copied, and the temporaries stay megabytes."""
+    import importlib
+    import re
+
+    import jax
+
+    for module in ("ray_tpu.ops.attention", "ray_tpu.ops.paged_attention"):  # the predicate's, and the kernel's "compiled, not interpreted"
+        monkeypatch.setattr(importlib.import_module(module), "_on_tpu", lambda: True)
+    engine = importlib.import_module("ray_tpu.serve.llm.engine")
+    monkeypatch.setattr(engine, "_JIT_CACHE", {})
+    _, _, args = _cell_programs("serve16.chat-open")
+    (fns,) = engine._JIT_CACHE.values()
+    with_chunk = program == "step_with_chunk"
+    described = jax.tree.map(
+        lambda a: jax.ShapeDtypeStruct(a.shape, a.dtype, sharding=one_v5e_chip), args(160, with_chunk=with_chunk)
+    )
+    compiled = fns[2 if with_chunk else 0].lower(*described).compile()
+    text = compiled.as_text()
+    assert re.search(r"HloModule (\S+?),", text).group(1).startswith("jit__lambda")
+    assert re.search(r"= bf16\[16,8,4,128\]\S* custom-call\(.*tpu_custom_call", text)
+    gathered = set(re.findall(r"= bf16\[(\d+),16,8,128\]\S* fusion\(", text))
+    assert gathered == ({"160"} if with_chunk else set()), gathered
+    assert not re.search(rf"= {re.escape('bf16[16,2561,16,8,128]')}\S* copy\(", text)
+    stats = compiled.memory_analysis()
+    assert stats.alias_size_in_bytes >= 2 * 16 * 2561 * 16 * 8 * 128 * 2  # 2.69 GB updated in place
+    assert stats.temp_size_in_bytes < (60e6 if with_chunk else 10e6), stats.temp_size_in_bytes
 
 
 def test_the_pattern_decode_step_gathers_rings_and_copies_neither_pools_nor_experts(one_v5e_chip):
