@@ -23,10 +23,12 @@ over the view: keys and values per KV head (GQA: ``_project_qkv``,
 ``_cache_attention``), or one latent and one rotary key a token (MLA:
 ``_project_latent``, ``_latent_attention``, in absorbed form: the cache is
 attended over as it lies, never expanded to per-head keys and values; a
-decode step over a paged latent pool on a TPU does not gather a view either:
-``latent_kernel_reads``, ``_latent_attention_in_place``; nor does one over a
-paged pool of one group of keys and values: ``kv_kernel_reads``,
-``_cache_attention_in_place``). Its
+decode step over a paged latent pool on a TPU does not gather a view either,
+nor does one over a paged pool of one group of keys and values:
+``kernel_reads``, ``_latent_attention_in_place``,
+``_cache_attention_in_place``). Which kinds of layer a configuration has, in
+which stack of ``params`` each lies and what each keeps in a cache is one
+table: ``_KINDS``, ``_layer_plan``. Its
 math intentionally mirrors transformer._attention_block/_mlp_block on the same
 param pytree — decode diverges (cache writes, position masking) enough that
 sharing one function would tangle the training hot path. A layer's MLP is what
@@ -54,6 +56,7 @@ from ray_tpu.models.transformer import (
     MAMBA_LAYERS,
     TransformerConfig,
     _logits,
+    _layer_stacks,
     _period,
     _rms_norm,
     _rope,
@@ -110,29 +113,90 @@ def _cache_rows(cfg: TransformerConfig) -> dict:
 
 _WINDOW, _FULL, _LINEAR, _MAMBA, _EXPERTS = "window", "full", "linear", "mamba", "experts"
 
+# THE table of kinds: kind of layer (None: every layer of a model without a
+# pattern) -> (the suffix of the names of its group of cache leaves; how a call
+# reaches the group). ``"table"``: a row's block table (a dense cache: the row
+# itself), growing with the row, leaves ``_cache_rows``; ``"ring"``: the same
+# leaves, named ``k_win`` / ``v_win``, of which a paged row holds no more than
+# a ring (``_ring_access``); ``"state"``: no token's rows, one recurrent state a
+# serving slot (``state_rows``, ``_StateAccess``); None: the kind caches nothing.
+# A new kind is a row here, its stack in ``transformer._layer_stacks``, its
+# mixer, its ``state_rows`` and its branch of ``_cached_layers``' ``run_layer``.
+_KINDS = {
+    None: ("", "table"), _FULL: ("", "table"), _WINDOW: ("_win", "ring"),
+    _LINEAR: ("", "state"), _MAMBA: ("", "state"), _EXPERTS: (None, None),
+}
+
+
+class _Kind(NamedTuple):
+    """What the layer stack knows of one kind of a configuration's layers: the
+    ``params`` key of the stack that holds them; whether that stack is the
+    kind's ``own`` (a layer at its rank among its kind, which is its index into
+    its group of cache leaves too) or shared (a layer at its position); how
+    many ``layers`` of the kind the model has, the depth of its group; the
+    group's suffix and how it is reached (``_KINDS``)."""
+
+    stack: str
+    own: bool
+    layers: int
+    group: Any
+    reach: Any
+
+
+class _Segment(NamedTuple):
+    """Layers ``first .. first + depth`` of the model, which run as one scan:
+    their ``kinds`` in order (``()`` without a pattern) and each kind's row."""
+
+    first: int
+    depth: int
+    kinds: tuple
+    rows: dict
+
+
+def _layer_plan(cfg: TransformerConfig) -> list:
+    """The segments of the layer stack in the order the model runs them, from
+    ``transformer._layer_stacks``: stacks that each hold one kind are ONE
+    segment of all the layers, the kinds interleaved as ``layer_kinds`` says;
+    any other stack (the leading dense layers, then the rest) is a segment of
+    its own, its kinds sharing it."""
+    stacks = _layer_stacks(cfg)
+    layers = lambda kind: cfg.layer_kinds.count(kind) if kind else cfg.n_layers  # noqa: E731
+    own = {of.kind: _Kind(name, True, layers(of.kind), *_KINDS[of.kind]) for name, of in stacks.items() if of.kind}
+    if own:
+        return [_Segment(0, cfg.n_layers, cfg.layer_kinds, own)]
+    plan, first = [], 0
+    for name, of in stacks.items():
+        kinds = cfg.layer_kinds[first : first + of.depth]
+        rows = {kind: _Kind(name, False, layers(kind), *_KINDS[kind]) for kind in dict.fromkeys(kinds or (None,))}
+        plan.append(_Segment(first, of.depth, kinds, rows))
+        first += of.depth
+    return plan
+
+
+def _kinds(cfg: TransformerConfig) -> dict:
+    """Kind -> its row of ``_layer_plan``, over all segments."""
+    return {kind: row for segment in _layer_plan(cfg) for kind, row in segment.rows.items()}
+
+
+def _group_rows(cfg: TransformerConfig, row: _Kind) -> dict:
+    """What one layer of a kind's group of cache leaves holds, leaf name ->
+    (trailing shape, dtype): a token's rows, or a slot's state."""
+    if row.reach == "state":
+        return state_rows(cfg)
+    return {name + row.group: (shape, cfg.dtype) for name, shape in _cache_rows(cfg).items()}
+
+
+def pool_reach(cfg: TransformerConfig) -> set:
+    """The ways this configuration's groups of cache leaves are reached
+    (``_KINDS``): what a paged call must be handed beside the block table, a
+    ring a row (``"ring"``) or a slot a row (``"state"``)."""
+    return {row.reach for row in _kinds(cfg).values() if row.reach}
+
 
 def state_kind(cfg: TransformerConfig):
     """The kind of layer whose recurrent state a serving slot keeps
     (``state_rows``): ``"linear"``, ``"mamba"``, or None without one."""
-    return next((kind for kind in (_LINEAR, _MAMBA) if kind in cfg.layer_kinds), None)
-
-
-def _group_suffix(kind) -> str:
-    """A pool leaf of the window layers' group is named ``k_win`` / ``v_win``;
-    the full layers', and every layer's without a pattern, ``k`` / ``v``."""
-    return "_win" if kind == _WINDOW else ""
-
-
-def _cache_groups(cfg: TransformerConfig) -> dict:
-    """Kind -> layers of each group of pool leaves: one group (kind None) of
-    all layers, or under a layer pattern the full layers and the window
-    layers apart, each indexed by a layer's rank among its kind."""
-    if not cfg.layer_kinds:
-        return {None: cfg.n_layers}
-    # Beside layers that keep a state (which hold no token's rows: ``state_rows``) and among
-    # single-mixer blocks only full layers hold any.
-    kinds = (_FULL,) if state_kind(cfg) or cfg.single_mixer else (_FULL, _WINDOW)
-    return {kind: cfg.layer_kinds.count(kind) for kind in kinds}
+    return next((kind for kind, row in _kinds(cfg).items() if row.reach == "state"), None)
 
 
 def state_rows(cfg: TransformerConfig) -> dict:
@@ -158,45 +222,47 @@ def state_rows(cfg: TransformerConfig) -> dict:
     }
 
 
+def _group_bytes(cfg: TransformerConfig, row: _Kind) -> int:
+    """Bytes of a kind's group, all its layers, a token or (a state) a slot."""
+    return row.layers * sum(math.prod(shape) * jnp.dtype(dtype).itemsize for shape, dtype in _group_rows(cfg, row).values())
+
+
 def state_slot_bytes(cfg: TransformerConfig) -> int:
-    """Bytes one slot holds in the linear layers' group of cache leaves, all
+    """Bytes one slot holds in the group of the layers that keep a state, all
     its layers: beside ``cache_token_bytes``, which grows with a row, this does not."""
-    row = sum(math.prod(shape) * jnp.dtype(dtype).itemsize for shape, dtype in state_rows(cfg).values())
-    return cfg.layer_kinds.count(state_kind(cfg)) * row
+    return sum(_group_bytes(cfg, row) for row in _kinds(cfg).values() if row.reach == "state")
 
 
 def cache_token_bytes(cfg: TransformerConfig) -> dict:
     """Bytes one token holds in each group of cache leaves, all the group's
     layers: ``{"full": n}``, and under a layer pattern ``"window"`` beside it."""
-    row = sum(math.prod(shape) for shape in _cache_rows(cfg).values()) * jnp.dtype(cfg.dtype).itemsize
-    return {kind or _FULL: layers * row for kind, layers in _cache_groups(cfg).items()}
+    return {kind or _FULL: _group_bytes(cfg, row) for kind, row in _kinds(cfg).items() if row.reach in ("table", "ring")}
+
+
+_NO_DENSE_CACHE = {_LINEAR: "linear-attention layers", _MAMBA: "Mamba-2 state-space blocks", _EXPERTS: "single-mixer blocks"}
 
 
 def init_cache(cfg: TransformerConfig, batch: int, max_len: int):
     """Preallocated cache, every leaf [L, B, max_len, ...] (``_cache_rows``):
     k/v [.., KV, Dh], or ckv [.., the latent and the rotary key] (bf16 on
     TPU — cache reads are the decode bandwidth bill). Under a layer pattern
-    the leaves of each kind's layers (``_cache_groups``); a dense cache keeps
-    ``max_len`` rows for a window layer too and masks them. Linear-attention
-    layers are refused: their state cannot be rewound to a position, which
-    ``speculative_generate`` and ``decode_chunk``'s callers count on, and they
-    are served through the paged cache only (``init_paged_cache``)."""
-    if state_kind(cfg):
-        what = {_LINEAR: "linear-attention layers", _MAMBA: "Mamba-2 state-space blocks"}[state_kind(cfg)]
-        raise NotImplementedError(
-            "a dense cache (init_cache: prefill / decode_step / generate / speculative_generate) cannot hold "
-            f"{what} (layer_kinds has {state_kind(cfg)!r}): their recurrent state is kept a slot of the "
-            "paged cache (init_paged_cache, serve/llm/engine.py)"
-        )
-    if cfg.single_mixer:
-        raise NotImplementedError(
-            "a dense cache (init_cache) cannot hold single-mixer blocks (layer_kinds has 'experts'): "
-            "they run over the paged cache only (init_paged_cache, serve/llm/engine.py)"
-        )
+    the leaves of each kind's layers (``_KINDS``); a dense cache keeps
+    ``max_len`` rows for a window layer too and masks them. Layers that keep a
+    state are refused: it cannot be rewound to a position, which
+    ``speculative_generate`` and ``decode_chunk``'s callers count on; they and
+    single-mixer blocks are served through the paged cache only (``init_paged_cache``)."""
+    kinds = _kinds(cfg)
+    for kind, row in kinds.items():
+        if row.reach not in ("table", "ring"):
+            raise NotImplementedError(
+                "a dense cache (init_cache: prefill / decode_step / generate / speculative_generate) cannot hold "
+                f"{_NO_DENSE_CACHE[kind]} (layer_kinds has {kind!r}): they run over the paged cache only "
+                "(init_paged_cache, serve/llm/engine.py)"
+            )
     return {
-        name + _group_suffix(kind): jnp.zeros((layers, batch, max_len, *row), cfg.dtype)
-        for kind, layers in _cache_groups(cfg).items()
-        for name, row in _cache_rows(cfg).items()
+        name: jnp.zeros((row.layers, batch, max_len, *shape), dtype)
+        for row in kinds.values()
+        for name, (shape, dtype) in _group_rows(cfg, row).items()
     }
 
 
@@ -280,31 +346,34 @@ def _latent_values(lp, o, cfg):
     return o.reshape(*o.shape[:2], -1)
 
 
-def _reads_in_place(paged: bool, q: int) -> bool:
-    """A PAGED pool, one query a row, a TPU backend: where a kernel can walk
-    each row's block table to the row's length (``latent_kernel_reads``,
-    ``kv_kernel_reads``). Everything else (a prefill chunk, the dense cache,
-    any CPU run) gathers ``_paged_view`` and attends over it."""
-    return paged and q == 1 and _attention_ops._on_tpu()
+def one_kv_group(cfg: TransformerConfig) -> bool:
+    """A pool of ONE group of key and value leaves: standard attention and no
+    layer pattern. What ``ops/paged_attention.py``'s kernel reads in place and
+    what a decode step can carry a chunk over (``paged_decode_step_with_chunk``)."""
+    return not cfg.latent_attention and not cfg.layer_kinds
+
+
+def kernel_reads(cfg: TransformerConfig, paged: bool, q: int) -> bool:
+    """Whether a call of the layer stack reads its cache in place, through a
+    kernel that walks each row's block table to the row's length, by what the
+    code can see: a pool such a kernel is written for (latent attention:
+    ``ops/latent_attention.py``; ``one_kv_group``: ``ops/paged_attention.py``),
+    PAGED, one query a row, a TPU backend. Everything else (a pattern's groups,
+    a prefill chunk, the dense cache, any CPU run) gathers ``_paged_view`` and
+    attends over it. Such a call's cost does not grow with the table's width:
+    ``_cached_layers`` asks for the program, ``LLMEngine`` for the table it
+    hands a decode step (one width, one program), and the two cannot disagree."""
+    return bool(cfg.latent_attention or one_kv_group(cfg)) and paged and q == 1 and _attention_ops._on_tpu()
 
 
 def latent_kernel_reads(cfg: TransformerConfig, paged: bool, q: int) -> bool:
-    """Whether a call of the layer stack reads its cache through
-    ``ops/latent_attention.py``'s kernel, by what the code can see: latent
-    attention, a PAGED pool, one query a row, a TPU backend. Such a call walks
-    each row's block table to its length, so its cost does not grow with the
-    table's width: ``_cached_layers`` asks for the program, ``LLMEngine`` for
-    the table it hands a decode step (one width, one program), and the two
-    cannot disagree."""
-    return bool(cfg.latent_attention) and _reads_in_place(paged, q)
+    """``kernel_reads`` of a latent pool."""
+    return bool(cfg.latent_attention) and kernel_reads(cfg, paged, q)
 
 
 def kv_kernel_reads(cfg: TransformerConfig, paged: bool, q: int) -> bool:
-    """The same of a pool of keys and values and ``ops/paged_attention.py``'s
-    kernel: standard attention over ONE group of ``k`` / ``v`` leaves (no layer
-    pattern: the condition ``paged_decode_step_with_chunk`` states too). A
-    pattern's full layers and rings keep the view."""
-    return not cfg.latent_attention and not cfg.layer_kinds and _reads_in_place(paged, q)
+    """``kernel_reads`` of a pool of one group of key and value leaves."""
+    return one_kv_group(cfg) and kernel_reads(cfg, paged, q)
 
 
 def _decode_lengths(tables, positions):
@@ -490,15 +559,9 @@ MOE_COUNTS = "moe_counts"
 MOE_CHOICE = "moe_choice"
 
 
-def _pool_leaves(cache: dict) -> dict:
-    """The cache without its counters and its record of expert choices: the
-    leaves that hold what attention reads."""
-    return {name: leaf for name, leaf in cache.items() if name not in (MOE_COUNTS, MOE_CHOICE)}
-
-
 def expert_layers(cfg: TransformerConfig) -> int:
     """Layers (or single-mixer blocks) with routed experts."""
-    return cfg.layer_kinds.count(_EXPERTS) if cfg.single_mixer else cfg.n_layers - cfg.first_dense_layers
+    return sum(of.depth for of in _layer_stacks(cfg).values() if of.mlp == "routed")
 
 
 def init_moe_counts(cfg: TransformerConfig):
@@ -701,31 +764,37 @@ def _cached_layers(params, x, cache, positions, access, cfg, key_len=None, valid
     all n rows; only the cache's write, view, mask and attention run a part
     at a time, each as its own [B, q], and their outputs are laid side by
     side again (every part's rows are written first; a part of decode rows
-    then reads the pool in place where ``kv_kernel_reads`` says so, the chunk
+    then reads the pool in place where ``kernel_reads`` says so, the chunk
     its own gathered view). Carried for one group of key and value leaves only.
 
     The whole cache rides the layer scan as its CARRY (never xs -> ys, which
     are distinct buffers of the loop) and a layer reaches its part through
-    its index within its group of leaves (``_cache_groups``) and the group's
-    ``access`` (``_Access``; under a layer pattern one a kind, by kind). A
-    caller that donates ``cache`` gets it updated in place.
+    its index within its kind's group of leaves and ``access``: how each way
+    of reaching a group (``_KINDS``: ``"table"``, ``"ring"``, ``"state"``) is
+    taken in this call, an ``_Access`` or a ``_StateAccess``. A caller that
+    donates ``cache`` gets it updated in place.
     Masked (p == 0) entries contribute nothing, so stale rows past a
-    position, padding and null-block garbage stay invisible. The leading
-    dense layers (``params["dense_layers"]``) run first, in a scan of their
-    own, then ``params["layers"]``: a layer's index into the cache counts
-    through both. ``valid`` [B, q]: the rows that are real tokens (read by
-    routed experts only).
+    position, padding and null-block garbage stay invisible. ``valid`` [B, q]:
+    the rows that are real tokens (read by routed experts only).
 
-    Without a pattern a stack is one homogeneous scan over its layers. With
-    one, a stack is scanned over the PERIODS of its kinds (``_period``): the
-    kind of a layer, which decides its mask, its rotary and its group, is
-    static inside the body, which runs one period; what is left of a stack
-    past its last whole period is unrolled behind the scan."""
+    Which stacks of ``params`` run, in what order and which kind lies where
+    is ``_layer_plan``'s to say; a layer's index into its group counts through
+    the segments. A segment without a pattern is one homogeneous scan over
+    its layers. One with a pattern is scanned over the PERIODS of its kinds
+    (``_period``): the kind of a layer, which decides its mask, its rotary and
+    its group, is static inside the body, which runs one period; what is left
+    past the last whole period is unrolled behind the scan."""
     B, q = positions.shape
     counts = cache.get(MOE_COUNTS)
     pool = {name: leaf for name, leaf in cache.items() if name != MOE_COUNTS}
     latent = cfg.latent_attention
     n_words, per_word = _choice_words(cfg) if cfg.routed_experts else (1, 0)
+    kinds_of = _kinds(cfg)
+
+    # Kernel or view, asked once a call, of each batch of it (its one batch, or each of its ``parts``):
+    # ``run_layer`` and ``attend_parts`` read the answer.
+    batches = [(part.access, part.positions.shape[1]) for part in parts] if parts else [(access["table"], q)]
+    in_place = [kernel_reads(cfg, acc.tables is not None, n) for acc, n in batches]
 
     def attend_parts(pool, qh, rows, at):
         """Every part's rows written, then every part attended over its own
@@ -739,9 +808,9 @@ def _cached_layers(params, x, cache, positions, access, cfg, key_len=None, valid
             pool = {**pool, **{name: part.access.write(pool[name], at, of(part, row)) for name, row in rows.items()}}
         out = []
         with jax.named_scope("cache_attention"):
-            for part in parts:
+            for part, walked in zip(parts, in_place):
                 acc = part.access
-                if kv_kernel_reads(cfg, acc.tables is not None, part.positions.shape[1]):  # the decode rows, in place
+                if walked:  # the decode rows, in place
                     o = _cache_attention_in_place(of(part, qh), pool["k"], pool["v"], at, acc.tables, part.positions, cfg)
                 else:
                     ck, cv = (acc.view(pool[name], at) for name in ("k", "v"))
@@ -750,12 +819,12 @@ def _cached_layers(params, x, cache, positions, access, cfg, key_len=None, valid
                 out.append(o.reshape(1, -1, o.shape[2] * o.shape[3]))
         return jnp.concatenate(out, axis=1), pool
 
-    def record_choice(pool, chosen, kind, l, first):
+    def record_choice(pool, chosen, l, first):
         """The pool with the experts ``chosen`` [B, q, k] beside the rows' tokens
         in expert layer ``l - first`` of ``MOE_CHOICE``, where the pool keeps them."""
         if chosen is None or MOE_CHOICE not in pool:
             return pool
-        put = (access[_FULL] if kind else access).write
+        put = access["table"].write
         if n_words == 1:
             words = jnp.sum(chosen << (_expert_bits(cfg) * jnp.arange(chosen.shape[-1])), axis=-1)
             return {**pool, MOE_CHOICE: put(pool[MOE_CHOICE], l - first, words)}
@@ -772,14 +841,14 @@ def _cached_layers(params, x, cache, positions, access, cfg, key_len=None, valid
     def run_layer(x, pool, lp, kind, at, l, first, held):
         """One layer of kind ``kind`` (None: no pattern), layer ``at`` of its
         group, layer ``l - first`` of its stack."""
-        acc = access.get(kind) if kind else access
-        sfx = _group_suffix(kind)
+        row = kinds_of[kind]
+        acc, sfx = access.get(row.reach) if access else None, row.group
         if kind == _MAMBA:  # a block that is this mixer and nothing else
             o, pool = _mamba_mixer(lp, x, pool, at, acc, cfg)
             return x + o @ lp["wo"].astype(o.dtype), pool, None
         if kind == _EXPERTS:  # a block that is its experts and nothing else
             x, sent, chosen = _mlp(lp, x, cfg, valid, layer=l - first if held else None)
-            return x, record_choice(pool, chosen, kind, l, first), sent
+            return x, record_choice(pool, chosen, l, first), sent
         if kind == _LINEAR:
             o, pool = _linear_mixer(lp, x, pool, at, acc, cfg)
             a = o @ lp["wo"].astype(o.dtype)
@@ -792,9 +861,9 @@ def _cached_layers(params, x, cache, positions, access, cfg, key_len=None, valid
         else:
             pool = {**pool, **{name + sfx: acc.write(pool[name + sfx], at, row) for name, row in rows.items()}}
             with jax.named_scope("cache_attention" + (f"_{kind}" if kind else "")):
-                if latent_kernel_reads(cfg, acc.tables is not None, q):  # the rows just written are read where they lie
+                if in_place[0] and row.reach == "table" and latent:  # the rows just written are read where they lie
                     o = _latent_attention_in_place(lp, qh, pool["ckv"], at, acc.tables, positions, cfg)
-                elif kv_kernel_reads(cfg, acc.tables is not None, q):
+                elif in_place[0] and row.reach == "table":
                     o = _cache_attention_in_place(qh, pool["k"], pool["v"], at, acc.tables, positions, cfg).reshape(B, q, -1)
                 else:
                     seen = {name: acc.view(pool[name + sfx], at) for name in rows}
@@ -813,7 +882,7 @@ def _cached_layers(params, x, cache, positions, access, cfg, key_len=None, valid
         if cfg.single_mixer:  # an attention block: no MLP behind it
             return x, pool, None
         x, sent, chosen = _mlp(lp, x, cfg, valid, layer=l - first if held else None)
-        return x, record_choice(pool, chosen, kind, l, first), sent
+        return x, record_choice(pool, chosen, l, first), sent
 
     def body(first, held, carry, layer):
         x, pool = carry
@@ -823,10 +892,9 @@ def _cached_layers(params, x, cache, positions, access, cfg, key_len=None, valid
 
     def scan_periods(first, held, stacks, kinds, x, pool):
         """Layers ``first..`` of the model under a layer pattern, of ``kinds``.
-        ``stacks``: one stack of leaves that holds them all, a layer at its
-        position, or (a dict by kind; ``first`` 0) one stack a kind, a layer at
-        its rank among its kind, which is its index into its group of cache
-        leaves too. One scan over the PERIODS; inside a period a run of layers
+        ``stacks``: by kind, the stack of leaves that holds the kind's layers:
+        its own, or one that the segment's kinds share (``_Kind.own``). One
+        scan over the PERIODS; inside a period a run of layers
         of one kind that lie in a stack of their own is a scan of its own
         (three linear layers: one body, not three), any other layer a call (a
         shared stack's program is as it was measured: Trinity's lowered text is
@@ -835,12 +903,11 @@ def _cached_layers(params, x, cache, positions, access, cfg, key_len=None, valid
         slice of every matrix is copied out before an inner scan may read it
         (15 ms of a 46 ms decode step at Olmo-Hybrid's widths, v5e, PR 41)."""
         P = _period(kinds)
-        by_kind = isinstance(next(iter(stacks.values())), dict)
         before = {kind: cfg.layer_kinds[:first].count(kind) for kind in set(kinds)}
         a_period = {kind: kinds[:P].count(kind) for kind in set(kinds)}
         runs, j = [], 0  # (first layer, layers) of each run of one kind through one period
         while j < P:
-            n = next((i for i in range(j, P) if kinds[i] != kinds[j]), P) - j if by_kind else 1
+            n = next((i for i in range(j, P) if kinds[i] != kinds[j]), P) - j if kinds_of[kinds[j]].own else 1
             runs.append((j, n))
             j += n
 
@@ -853,13 +920,12 @@ def _cached_layers(params, x, cache, positions, access, cfg, key_len=None, valid
             # ``period`` traced (the scan's) or static (the remainder's).
             kind = kinds[j]
             rank = lambda: before[kind] + period * a_period[kind] + kinds[:j].count(kind)  # noqa: E731
-            if by_kind:  # at its rank among its kind, in its stack as in its group of cache leaves
-                at = s = rank() + i
-                lp = take(stacks[kind], at)
-            else:  # at its position in the stack; a shared stack's runs are not scanned (i is 0)
-                s = period * P + j
-                lp = take(stacks, s)
-                at = rank()
+            # In a stack of its kind's own at its rank among its kind, as in its group of cache leaves; in a shared
+            # stack at its position (a shared stack's runs are not scanned: i is 0).
+            own = kinds_of[kind].own
+            s = rank() + i if own else period * P + j
+            lp = take(stacks[kind], s)
+            at = s if own else rank()
             return run_layer(x, pool, {**lp, **held}, kind, at, first + s, first, held)
 
         def one_period(carry, period):
@@ -886,39 +952,27 @@ def _cached_layers(params, x, cache, positions, access, cfg, key_len=None, valid
                 sent.append(s[None])
         return x, pool, jnp.concatenate(sent) if sent else None
 
-    if _LINEAR in cfg.layer_kinds:  # two stacks by kind (``transformer._layer_stacks``), and no routed experts
-        stacks = {_LINEAR: params[LINEAR_LAYERS], _FULL: params["layers"]}
-        x, pool, _ = scan_periods(0, {}, stacks, cfg.layer_kinds, x, pool)
-        return _rms_norm(x, params["norm_f"], cfg.norm_eps), pool
-
-    first, sent = 0, None
-    if cfg.single_mixer:  # three stacks by kind; the experts' matrices whole, as below, where their matmuls run grouped
-        experts = params.get(EXPERT_LAYERS, {})
-        held = {n: experts[n] for n in _EXPERT_STACKS if n in experts and grouped_matmul_tiles(cfg.d_model, cfg.d_expert)}
-        stacks = {
-            _MAMBA: params.get(MAMBA_LAYERS), _FULL: params.get("layers"),
-            _EXPERTS: {n: leaf for n, leaf in experts.items() if n not in held},
-        }
-        stacks = {kind: stack for kind, stack in stacks.items() if kind in cfg.layer_kinds}
-        x, pool, sent = scan_periods(0, held, stacks, cfg.layer_kinds, x, pool)
-    for name in ("dense_layers", "layers"):
-        if name in params and not cfg.single_mixer:
-            stack = params[name]
-            depth = stack["attn_norm"].shape[0]
-            # Routed experts' matrices stay whole, outside the scanned leaves:
-            # the TPU's compiler copies a scan's slice of them before the
-            # grouped matmul reads it, a layer's 64 experts every layer of
-            # every step (tests/test_tpu_lowering.py holds both halves: the
-            # slice is copied, the whole stack is not; when the first fails,
-            # ``held`` and ``routed_experts(layer=)`` can go).
-            held = {n: stack[n] for n in _EXPERT_STACKS if cfg.routed_experts and n in stack}
-            sliced = {n: leaf for n, leaf in stack.items() if n not in held} if held else stack
-            if cfg.layer_kinds:
-                x, pool, sent = scan_periods(first, held, sliced, cfg.layer_kinds[first : first + depth], x, pool)
-            else:
-                layer_ids = jnp.arange(first, first + depth, dtype=jnp.int32)
-                (x, pool), sent = lax.scan(partial(body, first, held), (x, pool), (sliced, layer_ids))
-            first += depth
+    sent = None
+    # Routed experts' matrices stay whole, outside the scanned leaves, where
+    # their matmuls run grouped: the TPU's compiler copies a scan's slice of
+    # them before the grouped matmul reads it, a layer's 64 experts every
+    # layer of every step (tests/test_tpu_lowering.py holds both halves: the
+    # slice is copied, the whole stack is not; when the first fails, ``held``
+    # and ``routed_experts(layer=)`` can go). Widths the grouped kernel does
+    # not tile (``grouped_matmul_tiles``) run batched over every held expert
+    # and are sliced like any leaf: held whole for a grouped matmul, Nemotron's
+    # [6, 64, 2688, 1856] was copied into the kernel's layout every step
+    # (3.8 GB, PR 43).
+    hold = cfg.routed_experts and grouped_matmul_tiles(cfg.d_model, cfg.d_expert)
+    for segment in _layer_plan(cfg):
+        stacks = {kind: params[row.stack] for kind, row in segment.rows.items()}
+        held = {n: stack[n] for stack in stacks.values() for n in _EXPERT_STACKS if hold and n in stack}
+        stacks = {kind: {n: leaf for n, leaf in stack.items() if n not in held} for kind, stack in stacks.items()}
+        if segment.kinds:
+            x, pool, sent = scan_periods(segment.first, held, stacks, segment.kinds, x, pool)
+        else:
+            layer_ids = jnp.arange(segment.first, segment.first + segment.depth, dtype=jnp.int32)
+            (x, pool), sent = lax.scan(partial(body, segment.first, held), (x, pool), (stacks[None], layer_ids))
     if counts is not None:  # sent [expert layers, E]: the routed stack's, which runs last
         touched = jnp.sum(sent > 0, axis=-1, keepdims=True)
         if cfg.expert_share[1] > 1:  # E the experts held; a call counts by what it routed, held or not, and says how much
@@ -952,11 +1006,10 @@ def _dense_write(pos, positions):
     return lambda c, l, rows: c.at[l, batch, positions].set(rows)
 
 
-def _dense_access(cfg, write, view):
+def _dense_access(write, view):
     """A dense cache is reached the same way whatever a layer's kind: a window
     layer keeps every row and its mask leaves out those behind the window."""
-    access = _Access(write, view)
-    return {kind: access for kind in (_WINDOW, _FULL)} if cfg.layer_kinds else access
+    return dict.fromkeys(("table", "ring"), _Access(write, view))
 
 
 def prefill(params, tokens, cache, cfg: TransformerConfig, prompt_lens=None):
@@ -982,7 +1035,7 @@ def prefill(params, tokens, cache, cfg: TransformerConfig, prompt_lens=None):
     # Attend only over the prompt's T rows — the generation region of the
     # cache is not written yet; scoring it would waste S/T the FLOPs/HBM.
     # Causal within the prompt; per-row padding invisible.
-    access = _dense_access(cfg, _dense_write(pos, positions), lambda c, l: c[l][:, :T])
+    access = _dense_access(_dense_write(pos, positions), lambda c, l: c[l][:, :T])
     x, cache = _cached_layers(
         params, x, cache, positions, access, cfg, key_len=prompt_lens,
         valid=positions < prompt_lens[:, None] if cfg.routed_experts else None,
@@ -997,7 +1050,7 @@ def _decode_chunk_hidden(params, tokens, cache, pos, cfg: TransformerConfig):
     themselves (``last_row_logits``) instead of paying [B, q, V]."""
     pos = jnp.asarray(pos, jnp.int32)
     x, positions = _embed_chunk(params, tokens, pos, cfg)
-    access = _dense_access(cfg, _dense_write(pos, positions), lambda c, l: c[l])
+    access = _dense_access(_dense_write(pos, positions), lambda c, l: c[l])
     return _cached_layers(params, x, cache, positions, access, cfg)
 
 
@@ -1061,25 +1114,21 @@ def init_paged_cache(
     are routed there, so the compiled step never needs a dynamic shape or a
     conditional write.
 
-    Under a layer pattern there are two groups of leaves (``_cache_groups``),
-    each with a null block of its own: the full layers' ``[full layers,
-    num_blocks, ...]``, reached through a row's block table as ever, and the
-    window layers' ``[window layers, window_blocks, ...]``, reached through a
-    row's RING (``_ring_access``): ``window_blocks`` is 1 + rings x blocks a ring.
-
-    Linear-attention layers have a third group, which holds no token's rows
-    and has no blocks: ``[linear layers, state_slots, ...]`` (``state_rows``),
+    Under a layer pattern a kind of layer has a group of leaves of its own
+    (``_KINDS``), each with a null block of its own: the full layers' ``[full
+    layers, num_blocks, ...]``, reached through a row's block table as ever,
+    and the window layers' ``[window layers, window_blocks, ...]``, reached
+    through a row's RING (``_ring_access``): ``window_blocks`` is 1 + rings x
+    blocks a ring. The group of layers that keep a state holds no token's rows
+    and has no blocks: ``[such layers, state_slots, ...]`` (``state_rows``),
     what each of ``state_slots`` serving slots carries from program to
     program, reached by the slot's index (``_StateAccess``)."""
-    blocks = {None: num_blocks, _FULL: num_blocks, _WINDOW: window_blocks}
-    pool = {
-        name + _group_suffix(kind): jnp.zeros((layers, blocks[kind], block_size, *row), cfg.dtype)
-        for kind, layers in _cache_groups(cfg).items()
-        for name, row in _cache_rows(cfg).items()
+    held = {"table": (num_blocks, block_size), "ring": (window_blocks, block_size), "state": (state_slots,)}
+    return {
+        name: jnp.zeros((row.layers, *held[row.reach], *shape), dtype)
+        for row in _kinds(cfg).values() if row.reach
+        for name, (shape, dtype) in _group_rows(cfg, row).items()
     }
-    for name, (shape, dtype) in state_rows(cfg).items():
-        pool[name] = jnp.zeros((cfg.layer_kinds.count(state_kind(cfg)), state_slots, *shape), dtype)
-    return pool
 
 
 def _paged_write(block_tables, positions, valid_to, block_size: int):
@@ -1156,22 +1205,22 @@ def paged_decode_chunk_hidden(
     pos = jnp.asarray(pos, jnp.int32)
     block_tables = jnp.asarray(block_tables, jnp.int32)
     x, positions = _embed_chunk(params, tokens, pos, cfg)
-    block_size = cache["k" if "k" in cache else "ckv"].shape[2]
-    access = _Access(
+    blocked = next(row for row in _kinds(cfg).values() if row.reach in ("table", "ring"))
+    block_size = cache[next(iter(_group_rows(cfg, blocked)))].shape[2]
+    access = {"table": _Access(
         _paged_write(block_tables, positions, valid_to, block_size), _paged_view(block_tables), tables=block_tables
-    )
-    if state_kind(cfg):
+    )}
+    reach = pool_reach(cfg)
+    if "state" in reach:
         B, q = positions.shape
         real = q if valid_to is None else jnp.clip(jnp.asarray(valid_to, jnp.int32) - pos, 0, q)
         live = block_tables[:, 0] != 0
         fresh = jnp.zeros((B,), bool) if state_fresh is None else jnp.asarray(state_fresh, bool)
-        access = {_FULL: access, state_kind(cfg): _StateAccess(state_slots, fresh, jnp.where(live, real, 0).astype(jnp.int32))}
-    elif cfg.single_mixer:  # attention and experts blocks only
-        access = {_FULL: access}
-    elif cfg.layer_kinds:
+        access["state"] = _StateAccess(state_slots, fresh, jnp.where(live, real, 0).astype(jnp.int32))
+    if "ring" in reach:
         ring_tables = jnp.asarray(ring_tables, jnp.int32)
         n_view = min(ring_tables.shape[1], block_tables.shape[1])
-        access = {_FULL: access, _WINDOW: _ring_access(ring_tables, positions, valid_to, block_size, n_view)}
+        access["ring"] = _ring_access(ring_tables, positions, valid_to, block_size, n_view)
     valid = None
     if cfg.routed_experts:
         # A live row's table starts at a real block; an inactive slot's, and
@@ -1238,13 +1287,13 @@ def paged_decode_step_with_chunk(
     the pool through its own table and is masked by its own positions
     (``_cached_layers``' ``parts``), so a row's arithmetic is that of the call
     it would have been in (on a TPU the decode rows read the pool in place,
-    as ``paged_decode_step``'s do: ``kv_kernel_reads``). Returns (final normed hidden states [S + q, D],
+    as ``paged_decode_step``'s do: ``kernel_reads``). Returns (final normed hidden states [S + q, D],
     the S decode rows first, and the cache).
 
-    For a cache of one group of key and value leaves. Not carried: a latent
-    pool, the two groups of a layer pattern (rings), and routed experts, whose
-    counters keep decode steps and chunks apart where such a pass is both."""
-    if cfg.latent_attention or cfg.layer_kinds or cfg.routed_experts:
+    For a cache of one group of key and value leaves (``one_kv_group``). Not
+    carried: a latent pool, the groups of a layer pattern, and routed experts,
+    whose counters keep decode steps and chunks apart where such a pass is both."""
+    if not one_kv_group(cfg) or cfg.routed_experts:
         raise NotImplementedError("a decode step carries a chunk over one group of key and value leaves only")
     S = token.shape[0]
     block_size = next(iter(cache.values())).shape[2]

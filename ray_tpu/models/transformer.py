@@ -518,28 +518,39 @@ MAMBA_LAYERS = "mamba_layers"
 EXPERT_LAYERS = "expert_layers"
 
 
+class _Stack(NamedTuple):
+    """A stack of layers: its depth, its kind of MLP and of mixer
+    (``_layer_leaves``' arguments), and the kind of ``layer_kinds`` whose layers
+    it holds, each at its rank among its kind, in the order they come in the
+    model, which runs the kinds interleaved. ``kind`` None: a run of
+    consecutive layers whatever their kinds, each at its position."""
+
+    depth: int
+    mlp: Any
+    mixer: Any
+    kind: Any = None
+
+
 def _layer_stacks(cfg: TransformerConfig) -> dict:
-    """``params`` key -> (depth, kind of MLP, kind of mixer) of each stack of
-    layers (``_layer_leaves``' arguments), in the order they run: the leading
+    """``params`` key -> ``_Stack``, in the order the stacks run: the leading
     dense layers (where the configuration has any), then ``"layers"``. A
     pattern with linear-attention layers, whose leaves are not the attention
     layers', is stacked by kind: its linear layers in ``"linear_layers"`` and
-    the others in ``"layers"``, each in the order its kind's layers come in the
-    model, which runs them interleaved. A pattern of single-mixer blocks is
-    three such stacks: the state-space blocks (``"mamba_layers"``), the
-    experts blocks (``"expert_layers"``) and the attention blocks (``"layers"``)."""
+    the others in ``"layers"``. A pattern of single-mixer blocks is three such
+    stacks: the state-space blocks (``"mamba_layers"``), the experts blocks
+    (``"expert_layers"``) and the attention blocks (``"layers"``). What a kind
+    of layer keeps in a cache is ``generate._layer_plan``'s to say."""
     mlp = "routed" if cfg.routed_experts else "switch" if cfg.num_experts > 0 else "dense"
+    count = cfg.layer_kinds.count
     if cfg.single_mixer:
-        count = cfg.layer_kinds.count
-        stacks = {MAMBA_LAYERS: (count("mamba"), None, "mamba"), EXPERT_LAYERS: (count("experts"), mlp, None),
-                  "layers": (count("full"), None, "attention")}
-        return {name: stack for name, stack in stacks.items() if stack[0]}
-    n_dense = cfg.first_dense_layers
-    n_linear = cfg.layer_kinds.count("linear")
-    stacks = {"dense_layers": (n_dense, "dense", "attention")} if n_dense else {}
+        stacks = {MAMBA_LAYERS: _Stack(count("mamba"), None, "mamba", "mamba"), EXPERT_LAYERS: _Stack(count("experts"), mlp, None, "experts"),
+                  "layers": _Stack(count("full"), None, "attention", "full")}
+        return {name: stack for name, stack in stacks.items() if stack.depth}
+    n_dense, n_linear = cfg.first_dense_layers, count("linear")
+    stacks = {"dense_layers": _Stack(n_dense, "dense", "attention")} if n_dense else {}
     if n_linear:
-        stacks[LINEAR_LAYERS] = (n_linear, mlp, "linear")
-    stacks["layers"] = (cfg.n_layers - n_dense - n_linear, mlp, "attention")
+        stacks[LINEAR_LAYERS] = _Stack(n_linear, mlp, "linear", "linear")
+    stacks["layers"] = _Stack(cfg.n_layers - n_dense - n_linear, mlp, "attention", "full" if n_linear else None)
     return stacks
 
 
@@ -565,13 +576,13 @@ def init_params(key, cfg: TransformerConfig) -> dict:
     # spent 34 of its 73 s here (v5e, PR 35), under a Serve that gives it 90.
     drawn = _Drawn(key)
 
-    def stack(i, L, mlp, mixer):
+    def stack(i, of: _Stack):
         keys = jax.random.split(jax.random.fold_in(key, i), 16) if own_keys else ks
         return {
-            name: jnp.full((L, *leaf.shape), 1.0 if leaf.scale is None else leaf.scale, leaf.dtype or dt)
+            name: jnp.full((of.depth, *leaf.shape), 1.0 if leaf.scale is None else leaf.scale, leaf.dtype or dt)
             if leaf.key is None
-            else drawn.later(keys[leaf.key], (L, *leaf.shape), leaf.scale, leaf.dtype or dt, leaf.post)
-            for name, leaf in _layer_leaves(cfg, mlp, mixer).items()
+            else drawn.later(keys[leaf.key], (of.depth, *leaf.shape), leaf.scale, leaf.dtype or dt, leaf.post)
+            for name, leaf in _layer_leaves(cfg, of.mlp, of.mixer).items()
         }
 
     params = {
@@ -579,7 +590,7 @@ def init_params(key, cfg: TransformerConfig) -> dict:
         "norm_f": jnp.ones((D,), dt),
     }
     for i, (name, of) in enumerate(_layer_stacks(cfg).items()):
-        params[name] = stack(i, *of)
+        params[name] = stack(i, of)
     if not cfg.tie_embeddings:
         params["lm_head"] = drawn.later(ks[9], (D, V), D**-0.5, dt)
     return drawn.now(params)
@@ -623,8 +634,8 @@ def param_logical_axes(cfg: TransformerConfig) -> dict:
     """Per-leaf logical axis names (mapped to mesh axes by
     parallel/mesh.logical_to_spec)."""
     axes = {"embed": ("vocab", "embed"), "norm_f": (None,)}
-    for stack, (_, mlp, mixer) in _layer_stacks(cfg).items():
-        axes[stack] = {name: ("layers", *leaf.axes) for name, leaf in _layer_leaves(cfg, mlp, mixer).items()}
+    for stack, of in _layer_stacks(cfg).items():
+        axes[stack] = {name: ("layers", *leaf.axes) for name, leaf in _layer_leaves(cfg, of.mlp, of.mixer).items()}
     if not cfg.tie_embeddings:
         axes["lm_head"] = ("embed", "vocab")
     return axes

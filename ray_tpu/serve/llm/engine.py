@@ -20,8 +20,9 @@ vLLM-lineage iteration-level scheduler on top of the paged KV cache:
   has no ladder: its decode step walks each row's table to the row's length
   in a kernel (``ops/latent_attention.py``, ``ops/paged_attention.py``), so it
   is handed the whole table and there is one decode program
-  (``generate.latent_kernel_reads`` / ``kv_kernel_reads``;
-  ``stats()["latent_kernel_steps"]`` / ``["kv_kernel_steps"]``).
+  (``generate.kernel_reads``; ``stats()["latent_kernel_steps"]`` /
+  ``["kv_kernel_steps"]``). A decode step that carries the pass's prefill
+  chunk (``_shape_fuses``) is handed the whole table on every backend.
 - **paged KV cache**: ``init_paged_cache`` block pool + per-sequence block
   tables with a host-side free-list. Block 0 is the reserved null block
   (inactive slots and write-masked padding rows land there). The pool is
@@ -274,7 +275,7 @@ _ROW_COUNTER = 6  # index of the token this dispatch draws
 # The block table from here on: n_max wide (prefill), a rung of _view_rungs
 # (decode). Under a layer pattern the row's ring in the window layers' group
 # comes first (``LLMEngine.ring_blocks`` wide); with layers that keep a recurrent
-# state a slot (linear attention, Mamba-2: ``generate.state_kind``)
+# state a slot (linear attention, Mamba-2: ``"state"`` in ``generate.pool_reach``)
 # ``_STATE_COLS`` columns, a prefill row's slot in their group and whether its
 # state starts from zero (a decode row IS its slot's: row b, slot b); then the
 # table.
@@ -349,19 +350,19 @@ def _compiled_fns(cfg, ring: int = 0):
     columns, a row's slot and whether its state starts from zero, also lie
     ahead of the table; only the prefill program reads them.
 
-    ``decode_with_chunk(params, rows [num_slots, 7 + w], pool, ids [num_slots],
-    tokens [1, q], chunk_rows [1, 7 + w]) -> (ids [num_slots], pool)`` is the
+    ``decode_with_chunk(params, rows [num_slots, 7 + n_max], pool, ids [num_slots],
+    tokens [1, q], chunk_rows [1, 7 + n_max]) -> (ids [num_slots], pool)`` is the
     decode step that carries the pass's prefill chunk: both go through the
     layers as ``num_slots + q`` rows of one matmul chain
-    (``generate.paged_decode_step_with_chunk``), each part over its own table
-    (the chunk's cut to the step's rung), the head is projected for
+    (``generate.paged_decode_step_with_chunk``), each part over its own table,
+    the head is projected for
     ``num_slots + 1`` rows and all of them drawn in the program. The chunk's
     id is the first token of its request if the chunk is the prompt's last: it
     is returned in the slot of the row whose token column says
     ``_ID_FROM_CHUNK`` (the request's own, inactive as a decode row until then),
     so the ids have the decode program's shape and the next step feeds them
     the same way. For a pool of one group of key and value leaves; the engine
-    builds it for no other, and at one rung (``LLMEngine._shape_fuses``)."""
+    builds it for no other (``LLMEngine._shape_fuses``)."""
     with _JIT_LOCK:
         fns = _JIT_CACHE.get((cfg, ring))
         if fns is None:
@@ -373,22 +374,24 @@ def _compiled_fns(cfg, ring: int = 0):
                 paged_decode_chunk_hidden,
                 paged_decode_step,
                 paged_decode_step_with_chunk,
-                state_kind,
+                pool_reach,
             )
             from ray_tpu.models.transformer import _logits
 
-            state = _STATE_COLS if state_kind(cfg) else 0
+            # What lies ahead of a row's block table, by how the pool's groups are
+            # reached: a ring's blocks, then the state's columns (only a chunk reads them).
+            reach = pool_reach(cfg)
+            state_at = _ROW_TABLE + ring
+            table_at = state_at + (_STATE_COLS if "state" in reach else 0)
 
             def tables(rows, chunk=False):
-                if state:  # never beside a ring: window layers do not come beside layers that keep a state
-                    own = dict(state_slots=rows[:, _ROW_TABLE], state_fresh=rows[:, _ROW_TABLE + 1] != 0)
-                    return dict(block_tables=rows[:, _ROW_TABLE + state :], **(own if chunk else {}))
-                if not ring:
-                    return dict(block_tables=rows[:, _ROW_TABLE:])
-                return dict(
-                    block_tables=rows[:, _ROW_TABLE + ring :],
-                    ring_tables=rows[:, _ROW_TABLE : _ROW_TABLE + ring],
-                )
+                cut = {}
+                if "state" in reach and chunk:
+                    cut.update(state_slots=rows[:, state_at], state_fresh=rows[:, state_at + 1] != 0)
+                cut["block_tables"] = rows[:, table_at:]
+                if "ring" in reach:
+                    cut["ring_tables"] = rows[:, _ROW_TABLE:state_at]
+                return cut
 
             def decode_rows(p, rows, c, ids):
                 fed = rows[:, _ROW_TOKEN]
@@ -533,8 +536,8 @@ class LLMEngine:
             init_moe_choice,
             init_moe_counts,
             init_paged_cache,
-            kv_kernel_reads,
-            latent_kernel_reads,
+            kernel_reads,
+            pool_reach,
             ring_blocks,
             state_slot_bytes,
         )
@@ -573,13 +576,12 @@ class LLMEngine:
         self.block_size = int(block_size)
         self.max_model_len = int(max_model_len or cfg.max_seq_len)
         self.n_max = -(-self.max_model_len // self.block_size)  # blocks/seq
-        # A decode step that reads its pool through a kernel (a latent pool:
-        # ``generate.latent_kernel_reads``; one group of key and value leaves:
-        # ``kv_kernel_reads``) stops at each row's length whatever the table's
-        # width: the whole table, one program, no ladder.
-        self._latent_kernel = latent_kernel_reads(cfg, paged=True, q=1)
-        self._kv_kernel = kv_kernel_reads(cfg, paged=True, q=1)
-        self._view_rungs = (self.n_max,) if self._latent_kernel or self._kv_kernel else _view_rungs(self.n_max)
+        # A decode step that reads its pool in place, through a kernel
+        # (``generate.kernel_reads``: a latent pool, or one group of key and
+        # value leaves, on a TPU), stops at each row's length whatever the
+        # table's width: the whole table, one program, no ladder.
+        self._reads_in_place = kernel_reads(cfg, paged=True, q=1)
+        self._view_rungs = (self.n_max,) if self._reads_in_place else _view_rungs(self.n_max)
         # Decode steps run at each width, for stats()["decode_width_steps"].
         self._width_steps = {w: 0 for w in self._view_rungs}
         # Default pool: every slot can run to max_model_len (+1 null block)
@@ -597,9 +599,9 @@ class LLMEngine:
         # has it whole from admission to its end, nothing of it is allocated
         # or freed, and however long the row grows a window layer holds and
         # gathers no more. The full layers keep the block tables above.
-        windowed = "window" in cfg.layer_kinds
-        self.ring_blocks = ring_blocks(cfg.sliding_window, self.prefill_chunk, self.block_size) if windowed else 0
-        self.num_window_blocks = self.num_slots * self.ring_blocks + 1 if windowed else 0
+        reach = pool_reach(cfg)
+        self.ring_blocks = ring_blocks(cfg.sliding_window, self.prefill_chunk, self.block_size) if "ring" in reach else 0
+        self.num_window_blocks = self.num_slots * self.ring_blocks + 1 if "ring" in reach else 0
         # Layers that keep a recurrent state (linear attention, Mamba-2 blocks)
         # have a group of their own too, with no blocks at all: slot s owns row
         # s of its leaves for good (the state and the convolution's carried rows, a layer),
@@ -611,7 +613,7 @@ class LLMEngine:
         # a step's last write for a request that ended, leaves a state that
         # the slot's next tenant never reads.
         self.state_slot_bytes = state_slot_bytes(cfg)
-        self._state_cols = _STATE_COLS if self.state_slot_bytes else 0
+        self._state_cols = _STATE_COLS if "state" in reach else 0
         self._rings = 1 + np.arange(self.num_slots * self.ring_blocks, dtype=np.int32).reshape(
             self.num_slots, self.ring_blocks
         )
@@ -619,7 +621,7 @@ class LLMEngine:
         t0 = time.monotonic()
         pool = init_paged_cache(
             cfg, self.num_blocks, self.block_size, self.num_window_blocks,
-            state_slots=self.num_slots if self.state_slot_bytes else 0,
+            state_slots=self.num_slots if "state" in reach else 0,
         )
         # Bytes one token holds in each group of pool leaves, all its layers,
         # and in the pool at large.
@@ -633,8 +635,7 @@ class LLMEngine:
             # token a layer), for ``submit(return_routed_experts=True)``.
             pool[MOE_CHOICE] = init_moe_choice(cfg, self.num_blocks, self.block_size)
         self._cache = jax.block_until_ready(pool)
-        # The widest rung but one (of a ladder of one rung, that one): ``_shape_fuses``.
-        self._fused_rungs = (self._view_rungs[-2:-1] or self._view_rungs) if self._shape_fuses(self._cache) else ()
+        self._fuses = self._shape_fuses()
         self._moe_wanted: Optional[threading.Event] = None
         self._moe_asking = threading.Lock()  # one asker at a time
         # The counters as last read, a NumPy array. Read once here, which
@@ -694,8 +695,8 @@ class LLMEngine:
             "decode_steps_with_chunk": 0,
             "decode_rows_dropped": 0,
             # Those dispatched to the program that reads the latent pool
-            # through its kernel (``_latent_kernel``): all of them or none.
-            # And the same of a pool of keys and values (``_kv_kernel``).
+            # through its kernel (``_reads_in_place`` of a latent pool): all
+            # of them or none. And the same of a pool of keys and values.
             "latent_kernel_steps": 0,
             "kv_kernel_steps": 0,
             # Tokens the prefill chunks carried that were real, and the
@@ -1283,12 +1284,10 @@ class LLMEngine:
         chunk is next, then the decode tick, which runs ONE STEP AHEAD: it
         dispatches step N+1 while step N is still on the device, and only then
         fetches and emits N's tokens. **Where the pass has a chunk AND rows
-        that decode, the engine's shape fuses (``_shape_fuses``) and the step
-        with a chunk is built wide enough for them (``_rides``), the chunk
+        that decode and the engine's shape fuses (``_shape_fuses``), the chunk
         rides inside step N+1**: one program, one read of the weights
         (``_launch_step``). Otherwise (no row decodes, no chunk, a shape that
-        does not fuse, rows past the rung that program is built at) the chunk
-        is a program of its own ahead of the step, as ever. So round the loop the order is dispatch N+1 (with this
+        does not fuse) the chunk is a program of its own ahead of the step. So round the loop the order is dispatch N+1 (with this
         pass's chunk) -> fetch N -> emit N -> admit -> dispatch N+2 (with the
         next chunk) -> fetch N+1 ..., or, unfused, dispatch N+1 -> fetch N ->
         emit N -> admit -> prefill chunk -> dispatch N+2 ..., and the host's
@@ -1377,31 +1376,13 @@ class LLMEngine:
             self._teardown_cluster_tier()
 
     def _ticks(self) -> bool:
-        """A pass's programs: the chunk inside the decode step where the pass
-        has both and a step with a chunk is built wide enough for them
-        (``_rides``), else the chunk, then the step."""
+        """A pass's programs: the chunk inside the decode step where the
+        engine's shape fuses and a row decodes, else the chunk, then the step."""
         chunk = self._next_prefill()
-        if chunk is not None and self._rides(chunk):
+        if chunk is not None and self._fuses and self._decoding(self._inflight):
             return self._decode_tick(chunk)
         busy = self._prefill_tick(chunk)
         return self._decode_tick() or busy
-
-    def _rides(self, chunk: LLMRequest) -> bool:
-        """Whether ``chunk``'s next chunk goes inside this pass's decode step:
-        the shape fuses, a row decodes, and the blocks they need between them
-        (each row's table with the block its next token may open, and what the
-        chunk has filled and fills) fit the widest rung the step with a chunk
-        is built at. Past it the pass keeps the two programs."""
-        riding = self._inflight.reqs if self._inflight is not None else ()
-        rows = self._decoding(self._inflight) if self._fused_rungs else ()
-        if not rows:
-            return False
-        bs = self.block_size
-        need = max(
-            -(-self._chunk_end(chunk) // bs),
-            *(max(len(r._sched_table), (r._sched_pos + (r in riding)) // bs + 1) for r in rows),
-        )
-        return need <= self._fused_rungs[-1]
 
     def _sweep_cancelled(self):
         for req in self._slots:
@@ -1551,8 +1532,8 @@ class LLMEngine:
             default=None,
         )
 
-    def _chunk_inputs(self, req: LLMRequest, width: int):
-        """(tokens [1, q], rows [1, 7 + width]) of ``req``'s next chunk as
+    def _chunk_inputs(self, req: LLMRequest):
+        """(tokens [1, q], rows [1, 7 + n_max]) of ``req``'s next chunk as
         NumPy arrays, and how many of the tokens are real."""
         q = self.prefill_chunk
         pos0 = req._sched_pos
@@ -1565,8 +1546,8 @@ class LLMEngine:
         # The program draws from the row of the prompt's LAST real token
         # within this chunk: only meaningful (and only fetched) on the
         # final chunk.
-        rows = self._program_rows(1, width)
-        self._fill_row(rows[0], req, req._sched_target, pos0, width=width)
+        rows = self._program_rows(1, self.n_max)
+        self._fill_row(rows[0], req, req._sched_target, pos0)
         if self._state_cols:
             # A chunk that starts a sequence starts its slot's state: admission
             # put the request at position 0 (no prefix hit under a pattern),
@@ -1608,7 +1589,7 @@ class LLMEngine:
 
         spans = self.spans
         with spans.span("llm.prefill.build", rid=req.id, pos=req._sched_pos):
-            fed, rows, n = self._chunk_inputs(req, self.n_max)
+            fed, rows, n = self._chunk_inputs(req)
             inputs = (jnp.asarray(fed), jnp.asarray(rows))
         spans.carried(prefill_tokens=n)
         with spans.span("llm.prefill.dispatch", rid=req.id):
@@ -1632,12 +1613,10 @@ class LLMEngine:
         inactive slot (token 0 at position 0 of the null block, drawn greedily)."""
         return np.zeros((n, _ROW_TABLE + self.ring_blocks + self._state_cols + width), np.int32)
 
-    def _fill_row(self, row: np.ndarray, req: LLMRequest, first: int, pos: int, ahead: int = 0, width: int = 0):
+    def _fill_row(self, row: np.ndarray, req: LLMRequest, first: int, pos: int, ahead: int = 0):
         """``first``: column 0, the token fed (decode) or valid_to (prefill);
         ``ahead``: 1 if a step in flight draws a token of ``req`` before this
-        dispatch draws its own; ``width``: the row's table in blocks where
-        that may be narrower than the request's (a chunk inside a step: the
-        blocks behind the rung are the prompt's that later chunks fill)."""
+        dispatch draws its own."""
         row[_ROW_TOKEN] = first
         row[_ROW_POS] = pos
         row[_ROW_DRAW] = req._sched_draw
@@ -1645,8 +1624,7 @@ class LLMEngine:
         rings = _ROW_TABLE + self.ring_blocks
         row[_ROW_TABLE:rings] = self._rings[req._sched_slot]
         table = rings + self._state_cols
-        blocks = req._sched_table[:width] if width else req._sched_table
-        row[table : table + len(blocks)] = blocks
+        row[table : table + len(req._sched_table)] = req._sched_table
 
     def _run_donated(self, fn, tokens, *rest):
         """Dispatch one pool-updating program. The pool is donated: the
@@ -1668,38 +1646,29 @@ class LLMEngine:
             )
         return drawn
 
-    def _shape_fuses(self, pool) -> bool:
-        """Whether a pass with a chunk AND decode rows may run as one program
+    def _shape_fuses(self) -> bool:
+        """Whether a pass with a chunk AND decode rows runs as one program
         (``_compiled_fns``' ``decode_with_chunk``), by what the engine can see
         of itself: its rows and the chunk's fill no more than one tile of the
-        matrix unit, the pool is one group of key and value leaves (a latent
-        pool, a layer pattern's two groups with their rings and the experts'
-        counters keep the two programs: ``generate.paged_decode_step_with_chunk``
-        carries none of them), and the engine decodes at all.
+        matrix unit, the pool is one group of key and value leaves and the
+        model routes no experts (``generate.paged_decode_step_with_chunk``
+        carries nothing else), and the engine decodes at all. ONE such
+        program, at the whole table: a second is ~1 s of EVERY warm start, its
+        trace and lowering, which no thread hides (the interpreter is one:
+        PERF.md, PR 40)."""
+        from ray_tpu.models.generate import one_kv_group
 
-        Such an engine builds the step with a chunk at ONE rung
-        (``_fused_rungs``): the widest but one, the last of the ladder's
-        doublings (2048 tokens of Mistral-16's 16 / 32 / 64 / 128 / 160
-        blocks; a ladder of one rung: that one). Every program more is ~1 s of
-        EVERY warm start, its trace and lowering, which no thread hides (the
-        interpreter is one: PERF.md, PR 40), while a pass rounded up to a
-        wider view pays only that view's gather, always less than the second
-        read of the weights it saves. Past that rung (``_rides``) a pass
-        keeps the two programs. Where a kernel reads the decode rows' pool in
-        place (``_kv_kernel``) the ladder IS one rung, the whole table: the
-        rows' part costs what the rows hold, only the chunk's one table is
-        gathered (0.4 ms at Mistral-16's 2560 tokens, 0.1 more than at 2048),
-        and every pass with rows and a chunk rides (PERF.md, PR 45)."""
         return (
             self.role != "prefill"
             and self.num_slots + self.prefill_chunk <= _MXU_TILE_ROWS
-            and set(pool) == {"k", "v"}
+            and one_kv_group(self.cfg)
+            and not self.cfg.routed_experts
         )
 
     def _build_programs(self):
         """Build the decode program at every rung before the scheduler starts
-        and, for an engine whose shape fuses, the step with a chunk at its one
-        rung (``_shape_fuses``) and the prefill program: one dispatch of
+        and, for an engine whose shape fuses (``_shape_fuses``), the step with a
+        chunk and the prefill program: one dispatch of
         all-inactive rows each, which writes row 0 of the null block and
         nothing else. A width first met while serving would compile for
         seconds inside a stream; an engine that adds programs to its start
@@ -1730,14 +1699,10 @@ class LLMEngine:
         # model takes it 8 s, seven of them in a row more than Serve gives a
         # replica to become ready (PERF.md, PR 35).
         calls = [(self._decode_fn, jnp.asarray(self._program_rows(self.num_slots, w)), ids) for w in self._view_rungs]
-        if self._fused_rungs:
-            calls.append((self._prefill_fn, chunk, jnp.asarray(self._program_rows(1, self.n_max))))
-        fused_from = len(calls)
-        for w in self._fused_rungs:
-            calls.append(
-                (self._fused_fn, jnp.asarray(self._program_rows(self.num_slots, w)), ids, chunk,
-                 jnp.asarray(self._program_rows(1, w)))
-            )
+        fused_from = len(calls) + 1
+        if self._fuses:
+            whole = [jnp.asarray(self._program_rows(n, self.n_max)) for n in (1, self.num_slots)]
+            calls += [(self._prefill_fn, chunk, whole[0]), (self._fused_fn, whole[1], ids, chunk, whole[0])]
         lowered, fused_s = [], 0.0
         for i, (fn, *args) in enumerate(calls):
             t = time.monotonic()
@@ -1754,7 +1719,7 @@ class LLMEngine:
         # rows reads them): a program's own output, like every other step's.
         self._no_ids = ids
         self.spans.setup["decode_build_s"] = time.monotonic() - t0 - fused_s
-        if self._fused_rungs:
+        if self._fuses:
             self.spans.setup["fused_build_s"] = fused_s
 
     def _register_prefix_blocks(self, req: LLMRequest):
@@ -1813,8 +1778,7 @@ class LLMEngine:
         changed hands never reads the tenant before's id.
 
         ``chunk``: the request whose next chunk goes into this step's program
-        (``decode_with_chunk``, at the rung the wider of rows and chunk
-        needs), if it still prefills once the rows have their blocks (a
+        (``decode_with_chunk``, at the whole table), if it still prefills once the rows have their blocks (a
         preemption for them may have taken it). With no row to decode nothing
         is dispatched and the chunk waits for the next pass."""
         riding = ahead_of.reqs if ahead_of is not None else ()
@@ -1872,14 +1836,9 @@ class LLMEngine:
 
             # The step gathers and attends over the table it is handed: the
             # smallest rung that covers the longest of ITS rows' tables, as
-            # the host has just extended them, and the blocks its chunk, if
-            # it has one, has filled and fills: the smallest such rung at
-            # which the step with a chunk is built (``_rides`` saw to it that
-            # there is one).
+            # the host has just extended them; with a chunk, the whole table.
             longest = max(len(r._sched_table) for r in active)
-            if chunk is not None:
-                longest = max(longest, -(-self._chunk_end(chunk) // bs))
-            width = next(w for w in (self._view_rungs if chunk is None else self._fused_rungs) if w >= longest)
+            width = self.n_max if chunk is not None else next(w for w in self._view_rungs if w >= longest)
             rows = self._program_rows(self.num_slots, width)
             context_tokens = window_tokens = 0
             window = self.cfg.sliding_window or self.max_model_len
@@ -1902,15 +1861,14 @@ class LLMEngine:
             inputs = [jnp.asarray(rows), ahead_of.ids if ahead_of is not None else self._no_ids]
         if chunk is not None:
             with spans.span("llm.prefill.build", rid=chunk.id, pos=chunk._sched_pos):
-                fed, chunk_rows, n = self._chunk_inputs(chunk, width)
+                fed, chunk_rows, n = self._chunk_inputs(chunk)
                 inputs += [jnp.asarray(fed), jnp.asarray(chunk_rows)]
             spans.carried(prefill_tokens=n, chunk_tokens=n)
             self._counts["decode_steps_with_chunk"] += 1
         self._width_steps[width] += 1
         self._counts["decode_steps"] += 1
         self._counts["decode_steps_run_ahead"] += ahead_of is not None
-        self._counts["latent_kernel_steps"] += self._latent_kernel
-        self._counts["kv_kernel_steps"] += self._kv_kernel
+        self._counts["latent_kernel_steps" if self.cfg.latent_attention else "kv_kernel_steps"] += self._reads_in_place
         with spans.span("llm.decode.dispatch"):
             ids = self._run_donated(self._decode_fn if chunk is None else self._fused_fn, *inputs)
         if chunk is not None and self._chunk_dispatched(chunk):

@@ -155,7 +155,7 @@ def test_twelve_plain_heads_cached_as_sixteen_serve_the_full_forward_passes_toke
     for fuse in (True, False):
         eng = _stopped((params, cfg))
         if not fuse:
-            eng._fused_rungs = ()
+            eng._fuses = False
         reqs = [eng.submit(_prompt(seed, n), max_new_tokens=new) for seed, n, new in specs]
         s = _drive(eng, reqs)
         assert (s["decode_steps_with_chunk"] > 0) == fuse
@@ -172,9 +172,9 @@ def test_the_pool_holds_what_the_two_programs_would_have_written(model):
     kept, streams = {}, {}
     for fuses in (True, False):
         eng = _stopped(model)
-        assert eng._fused_rungs == (16,)
+        assert eng._fuses
         if not fuses:
-            eng._fused_rungs = ()
+            eng._fuses = False
         kept[fuses] = {}
         _keep_rows_at_release(eng, kept[fuses])
         reqs = [eng.submit(_prompt(seed, n), max_new_tokens=new) for seed, n, new in SPECS]
@@ -232,7 +232,7 @@ def test_an_engine_fuses_by_its_shape_and_by_what_its_pool_holds(over, engine, f
     params = init_params(jax.random.PRNGKey(0), cfg)
     eng = LLMEngine(params, cfg, block_size=4, max_model_len=64, **engine)
     try:
-        assert bool(eng._fused_rungs) == fuses
+        assert eng._fuses == fuses
         if engine.get("role") == "prefill":
             return
         prompts = [_prompt(60 + i, n) for i, n in enumerate((19, 7, 26, 12))]
@@ -391,34 +391,29 @@ def test_a_dry_pool_preempts_the_prefilling_request_out_of_the_step_being_built(
     assert late.result(5) == _alone(model, _prompt(84, 30), 4)
 
 
-def test_a_pass_wider_than_the_step_with_a_chunk_is_built_keeps_the_two_programs(model):
-    """The step with a chunk is built at one rung, 16 blocks of this engine's
-    16 / 24 (64 tokens). While the decoding row is within it a prompt's chunks
-    ride its steps, rounded up to that rung; once the row has passed 64 tokens
-    a pass with a chunk runs the two programs (the chunk as a program of its
-    own beside a step in flight), and the streams are what they are alone
-    either way."""
+@pytest.mark.parametrize("runner_at", [0, 70], ids=["a_row_short_of_64_tokens", "a_row_past_them"])
+def test_chunks_ride_the_steps_however_long_the_decoding_row(model, runner_at):
+    """The step with a chunk is built once, at the whole table (24 blocks of
+    this engine's 16 / 24), so a prompt's chunks ride the steps of a decoding
+    row whether it is short of the narrower rung's 64 tokens or past them: no
+    chunk runs as a program of its own while a step is in flight, and the
+    streams are what they are alone either way."""
     eng = _stopped(model)
-    assert eng._view_rungs == (16, 24) and eng._fused_rungs == (16,)
+    assert eng._view_rungs == (16, 24) and eng._fuses
     prefill, alone = eng._prefill_fn, []
     eng._prefill_fn = lambda *a: (alone.append(eng._inflight is not None), prefill(*a))[1]
     runner = eng.submit(_prompt(85, 40), max_new_tokens=50)  # 40 -> 90 tokens: crosses 64
     _until_decoding(eng, runner)
     ran_alone = len(alone)  # the runner's own chunks: nothing decoded beside them
     assert ran_alone == 5 and not any(alone)
-    early = eng.submit(_prompt(86, 20), max_new_tokens=4)
-    while not early._finished:
+    while runner._sched_pos < runner_at:
         _pass(eng)
-    s = eng.stats()
-    assert s["decode_steps_with_chunk"] == 3 and len(alone) == ran_alone  # 20 tokens: three chunks, all inside steps
-    assert s["decode_width_steps"][24] == 0
-    while runner._sched_pos < 70:
-        _pass(eng)
-    late = eng.submit(_prompt(87, 20), max_new_tokens=4)
-    done = _drive(eng, [runner, late])
-    assert done["decode_steps_with_chunk"] == 3  # none since
-    assert len(alone) == ran_alone + 3 and all(alone[ran_alone:])  # its chunks ran alone, a step in flight each time
-    for req, (seed, n, new) in ((runner, (85, 40, 50)), (early, (86, 20, 4)), (late, (87, 20, 4))):
+    assert (runner._sched_pos < 64) == (runner_at == 0) and eng.stats()["decode_steps_with_chunk"] == 0
+    late = [eng.submit(_prompt(seed, 20), max_new_tokens=4) for seed in (86, 87)]
+    done = _drive(eng, [runner, *late])
+    assert done["decode_steps_with_chunk"] == 6 and len(alone) == ran_alone  # 20 tokens: three chunks each, all inside steps
+    assert done["decode_width_steps"][24] >= 6  # handed the whole table, whatever the rows hold
+    for req, (seed, n, new) in ((runner, (85, 40, 50)), (late[0], (86, 20, 4)), (late[1], (87, 20, 4))):
         assert req.result(5) == _alone(model, _prompt(seed, n), new)
 
 
@@ -476,8 +471,8 @@ def _backend_compiles(since):
 )
 def test_an_engine_that_fuses_builds_every_program_before_its_constructor_returns(over, fuses):
     """An engine whose shape fuses builds, before its scheduler starts, the
-    decode step at every rung, the step with a chunk at the widest rung but
-    one and the prefill program, and a mixed run across every rung builds
+    decode step at every rung, the step with a chunk (one, at the whole
+    table) and the prefill program, and a mixed run across every rung builds
     nothing more. One that does not keeps the start it had: the decode step at
     every rung, and its first request builds the prefill program."""
     import jax
@@ -493,7 +488,7 @@ def test_an_engine_that_fuses_builds_every_program_before_its_constructor_return
         init_params(jax.random.PRNGKey(0), cfg), cfg, num_slots=2, block_size=4, max_model_len=256, prefill_chunk=4
     )
     try:
-        assert eng._view_rungs == (16, 32, 64) and eng._fused_rungs == ((32,) if fuses else ())
+        assert eng._view_rungs == (16, 32, 64) and eng._fuses == fuses
         built = [name for name in _backend_compiles(since) if "lambda" in name or "prefill_chunk_row" in name]
         assert len(built) == (5 if fuses else 3) and ("jit(prefill_chunk_row)" in built) == fuses, built
         setup = eng.spans.setup

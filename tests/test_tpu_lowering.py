@@ -391,19 +391,13 @@ def test_a_scanned_slice_of_an_expert_stack_is_copied_and_a_whole_stack_is_not(o
     assert copied[sliced] == (True, True) and copied[whole] == (False, False)
 
 
-def _cell_programs(cell_name: str):
-    """(decode, prefill, their arguments as shapes) of a serving cell of the
-    benchmark at its published widths: ``args(width)`` for the decode program
-    at a rung, ``args(None)`` for the prefill chunk."""
-    import jax
+def _cell_config(cell_name: str):
+    """(the ``TransformerConfig`` of a serving cell of the benchmark at its
+    published widths, its engine's settings)."""
     import jax.numpy as jnp
 
     from benchmarks.harness import registry
-    from ray_tpu.models.generate import (
-        MOE_CHOICE, MOE_COUNTS, init_moe_choice, init_moe_counts, init_paged_cache, ring_blocks, state_kind,
-    )
-    from ray_tpu.models.transformer import TransformerConfig, init_params
-    from ray_tpu.serve.llm.engine import _ROW_TABLE, _STATE_COLS, _compiled_fns
+    from ray_tpu.models.transformer import TransformerConfig
 
     cell = registry.load_cell(registry.load_manifest(), cell_name)
     engine = cell["config"]["deployment"]["engine"]
@@ -411,7 +405,23 @@ def _cell_programs(cell_name: str):
         cell["config"], engine["max_model_len"], "bfloat16"
     )
     model.update(dtype=jnp.bfloat16, param_dtype=jnp.bfloat16)
-    cfg = TransformerConfig(**model)
+    return TransformerConfig(**model), engine
+
+
+def _cell_programs(cell_name: str):
+    """(decode, prefill, their arguments as shapes) of a serving cell of the
+    benchmark at its published widths: ``args(width)`` for the decode program
+    at a rung, ``args(None)`` for the prefill chunk."""
+    import jax
+    import jax.numpy as jnp
+
+    from ray_tpu.models.generate import (
+        MOE_CHOICE, MOE_COUNTS, init_moe_choice, init_moe_counts, init_paged_cache, ring_blocks, state_kind,
+    )
+    from ray_tpu.models.transformer import init_params
+    from ray_tpu.serve.llm.engine import _ROW_TABLE, _STATE_COLS, _compiled_fns
+
+    cfg, engine = _cell_config(cell_name)
     slots, chunk, bs = engine["num_slots"], engine.get("prefill_chunk", 32), engine["block_size"]
     linear = state_kind(cfg) is not None  # a state group a slot, two columns of a program row for it, and no ring
     ring = ring_blocks(cfg.sliding_window, chunk, bs) if "window" in cfg.layer_kinds else 0
@@ -480,35 +490,94 @@ def test_a_configuration_without_a_layer_pattern_keeps_the_programs_it_had(cell_
     assert tuple(hashlib.sha1(t.encode()).hexdigest() for t in texts) == _PROGRAMS_OF_PR_34[cell_name]
 
 
-def test_the_step_with_a_chunk_reads_the_weights_once_and_updates_the_pool_in_place(one_v5e_chip):
-    """Mistral-16's decode step that carries a prefill chunk (PR 40), at the
-    benchmark's widths and the 2048-token rung, compiled for the v5e: the
-    d_ff matmuls run once over 16 + 32 = 48 rows (none at 16 or at 32), the
-    pool is aliased to the output and never copied, each part gathers its own
-    view (16 tables and 1 table of 128 blocks), and the step's temporaries
-    stay tens of megabytes. In a trace it is found as a decode step."""
-    import re
+@pytest.mark.parametrize("cell_name", sorted(_PROGRAMS_OF_PR_34))
+def test_the_table_of_kinds_names_every_stack_and_every_group_of_the_pool(cell_name):
+    """``generate._layer_plan`` against what it is read in place of, at each
+    serving architecture's published widths (shapes only): its stacks are
+    ``transformer._layer_stacks``' keys, its groups' leaves are
+    ``init_paged_cache``'s, every kind of ``layer_kinds`` has a row, and its
+    segments cover the layers once, in order."""
+    import importlib
 
     import jax
 
-    from ray_tpu.serve.llm.engine import _JIT_CACHE
+    from ray_tpu.models.transformer import _layer_stacks
 
-    _, _, args = _cell_programs("serve16.chat-open")
-    ((cfg, ring),) = [key for key in _JIT_CACHE if key[0].d_ff == 14336 and key[0].n_layers == 16]
-    with_chunk = _JIT_CACHE[cfg, ring][2]
-    described = jax.tree.map(
-        lambda a: jax.ShapeDtypeStruct(a.shape, a.dtype, sharding=one_v5e_chip), args(128, with_chunk=True)
-    )
-    compiled = with_chunk.lower(*described).compile()
-    text = compiled.as_text()
-    assert re.search(r"HloModule (\S+?),", text).group(1).startswith("jit__lambda")
-    assert "bf16[48,14336]" in text and "bf16[16,14336]" not in text and "bf16[32,14336]" not in text
-    assert not re.search(rf"= {re.escape('bf16[16,2561,16,8,128]')}\S* copy\(", text)
-    gathered = set(re.findall(r"= bf16\[(\d+),16,8,128\]\S* fusion\(", text))
-    assert {"2048", "128"} <= gathered, gathered  # 16 x 128 blocks and 1 x 128 blocks
-    stats = compiled.memory_analysis()
-    assert stats.alias_size_in_bytes >= 2 * 16 * 2561 * 16 * 8 * 128 * 2  # 2.69 GB updated in place
-    assert stats.temp_size_in_bytes < 60e6, stats.temp_size_in_bytes  # 35 MB; the bare step's 3.4
+    generate = importlib.import_module("ray_tpu.models.generate")
+    cfg, _ = _cell_config(cell_name)
+    plan = generate._layer_plan(cfg)
+    assert {row.stack for segment in plan for row in segment.rows.values()} == set(_layer_stacks(cfg))
+    first = 0
+    for segment in plan:
+        assert segment.first == first and segment.kinds == cfg.layer_kinds[first : first + segment.depth]
+        assert set(segment.rows) == (set(segment.kinds) or {None})
+        first += segment.depth
+    assert first == cfg.n_layers
+    kinds = generate._kinds(cfg)
+    assert set(kinds) == (set(cfg.layer_kinds) or {None})
+    pool = jax.eval_shape(lambda: generate.init_paged_cache(cfg, 9, 16, window_blocks=5, state_slots=3))
+    leaves = {name: row for row in kinds.values() if row.reach for name in generate._group_rows(cfg, row)}
+    assert set(leaves) == set(pool)
+    held = {"table": (9, 16), "ring": (5, 16), "state": (3,)}
+    for name, row in leaves.items():
+        assert pool[name].shape[: 1 + len(held[row.reach])] == (row.layers, *held[row.reach]), name
+    assert generate.pool_reach(cfg) == {row.reach for row in leaves.values()}
+
+
+def _without_locations(text: str) -> str:
+    """A lowered program's text with each Mosaic kernel's body, which the text
+    carries as bytecode WITH the file and line of every frame that traced it
+    (this checkout's path, ``generate.py``'s line numbers), replaced by the
+    digest of that body printed without them."""
+    import base64
+    import hashlib
+    import re
+
+    from jax._src.interpreters import mlir
+    from jax._src.lib.mlir import ir
+
+    def digest(body):
+        context = mlir.make_ir_context()
+        context.allow_unregistered_dialects = True
+        with context:
+            asm = ir.Module.parse(base64.b64decode(body.group(1))).operation.get_asm(enable_debug_info=False)
+        return '\\22body\\22: \\22' + hashlib.sha1(asm.encode()).hexdigest() + '\\22'
+
+    text, found = re.subn(r'\\22body\\22: \\22([A-Za-z0-9+/=]+)\\22', digest, text)
+    assert found, "no kernel in this program"
+    return text
+
+
+# The same of the programs a TPU backend gets where a kernel reads the pool in
+# place, which the table above cannot hold (a CPU backend lowers the view):
+# Mistral-16's decode step and its step with a chunk at the whole table (160
+# blocks), GLM's decode step at its whole table (256), as PR 45's tree gives
+# them (computed from a copy of that commit, PR 46).
+_KERNEL_PROGRAMS_OF_PR_45 = {
+    ("serve16.chat-open", "step"): "300a106ea60589510bbff1569b8ba27f96bd2eb2",
+    ("serve16.chat-open", "step_with_chunk"): "1454ae8052439a18d8c24bbd91f3bd33418535d1",
+    ("glm8.rollout-long", "step"): "a0e0493aaa16e5b669e7069b5a8d9741974030e0",
+}
+
+
+@pytest.mark.parametrize("cell_name, program", sorted(_KERNEL_PROGRAMS_OF_PR_45))
+def test_the_programs_that_read_a_pool_in_place_are_the_ones_pr_45_built(cell_name, program, monkeypatch):
+    """Lowered for the TPU from here, as ``_PROGRAMS_OF_PR_34``'s are, with the
+    predicate told what it would see there."""
+    import hashlib
+    import importlib
+
+    for module in ("ray_tpu.ops.attention", "ray_tpu.ops.paged_attention", "ray_tpu.ops.latent_attention"):
+        monkeypatch.setattr(importlib.import_module(module), "_on_tpu", lambda: True)
+    engine = importlib.import_module("ray_tpu.serve.llm.engine")
+    monkeypatch.setattr(engine, "_JIT_CACHE", {})
+    _, _, args = _cell_programs(cell_name)
+    (fns,) = engine._JIT_CACHE.values()
+    settings = _cell_config(cell_name)[1]
+    n_max = -(-settings["max_model_len"] // settings["block_size"])
+    with_chunk = program == "step_with_chunk"
+    text = fns[2 if with_chunk else 0].trace(*args(n_max, with_chunk=with_chunk)).lower(lowering_platforms=("tpu",)).as_text()
+    assert hashlib.sha1(_without_locations(text).encode()).hexdigest() == _KERNEL_PROGRAMS_OF_PR_45[cell_name, program]
 
 
 @pytest.mark.parametrize("program", ["step", "step_with_chunk"])
@@ -519,7 +588,9 @@ def test_the_kv_decode_programs_read_the_pool_in_place_on_a_tpu(one_v5e_chip, pr
     values where they lie (one custom call, its result ``[slots, KV, group,
     Dh]``), no ``[rows, 16, 8, 128]`` view is gathered for them (the step with
     a chunk gathers its chunk's one table and nothing else), the pool is
-    aliased and never copied, and the temporaries stay megabytes."""
+    aliased and never copied, and the temporaries stay megabytes. The step with
+    a chunk (PR 40) runs the d_ff matmuls once over 16 + 32 = 48 rows, none at
+    16 or at 32. In a trace either is found as a decode step."""
     import importlib
     import re
 
@@ -541,6 +612,7 @@ def test_the_kv_decode_programs_read_the_pool_in_place_on_a_tpu(one_v5e_chip, pr
     assert re.search(r"= bf16\[16,8,4,128\]\S* custom-call\(.*tpu_custom_call", text)
     gathered = set(re.findall(r"= bf16\[(\d+),16,8,128\]\S* fusion\(", text))
     assert gathered == ({"160"} if with_chunk else set()), gathered
+    assert ("bf16[48,14336]" in text) == with_chunk and ("bf16[16,14336]" in text) != with_chunk and "bf16[32,14336]" not in text
     assert not re.search(rf"= {re.escape('bf16[16,2561,16,8,128]')}\S* copy\(", text)
     stats = compiled.memory_analysis()
     assert stats.alias_size_in_bytes >= 2 * 16 * 2561 * 16 * 8 * 128 * 2  # 2.69 GB updated in place
