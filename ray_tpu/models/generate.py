@@ -60,8 +60,10 @@ from ray_tpu.models.transformer import (
     _period,
     _rms_norm,
     _rope,
+    latent_softmax_scale,
 )
 from ray_tpu.ops import attention as _attention_ops
+from ray_tpu.ops import hyper_connection
 from ray_tpu.ops.latent_attention import paged_latent_attention
 from ray_tpu.ops.paged_attention import paged_attention
 from ray_tpu.parallel.moe import grouped_matmul_tiles
@@ -310,12 +312,12 @@ def _project_latent(lp, x, positions, cfg):
     q_nope, q_rope = q[..., : cfg.qk_nope_head_dim], q[..., cfg.qk_nope_head_dim :]
     kv = h @ lp["wkv_a"].astype(h.dtype)
     c = _rms_norm(kv[..., :R], lp["kv_norm"], cfg.norm_eps)
-    k_rope = _rope(kv[..., None, R:], positions, cfg.rope_theta)[:, :, 0]
+    k_rope = _rope(kv[..., None, R:], positions, cfg.rope_theta, cfg.rope_scaling)[:, :, 0]
     w_uk, _ = _latent_kv_up(lp, cfg)
     q_abs = jnp.einsum("bthn,rhn->bthr", q_nope, w_uk.astype(h.dtype))
     pad = _latent_row_width(cfg) - R - P
     q_full = jnp.concatenate(
-        [q_abs, _rope(q_rope, positions, cfg.rope_theta), jnp.zeros((B, T, H, pad), q.dtype)], axis=-1
+        [q_abs, _rope(q_rope, positions, cfg.rope_theta, cfg.rope_scaling), jnp.zeros((B, T, H, pad), q.dtype)], axis=-1
     )
     return q_full, {"ckv": jnp.concatenate([c, k_rope, jnp.zeros((B, T, pad), c.dtype)], axis=-1)}
 
@@ -327,7 +329,7 @@ def _latent_attention(lp, q, view, pos_mask, cfg):
     W_uv, so no per-head key or value of a cached token is ever formed."""
     ckv = view["ckv"]
     R = cfg.kv_lora_rank
-    scale = (cfg.qk_nope_head_dim + cfg.qk_rope_head_dim) ** -0.5
+    scale = latent_softmax_scale(cfg)
     s = jnp.einsum("bqhr,bkr->bhqk", q, ckv, preferred_element_type=jnp.float32)
     s = jnp.where(pos_mask[:, None], s * scale, -jnp.inf)
     p = jax.nn.softmax(s, axis=-1)
@@ -388,8 +390,7 @@ def _latent_attention_in_place(lp, q, ckv, at, tables, positions, cfg):
     ``positions`` [B, 1], over layer ``at`` of the pool leaf ``ckv`` [L, N,
     Bs, W] through ``tables`` [B, n_max], with no view: the kernel returns
     what the ``bhqk,bkr->bhqr`` product does (``_decode_lengths``)."""
-    scale = (cfg.qk_nope_head_dim + cfg.qk_rope_head_dim) ** -0.5
-    o = paged_latent_attention(q, ckv, at, tables, _decode_lengths(tables, positions), sm_scale=scale)
+    o = paged_latent_attention(q, ckv, at, tables, _decode_lengths(tables, positions), sm_scale=latent_softmax_scale(cfg))
     return _latent_values(lp, o[..., : cfg.kv_lora_rank], cfg)
 
 
@@ -406,46 +407,71 @@ def _relu2(h, wi, wo):
 _EXPERT_STACKS = ("wg_e", "wi_e", "wo_e")
 
 
+def _residual(lp, x, sub, cfg, branch):
+    """THE join of a sub-layer's branch with the residual path, every kind of
+    layer's: ``branch(u) -> (out, whatever else it returns)`` reads the path's
+    value a token ``u`` [B, q, D] (and norms it itself) and its ``out`` [B, q, D]
+    joins the path. Returns (the path after the join, what else the branch
+    returned). The plain residual: ``u`` is ``x`` and the join ``x + out``.
+    Hyper-connections (``cfg.hc_mult``; ops/hyper_connection.py): ``x`` is the
+    stream [B, q, hc_mult * D], ``u`` a mixture of its rows by the leaves
+    ``hc_<sub>_*`` of ``lp`` (``sub``: ``"attn"``, the mixer's, or ``"mlp"``),
+    and ``out`` is written back to every row beside a mixing of the rows."""
+    if not cfg.hc_mult:
+        out, rest = branch(x)
+        return x + out, rest
+    with jax.named_scope("hyper_connection_mix"):
+        u, post, res = hyper_connection.mix(x, lp[f"hc_{sub}_phi"], lp[f"hc_{sub}_b"], lp[f"hc_{sub}_alpha"], cfg)
+    out, rest = branch(u)
+    with jax.named_scope("hyper_connection_join"):
+        return hyper_connection.join(x, out, post, res), rest
+
+
 def _mlp(lp, x, cfg, valid=None, layer=None):
-    """(x + MLP(norm(x)), the MLP this layer's leaves hold; with routed
-    experts the tokens sent to each expert [E] int32 and each row's experts
-    [B, q, k] int32, else None and None).
+    """(the residual path joined with MLP(norm(.)) (``_residual``), the MLP this
+    layer's leaves hold; with routed experts the tokens sent to each expert [E]
+    int32 and each row's experts [B, q, k] int32, else None and None).
     ``valid`` [B, q] marks the rows that are real tokens (routed experts
     only: padding reaches no expert and is not counted). ``layer``: the
     expert leaves of ``lp`` are whole stacks and this is the layer to run
     (``routed_experts``)."""
-    h = _rms_norm(x, lp["mlp_norm"], cfg.norm_eps) if cfg.pre_norms else x
 
-    def add(out):  # ``cfg.post_norms``: the branch is normed once more before it joins the residual
-        return x + (_rms_norm(out, lp["mlp_post_norm"], cfg.norm_eps) if cfg.post_norms else out)
+    def branch(u):
+        h = _rms_norm(u, lp["mlp_norm"], cfg.norm_eps) if cfg.pre_norms else u
 
-    if "gate" not in lp:
-        return add(_swiglu(h, lp["wg"], lp["wi"], lp["wo_mlp"])), None, None
-    if cfg.routed_experts:
-        from ray_tpu.parallel.moe import routed_experts
+        def post(out):  # ``cfg.post_norms``: the branch is normed once more before it joins the residual
+            return _rms_norm(out, lp["mlp_post_norm"], cfg.norm_eps) if cfg.post_norms else out
 
-        B, q, D = h.shape
-        out, sent, chosen = routed_experts(
-            lp, h.reshape(B * q, D), k=cfg.experts_per_token, scale=cfg.routed_scaling_factor,
-            valid=None if valid is None else valid.reshape(B * q), layer=layer, share=cfg.expert_share,
-        )
-        out = out.reshape(B, q, D)
-        if "wg_s" in lp:
-            out = out + _swiglu(h, lp["wg_s"], lp["wi_s"], lp["wo_s"])
-        elif "wi_s" in lp:
-            out = out + _relu2(h, lp["wi_s"], lp["wo_s"])
-        return add(out), sent, chosen.reshape(B, q, -1)
-    from ray_tpu.models.transformer import _moe_mlp
+        if "gate" not in lp:
+            return post(_swiglu(h, lp["wg"], lp["wi"], lp["wo_mlp"])), (None, None)
+        if cfg.routed_experts:
+            from ray_tpu.parallel.moe import routed_experts
 
-    # LOSSLESS dispatch at inference: capacity_factor=E gives every
-    # token a slot (capacity == T), so routing is per-token and
-    # independent of batch padding — ragged rows behave exactly like
-    # solo rows, and prefill agrees with T=1 decode. Training's
-    # capacity drops (expert_capacity_factor) are an efficiency
-    # approximation that inference deliberately does not replicate.
-    # Aux loss is meaningless at inference and discarded.
-    out, _aux = _moe_mlp(lp, h, float(cfg.num_experts))
-    return add(out), None, None
+            B, q, D = h.shape
+            out, sent, chosen = routed_experts(
+                lp, h.reshape(B * q, D), k=cfg.experts_per_token, scale=cfg.routed_scaling_factor,
+                valid=None if valid is None else valid.reshape(B * q), layer=layer, share=cfg.expert_share,
+            )
+            out = out.reshape(B, q, D)
+            if "wg_s" in lp:
+                out = out + _swiglu(h, lp["wg_s"], lp["wi_s"], lp["wo_s"])
+            elif "wi_s" in lp:
+                out = out + _relu2(h, lp["wi_s"], lp["wo_s"])
+            return post(out), (sent, chosen)
+        from ray_tpu.models.transformer import _moe_mlp
+
+        # LOSSLESS dispatch at inference: capacity_factor=E gives every
+        # token a slot (capacity == T), so routing is per-token and
+        # independent of batch padding — ragged rows behave exactly like
+        # solo rows, and prefill agrees with T=1 decode. Training's
+        # capacity drops (expert_capacity_factor) are an efficiency
+        # approximation that inference deliberately does not replicate.
+        # Aux loss is meaningless at inference and discarded.
+        out, _aux = _moe_mlp(lp, h, float(cfg.num_experts))
+        return post(out), (None, None)
+
+    x, (sent, chosen) = _residual(lp, x, "mlp", cfg, branch)
+    return x, sent, None if chosen is None else chosen.reshape(*x.shape[:2], -1)
 
 
 def _cache_attention(q, ck, cv, pos_mask, cfg):
@@ -504,12 +530,14 @@ def _cache_attention_in_place(q, k, v, at, tables, positions, cfg):
 
 def _embed_chunk(params, tokens, pos, cfg):
     """tokens [B, q] fed at positions pos[b].. (``pos`` [B] or a scalar):
-    embeddings [B, q, D] and positions [B, q]."""
+    what the residual path starts from [B, q, D] (``_residual``) and positions [B, q]."""
     B, q = tokens.shape
     pos_b = jnp.broadcast_to(pos, (B,))
     x = params["embed"].astype(cfg.dtype)[tokens]
     if cfg.embed_multiplier != 1.0:
         x = x * cfg.embed_multiplier
+    if cfg.hc_mult:  # the residual path starts as hc_mult rows of the embedding: [B, q, hc_mult * D]
+        x = hyper_connection.widen(x, cfg.hc_mult)
     offs = jnp.arange(q, dtype=jnp.int32)
     return x, pos_b[:, None] + offs[None, :]
 
@@ -755,8 +783,9 @@ def _linear_mixer(lp, x, pool, at, acc: _StateAccess, cfg):
 
 
 def _cached_layers(params, x, cache, positions, access, cfg, key_len=None, valid=None, parts=None):
-    """THE layer stack over a cache, dense or paged: x [B, q, D] at
-    ``positions`` [B, q] -> (final normed hidden states, cache).
+    """THE layer stack over a cache, dense or paged: x [B, q, D] (``_embed_chunk``'s: under
+    hyper-connections the stream, [B, q, hc_mult * D], which the layer scan carries) at
+    ``positions`` [B, q] -> (final normed hidden states [B, q, D], cache).
 
     ``parts`` (in place of ``access``; ``paged_decode_step_with_chunk``): x is
     [1, n, D], several batches of different shapes laid side by side
@@ -844,41 +873,53 @@ def _cached_layers(params, x, cache, positions, access, cfg, key_len=None, valid
         row = kinds_of[kind]
         acc, sfx = access.get(row.reach) if access else None, row.group
         if kind == _MAMBA:  # a block that is this mixer and nothing else
-            o, pool = _mamba_mixer(lp, x, pool, at, acc, cfg)
-            return x + o @ lp["wo"].astype(o.dtype), pool, None
+            def mamba(u):
+                o, moved = _mamba_mixer(lp, u, pool, at, acc, cfg)
+                return o @ lp["wo"].astype(o.dtype), moved
+
+            return *_residual(lp, x, "attn", cfg, mamba), None
         if kind == _EXPERTS:  # a block that is its experts and nothing else
             x, sent, chosen = _mlp(lp, x, cfg, valid, layer=l - first if held else None)
             return x, record_choice(pool, chosen, l, first), sent
+
+        def post(a):  # ``cfg.post_norms``: the branch is normed once more before it joins the residual
+            return _rms_norm(a, lp["attn_post_norm"], cfg.norm_eps) if cfg.post_norms else a
+
         if kind == _LINEAR:
-            o, pool = _linear_mixer(lp, x, pool, at, acc, cfg)
-            a = o @ lp["wo"].astype(o.dtype)
-            x = x + (_rms_norm(a, lp["attn_post_norm"], cfg.norm_eps) if cfg.post_norms else a)
+            def linear(u):
+                o, moved = _linear_mixer(lp, u, pool, at, acc, cfg)
+                return post(o @ lp["wo"].astype(o.dtype)), moved
+
+            x, pool = _residual(lp, x, "attn", cfg, linear)
             return _mlp(lp, x, cfg)[0], pool, None
-        project = _project_latent if latent else _project_qkv if kind != _FULL else partial(_project_qkv, rope=False)
-        qh, rows = project(lp, x, positions, cfg)
-        if parts is not None:
-            o, pool = attend_parts(pool, qh, rows, at)
-        else:
-            pool = {**pool, **{name + sfx: acc.write(pool[name + sfx], at, row) for name, row in rows.items()}}
-            with jax.named_scope("cache_attention" + (f"_{kind}" if kind else "")):
-                if in_place[0] and row.reach == "table" and latent:  # the rows just written are read where they lie
-                    o = _latent_attention_in_place(lp, qh, pool["ckv"], at, acc.tables, positions, cfg)
-                elif in_place[0] and row.reach == "table":
-                    o = _cache_attention_in_place(qh, pool["k"], pool["v"], at, acc.tables, positions, cfg).reshape(B, q, -1)
-                else:
-                    seen = {name: acc.view(pool[name + sfx], at) for name in rows}
-                    n_keys = next(iter(seen.values())).shape[1]
-                    window = 0 if kind == _FULL else cfg.sliding_window
-                    mask = _cache_mask(positions, n_keys, window, key_len, acc.key_pos)
-                    if latent:
-                        o = _latent_attention(lp, qh, seen, mask, cfg)
+
+        def attention(u, pool=pool):
+            project = _project_latent if latent else _project_qkv if kind != _FULL else partial(_project_qkv, rope=False)
+            qh, rows = project(lp, u, positions, cfg)
+            if parts is not None:
+                o, pool = attend_parts(pool, qh, rows, at)
+            else:
+                pool = {**pool, **{name + sfx: acc.write(pool[name + sfx], at, row) for name, row in rows.items()}}
+                with jax.named_scope("cache_attention" + (f"_{kind}" if kind else "")):
+                    if in_place[0] and row.reach == "table" and latent:  # the rows just written are read where they lie
+                        o = _latent_attention_in_place(lp, qh, pool["ckv"], at, acc.tables, positions, cfg)
+                    elif in_place[0] and row.reach == "table":
+                        o = _cache_attention_in_place(qh, pool["k"], pool["v"], at, acc.tables, positions, cfg).reshape(B, q, -1)
                     else:
-                        o = _cache_attention(qh, seen["k"], seen["v"], mask, cfg).reshape(B, q, -1)
-        if cfg.attn_gate:
-            gate = _rms_norm(x, lp["attn_norm"], cfg.norm_eps) @ lp["wg_attn"].astype(x.dtype)
-            o = o * jax.nn.sigmoid(gate)
-        a = o @ lp["wo"].astype(o.dtype)
-        x = x + (_rms_norm(a, lp["attn_post_norm"], cfg.norm_eps) if cfg.post_norms else a)
+                        seen = {name: acc.view(pool[name + sfx], at) for name in rows}
+                        n_keys = next(iter(seen.values())).shape[1]
+                        window = 0 if kind == _FULL else cfg.sliding_window
+                        mask = _cache_mask(positions, n_keys, window, key_len, acc.key_pos)
+                        if latent:
+                            o = _latent_attention(lp, qh, seen, mask, cfg)
+                        else:
+                            o = _cache_attention(qh, seen["k"], seen["v"], mask, cfg).reshape(B, q, -1)
+            if cfg.attn_gate:
+                gate = _rms_norm(u, lp["attn_norm"], cfg.norm_eps) @ lp["wg_attn"].astype(u.dtype)
+                o = o * jax.nn.sigmoid(gate)
+            return post(o @ lp["wo"].astype(o.dtype)), pool
+
+        x, pool = _residual(lp, x, "attn", cfg, attention)
         if cfg.single_mixer:  # an attention block: no MLP behind it
             return x, pool, None
         x, sent, chosen = _mlp(lp, x, cfg, valid, layer=l - first if held else None)
@@ -983,6 +1024,8 @@ def _cached_layers(params, x, cache, positions, access, cfg, key_len=None, valid
             columns = [sent, touched, jnp.max(sent, axis=-1, keepdims=True), jnp.minimum(touched, 1)]
         step = jnp.concatenate(columns, axis=-1)
         pool[MOE_COUNTS] = counts.at[0 if q == 1 else 1].add(step.astype(counts.dtype))
+    if cfg.hc_mult:  # the stream ends as the sum of its rows
+        x = hyper_connection.collapse(x, cfg.hc_mult)
     return _rms_norm(x, params["norm_f"], cfg.norm_eps), pool
 
 

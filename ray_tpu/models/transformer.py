@@ -149,6 +149,24 @@ class TransformerConfig:
     # the table is drawn that much smaller, so that a layer's branch weighs as
     # much beside the residual as without it.
     embed_multiplier: float = 1.0
+    # Manifold-constrained hyper-connections (inference only; ops/hyper_connection.py):
+    # the residual path is ``hc_mult`` streams (0: the plain residual, ``x +
+    # F(norm(x))``). A sub-layer reads a learned, input-dependent mixture of them
+    # and writes back through a doubly stochastic ``hc_mult`` x ``hc_mult``
+    # matrix that ``hc_sinkhorn_iters`` Sinkhorn iterations (denominators +
+    # ``hc_eps``) make of ``exp`` of logits clipped to ``hc_res_clamp``; three
+    # float32 leaves a sub-layer (``_layer_leaves``).
+    hc_mult: int = 0
+    hc_sinkhorn_iters: int = 20
+    hc_eps: float = 1e-6
+    hc_res_clamp: tuple = (-30.0, 30.0)
+    # YaRN (inference only, latent attention only): the six numbers of a
+    # published ``rope_scaling`` of that type (``factor``,
+    # ``original_max_position_embeddings``, ``beta_fast``, ``beta_slow``,
+    # ``mscale``, ``mscale_all_dim``) as sorted pairs, or empty for none. They
+    # change the rotary frequencies (``_rope_tables``) and the softmax scale of
+    # latent attention (``latent_softmax_scale``).
+    rope_scaling: tuple = ()
     # Fuse the LM-head projection into a chunked cross-entropy
     # (ops/losses.fused_lm_loss) so the [B*T, V] f32 logits tensor never
     # hits HBM — loss_fn only; forward() still returns full logits for
@@ -163,6 +181,18 @@ class TransformerConfig:
         kinds = tuple(self.layer_kinds)
         object.__setattr__(self, "layer_kinds", kinds)
         object.__setattr__(self, "expert_share", tuple(self.expert_share))
+        object.__setattr__(self, "hc_res_clamp", tuple(float(v) for v in self.hc_res_clamp))
+        scaling = dict(self.rope_scaling)
+        if scaling.pop("type", "yarn") != "yarn" or (scaling and set(scaling) != set(_YARN_KEYS)):
+            raise ValueError(f"rope_scaling {dict(self.rope_scaling)!r}: type 'yarn' with {', '.join(_YARN_KEYS)}, or none")
+        object.__setattr__(self, "rope_scaling", tuple(sorted((k, float(v)) for k, v in scaling.items())))
+        for field, what in (
+            (self.rope_scaling and not self.latent_attention, "rope_scaling without latent attention (kv_lora_rank > 0)"),
+            (self.hc_mult and not self.latent_attention, "hyper-connections (hc_mult > 0) without latent attention (kv_lora_rank > 0)"),
+            (self.hc_mult and kinds, "hyper-connections (hc_mult > 0) under a layer pattern (layer_kinds)"),
+        ):
+            if field:
+                raise ValueError(f"{what}: has not run and is not built")
         if kinds and (len(kinds) != self.n_layers or set(kinds) - {"window", "full", "linear", "mamba", "experts"}):
             raise ValueError(
                 f"layer_kinds names {len(kinds)} layers {sorted(set(kinds))}: need "
@@ -284,7 +314,14 @@ class TransformerConfig:
             missing.append("a head_dim other than d_model // n_heads has no training block")
         if self.embed_multiplier != 1.0:
             missing.append("an embedding multiplier (embed_multiplier) has no training block")
+        if self.hc_mult:
+            missing.append("a residual path of several streams (hc_mult) has no training block")
+        if self.rope_scaling:
+            missing.append("scaled rotary frequencies (rope_scaling) have no training block")
         return "; ".join(missing)
+
+
+_YARN_KEYS = ("factor", "original_max_position_embeddings", "beta_fast", "beta_slow", "mscale", "mscale_all_dim")
 
 
 def _period(kinds: tuple) -> int:
@@ -368,6 +405,14 @@ def _log_uniform_dt_bias(z):
     return dt + jnp.log(-jnp.expm1(-dt))
 
 
+def _hc_bias(z):
+    """Drawn values [2n + n^2] -> a hyper-connection's ``b``: + 2 on the diagonal
+    of ``H_res``'s logits (the last n^2, row-major), so that a stream keeps most
+    of itself and the matrix is neither the identity nor uniform."""
+    n = math.isqrt(z.shape[-1] + 1) - 1
+    return z.at[..., 2 * n + (n + 1) * jnp.arange(n)].add(2.0)
+
+
 def _layer_leaves(cfg: TransformerConfig, mlp, mixer="attention") -> dict:
     """One layer's leaves by name. ``mlp`` is ``"dense"``, ``"switch"``,
     ``"routed"`` or None (a block that is a mixer alone); ``mixer`` is
@@ -386,6 +431,18 @@ def _layer_leaves(cfg: TransformerConfig, mlp, mixer="attention") -> dict:
             **({"attn_norm": _Leaf(None, (D,), None, (None,))} if mixer else {}),
             **({"mlp_norm": _Leaf(None, (D,), None, (None,))} if mlp else {}),
         }
+    if cfg.hc_mult:
+        # A sub-layer's hyper-connection (ops/hyper_connection.py), float32 like a
+        # router: ``phi`` stored [2n + n^2, n D] (the stream's width on the lanes;
+        # columns in the order [pre | post | res]), ``b``, and ``alpha`` = 1. A
+        # trained model starts at ``alpha`` near 0, a constant mixing: here ``m``
+        # is of order 1, so that the mixing depends on the token.
+        n = cfg.hc_mult
+        width = 2 * n + n * n
+        for sub, k in ([("attn", 14)] if mixer else []) + ([("mlp", 16)] if mlp else []):
+            leaves[f"hc_{sub}_phi"] = _Leaf(k, (width, n * D), (n * D) ** -0.5, (None, None), jnp.float32)
+            leaves[f"hc_{sub}_b"] = _Leaf(k + 1, (width,), 0.5, (None,), jnp.float32, _hc_bias)
+            leaves[f"hc_{sub}_alpha"] = _Leaf(None, (3,), None, (None,), jnp.float32)
     if mixer == "mamba":
         Hm, P, N, G, K = cfg.mamba_heads, cfg.mamba_head_dim, cfg.ssm_state, cfg.ssm_groups, cfg.mamba_conv
         inner, channels = Hm * P, Hm * P + 2 * G * N
@@ -566,7 +623,7 @@ def init_params(key, cfg: TransformerConfig) -> dict:
     # stack then splits sixteen keys of its own from ``fold_in(key, stack)``.
     own_keys = (
         cfg.latent_attention or cfg.routed_experts or cfg.first_dense_layers > 0 or cfg.attn_gate
-        or "linear" in cfg.layer_kinds or cfg.single_mixer
+        or "linear" in cfg.layer_kinds or cfg.single_mixer or cfg.hc_mult > 0
     )
 
     # Every drawn leaf is a program of its own to compile (about a second each
@@ -577,7 +634,7 @@ def init_params(key, cfg: TransformerConfig) -> dict:
     drawn = _Drawn(key)
 
     def stack(i, of: _Stack):
-        keys = jax.random.split(jax.random.fold_in(key, i), 16) if own_keys else ks
+        keys = jax.random.split(jax.random.fold_in(key, i), 18 if cfg.hc_mult else 16) if own_keys else ks
         return {
             name: jnp.full((of.depth, *leaf.shape), 1.0 if leaf.scale is None else leaf.scale, leaf.dtype or dt)
             if leaf.key is None
@@ -646,14 +703,42 @@ def _rms_norm(x, weight, eps):
     return (x * jax.lax.rsqrt(var + eps)).astype(x.dtype) * weight.astype(x.dtype)
 
 
-def _rope_tables(positions, Dh: int, theta):
+def _yarn_mscale(factor: float, mscale: float) -> float:
+    return 0.1 * mscale * math.log(factor) + 1.0 if factor > 1.0 else 1.0
+
+
+def latent_softmax_scale(cfg: TransformerConfig) -> float:
+    """THE softmax scale of latent attention: ``(nope + rope)^-1/2``, under
+    YaRN times ``(0.1 mscale_all_dim ln factor + 1)^2`` (the view path, the
+    kernel's ``sm_scale``; the benchmark's reference states its own)."""
+    scale = (cfg.qk_nope_head_dim + cfg.qk_rope_head_dim) ** -0.5
+    yarn = dict(cfg.rope_scaling)
+    return scale * _yarn_mscale(yarn["factor"], yarn["mscale_all_dim"]) ** 2 if yarn.get("mscale_all_dim") else scale
+
+
+def _rope_tables(positions, Dh: int, theta, scaling: tuple = ()):
     """cos/sin rotation tables [B, T, Dh/2] for the given positions. The
     training path computes these ONCE per step (forward_hidden) instead of
     per layer per projection — positions are layer-invariant, and 16 sin+cos
-    sweeps per step over [B,T,Dh/2] is pure wasted VPU time."""
-    freqs = theta ** (-jnp.arange(0, Dh // 2, dtype=jnp.float32) / (Dh // 2))
+    sweeps per step over [B,T,Dh/2] is pure wasted VPU time.
+
+    ``scaling`` (``TransformerConfig.rope_scaling``; YaRN): pair i turns at its
+    own frequency where it completes more than ``beta_fast`` turns over the
+    original positions, at a ``factor``-th of it where fewer than
+    ``beta_slow``, and in between by a linear ramp over the pairs; cos and sin
+    are multiplied by the ratio of the two ``mscale`` terms."""
+    half = Dh // 2
+    freqs = theta ** (-jnp.arange(0, half, dtype=jnp.float32) / half)
+    yarn, amplitude = dict(scaling), 1.0
+    if yarn:
+        turns_at = lambda r: Dh * math.log(yarn["original_max_position_embeddings"] / (2 * math.pi * r)) / (2 * math.log(theta))  # noqa: E731
+        low, high = max(math.floor(turns_at(yarn["beta_fast"])), 0), min(math.ceil(turns_at(yarn["beta_slow"])), Dh - 1)
+        ramp = jnp.clip((jnp.arange(half, dtype=jnp.float32) - low) / max(high - low, 1e-3), 0.0, 1.0)
+        freqs = freqs * (1.0 - ramp) + freqs / yarn["factor"] * ramp
+        amplitude = _yarn_mscale(yarn["factor"], yarn["mscale"]) / _yarn_mscale(yarn["factor"], yarn["mscale_all_dim"])
     angles = positions[:, :, None].astype(jnp.float32) * freqs[None, None, :]
-    return jnp.cos(angles), jnp.sin(angles)
+    cos, sin = jnp.cos(angles), jnp.sin(angles)
+    return (cos, sin) if amplitude == 1.0 else (cos * amplitude, sin * amplitude)
 
 
 def _rope_apply(x, cos, sin):
@@ -664,9 +749,9 @@ def _rope_apply(x, cos, sin):
     return jnp.concatenate([rx1, rx2], axis=-1).astype(x.dtype)
 
 
-def _rope(x, positions, theta):
+def _rope(x, positions, theta, scaling: tuple = ()):
     # Convenience form (decode paths in models/generate.py use this).
-    cos, sin = _rope_tables(positions, x.shape[-1], theta)
+    cos, sin = _rope_tables(positions, x.shape[-1], theta, scaling)
     return _rope_apply(x, cos, sin)
 
 

@@ -188,7 +188,7 @@ def routed_experts(params, x, *, k: int, scale: float = 1.0, valid=None, layer=N
             h = jax.nn.silu(grouped(xs, params["wg_e"])) * grouped(xs, params["wi_e"])
         else:
             h = jnp.square(jax.nn.relu(grouped(xs, params["wi_e"])))
-        ys = grouped(h, params["wo_e"])  # [N * k, D], rows of no group are zero
+        ys = grouped(h, params["wo_e"])  # [N * k, D]; a row of no group holds whatever the kernel left there
     # Un-sort by gather (assignment a sits at sorted row inverse[a]), combine in float32.
     inverse = jnp.zeros((N * k,), jnp.int32).at[order].set(jnp.arange(N * k, dtype=jnp.int32))
     y = ys[inverse].reshape(N, k, D).astype(jnp.float32)
@@ -196,5 +196,10 @@ def routed_experts(params, x, *, k: int, scale: float = 1.0, valid=None, layer=N
         first = share[0] * held
         y = jnp.where(((chosen >= first) & (chosen < first + held))[..., None], y, 0.0)
     if valid is not None:
+        # A row that is no token (an inactive slot, a chunk's padding) is in no group, and what the grouped matmul
+        # leaves in such a row is whatever was there: zeros at some widths, at others (3584 x 1024, v5e, PR 47) not
+        # even finite, and a weight of 0 does not mask that. Such a row's output is read by nobody, but its cached
+        # row lands in the null block, which every gathered view holds behind its mask, where 0 x NaN is NaN.
+        y = jnp.where(valid[:, None, None], y, 0.0)
         w = jnp.where(valid[:, None], w, 0.0)
     return jnp.einsum("nk,nkd->nd", w, y).astype(x.dtype), sizes, chosen

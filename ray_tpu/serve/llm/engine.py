@@ -541,6 +541,7 @@ class LLMEngine:
             ring_blocks,
             state_slot_bytes,
         )
+        from ray_tpu.models.transformer import latent_softmax_scale
 
         if role not in ("both", "prefill", "decode"):
             raise ValueError(f"role must be both|prefill|decode, got {role!r}")
@@ -581,6 +582,7 @@ class LLMEngine:
         # value leaves, on a TPU), stops at each row's length whatever the
         # table's width: the whole table, one program, no ladder.
         self._reads_in_place = kernel_reads(cfg, paged=True, q=1)
+        self._latent_scale = latent_softmax_scale(cfg) if cfg.latent_attention else None
         self._view_rungs = (self.n_max,) if self._reads_in_place else _view_rungs(self.n_max)
         # Decode steps run at each width, for stats()["decode_width_steps"].
         self._width_steps = {w: 0 for w in self._view_rungs}
@@ -887,6 +889,10 @@ class LLMEngine:
             **({"moe": moe} if moe else {}),
             "kv_token_bytes": self.kv_token_bytes,
             "kv_groups": self._kv_groups(),
+            # Rows of the residual path (hyper-connections' streams; 1: the plain residual).
+            "residual_streams": self.cfg.hc_mult or 1,
+            # The softmax scale latent attention runs at (``transformer.latent_softmax_scale``: YaRN's mscale in it).
+            **({"latent_softmax_scale": self._latent_scale} if self.cfg.latent_attention else {}),
             "num_blocks": self.num_blocks - 1,
             "free_blocks": len(self._free),
             "cached_blocks": len(self._prefix),
@@ -1591,7 +1597,7 @@ class LLMEngine:
         with spans.span("llm.prefill.build", rid=req.id, pos=req._sched_pos):
             fed, rows, n = self._chunk_inputs(req)
             inputs = (jnp.asarray(fed), jnp.asarray(rows))
-        spans.carried(prefill_tokens=n)
+        spans.carried(prefill_tokens=n, chunk_context_tokens=req._sched_pos)
         with spans.span("llm.prefill.dispatch", rid=req.id):
             drawn = self._run_donated(self._prefill_fn, *inputs)
         if self._chunk_dispatched(req):
@@ -1863,7 +1869,7 @@ class LLMEngine:
             with spans.span("llm.prefill.build", rid=chunk.id, pos=chunk._sched_pos):
                 fed, chunk_rows, n = self._chunk_inputs(chunk)
                 inputs += [jnp.asarray(fed), jnp.asarray(chunk_rows)]
-            spans.carried(prefill_tokens=n, chunk_tokens=n)
+            spans.carried(prefill_tokens=n, chunk_tokens=n, chunk_context_tokens=chunk._sched_pos)
             self._counts["decode_steps_with_chunk"] += 1
         self._width_steps[width] += 1
         self._counts["decode_steps"] += 1

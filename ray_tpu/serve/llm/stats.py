@@ -68,12 +68,12 @@ ITERATION_KINDS = ("decode", "prefill", "mixed")
 # start stamp, what the pass carried, and the nanoseconds of each span.
 ITERATION_FIELDS = (
     "t_start_ns", "rows", "prefill_tokens", "waiting", "running", "view_blocks", "context_tokens",
-    "window_tokens", "chunk_tokens",
+    "window_tokens", "chunk_tokens", "chunk_context_tokens",
 ) + SPAN_NAMES
 (
     _T_START, _ROWS, _PREFILL_TOKENS, _WAITING, _RUNNING, _VIEW_BLOCKS, _CONTEXT_TOKENS, _WINDOW_TOKENS,
-    _CHUNK_TOKENS,
-) = range(9)
+    _CHUNK_TOKENS, _CHUNK_CONTEXT_TOKENS,
+) = range(10)
 _FIRST_SPAN = len(ITERATION_FIELDS) - len(SPAN_NAMES)
 _SPAN_FIELD = {name: _FIRST_SPAN + i for i, name in enumerate(SPAN_NAMES)}
 # One record per request that ended; stamps are CLOCK_MONOTONIC nanoseconds
@@ -402,15 +402,19 @@ class EngineSpans:
         return _Span(self._cur, _SPAN_FIELD[name], self.annotation(name, **args))
 
     def carried(self, rows: int = 0, prefill_tokens: int = 0, view_blocks: int = 0,
-                context_tokens: int = 0, window_tokens: int = 0, chunk_tokens: int = 0):
+                context_tokens: int = 0, window_tokens: int = 0, chunk_tokens: int = 0,
+                chunk_context_tokens: int = 0):
         """What this pass dispatched: decode rows, the width in blocks of
         their step's block table, the tokens of context they hold between
         them (each row's length, the token fed included) and how many of
         those a layer with the model's sliding window may read (each row's
         length or the window, whichever is less); prompt tokens of its chunk,
         and how many of those rode inside the decode step it dispatched
-        (``chunk_tokens``: all or none)."""
+        (``chunk_tokens``: all or none), and how many tokens the chunk's row
+        held before it (``chunk_context_tokens``: what the chunk's attention
+        reads beside the chunk itself, whatever the width of its view)."""
         self._cur[_CHUNK_TOKENS] += chunk_tokens
+        self._cur[_CHUNK_CONTEXT_TOKENS] += chunk_context_tokens
         self._cur[_ROWS] += rows
         self._cur[_PREFILL_TOKENS] += prefill_tokens
         self._cur[_VIEW_BLOCKS] += view_blocks

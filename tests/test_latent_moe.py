@@ -264,6 +264,38 @@ def test_padding_rows_reach_no_expert_and_a_stack_is_run_by_layer():
     assert sent_l.tolist() == all_sent.tolist()
 
 
+@pytest.mark.parametrize("stacked", [False, True], ids=["a layer's own leaves", "a stack run by layer"])
+def test_what_the_grouped_matmul_leaves_in_a_row_of_no_group_reaches_nothing(stacked, monkeypatch):
+    """``jax.lax.ragged_dot`` promises nothing about a row past the last group:
+    zeros here and at GLM's and Trinity's widths, NaN at 3584 x 1024 on the v5e
+    (PR 47: an inactive slot's NaN latents in the null block, which every
+    gathered view holds behind its mask). The rows that are no token are
+    selected away, not weighted by 0: with NaN in every such row of every
+    grouped product the result is finite, those rows' are zero and a token's
+    is what it was."""
+    params, x = _experts(jax.random.PRNGKey(5))
+    valid = jnp.arange(12) % 3 != 0
+    kwargs = dict(k=2, scale=1.8, valid=valid)
+    if stacked:
+        others, _ = _experts(jax.random.PRNGKey(6))
+        params = {**params, **{n: jnp.stack([others[n], params[n], others[n]]) for n in ("wg_e", "wi_e", "wo_e")}}
+        kwargs["layer"] = jnp.int32(1)
+    want, sent, _ = routed_experts(params, x, **kwargs)
+    ragged_dot, poisoned = jax.lax.ragged_dot, []
+
+    def poisoning(a, w_e, groups):
+        in_a_group = jnp.arange(a.shape[0]) < jnp.sum(groups)
+        poisoned.append(int(jnp.sum(~in_a_group)))
+        return jnp.where(in_a_group[:, None], ragged_dot(a, w_e, groups), jnp.nan)
+
+    monkeypatch.setattr(jax.lax, "ragged_dot", poisoning)
+    out, sent_p, _ = routed_experts(params, x, **kwargs)
+    assert poisoned == [2 * 4] * 3  # the four rows that are no token, twice each, in all three products
+    assert np.isfinite(np.asarray(out)).all() and not np.asarray(out[~valid]).any()
+    np.testing.assert_array_equal(np.asarray(out[valid]), np.asarray(want[valid]))
+    assert sent_p.tolist() == sent.tolist()
+
+
 def test_the_dense_layer_sits_outside_the_scan_over_the_expert_layers(model):
     cfg, params = model
     pool = init_paged_cache(cfg, 5, 8)

@@ -454,9 +454,14 @@ def _cell_programs(cell_name: str):
 # tree gives them (computed from a copy of that commit, PR 35).
 _PROGRAMS_OF_PR_34 = {
     "serve16.chat-open": ("80f55ef50a78c761acd079a445c783f338c74f07", "12bea586c90179b8fe8ea8526dc82e3f9d61ecfa"),
-    "glm8.rollout-long": ("cba2cf83a22dbe9cf5dcbe722bd4d53900cfa782", "70387e17a53cdd651ef0cd0d932cd22eff73366e"),
+    # Since PR 47 the two configurations whose experts run grouped as PR 47's tree gives them: ``routed_experts``
+    # selects zeros into the rows that are no token, where a weight of 0 stood alone (0 x what the grouped kernel left
+    # there: NaN at Xing4.0's widths). One select, one convert and the broadcasts of its mask more in each program,
+    # 1585 -> 1591 and 1620 -> 1626 operations (GLM), 3922 -> 3934 and 3994 -> 4006 (Trinity), nothing else moved
+    # (computed beside a copy of PR 46's commit). They were ("cba2cf83...", "70387e17...") and ("297552fc...", "8fcb0907...").
+    "glm8.rollout-long": ("f9f997ae62241335aa2cd39ed7f12aca83698444", "931d4f5514a91ffbdd18dc838e069855b4c12855"),
     # The configuration with a layer pattern, as PR 38's tree gives it (computed from a copy of that commit, PR 40).
-    "trinity5.rollout-longctx": ("297552fcc1973bcb0eef322c5e9ae63a440b5d59", "8fcb09071f66108e85f14215daff025c5708d073"),
+    "trinity5.rollout-longctx": ("95e06a237e124b8f5f0ac86c299afb760b83d974", "82c28c7927a264027a126ccbe518ca1398785739"),
     # The configuration with linear-attention layers, as PR 42's tree gives it (computed from a copy of that commit, PR 43).
     "olmo16.longdoc-8k": ("de78f60b73b654d4eadbb63811fa2ff4b68953d9", "0fda9676a9cf4a725d3b4724104b7306aaa6c29a"),
     # The configuration of single-mixer blocks, as PR 44's tree gives it (computed from a copy of that commit, PR 45).
@@ -556,7 +561,7 @@ def _without_locations(text: str) -> str:
 _KERNEL_PROGRAMS_OF_PR_45 = {
     ("serve16.chat-open", "step"): "300a106ea60589510bbff1569b8ba27f96bd2eb2",
     ("serve16.chat-open", "step_with_chunk"): "1454ae8052439a18d8c24bbd91f3bd33418535d1",
-    ("glm8.rollout-long", "step"): "a0e0493aaa16e5b669e7069b5a8d9741974030e0",
+    ("glm8.rollout-long", "step"): "7f052d4f03462a7ada2a691555e5dd8d0e262440",  # since PR 47's select in ``routed_experts`` (above); was "a0e0493a..."
 }
 
 
@@ -712,3 +717,69 @@ def test_the_single_mixer_programs_copy_neither_pool_nor_state_nor_the_expert_st
         # a chunk's 0.17 GB, a step's 0.04: no block's experts (0.64 GB a stack's slice) are written anywhere
         assert stats.temp_size_in_bytes < 0.3e9, stats.temp_size_in_bytes
         assert stats.temp_size_in_bytes + stats.argument_size_in_bytes < 11e9  # 10.27 GB of arguments: 61 % of the chip's 16.9
+
+
+def test_the_hyper_connection_programs_keep_the_stream_on_the_lanes_and_copy_no_pool(one_v5e_chip, monkeypatch):
+    """Xing4.0's decode program (the pool read in place, the whole table: the one
+    a TPU backend gets) and its prefill chunk at the benchmark's widths,
+    compiled for the v5e (PR 47). The residual path is four streams: held
+    ``[B, q, 4 * 3584]`` it tiles (8, 128)(2, 1) with nothing padded; with the 4
+    on an axis of its own it would sit on the sublanes and pad to 16. The 24
+    coefficients a token stay vectors over the tokens (no ``[.., 4, 4]`` array,
+    each 4 x 4 a padded tile), and a sub-layer's mixing and joining are some
+    twenty small operations, not the forty normalisations one by one. The latent
+    pool and the words of the experts taken are updated in place; the chunk's
+    float32 scores over the 12288-wide view (0.8 GB) are its largest temporary."""
+    import importlib
+    import re
+
+    import jax
+
+    for module in ("ray_tpu.ops.attention", "ray_tpu.ops.latent_attention"):
+        monkeypatch.setattr(importlib.import_module(module), "_on_tpu", lambda: True)
+    monkeypatch.setattr(importlib.import_module("ray_tpu.serve.llm.engine"), "_JIT_CACHE", {})
+    decode, prefill, args = _cell_programs("xing6.longdoc-12k")
+    describe = lambda a: jax.tree.map(lambda x: jax.ShapeDtypeStruct(x.shape, x.dtype, sharding=one_v5e_chip), a)  # noqa: E731
+    pool_bytes = 6 * 6145 * 16 * 640 * 2 + 5 * 6145 * 16 * 4
+    for program, given, rows, temp in ((decode, args(768), "8,1", 0.1e9), (prefill, args(None), "1,512", 1.0e9)):
+        compiled = program.lower(*describe(given)).compile()
+        text = compiled.as_text()
+        assert "ragged-dot" in text  # 3584 and 1024 are widths the grouped kernel tiles
+        walks = bool(re.search(r"= bf16\[8,32,1,640\]\S* custom-call\(.*tpu_custom_call", text))
+        assert walks == (rows == "8,1")  # the walk over the pool: the decode step's alone
+        for leaf in ("bf16[6,6145,16,640]", "s32[5,6145,16]"):
+            assert leaf in text and not re.search(rf"= {re.escape(leaf)}\S* copy\(", text), leaf
+        assert re.search(rf"bf16\[{rows},14336\]\{{[0-9,]*:T\(8,128\)\(2,1\)", text)  # the stream, tiled whole
+        assert not re.search(r"f32\[[0-9,]*,4,4\]", text)
+        fusions = len(re.findall(r"^\s+(?:ROOT )?%\S+ = \S+ fusion\(", text, flags=re.M))
+        assert fusions < 260, fusions  # 160 and 178 as built (PR 47); 12 sub-layers of ~20 small operations among them
+        assert not re.search(r"= bf16\[64,(3584,1024|1024,3584)\]\S* fusion\(", text)  # no layer's experts materialised
+        stats = compiled.memory_analysis()
+        assert stats.alias_size_in_bytes >= pool_bytes and stats.temp_size_in_bytes < temp, stats.temp_size_in_bytes
+        assert stats.temp_size_in_bytes + stats.argument_size_in_bytes < 11.5e9  # 10.35 GB of arguments
+
+
+@pytest.mark.parametrize("cell_name", sorted(_PROGRAMS_OF_PR_34))
+def test_the_new_fields_at_their_defaults_are_the_configuration_that_states_neither(cell_name):
+    """PR 47 sends every join of the cached layer through ``generate._residual``
+    and gives ``TransformerConfig`` ``hc_mult`` and ``rope_scaling``. An accepted
+    configuration, of each kind of pool the benchmark has, states neither; one
+    that states both at their defaults (0: the plain residual; no scaling) is the
+    same static argument, so the same programs, whose text
+    ``test_a_configuration_without_a_layer_pattern_keeps_the_programs_it_had``
+    holds to the parent's; and its softmax scale and rotary tables are the plain ones."""
+    import dataclasses
+
+    import jax.numpy as jnp
+
+    from ray_tpu.models.transformer import _rope_tables, latent_softmax_scale
+
+    cfg, _ = _cell_config(cell_name)
+    assert cfg.hc_mult == 0 and cfg.rope_scaling == ()
+    stated = dataclasses.replace(cfg, hc_mult=0, rope_scaling={})
+    assert stated == cfg and hash(stated) == hash(cfg)
+    if cfg.latent_attention:
+        assert latent_softmax_scale(cfg) == (cfg.qk_nope_head_dim + cfg.qk_rope_head_dim) ** -0.5
+    positions = jnp.arange(8)[None]
+    plain, scaled = _rope_tables(positions, 64, cfg.rope_theta), _rope_tables(positions, 64, cfg.rope_theta, cfg.rope_scaling)
+    assert all(bool((a == b).all()) for a, b in zip(plain, scaled))
