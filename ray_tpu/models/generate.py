@@ -24,8 +24,8 @@ over the view: keys and values per KV head (GQA: ``_project_qkv``,
 ``_project_latent``, ``_latent_attention``, in absorbed form: the cache is
 attended over as it lies, never expanded to per-head keys and values; a
 decode step over a paged latent pool on a TPU does not gather a view either,
-nor does one over a paged pool of one group of keys and values:
-``kernel_reads``, ``_latent_attention_in_place``,
+nor does a prefill chunk over one, nor a decode step over a paged pool of one
+group of keys and values: ``kernel_reads``, ``_latent_attention_in_place``,
 ``_cache_attention_in_place``). Which kinds of layer a configuration has, in
 which stack of ``params`` each lies and what each keeps in a cache is one
 table: ``_KINDS``, ``_layer_plan``. Its
@@ -64,7 +64,7 @@ from ray_tpu.models.transformer import (
 )
 from ray_tpu.ops import attention as _attention_ops
 from ray_tpu.ops import hyper_connection
-from ray_tpu.ops.latent_attention import paged_latent_attention
+from ray_tpu.ops.latent_attention import paged_latent_attention, paged_latent_chunk_attention
 from ray_tpu.ops.paged_attention import paged_attention
 from ray_tpu.parallel.moe import grouped_matmul_tiles
 
@@ -358,14 +358,17 @@ def one_kv_group(cfg: TransformerConfig) -> bool:
 def kernel_reads(cfg: TransformerConfig, paged: bool, q: int) -> bool:
     """Whether a call of the layer stack reads its cache in place, through a
     kernel that walks each row's block table to the row's length, by what the
-    code can see: a pool such a kernel is written for (latent attention:
-    ``ops/latent_attention.py``; ``one_kv_group``: ``ops/paged_attention.py``),
-    PAGED, one query a row, a TPU backend. Everything else (a pattern's groups,
-    a prefill chunk, the dense cache, any CPU run) gathers ``_paged_view`` and
-    attends over it. Such a call's cost does not grow with the table's width:
-    ``_cached_layers`` asks for the program, ``LLMEngine`` for the table it
-    hands a decode step (one width, one program), and the two cannot disagree."""
-    return bool(cfg.latent_attention or one_kv_group(cfg)) and paged and q == 1 and _attention_ops._on_tpu()
+    code can see: a pool such a kernel is written for, PAGED, a TPU backend.
+    A latent pool (``ops/latent_attention.py``) at ANY ``q`` queries a row: a
+    decode row and a prefill chunk each have their kernel over the one walk;
+    ``one_kv_group`` (``ops/paged_attention.py``) at one query a row, its
+    chunk keeps the view. Everything else (a pattern's groups, the dense
+    cache, any CPU run) gathers ``_paged_view`` and attends over it. Such a
+    call's cost does not grow with the table's width: ``_cached_layers`` asks
+    for the program, ``LLMEngine`` for the table it hands a decode step (one
+    width, one program) and for what it counts, and they cannot disagree."""
+    written = bool(cfg.latent_attention) or (one_kv_group(cfg) and q == 1)
+    return written and paged and _attention_ops._on_tpu()
 
 
 def latent_kernel_reads(cfg: TransformerConfig, paged: bool, q: int) -> bool:
@@ -385,12 +388,23 @@ def _decode_lengths(tables, positions):
     return jnp.where(tables[:, 0] != 0, positions[:, 0] + 1, 0)
 
 
-def _latent_attention_in_place(lp, q, ckv, at, tables, positions, cfg):
-    """``_latent_attention`` of one query a row, q [B, 1, H, W] at
-    ``positions`` [B, 1], over layer ``at`` of the pool leaf ``ckv`` [L, N,
-    Bs, W] through ``tables`` [B, n_max], with no view: the kernel returns
-    what the ``bhqk,bkr->bhqr`` product does (``_decode_lengths``)."""
-    o = paged_latent_attention(q, ckv, at, tables, _decode_lengths(tables, positions), sm_scale=latent_softmax_scale(cfg))
+def _latent_attention_in_place(lp, q, ckv, at, tables, positions, ends, cfg):
+    """``_latent_attention`` of q [B, T, H, W] at ``positions`` [B, T] over
+    layer ``at`` of the pool leaf ``ckv`` [L, N, Bs, W] through ``tables`` [B,
+    n_max], with no view: either kernel returns what the ``bhqk,bkr->bhqr``
+    product does. One query a row (``_decode_lengths``), or a chunk whose rows
+    under ``ends`` [B] are real (None: all): causal among themselves behind
+    what the row held, and nothing of an inactive row (``_decode_lengths``' test)."""
+    scale = latent_softmax_scale(cfg)
+    if q.shape[1] == 1:
+        o = paged_latent_attention(q, ckv, at, tables, _decode_lengths(tables, positions), sm_scale=scale)
+    else:
+        fed = positions[:, -1] + 1
+        real = fed if ends is None else jnp.minimum(fed, jnp.asarray(ends, jnp.int32))
+        o = paged_latent_chunk_attention(
+            q, ckv, at, tables, positions[:, 0], jnp.where(tables[:, 0] != 0, real, 0), sm_scale=scale,
+            value_width=cfg.kv_lora_rank,
+        )
     return _latent_values(lp, o[..., : cfg.kv_lora_rank], cfg)
 
 
@@ -632,12 +646,15 @@ class _Access(NamedTuple):
     layer l of the group, ``view(c, l)`` takes the rows [B, S, ...] to attend
     over, ``key_pos`` [B, S] is the position each view row holds (None: row i
     holds position i), ``tables`` [B, n_max] the block tables the view gathers
-    through (a paged pool's; None: a dense cache, a ring)."""
+    through (a paged pool's; None: a dense cache, a ring), ``ends`` [B] the
+    position the fed rows that ``write`` keeps stop short of (``valid_to``;
+    None: it keeps them all)."""
 
     write: Any
     view: Any
     key_pos: Any = None
     tables: Any = None
+    ends: Any = None
 
 
 class _Part(NamedTuple):
@@ -902,7 +919,7 @@ def _cached_layers(params, x, cache, positions, access, cfg, key_len=None, valid
                 pool = {**pool, **{name + sfx: acc.write(pool[name + sfx], at, row) for name, row in rows.items()}}
                 with jax.named_scope("cache_attention" + (f"_{kind}" if kind else "")):
                     if in_place[0] and row.reach == "table" and latent:  # the rows just written are read where they lie
-                        o = _latent_attention_in_place(lp, qh, pool["ckv"], at, acc.tables, positions, cfg)
+                        o = _latent_attention_in_place(lp, qh, pool["ckv"], at, acc.tables, positions, acc.ends, cfg)
                     elif in_place[0] and row.reach == "table":
                         o = _cache_attention_in_place(qh, pool["k"], pool["v"], at, acc.tables, positions, cfg).reshape(B, q, -1)
                     else:
@@ -1251,7 +1268,8 @@ def paged_decode_chunk_hidden(
     blocked = next(row for row in _kinds(cfg).values() if row.reach in ("table", "ring"))
     block_size = cache[next(iter(_group_rows(cfg, blocked)))].shape[2]
     access = {"table": _Access(
-        _paged_write(block_tables, positions, valid_to, block_size), _paged_view(block_tables), tables=block_tables
+        _paged_write(block_tables, positions, valid_to, block_size), _paged_view(block_tables), tables=block_tables,
+        ends=valid_to,
     )}
     reach = pool_reach(cfg)
     if "state" in reach:

@@ -72,21 +72,31 @@ _PAGES = 16
 
 
 def _walk(
-    lengths_ref, tables_ref, layer_ref,  # scalar prefetch: [B], [B * n_max], [1]
-    q_ref, *refs,  # the slot's queries; then the pool leaves [L, N, Bs, ...] in HBM, the slot's result, and the scratch:
+    lengths_ref, tables_ref, layer_ref,  # scalar prefetch: [G], [B * n_max], [1] (and, ``causal``, [G] behind them)
+    *refs,  # the slot's queries; then the pool leaves [L, N, Bs, ...] in HBM, the slot's result, and the scratch:
     # a buffer [2, pages, Bs, ...] a leaf, DMA semaphores [2], SMEM [2]: (buffer of the next step, 1 if its copies are in flight)
-    pages: int, n_max: int, window: int, load, scores, sums,
+    pages: int, n_max: int, window: int, load, scores, sums, tiles: int = 1, causal: bool = False,
 ):
     """The kernel's body, for any row a block table names: ``load(buffers)``
     takes a compute step's rows out of the leaves' buffers (``[pages, Bs,
     ...]`` each), ``scores(q, rows) -> [..., T]`` float32 (scaled) scores them
     against the slot's queries, ``sums(p, rows) -> [..., W]`` float32 is the
     second product, and the result ``[..., W]`` has the shape of the slot's
-    block of the output. ``window`` 0: none."""
+    block of the output. ``window`` 0: none.
+
+    A "slot" is one of the grid's G programs: a row of the tables, or
+    (``tiles`` > 1) one of the ``tiles`` tiles of a row's queries, which walk
+    the row's table one after the other, each to its own ``lengths_ref`` entry.
+    ``causal``: a tile's queries lie on the scores' axis -2, the first at
+    position ``starts_ref[slot]`` (one more scalar-prefetch operand) and each
+    one position behind the one before it, and a query sees no row past its
+    own position; what the tile's last query may not see is not walked at all,
+    its ``lengths_ref`` entry being that query's position + 1 at most."""
     from jax import lax
     from jax.experimental import pallas as pl
     from jax.experimental.pallas import tpu as pltpu
 
+    starts_ref, q_ref, *refs = refs if causal else (None, *refs)
     n = (len(refs) - 3) // 2
     leaves, o_ref, bufs, (sems, state) = refs[:n], refs[n], refs[n + 1 : 2 * n + 1], refs[2 * n + 1 :]
     b, B = pl.program_id(0), pl.num_programs(0)
@@ -107,7 +117,7 @@ def _walk(
 
     def starts(slot, step, at):
         """``start(i)``: start the copies of block ``i`` of compute step ``step`` of ``slot`` into buffer ``at``."""
-        base = slot * n_max + first_of(slot) + step * pages
+        base = (slot // tiles if tiles > 1 else slot) * n_max + first_of(slot) + step * pages
 
         def start(i):
             block = tables_ref[base + i]
@@ -168,10 +178,13 @@ def _walk(
         each_block(blocks_from(b, i), lambda j: wait(at, j))
         rows = load(tuple(buf.at[at] for buf in bufs))
         s = scores(q, rows)
-        col = first_of(b) * Bs + i * T + lax.broadcasted_iota(jnp.int32, s.shape, s.ndim - 1)
+        by = s.shape[-2:] if causal else s.shape  # one mask for every head of a tile
+        col = first_of(b) * Bs + i * T + lax.broadcasted_iota(jnp.int32, by, len(by) - 1)
         seen = col < lengths_ref[b]
         if window:
             seen &= col >= lengths_ref[b] - window
+        if causal:
+            seen &= col <= starts_ref[b] + lax.broadcasted_iota(jnp.int32, by, 0)
         s = jnp.where(seen, s, -jnp.inf)
         # Every step holds a column the query sees, so the maximum is finite from the first step on.
         m_cur = jnp.maximum(m_prev, s.max(axis=-1, keepdims=True))
@@ -191,22 +204,38 @@ def _walk(
         state[1] = hand_over.astype(jnp.int32)
 
 
-def walk_call(q, leaves, layer, block_tables, lengths, *, q_block, name: str, pages: int, interpret: bool, **products):
+def walk_call(
+    q, leaves, layer, block_tables, lengths, *, q_block, name: str, pages: int, interpret: bool,
+    starts=None, tile_axis: int | None = None, vmem_limit_bytes: int | None = None, **products,
+):
     """``_walk`` over ``leaves`` (each [L, N, Bs, ...]) for the queries ``q``
     [B, ...], a slot's block of them (and of the result, which has q's shape
-    and dtype) ``q_block`` (None: an axis the kernel does not see)."""
+    and dtype) ``q_block`` (None: an axis the kernel does not see). ``lengths``
+    [G]: one program a row of ``block_tables``, or (``tile_axis``: the axis of
+    q that ``q_block`` cuts into tiles) G / B programs a row, one a tile, in
+    order; ``starts`` [G]: the position of each program's first query
+    (``_walk``'s ``causal``)."""
     from jax.experimental import pallas as pl
     from jax.experimental.pallas import tpu as pltpu
 
     B, n_max = block_tables.shape
-    spec = pl.BlockSpec(q_block, lambda b, *_: (b,) + (0,) * (len(q_block) - 1))
+    tiles = lengths.shape[0] // B
+
+    def block_of(g, *_):
+        if tile_axis is None:
+            return (g,) + (0,) * (len(q_block) - 1)
+        return tuple(g // tiles if d == 0 else g % tiles if d == tile_axis else 0 for d in range(len(q_block)))
+
+    spec = pl.BlockSpec(q_block, block_of)
+    scalars = (lengths, block_tables.reshape(-1), layer.reshape(1), *(() if starts is None else (starts,)))
+    limit = {} if vmem_limit_bytes is None else {"vmem_limit_bytes": vmem_limit_bytes}
     return pl.pallas_call(
-        functools.partial(_walk, pages=pages, n_max=n_max, **products),
+        functools.partial(_walk, pages=pages, n_max=n_max, tiles=tiles, causal=starts is not None, **products),
         grid_spec=pltpu.PrefetchScalarGridSpec(
-            num_scalar_prefetch=3,
+            num_scalar_prefetch=len(scalars),
             in_specs=[spec, *[pl.BlockSpec(memory_space=pl.ANY)] * len(leaves)],
             out_specs=spec,
-            grid=(B,),
+            grid=(lengths.shape[0],),
             scratch_shapes=(
                 *[pltpu.VMEM((2, pages, *leaf.shape[2:]), leaf.dtype) for leaf in leaves],
                 pltpu.SemaphoreType.DMA((2,)),
@@ -214,11 +243,11 @@ def walk_call(q, leaves, layer, block_tables, lengths, *, q_block, name: str, pa
             ),
         ),
         # Slots in order: the buffers' turn and the copies in flight pass from one to the next.
-        compiler_params=pltpu.CompilerParams(dimension_semantics=("arbitrary",)),
+        compiler_params=pltpu.CompilerParams(dimension_semantics=("arbitrary",), **limit),
         out_shape=jax.ShapeDtypeStruct(q.shape, q.dtype),
         interpret=interpret,
         name=name,
-    )(lengths, block_tables.reshape(-1), layer.reshape(1), q, *leaves)
+    )(*scalars, q, *leaves)
 
 
 def _head_rows(ref):

@@ -21,7 +21,10 @@ vLLM-lineage iteration-level scheduler on top of the paged KV cache:
   in a kernel (``ops/latent_attention.py``, ``ops/paged_attention.py``), so it
   is handed the whole table and there is one decode program
   (``generate.kernel_reads``; ``stats()["latent_kernel_steps"]`` /
-  ``["kv_kernel_steps"]``). A decode step that carries the pass's prefill
+  ``["kv_kernel_steps"]``). A latent pool's prefill chunk is read in place
+  there too, each tile of its queries to its own position
+  (``["latent_kernel_chunks"]``): the chunk keeps ``n_max`` and pays for what
+  the row holds. A decode step that carries the pass's prefill
   chunk (``_shape_fuses``) is handed the whole table on every backend.
 - **paged KV cache**: ``init_paged_cache`` block pool + per-sequence block
   tables with a host-side free-list. Block 0 is the reserved null block
@@ -537,6 +540,7 @@ class LLMEngine:
             init_moe_counts,
             init_paged_cache,
             kernel_reads,
+            latent_kernel_reads,
             pool_reach,
             ring_blocks,
             state_slot_bytes,
@@ -582,6 +586,8 @@ class LLMEngine:
         # value leaves, on a TPU), stops at each row's length whatever the
         # table's width: the whole table, one program, no ladder.
         self._reads_in_place = kernel_reads(cfg, paged=True, q=1)
+        # A latent pool's prefill program asks the same predicate at the chunk's width, for ``latent_kernel_chunks``.
+        self._chunk_reads_in_place = latent_kernel_reads(cfg, paged=True, q=int(prefill_chunk))
         self._latent_scale = latent_softmax_scale(cfg) if cfg.latent_attention else None
         self._view_rungs = (self.n_max,) if self._reads_in_place else _view_rungs(self.n_max)
         # Decode steps run at each width, for stats()["decode_width_steps"].
@@ -701,6 +707,9 @@ class LLMEngine:
             # of them or none. And the same of a pool of keys and values.
             "latent_kernel_steps": 0,
             "kv_kernel_steps": 0,
+            # Prefill passes whose program reads the latent pool through the
+            # chunk's kernel (``_chunk_reads_in_place``): all of them or none.
+            "latent_kernel_chunks": 0,
             # Tokens the prefill chunks carried that were real, and the
             # padding behind a prompt's last ones: what the fixed chunk wastes.
             "chunk_tokens_valid": 0,
@@ -1598,6 +1607,7 @@ class LLMEngine:
             fed, rows, n = self._chunk_inputs(req)
             inputs = (jnp.asarray(fed), jnp.asarray(rows))
         spans.carried(prefill_tokens=n, chunk_context_tokens=req._sched_pos)
+        self._counts["latent_kernel_chunks"] += self._chunk_reads_in_place
         with spans.span("llm.prefill.dispatch", rid=req.id):
             drawn = self._run_donated(self._prefill_fn, *inputs)
         if self._chunk_dispatched(req):

@@ -205,6 +205,7 @@ def test_a_kv_pool_has_one_decode_program_where_the_kernel_reads_it(model, monke
     new_tokens = (40, 20, 9, 12)
     want, stats, rungs = _serve(model, prompts, new_tokens, stagger=3)
     assert rungs == (16, 32, 48) and stats["kv_kernel_steps"] == 0 and stats["decode_steps"] > 0
+    assert stats["latent_kernel_chunks"] == 0
     assert stats["decode_steps_with_chunk"] > 0
 
     _, cfg = model
@@ -219,7 +220,7 @@ def test_a_kv_pool_has_one_decode_program_where_the_kernel_reads_it(model, monke
     assert got == want
     assert rungs == (48,) and set(stats["decode_width_steps"]) == {48}
     assert stats["kv_kernel_steps"] == stats["decode_steps"] == stats["decode_width_steps"][48] > 0
-    assert stats["latent_kernel_steps"] == 0
+    assert stats["latent_kernel_steps"] == 0 and stats["latent_kernel_chunks"] == 0  # a K/V pool's chunk keeps the view
     # Chunks rode steps (three prompts arrive while the first decodes: 15 chunks), a last chunk among them.
     assert stats["decode_steps_with_chunk"] >= 10
     assert stats["kv_pool_not_donated"] == 0

@@ -58,6 +58,8 @@ def test_the_engine_serves_what_generate_computes_and_donates_every_leaf(model):
             assert tokens == want
         st = eng.stats()
         assert st["kv_pool_not_donated"] == 0 and st["host_logit_rows"] == 0
+        # a CPU's engine: the view at every width, no kernel's counter moves
+        assert st["latent_kernel_steps"] == 0 and st["latent_kernel_chunks"] == 0
         # the latent and the rotary key (16 + 8), padded to 128 lanes, float32, three layers
         assert st["kv_token_bytes"] == 3 * 128 * 4
         # A prompt sent again finds its full blocks in the prefix cache: a block is a block.

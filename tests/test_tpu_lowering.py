@@ -349,6 +349,33 @@ def test_the_latent_decode_step_copies_neither_the_pool_nor_the_expert_stacks(on
     assert stats.alias_size_in_bytes >= 8 * 8193 * 16 * 640 * 2 + 7 * 8193 * 16 * 4  # the pool is updated in place
 
 
+def test_the_latent_prefill_chunk_reads_the_pool_in_place_on_a_tpu(one_v5e_chip, monkeypatch):
+    """GLM-4.7-Flash's prefill chunk as a TPU backend gets it (PR 48), at the
+    benchmark's widths, compiled for the v5e: 20 heads x 32 queries a tile of
+    ``ops/latent_attention.py``'s chunk kernel, its one result ``[1, 20, 512,
+    640]``; no view of the 256-block table is gathered, the pool is updated in
+    place and never copied, and the float32 scores over 4096 keys (0.17 GB a
+    layer) are not among the temporaries."""
+    import importlib
+    import re
+
+    import jax
+
+    for module in ("ray_tpu.ops.attention", "ray_tpu.ops.latent_attention"):  # the predicate's, and the kernel's "compiled, not interpreted"
+        monkeypatch.setattr(importlib.import_module(module), "_on_tpu", lambda: True)
+    monkeypatch.setattr(importlib.import_module("ray_tpu.serve.llm.engine"), "_JIT_CACHE", {})
+    _, prefill, args = _cell_programs("glm8.rollout-long")
+    described = jax.tree.map(lambda a: jax.ShapeDtypeStruct(a.shape, a.dtype, sharding=one_v5e_chip), args(None))
+    compiled = prefill.lower(*described).compile()
+    text = compiled.as_text()
+    assert re.search(r"= bf16\[1,20,512,640\]\S* custom-call\(.*tpu_custom_call", text)
+    assert not re.search(r"= bf16\[\d+,16,640\]\S* fusion\(", text)
+    assert not re.search(rf"= {re.escape('bf16[8,8193,16,640]')}\S* copy\(", text)
+    stats = compiled.memory_analysis()
+    assert stats.alias_size_in_bytes >= 8 * 8193 * 16 * 640 * 2 + 7 * 8193 * 16 * 4
+    assert stats.temp_size_in_bytes < 150e6, stats.temp_size_in_bytes
+
+
 def test_a_scanned_slice_of_an_expert_stack_is_copied_and_a_whole_stack_is_not(one_v5e_chip):
     """Why ``generate._cached_layers`` keeps the routed experts' ``[L, E, in, out]``
     stacks out of the leaves its layer scan slices, and ``moe.routed_experts``
@@ -728,8 +755,12 @@ def test_the_hyper_connection_programs_keep_the_stream_on_the_lanes_and_copy_no_
     coefficients a token stay vectors over the tokens (no ``[.., 4, 4]`` array,
     each 4 x 4 a padded tile), and a sub-layer's mixing and joining are some
     twenty small operations, not the forty normalisations one by one. The latent
-    pool and the words of the experts taken are updated in place; the chunk's
-    float32 scores over the 12288-wide view (0.8 GB) are its largest temporary."""
+    pool and the words of the experts taken are updated in place. Since PR 48
+    the chunk reads the pool in place too (one kernel under ``jit`` for the dense
+    layer and the scanned ones, its one result the ``bf16[1,32,512,640]`` that
+    the benchmark's ``trace_ops.latent_prefill`` names): no 768-block view is
+    gathered and the float32 scores over it (0.8 GB, until then the chunk's
+    largest temporary) are gone."""
     import importlib
     import re
 
@@ -741,12 +772,13 @@ def test_the_hyper_connection_programs_keep_the_stream_on_the_lanes_and_copy_no_
     decode, prefill, args = _cell_programs("xing6.longdoc-12k")
     describe = lambda a: jax.tree.map(lambda x: jax.ShapeDtypeStruct(x.shape, x.dtype, sharding=one_v5e_chip), a)  # noqa: E731
     pool_bytes = 6 * 6145 * 16 * 640 * 2 + 5 * 6145 * 16 * 4
-    for program, given, rows, temp in ((decode, args(768), "8,1", 0.1e9), (prefill, args(None), "1,512", 1.0e9)):
+    for program, given, rows, temp in ((decode, args(768), "8,1", 0.1e9), (prefill, args(None), "1,512", 0.3e9)):
         compiled = program.lower(*describe(given)).compile()
         text = compiled.as_text()
         assert "ragged-dot" in text  # 3584 and 1024 are widths the grouped kernel tiles
-        walks = bool(re.search(r"= bf16\[8,32,1,640\]\S* custom-call\(.*tpu_custom_call", text))
-        assert walks == (rows == "8,1")  # the walk over the pool: the decode step's alone
+        walked = "8,32,1,640" if rows == "8,1" else "1,32,512,640"  # the walk over the pool, a row's query or a chunk's tiles
+        assert re.search(rf"= bf16\[{walked}\]\S* custom-call\(.*tpu_custom_call", text)
+        assert not re.search(r"= bf16\[\d+,16,640\]\S* fusion\(", text)  # and no view of a table is gathered
         for leaf in ("bf16[6,6145,16,640]", "s32[5,6145,16]"):
             assert leaf in text and not re.search(rf"= {re.escape(leaf)}\S* copy\(", text), leaf
         assert re.search(rf"bf16\[{rows},14336\]\{{[0-9,]*:T\(8,128\)\(2,1\)", text)  # the stream, tiled whole
