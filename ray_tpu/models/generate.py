@@ -61,6 +61,7 @@ from ray_tpu.models.transformer import (
     _rms_norm,
     _rope,
     latent_softmax_scale,
+    layer_rope,
 )
 from ray_tpu.ops import attention as _attention_ops
 from ray_tpu.ops import hyper_connection
@@ -268,10 +269,11 @@ def init_cache(cfg: TransformerConfig, batch: int, max_len: int):
     }
 
 
-def _project_qkv(lp, x, positions, cfg, rope: bool = True):
+def _project_qkv(lp, x, positions, cfg, rope: tuple | None = ()):
     """GQA: (q [B, T, H, Dh], the rows to cache {"k", "v"}: [B, T, KV, Dh]).
     ``cfg.qk_norm``: queries and keys normed over the head's width first.
-    ``rope`` False: no positional encoding (a full layer of a layer pattern)."""
+    ``rope``: what ``transformer.layer_rope`` says of the layer's kind, None for
+    no positional encoding, else the scaling of its rotary tables (() plain)."""
     B, T, _ = x.shape
     H, KV, Dh = cfg.n_heads, cfg.n_kv_heads, cfg.head_dim
     h = _rms_norm(x, lp["attn_norm"], cfg.norm_eps) if cfg.pre_norms else x
@@ -287,9 +289,9 @@ def _project_qkv(lp, x, positions, cfg, rope: bool = True):
         q, k = _rms_norm(q, lp["q_norm"], cfg.norm_eps), _rms_norm(k, lp["k_norm"], cfg.norm_eps)
     if _cache_heads(cfg) != KV:  # zero heads up to what a cached row holds
         q, k, v = (jnp.pad(a, ((0, 0), (0, 0), (0, _cache_heads(cfg) - KV), (0, 0))) for a in (q, k, v))
-    if not rope:
+    if rope is None:
         return q, {"k": k, "v": v}
-    return _rope(q, positions, cfg.rope_theta), {"k": _rope(k, positions, cfg.rope_theta), "v": v}
+    return _rope(q, positions, cfg.rope_theta, rope), {"k": _rope(k, positions, cfg.rope_theta, rope), "v": v}
 
 
 def _latent_kv_up(lp, cfg):
@@ -469,9 +471,10 @@ def _mlp(lp, x, cfg, valid=None, layer=None):
             return post(_swiglu(h, lp["wg"], lp["wi"], lp["wo_mlp"])), (None, None)
         if cfg.routed_experts:
             B, q, D = h.shape
-            out, sent, chosen = moe.routed_experts(
+            out, sent, chosen, _ = moe.routed_experts(
                 lp, h.reshape(B * q, D), k=cfg.experts_per_token, scale=cfg.routed_scaling_factor,
                 valid=None if valid is None else valid.reshape(B * q), layer=layer, share=cfg.expert_share,
+                score=cfg.router_score,
             )
             out = out.reshape(B, q, D)
             if "wg_s" in lp:
@@ -918,7 +921,7 @@ def _cached_layers(params, x, cache, positions, access, cfg, key_len=None, valid
             return _mlp(lp, x, cfg)[0], pool, None
 
         def attention(u, pool=pool):
-            project = _project_latent if latent else _project_qkv if kind != _FULL else partial(_project_qkv, rope=False)
+            project = _project_latent if latent else partial(_project_qkv, rope=layer_rope(cfg, kind))
             qh, rows = project(lp, u, positions, cfg)
             if parts is not None:
                 o, pool = attend_parts(pool, qh, rows, at)

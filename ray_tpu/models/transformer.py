@@ -23,6 +23,7 @@ of what it delegates to HF/DeepSpeed.)
 
 from __future__ import annotations
 
+import contextlib
 import dataclasses
 import math
 from functools import partial
@@ -51,18 +52,26 @@ class TransformerConfig:
     # experts with a capacity: parallel/moe.moe_layer), which trains.
     num_experts: int = 0
     expert_capacity_factor: float = 1.25
-    # Dropless routed experts (parallel/moe.routed_experts; inference only):
+    # Dropless routed experts (parallel/moe.routed_experts):
     # ``experts_per_token`` of ``num_experts`` SwiGLU experts of width
-    # ``d_expert`` a token, chosen by sigmoid scores plus a bias that chooses
-    # and does not weigh, their weights normalised and scaled by
-    # ``routed_scaling_factor``; ``num_shared_experts`` more of that width
-    # see every token. The first ``first_dense_layers`` layers keep the dense
-    # MLP of width ``d_ff`` and are stacked apart (``params["dense_layers"]``).
+    # ``d_expert`` a token, chosen by the router's scores (``router_score``:
+    # ``"sigmoid"``, or ``"softmax"`` over all the experts) plus, with
+    # ``router_bias``, a bias that chooses and does not weigh (a leaf of its own;
+    # nothing here updates it, so it is inference only), their weights normalised
+    # and scaled by ``routed_scaling_factor``; ``num_shared_experts`` more of that
+    # width see every token (inference only). The first ``first_dense_layers``
+    # layers keep the dense MLP of width ``d_ff`` and are stacked apart
+    # (``params["dense_layers"]``; inference only). Training adds
+    # ``balance_loss_coef`` times the layers' mean balance term to the loss
+    # (``parallel/moe.balance_term``; the Switch layer's summed term likewise).
     experts_per_token: int = 0
     d_expert: int = 0
     num_shared_experts: int = 0
     routed_scaling_factor: float = 1.0
     first_dense_layers: int = 0
+    router_score: str = "sigmoid"
+    router_bias: bool = True
+    balance_loss_coef: float = 0.01
     # Latent attention (MLA; inference only), selected by ``kv_lora_rank`` > 0:
     # queries through a ``q_lora_rank`` bottleneck; keys and values expanded
     # per head from one normed latent of ``kv_lora_rank`` a token, beside one
@@ -80,14 +89,17 @@ class TransformerConfig:
     # k-block pruning in training and the decode position mask at
     # inference; not combinable with ring/Ulysses sequence parallelism.
     sliding_window: int = 0
-    # A layer pattern (inference only): one of ``"window"`` / ``"full"`` a layer,
-    # ``n_layers`` of them, or empty for a model whose layers are all alike. A
-    # window layer attends within ``sliding_window`` and ropes its queries and
-    # keys; a full layer attends over the whole context and carries NO
-    # positional encoding (the published ``afmoe`` layer). A paged cache then
-    # holds the two kinds apart: the full layers' blocks grow with the row, a
-    # window layer holds a ring no longer than the window and a prefill chunk
-    # (models/generate.py, serve/llm/engine.py).
+    # A layer pattern: one of ``"window"`` / ``"full"`` a layer, ``n_layers`` of
+    # them, or empty for a model whose layers are all alike. A window layer
+    # attends within ``sliding_window`` and ropes its queries and keys plainly; a
+    # full layer attends over the whole context and carries NO positional
+    # encoding (the published ``afmoe`` layer) unless ``rope_scaling`` is set,
+    # which then scales the full layers' rotary and theirs alone (the published
+    # ``mellum`` layer). ``layer_rope`` is the one place that says so. A paged
+    # cache then holds the two kinds apart: the full layers' blocks grow with
+    # the row, a window layer holds a ring no longer than the window and a
+    # prefill chunk (models/generate.py, serve/llm/engine.py). Training scans
+    # whole periods of the pattern, a period's layers unrolled.
     layer_kinds: tuple = ()
     # Width of a head; 0: ``d_model // n_heads`` (``__post_init__`` fills it in).
     head_dim: int = 0
@@ -160,12 +172,13 @@ class TransformerConfig:
     hc_sinkhorn_iters: int = 20
     hc_eps: float = 1e-6
     hc_res_clamp: tuple = (-30.0, 30.0)
-    # YaRN (inference only, latent attention only): the six numbers of a
-    # published ``rope_scaling`` of that type (``factor``,
-    # ``original_max_position_embeddings``, ``beta_fast``, ``beta_slow``,
-    # ``mscale``, ``mscale_all_dim``) as sorted pairs, or empty for none. They
-    # change the rotary frequencies (``_rope_tables``) and the softmax scale of
-    # latent attention (``latent_softmax_scale``).
+    # YaRN: the six numbers of a published ``rope_scaling`` of that type
+    # (``factor``, ``original_max_position_embeddings``, ``beta_fast``,
+    # ``beta_slow``, ``mscale``, ``mscale_all_dim``) as sorted pairs, or empty
+    # for none. They change the rotary frequencies and amplitude
+    # (``_rope_tables``) of latent attention (inference only; its softmax scale
+    # too, ``latent_softmax_scale``), of every layer of a model without a
+    # pattern, and of a pattern's full layers (``layer_rope``).
     rope_scaling: tuple = ()
     # Fuse the LM-head projection into a chunked cross-entropy
     # (ops/losses.fused_lm_loss) so the [B*T, V] f32 logits tensor never
@@ -186,10 +199,12 @@ class TransformerConfig:
         if scaling.pop("type", "yarn") != "yarn" or (scaling and set(scaling) != set(_YARN_KEYS)):
             raise ValueError(f"rope_scaling {dict(self.rope_scaling)!r}: type 'yarn' with {', '.join(_YARN_KEYS)}, or none")
         object.__setattr__(self, "rope_scaling", tuple(sorted((k, float(v)) for k, v in scaling.items())))
+        if self.router_score not in ("sigmoid", "softmax"):
+            raise ValueError(f"router_score {self.router_score!r}: 'sigmoid' or 'softmax'")
         for field, what in (
-            (self.rope_scaling and not self.latent_attention, "rope_scaling without latent attention (kv_lora_rank > 0)"),
             (self.hc_mult and not self.latent_attention, "hyper-connections (hc_mult > 0) without latent attention (kv_lora_rank > 0)"),
             (self.hc_mult and kinds, "hyper-connections (hc_mult > 0) under a layer pattern (layer_kinds)"),
+            (self.rope_scaling and kinds and "full" not in kinds and not self.latent_attention, "rope_scaling under a layer pattern without full layers, the ones it scales"),
         ):
             if field:
                 raise ValueError(f"{what}: has not run and is not built")
@@ -278,14 +293,17 @@ class TransformerConfig:
         if self.latent_attention:
             missing.append("latent attention (kv_lora_rank > 0) has no training block")
         if self.routed_experts:
-            missing.append(
-                "dropless routed experts (experts_per_token > 0) have no backward "
-                "pass, balance loss or ep sharding"
-            )
+            for field, what in (
+                (self.router_bias, "a router's bias that chooses (router_bias) has no bias update rule"),
+                (self.num_shared_experts, "shared experts (num_shared_experts) have no training block"),
+                (self.first_dense_layers, "leading dense layers (first_dense_layers) have no training block"),
+            ):
+                if field:
+                    missing.append(what)
+        if set(self.layer_kinds) - {"window", "full"}:
+            missing.append("a layer pattern (layer_kinds) of other kinds than 'window' and 'full' has no training block")
         for field, what in (
-            ("layer_kinds", "a layer pattern (layer_kinds)"),
             ("attn_gate", "gated attention (attn_gate)"),
-            ("qk_norm", "per-head query and key norms (qk_norm)"),
             ("post_norms", "post-branch norms (post_norms)"),
             ("qk_norm_whole", "query and key norms over the whole projection (qk_norm_whole)"),
             ("linear_heads", "linear-attention layers (linear_heads)"),
@@ -308,16 +326,10 @@ class TransformerConfig:
             missing.append("a state-space block's convolution (mamba_conv) has no training block")
         if self.expert_activation != "swiglu":
             missing.append("experts without a gate matrix (expert_activation) have no training block")
-        if self.expert_share != (0, 1):
-            missing.append("a held share of the experts (expert_share) has no training block")
-        if self.head_dim * self.n_heads != self.d_model:
-            missing.append("a head_dim other than d_model // n_heads has no training block")
         if self.embed_multiplier != 1.0:
             missing.append("an embedding multiplier (embed_multiplier) has no training block")
         if self.hc_mult:
             missing.append("a residual path of several streams (hc_mult) has no training block")
-        if self.rope_scaling:
-            missing.append("scaled rotary frequencies (rope_scaling) have no training block")
         return "; ".join(missing)
 
 
@@ -546,7 +558,8 @@ def _layer_leaves(cfg: TransformerConfig, mlp, mixer="attention") -> dict:
         leaves.update(
             {
                 "gate": _Leaf(4, (D, E), s, ("embed", None), jnp.float32),
-                "gate_bias": _Leaf(9, (E,), 0.01, (None,), jnp.float32),
+                # A leaf only where the router has one: one that no gradient reaches would still be decayed by AdamW.
+                **({"gate_bias": _Leaf(9, (E,), 0.01, (None,), jnp.float32)} if cfg.router_bias else {}),
             }
         )
         if Fs:
@@ -755,30 +768,51 @@ def _rope(x, positions, theta, scaling: tuple = ()):
     return _rope_apply(x, cos, sin)
 
 
-def _attention_block(lp, x, rope_cs, cfg: TransformerConfig, mesh, attn_impl: str):
+def layer_rope(cfg: TransformerConfig, kind):
+    """What positions a GQA layer of ``kind`` (of ``layer_kinds``; None: a model
+    without a pattern) gives its queries and keys, THE one place that says so,
+    for the training block and the cached layers (models/generate.py) alike:
+    None for none, else the ``scaling`` of ``_rope_tables`` at ``rope_theta``
+    (() for the plain rotary, ``rope_scaling`` for YaRN's frequencies and
+    amplitude). A window layer ropes plainly; a full layer of a pattern by
+    ``rope_scaling`` or, without it, not at all; a model without a pattern by
+    ``rope_scaling`` or, without it, plainly."""
+    if kind == "full":
+        return cfg.rope_scaling or None
+    return () if kind else cfg.rope_scaling
+
+
+def _attention_block(lp, x, rope_cs, cfg: TransformerConfig, mesh, attn_impl: str, kind=None):
+    """``kind``: the layer's of ``layer_kinds`` (None without a pattern): a full
+    layer attends over the whole context, the others within
+    ``sliding_window``; ``rope_cs`` is its kind's tables, None for no positions."""
     B, T, D = x.shape
     H, KV, Dh = cfg.n_heads, cfg.n_kv_heads, cfg.head_dim
     h = _rms_norm(x, lp["attn_norm"], cfg.norm_eps)
     q = (h @ lp["wq"].astype(h.dtype)).reshape(B, T, H, Dh)
     k = (h @ lp["wk"].astype(h.dtype)).reshape(B, T, KV, Dh)
     v = (h @ lp["wv"].astype(h.dtype)).reshape(B, T, KV, Dh)
-    cos, sin = rope_cs
-    q = _rope_apply(q, cos, sin)
-    k = _rope_apply(k, cos, sin)
+    if cfg.qk_norm:
+        q, k = _rms_norm(q, lp["q_norm"], cfg.norm_eps), _rms_norm(k, lp["k_norm"], cfg.norm_eps)
+    if rope_cs is not None:
+        cos, sin = rope_cs
+        q = _rope_apply(q, cos, sin)
+        k = _rope_apply(k, cos, sin)
     if KV != H:  # GQA: repeat kv heads
         rep = H // KV
         k = jnp.repeat(k, rep, axis=2)
         v = jnp.repeat(v, rep, axis=2)
     from ray_tpu.ops.attention import flash_attention
 
+    window = 0 if kind == "full" else cfg.sliding_window
     if attn_impl == "ring" and mesh is not None and mesh.shape.get("sp", 1) > 1:
-        if cfg.sliding_window:
+        if window:
             raise NotImplementedError("sliding_window + ring attention not supported")
         from ray_tpu.parallel.ring_attention import ring_attention
 
         o = ring_attention(q, k, v, mesh, causal=True)
     else:
-        attn = partial(flash_attention, causal=True, window=cfg.sliding_window)
+        attn = partial(flash_attention, causal=True, window=window)
         if mesh is not None and mesh.size > 1:
             # The compiler cannot partition a Mosaic kernel ("wrap the call
             # in a shard_map"): each device runs it on its own batch rows
@@ -790,7 +824,8 @@ def _attention_block(lp, x, rope_cs, cfg: TransformerConfig, mesh, attn_impl: st
                 attn, mesh=mesh, in_specs=(spec, spec, spec), out_specs=spec,
                 check_vma=False,
             )
-        o = attn(q, k, v)
+        with jax.named_scope(f"attention_{kind}") if kind else contextlib.nullcontext():
+            o = attn(q, k, v)
     o = o.reshape(B, T, H * Dh)
     return x + o @ lp["wo"].astype(o.dtype)
 
@@ -812,22 +847,42 @@ def _moe_mlp(lp, h, capacity_factor: float):
     )
 
 
+def _experts_block(lp, x, cfg: TransformerConfig):
+    """The dropless routed experts in the MLP's place: (x + this program's
+    share of the experts' sum, the layer's balance term over the model's depth,
+    the assignments sent to each of ALL the experts [E] int32)."""
+    from ray_tpu.parallel import moe
+
+    B, T, D = x.shape
+    h = _rms_norm(x, lp["mlp_norm"], cfg.norm_eps)
+    out, _, chosen, scores = moe.routed_experts(
+        lp, h.reshape(B * T, D), k=cfg.experts_per_token, scale=cfg.routed_scaling_factor, share=cfg.expert_share,
+        score=cfg.router_score, rows=moe.held_rows(B * T * cfg.experts_per_token, cfg.expert_share),
+    )
+    with jax.named_scope("moe_router"):
+        balance, sent = moe.balance_term(scores, chosen, cfg.router_score)
+    return x + out.reshape(B, T, D), balance / cfg.n_layers, sent
+
+
 def _mlp_block(lp, x, cfg: TransformerConfig):
+    if cfg.routed_experts:
+        return _experts_block(lp, x, cfg)
     h = _rms_norm(x, lp["mlp_norm"], cfg.norm_eps)
     if cfg.num_experts > 0:
         out, aux = _moe_mlp(lp, h, cfg.expert_capacity_factor)
         # SwiGLU-ish gate path folded into experts (wg_e unused in moe path
         # to keep dispatch einsums lean; kept in params for parity).
-        return x + out, aux
+        return x + out, aux, None
     gate = jax.nn.silu(h @ lp["wg"].astype(h.dtype))
     up = h @ lp["wi"].astype(h.dtype)
-    return x + (gate * up) @ lp["wo_mlp"].astype(h.dtype), 0.0
+    return x + (gate * up) @ lp["wo_mlp"].astype(h.dtype), 0.0, None
 
 
-def _layer(lp, x, rope_cs, cfg: TransformerConfig, mesh, attn_impl: str):
-    x = _attention_block(lp, x, rope_cs, cfg, mesh, attn_impl)
-    x, aux = _mlp_block(lp, x, cfg)
-    return x, aux
+def _layer(lp, x, rope_cs, cfg: TransformerConfig, mesh, attn_impl: str, kind=None):
+    """-> (x, the layer's term of the balance loss, the assignments its routed
+    experts were sent [E] int32 or None)."""
+    x = _attention_block(lp, x, rope_cs, cfg, mesh, attn_impl, kind)
+    return _mlp_block(lp, x, cfg)
 
 
 def _refuse_inference_only(cfg: TransformerConfig, what: str):
@@ -836,6 +891,50 @@ def _refuse_inference_only(cfg: TransformerConfig, what: str):
             f"{what} cannot run this configuration: {cfg.inference_only}. "
             "It is served through models/generate.py (prefill and decode over a cache) only."
         )
+
+
+def _run_layers(params: dict, tokens, cfg: TransformerConfig, mesh, attn_impl: str):
+    """tokens [B, T] int32 -> (final hidden [B, T, D], moe aux, the assignments
+    of each layer's routed experts [L, E] int32 or None)."""
+    B, T = tokens.shape
+    x = params["embed"].astype(cfg.dtype)[tokens]
+    positions = jnp.broadcast_to(jnp.arange(T, dtype=jnp.int32)[None], (B, T))
+    # One period of the pattern (a model without one: one layer of kind None) is what the scan's body runs,
+    # its layers unrolled, each with the window and the rotary tables of its kind.
+    kinds = cfg.layer_kinds[: _period(cfg.layer_kinds)] if cfg.layer_kinds else (None,)
+    # Rope tables are layer-invariant: one sin+cos sweep per step and kind,
+    # shared by every such layer's q and k (vs 2·n_layers recomputations inside the scan).
+    def table_of(kind):
+        rope = layer_rope(cfg, kind)
+        return None if rope is None else _rope_tables(positions, cfg.head_dim, cfg.rope_theta, rope)
+
+    def layer_of(kind):
+        layer_fn = partial(_layer, cfg=cfg, mesh=mesh, attn_impl=attn_impl, kind=kind)
+        return jax.checkpoint(layer_fn, static_argnums=()) if cfg.remat else layer_fn
+
+    # Each kind once, in the pattern's order: a set's order changes from process to process, the traced program's
+    # text with it, and a compile cache then misses every other run (setup_s 160 s for 60, v5e, PR 50).
+    tables, layer_fns = ({kind: of(kind) for kind in dict.fromkeys(kinds)} for of in (table_of, layer_of))
+
+    def scan_body(carry, lps):
+        x, aux = carry
+        sent = []
+        if len(kinds) > 1:
+            # A period's leaves [p, ...] parted ONCE, so that their gradients are joined once: p indexings
+            # would each hand back a cotangent as large as the period's (an expert stack: 528 MB) to be summed.
+            lps = jax.tree.map(lambda leaf: [part[0] for part in jnp.split(leaf, len(kinds))], lps)
+        for i, kind in enumerate(kinds):
+            lp = lps if len(kinds) == 1 else jax.tree.map(lambda parts: parts[i], lps, is_leaf=lambda node: isinstance(node, list))
+            x, a, s = layer_fns[kind](lp, x, tables[kind])
+            aux = aux + a
+            sent.append(s)
+        return (x, aux), None if sent[0] is None else jnp.stack(sent)
+
+    layers = params["layers"]
+    if len(kinds) > 1:  # [L, ...] -> [periods, layers of a period, ...]
+        layers = jax.tree.map(lambda leaf: leaf.reshape(-1, len(kinds), *leaf.shape[1:]), layers)
+    (x, aux), sent = lax.scan(scan_body, (x, 0.0), layers)
+    return _rms_norm(x, params["norm_f"], cfg.norm_eps), aux, None if sent is None else sent.reshape(cfg.n_layers, -1)
 
 
 def forward_hidden(
@@ -847,24 +946,31 @@ def forward_hidden(
 ):
     """tokens [B, T] int32 -> (final hidden [B, T, D], moe aux)."""
     _refuse_inference_only(cfg, "forward_hidden")
-    B, T = tokens.shape
-    x = params["embed"].astype(cfg.dtype)[tokens]
-    positions = jnp.broadcast_to(jnp.arange(T, dtype=jnp.int32)[None], (B, T))
-    # Rope tables are layer-invariant: one sin+cos sweep per step, shared by
-    # every layer's q and k (vs 2·n_layers recomputations inside the scan).
-    rope_cs = _rope_tables(positions, cfg.head_dim, cfg.rope_theta)
+    return _run_layers(params, tokens, cfg, mesh, attn_impl)[:2]
 
-    layer_fn = partial(_layer, cfg=cfg, mesh=mesh, attn_impl=attn_impl)
-    if cfg.remat:
-        layer_fn = jax.checkpoint(layer_fn, static_argnums=())
 
-    def scan_body(carry, lp):
-        x, aux = carry
-        x, a = layer_fn(lp, x, rope_cs)
-        return (x, aux + a), None
-
-    (x, aux), _ = lax.scan(scan_body, (x, 0.0), params["layers"])
-    return _rms_norm(x, params["norm_f"], cfg.norm_eps), aux
+def moe_stats(params: dict, batch, cfg: TransformerConfig, mesh=None, attn_impl: str = "auto") -> dict:
+    """What a training loop reports of its routed experts every N steps: one
+    forward pass's worth (no loss, no gradient), jit-able. batch: {"tokens": [B,
+    T+1]} as ``loss_fn`` takes it. Returns ``assignments`` [L, E] int32 (the
+    tokens x experts a token each layer sent to each of ALL the experts),
+    ``balance`` (the mean over the layers of ``moe.balance_term``: 1.0 where
+    routing is uniform), ``held_share`` (the share of the assignments sent to
+    the experts this program holds, ``expert_share``) and ``fullest_over_mean``
+    (the fullest held expert's rows over the held experts' mean, the worst
+    layer's)."""
+    _refuse_inference_only(cfg, "moe_stats")
+    if not cfg.routed_experts:
+        raise ValueError("moe_stats: the configuration has no routed experts (experts_per_token = 0)")
+    _, balance, sent = _run_layers(params, batch["tokens"][:, :-1], cfg, mesh, attn_impl)
+    first = cfg.expert_share[0] * cfg.held_experts
+    held = sent[:, first : first + cfg.held_experts].astype(jnp.float32)
+    return {
+        "assignments": sent,
+        "balance": balance,
+        "held_share": jnp.sum(held) / jnp.sum(sent),
+        "fullest_over_mean": jnp.max(jnp.max(held, axis=1) / jnp.maximum(jnp.mean(held, axis=1), 1.0)),
+    }
 
 
 def _head(params):
@@ -896,11 +1002,11 @@ def loss_fn(params, batch, cfg: TransformerConfig, mesh=None, attn_impl: str = "
         from ray_tpu.ops.losses import fused_lm_loss
 
         x, aux = forward_hidden(params, inputs, cfg, mesh=mesh, attn_impl=attn_impl)
-        return fused_lm_loss(x, _head(params), targets, mesh=mesh) + 0.01 * aux
+        return fused_lm_loss(x, _head(params), targets, mesh=mesh) + cfg.balance_loss_coef * aux
     logits, aux = forward(params, inputs, cfg, mesh=mesh, attn_impl=attn_impl)
     logp = jax.nn.log_softmax(logits, axis=-1)
     nll = -jnp.take_along_axis(logp, targets[..., None], axis=-1)[..., 0]
-    return nll.mean() + 0.01 * aux
+    return nll.mean() + cfg.balance_loss_coef * aux
 
 
 def make_train_step(cfg: TransformerConfig, optimizer, mesh=None, attn_impl: str = "auto"):

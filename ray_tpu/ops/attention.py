@@ -64,6 +64,26 @@ _LSE_LANES = 128
 # shapes; the benchmark's cells have run only these values.
 _FWD_BLOCK = 1024
 _BWD_BLOCK = 512
+# Each of the three kernels keeps two operands of a head whole in VMEM (the
+# forward and dq its keys and values, dkv its queries and their cotangent), two
+# buffers each. Up to this many bytes of them the compiler's own scoped limit
+# (16 MiB on the v5e) holds them and the tiles (4096 tokens of 128 in bfloat16:
+# every cell the benchmark had before its 8192-token one); beyond, the kernel
+# asks for them and this much beside (at 8192 tokens the forward needs 17.7 MB
+# and was refused: compiled for the v5e off the chip, PR 50).
+_RESIDENT_BYTES = 4 << 20
+_TILES_BYTES = 16 << 20
+
+
+def _vmem(rows: int, d: int, itemsize: int) -> dict:
+    """``pallas_call``'s compiler parameters for a kernel that keeps two ``[rows,
+    d]`` operands whole: none while the default limit holds them."""
+    resident = 4 * rows * d * itemsize
+    if resident <= _RESIDENT_BYTES:
+        return {}
+    from jax.experimental.pallas import tpu as pltpu
+
+    return {"compiler_params": pltpu.CompilerParams(vmem_limit_bytes=resident + _TILES_BYTES)}
 
 
 def _flash_kernel(q_ref, k_ref, v_ref, o_ref, lse_ref=None, *, block_k: int, causal: bool, sm_scale: float, seq_k: int, block_q: int, window: int = 0):
@@ -191,6 +211,7 @@ def _pallas_flash_with_lse(q, k, v, causal: bool, sm_scale: float, block_q: int,
         out_specs=out_specs,
         out_shape=out_shape,
         interpret=interpret,
+        **_vmem(Tk, D, k.dtype.itemsize),
     )(qf, kf, vf)
     out = res[0].reshape(B, H, Tq, D).transpose(0, 2, 1, 3)
     lse = res[1][..., 0].reshape(B, H, Tq) if save_lse else None
@@ -450,6 +471,7 @@ def _pallas_bwd_impl(q, k, v, out, lse, dout, causal, sm_scale, block_q, block_k
             jax.ShapeDtypeStruct((B * H, Tk, D), v.dtype),
         ],
         interpret=interpret,
+        **_vmem(Tq, D, q.dtype.itemsize),
     )(qf, dof, kf, vf, lsef, delta)
     (dq,) = pl.pallas_call(
         functools.partial(_flash_bwd_dq_kernel, **kw),
@@ -465,6 +487,7 @@ def _pallas_bwd_impl(q, k, v, out, lse, dout, causal, sm_scale, block_q, block_k
         out_specs=[pl.BlockSpec((None, block_q, D), lambda bh, qb: (bh, qb, 0))],
         out_shape=[jax.ShapeDtypeStruct((B * H, Tq, D), q.dtype)],
         interpret=interpret,
+        **_vmem(Tk, D, k.dtype.itemsize),
     )(qf, dof, kf, vf, lsef, delta)
     unfold = lambda x, T: x.reshape(B, H, T, D).transpose(0, 2, 1, 3)
     return unfold(dq, Tq), unfold(dk, Tk), unfold(dv, Tk)

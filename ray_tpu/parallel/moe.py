@@ -10,6 +10,8 @@ tokens drop (standard Switch semantics), so the whole layer jits cleanly.
 
 from __future__ import annotations
 
+from functools import partial
+
 import jax
 import jax.numpy as jnp
 
@@ -156,7 +158,52 @@ def _every_expert(params, x, weights, layer):
     return jnp.einsum("enf,efd->nd", h, wo.astype(x.dtype), preferred_element_type=jnp.float32)
 
 
-def routed_experts(params, x, *, k: int, scale: float = 1.0, valid=None, layer=None, share=(0, 1)):
+def router_scores(logits, score: str):
+    """A router's scores over ALL its experts from its float32 logits [N, E]:
+    ``"sigmoid"`` (each expert by itself) or ``"softmax"`` (probabilities over
+    the E)."""
+    if score not in ("sigmoid", "softmax"):
+        raise ValueError(f"router score {score!r}: 'sigmoid' or 'softmax'")
+    return jax.nn.sigmoid(logits) if score == "sigmoid" else jax.nn.softmax(logits, axis=-1)
+
+
+def balance_term(scores, chosen, score: str):
+    """The Switch balance term of one layer, ``E sum_e f_e P_e``: ``f_e`` the
+    share of the ``N k`` assignments sent to expert e (a count: no gradient),
+    ``P_e`` the mean over the tokens of the router's probability for e (sigmoid
+    scores are divided by their sum over the experts first). 1.0 where the
+    routing is uniform, E where one expert takes all. scores [N, E] float32,
+    chosen [N, k] int32 -> (term, assignments [E] int32)."""
+    N, E = scores.shape
+    sent = _count(chosen.reshape(-1), E)
+    probs = scores if score == "softmax" else scores / jnp.sum(scores, axis=-1, keepdims=True)
+    return E * jnp.sum(sent.astype(jnp.float32) / chosen.size * jnp.mean(probs, axis=0)), sent
+
+
+def _count(ids, n: int):
+    """How many of ``ids`` [M] int32 are each of 0 .. n - 1, [n] int32; an id outside them is counted nowhere. By
+    comparison and sum: a scatter-add of 131,072 ones into 64 counters takes the v5e 1.2 ms, one at a time (PR 50)."""
+    return jnp.sum(ids[:, None] == jnp.arange(n, dtype=ids.dtype), axis=0, dtype=jnp.int32)
+
+
+def held_rows(assignments: int, share) -> int | None:
+    """The static bound ``routed_experts(rows=)`` takes from a caller that
+    trains ONE share of the experts, None where every expert is held. A chip
+    that holds ``1 / of`` of the experts is sent ``assignments / of`` of the
+    assignments where the routing is balanced, which a balance loss keeps it
+    near (16,384 tokens choosing 8 of 64 uniformly: a quarter +- 0.45 % of the
+    131,072): the bound is that share and a quarter more, in whole row tiles.
+    It is no capacity: a step whose routing sends more runs over the rows
+    behind the bound as well, ``rows`` at a time, and drops nothing."""
+    if share[1] == 1:
+        return None
+    tile = grouped_matmul._ROW_TILE
+    bound = -(-assignments * 5 // (4 * share[1] * tile)) * tile
+    return bound if bound < assignments else None
+
+
+def routed_experts(params, x, *, k: int, scale: float = 1.0, valid=None, layer=None, share=(0, 1),
+                   score: str = "sigmoid", rows: int | None = None):
     """Dropless top-``k`` routing over SwiGLU experts: every token reaches its
     ``k`` experts, whatever the load. No capacity, so no ``[tokens, E, C]``
     tensor: assignments are sorted by expert and the three matmuls run
@@ -164,15 +211,17 @@ def routed_experts(params, x, *, k: int, scale: float = 1.0, valid=None, layer=N
     or on a TPU, where the rows are a prefill chunk's, a kernel whose row tile
     fits a group (``experts_run``).
 
-    params: ``gate`` [D, E] and ``gate_bias`` [E] (float32), ``wg_e`` / ``wi_e``
-    [E, D, F], ``wo_e`` [E, F, D]; without ``wg_e`` an expert is ``relu(x
-    W_up)^2 W_down``, two matrices. x: [N, D]. Widths that neither grouped kernel
-    takes at the call's rows run plain batched matmuls instead (``experts_run``);
-    routing, counts and result are the same. The router runs in float32:
-    ``s = sigmoid(x gate)``; the ``k`` experts with the largest ``s + gate_bias``
-    are chosen (the bias chooses, it does not weigh), weighted
-    ``scale * s / sum(s over the chosen)``. ``valid`` [N] bool (optional):
-    rows that are padding; they reach no expert, add zeros, and are not counted.
+    params: ``gate`` [D, E] and, where the router has one, ``gate_bias`` [E]
+    (float32), ``wg_e`` / ``wi_e`` [E, D, F], ``wo_e`` [E, F, D]; without
+    ``wg_e`` an expert is ``relu(x W_up)^2 W_down``, two matrices. x: [N, D].
+    Widths that neither grouped kernel takes at the call's rows run plain
+    batched matmuls instead (``experts_run``); routing, counts and result are
+    the same. The router runs in float32: ``s = score(x gate)``
+    (``router_scores``: ``"sigmoid"``, or ``"softmax"`` over all E); the ``k``
+    experts with the largest ``s + gate_bias`` are chosen (the bias chooses, it
+    does not weigh), weighted ``scale * s / sum(s over the chosen)``. ``valid``
+    [N] bool (optional): rows that are padding; they reach no expert, add
+    zeros, and are not counted.
 
     ``layer`` (a traced int32, optional): the three expert leaves are then
     whole STACKS ``[L, E, ...]`` and this call runs layer ``layer`` of them, as
@@ -189,16 +238,29 @@ def routed_experts(params, x, *, k: int, scale: float = 1.0, valid=None, layer=N
     adds nothing: ``out`` is this share's PART of the layer's result (the
     shares' parts add up to it), and nothing stands in for the others'.
 
+    ``rows`` (static, ``held_rows``; a caller that TRAINS a share): the sorted
+    rows the grouped matmuls and their gathers run over, the held assignments
+    first. Three quarters of a quarter share's sorted rows lie in no group, and
+    what the call above carries through ``xs``, ``h`` and ``ys`` for them is
+    nothing at a decode step and, at a training step's 131,072 assignments, 604
+    MB of gathered rows a layer, forward, recomputed and backward. Dropless
+    still: a step whose routing holds more than ``rows`` assignments runs over
+    all of them (``_held_rows``), and rows of no group are selected out on the
+    way in and on the way out, so that what a grouped matmul leaves in them
+    reaches neither the result nor a gradient.
+
     Returns (out [N, D] in x's dtype, assignments [experts held] int32: tokens
     sent to each, chosen [N, k] int32: each row's experts among all E, padding
-    rows' too)."""
+    rows' too, scores [N, E] float32: the router's, for a balance term)."""
     N, D = x.shape
     E = params["gate"].shape[-1]
     held = E // share[1]
-    s = jax.nn.sigmoid(x.astype(jnp.float32) @ params["gate"].astype(jnp.float32))  # [N, E]
-    _, chosen = jax.lax.top_k(s + params["gate_bias"].astype(jnp.float32), k)  # [N, k]
-    w = jnp.take_along_axis(s, chosen, axis=-1)
-    w = scale * w / jnp.sum(w, axis=-1, keepdims=True)
+    with jax.named_scope("moe_router"):
+        s = router_scores(x.astype(jnp.float32) @ params["gate"].astype(jnp.float32), score)  # [N, E]
+        bias = params.get("gate_bias")
+        _, chosen = jax.lax.top_k(s if bias is None else s + bias.astype(jnp.float32), k)  # [N, k]
+        w = jnp.take_along_axis(s, chosen, axis=-1)
+        w = scale * w / jnp.sum(w, axis=-1, keepdims=True)
     expert = chosen.reshape(N * k)
     if held != E:  # by its rank among the experts held; one that is not held: past the last
         expert = expert - share[0] * held
@@ -209,40 +271,106 @@ def routed_experts(params, x, *, k: int, scale: float = 1.0, valid=None, layer=N
     how = experts_run(N * k, E, D, params["wo_e"].shape[-2])
     if how == "every_expert":
         sizes = jnp.zeros((held,), jnp.int32).at[expert].add(1, mode="drop")
-        rows = jnp.arange(N, dtype=jnp.int32)[:, None]
-        by_expert = jnp.zeros((N, held), jnp.float32).at[rows, expert.reshape(N, k)].add(w, mode="drop")
+        rows_of = jnp.arange(N, dtype=jnp.int32)[:, None]
+        by_expert = jnp.zeros((N, held), jnp.float32).at[rows_of, expert.reshape(N, k)].add(w, mode="drop")
         with jax.named_scope("moe_experts"):
-            return _every_expert(params, x, by_expert, layer).astype(x.dtype), sizes, chosen
-    order = jnp.argsort(expert)  # stable: assignment ids grouped by expert
-    sizes = jnp.zeros((held,), jnp.int32).at[expert].add(1, mode="drop")
-    xs = x[order // k]  # [N * k, D]
+            return _every_expert(params, x, by_expert, layer).astype(x.dtype), sizes, chosen, s
+    bounded = rows is not None and rows < N * k  # a training step's share: ``_held_rows`` gathers its own rows
+    with jax.named_scope("moe_dispatch"):
+        order = jnp.argsort(expert)  # stable: assignment ids grouped by expert
+        # (a decode step's programs keep the scatter they were built with: their text is held to the parent's)
+        sizes = _count(expert, held) if bounded else jnp.zeros((held,), jnp.int32).at[expert].add(1, mode="drop")
+        xs = None if bounded else x[order // k]  # [N * k, D]
     groups = sizes
     if layer is not None:
         stacked = params["wi_e"].shape[0]
         groups = jax.lax.dynamic_update_slice(jnp.zeros((stacked * held,), jnp.int32), sizes, (layer * held,))
 
-    def grouped(a, w_e):
-        w_e = w_e.reshape(-1, *w_e.shape[-2:])  # [L, E, in, out] -> [L * E, in, out]: no copy
-        dot = grouped_matmul.grouped_matmul if how == "kernel" else jax.lax.ragged_dot
-        return dot(a, w_e.astype(a.dtype), groups)
+    def experts(xs, groups, cast=None):
+        """``cast``: the matrices in x's dtype by leaf name, made once outside the caller's loop (``_held_rows``)."""
 
-    with jax.named_scope("moe_experts"):
-        if "wg_e" in params:
-            h = jax.nn.silu(grouped(xs, params["wg_e"])) * grouped(xs, params["wi_e"])
-        else:
-            h = jnp.square(jax.nn.relu(grouped(xs, params["wi_e"])))
-        ys = grouped(h, params["wo_e"])  # [N * k, D]; a row of no group holds whatever the kernel left there
-    # Un-sort by gather (assignment a sits at sorted row inverse[a]), combine in float32.
-    inverse = jnp.zeros((N * k,), jnp.int32).at[order].set(jnp.arange(N * k, dtype=jnp.int32))
-    y = ys[inverse].reshape(N, k, D).astype(jnp.float32)
-    if held != E:  # whatever the grouped matmul left in a row of no group
-        first = share[0] * held
-        y = jnp.where(((chosen >= first) & (chosen < first + held))[..., None], y, 0.0)
-    if valid is not None:
-        # A row that is no token (an inactive slot, a chunk's padding) is in no group, and what the grouped matmul
-        # leaves in such a row is whatever was there: zeros at some widths, at others (3584 x 1024, v5e, PR 47) not
-        # even finite, and a weight of 0 does not mask that. Such a row's output is read by nobody, but its cached
-        # row lands in the null block, which every gathered view holds behind its mask, where 0 x NaN is NaN.
-        y = jnp.where(valid[:, None, None], y, 0.0)
-        w = jnp.where(valid[:, None], w, 0.0)
-    return jnp.einsum("nk,nkd->nd", w, y).astype(x.dtype), sizes, chosen
+        def grouped(a, name):
+            w_e = params[name].reshape(-1, *params[name].shape[-2:])  # [L, E, in, out] -> [L * E, in, out]: no copy
+            if how == "kernel":  # casts the matrices itself: their gradient comes back in the leaf's dtype
+                return grouped_matmul.grouped_matmul(a, w_e, groups, cast=None if cast is None else cast[name])
+            return jax.lax.ragged_dot(a, w_e.astype(a.dtype) if cast is None else cast[name], groups)
+
+        with jax.named_scope("moe_experts"):
+            if "wg_e" in params:
+                h = jax.nn.silu(grouped(xs, "wg_e")) * grouped(xs, "wi_e")
+            else:
+                h = jnp.square(jax.nn.relu(grouped(xs, "wi_e")))
+            return grouped(h, "wo_e")  # a row of no group holds whatever the kernel left there
+
+    if bounded:
+        if valid is not None:
+            raise ValueError("rows= is a training step's bound: it has no padding rows (valid=)")
+        cast = {name: params[name].astype(x.dtype) for name in ("wg_e", "wi_e", "wo_e") if name in params}
+        return _held_rows(partial(experts, cast=cast), x, w, order, groups, rows, k), sizes, chosen, s
+    ys = experts(xs, groups)  # [N * k, D]
+    with jax.named_scope("moe_combine"):
+        # Un-sort by gather (assignment a sits at sorted row inverse[a]), combine in float32.
+        inverse = jnp.zeros((N * k,), jnp.int32).at[order].set(jnp.arange(N * k, dtype=jnp.int32))
+        y = ys[inverse].reshape(N, k, D).astype(jnp.float32)
+        if held != E:  # whatever the grouped matmul left in a row of no group
+            first = share[0] * held
+            y = jnp.where(((chosen >= first) & (chosen < first + held))[..., None], y, 0.0)
+        if valid is not None:
+            # A row that is no token (an inactive slot, a chunk's padding) is in no group, and what the grouped matmul
+            # leaves in such a row is whatever was there: zeros at some widths, at others (3584 x 1024, v5e, PR 47) not
+            # even finite, and a weight of 0 does not mask that. Such a row's output is read by nobody, but its cached
+            # row lands in the null block, which every gathered view holds behind its mask, where 0 x NaN is NaN.
+            y = jnp.where(valid[:, None, None], y, 0.0)
+            w = jnp.where(valid[:, None], w, 0.0)
+        return jnp.einsum("nk,nkd->nd", w, y).astype(x.dtype), sizes, chosen, s
+
+
+def _held_rows(experts, x, w, order, groups, rows: int, k: int):
+    """``routed_experts(rows=)``: the experts over the sorted assignments
+    ``rows`` at a time, as many times as the held ones take: once where they
+    fit the bound, and no row dropped where they do not. x [N, D], w [N, k]
+    float32, ``order`` [N k] the assignments sorted by expert, those of no held
+    expert last, ``groups`` the held experts' rows -> this share's part of the
+    result [N, D].
+
+    A sorted row is gathered from its token and its weighted result added back
+    to its token (a scatter-add of ``rows`` rows: the un-sort by gather that a
+    decode step uses would gather all ``N k``, dead ones too). The first piece
+    runs as it stands; the pieces behind the bound are the iterations of one
+    scan inside one cond, so a step holds one piece's temporaries however the
+    routing falls, and a step whose held rows fit the bound pays the others
+    nothing (a scan that runs and skips its iterations still sums a zero
+    gradient of every matrix an iteration: 28 ms a step at the benchmark's
+    widths, v5e, PR 50). A piece is checkpointed, and so is the scan: neither
+    keeps residuals of its own (a cond saves both branches', zeros for the one
+    not taken). Under a layer's own checkpoint a piece does not run once more
+    for that: nothing keeps the result of an expert block as a residual."""
+    N, D = x.shape
+    pieces = -(-N * k // rows)
+    order = jnp.pad(order, (0, pieces * rows - N * k))
+    ends = jnp.cumsum(groups)
+    starts, sent = ends - groups, ends[-1]
+
+    @jax.checkpoint
+    def run(out, x, w, first):
+        with jax.named_scope("moe_dispatch"):
+            at = jax.lax.dynamic_slice(order, (first,), (rows,))
+            live = (first + jnp.arange(rows, dtype=jnp.int32) < sent)[:, None]
+            token = jnp.where(live[:, 0], at // k, 0)
+            xs = jnp.where(live, x[token], 0)
+            inside = jnp.clip(ends, first, first + rows) - jnp.clip(starts, first, first + rows)
+        ys = experts(xs, inside)
+        with jax.named_scope("moe_combine"):
+            # Selected BEFORE the product: what a grouped matmul leaves in a row of no group may not be finite, and
+            # the product's gradient in the weight is the cotangent TIMES that row, where 0 x NaN is NaN.
+            return out.at[token].add(jnp.where(live, ys, 0).astype(jnp.float32) * w.reshape(-1)[at][:, None])
+
+    @jax.checkpoint
+    def behind(out, x, w):
+        def piece(out, first):  # a piece behind the last held row leaves the sum as it is
+            return jax.lax.cond(first < sent, run, lambda out, x, w, first: out, out, x, w, first), None
+
+        return jax.lax.scan(piece, out, jnp.arange(1, pieces, dtype=jnp.int32) * rows)[0]
+
+    out = run(jnp.zeros((N, D), jnp.float32), x, w, jnp.int32(0))
+    return jax.lax.cond(sent > rows, behind, lambda out, x, w: out, out, x, w).astype(x.dtype)

@@ -14,6 +14,7 @@ REPO = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
 
 EXAMPLES = [
     ("train_transformer.py", ["2"], "final loss:"),
+    ("train_moe.py", ["3"], "held share: 0."),
     ("serve_llm.py", [], "generated:"),
     ("tune_hyperparams.py", [], "best config:"),
     ("data_pipeline.py", [], "jax batches ok"),

@@ -219,7 +219,7 @@ def test_the_bias_chooses_and_does_not_weigh():
     plain, *_ = routed_experts(params, x, k=2, scale=1.8)
     # A bias that lifts experts 6 and 7 over every other: all tokens go there ...
     biased = dict(params, gate_bias=jnp.zeros((8,)).at[6:].set(5.0))
-    out, sent, _ = routed_experts(biased, x, k=2, scale=1.8)
+    out, sent, *_ = routed_experts(biased, x, k=2, scale=1.8)
     assert sent.tolist() == [0] * 6 + [12, 12]
     # ... weighted by their sigmoid scores alone, normalised, times the scale.
     w = 1.8 * s[:, 6:] / s[:, 6:].sum(-1, keepdims=True)
@@ -237,7 +237,7 @@ def test_every_token_routed_to_one_expert_loses_none(tokens):
     Switch layer at its training capacity drops most of them."""
     params, x = _experts(jax.random.PRNGKey(4), N=tokens)
     params["gate_bias"] = jnp.zeros((8,)).at[3].set(9.0).at[5].set(8.0)
-    out, sent, _ = routed_experts(params, x, k=2, scale=1.0)
+    out, sent, *_ = routed_experts(params, x, k=2, scale=1.0)
     assert sent.tolist() == [0, 0, 0, tokens, 0, tokens, 0, 0]
     s = jax.nn.sigmoid(x @ params["gate"])
     w3, w5 = s[:, 3] / (s[:, 3] + s[:, 5]), s[:, 5] / (s[:, 3] + s[:, 5])
@@ -249,15 +249,15 @@ def test_every_token_routed_to_one_expert_loses_none(tokens):
 def test_padding_rows_reach_no_expert_and_a_stack_is_run_by_layer():
     params, x = _experts(jax.random.PRNGKey(5))
     valid = jnp.arange(12) % 3 != 0
-    out, sent, _ = routed_experts(params, x, k=2, scale=1.8, valid=valid)
-    whole, all_sent, _ = routed_experts(params, x, k=2, scale=1.8)
+    out, sent, *_ = routed_experts(params, x, k=2, scale=1.8, valid=valid)
+    whole, all_sent, *_ = routed_experts(params, x, k=2, scale=1.8)
     assert int(sent.sum()) == 2 * 8 and int(all_sent.sum()) == 2 * 12
     assert not np.asarray(out[~valid]).any()
     np.testing.assert_allclose(np.asarray(out[valid]), np.asarray(whole[valid]), atol=1e-6)
     # Stacked [L, E, ...] leaves with a layer index: the same numbers as the layer's own leaves.
     others, _ = _experts(jax.random.PRNGKey(6))
     stacked = {n: jnp.stack([others[n], params[n], others[n]]) for n in ("wg_e", "wi_e", "wo_e")}
-    by_layer, sent_l, _ = jax.jit(
+    by_layer, sent_l, *_ = jax.jit(
         lambda layer: routed_experts({**params, **stacked}, x, k=2, scale=1.8, layer=layer)
     )(jnp.int32(1))
     np.testing.assert_allclose(np.asarray(by_layer), np.asarray(whole), atol=1e-6)
@@ -280,7 +280,7 @@ def test_what_the_grouped_matmul_leaves_in_a_row_of_no_group_reaches_nothing(sta
         others, _ = _experts(jax.random.PRNGKey(6))
         params = {**params, **{n: jnp.stack([others[n], params[n], others[n]]) for n in ("wg_e", "wi_e", "wo_e")}}
         kwargs["layer"] = jnp.int32(1)
-    want, sent, _ = routed_experts(params, x, **kwargs)
+    want, sent, *_ = routed_experts(params, x, **kwargs)
     ragged_dot, poisoned = jax.lax.ragged_dot, []
 
     def poisoning(a, w_e, groups):
@@ -289,7 +289,7 @@ def test_what_the_grouped_matmul_leaves_in_a_row_of_no_group_reaches_nothing(sta
         return jnp.where(in_a_group[:, None], ragged_dot(a, w_e, groups), jnp.nan)
 
     monkeypatch.setattr(jax.lax, "ragged_dot", poisoning)
-    out, sent_p, _ = routed_experts(params, x, **kwargs)
+    out, sent_p, *_ = routed_experts(params, x, **kwargs)
     assert poisoned == [2 * 4] * 3  # the four rows that are no token, twice each, in all three products
     assert np.isfinite(np.asarray(out)).all() and not np.asarray(out[~valid]).any()
     np.testing.assert_array_equal(np.asarray(out[valid]), np.asarray(want[valid]))
@@ -334,15 +334,15 @@ def test_the_grouped_kernel_gives_what_ragged_dot_and_a_plain_loop_give(case, mo
         others, _ = _experts(jax.random.PRNGKey(6), N=N, D=D, E=E, F=F)
         params.update({n: jnp.stack([others[n][:held].astype(jnp.bfloat16), params[n], params[n] * 0]) for n in leaves})
         kwargs["layer"] = jnp.int32(1)
-    want, sent, chosen = routed_experts(params, x, **kwargs)
+    want, sent, chosen, _ = routed_experts(params, x, **kwargs)
     by_expert = dict(zip(range(first, first + held), sent.tolist()))  # of the experts held here
     assert by_expert.get(3, real) == real and by_expert.get(5, 0) == 0 and sum(by_expert.values()) <= real * k
 
     calls = []
     kernel = grouped_matmul.grouped_matmul
-    monkeypatch.setattr(grouped_matmul, "grouped_matmul", lambda *a: calls.append(a[0].shape) or kernel(*a))
+    monkeypatch.setattr(grouped_matmul, "grouped_matmul", lambda *a, **kw: calls.append(a[0].shape) or kernel(*a, **kw))
     monkeypatch.setattr(importlib.import_module("ray_tpu.ops.attention"), "_on_tpu", lambda: True)
-    out, sent_k, chosen_k = routed_experts(params, x, **kwargs)
+    out, sent_k, chosen_k, _ = routed_experts(params, x, **kwargs)
     assert calls == [(N * k, D)] * (2 if opts["gated"] else 1) + [(N * k, F)]
     assert sent_k.tolist() == sent.tolist() and np.array_equal(np.asarray(chosen_k), np.asarray(chosen))
 
@@ -367,10 +367,10 @@ def test_the_grouped_kernel_gives_what_ragged_dot_and_a_plain_loop_give(case, mo
 
 
 def _expert_cells():
-    """The benchmark's cells whose configuration routes experts, by its published keys."""
+    """The benchmark's SERVING cells whose configuration routes experts, by its published keys."""
     manifest = registry.load_manifest()
     configs = {w["name"]: registry.load_cell(manifest, w["name"])["config"] for w in manifest["workloads"]}
-    return [name for name, config in configs.items() if {"n_routed_experts", "num_experts"} & set(config)]
+    return [name for name, config in configs.items() if {"n_routed_experts", "num_experts"} & set(config) and config["path"] == "serve"]
 
 
 @pytest.mark.parametrize("cell_name", _expert_cells())
@@ -425,8 +425,11 @@ def test_the_dense_layer_sits_outside_the_scan_over_the_expert_layers(model):
     assert (np.abs(written).sum(axis=-1) > 0).all() and not np.asarray(after["ckv"])[:, 2].any()
 
 
+STANDARD_ATTENTION = dict(kv_lora_rank=0, qk_nope_head_dim=0, qk_rope_head_dim=0, v_head_dim=0, q_lora_rank=0)
+
+
 @pytest.mark.parametrize("field, what", [
-    (dict(kv_lora_rank=0, qk_nope_head_dim=0, qk_rope_head_dim=0, v_head_dim=0, q_lora_rank=0), "dropless routed experts"),
+    (STANDARD_ATTENTION, "a router's bias that chooses \\(router_bias\\) has no bias update rule; shared experts"),
     (dict(experts_per_token=0, num_experts=0, num_shared_experts=0, first_dense_layers=0), "latent attention"),
 ])
 def test_the_training_path_refuses_what_it_cannot_train(model, field, what):
@@ -438,9 +441,37 @@ def test_the_training_path_refuses_what_it_cannot_train(model, field, what):
         transformer.make_train_step(one, optax.sgd(0.1))
     with pytest.raises(NotImplementedError, match="forward_hidden cannot run.*served through models/generate.py"):
         transformer.forward_hidden(init_params(jax.random.PRNGKey(0), one), jnp.zeros((1, 4), jnp.int32), one)
-    with pytest.raises(NotImplementedError, match="latent attention.*; dropless routed experts"):
+    with pytest.raises(NotImplementedError, match="latent attention.*; a router's bias that chooses"):
         transformer.loss_fn({}, {"tokens": jnp.zeros((1, 5), jnp.int32)}, cfg)
     assert TransformerConfig(num_experts=4).inference_only == ""  # the Switch layer trains
+
+
+def test_dropless_routed_experts_train_and_agree_with_the_cached_forward_pass(model):
+    """The case of the test above whose field gained a block (PR 50): the dropless
+    routed experts, under standard attention, without a router bias, a shared
+    expert or a leading dense layer (each still refused by name). The training
+    block's logits are the serving path's over a cache (``prefill``: the same
+    router, sort and grouped matmuls from the other caller), a step of SGD lowers
+    the loss, and every leaf moves."""
+    import optax
+
+    cfg, _ = model
+    trains = dataclasses.replace(cfg, **STANDARD_ATTENTION, router_bias=False, num_shared_experts=0, first_dense_layers=0, head_dim=0)
+    assert trains.routed_experts and trains.inference_only == ""
+    params = init_params(jax.random.PRNGKey(5), trains)
+    assert "gate_bias" not in params["layers"] and set(params) == {"embed", "layers", "norm_f", "lm_head"}
+    tokens = jnp.asarray(np.random.default_rng(5).integers(0, trains.vocab_size, (2, 33), dtype=np.int32))
+    logits, balance = transformer.forward(params, tokens[:, :-1], trains)
+    cache = init_cache(trains, 2, 32)
+    served, _, _ = prefill(params, tokens[:, :-1], cache, trains)
+    np.testing.assert_allclose(np.asarray(logits[:, -1]), np.asarray(served), atol=2e-4)
+    assert 0.99 < float(balance) < 1.5
+    opt = optax.sgd(0.05)
+    step = jax.jit(transformer.make_train_step(trains, opt))
+    new, _, loss = step(params, opt.init(params), {"tokens": tokens})
+    _, _, after = step(new, opt.init(new), {"tokens": tokens})
+    assert float(after) < float(loss)
+    assert all(jax.tree.leaves(jax.tree.map(lambda a, b: bool(jnp.any(a != b)), params, new)))
 
 
 @pytest.mark.parametrize("shape", [(50, 7), (12, 5, 6), (3, 4, 5, 6), (6,)])

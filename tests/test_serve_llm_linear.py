@@ -416,10 +416,12 @@ def test_the_training_path_refuses_the_new_fields_by_name(model):
     plain = dict(layer_kinds=(), post_norms=False, qk_norm_whole=False, pre_norms=True, linear_heads=0, linear_key_dim=0,
                  linear_value_dim=0, linear_conv=4, linear_neg_eigval=False)
     for field in plain:
-        if field == "layer_kinds":
+        if field == "layer_kinds":  # since PR 50 a pattern of window and full layers trains (linear layers: the regex above)
             one = dataclasses.replace(cfg, **{**plain, "layer_kinds": ("full",) * 8})
-        else:
-            one = dataclasses.replace(cfg, **{**plain, field: 3 if field == "linear_conv" else getattr(cfg, field)})
+            assert one.inference_only == ""
+            transformer.make_train_step(one, optax.sgd(0.1))
+            continue
+        one = dataclasses.replace(cfg, **{**plain, field: 3 if field == "linear_conv" else getattr(cfg, field)})
         with pytest.raises(NotImplementedError, match="forward_hidden cannot run.*no training block"):
             transformer.forward_hidden({}, jnp.zeros((1, 4), jnp.int32), one)
     transformer.make_train_step(dataclasses.replace(cfg, **plain), optax.sgd(0.1))  # and nothing else is in the way

@@ -418,7 +418,7 @@ def test_the_training_path_refuses_the_new_fields_by_name(model):
 
     _, cfg = model
     with pytest.raises(NotImplementedError, match=r"layer pattern.*Mamba-2 state-space blocks \(mamba_heads\).*\(mamba_head_dim\).*"
-                                                   r"\(ssm_state\).*\(ssm_groups\).*gate matrix \(expert_activation\).*held share of the experts"):
+                                                   r"\(ssm_state\).*\(ssm_groups\).*gate matrix \(expert_activation\)"):
         transformer.make_train_step(cfg, optax.sgd(0.1))
     plain = dict(layer_kinds=(), mamba_heads=0, mamba_head_dim=0, ssm_state=0, ssm_groups=1, mamba_conv=4,
                  experts_per_token=0, num_experts=0, d_expert=0, num_shared_experts=0, routed_scaling_factor=1.0,
@@ -429,7 +429,10 @@ def test_the_training_path_refuses_the_new_fields_by_name(model):
         with pytest.raises(NotImplementedError, match="forward_hidden cannot run.*no training block"):
             transformer.forward_hidden({}, jnp.zeros((1, 4), jnp.int32), one)
     shared = dataclasses.replace(cfg, **{**plain, "num_experts": 8, "experts_per_token": 2, "d_expert": 8, "expert_share": (1, 2)})
-    assert "a held share of the experts (expert_share)" in shared.inference_only
+    # Since PR 50 a held share of the experts trains (tests/test_moe_training.py: the shares add up, and the share
+    # agrees with the benchmark's reference); what is still in such a configuration's way is its router's bias.
+    assert "expert_share" not in shared.inference_only and "router_bias" in shared.inference_only
+    transformer.make_train_step(dataclasses.replace(shared, router_bias=False), optax.sgd(0.1))
     transformer.make_train_step(dataclasses.replace(cfg, **plain), optax.sgd(0.1))  # and nothing else is in the way
 
 

@@ -870,3 +870,98 @@ def test_the_new_fields_at_their_defaults_are_the_configuration_that_states_neit
     positions = jnp.arange(8)[None]
     plain, scaled = _rope_tables(positions, 64, cfg.rope_theta), _rope_tables(positions, 64, cfg.rope_theta, cfg.rope_scaling)
     assert all(bool((a == b).all()) for a, b in zip(plain, scaled))
+
+
+# The same of EVERY serving configuration's two programs as a TPU backend gets them (each kernel's predicate told it
+# is on a TPU: the pool read in place where the configuration has such a kernel, the prefill chunk's experts through
+# ``ops/grouped_matmul.py``): the decode step at the whole table and the prefill chunk, kernels' bodies without their
+# locations, as PR 49's tree gives them (computed from a copy of that commit, PR 50, which gave ``routed_experts`` a
+# score function, scopes and a bounded path for a training step's share, ``grouped_matmul`` a ``custom_vjp`` of the
+# repo's own and ``_project_qkv`` its rotary by kind of layer: none of it may reach a serving program).
+_TPU_PROGRAMS_OF_PR_49 = {
+    "serve16.chat-open": ("300a106ea60589510bbff1569b8ba27f96bd2eb2", "12bea586c90179b8fe8ea8526dc82e3f9d61ecfa"),
+    "glm8.rollout-long": ("7f052d4f03462a7ada2a691555e5dd8d0e262440", "84e8fc4c41be17775d4d6e03b74b75432e221d6f"),
+    "trinity5.rollout-longctx": ("3f92c0e52e7e1dc2cc33e5135e415cc8b96e01a8", "eef0631f6170706e36c020383d44ddd2cd9948a2"),
+    "olmo16.longdoc-8k": ("e7be9e7bfae70d4fec81535e9821bc0a4a760f75", "0fda9676a9cf4a725d3b4724104b7306aaa6c29a"),
+    "nemo14.chat-churn": ("88e952e949349c8c6516f24d03ffafa061a6acbf", "708022f335fd295af089b98832841c8e12c1d188"),
+    "xing6.longdoc-12k": ("bf88be242df72adabf5f9c274ad0be66a09e2431", "f065f095363487da4fcdb34196e8dd95831a9d7f"),
+}
+# And of Xing4.0's two programs as a CPU backend gets them, which ``_PROGRAMS_OF_PR_34`` lacks.
+_PROGRAMS_OF_PR_49 = {"xing6.longdoc-12k": ("338740d7603432f4faf41e8dec89afe6446efa2d", "4b7743fb4648293d40309ee3c41b4c2191bc95d7")}
+# The train step of ``train2.dense-4k`` (Mistral-7B at 2 layers, T = 4096, batch 1, AdamW, donated) with the flash
+# kernels, and as a CPU backend lowers it; the Switch layer's at a toy size.
+_TRAIN_STEPS_OF_PR_49 = {
+    "train2@tpu": "e13c36b676bac5064596874c4cf745270bdce54a", "train2@cpu": "bf81db40cdc8b2d45bb6d93a1f806385e3d3111a", "switch": "354aa96426f78165d3e5b17f35ff98123ea717ea",
+}
+
+
+def _digest(text, kernels=True):
+    import hashlib
+
+    if kernels:
+        try:
+            text = _without_locations(text)
+        except AssertionError:  # no kernel in this program
+            pass
+    return hashlib.sha1(text.encode()).hexdigest()
+
+
+@pytest.mark.parametrize("cell_name", sorted(_TPU_PROGRAMS_OF_PR_49))
+def test_every_serving_configurations_tpu_programs_are_the_parents(cell_name, monkeypatch):
+    import importlib
+
+    for module in ("ray_tpu.ops.attention", "ray_tpu.ops.paged_attention", "ray_tpu.ops.latent_attention", "ray_tpu.ops.grouped_matmul"):
+        monkeypatch.setattr(importlib.import_module(module), "_on_tpu", lambda: True)
+    engine = importlib.import_module("ray_tpu.serve.llm.engine")
+    monkeypatch.setattr(engine, "_JIT_CACHE", {})
+    _, _, args = _cell_programs(cell_name)
+    (fns,) = engine._JIT_CACHE.values()
+    settings = _cell_config(cell_name)[1]
+    n_max = -(-settings["max_model_len"] // settings["block_size"])
+    texts = (
+        fns[0].trace(*args(n_max)).lower(lowering_platforms=("tpu",)).as_text(),
+        fns[1].trace(*args(None)).lower(lowering_platforms=("tpu",)).as_text(),
+    )
+    assert tuple(_digest(t) for t in texts) == _TPU_PROGRAMS_OF_PR_49[cell_name]
+
+
+@pytest.mark.parametrize("cell_name", sorted(_PROGRAMS_OF_PR_49))
+def test_the_seventh_configurations_cpu_programs_are_the_parents(cell_name):
+    decode, prefill, args = _cell_programs(cell_name)
+    texts = (
+        decode.trace(*args(64)).lower(lowering_platforms=("tpu",)).as_text(),
+        prefill.trace(*args(None)).lower(lowering_platforms=("tpu",)).as_text(),
+    )
+    assert tuple(_digest(t, kernels=False) for t in texts) == _PROGRAMS_OF_PR_49[cell_name]
+
+
+@pytest.mark.parametrize("which", sorted(_TRAIN_STEPS_OF_PR_49))
+def test_the_accepted_train_steps_are_the_parents(which, monkeypatch):
+    """PR 50 gave the training block a pattern of layers, a rotary table a kind,
+    routed experts and a balance coefficient: a configuration that states none of
+    them (Mistral's two cells, the Switch layer) lowers to the parent's text."""
+    import importlib
+
+    import jax
+    import jax.numpy as jnp
+    import optax
+
+    from benchmarks.harness import registry
+    from ray_tpu.models.transformer import TransformerConfig, init_params, make_train_step
+
+    if which == "switch":
+        cfg, tokens, donate = TransformerConfig(vocab_size=512, d_model=128, n_layers=2, n_heads=4, n_kv_heads=2, d_ff=256, max_seq_len=128, num_experts=4), (2, 129), ()
+    else:
+        cell = registry.load_cell(registry.load_manifest(), "train2.dense-4k")
+        model = registry.load_architecture(cell, "config").model_config(cell["config"], 4096, "float32")
+        for key in ("dtype", "param_dtype"):
+            model[key] = jnp.dtype(model[key]).type
+        cfg, tokens, donate = TransformerConfig(**model, remat=True, fused_loss=True), (1, 4097), (0, 1)
+    if which == "train2@tpu":
+        monkeypatch.setattr(importlib.import_module("ray_tpu.ops.attention"), "_on_tpu", lambda: True)
+    assert cfg.balance_loss_coef == 0.01 and cfg.router_score == "sigmoid" and cfg.rope_scaling == ()
+    params = jax.eval_shape(lambda: init_params(jax.random.PRNGKey(0), cfg))
+    opt = optax.adamw(1e-4)
+    step = jax.jit(make_train_step(cfg, opt), donate_argnums=donate)
+    text = step.trace(params, jax.eval_shape(opt.init, params), {"tokens": jax.ShapeDtypeStruct(tokens, jnp.int32)}).lower(lowering_platforms=("tpu",)).as_text()
+    assert _digest(text, kernels=which == "train2@tpu") == _TRAIN_STEPS_OF_PR_49[which]
