@@ -13,8 +13,19 @@ TPU-first choices:
 - bf16 activations/params by default; f32 RMSNorm epsilon path and logits
 - rotary embeddings, GQA (n_kv_heads <= n_heads), SwiGLU MLP, optional
   mixture-of-experts MLP (parallel/moe.py) sharded over ``ep``
-- remat (jax.checkpoint) around each layer: trades FLOPs for HBM, the standard
-  TPU fit knob.
+- remat (``jax.checkpoint``) around each layer trades FLOPs for HBM. A layer
+  keeps its input and, by a policy over names (``_KEPT_UNDER_REMAT``), what its
+  attention core was given and gave back: q, k and v behind the norms and the
+  rotary (K and V at ``n_kv_heads``), the flash kernel's output and its
+  log-sum-exp: ``B x T x (2 H + 2 KV) x Dh`` values of ``dtype`` and ``B x H x
+  T`` float32 a layer, ~2.5x a layer's input for Mistral-7B's widths and ~4x for
+  Mellum's; under ``qk_norm`` also q and k as projected, which the norms'
+  backward pass reads (``(H + KV) x Dh`` more a token: ~6x for Mellum's). An
+  eighth or less of what ``remat=False`` keeps. The backward pass then runs the
+  two flash backward kernels on them; the forward kernel, the q/k/v
+  projections, their norms and their rotary run once a step. The block's other
+  norms, ``wo`` and the MLP or the experts (the d_ff-wide rows) are recomputed.
+  One policy for every configuration, sized by the shapes alone.
 
 (The reference has no in-tree model zoo for LLMs — its Train/RLlib models are
 torch modules; SURVEY.md §2.3/§5.7. This module is the TPU-native equivalent
@@ -32,6 +43,9 @@ from typing import Any, NamedTuple
 import jax
 import jax.numpy as jnp
 from jax import lax
+from jax.ad_checkpoint import checkpoint_name
+
+from ray_tpu.ops.attention import FLASH_LSE, FLASH_OUT, flash_attention
 
 
 @dataclasses.dataclass(frozen=True)
@@ -82,6 +96,10 @@ class TransformerConfig:
     qk_nope_head_dim: int = 0
     qk_rope_head_dim: int = 0
     v_head_dim: int = 0
+    # Each layer under ``jax.checkpoint``: kept for the backward pass are the layer's input [B, T, D] and the attention
+    # core's q, k, v, output and log-sum-exp ((2 H + 2 KV) Dh values of ``dtype`` + H float32 a token: 84 MB a layer at
+    # Mistral's 1 x 4096 tokens) and, under ``qk_norm``, q and k as projected ((H + KV) Dh more: 455 MB a layer at
+    # Mellum's 2 x 8192); the rest of the layer is recomputed (the module's docstring).
     remat: bool = True
     tie_embeddings: bool = False
     # Mistral-style sliding-window causal attention (0 = full causal):
@@ -782,6 +800,13 @@ def layer_rope(cfg: TransformerConfig, kind):
     return () if kind else cfg.rope_scaling
 
 
+# What a layer under ``cfg.remat`` keeps for its backward pass beside its input (the module's docstring): the
+# attention core's inputs, named in ``_attention_block``, and the flash kernel's results, named in its forward rule.
+_KEPT_INPUTS = ("attention_q", "attention_k", "attention_v")
+_KEPT_NORM_INPUTS = ("attention_q_projected", "attention_k_projected")
+_KEPT_UNDER_REMAT = jax.checkpoint_policies.save_only_these_names(*_KEPT_INPUTS, *_KEPT_NORM_INPUTS, FLASH_OUT, FLASH_LSE)
+
+
 def _attention_block(lp, x, rope_cs, cfg: TransformerConfig, mesh, attn_impl: str, kind=None):
     """``kind``: the layer's of ``layer_kinds`` (None without a pattern): a full
     layer attends over the whole context, the others within
@@ -793,17 +818,21 @@ def _attention_block(lp, x, rope_cs, cfg: TransformerConfig, mesh, attn_impl: st
     k = (h @ lp["wk"].astype(h.dtype)).reshape(B, T, KV, Dh)
     v = (h @ lp["wv"].astype(h.dtype)).reshape(B, T, KV, Dh)
     if cfg.qk_norm:
+        # A norm's backward pass needs its input: kept too, or the projections run again for it (Mellum's cell, v5e,
+        # PR 51: 435.4 ms a step with q and k kept behind the norms alone, 408.7 with both).
+        q, k = (checkpoint_name(a, name) for a, name in zip((q, k), _KEPT_NORM_INPUTS))
         q, k = _rms_norm(q, lp["q_norm"], cfg.norm_eps), _rms_norm(k, lp["k_norm"], cfg.norm_eps)
     if rope_cs is not None:
         cos, sin = rope_cs
         q = _rope_apply(q, cos, sin)
         k = _rope_apply(k, cos, sin)
+    # What the attention core was given, as ``_run_layers``' checkpoint policy keeps it: behind the norms and the
+    # rotary and before the repeat (K and V at ``KV`` heads).
+    q, k, v = (checkpoint_name(a, name) for a, name in zip((q, k, v), _KEPT_INPUTS))
     if KV != H:  # GQA: repeat kv heads
         rep = H // KV
         k = jnp.repeat(k, rep, axis=2)
         v = jnp.repeat(v, rep, axis=2)
-    from ray_tpu.ops.attention import flash_attention
-
     window = 0 if kind == "full" else cfg.sliding_window
     if attn_impl == "ring" and mesh is not None and mesh.shape.get("sp", 1) > 1:
         if window:
@@ -910,7 +939,7 @@ def _run_layers(params: dict, tokens, cfg: TransformerConfig, mesh, attn_impl: s
 
     def layer_of(kind):
         layer_fn = partial(_layer, cfg=cfg, mesh=mesh, attn_impl=attn_impl, kind=kind)
-        return jax.checkpoint(layer_fn, static_argnums=()) if cfg.remat else layer_fn
+        return jax.checkpoint(layer_fn, policy=_KEPT_UNDER_REMAT) if cfg.remat else layer_fn
 
     # Each kind once, in the pattern's order: a set's order changes from process to process, the traced program's
     # text with it, and a compile cache then misses every other run (setup_s 160 s for 60, v5e, PR 50).

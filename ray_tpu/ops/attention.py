@@ -24,6 +24,7 @@ import math
 
 import jax
 import jax.numpy as jnp
+from jax.ad_checkpoint import checkpoint_name
 
 
 def _xla_attention(q, k, v, causal: bool, sm_scale: float, bias=None, window: int = 0):
@@ -224,8 +225,15 @@ def _pallas_flash(q, k, v, causal: bool, sm_scale: float, block_q: int, block_k:
     return out
 
 
+# What a layer under ``jax.checkpoint`` may keep of this call (models/transformer.py's policy names them): the
+# forward kernel's two results. Kept, the backward pass runs the two backward kernels on them and the forward kernel
+# once a step, not twice. A name outside a checkpoint with a policy is the identity.
+FLASH_OUT, FLASH_LSE = "flash_out", "flash_lse"
+
+
 def _pallas_flash_fwd(q, k, v, causal, sm_scale, block_q, block_k, interpret, window=0):
     out, lse = _pallas_flash_with_lse(q, k, v, causal, sm_scale, block_q, block_k, interpret, window=window)
+    out, lse = checkpoint_name(out, FLASH_OUT), checkpoint_name(lse, FLASH_LSE)
     return out, (q, k, v, out, lse)
 
 

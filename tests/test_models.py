@@ -249,3 +249,90 @@ def test_pallas_backward_kernels_vs_oracle(monkeypatch):
         for name, a, b in zip("qkv", gp, gx):
             rel = float(jnp.abs(a - b).max() / (jnp.abs(b).max() + 1e-9))
             assert rel < 1e-4, f"T={Tq}/{Tk} causal={causal} w={window} d{name}: {rel}"
+
+
+# What a layer under ``cfg.remat`` keeps (PR 51): the attention core's inputs and the flash kernel's results. The
+# toys: the dense GQA block, the ``mellum`` block (norms of queries and keys, a pattern of window and full layers,
+# dropless routed experts) two periods deep, and the Switch layer.
+_REMAT_TOYS = {
+    "dense": dict(),
+    "qk_norm, a pattern, routed experts": dict(
+        n_layers=4, layer_kinds=("window", "full") * 2, sliding_window=32, qk_norm=True, head_dim=16, rope_scaling=(("factor", 4.0), ("original_max_position_embeddings", 16.0), ("beta_fast", 32.0), ("beta_slow", 1.0), ("mscale", 1.0), ("mscale_all_dim", 0.0)),
+        num_experts=8, experts_per_token=2, d_expert=16, router_score="softmax", router_bias=False,
+    ),
+    "switch": dict(num_experts=4),
+}
+
+
+def _scanned_layers(cfg):
+    """Layers in the body of ``_run_layers``' scan: a period of the pattern, or one."""
+    from ray_tpu.models.transformer import _period
+
+    return _period(cfg.layer_kinds) if cfg.layer_kinds else 1
+
+
+@pytest.mark.parametrize("toy", _REMAT_TOYS)
+def test_a_layer_under_remat_gives_the_loss_and_the_gradients_of_a_layer_that_keeps_everything(toy, monkeypatch):
+    """The flash kernels run (interpreted) in both programs; under remat the
+    backward pass reads q, k, v, the kernel's output and its log-sum-exp where
+    the forward pass left them and recomputes the rest of the layer: the same
+    float32 operations on the same values, so the loss and every leaf's gradient
+    are the ones ``remat=False`` gives."""
+    from functools import partial
+
+    from ray_tpu.models import transformer
+    from ray_tpu.ops import attention
+
+    monkeypatch.setattr(transformer, "flash_attention", partial(attention.flash_attention, interpret=True))
+    tokens = {"tokens": jax.random.randint(jax.random.PRNGKey(1), (2, 65), 0, 128)}
+    got = {}
+    for remat in (True, False):
+        cfg = tiny_cfg(remat=remat, **_REMAT_TOYS[toy])
+        params = init_params(jax.random.PRNGKey(0), cfg)
+        loss_and_grads = jax.value_and_grad(partial(loss_fn, cfg=cfg))
+        jaxpr = str(jax.make_jaxpr(loss_and_grads)(params, tokens))
+        # a layer of the scan's body: the forward kernel once and the two backward kernels, whatever it keeps
+        assert jaxpr.count("pallas_call[") == 3 * _scanned_layers(cfg) and ("remat" in jaxpr) == remat
+        got[remat] = jax.jit(loss_and_grads)(params, tokens)
+    (loss, grads), (want_loss, want_grads) = got[True], got[False]
+    assert abs(float(loss) - float(want_loss)) <= 1e-6
+    gaps = jax.tree.map(lambda a, b: float(jnp.linalg.norm(a - b) / jnp.maximum(jnp.linalg.norm(b), 1e-30)), grads, want_grads)
+    assert max(jax.tree.leaves(gaps)) <= 1e-6, gaps
+
+
+_LOWERED_TOYS = {
+    "dense": dict(),
+    "dense, a dp mesh of four": dict(),
+    "a pattern of two kinds": dict(layer_kinds=("window", "full") * 2, n_layers=4, sliding_window=128, qk_norm=True),
+}
+
+
+@pytest.mark.parametrize("toy", _LOWERED_TOYS)
+def test_the_backward_pass_of_a_layer_under_remat_runs_no_flash_forward_kernel(toy, monkeypatch):
+    """The mechanism's counter: the train step lowered for the TPU from here
+    holds ONE ``_flash_kernel`` call a layer of the scan's body (the forward
+    pass's; the backward scan has the two backward kernels only), under a
+    ``shard_map`` too, where a bare ``jax.checkpoint`` (the parent's, here the
+    policy taken away) has two."""
+    from jax.sharding import NamedSharding
+
+    from ray_tpu.models import transformer
+    from ray_tpu.ops import attention
+    from ray_tpu.parallel.mesh import single_axis_mesh
+
+    monkeypatch.setattr(attention, "_on_tpu", lambda: True)
+    cfg = tiny_cfg(vocab_size=512, d_model=256, n_heads=2, n_kv_heads=1, d_ff=256, max_seq_len=256, dtype=jnp.bfloat16, remat=True, **_LOWERED_TOYS[toy])
+    mesh = single_axis_mesh("dp", devices=jax.devices()[:4]) if "mesh" in toy else None
+    opt = optax.adamw(1e-4)
+    params = jax.eval_shape(lambda: init_params(jax.random.PRNGKey(0), cfg))
+    tokens = jax.ShapeDtypeStruct((4, 257), jnp.int32, sharding=NamedSharding(mesh, logical_to_spec(("batch", None))) if mesh else None)
+
+    def kernels():
+        step = jax.jit(make_train_step(cfg, opt, mesh=mesh))
+        text = step.trace(params, jax.eval_shape(opt.init, params), {"tokens": tokens}).lower(lowering_platforms=("tpu",)).as_text()
+        return tuple(text.count(f'kernel_name = "{name}"') for name in ("_flash_kernel", "_flash_bwd_dkv_kernel", "_flash_bwd_dq_kernel"))
+
+    body = _scanned_layers(cfg)
+    assert kernels() == (body, body, body)
+    monkeypatch.setattr(transformer, "_KEPT_UNDER_REMAT", None)
+    assert kernels() == (2 * body, body, body)
