@@ -9,6 +9,9 @@ import contextlib
 
 _lock = threading.Lock()
 _core_worker = None
+# CLOCK_MONOTONIC nanoseconds of the first line of ``worker_main.main`` (a
+# forked zygote child's too); 0 in a process that is no worker.
+T_PROCESS_NS = 0
 # Thread-local override: the client server executes driver work on behalf of
 # thin clients inside a process whose global slot may hold something else (or
 # nothing) — e.g. serialization registering deserialized ObjectRefs must bind

@@ -731,6 +731,7 @@ def _apply_runtime_env(raw: str | None):
 def main():
     import time as _time
 
+    t_process_ns = _time.monotonic_ns()
     _boot_t0 = _time.monotonic()
     _trace = os.environ.get("RAY_TPU_BOOT_TRACE")
 
@@ -765,6 +766,7 @@ def main():
     from ray_tpu._private.core_worker import WORKER, CoreWorker
     from ray_tpu._private.ids import JobID
 
+    worker_context.T_PROCESS_NS = t_process_ns
     _mark("imports")
     worker_env = os.environ.get("RAY_TPU_RUNTIME_ENV")
     cw = CoreWorker(

@@ -689,6 +689,8 @@ class ServeController:
                     "deployment_name": info.name,
                     "replica_id": replica_id,
                     "controller_name": CONTROLLER_NAME,
+                    # replica.py::SETUP_STAMPS: the first of a replica's start.
+                    "t_requested_ns": time.monotonic_ns(),
                 },
                 resource_request=ResourceRequest([bundle]),
                 actor_options=actor_options,

@@ -10,12 +10,15 @@ depth reported to the controller for autoscaling.
 from __future__ import annotations
 
 import contextlib
+import logging
 import pickle
 import queue as _queue
 import sys
 import threading
 import time
 import traceback
+
+logger = logging.getLogger(__name__)
 
 # A streamed response leaves by POLLS, and the unit of a poll is "one proxy's
 # streams on this replica", not one stream (``Replica.next_stream_chunks``):
@@ -38,6 +41,15 @@ import traceback
 # last batch never gets them. A response that gives
 # ``StreamingResponse.on_delivered`` is handed these, one tuple a chunk.
 CHUNK_STAMPS = ("t_yield_ns", "t_asked_ns", "t_enter_ns", "t_sweep_ns", "t_got_ns", "t_wrote_ns")
+
+# A replica's own way from the controller's decision to its first answer, on
+# the same clock, in the order taken (0 = not taken): the controller as it asks
+# the actor manager for the replica (it rides in the actor's keyword arguments);
+# the worker process's ``main`` on its first line; ``Replica.__init__`` on its
+# first line and on the line before it calls the deployment's class; and
+# ``check_health`` as it first answers. One replica a process: one dict.
+SETUP_STAMPS = {"t_requested_ns": 0, "t_process_ns": 0, "t_actor_ns": 0, "t_callable_ns": 0, "t_ready_ns": 0}
+
 
 # A poll that finds nothing waits this long for a chunk of any of its streams.
 _POLL_WAIT_S = 0.5
@@ -152,8 +164,15 @@ class Replica:
         deployment_name: str = "",
         replica_id: str = "",
         controller_name: str = "",
+        t_requested_ns: int = 0,
     ):
+        from ray_tpu._private import worker_context
         from ray_tpu.serve._private.common import HandleMarker
+
+        SETUP_STAMPS.update(
+            t_requested_ns=t_requested_ns, t_process_ns=worker_context.T_PROCESS_NS,
+            t_actor_ns=time.monotonic_ns(), t_callable_ns=0, t_ready_ns=0,
+        )
 
         cls_or_fn, init_args, init_kwargs = pickle.loads(import_spec)
 
@@ -174,6 +193,7 @@ class Replica:
         init_args = tuple(materialize(a) for a in init_args)
         init_kwargs = {k: materialize(v) for k, v in init_kwargs.items()}
         if isinstance(cls_or_fn, type):
+            SETUP_STAMPS["t_callable_ns"] = time.monotonic_ns()
             self._callable = cls_or_fn(*init_args, **init_kwargs)
         else:
             self._callable = cls_or_fn
@@ -558,6 +578,10 @@ class Replica:
         fn = getattr(self._callable, "check_health", None)
         if fn is not None:
             fn()
+        if not SETUP_STAMPS["t_ready_ns"]:
+            SETUP_STAMPS["t_ready_ns"] = time.monotonic_ns()
+            since = SETUP_STAMPS["t_requested_ns"] or SETUP_STAMPS["t_actor_ns"]
+            logger.info("setup: ready %.1f s after the controller asked", (SETUP_STAMPS["t_ready_ns"] - since) / 1e9)
         return True
 
     def prepare_for_shutdown(self):

@@ -37,7 +37,6 @@ from __future__ import annotations
 
 import collections
 import json
-import time
 
 from ray_tpu.serve._private.common import (  # noqa: F401
     PREFILL_SUFFIX,
@@ -55,32 +54,33 @@ class LLMDeployment:
         init_seed: int = 0,
         params=None,
     ):
-        # Seconds of each stage of the replica's start, beside the engine's
-        # own (get_stats()["spans"]["setup"]).
-        t0 = time.monotonic()
-        import jax
-        import jax.numpy as jnp
+        # The stages of the replica's start that come before the engine's own
+        # (get_stats()["spans"]["stages"], and "setup" computed from them).
+        from ray_tpu.serve.llm.stats import listen_for_compiles, listen_for_gc, stage
 
-        from ray_tpu.models.transformer import TransformerConfig, init_params
-        from ray_tpu.serve.llm.stats import listen_for_compiles
+        stages: list = []
+        with stage(stages, "jax_import"):
+            import jax
+            import jax.numpy as jnp
 
-        listen_for_compiles()  # before the first program: the draw's compiles count
-        t1 = time.monotonic()
-        jax.devices()
-        t2 = time.monotonic()
-        setup = {"jax_import_s": t1 - t0, "backend_s": t2 - t1}
+            from ray_tpu.models.transformer import TransformerConfig, init_params
+
+            listen_for_compiles()  # before the first program: the draw's compiles count
+            listen_for_gc()  # and its collections
+        with stage(stages, "backend"):
+            jax.devices()
         model_config = dict(model_config)
         for key in ("dtype", "param_dtype"):
             if isinstance(model_config.get(key), str):  # JSON-friendly configs
                 model_config[key] = jnp.dtype(model_config[key]).type
         self.cfg = TransformerConfig(**model_config)
         if params is None:
-            params = jax.block_until_ready(
-                init_params(jax.random.PRNGKey(init_seed), self.cfg)
-            )
-            setup["params_s"] = time.monotonic() - t2
+            with stage(stages, "params"):
+                params = jax.block_until_ready(
+                    init_params(jax.random.PRNGKey(init_seed), self.cfg)
+                )
         self.engine = LLMEngine(params, self.cfg, **(engine_config or {}))
-        self.engine.spans.setup.update(setup)
+        self.engine.spans.stages[:0] = stages
 
     def __call__(self, request):
         from ray_tpu.serve.api import StreamingResponse
@@ -212,11 +212,19 @@ class LLMDeployment:
     def get_stats(self) -> dict:
         """Engine snapshot plus the device this replica runs on and, under
         ``"spans"``, the engine's iteration, request, compile, delivery and
-        collector records (``stats.EngineSpans.export``) (handle-callable;
-        used by tests and benches)."""
+        collector records and the records of the replica's start, its own
+        stamps (``replica.SETUP_STAMPS``) among them
+        (``stats.EngineSpans.export``) (handle-callable; used by tests and
+        benches)."""
         from ray_tpu.util.device_report import device_report
 
+        from ray_tpu.serve._private.replica import SETUP_STAMPS
+        from ray_tpu.serve.llm.stats import FOREIGN_STAMP_S
+
         ring = self.engine.spans.deliveries
+        stamps = dict(SETUP_STAMPS)
+        if abs(stamps["t_requested_ns"] - stamps["t_actor_ns"]) > FOREIGN_STAMP_S * 1e9:
+            stamps["t_requested_ns"] = 0  # a controller on another host: not this clock
         return {
             **self.engine.stats(),
             # The polls that carried a token of this engine's, and the tokens
@@ -227,7 +235,7 @@ class LLMDeployment:
             "stream_poll_chunks": ring.n,
             "stream_poll_streams": ring.batches,
             "device": device_report(),
-            "spans": self.engine.spans.export(),
+            "spans": {**self.engine.spans.export(), "setup_stamps": stamps},
         }
 
     def check_health(self):

@@ -92,7 +92,9 @@ import numpy as np
 
 from ray_tpu._private import flight_recorder as _flight
 from ray_tpu._private.concurrency import any_thread, blocking
-from ray_tpu.serve.llm.stats import ENGINES, LLM, EngineSpans, listen_for_compiles, listen_for_gc
+from ray_tpu.serve.llm.stats import (
+    ENGINES, LLM, EngineSpans, busiest_threads, listen_for_compiles, listen_for_gc, stage, thread_cpu_ns,
+)
 from ray_tpu.util import tracing
 
 
@@ -304,6 +306,24 @@ _MIN_VIEW_BLOCKS = 16
 # fill no more than one: that is where the chip showed the chunk's rows to
 # ride for nothing on the step's read of the weights (PERF.md, PR 39 and 40).
 _MXU_TILE_ROWS = 128
+
+
+def _with_room(fn):
+    """``fn()``, called from a frame so large that the interpreter gives it a
+    chunk of its thread's data stack to itself, with ~190 KB left in it for
+    every frame under it. CPython 3.11+ keeps a thread's frames in chunks of
+    16 KB and FREES a chunk as its first frame returns: a call that happens to
+    start a chunk maps and unmaps it every time it is made, ~8 us where a call
+    takes 0.1. Where the boundary falls follows the size of every frame below,
+    so tracing and lowering, which make millions of calls 50-150 frames deep,
+    ran up to twice as long in a replica as alone, and seconds longer or
+    shorter with a key more in a dict literal of ``__init__`` (PERF.md, PR 49
+    and PR 52). No boundary is in reach of a build that starts from here."""
+    return fn()
+
+
+# 40,000 words of evaluation stack the function never uses: a 320 KB frame, so a 512 KB chunk.
+_with_room.__code__ = _with_room.__code__.replace(co_stacksize=40_000)
 
 
 def _view_rungs(n_max: int) -> tuple:
@@ -626,32 +646,31 @@ class LLMEngine:
             self.num_slots, self.ring_blocks
         )
         self.params = params
-        t0 = time.monotonic()
-        pool = init_paged_cache(
-            cfg, self.num_blocks, self.block_size, self.num_window_blocks,
-            state_slots=self.num_slots if "state" in reach else 0,
-        )
-        # Bytes one token holds in each group of pool leaves, all its layers,
-        # and in the pool at large.
-        self._group_token_bytes = cache_token_bytes(cfg)
-        self.kv_token_bytes = sum(self._group_token_bytes.values())
-        if cfg.routed_experts:
-            # Expert counters ride the pool through both programs, donated
-            # with it and updated on the device; ``stats()`` reads them.
-            pool[MOE_COUNTS] = init_moe_counts(cfg)
-            # And beside each cached token the experts it took (one word a
-            # token a layer), for ``submit(return_routed_experts=True)``.
-            pool[MOE_CHOICE] = init_moe_choice(cfg, self.num_blocks, self.block_size)
-        self._cache = jax.block_until_ready(pool)
-        self._fuses = self._shape_fuses()
-        self._moe_wanted: Optional[threading.Event] = None
-        self._moe_asking = threading.Lock()  # one asker at a time
-        # The counters as last read, a NumPy array. Read once here, which
-        # builds the copy's program before the replica is ready: the first
-        # ``stats()`` of a serving engine compiles nothing inside a stream.
-        self._moe_read = np.asarray(self._copy_moe_counts()) if cfg.routed_experts else None
-        self._moe_folded = (0, 0)  # assignments (held, all) of it that ``LLM`` has
-        self.spans.setup["pool_s"] = time.monotonic() - t0
+        with stage(self.spans.stages, "pool"):
+            pool = init_paged_cache(
+                cfg, self.num_blocks, self.block_size, self.num_window_blocks,
+                state_slots=self.num_slots if "state" in reach else 0,
+            )
+            # Bytes one token holds in each group of pool leaves, all its layers,
+            # and in the pool at large.
+            self._group_token_bytes = cache_token_bytes(cfg)
+            self.kv_token_bytes = sum(self._group_token_bytes.values())
+            if cfg.routed_experts:
+                # Expert counters ride the pool through both programs, donated
+                # with it and updated on the device; ``stats()`` reads them.
+                pool[MOE_COUNTS] = init_moe_counts(cfg)
+                # And beside each cached token the experts it took (one word a
+                # token a layer), for ``submit(return_routed_experts=True)``.
+                pool[MOE_CHOICE] = init_moe_choice(cfg, self.num_blocks, self.block_size)
+            self._cache = jax.block_until_ready(pool)
+            self._fuses = self._shape_fuses()
+            self._moe_wanted: Optional[threading.Event] = None
+            self._moe_asking = threading.Lock()  # one asker at a time
+            # The counters as last read, a NumPy array. Read once here, which
+            # builds the copy's program before the replica is ready: the first
+            # ``stats()`` of a serving engine compiles nothing inside a stream.
+            self._moe_read = np.asarray(self._copy_moe_counts()) if cfg.routed_experts else None
+            self._moe_folded = (0, 0)  # assignments (held, all) of it that ``LLM`` has
         # Block 0 is the reserved null block — never handed out.
         self._free: list[int] = list(range(self.num_blocks - 1, 0, -1))
         self._prefix: dict[bytes, _PrefixEntry] = {}
@@ -720,15 +739,15 @@ class LLMEngine:
         }
         # The decode step in flight: dispatched, its ids not fetched.
         self._inflight: Optional[_Step] = None
-        t0 = time.monotonic()
-        self._decode_fn, self._prefill_fn, self._fused_fn = _compiled_fns(cfg, self.ring_blocks)
-        self.spans.setup["jit_build_s"] = time.monotonic() - t0
-        self._build_programs()
+        with stage(self.spans.stages, "jit_build"):
+            self._decode_fn, self._prefill_fn, self._fused_fn = _compiled_fns(cfg, self.ring_blocks)
+        _with_room(self._build_programs)
         # Prefill passes whose routed experts' matmuls run the grouped kernel whose row tile fits a group: the
         # prefill program's own predicate (``generate.experts_run``) asked at the chunk's rows, all of them or none.
-        # BEHIND the build, the counter too: asked beside ``_chunk_reads_in_place`` with the key in the literal
-        # above, a Trinity replica built its seven unchanged decode programs in 15.3 s for 12.5, so 13.2 (v5e, PR 49;
-        # not explained: PERF.md section 7, "Left by PR 49").
+        # BEHIND the build, the counter too, since PR 49: asked beside ``_chunk_reads_in_place`` with the key in the
+        # literal above, a Trinity replica built its seven unchanged decode programs in 15.3 s for 12.5 (v5e). What
+        # it moved was this frame's size, and with it where the build's calls met a boundary of the data stack
+        # (``_with_room``, PR 52); from there the build takes 9.4 s wherever these statements stand.
         from ray_tpu.models.generate import experts_run
 
         self._chunk_experts_in_kernel = experts_run(cfg, self.prefill_chunk) == "kernel"
@@ -1703,14 +1722,17 @@ class LLMEngine:
         1.6), and one that adds none keeps the start it had, its prefill
         program built by its first request: a cold five-layer expert replica
         has 17 s of the 90 Serve gives it to spare (PERF.md, PR 35 and 40).
-        Stamps ``fused_build_s``, the trace and lowering of the step with a
-        chunk, and ``decode_build_s``, all the rest of it."""
+        One stage record a program and stage (``stats.stage``: ``trace``,
+        ``lower``, ``compile`` on the pool thread that ran it, ``first_run``),
+        from which ``fused_build_s``, the trace and lowering of the step with a
+        chunk, and ``decode_build_s``, all the rest of it, are computed
+        (``stats.setup_seconds``); and the threads that burned most meanwhile."""
         from concurrent.futures import ThreadPoolExecutor
 
         import jax
         import jax.numpy as jnp
 
-        t0 = time.monotonic()
+        stages, threads0 = self.spans.stages, thread_cpu_ns()
         ids = jnp.zeros((self.num_slots,), jnp.int32)
         chunk = jnp.zeros((1, self.prefill_chunk), jnp.int32)
         # Traced, lowered and compiled apart from the call, and all three
@@ -1724,29 +1746,38 @@ class LLMEngine:
         # compiler runs outside the GIL): cold, a rung of a five-layer expert
         # model takes it 8 s, seven of them in a row more than Serve gives a
         # replica to become ready (PERF.md, PR 35).
-        calls = [(self._decode_fn, jnp.asarray(self._program_rows(self.num_slots, w)), ids) for w in self._view_rungs]
-        fused_from = len(calls) + 1
+        calls = [
+            (f"decode@{w}", self._decode_fn, jnp.asarray(self._program_rows(self.num_slots, w)), ids)
+            for w in self._view_rungs
+        ]
         if self._fuses:
             whole = [jnp.asarray(self._program_rows(n, self.n_max)) for n in (1, self.num_slots)]
-            calls += [(self._prefill_fn, chunk, whole[0]), (self._fused_fn, whole[1], ids, chunk, whole[0])]
-        lowered, fused_s = [], 0.0
-        for i, (fn, *args) in enumerate(calls):
-            t = time.monotonic()
-            traced = fn.trace(self.params, args[0], self._cache, *args[1:])
-            lowered.append((traced, traced.lower()))
-            fused_s += time.monotonic() - t if i >= fused_from else 0.0
-        with ThreadPoolExecutor(max_workers=len(calls)) as pool:
-            held = lowered, list(pool.map(lambda pair: pair[1].compile(), lowered))
-        for fn, *args in calls:
-            drawn = jax.block_until_ready(self._run_donated(fn, *args))
+            calls += [
+                ("prefill", self._prefill_fn, chunk, whole[0]),
+                ("decode_with_chunk", self._fused_fn, whole[1], ids, chunk, whole[0]),
+            ]
+        lowered = []
+        for name, fn, *args in calls:
+            with stage(stages, "trace", name):
+                traced = fn.trace(self.params, args[0], self._cache, *args[1:])
+            with stage(stages, "lower", name):
+                lowered.append((name, traced, traced.lower()))
+
+        def compile_(program):
+            with stage(stages, "compile", program[0]):
+                return program[2].compile()
+
+        with ThreadPoolExecutor(max_workers=len(calls), thread_name_prefix="llm-compile") as pool:
+            held = lowered, list(pool.map(compile_, lowered))
+        for name, fn, *args in calls:
+            with stage(stages, "first_run", name):
+                drawn = jax.block_until_ready(self._run_donated(fn, *args))
             ids = drawn if fn is not self._prefill_fn else ids
         del held
         # What a step with no step before it is given as ``ids`` (none of its
         # rows reads them): a program's own output, like every other step's.
         self._no_ids = ids
-        self.spans.setup["decode_build_s"] = time.monotonic() - t0 - fused_s
-        if self._fuses:
-            self.spans.setup["fused_build_s"] = fused_s
+        self.spans.build_threads = busiest_threads(threads0, thread_cpu_ns())
 
     def _register_prefix_blocks(self, req: LLMRequest):
         """Publish freshly-WRITTEN full prompt blocks for reuse. Done as

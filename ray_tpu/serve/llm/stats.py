@@ -35,17 +35,38 @@ at once and stamps ``t_enter_ns`` once, so the records that share a
 on the record too: one ``gc.callbacks`` hook a process (``listen_for_gc``)
 keeps the generation-2 collections in a ring and the younger ones as plain
 ints. Both leave through ``get_stats()["spans"]`` only.
+
+A replica's START (ISSUE 52) is on the record too, written once: one
+``STAGE_FIELDS`` record a stage (``stage``: wall, the thread's and the
+process's CPU time, the collector's share), from the deployment's first line
+through every program ``LLMEngine._build_programs`` traces, lowers, compiles
+and first runs; the seven seconds of ``setup`` are computed from them
+(``setup_seconds``). Compilations are split three ways by what the installed
+jax (0.9) raises on the compiling thread: a persistent-cache HIT raises
+``cache_retrieval`` and then, around it, ``backend_compile`` with the read's
+duration; a compile that is WRITTEN to the cache (a miss of an entry the next
+start reads) raises the plain event ``cache_misses`` and then
+``backend_compile``; a program the cache never holds (compiled in less than
+``jax_persistent_cache_min_compile_time_secs``, or no cache) raises
+``backend_compile`` alone. The ring's ``afresh`` column is 1 for the second
+kind, and ``COMPILE_TOTALS`` counts all of it in plain ints no ring overflows.
 """
 
 from __future__ import annotations
 
 import array
 import collections
+import contextlib
 import gc
 import itertools
+import logging
+import os
+import sys
 import threading
 import time
 import weakref
+
+logger = logging.getLogger(__name__)
 
 # Engines register here at construction; the scheduler loop's exit (stop or
 # crash) withdraws them. WeakSet so an abandoned engine can't pin itself.
@@ -83,7 +104,20 @@ REQUEST_FIELDS = (
     "t_recv_ns", "t_submit_ns", "t_admit_ns", "t_first_ns", "t_done_ns",
     "prompt_tokens", "cached_tokens", "generated", "preemptions", "outcome",
 )
-COMPILE_FIELDS = ("t_end_ns", "duration_ns", "event", "program")
+# ``afresh``: 1 on a ``backend_compile`` record whose program was compiled and
+# written to the persistent cache, an entry a warm start would have read.
+COMPILE_FIELDS = ("t_end_ns", "duration_ns", "event", "program", "afresh")
+# One record per stage of a replica's start (``stage``): the stage's and, for a
+# stage of one program's build, the program's name; the name of the thread that
+# ran it; its two ends on CLOCK_MONOTONIC; the CPU time of that thread and of
+# the whole process between them (wall less ``cpu_ns``: the thread did not
+# run; ``process_cpu_ns`` less ``cpu_ns``: what the other threads burned
+# meanwhile); the collector's nanoseconds (all generations) and its
+# generation-2 collections inside the stage, process-wide.
+STAGE_FIELDS = (
+    "stage", "program", "thread", "t_start_ns", "t_end_ns", "cpu_ns", "process_cpu_ns", "gc_ns", "gc2",
+)
+_S_STAGE, _S_PROGRAM, _S_START, _S_END = (STAGE_FIELDS.index(f) for f in ("stage", "program", "t_start_ns", "t_end_ns"))
 # One record per streamed token, all ints: the number N of the request ring's
 # ``rid`` "llm-N", the token's index among those the request streamed, then
 # seven CLOCK_MONOTONIC stamps in the order they are taken on a token's way
@@ -183,25 +217,48 @@ _COMPILE_EVENTS = {
     "/jax/core/compile/backend_compile_duration": "backend_compile",
     "/jax/compilation_cache/cache_retrieval_time_sec": "cache_retrieval",
 }
+_CACHE_WRITE_EVENT = "/jax/compilation_cache/cache_misses"
 COMPILES = Ring(COMPILE_RING)
+# [count, nanoseconds] of each event since the listener's start, and of the
+# ``backend_compile`` events that were ``afresh``: what the ring drops, these keep.
+COMPILE_TOTALS = {"backend_compile": [0, 0], "cache_retrieval": [0, 0], "compiled_afresh": [0, 0]}
 _compile_lock = threading.Lock()  # any thread may compile
+_cache_writes: set = set()  # threads whose compile in flight was written to the persistent cache
 _listening = False
+
+
+def _on_cache_write(event: str, **kwargs):
+    if event == _CACHE_WRITE_EVENT:
+        with _compile_lock:
+            _cache_writes.add(threading.get_ident())
 
 
 def _on_compile_event(event: str, duration_secs: float, **kwargs):
     kind = _COMPILE_EVENTS.get(event)
     if kind is not None:
-        rec = (time.monotonic_ns(), int(duration_secs * 1e9), kind, str(kwargs.get("fun_name", "")))
+        ns = int(duration_secs * 1e9)
         with _compile_lock:
-            COMPILES.push(rec)
+            # The write's event comes on the compiling thread, inside the compile's.
+            afresh = int(kind == "backend_compile" and threading.get_ident() in _cache_writes)
+            if kind == "backend_compile":
+                _cache_writes.discard(threading.get_ident())
+            COMPILES.push((time.monotonic_ns(), ns, kind, str(kwargs.get("fun_name", "")), afresh))
+            for total in (kind,) + (("compiled_afresh",) if afresh else ()):
+                COMPILE_TOTALS[total][0] += 1
+                COMPILE_TOTALS[total][1] += ns
 
 
 def listen_for_compiles():
     """Once per process, before its first program is built: every program
     the backend builds (``backend_compile``; a persistent-cache hit raises it
-    too, with the read's duration) and every read of the persistent cache
-    (``cache_retrieval``), stamped as it ends. The counter behind "which step
-    recompiled" and "compilations inside the window: there should be none"."""
+    too, around the read and with the read's duration), every read of the
+    persistent cache (``cache_retrieval``, raised before the hit's
+    ``backend_compile`` on the same thread) and, as ``afresh`` on its
+    ``backend_compile`` record, every write to it (the plain event
+    ``cache_misses``: jax 0.9 raises it only where it writes the entry),
+    stamped as it ends. The counter behind "which step recompiled",
+    "compilations inside the window: there should be none" and "was this
+    warm start warm"."""
     global _listening
     with _compile_lock:
         if _listening:
@@ -210,11 +267,17 @@ def listen_for_compiles():
     import jax
 
     jax.monitoring.register_event_duration_secs_listener(_on_compile_event)
+    jax.monitoring.register_event_listener(_on_cache_write)
 
 
 def compile_records() -> list:
     with _compile_lock:
         return [list(r) for r in COMPILES.since()]
+
+
+def compile_totals() -> dict:
+    with _compile_lock:
+        return {k: list(v) for k, v in COMPILE_TOTALS.items()}
 
 
 # -- collector pauses: the hook is the process's, like the collector. A
@@ -224,6 +287,7 @@ def compile_records() -> list:
 GC_PAUSES = Ring(GC_RING)  # generation 2, GC_FIELDS
 # Generations 0 and 1, from the hook's installation: how many, and their nanoseconds.
 GC_YOUNGER = {"collections": [0, 0], "ns": [0, 0]}
+_GC2 = [0, 0]  # generation 2 likewise: what the ring holds record by record, as two plain ints
 _gc_annotation = None  # jax.profiler.TraceAnnotation once the hook is installed
 _gc_open = None  # (t_start_ns, annotation or None) of the collection that is running
 
@@ -245,6 +309,8 @@ def _on_gc(phase: str, info: dict):
         if ann is not None:
             ann.__exit__(None, None, None)
             GC_PAUSES.push((t0, ns, info["collected"]))
+            _GC2[0] += 1
+            _GC2[1] += ns
         else:
             GC_YOUNGER["collections"][info["generation"]] += 1
             GC_YOUNGER["ns"][info["generation"]] += ns
@@ -267,6 +333,89 @@ def listen_for_gc():
 
 def gc_records() -> list:
     return [list(r) for r in GC_PAUSES.since()]
+
+
+# -- a replica's start: one record a stage, written once ----------------------
+
+
+def _gc_ns() -> int:
+    return GC_YOUNGER["ns"][0] + GC_YOUNGER["ns"][1] + _GC2[1]
+
+
+@contextlib.contextmanager
+def stage(records: list, name: str, program: str = ""):
+    """One stage of a start: appends its ``STAGE_FIELDS`` record to ``records``
+    as it ends, whichever way, and says so in the log, so that a replica
+    refused at Serve's limit leaves word of where it was. Under a
+    ``TraceAnnotation`` where jax is loaded, as ``EngineSpans.span`` is: a
+    profile taken over a start shows the stages beside the device's programs.
+    For ``__init__``s and ``_build_programs``: nothing a token passes."""
+    jax = sys.modules.get("jax")
+    label = f"setup.{name} {program}" if program else f"setup.{name}"
+    gc0, gc20 = _gc_ns(), _GC2[0]
+    process0, cpu0, t0 = time.process_time_ns(), time.thread_time_ns(), time.monotonic_ns()
+    try:
+        with jax.profiler.TraceAnnotation(label) if jax is not None else contextlib.nullcontext():
+            yield
+    finally:
+        t1, cpu, process = time.monotonic_ns(), time.thread_time_ns() - cpu0, time.process_time_ns() - process0
+        records.append(
+            (name, program, threading.current_thread().name, t0, t1, cpu, process, _gc_ns() - gc0, _GC2[0] - gc20)
+        )
+        logger.info("setup: %s %.1f s (cpu %.1f)", label[len("setup."):], (t1 - t0) / 1e9, cpu / 1e9)
+
+
+def setup_seconds(stages: list) -> dict:
+    """The seconds ``get_stats()["spans"]["setup"]`` has carried since PR 40,
+    from the stage records: each ``__init__`` stage's wall under its own name;
+    ``fused_build_s``, the trace and lowering of the step with a chunk;
+    ``decode_build_s``, all the rest of the programs' build (its first start to
+    its last end, less that)."""
+    setup, built, fused_ns = {}, [], 0
+    for rec in stages:
+        if not rec[_S_PROGRAM]:
+            setup[rec[_S_STAGE] + "_s"] = (rec[_S_END] - rec[_S_START]) / 1e9
+            continue
+        built.append(rec)
+        if rec[_S_PROGRAM] == "decode_with_chunk" and rec[_S_STAGE] in ("trace", "lower"):
+            fused_ns += rec[_S_END] - rec[_S_START]
+    if built:
+        wall_ns = max(rec[_S_END] for rec in built) - min(rec[_S_START] for rec in built)
+        setup["decode_build_s"] = (wall_ns - fused_ns) / 1e9
+    if fused_ns:
+        setup["fused_build_s"] = fused_ns / 1e9
+    return setup
+
+
+def thread_cpu_ns() -> dict:
+    """CPU nanoseconds of every thread of this process, ``{tid: (name,
+    ns)}``: user and system time of ``/proc/self/task/<tid>/stat`` (in clock
+    ticks: 10 ms) under the Python thread's name, or the kernel's (``comm``)
+    for a thread Python did not start. Linux only; empty elsewhere."""
+    try:
+        tids = os.listdir("/proc/self/task")
+        tick_ns = 10**9 // os.sysconf("SC_CLK_TCK")
+    except (OSError, ValueError, AttributeError):
+        return {}
+    named = {t.native_id: t.name for t in threading.enumerate()}
+    found = {}
+    for tid in tids:
+        try:
+            with open(f"/proc/self/task/{tid}/stat") as f:
+                # "tid (comm) state ...": comm may hold spaces and brackets; utime and stime are fields 14 and 15
+                comm, _, rest = f.read().partition("(")[2].rpartition(")")
+        except OSError:
+            continue  # gone meanwhile
+        fields = rest.split()
+        found[int(tid)] = (named.get(int(tid), comm), (int(fields[11]) + int(fields[12])) * tick_ns)
+    return found
+
+
+def busiest_threads(before: dict, after: dict, n: int = 5) -> list:
+    """``[name, cpu_ns]`` of the ``n`` threads that burned most between two
+    ``thread_cpu_ns()``: which thread a build waited for."""
+    burned = [[name, ns - before.get(tid, ("", 0))[1]] for tid, (name, ns) in after.items()]
+    return sorted((b for b in burned if b[1] > 0), key=lambda b: -b[1])[:n]
 
 
 # -- deliveries: one record a streamed token ----------------------------------
@@ -382,8 +531,16 @@ class EngineSpans:
         self.span_ns = [0] * len(SPAN_NAMES)
         self.kinds = [0] * len(ITERATION_KINDS)
         self.requests_folded = 0  # the /metrics collector's cursor into ``requests``
-        self.setup: dict = {}  # seconds of each stage of building the engine
+        # The start, written once: STAGE_FIELDS records in order of their ends (the deployment puts its own
+        # ahead), and the threads that burned most while the programs were built.
+        self.stages: list = []
+        self.build_threads: list = []
         RECORDERS.add(self)
+
+    @property
+    def setup(self) -> dict:
+        """Seconds of each stage of building the replica and its engine (``setup_seconds``)."""
+        return setup_seconds(self.stages)
 
     # -- one iteration (scheduler thread) -------------------------------
 
@@ -480,12 +637,16 @@ class EngineSpans:
             "deliveries": self.deliveries.export(),
             "gc": gc_records(),
             "gc_younger": {k: list(v) for k, v in GC_YOUNGER.items()},
-            "setup": dict(self.setup),
+            "setup": self.setup,
+            "stages": [list(r) for r in self.stages],
+            "build_threads": [list(t) for t in self.build_threads],
+            "compile_totals": compile_totals(),
             "fields": {
                 "iterations": list(ITERATION_FIELDS),
                 "requests": list(REQUEST_FIELDS),
                 "compiles": list(COMPILE_FIELDS),
                 "deliveries": list(DELIVERY_FIELDS),
                 "gc": list(GC_FIELDS),
+                "stages": list(STAGE_FIELDS),
             },
         }

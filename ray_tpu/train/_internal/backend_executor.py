@@ -38,6 +38,18 @@ from ray_tpu.train._internal.worker_group import TrainWorker, WorkerGroup
 
 logger = logging.getLogger(__name__)
 
+# The key under which every report's metrics carry the stamps of the gang's
+# start (CLOCK_MONOTONIC nanoseconds, 0 = not taken), filled in here the way
+# Ray fills ``pid`` and ``hostname`` into a report: ``t_fit_ns``, the entry of
+# the trainer's ``fit()`` in the driver; and the reporting worker's own
+# ``t_worker_ns`` (``TrainWorker.__init__``), ``t_mesh_ns`` (its mesh is up: the
+# jax import and the backend's start over its chips lie before it) and
+# ``t_loop_ns`` (the first line of the thread that runs the user's loop).
+TRAINER_START = "_trainer_start"
+# Further than this from the worker's own stamps, ``t_fit_ns`` is another
+# host's clock and is dropped (``serve.llm.stats.FOREIGN_STAMP_S`` draws the same line).
+_FOREIGN_STAMP_NS = 600 * 10**9
+
 
 class Backend:
     """Backend plugin protocol (reference: train/_internal/backend.py)."""
@@ -80,10 +92,12 @@ class BackendExecutor:
         backend: Backend,
         scaling_config: ScalingConfig,
         max_failures: int = 0,
+        t_fit_ns: int = 0,
     ):
         self.backend = backend
         self.scaling_config = scaling_config
         self.max_failures = max_failures
+        self.t_fit_ns = t_fit_ns
         self.worker_group: WorkerGroup | None = None
         # TPU gangs need atomic co-reservation (one ICI domain); CPU gangs
         # get budget bookkeeping with raylet enforcement.
@@ -198,7 +212,10 @@ class BackendExecutor:
             except ray_tpu.exceptions.RayTpuError as e:
                 raise _WorkerGroupError(str(e), latest_checkpoint) from None
             for rank, p in enumerate(polls):
+                start = p["start"]
+                near = abs(self.t_fit_ns - start["t_worker_ns"]) <= _FOREIGN_STAMP_NS
                 for metrics, ckpt_blob in p["reports"]:
+                    metrics[TRAINER_START] = {"t_fit_ns": self.t_fit_ns if near else 0, **start}
                     final_reports[rank] = metrics
                     ckpt = Checkpoint.from_bytes(ckpt_blob) if ckpt_blob else None
                     if rank == 0 and ckpt is not None:

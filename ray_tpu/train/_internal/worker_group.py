@@ -8,11 +8,15 @@ of them, polls session reports.
 
 from __future__ import annotations
 
+import logging
 import queue
 import threading
+import time
 
 import ray_tpu
 from ray_tpu.air import session as air_session
+
+logger = logging.getLogger(__name__)
 
 
 @ray_tpu.remote
@@ -23,6 +27,8 @@ class TrainWorker:
     def __init__(self, rank: int, world_size: int, env: dict | None = None):
         import os
 
+        # The worker's three of ``backend_executor.TRAINER_START``; ``poll`` hands them back.
+        self._start = {"t_worker_ns": time.monotonic_ns(), "t_mesh_ns": 0, "t_loop_ns": 0}
         self.rank = rank
         self.world_size = world_size
         for k, v in (env or {}).items():
@@ -38,6 +44,7 @@ class TrainWorker:
 
         group = col.init_collective_group(world, rank, backend=backend, group_name=group_name)
         self._mesh = getattr(group, "mesh", None)
+        self._mesh_is_up()
         return rank
 
     def build_local_mesh(self):
@@ -45,7 +52,12 @@ class TrainWorker:
         from ray_tpu.parallel.mesh import single_axis_mesh
 
         self._mesh = single_axis_mesh("dp")
+        self._mesh_is_up()
         return True
+
+    def _mesh_is_up(self):
+        self._start["t_mesh_ns"] = time.monotonic_ns()
+        logger.info("setup: backend %.1f s", (self._start["t_mesh_ns"] - self._start["t_worker_ns"]) / 1e9)
 
     def run_train_fn(self, fn, config, dataset_shards=None, checkpoint=None):
         """Start the user loop in a thread; returns immediately."""
@@ -61,6 +73,9 @@ class TrainWorker:
         )
 
         def runner():
+            self._start["t_loop_ns"] = time.monotonic_ns()
+            logger.info("setup: loop %.1f s after the worker's first line",
+                        (self._start["t_loop_ns"] - self._start["t_worker_ns"]) / 1e9)
             air_session._set_context(ctx)
             try:
                 fn(config) if _wants_config(fn) else fn()
@@ -78,7 +93,7 @@ class TrainWorker:
         return True
 
     def poll(self):
-        """Drain queued reports; returns (reports, done, error)."""
+        """Drain queued reports; returns them with ``done``, ``error`` and the stamps of the worker's start."""
         reports = []
         while True:
             try:
@@ -87,7 +102,7 @@ class TrainWorker:
                 reports.append((metrics, blob))
             except queue.Empty:
                 break
-        return {"reports": reports, "done": self._done, "error": self._error}
+        return {"reports": reports, "done": self._done, "error": self._error, "start": dict(self._start)}
 
     def execute(self, fn, *args, **kwargs):
         """Run an arbitrary function in the worker (reference: execute)."""

@@ -39,6 +39,7 @@ class BaseTrainer:
         self.run_config = run_config or RunConfig()
         self.resume_from_checkpoint = resume_from_checkpoint
         self.datasets = datasets or {}
+        self._t_fit_ns = 0  # CLOCK_MONOTONIC of fit()'s entry: the first stamp of ``_trainer_start``
 
     def _run_dir(self) -> str:
         return self.run_config.resolve_dir(type(self).__name__)
@@ -49,6 +50,7 @@ class BaseTrainer:
     def fit(self) -> Result:
         """Run to completion (reference routes this through a 1-trial Tune
         experiment — tune.Tuner(trainer).fit() does the same here)."""
+        self._t_fit_ns = time.monotonic_ns()
         return self._fit_direct()
 
     def _fit_direct(self) -> Result:

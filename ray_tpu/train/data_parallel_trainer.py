@@ -62,6 +62,7 @@ class DataParallelTrainer(BaseTrainer):
             self.backend,
             self.scaling_config,
             max_failures=self.run_config.failure_config.max_failures,
+            t_fit_ns=self._t_fit_ns,
         )
         executor.start()
         last_metrics: dict = {}

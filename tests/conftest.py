@@ -131,6 +131,31 @@ _WRONG_SINCE_A_SECOND_ARCHITECTURE = {  # the first four (PR 32)
         "test_the_new_entries_are_appended_behind_what_was_there holds PR 43's two behind Olmo-Hybrid's four, PR 47's "
         "two behind those and every list's order, without a pin on the END"
     ),
+    # And since PR 52 appends the eight metrics of a start, six to every serving cell's list and two to every training cell's:
+    "test_bench_span_metrics.py::test_a_traced_line_carries_them_and_a_program_without_spans_leaves_them_out[serve16.batch-decode]": (
+        "holds a line built from PR 24's recording of get_stats() to carry EVERY metric declared for the cell; that "
+        "recording is a start without stage records, and PR 52's six readers find nothing in it. test_bench_setup_stages.py::"
+        "test_a_traced_line_carries_the_span_metrics_and_a_start_without_records_leaves_the_six_out holds the rest of it"
+    ),
+    "test_bench_span_metrics.py::test_a_traced_line_carries_them_and_a_program_without_spans_leaves_them_out[serve16.chat-open]": (
+        "as its other case: PR 24's recording holds no record of PR 52's. test_bench_setup_stages.py::"
+        "test_a_traced_line_carries_the_span_metrics_and_a_start_without_records_leaves_the_six_out stands in"
+    ),
+    "test_bench_glm.py::test_the_span_metrics_stand_and_new_cells_are_only_appended": (
+        "counts 24 and 18 metrics on the traced lines of the two first cells; PR 52 appends six to each. "
+        "test_bench_setup_stages.py::test_the_span_metrics_stand_where_they_were_and_a_cells_count_is_its_lists holds "
+        "the twelve where they were and each cell's count to the lists that name it"
+    ),
+    "test_bench_mellum.py::test_the_job_is_the_issues_and_nothing_but_files_and_appended_entries_came": (
+        "holds the SET of Mellum's per-layer metrics to PR 50's five; PR 52 appends the two of a trainer's start. "
+        "test_bench_setup_stages.py::test_the_training_cells_report_what_they_did_and_the_two_of_their_start holds the rest "
+        "of it, and the two behind the five"
+    ),
+    "test_bench_xing.py::test_the_new_entries_are_appended_behind_what_was_there": (
+        "holds the SET of Xing's traced metrics to what PR 47 left; PR 52 appends the six of a replica's start. "
+        "test_bench_setup_stages.py::test_xings_cell_reports_what_it_did_and_the_six_of_its_start holds the rest of it, "
+        "and the six behind everything else"
+    ),
 }
 
 

@@ -15,6 +15,7 @@ import urllib.request
 import numpy as np
 import pytest
 
+from ray_tpu.serve._private.replica import SETUP_STAMPS
 from ray_tpu.serve.llm import stats
 
 MODEL = dict(
@@ -499,7 +500,17 @@ def test_get_stats_carries_the_records_the_setup_and_the_proxys_stamps():
     finally:
         dep.prepare_for_shutdown()
     spans = got["spans"]
-    assert set(spans) == {"iterations", "requests", "compiles", "deliveries", "gc", "gc_younger", "setup", "fields"}
+    assert set(spans) == {
+        "iterations", "requests", "compiles", "deliveries", "gc", "gc_younger", "setup", "fields",
+        "stages", "setup_stamps", "compile_totals", "build_threads",
+    }
+    assert spans["fields"]["stages"] == list(stats.STAGE_FIELDS)
+    assert [r[0] for r in spans["stages"][:5]] == ["jax_import", "backend", "params", "pool", "jit_build"]
+    assert spans["setup_stamps"] == SETUP_STAMPS  # a copy of this process's: no replica wraps this deployment
+    assert set(spans["compile_totals"]) == {"backend_compile", "cache_retrieval", "compiled_afresh"}
+    assert spans["compile_totals"]["backend_compile"][0] >= len(spans["compiles"]) > 0
+    assert all(isinstance(name, str) and ns > 0 for name, ns in spans["build_threads"])
+    assert len(spans["build_threads"]) <= 5
     assert set(spans["setup"]) == {
         "jax_import_s", "backend_s", "params_s", "pool_s", "jit_build_s", "decode_build_s", "fused_build_s",
     }
@@ -513,6 +524,281 @@ def test_get_stats_carries_the_records_the_setup_and_the_proxys_stamps():
     assert spans["deliveries"] == b""  # nothing was streamed
     json.dumps({ring: recs for ring, recs in spans.items() if ring != "deliveries"})
     assert got["iterations"] == dep.engine.stats()["iterations"]
+
+
+# ---------------------------------------------------------------------------
+# a start, stage by stage and program by program (ISSUE 52)
+# ---------------------------------------------------------------------------
+
+STAGE = {name: i for i, name in enumerate(stats.STAGE_FIELDS)}
+BUILD_STAGES = ("trace", "lower", "compile", "first_run")
+
+
+def _wall(rec):
+    return rec[STAGE["t_end_ns"]] - rec[STAGE["t_start_ns"]]
+
+
+@pytest.fixture(scope="module")
+def built(model):
+    """One engine whose shape fuses (five programs) and its stage records."""
+    eng = _engine(model)
+    try:
+        yield eng, [tuple(r) for r in eng.spans.export()["stages"]]
+    finally:
+        eng.shutdown()
+
+
+def test_stage_records_name_every_program_of_the_build_with_its_four_stages(built):
+    eng, stages = built
+    programs = [f"decode@{w}" for w in eng._view_rungs] + ["prefill", "decode_with_chunk"]
+    assert eng._fuses and len(programs) >= 2
+    assert [r[0] for r in stages if not r[STAGE["program"]]] == ["pool", "jit_build"]
+    of_programs = [(r[STAGE["program"]], r[STAGE["stage"]]) for r in stages if r[STAGE["program"]]]
+    assert sorted(of_programs) == sorted((p, s) for p in programs for s in BUILD_STAGES)
+    # traced and lowered one after another, program by program, then run in the same order
+    python = [(p, s) for p in programs for s in ("trace", "lower")]
+    assert [x for x in of_programs if x[1] in ("trace", "lower")] == python
+    assert [p for p, s in of_programs if s == "first_run"] == programs
+
+
+@pytest.mark.parametrize("field", ["t_start_ns", "cpu_ns", "process_cpu_ns", "gc_ns", "gc2"])
+def test_a_stage_records_ints_that_do_not_run_backwards(built, field):
+    _, stages = built
+    for rec in stages:
+        assert len(rec) == len(stats.STAGE_FIELDS)
+        assert all(isinstance(rec[STAGE[f]], str) for f in ("stage", "program", "thread"))
+        assert isinstance(rec[STAGE[field]], int) and rec[STAGE[field]] >= 0
+    if field == "t_start_ns":
+        assert all(0 < r[STAGE["t_start_ns"]] <= r[STAGE["t_end_ns"]] for r in stages)
+    if field == "cpu_ns":  # a thread burns no more than the process it belongs to (the clocks' grain apart)
+        assert all(r[STAGE["cpu_ns"]] <= r[STAGE["process_cpu_ns"]] + 20_000_000 for r in stages)
+    if field == "gc_ns":  # the collector cannot have run for longer than the stage lasted
+        assert all(r[STAGE["gc_ns"]] <= _wall(r) for r in stages if r[STAGE["thread"]] == stages[0][STAGE["thread"]])
+
+
+def test_the_building_threads_stages_do_not_overlap_and_the_pool_compiles(built):
+    _, stages = built
+    builder = stages[0][STAGE["thread"]]
+    mine = sorted((r for r in stages if r[STAGE["thread"]] == builder), key=lambda r: r[STAGE["t_start_ns"]])
+    assert all(a[STAGE["t_end_ns"]] <= b[STAGE["t_start_ns"]] for a, b in zip(mine, mine[1:]))
+    assert {r[STAGE["stage"]] for r in mine} == {"pool", "jit_build", "trace", "lower", "first_run"}
+    compiles = [r for r in stages if r[STAGE["stage"]] == "compile"]
+    assert len(compiles) >= 2 and all(r[STAGE["thread"]] != builder for r in compiles)
+    # side by side: the compiles' wall, first start to last end, is less than their sum, or no longer than the longest
+    python_end = max(r[STAGE["t_end_ns"]] for r in stages if r[STAGE["stage"]] == "lower")
+    first_run = min(r[STAGE["t_start_ns"]] for r in stages if r[STAGE["stage"]] == "first_run")
+    assert all(python_end <= r[STAGE["t_start_ns"]] and r[STAGE["t_end_ns"]] <= first_run for r in compiles)
+
+
+def test_the_seven_seconds_of_setup_are_the_sums_of_their_records(built):
+    eng, stages = built
+    setup = eng.spans.setup
+    of_programs = [r for r in stages if r[STAGE["program"]]]
+    fused = sum(
+        _wall(r) for r in of_programs
+        if r[STAGE["program"]] == "decode_with_chunk" and r[STAGE["stage"]] in ("trace", "lower")
+    )
+    whole = max(r[STAGE["t_end_ns"]] for r in of_programs) - min(r[STAGE["t_start_ns"]] for r in of_programs)
+    assert setup["fused_build_s"] == fused / 1e9 > 0
+    assert setup["decode_build_s"] == (whole - fused) / 1e9 > 0
+    for name in ("pool", "jit_build"):
+        (rec,) = [r for r in stages if r[STAGE["stage"]] == name]
+        assert setup[name + "_s"] == _wall(rec) / 1e9
+    assert set(setup) == {"pool_s", "jit_build_s", "decode_build_s", "fused_build_s"}  # the deployment adds its three
+    assert stats.setup_seconds([]) == {}
+
+
+def test_an_engine_that_builds_one_rung_a_program_keeps_no_fused_seconds(model):
+    eng = _engine(model, num_slots=200)  # more rows than one tile of the matrix unit: no step with a chunk
+    try:
+        assert not eng._fuses
+        stages = eng.spans.export()["stages"]
+        assert {r[STAGE["program"]] for r in stages if r[STAGE["program"]]} == {f"decode@{w}" for w in eng._view_rungs}
+        assert "fused_build_s" not in eng.spans.setup and eng.spans.setup["decode_build_s"] > 0
+    finally:
+        eng.shutdown()
+
+
+def test_a_stage_is_recorded_and_logged_when_its_body_raises(caplog):
+    records = []
+    with caplog.at_level("INFO", logger="ray_tpu.serve.llm.stats"):
+        with pytest.raises(KeyError):
+            with stats.stage(records, "backend"):
+                raise KeyError("the backend did not come up")
+        with stats.stage(records, "trace", "decode@8"):
+            sum(range(1000))
+    assert [(r[0], r[1]) for r in records] == [("backend", ""), ("trace", "decode@8")]
+    assert [m for m in caplog.messages if m.startswith("setup: ")] == [
+        "setup: backend 0.0 s (cpu 0.0)", "setup: trace decode@8 0.0 s (cpu 0.0)",
+    ]
+
+
+def test_a_stage_counts_the_collections_that_fell_inside_it():
+    import gc
+
+    stats.listen_for_gc()
+    records = []
+    with stats.stage(records, "params"):
+        gc.collect()  # generation 2
+        gc.collect(0)
+    with stats.stage(records, "pool"):
+        pass
+    (params, pool) = records
+    assert params[STAGE["gc2"]] == 1 and 0 < params[STAGE["gc_ns"]] <= _wall(params)
+    assert pool[STAGE["gc2"]] == 0 and pool[STAGE["gc_ns"]] == 0
+
+
+def test_a_profile_taken_over_a_stage_holds_it_by_name(tmp_path):
+    import jax
+
+    jax.profiler.start_trace(str(tmp_path))
+    try:
+        with stats.stage([], "lower", "decode@8"):
+            time.sleep(0.01)
+    finally:
+        jax.profiler.stop_trace()
+    (path,) = glob.glob(str(tmp_path / "plugins/profile/*/*.xplane.pb"))
+    data = jax.profiler.ProfileData.from_file(path)
+    names = {e.name for plane in data.planes if plane.name == "/host:CPU" for line in plane.lines for e in line.events}
+    assert "setup.lower decode@8" in names
+
+
+def test_thread_cpu_reads_every_thread_by_name_and_keeps_the_busiest():
+    import threading
+
+    before = stats.thread_cpu_ns()
+    assert before and all(isinstance(name, str) and ns >= 0 for name, ns in before.values())
+    assert threading.current_thread().name in {name for name, _ in before.values()}
+    stop = threading.Event()
+
+    def spin():
+        while not stop.is_set():
+            sum(range(2000))
+
+    t = threading.Thread(target=spin, name="a-spinning-thread")
+    t.start()
+    time.sleep(0.3)
+    after = stats.thread_cpu_ns()
+    stop.set()
+    t.join()
+    busiest = stats.busiest_threads(before, after)
+    assert 0 < len(busiest) <= 5 and busiest == sorted(busiest, key=lambda b: -b[1])
+    assert "a-spinning-thread" in [name for name, _ in busiest]
+    assert stats.busiest_threads(after, after) == []
+    assert stats.busiest_threads({}, {1: ("a", 5), 2: ("b", 9), 3: ("c", 7)}, n=2) == [["b", 9], ["c", 7]]
+
+
+# What the installed jax raises on the compiling thread (stats.py's docstring): a hit, a write, neither.
+_HIT = ["/jax/compilation_cache/cache_retrieval_time_sec", "/jax/core/compile/backend_compile_duration"]
+_WRITE = ["/jax/compilation_cache/cache_misses", "/jax/core/compile/backend_compile_duration"]
+_NEITHER = ["/jax/core/compile/backend_compile_duration"]
+
+
+@pytest.mark.parametrize(
+    "events,afresh,retrievals", [(_HIT, 0, 1), (_WRITE, 1, 0), (_NEITHER, 0, 0)], ids=["hit", "write", "neither"],
+)
+def test_the_listener_tells_a_cache_hit_from_a_compile_that_was_written(events, afresh, retrievals):
+    before, held = stats.compile_totals(), stats.COMPILES.n
+    for event in events:
+        if event.endswith("cache_misses"):
+            stats._on_cache_write(event)
+        else:
+            stats._on_compile_event(event, 0.25, fun_name="jit(a_program)")
+    after = stats.compile_totals()
+    gained = {k: [after[k][0] - before[k][0], after[k][1] - before[k][1]] for k in after}
+    assert gained == {
+        "backend_compile": [1, 250_000_000],
+        "cache_retrieval": [retrievals, 250_000_000 * retrievals],
+        "compiled_afresh": [afresh, 250_000_000 * afresh],
+    }
+    recs = stats.compile_records()[-(stats.COMPILES.n - held):]
+    assert [dict(zip(stats.COMPILE_FIELDS, r))["afresh"] for r in recs if r[2] == "backend_compile"] == [afresh]
+    assert all(r[4] == 0 for r in recs if r[2] == "cache_retrieval")
+    stats._on_compile_event(_NEITHER[0], 0.1, fun_name="jit(the_next)")  # a write marks ONE compile, its thread's next
+    assert stats.compile_records()[-1][4] == 0
+
+
+def test_the_installed_jax_raises_the_pairs_the_listener_reads(tmp_path):
+    """A program compiled with a persistent cache under it: written the first
+    time (``afresh``), read the second (a retrieval, then a ``backend_compile``
+    that is not afresh); in another process, since jax fixes its cache on first use."""
+    import subprocess
+    import sys
+
+    code = """
+import json, sys
+import jax, jax.numpy as jnp
+from jax.experimental.compilation_cache import compilation_cache
+from ray_tpu.serve.llm import stats
+jax.config.update("jax_compilation_cache_dir", sys.argv[1])
+jax.config.update("jax_persistent_cache_min_compile_time_secs", 0.0)
+jax.config.update("jax_persistent_cache_min_entry_size_bytes", -1)
+stats.listen_for_compiles()
+def a_program_worth_keeping(x):
+    return jnp.tanh(x @ x) + 1
+out = []
+for _ in range(2):
+    n = stats.COMPILES.n
+    jax.jit(a_program_worth_keeping)(jnp.ones((8, 8))).block_until_ready()
+    recs = [r[2:] for r in stats.compile_records()[-(stats.COMPILES.n - n):]]
+    at = [r[1] for r in recs].index("jit(a_program_worth_keeping)")
+    out.append(recs[max(0, at - 1):at + 1])  # the program's own record and the one raised just before it
+    jax.clear_caches()
+    compilation_cache.reset_cache()
+print(json.dumps([out, stats.compile_totals()]))
+"""
+    import os
+
+    env = {k: v for k, v in os.environ.items() if k != "JAX_COMPILATION_CACHE_DIR"}
+    done = subprocess.run(
+        [sys.executable, "-c", code, str(tmp_path)], env=dict(env, JAX_PLATFORMS="cpu"),
+        capture_output=True, text=True, timeout=120, cwd=os.path.dirname(os.path.dirname(os.path.abspath(__file__))),
+    )
+    assert done.returncode == 0, done.stderr[-2000:]
+    (cold, warm), totals = json.loads(done.stdout.splitlines()[-1])
+    assert cold[-1] == ["backend_compile", "jit(a_program_worth_keeping)", 1] and cold[0][0] == "backend_compile"
+    assert warm == [["cache_retrieval", "", 0], ["backend_compile", "jit(a_program_worth_keeping)", 0]]
+    assert totals["cache_retrieval"][0] >= 1 and totals["compiled_afresh"][0] >= 1
+
+
+def test_a_build_called_with_room_meets_no_chunk_boundary_of_the_data_stack():
+    """What moved ``decode_build_s`` by seconds with a key more in a dict literal (PERF.md, PR 49 and 52): a call
+    that starts a chunk of the thread's data stack maps and frees the chunk every time. Found here by scanning
+    depths for the one where a loop of calls runs tens of times longer; from ``_with_room`` it does not."""
+    import sys
+
+    from ray_tpu.serve.llm.engine import _with_room
+
+    assert _with_room.__code__.co_stacksize == 40_000 and _with_room(lambda: 7) == 7
+
+    def leaf(x):
+        return x + 1
+
+    def loop():
+        t0 = time.perf_counter()
+        for i in range(4000):
+            leaf(i)
+        return time.perf_counter() - t0
+
+    def at_depth(depth, run):
+        return run() if depth == 0 else at_depth(depth - 1, run)
+
+    found = {}
+
+    def scan():  # on a thread of its own: its data stack starts empty, whatever called this test
+        plain = [min(at_depth(d, loop) for _ in range(2)) for d in range(600)]
+        worst = max(range(600), key=plain.__getitem__)
+        found.update(usual=sorted(plain)[len(plain) // 2], worst=worst, slow=plain[worst])
+        found["roomy"] = min(at_depth(worst, lambda: _with_room(loop)) for _ in range(5))
+
+    import threading
+
+    thread = threading.Thread(target=scan)
+    thread.start()
+    thread.join()
+    if found["slow"] < 15 * found["usual"]:
+        pytest.skip(f"no boundary found under 600 frames on {sys.version.split()[0]}: {found}")
+    assert found["roomy"] < found["slow"] / 5 and found["roomy"] < 8 * found["usual"], found
 
 
 def test_metrics_fold_observes_ttft_once_per_request_that_ended(model):
@@ -583,3 +869,31 @@ def test_the_proxy_stamps_a_request_and_forwards_its_identifier():
     for rec in recs:  # the proxy's stamp is on the replica's clock: one host
         assert 0 < rec["t_recv_ns"] <= rec["t_submit_ns"] <= rec["t_first_ns"] <= rec["t_done_ns"]
     assert recs[1]["t_recv_ns"] >= t0
+
+
+def test_a_replica_stamps_its_own_way_from_the_controllers_decision_to_its_first_answer():
+    import ray_tpu
+    from ray_tpu import serve
+    from ray_tpu.serve.llm import LLMDeployment
+
+    ray_tpu.init(num_cpus=4, object_store_memory=128 * 1024 * 1024)
+    try:
+        serve.start()
+        app = serve.deployment(LLMDeployment).bind(MODEL, engine_config=ENGINE)
+        t0 = time.monotonic_ns()
+        handle = serve.run(app, route_prefix="/llm")
+        t1 = time.monotonic_ns()
+        spans = ray_tpu.get(handle.get_stats.remote(), timeout=60)["spans"]
+    finally:
+        serve.shutdown()
+        ray_tpu.shutdown()
+    stamps = spans["setup_stamps"]
+    assert list(stamps) == list(SETUP_STAMPS)  # in the order taken
+    order = [stamps[k] for k in ("t_requested_ns", "t_process_ns", "t_actor_ns", "t_callable_ns", "t_ready_ns")]
+    assert all(isinstance(t, int) for t in order) and t0 <= order[0] and order[-1] <= t1
+    assert order == sorted(order)
+    # The deployment's first stage starts behind the replica's last stamp before it, and the engine is built
+    # before the first health check answers: the stages lie between the two.
+    stages = spans["stages"]
+    assert stamps["t_callable_ns"] <= stages[0][3] and max(r[4] for r in stages) <= stamps["t_ready_ns"]
+    assert stamps != SETUP_STAMPS  # the replica's process has them, not this one

@@ -182,3 +182,34 @@ def test_gbdt_trainers_gated():
         XGBoostTrainer(datasets={})
     with pytest.raises(ImportError, match="lightgbm"):
         LightGBMTrainer(datasets={})
+
+
+@pytest.mark.parametrize("num_workers", [1, 2], ids=["one_worker", "a_gang_of_two"])
+def test_a_reports_metrics_carry_the_stamps_of_the_trainers_start(ray_start_regular, num_workers):
+    """ISSUE 52: ``_trainer_start`` is filled into every report on its way to
+    ``on_report``, four CLOCK_MONOTONIC stamps in the order taken, and the
+    user's own keys are as the loop reported them."""
+    import time
+
+    from ray_tpu.train._internal.backend_executor import TRAINER_START
+
+    def loop(config):
+        for step in range(3):
+            session.report({"step": step, "loss": 1.0 / (step + 1), "rank": session.get_world_rank()})
+
+    t0 = time.monotonic_ns()
+    result = JaxTrainer(
+        loop, scaling_config=ScalingConfig(num_workers=num_workers),
+        run_config=RunConfig(storage_path="/tmp/rtpu_train_start_test"),
+    ).fit()
+    t1 = time.monotonic_ns()
+    assert TRAINER_START == "_trainer_start"
+    start = result.metrics.pop(TRAINER_START)
+    assert result.metrics == {"step": 2, "loss": 1.0 / 3, "rank": 0}
+    assert list(start) == ["t_fit_ns", "t_worker_ns", "t_mesh_ns", "t_loop_ns"]
+    order = list(start.values())
+    assert all(isinstance(t, int) for t in order) and order == sorted(order)
+    assert t0 <= order[0] and order[-1] <= t1
+    # every report of the run, not the last alone
+    frame = result.metrics_dataframe
+    assert list(frame["step"]) == [0, 1, 2] and all(s == start for s in frame[TRAINER_START])
