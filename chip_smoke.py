@@ -107,9 +107,13 @@ def _kernel_check(shape: dict, interpret: bool) -> dict:
     kq, kk, kv, kd = jax.random.split(jax.random.PRNGKey(21), 4)
     q = jax.random.normal(kq, (1, T, H, D), jnp.bfloat16)
     do = jax.random.normal(kd, (1, T, H, D), jnp.bfloat16)
-    # GQA as the model runs it: KV heads repeated to H before the kernel.
-    k = jnp.repeat(jax.random.normal(kk, (1, T, KV, D), jnp.bfloat16), H // KV, axis=2)
-    v = jnp.repeat(jax.random.normal(kv, (1, T, KV, D), jnp.bfloat16), H // KV, axis=2)
+    # GQA as the model runs it: heads before tokens, k and v at KV heads, read in place by the kernels. The
+    # reference repeats them to H heads, and a KV head's cotangent is the sum over its group's query heads.
+    k = jax.random.normal(kk, (1, T, KV, D), jnp.bfloat16)
+    v = jax.random.normal(kv, (1, T, KV, D), jnp.bfloat16)
+    swap = lambda x: x.transpose(0, 2, 1, 3)
+    repeated = lambda x: jnp.repeat(x, H // KV, axis=2)
+    summed = lambda dx: dx.reshape(1, T, KV, H // KV, D).sum(axis=3)
 
     def flash(window):
         def run(q, k, v, do):
@@ -118,9 +122,9 @@ def _kernel_check(shape: dict, interpret: bool) -> dict:
                     a, b, c, causal=True, window=window,
                     force_pallas=True, interpret=interpret,
                 ),
-                q, k, v,
+                swap(q), swap(k), swap(v),
             )
-            return (out, *vjp(do))
+            return tuple(swap(x) for x in (out, *vjp(swap(do))))
 
         return jax.jit(run)
 
@@ -134,11 +138,12 @@ def _kernel_check(shape: dict, interpret: bool) -> dict:
                 )
                 return (out, *vjp(dog))
 
-        def run(*arrays):
+        def run(q, k, v, do):
             # [1, T, H, D] -> [H/group, 1, T, group, D] and back.
             split = lambda x: jnp.moveaxis(x.reshape(1, T, H // group, group, D), 2, 0)
-            outs = jax.lax.map(one_group, tuple(split(x) for x in arrays))
-            return tuple(jnp.moveaxis(o, 0, 2).reshape(1, T, H, D) for o in outs)
+            outs = jax.lax.map(one_group, tuple(split(x) for x in (q, repeated(k), repeated(v), do)))
+            out, dq, dk, dv = (jnp.moveaxis(o, 0, 2).reshape(1, T, H, D) for o in outs)
+            return out, dq, summed(dk), summed(dv)
 
         return jax.jit(run)
 

@@ -10,6 +10,11 @@ TPU-first choices:
   over the ``pp`` axis (parallel/pipeline.py)
 - attention runs the Pallas flash kernel on TPU (ops/attention.py), ring
   attention over the ``sp`` axis for long context (parallel/ring_attention.py)
+- the training block holds q, k and v heads before tokens, ``[B, H or KV, T,
+  Dh]``, from the projections' matmuls to ``wo``'s: the layout the flash kernels
+  read in place (a head's ``[T, Dh]`` contiguous, tokens on the sublanes). K and
+  V stay at ``n_kv_heads`` forward and backward, nothing is transposed between,
+  and the rotary swaps a head's halves on the MXU (``_rope_rotate``)
 - bf16 activations/params by default; f32 RMSNorm epsilon path and logits
 - rotary embeddings, GQA (n_kv_heads <= n_heads), SwiGLU MLP, optional
   mixture-of-experts MLP (parallel/moe.py) sharded over ``ep``
@@ -45,7 +50,7 @@ import jax.numpy as jnp
 from jax import lax
 from jax.ad_checkpoint import checkpoint_name
 
-from ray_tpu.ops.attention import FLASH_LSE, FLASH_OUT, flash_attention
+from ray_tpu.ops.attention import FLASH_LSE, FLASH_OUT, flash_attention, repeat_kv
 
 
 @dataclasses.dataclass(frozen=True)
@@ -780,6 +785,44 @@ def _rope_apply(x, cos, sin):
     return jnp.concatenate([rx1, rx2], axis=-1).astype(x.dtype)
 
 
+def _whole_width(cos, sin):
+    """``_rope_tables``' [B, T, Dh/2] as ``_rope_rotate`` takes them: ([cos, cos], [sin, sin]), [B, T, Dh]."""
+    return jnp.concatenate([cos, cos], axis=-1), jnp.concatenate([sin, sin], axis=-1)
+
+
+def _swap_halves(x, sign):
+    """x [..., Dh] -> ``sign`` x [-x2, x1], exactly: a matmul with the signed permutation (each output is one input
+    times +-1, summed with zeros in float32). The MXU does what a split and a concatenation, or ``jnp.roll``, make the
+    compiler write to memory a half [.., Dh/2] at a time, forward and backward (Mellum's step, v5e, PR 53: 404.3 ms
+    with the split, 401.2 with the roll, 390.9 with this)."""
+    half = x.shape[-1] // 2
+    swap = jnp.eye(2 * half, k=half) - jnp.eye(2 * half, k=-half)  # [i, i + half] = 1: out[i + half] = x[i]
+    return jnp.einsum("...k,kj->...j", x, (sign * swap).astype(x.dtype), precision=lax.Precision.HIGHEST, preferred_element_type=jnp.float32)
+
+
+@jax.custom_vjp
+def _rope_rotate(x, cos, sin):
+    """``_rope_apply`` for the training block's x [B, H, T, Dh] and ``_whole_width`` tables: the same float32
+    products and sums, so the same values and cotangents, written over whole heads: ``x1 * cos - x2 * sin`` beside
+    ``x2 * cos + x1 * sin`` is x [cos, cos] + [-x2, x1] [sin, sin]. The cotangent is the rotation back (its own rule:
+    autodiff would swap the halves of a float32 product, a matmul the MXU rounds)."""
+    return (x * cos[:, None] + _swap_halves(x, 1.0) * sin[:, None]).astype(x.dtype)
+
+
+def _rope_rotate_fwd(x, cos, sin):
+    return _rope_rotate(x, cos, sin), (cos, sin)
+
+
+def _rope_rotate_bwd(tables, dy):
+    cos, sin = tables
+    # Each term rounded to x's dtype before the sum, as autodiff rounds the cotangents of ``_rope_apply``'s two uses of x.
+    dx = (dy * cos[:, None]).astype(dy.dtype) + (_swap_halves(dy, -1.0) * sin[:, None]).astype(dy.dtype)
+    return dx, jnp.zeros_like(cos), jnp.zeros_like(sin)  # the tables are functions of the positions alone
+
+
+_rope_rotate.defvjp(_rope_rotate_fwd, _rope_rotate_bwd)
+
+
 def _rope(x, positions, theta, scaling: tuple = ()):
     # Convenience form (decode paths in models/generate.py use this).
     cos, sin = _rope_tables(positions, x.shape[-1], theta, scaling)
@@ -810,13 +853,17 @@ _KEPT_UNDER_REMAT = jax.checkpoint_policies.save_only_these_names(*_KEPT_INPUTS,
 def _attention_block(lp, x, rope_cs, cfg: TransformerConfig, mesh, attn_impl: str, kind=None):
     """``kind``: the layer's of ``layer_kinds`` (None without a pattern): a full
     layer attends over the whole context, the others within
-    ``sliding_window``; ``rope_cs`` is its kind's tables, None for no positions."""
+    ``sliding_window``; ``rope_cs`` is its kind's ``_whole_width`` tables, None for no positions."""
     B, T, D = x.shape
     H, KV, Dh = cfg.n_heads, cfg.n_kv_heads, cfg.head_dim
     h = _rms_norm(x, lp["attn_norm"], cfg.norm_eps)
-    q = (h @ lp["wq"].astype(h.dtype)).reshape(B, T, H, Dh)
-    k = (h @ lp["wk"].astype(h.dtype)).reshape(B, T, KV, Dh)
-    v = (h @ lp["wv"].astype(h.dtype)).reshape(B, T, KV, Dh)
+    # q, k, v leave their projections heads before tokens, [B, H or KV, T, Dh]: a head's [T, Dh] contiguous, which
+    # is how the flash kernels read it, so that nothing between a projection and ``wo`` transposes or repeats. The
+    # matmul writes that layout itself (the compiler folds the transpose into it); the weight stays [D, H * Dh] in
+    # it, so that its gradient leaves the backward matmul in the leaf's layout (over the weight seen as [D, H, Dh]
+    # the gradients of wq, wk, wv were relaid in float32 before they were stacked: +96 MB in ``train2.dp4-4k``).
+    project = lambda w, heads: (h @ w.astype(h.dtype)).reshape(B, T, heads, Dh).transpose(0, 2, 1, 3)  # noqa: E731
+    q, k, v = project(lp["wq"], H), project(lp["wk"], KV), project(lp["wv"], KV)
     if cfg.qk_norm:
         # A norm's backward pass needs its input: kept too, or the projections run again for it (Mellum's cell, v5e,
         # PR 51: 435.4 ms a step with q and k kept behind the norms alone, 408.7 with both).
@@ -824,22 +871,20 @@ def _attention_block(lp, x, rope_cs, cfg: TransformerConfig, mesh, attn_impl: st
         q, k = _rms_norm(q, lp["q_norm"], cfg.norm_eps), _rms_norm(k, lp["k_norm"], cfg.norm_eps)
     if rope_cs is not None:
         cos, sin = rope_cs
-        q = _rope_apply(q, cos, sin)
-        k = _rope_apply(k, cos, sin)
-    # What the attention core was given, as ``_run_layers``' checkpoint policy keeps it: behind the norms and the
-    # rotary and before the repeat (K and V at ``KV`` heads).
+        q = _rope_rotate(q, cos, sin)
+        k = _rope_rotate(k, cos, sin)
+    # What the attention core is given, as ``_run_layers``' checkpoint policy keeps it: behind the norms and the
+    # rotary, K and V at ``KV`` heads (``flash_attention`` reads a group's KV head in place, forward and backward).
     q, k, v = (checkpoint_name(a, name) for a, name in zip((q, k, v), _KEPT_INPUTS))
-    if KV != H:  # GQA: repeat kv heads
-        rep = H // KV
-        k = jnp.repeat(k, rep, axis=2)
-        v = jnp.repeat(v, rep, axis=2)
     window = 0 if kind == "full" else cfg.sliding_window
     if attn_impl == "ring" and mesh is not None and mesh.shape.get("sp", 1) > 1:
         if window:
             raise NotImplementedError("sliding_window + ring attention not supported")
         from ray_tpu.parallel.ring_attention import ring_attention
 
-        o = ring_attention(q, k, v, mesh, causal=True)
+        # its chunks are [B, T, H, Dh] and pair head with head
+        tokens_major = lambda a: repeat_kv(a, H, axis=1).transpose(0, 2, 1, 3)  # noqa: E731
+        o = ring_attention(tokens_major(q), tokens_major(k), tokens_major(v), mesh, causal=True).transpose(0, 2, 1, 3)
     else:
         attn = partial(flash_attention, causal=True, window=window)
         if mesh is not None and mesh.size > 1:
@@ -848,15 +893,18 @@ def _attention_block(lp, x, rope_cs, cfg: TransformerConfig, mesh, attn_impl: st
             # and heads, the axes the model's sharding rules already split.
             from ray_tpu.parallel.mesh import logical_to_spec
 
-            spec = logical_to_spec(("batch", None, "heads", None))
+            spec = logical_to_spec(("batch", "heads", None, None))
+            # A device's query heads must find their KV heads on it: where the mesh splits the heads further than
+            # the KV heads go round, K and V are repeated up to the least width it does divide.
+            split = math.prod(mesh.shape[axis] for axis in jax.tree.leaves(spec[1]))
+            k, v = (repeat_kv(a, math.lcm(KV, split), axis=1) for a in (k, v))
             attn = jax.shard_map(
                 attn, mesh=mesh, in_specs=(spec, spec, spec), out_specs=spec,
                 check_vma=False,
             )
         with jax.named_scope(f"attention_{kind}") if kind else contextlib.nullcontext():
             o = attn(q, k, v)
-    o = o.reshape(B, T, H * Dh)
-    return x + o @ lp["wo"].astype(o.dtype)
+    return x + jnp.einsum("bhtk,hkd->btd", o, lp["wo"].astype(o.dtype).reshape(H, Dh, D))
 
 
 def _moe_mlp(lp, h, capacity_factor: float):
@@ -935,7 +983,7 @@ def _run_layers(params: dict, tokens, cfg: TransformerConfig, mesh, attn_impl: s
     # shared by every such layer's q and k (vs 2·n_layers recomputations inside the scan).
     def table_of(kind):
         rope = layer_rope(cfg, kind)
-        return None if rope is None else _rope_tables(positions, cfg.head_dim, cfg.rope_theta, rope)
+        return None if rope is None else _whole_width(*_rope_tables(positions, cfg.head_dim, cfg.rope_theta, rope))
 
     def layer_of(kind):
         layer_fn = partial(_layer, cfg=cfg, mesh=mesh, attn_impl=attn_impl, kind=kind)

@@ -24,10 +24,8 @@ class ViTAttention(nn.Module):
         H = self.num_heads
         qkv = nn.Dense(3 * D, dtype=self.dtype, name="qkv")(x)
         q, k, v = jnp.split(qkv, 3, axis=-1)
-        q = q.reshape(B, T, H, D // H)
-        k = k.reshape(B, T, H, D // H)
-        v = v.reshape(B, T, H, D // H)
-        o = flash_attention(q, k, v, causal=False)
+        heads = lambda a: a.reshape(B, T, H, D // H).transpose(0, 2, 1, 3)  # flash_attention's [B, H, T, D // H]
+        o = flash_attention(heads(q), heads(k), heads(v), causal=False).transpose(0, 2, 1, 3)
         return nn.Dense(D, dtype=self.dtype, name="proj")(o.reshape(B, T, D))
 
 

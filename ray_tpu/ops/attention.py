@@ -9,8 +9,11 @@ torch; SURVEY.md §5.7) — this module is where the TPU-native build spends the
 FLOPs the reference hands to external frameworks.
 
 Design notes (per /opt/skills/guides/pallas_guide.md):
-- grid = (batch*heads, q_blocks); the k-loop runs inside the kernel as a
+- grid = (batch, heads, q_blocks); the k-loop runs inside the kernel as a
   fori_loop so the running max/denominator stay in VMEM scratch.
+- the kernels read q ``[B, H, T, D]`` and k, v ``[B, KV, T, D]`` (heads before
+  tokens: a head's ``[T, D]`` is contiguous and tiled as a kernel wants it) in
+  place: a KV head is block ``h // rep`` of its axis, so nothing repeats k and v.
 - block sizes default to (128, 128): MXU-shaped, and multiples of the
   (8,128)/f32, (16,128)/bf16 tile constraints.
 - causal masking prunes fully-masked k-blocks via the loop upper bound
@@ -30,9 +33,11 @@ from jax.ad_checkpoint import checkpoint_name
 def _xla_attention(q, k, v, causal: bool, sm_scale: float, bias=None, window: int = 0):
     """Reference implementation (XLA fuses this fine on CPU; used for
     correctness tests and non-TPU fallback). ``window`` > 0: sliding-window
-    causal attention — row i sees keys (i-window, i]."""
+    causal attention — row i sees keys (i-window, i]. k and v may have fewer
+    heads than q (``H % KV == 0``): query head h reads KV head ``h // (H // KV)``."""
     B, Tq, H, D = q.shape
     Tk = k.shape[1]
+    k, v = repeat_kv(k, H, axis=2), repeat_kv(v, H, axis=2)
     logits = jnp.einsum("bqhd,bkhd->bhqk", q, k) * sm_scale
     if bias is not None:
         logits = logits + bias
@@ -47,11 +52,12 @@ def _xla_attention(q, k, v, causal: bool, sm_scale: float, bias=None, window: in
     return jnp.einsum("bhqk,bkhd->bqhd", probs, v)
 
 
-# LSE (and the in-kernel running max/denominator) carry a replicated
-# 128-lane trailing dim: Mosaic tiles the last two dims as (8, 128), so a
-# 1-D [block_q] vector (or a [BH, Tq] output with a squeezed block dim)
-# cannot be laid out. Same layout as jax's reference TPU flash kernel
-# (jax/experimental/pallas/ops/tpu/flash_attention.py, MIN_BLOCK_SIZE).
+# A row's log-sum-exp is a [block_q, 1] column in the kernel, and Mosaic tiles the
+# last two dims as (8, 128): the column is spread over this many lanes and
+# transposed, and ONE row of that, [1, block_q] lane-major, is what leaves
+# (``[B, H, 1, Tq]``, the layout the backward kernels slice). Until PR 53 all 128
+# replicated lanes were written, ``[B*H, Tq, 128]`` float32 (268 MB a layer at
+# Mellum's widths), and sliced after.
 _LSE_LANES = 128
 
 # Preferred block edge of the forward kernel and of the two backward kernels
@@ -87,11 +93,31 @@ def _vmem(rows: int, d: int, itemsize: int) -> dict:
     return {"compiler_params": pltpu.CompilerParams(vmem_limit_bytes=resident + _TILES_BYTES)}
 
 
+def repeat_kv(x, H: int, axis: int):
+    """k or v at ``H`` heads along ``axis``, each KV head ``H // KV`` times in a row: what a path pays that cannot
+    address a KV head as ``h // rep``."""
+    KV = x.shape[axis]
+    return x if KV == H else jnp.repeat(x, H // KV, axis=axis)
+
+
+def _sum_groups(dx, KV: int, dtype):
+    """``repeat_kv``'s cotangent: ``[B, H, T, D]`` summed over each KV head's query heads, in float32."""
+    B, H, T, D = dx.shape
+    if KV == H:
+        return dx.astype(dtype)
+    return dx.reshape(B, KV, H // KV, T, D).astype(jnp.float32).sum(axis=2).astype(dtype)
+
+
+def _swap(x):
+    """``[B, T, H, D]`` <-> ``[B, H, T, D]``."""
+    return x.transpose(0, 2, 1, 3)
+
+
 def _flash_kernel(q_ref, k_ref, v_ref, o_ref, lse_ref=None, *, block_k: int, causal: bool, sm_scale: float, seq_k: int, block_q: int, window: int = 0):
     from jax.experimental import pallas as pl
 
     q = q_ref[...]  # [block_q, d]
-    q_idx = pl.program_id(1)
+    q_idx = pl.program_id(2)  # grid = (batch, heads, q blocks)
     d = q.shape[-1]
 
     m0 = jnp.full((q.shape[0], 1), -jnp.inf, dtype=jnp.float32)
@@ -103,7 +129,7 @@ def _flash_kernel(q_ref, k_ref, v_ref, o_ref, lse_ref=None, *, block_k: int, cau
     # tril(k=Tk-Tq)): query row i sees keys 0..i+(Tk-Tq). Identical to the
     # usual mask when Tq == Tk; for Tq < Tk (decode with cache) the tail of
     # the keys is what's visible.
-    causal_offset = seq_k - block_q * pl.num_programs(1)
+    causal_offset = seq_k - block_q * pl.num_programs(2)
     start_block = 0
     if causal:
         # K blocks strictly after this Q block's last visible key are masked.
@@ -173,20 +199,19 @@ def _flash_kernel(q_ref, k_ref, v_ref, o_ref, lse_ref=None, *, block_k: int, cau
         # online softmax. Replicated across the lane dim (see _LSE_LANES).
         # Only materialized on the VJP forward — the primal path skips the
         # HBM write entirely.
-        lse_ref[...] = jnp.broadcast_to(m + jnp.log(l), lse_ref.shape).astype(lse_ref.dtype)
+        lse = jnp.broadcast_to(m + jnp.log(l), (q.shape[0], _LSE_LANES)).T  # [lanes, block_q]: a row is lane-major
+        lse_ref[...] = lse[:1].astype(lse_ref.dtype)
 
 
 def _pallas_flash_with_lse(q, k, v, causal: bool, sm_scale: float, block_q: int, block_k: int, interpret: bool, save_lse: bool = True, window: int = 0):
+    """q ``[B, H, Tq, D]``, k and v ``[B, KV, Tk, D]`` (``H % KV == 0``) -> (out ``[B, H, Tq, D]``, log-sum-exp
+    ``[B, H, Tq]`` float32 or None), read and written where they lie: the keys and values of query head ``h`` are
+    block ``h // rep`` of the KV axis, which the pipeline fetches once for the ``rep`` consecutive heads that name it."""
     from jax.experimental import pallas as pl
 
-    B, Tq, H, D = q.shape
-    Tk = k.shape[1]
-    # Fold batch and heads into the grid's first axis; layout [BH, T, D].
-    qf = q.transpose(0, 2, 1, 3).reshape(B * H, Tq, D)
-    kf = k.transpose(0, 2, 1, 3).reshape(B * H, Tk, D)
-    vf = v.transpose(0, 2, 1, 3).reshape(B * H, Tk, D)
-
-    grid = (B * H, pl.cdiv(Tq, block_q))
+    B, H, Tq, D = q.shape
+    KV, Tk = k.shape[1], k.shape[2]
+    rep = H // KV
     kernel = functools.partial(
         _flash_kernel,
         block_k=block_k,
@@ -196,27 +221,23 @@ def _pallas_flash_with_lse(q, k, v, causal: bool, sm_scale: float, block_q: int,
         block_q=block_q,
         window=window,
     )
-    out_specs = [pl.BlockSpec((None, block_q, D), lambda bh, qb: (bh, qb, 0))]
-    out_shape = [jax.ShapeDtypeStruct((B * H, Tq, D), q.dtype)]
+    q_block = pl.BlockSpec((None, None, block_q, D), lambda b, h, qb: (b, h, qb, 0))
+    kv_whole = pl.BlockSpec((None, None, Tk, D), lambda b, h, qb: (b, h // rep, 0, 0))
+    out_specs = [q_block]
+    out_shape = [jax.ShapeDtypeStruct(q.shape, q.dtype)]
     if save_lse:
-        out_specs.append(pl.BlockSpec((None, block_q, _LSE_LANES), lambda bh, qb: (bh, qb, 0)))
-        out_shape.append(jax.ShapeDtypeStruct((B * H, Tq, _LSE_LANES), jnp.float32))
+        out_specs.append(pl.BlockSpec((None, None, 1, block_q), lambda b, h, qb: (b, h, 0, qb)))
+        out_shape.append(jax.ShapeDtypeStruct((B, H, 1, Tq), jnp.float32))
     res = pl.pallas_call(
         kernel,
-        grid=grid,
-        in_specs=[
-            pl.BlockSpec((None, block_q, D), lambda bh, qb: (bh, qb, 0)),
-            pl.BlockSpec((None, Tk, D), lambda bh, qb: (bh, 0, 0)),
-            pl.BlockSpec((None, Tk, D), lambda bh, qb: (bh, 0, 0)),
-        ],
+        grid=(B, H, pl.cdiv(Tq, block_q)),
+        in_specs=[q_block, kv_whole, kv_whole],
         out_specs=out_specs,
         out_shape=out_shape,
         interpret=interpret,
         **_vmem(Tk, D, k.dtype.itemsize),
-    )(qf, kf, vf)
-    out = res[0].reshape(B, H, Tq, D).transpose(0, 2, 1, 3)
-    lse = res[1][..., 0].reshape(B, H, Tq) if save_lse else None
-    return out, lse
+    )(q, k, v)
+    return res[0], res[1][:, :, 0] if save_lse else None
 
 
 @functools.partial(jax.custom_vjp, nondiff_argnums=(3, 4, 5, 6, 7, 8))
@@ -249,19 +270,16 @@ def _xla_blockwise_bwd(causal, sm_scale, block_q, block_k, window, res, dout):
         dV = P^T dO;  dP = dO V^T;  dS = P * (dP - D) * sm_scale
         dQ = dS K;    dK = dS^T Q
     """
-    q, k, v, out, lse = res
-    B, Tq, H, D = q.shape
-    Tk = k.shape[1]
+    qT, kT, vT, oT, lse = res                          # [B,H,Tq,D], [B,KV,Tk,D] x2, [B,H,Tq,D], [B,H,Tq]
+    doT = dout
+    B, H, Tq, D = qT.shape
+    KV, Tk = kT.shape[1], kT.shape[2]
+    kT, vT = repeat_kv(kT, H, axis=1), repeat_kv(vT, H, axis=1)
     # Inputs stay in their storage dtype (bf16 on TPU): every matmul below
     # asks for f32 accumulation via preferred_element_type, which is the
     # MXU's native mode. An upfront .astype(f32) would instead force f32
     # matmuls (multi-pass on the MXU, ~4x slower) — measured 89.8k -> 97k+
     # tok/s on the v5e bench when the casts were dropped.
-    qT = q.transpose(0, 2, 1, 3)                       # [B,H,Tq,D]
-    kT = k.transpose(0, 2, 1, 3)                       # [B,H,Tk,D]
-    vT = v.transpose(0, 2, 1, 3)
-    oT = out.transpose(0, 2, 1, 3)
-    doT = dout.transpose(0, 2, 1, 3)
     delta = jnp.sum(doT.astype(jnp.float32) * oT.astype(jnp.float32), axis=-1)  # [B,H,Tq]
 
     def mm(a, b, pat):
@@ -316,11 +334,7 @@ def _xla_blockwise_bwd(causal, sm_scale, block_q, block_k, window, res, dout):
     dk0 = jnp.zeros((B, H, Tk, D), jnp.float32)
     dv0 = jnp.zeros((B, H, Tk, D), jnp.float32)
     dq, dk, dv = jax.lax.fori_loop(0, num_kb, body, (dq0, dk0, dv0))
-    return (
-        dq.transpose(0, 2, 1, 3).astype(q.dtype),
-        dk.transpose(0, 2, 1, 3).astype(k.dtype),
-        dv.transpose(0, 2, 1, 3).astype(v.dtype),
-    )
+    return dq.astype(qT.dtype), _sum_groups(dk, KV, kT.dtype), _sum_groups(dv, KV, vT.dtype)
 
 
 # --- Pallas backward kernels -------------------------------------------------
@@ -361,7 +375,7 @@ def _bwd_tile(q_blk, do_blk, k_blk, v_blk, lse_row, delta_row, q_pos0, k_pos0, c
 def _flash_bwd_dkv_kernel(q_ref, do_ref, k_ref, v_ref, lse_ref, delta_ref, dk_ref, dv_ref, *, block_q: int, block_k: int, causal: bool, sm_scale: float, seq_q: int, seq_k: int, window: int):
     from jax.experimental import pallas as pl
 
-    kb = pl.program_id(1)
+    kb = pl.program_id(2)
     k_blk = k_ref[...]
     v_blk = v_ref[...]
     offset = seq_k - seq_q  # bottom-right causal alignment
@@ -407,7 +421,7 @@ def _flash_bwd_dkv_kernel(q_ref, do_ref, k_ref, v_ref, lse_ref, delta_ref, dk_re
 def _flash_bwd_dq_kernel(q_ref, do_ref, k_ref, v_ref, lse_ref, delta_ref, dq_ref, *, block_q: int, block_k: int, causal: bool, sm_scale: float, seq_q: int, seq_k: int, window: int):
     from jax.experimental import pallas as pl
 
-    qb = pl.program_id(1)
+    qb = pl.program_id(2)
     q_blk = q_ref[...]
     do_blk = do_ref[...]
     lse_row = lse_ref[...]
@@ -442,68 +456,52 @@ def _flash_bwd_dq_kernel(q_ref, do_ref, k_ref, v_ref, lse_ref, delta_ref, dq_ref
 
 
 def _pallas_bwd_impl(q, k, v, out, lse, dout, causal, sm_scale, block_q, block_k, interpret, window):
+    """Operands as the forward call's -> (dq ``[B, H, Tq, D]``, dk and dv ``[B, KV, Tk, D]``). The dkv kernel writes
+    a query head's dk and dv each, ``[B, H, Tk, D]``, and ONE reduction in float32 sums a KV head's over its
+    ``rep`` query heads (``_sum_groups``), as the cotangent of a repeat would."""
     from jax.experimental import pallas as pl
 
-    B, Tq, H, D = q.shape
-    Tk = k.shape[1]
-    qf = q.transpose(0, 2, 1, 3).reshape(B * H, Tq, D)
-    kf = k.transpose(0, 2, 1, 3).reshape(B * H, Tk, D)
-    vf = v.transpose(0, 2, 1, 3).reshape(B * H, Tk, D)
-    of = out.transpose(0, 2, 1, 3).reshape(B * H, Tq, D)
-    dof = dout.transpose(0, 2, 1, 3).reshape(B * H, Tq, D)
-    # delta = rowsum(dO · O): tiny [BH, Tq] f32; lane-major [BH, 1, Tq] so
-    # kernels can slice [1, block_q] rows without layout tricks.
-    delta = jnp.sum(dof.astype(jnp.float32) * of.astype(jnp.float32), axis=-1)
-    delta = delta[:, None, :]
-    lsef = lse.reshape(B * H, 1, Tq)
+    B, H, Tq, D = q.shape
+    KV, Tk = k.shape[1], k.shape[2]
+    rep = H // KV
+    # delta = rowsum(dO · O) and the log-sum-exp: small float32 [B, H, 1, Tq], lane-major so that the kernels
+    # can slice [1, block_q] rows without layout tricks.
+    delta = jnp.sum(dout.astype(jnp.float32) * out.astype(jnp.float32), axis=-1)[:, :, None, :]
+    operands = (q, dout, k, v, lse[:, :, None, :], delta)
 
     kw = dict(block_q=block_q, block_k=block_k, causal=causal,
               sm_scale=sm_scale, seq_q=Tq, seq_k=Tk, window=window)
+    q_whole = pl.BlockSpec((None, None, Tq, D), lambda b, h, kb: (b, h, 0, 0))
+    kv_block = pl.BlockSpec((None, None, block_k, D), lambda b, h, kb: (b, h // rep, kb, 0))
+    dkv_block = pl.BlockSpec((None, None, block_k, D), lambda b, h, kb: (b, h, kb, 0))
+    row_whole = pl.BlockSpec((None, None, 1, Tq), lambda b, h, kb: (b, h, 0, 0))
     dk, dv = pl.pallas_call(
         functools.partial(_flash_bwd_dkv_kernel, **kw),
-        grid=(B * H, pl.cdiv(Tk, block_k)),
-        in_specs=[
-            pl.BlockSpec((None, Tq, D), lambda bh, kb: (bh, 0, 0)),
-            pl.BlockSpec((None, Tq, D), lambda bh, kb: (bh, 0, 0)),
-            pl.BlockSpec((None, block_k, D), lambda bh, kb: (bh, kb, 0)),
-            pl.BlockSpec((None, block_k, D), lambda bh, kb: (bh, kb, 0)),
-            pl.BlockSpec((None, 1, Tq), lambda bh, kb: (bh, 0, 0)),
-            pl.BlockSpec((None, 1, Tq), lambda bh, kb: (bh, 0, 0)),
-        ],
-        out_specs=[
-            pl.BlockSpec((None, block_k, D), lambda bh, kb: (bh, kb, 0)),
-            pl.BlockSpec((None, block_k, D), lambda bh, kb: (bh, kb, 0)),
-        ],
-        out_shape=[
-            jax.ShapeDtypeStruct((B * H, Tk, D), k.dtype),
-            jax.ShapeDtypeStruct((B * H, Tk, D), v.dtype),
-        ],
+        grid=(B, H, pl.cdiv(Tk, block_k)),
+        in_specs=[q_whole, q_whole, kv_block, kv_block, row_whole, row_whole],
+        out_specs=[dkv_block, dkv_block],
+        out_shape=[jax.ShapeDtypeStruct((B, H, Tk, D), k.dtype), jax.ShapeDtypeStruct((B, H, Tk, D), v.dtype)],
         interpret=interpret,
         **_vmem(Tq, D, q.dtype.itemsize),
-    )(qf, dof, kf, vf, lsef, delta)
+    )(*operands)
+    q_block = pl.BlockSpec((None, None, block_q, D), lambda b, h, qb: (b, h, qb, 0))
+    kv_whole = pl.BlockSpec((None, None, Tk, D), lambda b, h, qb: (b, h // rep, 0, 0))
+    row_block = pl.BlockSpec((None, None, 1, block_q), lambda b, h, qb: (b, h, 0, qb))
     (dq,) = pl.pallas_call(
         functools.partial(_flash_bwd_dq_kernel, **kw),
-        grid=(B * H, pl.cdiv(Tq, block_q)),
-        in_specs=[
-            pl.BlockSpec((None, block_q, D), lambda bh, qb: (bh, qb, 0)),
-            pl.BlockSpec((None, block_q, D), lambda bh, qb: (bh, qb, 0)),
-            pl.BlockSpec((None, Tk, D), lambda bh, qb: (bh, 0, 0)),
-            pl.BlockSpec((None, Tk, D), lambda bh, qb: (bh, 0, 0)),
-            pl.BlockSpec((None, 1, block_q), lambda bh, qb: (bh, 0, qb)),
-            pl.BlockSpec((None, 1, block_q), lambda bh, qb: (bh, 0, qb)),
-        ],
-        out_specs=[pl.BlockSpec((None, block_q, D), lambda bh, qb: (bh, qb, 0))],
-        out_shape=[jax.ShapeDtypeStruct((B * H, Tq, D), q.dtype)],
+        grid=(B, H, pl.cdiv(Tq, block_q)),
+        in_specs=[q_block, q_block, kv_whole, kv_whole, row_block, row_block],
+        out_specs=[q_block],
+        out_shape=[jax.ShapeDtypeStruct(q.shape, q.dtype)],
         interpret=interpret,
         **_vmem(Tk, D, k.dtype.itemsize),
-    )(qf, dof, kf, vf, lsef, delta)
-    unfold = lambda x, T: x.reshape(B, H, T, D).transpose(0, 2, 1, 3)
-    return unfold(dq, Tq), unfold(dk, Tk), unfold(dv, Tk)
+    )(*operands)
+    return dq, _sum_groups(dk, KV, k.dtype), _sum_groups(dv, KV, v.dtype)
 
 
 def _pallas_flash_bwd(causal, sm_scale, block_q, block_k, interpret, window, res, dout):
     q, k, v, out, lse = res
-    Tq, Tk = q.shape[1], k.shape[1]
+    Tq, Tk = q.shape[2], k.shape[2]
     bq, bk = _fit_block(_BWD_BLOCK, Tq), _fit_block(_BWD_BLOCK, Tk)
     use_pallas = (_on_tpu() or interpret) and Tq % bq == 0 and Tk % bk == 0
     if not use_pallas:
@@ -532,7 +530,10 @@ def flash_attention(
     interpret: bool = False,
     window: int = 0,
 ):
-    """Multi-head attention, [B, T, H, D] layout.
+    """Multi-head attention, heads before tokens: q [B, H, Tq, D], k and v [B, KV, Tk, D] with ``H % KV == 0``
+    (grouped queries: head h reads KV head ``h // (H // KV)``; no caller repeats k and v) -> [B, H, Tq, D]. It is
+    the layout the kernels read in place (a head's ``[T, D]`` contiguous); a caller that holds ``[B, T, H, D]``
+    transposes at its own call.
 
     Pallas on TPU; XLA reference elsewhere (or with a bias, which the kernel
     does not support yet). ``window`` > 0 (requires causal) is Mistral-style
@@ -542,18 +543,20 @@ def flash_attention(
     """
     if window and not causal:
         raise ValueError("sliding window requires causal=True")
+    if q.shape[1] % k.shape[1] or k.shape != v.shape:
+        raise ValueError(f"{q.shape[1]} query heads over k {k.shape} and v {v.shape}: not whole groups")
     if sm_scale is None:
         sm_scale = 1.0 / math.sqrt(q.shape[-1])
     block_q = _FWD_BLOCK if block_q is None else block_q
     block_k = _FWD_BLOCK if block_k is None else block_k
     use_pallas = force_pallas if force_pallas is not None else (_on_tpu() or interpret)
-    Tq, Tk = q.shape[1], k.shape[1]
+    Tq, Tk = q.shape[2], k.shape[2]
     bq = _fit_block(block_q, Tq)
     bk = _fit_block(block_k, Tk)
     # Block sizes must tile the sequence exactly: a clamped tail slice would
     # read overlapping rows (and the backward would double-count them).
     if bias is not None or not use_pallas or Tq % bq or Tk % bk:
-        return _xla_attention(q, k, v, causal, sm_scale, bias, window=window)
+        return _swap(_xla_attention(_swap(q), _swap(k), _swap(v), causal, sm_scale, bias, window=window))
     return _pallas_flash(q, k, v, causal, sm_scale, bq, bk, interpret, window)
 
 

@@ -65,9 +65,13 @@ def _pallas_chunk(q, k, v, q_start, k_start, sm_scale: float, interpret: bool):
     three cases decided per device at runtime: k-chunk strictly after the
     q-chunk (fully masked), the diagonal (causal within the chunk), or
     strictly before (no mask)."""
-    from ray_tpu.ops.attention import _pallas_flash_with_lse
+    from ray_tpu.ops.attention import _pallas_flash_with_lse, _swap
 
     B, Tq, H, D = q.shape
+
+    def kernel(q, k, v, causal):  # the kernel reads and writes [B, H, T, D]
+        out, lse = _pallas_flash_with_lse(_swap(q), _swap(k), _swap(v), causal, sm_scale, min(128, Tq), min(128, k.shape[1]), interpret)
+        return _swap(out).astype(jnp.float32), lse
 
     def masked_case(_q, _k, _v):
         return (
@@ -75,13 +79,7 @@ def _pallas_chunk(q, k, v, q_start, k_start, sm_scale: float, interpret: bool):
             jnp.full((B, H, Tq), _NEG_INF, jnp.float32),
         )
 
-    def diag_case(q, k, v):
-        out, lse = _pallas_flash_with_lse(q, k, v, True, sm_scale, min(128, Tq), min(128, k.shape[1]), interpret)
-        return out.astype(jnp.float32), lse
-
-    def full_case(q, k, v):
-        out, lse = _pallas_flash_with_lse(q, k, v, False, sm_scale, min(128, Tq), min(128, k.shape[1]), interpret)
-        return out.astype(jnp.float32), lse
+    diag_case, full_case = functools.partial(kernel, causal=True), functools.partial(kernel, causal=False)
 
     if q_start is None:  # non-causal: every chunk is a plain full block
         return full_case(q, k, v)

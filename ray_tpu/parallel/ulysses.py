@@ -53,8 +53,9 @@ def ulysses_attention(
             return lax.all_to_all(x, axis_name, split_axis=1, concat_axis=2, tiled=True)
 
         qh, kh, vh = seq_to_heads(q_loc), seq_to_heads(k_loc), seq_to_heads(v_loc)
-        out = flash_attention(qh, kh, vh, causal=causal, sm_scale=sm_scale)
-        return heads_to_seq(out)
+        swap = lambda x: x.transpose(0, 2, 1, 3)  # flash_attention's layout is [B, H/n, T, D]
+        out = flash_attention(swap(qh), swap(kh), swap(vh), causal=causal, sm_scale=sm_scale)
+        return heads_to_seq(swap(out))
 
     spec = P(None, axis_name, None, None)
     fn = jax.shard_map(

@@ -82,9 +82,9 @@ def dt_forward(params, rtg, obs, act_onehot, timesteps, n_heads):
     dh = d // n_heads
     for blk in params["blocks"]:
         h = _layernorm(x)
-        qkv = _linear(blk["qkv"], h).reshape(B, 3 * K, 3, n_heads, dh)
-        q, k, v = qkv[:, :, 0], qkv[:, :, 1], qkv[:, :, 2]   # [B,3K,H,dh]
-        o = flash_attention(q, k, v, causal=True)
+        qkv = _linear(blk["qkv"], h).reshape(B, 3 * K, 3, n_heads, dh).transpose(2, 0, 3, 1, 4)
+        q, k, v = qkv[0], qkv[1], qkv[2]                     # [B,H,3K,dh]
+        o = flash_attention(q, k, v, causal=True).transpose(0, 2, 1, 3)
         x = x + _linear(blk["proj"], o.reshape(B, 3 * K, d))
         h = _layernorm(x)
         x = x + _linear(blk["ff2"], jnp.maximum(_linear(blk["ff1"], h), 0.0))

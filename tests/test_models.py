@@ -124,15 +124,76 @@ def test_vit_forward():
     assert out.shape == (2, 10)
 
 
-def test_transformer_ring_attention_path():
+@pytest.mark.parametrize("kv_heads", [4, 2])  # 2: the ring's chunks pair head with head, so K and V are repeated for it
+def test_transformer_ring_attention_path(kv_heads):
     """attn_impl='ring' over an sp mesh matches the dense path."""
-    cfg = tiny_cfg(n_kv_heads=4)
+    cfg = tiny_cfg(n_kv_heads=kv_heads)
     mesh = create_mesh(MeshConfig(sp=4, dp=2))
     params = init_params(jax.random.PRNGKey(0), cfg)
     tokens = jax.random.randint(jax.random.PRNGKey(1), (2, 32), 0, cfg.vocab_size)
     dense, _ = forward(params, tokens, cfg)
     ring, _ = forward(params, tokens, cfg, mesh=mesh, attn_impl="ring")
     np.testing.assert_allclose(np.asarray(dense), np.asarray(ring), atol=2e-4)
+
+
+@pytest.mark.parametrize("kv_heads", [4, 2, 1])
+def test_a_mesh_that_splits_the_heads_further_than_the_kv_heads_go_round(kv_heads):
+    """Under a mesh the attention core runs in a ``shard_map`` over the batch and the heads. A device's query heads
+    must find their KV heads on it: 8 heads over ``tp`` = 4 with 4 KV heads split as they are, with 2 or 1 K and V
+    are repeated up to 4, the least width the mesh divides, and no further. The logits and every gradient are the
+    unsharded model's."""
+    from functools import partial
+
+    cfg = tiny_cfg(n_heads=8, n_kv_heads=kv_heads)
+    mesh = create_mesh(MeshConfig(tp=4, dp=2))
+    params = init_params(jax.random.PRNGKey(0), cfg)
+    tokens = jax.random.randint(jax.random.PRNGKey(1), (2, 33), 0, cfg.vocab_size)
+    want, _ = forward(params, tokens[:, :-1], cfg)
+    got, _ = forward(params, tokens[:, :-1], cfg, mesh=mesh)
+    np.testing.assert_allclose(np.asarray(got), np.asarray(want), atol=2e-4)
+    want_grads = jax.grad(partial(loss_fn, cfg=cfg))(params, {"tokens": tokens})
+    got_grads = jax.grad(partial(loss_fn, cfg=cfg, mesh=mesh))(params, {"tokens": tokens})
+    gaps = jax.tree.map(lambda a, b: float(jnp.linalg.norm(a - b) / jnp.maximum(jnp.linalg.norm(b), 1e-30)), got_grads, want_grads)
+    assert max(jax.tree.leaves(gaps)) <= 1e-4, gaps
+
+
+_YARN = (("factor", 4.0), ("original_max_position_embeddings", 64.0), ("beta_fast", 32.0), ("beta_slow", 1.0), ("mscale", 1.0), ("mscale_all_dim", 0.0))
+
+
+@pytest.mark.parametrize("scaling", [(), _YARN], ids=["plain", "yarn"])
+@pytest.mark.parametrize("dtype", ["bfloat16", "float32"])
+def test_the_training_blocks_rotary_is_the_serving_one(dtype, scaling):
+    """``_rope_rotate`` over the training block's ``[B, H, T, Dh]`` (a head's halves swapped by a signed permutation,
+    tables at the whole width, a cotangent rule of its own) against ``_rope_apply`` over ``[B, T, H, Dh]``, which
+    serving runs and autodiff differentiates: the same values and the same cotangent, bit for bit (each output is
+    the same two float32 products and their sum, rounded once), with plain and with YaRN's tables; and the tables,
+    functions of the positions alone, get no gradient."""
+    import jax
+    import jax.numpy as jnp
+    import numpy as np
+
+    from ray_tpu.models.transformer import _rope_apply, _rope_rotate, _rope_tables, _whole_width
+
+    B, H, T, Dh = 2, 3, 256, 128
+    x, dy = (jax.random.normal(jax.random.PRNGKey(seed), (B, H, T, Dh), jnp.float32).astype(dtype) for seed in (0, 1))
+    positions = jnp.broadcast_to(jnp.arange(T), (B, T)) + jnp.array([[0], [1000]])
+    cos, sin = _rope_tables(positions, Dh, 10000.0, scaling)
+    swap = lambda a: a.transpose(0, 2, 1, 3)
+    want, vjp_want = jax.vjp(lambda a: swap(_rope_apply(swap(a), cos, sin)), x)
+    got, vjp_got = jax.vjp(_rope_rotate, x, *_whole_width(cos, sin))
+    assert got.dtype == want.dtype == x.dtype and float(jnp.abs(want.astype(jnp.float32) - x.astype(jnp.float32)).max()) > 0.5
+    np.testing.assert_array_equal(np.asarray(got.astype(jnp.float32)), np.asarray(want.astype(jnp.float32)))
+    dx, dcos, dsin = vjp_got(dy)
+    np.testing.assert_array_equal(np.asarray(dx.astype(jnp.float32)), np.asarray(vjp_want(dy)[0].astype(jnp.float32)))
+    assert not dcos.any() and not dsin.any()
+
+
+def _flash_tokens_major(q, k, v, **kw):
+    """``flash_attention`` over the reference's ``[B, T, H, D]``: the call itself takes and gives heads before tokens."""
+    from ray_tpu.ops.attention import flash_attention
+
+    swap = lambda x: x.transpose(0, 2, 1, 3)
+    return swap(flash_attention(swap(q), swap(k), swap(v), **kw))
 
 
 def test_pallas_flash_attention_matches_xla_fwd_bwd():
@@ -144,7 +205,7 @@ def test_pallas_flash_attention_matches_xla_fwd_bwd():
     import jax.numpy as jnp
     import numpy as np
 
-    from ray_tpu.ops.attention import _xla_attention, flash_attention
+    from ray_tpu.ops.attention import _xla_attention
 
     rng = np.random.default_rng(0)
     B, T, H, D = 2, 256, 2, 32
@@ -153,14 +214,14 @@ def test_pallas_flash_attention_matches_xla_fwd_bwd():
     )
     for causal in (False, True):
         ref = _xla_attention(q, k, v, causal, 0.125)
-        out = flash_attention(
+        out = _flash_tokens_major(
             q, k, v, causal=causal, sm_scale=0.125,
             force_pallas=True, interpret=True, block_q=128, block_k=128,
         )
         assert float(jnp.abs(out - ref).max()) < 1e-5
 
         def loss_p(q, k, v, _c=causal):
-            return (flash_attention(q, k, v, causal=_c, sm_scale=0.125,
+            return (_flash_tokens_major(q, k, v, causal=_c, sm_scale=0.125,
                                     force_pallas=True, interpret=True) ** 2).sum()
 
         def loss_x(q, k, v, _c=causal):
@@ -179,11 +240,11 @@ def test_flash_attention_odd_lengths_fall_back():
     import jax.numpy as jnp
     import numpy as np
 
-    from ray_tpu.ops.attention import _xla_attention, flash_attention
+    from ray_tpu.ops.attention import _xla_attention
 
     rng = np.random.default_rng(1)
     q = jnp.asarray(rng.standard_normal((1, 100, 2, 16)), jnp.float32)
-    out = flash_attention(q, q, q, causal=True, force_pallas=True, interpret=True)
+    out = _flash_tokens_major(q, q, q, causal=True, force_pallas=True, interpret=True)
     ref = _xla_attention(q, q, q, True, 0.25)
     assert float(jnp.abs(out - ref).max()) < 1e-5
 
@@ -195,14 +256,14 @@ def test_flash_attention_cross_length_causal_alignment():
     import jax.numpy as jnp
     import numpy as np
 
-    from ray_tpu.ops.attention import _xla_attention, flash_attention
+    from ray_tpu.ops.attention import _xla_attention
 
     rng = np.random.default_rng(2)
     q = jnp.asarray(rng.standard_normal((1, 128, 2, 32)), jnp.float32)
     k = jnp.asarray(rng.standard_normal((1, 256, 2, 32)), jnp.float32)
     v = jnp.asarray(rng.standard_normal((1, 256, 2, 32)), jnp.float32)
     ref = _xla_attention(q, k, v, True, 0.125)
-    out = flash_attention(q, k, v, causal=True, sm_scale=0.125,
+    out = _flash_tokens_major(q, k, v, causal=True, sm_scale=0.125,
                           force_pallas=True, interpret=True, block_q=64, block_k=64)
     assert float(jnp.abs(out - ref).max()) < 1e-5
 
@@ -217,7 +278,7 @@ def test_pallas_backward_kernels_vs_oracle(monkeypatch):
     import numpy as np
 
     from ray_tpu.ops import attention
-    from ray_tpu.ops.attention import _xla_attention, flash_attention
+    from ray_tpu.ops.attention import _xla_attention
 
     monkeypatch.setattr(attention, "_BWD_BLOCK", 128)
 
@@ -237,7 +298,7 @@ def test_pallas_backward_kernels_vs_oracle(monkeypatch):
         v = jnp.asarray(rng.standard_normal((2, Tk, 2, 32)), jnp.float32)
 
         def loss_p(q, k, v, _c=causal, _w=window):
-            return (flash_attention(q, k, v, causal=_c, sm_scale=0.2, window=_w,
+            return (_flash_tokens_major(q, k, v, causal=_c, sm_scale=0.2, window=_w,
                                     force_pallas=True, interpret=True,
                                     block_q=64, block_k=64) ** 2).sum()
 

@@ -43,7 +43,8 @@ def test_logical_to_spec():
 def test_flash_attention_matches_reference(qkv):
     q, k, v = qkv
     ref = _xla_attention(q, k, v, True, q.shape[-1] ** -0.5)
-    out = flash_attention(q, k, v, causal=True, interpret=True, block_q=32, block_k=32)
+    swap = lambda x: x.transpose(0, 2, 1, 3)  # the call takes and gives heads before tokens
+    out = swap(flash_attention(swap(q), swap(k), swap(v), causal=True, interpret=True, block_q=32, block_k=32))
     np.testing.assert_allclose(np.asarray(out), np.asarray(ref), atol=2e-5)
 
 

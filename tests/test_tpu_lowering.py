@@ -38,7 +38,7 @@ CASES = [
     (1, 4096, 32, 128, True, 4096),
 ]
 for B, T, H, D, causal, window in CASES:
-    q = jax.ShapeDtypeStruct((B, T, H, D), jnp.bfloat16)
+    q = jax.ShapeDtypeStruct((B, H, T, D), jnp.bfloat16)
     attn = lambda q, k, v: flash_attention(
         q, k, v, causal=causal, window=window, force_pallas=True)
     jax.jit(attn).lower(q, q, q).compile()
@@ -279,8 +279,12 @@ def test_the_dp4_cell_train_step_gathers_nothing_on_the_v5e_host(v5e_host):
         stats.argument_size_in_bytes + stats.output_size_in_bytes
         - stats.alias_size_in_bytes + stats.temp_size_in_bytes
     )
-    # 14.347 GB since PR 51 (each of the two layers keeps its q, k, v, attention output and log-sum-exp, 84 MB a layer;
-    # 14.242 until then, as before PR 36): 8.38 resident + 5.97 of temporaries
+    # 14.274 GB since PR 53 (no transposed copies of q, k, v and the output, no k and v at H heads; a projection is
+    # ``h @ w`` with only its RESULT heads-major, so that its weight's gradient leaves the matmul in the leaf's
+    # [D, H * Dh]: over ``w`` seen as [D, H, Dh] the compiler relaid the gradients of wq, wk and wv in float32 under
+    # this mesh before it stacked them, 67 + 2 x 17 MB of temporaries more, 14.442 GB); 14.347 since PR 51 (each of
+    # the two layers keeps its q, k, v, attention output and log-sum-exp, 84 MB a layer; 14.242 until then, as before
+    # PR 36): 8.38 resident + 5.89 of temporaries
     assert in_use < 14.4e9, in_use
 
 
@@ -455,6 +459,118 @@ def test_a_scanned_slice_of_an_expert_stack_is_copied_and_a_whole_stack_is_not(o
             compiled.memory_analysis().temp_size_in_bytes >= one_layer,
         )
     assert copied[sliced] == (True, True) and copied[whole] == (False, False)
+
+
+def _train_step_for_the_v5e(cfg, batch, one_chip, monkeypatch):
+    """``make_train_step`` (AdamW, donated) with the flash kernels, for the described chip: (lowered, compiled)."""
+    import importlib
+
+    import jax
+    import jax.numpy as jnp
+    import optax
+
+    from ray_tpu.models.transformer import init_params, make_train_step
+
+    monkeypatch.setattr(importlib.import_module("ray_tpu.ops.attention"), "_on_tpu", lambda: True)
+    on = lambda tree: jax.tree.map(lambda a: jax.ShapeDtypeStruct(a.shape, a.dtype, sharding=one_chip), tree)
+    params = on(jax.eval_shape(lambda: init_params(jax.random.PRNGKey(0), cfg)))
+    opt = optax.adamw(1e-4)
+    tokens = jax.ShapeDtypeStruct((batch, cfg.max_seq_len + 1), jnp.int32, sharding=one_chip)
+    lowered = jax.jit(make_train_step(cfg, opt), donate_argnums=(0, 1)).lower(params, on(jax.eval_shape(opt.init, params)), {"tokens": tokens})
+    return lowered, lowered.compile()
+
+
+def _flash_calls(lowered_text: str) -> dict:
+    """kernel name -> the calls' (operand types, result types) as the lowered text writes them."""
+    import re
+
+    calls = {}
+    for line in lowered_text.splitlines():
+        if "stablehlo.custom_call @tpu_custom_call" in line:
+            name = re.search(r'kernel_name = "(\w+)"', line).group(1)
+            operands, results = re.search(r"\} : \((.*?)\) -> (.*)$", line).groups()
+            calls.setdefault(name, []).append((re.findall(r"tensor<([^>]+)>", operands), re.findall(r"tensor<([^>]+)>", results)))
+    return calls
+
+
+def _moved_whole(compiled_text: str, shapes: set) -> list:
+    """The compiled program's ``copy`` and ``transpose`` instructions (outside fusions: each runs, reads its operand
+    and writes it again) over a tensor whose axes, ones aside and in any order, are one of ``shapes``."""
+    import re
+
+    wanted = {tuple(sorted(d for d in shape if d != 1)) for shape in shapes}
+    found, inside_fusion = [], False
+    for line in compiled_text.splitlines():
+        if not line.startswith(" "):
+            inside_fusion = line.startswith("%fused_computation") or line.startswith("fused_computation")
+            continue
+        m = None if inside_fusion else re.match(r"\s+(?:ROOT )?%[\w.\-]+ = \w+\[([\d,]*)\]\S* (copy|transpose)\(", line)
+        if m and tuple(sorted(int(d) for d in m.group(1).split(",") if d and d != "1")) in wanted:
+            found.append(line.strip()[:240])
+    return found
+
+
+# What ``_attention_block`` hands the three flash kernels (PR 53), at Mistral-7B's widths (``train2``'s configuration
+# at its 4096 tokens; TWO rows, so that a q-sized tensor is no matrix's size: wo is [32, 128, 4096] too) and at a
+# toy of Mellum's shape (a pattern of window and full layers, norms of q and k, YaRN, 8 query heads a KV head).
+_HEADS_MAJOR_STEPS = {
+    "train2": dict(batch=2, H=32, KV=8, T=4096, layers=1),
+    "mellum-shaped": dict(batch=2, H=8, KV=1, T=512, layers=2),
+}
+
+
+@pytest.mark.parametrize("which", sorted(_HEADS_MAJOR_STEPS))
+def test_the_train_step_hands_the_flash_kernels_q_k_v_where_the_layer_holds_them(which, one_v5e_chip, monkeypatch):
+    """The counter that says PR 53's change engaged. In the step lowered for a TPU every call of the three flash
+    kernels takes q, the output, its cotangent and dq as ``[B, H, T, Dh]`` and k and v as ``[B, KV, T, Dh]``, the
+    layout the projections' matmuls write and ``wo``'s reads; nothing broadcasts k or v to H heads, and a query
+    head's dk and dv are summed over its group by ONE reduction a tensor. The lowered text still holds a
+    projection's own transpose of its matmul's result, which the compiler folds into the matmul; the COMPILED step
+    is what must hold no ``copy`` and no ``transpose`` of a q-sized or a k-sized tensor in the forward pass, and in
+    the backward pass none but the cotangents of q, k and v as their projections' weight gradients read them (tokens
+    on the lanes: the price of a gradient that leaves its matmul in the leaf's ``[D, H * Dh]``; at most three a
+    layer. The parent's holds ten a layer at Mellum's widths: q into ``[B*H, T, Dh]``, the output back, and q, k, v,
+    the output and its cotangent again in the backward pass, 134 MB each; and a float32 relayout of the output for
+    ``delta``, 268 MB)."""
+    import jax.numpy as jnp
+
+    from benchmarks.harness import registry
+    from ray_tpu.models.transformer import TransformerConfig
+
+    want = _HEADS_MAJOR_STEPS[which]
+    B, H, KV, T, Dh = want["batch"], want["H"], want["KV"], want["T"], 128
+    if which == "train2":
+        cell = registry.load_cell(registry.load_manifest(), "train2.dense-4k")
+        model = registry.load_architecture(cell, "config").model_config(cell["config"], T, "float32")
+        for key in ("dtype", "param_dtype"):
+            model[key] = jnp.dtype(model[key]).type
+        cfg = TransformerConfig(**model, remat=True, fused_loss=True)
+    else:
+        cfg = TransformerConfig(
+            vocab_size=1024, d_model=384, n_layers=4, n_heads=H, n_kv_heads=KV, head_dim=Dh, d_ff=256, max_seq_len=T, sliding_window=256,
+            layer_kinds=("window", "full") * 2, qk_norm=True, dtype=jnp.bfloat16, remat=True, fused_loss=True,
+            rope_scaling=(("factor", 4.0), ("original_max_position_embeddings", 128.0), ("beta_fast", 32.0), ("beta_slow", 1.0), ("mscale", 1.0), ("mscale_all_dim", 0.0)),
+            num_experts=8, experts_per_token=2, d_expert=128, router_score="softmax", router_bias=False,
+        )
+    assert (cfg.n_heads, cfg.n_kv_heads, cfg.head_dim) == (H, KV, Dh)
+    lowered, compiled = _train_step_for_the_v5e(cfg, B, one_v5e_chip, monkeypatch)
+    text = lowered.as_text()
+    q, kv, row = f"{B}x{H}x{T}x{Dh}xbf16", f"{B}x{KV}x{T}x{Dh}xbf16", f"{B}x{H}x1x{T}xf32"
+    calls = _flash_calls(text)
+    assert {name: len(of) for name, of in calls.items()} == dict.fromkeys(("_flash_kernel", "_flash_bwd_dkv_kernel", "_flash_bwd_dq_kernel"), want["layers"])
+    for operands, results in calls["_flash_kernel"]:
+        assert operands == [q, kv, kv] and results == [q, row]  # the log-sum-exp leaves the kernel lane-major, no lane replicated
+    for operands, results in calls["_flash_bwd_dkv_kernel"]:
+        assert operands == [q, q, kv, kv, row, row] and results == [q, q]  # a query head's dk and dv each
+    for operands, results in calls["_flash_bwd_dq_kernel"]:
+        assert operands == [q, q, kv, kv, row, row] and results == [q]
+    # k and v are never seen at H heads (``jnp.repeat`` is a broadcast into [.., KV, rep, ..]); the only tensors of
+    # that shape are dk and dv on their way into the one sum over a group's query heads
+    grouped = [line for line in text.splitlines() if f"-> tensor<{B}x{KV}x{H // KV}x{T}x{Dh}x" in line]
+    assert "stablehlo.broadcast_in_dim" in text and f"{B}x{T}x{KV}x{H // KV}x{Dh}x" not in text
+    assert len(grouped) == 4 * want["layers"] and not any("broadcast" in line for line in grouped)  # a reshape and a convert each
+    moved = _moved_whole(compiled.as_text(), {(B, H, T, Dh), (B, KV, T, Dh), (B * H, T, Dh), (B * KV, T, Dh)})
+    assert len(moved) <= 3 * want["layers"] and all("/transpose(jvp())/" in line for line in moved), moved
 
 
 def _cell_config(cell_name: str):
@@ -891,10 +1007,11 @@ _TPU_PROGRAMS_OF_PR_49 = {
 # And of Xing4.0's two programs as a CPU backend gets them, which ``_PROGRAMS_OF_PR_34`` lacks.
 _PROGRAMS_OF_PR_49 = {"xing6.longdoc-12k": ("338740d7603432f4faf41e8dec89afe6446efa2d", "4b7743fb4648293d40309ee3c41b4c2191bc95d7")}
 # The train step of ``train2.dense-4k`` (Mistral-7B at 2 layers, T = 4096, batch 1, AdamW, donated) with the flash
-# kernels, and as a CPU backend lowers it; the Switch layer's at a toy size. PR 51 moved all three on purpose (the test's
-# docstring says by what); until then they were PR 49's: e13c36b6..., bf81db40..., 354aa964... .
-_TRAIN_STEPS_OF_PR_51 = {
-    "train2@tpu": "c74918bacc9e41f0074ddf9f5a1c93c8d3b20f10", "train2@cpu": "d5d1938139dd8f962b6e3e34b4e828d84e36f2cc", "switch": "74e29155e4dcddca5c4929b7a306d50e37b0bf31",
+# kernels, and as a CPU backend lowers it; the Switch layer's at a toy size. PR 53 moved all three on purpose (the test's
+# docstring says by what); until then they were PR 51's: c74918ba..., d5d19381..., 74e29155... (PR 49's before: e13c36b6...,
+# bf81db40..., 354aa964...).
+_TRAIN_STEPS_OF_PR_53 = {
+    "train2@tpu": "c45250bf9f96f9448e08bf836c04eb843037d2db", "train2@cpu": "11c51b6d394c41bda0251463dbbc890a78629f13", "switch": "5cf04e44eec22de9c8d7f7a1e642e2a502befc43",
 }
 
 
@@ -938,7 +1055,7 @@ def test_the_seventh_configurations_cpu_programs_are_the_parents(cell_name):
     assert tuple(_digest(t, kernels=False) for t in texts) == _PROGRAMS_OF_PR_49[cell_name]
 
 
-@pytest.mark.parametrize("which", sorted(_TRAIN_STEPS_OF_PR_51))
+@pytest.mark.parametrize("which", sorted(_TRAIN_STEPS_OF_PR_53))
 def test_the_accepted_train_steps_are_the_parents(which, monkeypatch):
     """PR 50 gave the training block a pattern of layers, a rotary table a kind,
     routed experts and a balance coefficient: a configuration that states none of
@@ -947,8 +1064,14 @@ def test_the_accepted_train_steps_are_the_parents(which, monkeypatch):
     q, k, v and the attention core's results (``transformer._KEPT_UNDER_REMAT``),
     so the backward scan's body holds no second forward of the attention core
     (on a TPU: no second ``_flash_kernel``, counted in ``tests/test_models.py``)
-    nor the projections, norms and rotary that fed it. Later PRs that leave the
-    training block alone keep these texts."""
+    nor the projections, norms and rotary that fed it. PR 53 moved all three
+    again, by ONE thing: ``_attention_block`` holds q, k, v heads before tokens,
+    ``[B, H or KV, T, Dh]``, from the projections (``h @ w``, the result
+    transposed, which the compiler folds into the matmul) to ``wo`` (the rotary
+    as a signed permutation on the MXU, K and V never repeated), which is where
+    the flash kernels read them: the CPU texts moved with the TPU's because the
+    block is one program for both, only the attention core differs. Later PRs
+    that leave the training block alone keep these texts."""
     import importlib
 
     import jax
@@ -973,4 +1096,4 @@ def test_the_accepted_train_steps_are_the_parents(which, monkeypatch):
     opt = optax.adamw(1e-4)
     step = jax.jit(make_train_step(cfg, opt), donate_argnums=donate)
     text = step.trace(params, jax.eval_shape(opt.init, params), {"tokens": jax.ShapeDtypeStruct(tokens, jnp.int32)}).lower(lowering_platforms=("tpu",)).as_text()
-    assert _digest(text, kernels=which == "train2@tpu") == _TRAIN_STEPS_OF_PR_51[which]
+    assert _digest(text, kernels=which == "train2@tpu") == _TRAIN_STEPS_OF_PR_53[which]
