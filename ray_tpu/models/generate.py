@@ -105,29 +105,60 @@ def _cache_heads(cfg: TransformerConfig) -> int:
     return KV
 
 
+def _heads_paired(cfg: TransformerConfig) -> bool:
+    """TWO KV heads in one cached row, ``[head 2i | head 2i + 1]``, where two fill
+    the TPU's 128 lanes and one does not: heads 64 wide, in the groups of a
+    layer pattern (gathered as views; a pool of one group keeps the rows
+    ``ops/paged_attention.py``'s kernel reads). A leaf ``[.., KV, 64]`` is laid
+    out one way round for the gather and another for the scatter, and the whole
+    pool is copied between them, six times a decode step at LFM2-24B-A2B's
+    widths (2 x 1.07 GB a copy, 4.4 GB of temporaries; compiled for the v5e off
+    the chip, PR 54: the trap of ``_latent_row_width`` and ``_cache_heads``
+    again). Paired, nothing is padded and a token's bytes are the same; a query
+    head carries zeros beside its own KV head's half of the row
+    (``_project_qkv``), and of its weighted sum over such rows its half is kept
+    (``_paired_half``)."""
+    return bool(cfg.layer_kinds) and not cfg.latent_attention and 2 * cfg.head_dim == _LANES and cfg.n_kv_heads % 2 == 0
+
+
+def _second_of_pair(cfg: TransformerConfig):
+    """[H] bool: the query heads whose KV head is the second of its cached row (``_heads_paired``)."""
+    return (jnp.arange(cfg.n_heads) // (cfg.n_heads // cfg.n_kv_heads)) % 2 == 1
+
+
+def _paired_half(o, cfg: TransformerConfig):
+    """o [B, T, H, 2 Dh], weighted sums over rows of two heads' values -> [B, T, H, Dh], each head's own half."""
+    Dh = cfg.head_dim
+    return jnp.where(_second_of_pair(cfg)[:, None], o[..., Dh:], o[..., :Dh])
+
+
 def _cache_rows(cfg: TransformerConfig) -> dict:
     """What one token leaves in one layer of a cache, leaf name -> trailing
-    shape: keys and values per KV head, or (latent attention) one leaf holding
-    the normed latent followed by the rotary key, as the absorbed form reads them."""
+    shape: keys and values per KV head (per two heads where ``_heads_paired``),
+    or (latent attention) one leaf holding the normed latent followed by the
+    rotary key, as the absorbed form reads them."""
     if cfg.latent_attention:
         return {"ckv": (_latent_row_width(cfg),)}
+    if _heads_paired(cfg):
+        return {"k": (cfg.n_kv_heads // 2, 2 * cfg.head_dim), "v": (cfg.n_kv_heads // 2, 2 * cfg.head_dim)}
     return {"k": (_cache_heads(cfg), cfg.head_dim), "v": (_cache_heads(cfg), cfg.head_dim)}
 
 
-_WINDOW, _FULL, _LINEAR, _MAMBA, _EXPERTS = "window", "full", "linear", "mamba", "experts"
+_WINDOW, _FULL, _LINEAR, _MAMBA, _EXPERTS, _CONV = "window", "full", "linear", "mamba", "experts", "conv"
 
 # THE table of kinds: kind of layer (None: every layer of a model without a
 # pattern) -> (the suffix of the names of its group of cache leaves; how a call
 # reaches the group). ``"table"``: a row's block table (a dense cache: the row
 # itself), growing with the row, leaves ``_cache_rows``; ``"ring"``: the same
 # leaves, named ``k_win`` / ``v_win``, of which a paged row holds no more than
-# a ring (``_ring_access``); ``"state"``: no token's rows, one recurrent state a
-# serving slot (``state_rows``, ``_StateAccess``); None: the kind caches nothing.
+# a ring (``_ring_access``); ``"state"``: no token's rows, what a serving slot
+# carries whatever its row's length (``state_rows``, ``_StateAccess``: a
+# recurrent state, a short convolution's last rows); None: the kind caches nothing.
 # A new kind is a row here, its stack in ``transformer._layer_stacks``, its
 # mixer, its ``state_rows`` and its branch of ``_cached_layers``' ``run_layer``.
 _KINDS = {
     None: ("", "table"), _FULL: ("", "table"), _WINDOW: ("_win", "ring"),
-    _LINEAR: ("", "state"), _MAMBA: ("", "state"), _EXPERTS: (None, None),
+    _LINEAR: ("", "state"), _MAMBA: ("", "state"), _EXPERTS: (None, None), _CONV: ("", "state"),
 }
 
 
@@ -158,26 +189,31 @@ class _Segment(NamedTuple):
 
 def _layer_plan(cfg: TransformerConfig) -> list:
     """The segments of the layer stack in the order the model runs them, from
-    ``transformer._layer_stacks``: stacks that each hold one kind are ONE
-    segment of all the layers, the kinds interleaved as ``layer_kinds`` says;
-    any other stack (the leading dense layers, then the rest) is a segment of
-    its own, its kinds sharing it."""
+    ``transformer._layer_stacks``: a stack that holds no one kind (the leading
+    dense layers, the layers of a model without stacks by kind) is a segment of
+    its own, its kinds sharing it; the stacks that each hold one kind are ONE
+    segment of all the layers behind those, the kinds interleaved as
+    ``layer_kinds`` says. A kind's group of cache leaves is as deep as the
+    model has layers of the kind, whichever segments they lie in."""
     stacks = _layer_stacks(cfg)
     layers = lambda kind: cfg.layer_kinds.count(kind) if kind else cfg.n_layers  # noqa: E731
-    own = {of.kind: _Kind(name, True, layers(of.kind), *_KINDS[of.kind]) for name, of in stacks.items() if of.kind}
-    if own:
-        return [_Segment(0, cfg.n_layers, cfg.layer_kinds, own)]
     plan, first = [], 0
     for name, of in stacks.items():
+        if of.kind:
+            continue
         kinds = cfg.layer_kinds[first : first + of.depth]
         rows = {kind: _Kind(name, False, layers(kind), *_KINDS[kind]) for kind in dict.fromkeys(kinds or (None,))}
         plan.append(_Segment(first, of.depth, kinds, rows))
         first += of.depth
+    own = {of.kind: _Kind(name, True, layers(of.kind), *_KINDS[of.kind]) for name, of in stacks.items() if of.kind}
+    if own:
+        plan.append(_Segment(first, cfg.n_layers - first, cfg.layer_kinds[first:], own))
     return plan
 
 
 def _kinds(cfg: TransformerConfig) -> dict:
-    """Kind -> its row of ``_layer_plan``, over all segments."""
+    """Kind -> its row of ``_layer_plan``, over all segments (of a kind that lies
+    in two, the later one's: the group and how it is reached are the same)."""
     return {kind: row for segment in _layer_plan(cfg) for kind, row in segment.rows.items()}
 
 
@@ -197,8 +233,9 @@ def pool_reach(cfg: TransformerConfig) -> set:
 
 
 def state_kind(cfg: TransformerConfig):
-    """The kind of layer whose recurrent state a serving slot keeps
-    (``state_rows``): ``"linear"``, ``"mamba"``, or None without one."""
+    """The kind of layer of which a serving slot keeps something whatever its
+    row's length (``state_rows``): ``"linear"``, ``"mamba"``, ``"conv"``, or
+    None without one."""
     return next((kind for kind, row in _kinds(cfg).items() if row.reach == "state"), None)
 
 
@@ -209,7 +246,11 @@ def state_rows(cfg: TransformerConfig) -> dict:
     ``linear_conv - 1`` rows of the query / key / value projection, which the
     next token's convolution reads. A Mamba-2 block's: the state [heads, head
     channels, ssm_state] in float32 and the last ``mamba_conv - 1`` rows of the
-    convolution's channels (x, B and C ahead of it). Empty without such layers."""
+    convolution's channels (x, B and C ahead of it). A gated short convolution's:
+    the last ``conv_cache - 1`` rows of ``B * u`` and no state. Empty without
+    such layers."""
+    if _CONV in cfg.layer_kinds:
+        return {"conv": ((cfg.conv_cache - 1, cfg.d_model), cfg.dtype)}
     if _MAMBA in cfg.layer_kinds:
         Hm, P, N = cfg.mamba_heads, cfg.mamba_head_dim, cfg.ssm_state
         return {
@@ -242,7 +283,10 @@ def cache_token_bytes(cfg: TransformerConfig) -> dict:
     return {kind or _FULL: _group_bytes(cfg, row) for kind, row in _kinds(cfg).items() if row.reach in ("table", "ring")}
 
 
-_NO_DENSE_CACHE = {_LINEAR: "linear-attention layers", _MAMBA: "Mamba-2 state-space blocks", _EXPERTS: "single-mixer blocks"}
+_NO_DENSE_CACHE = {
+    _LINEAR: "linear-attention layers", _MAMBA: "Mamba-2 state-space blocks", _EXPERTS: "single-mixer blocks",
+    _CONV: "gated short-convolution layers",
+}
 
 
 def init_cache(cfg: TransformerConfig, batch: int, max_len: int):
@@ -270,7 +314,8 @@ def init_cache(cfg: TransformerConfig, batch: int, max_len: int):
 
 
 def _project_qkv(lp, x, positions, cfg, rope: tuple | None = ()):
-    """GQA: (q [B, T, H, Dh], the rows to cache {"k", "v"}: [B, T, KV, Dh]).
+    """GQA: (q [B, T, H, Dh], the rows to cache {"k", "v"}: [B, T, KV, Dh]; where
+    ``_heads_paired``, q [B, T, H, 2 Dh] and rows [B, T, KV / 2, 2 Dh]).
     ``cfg.qk_norm``: queries and keys normed over the head's width first.
     ``rope``: what ``transformer.layer_rope`` says of the layer's kind, None for
     no positional encoding, else the scaling of its rotary tables (() plain)."""
@@ -289,9 +334,13 @@ def _project_qkv(lp, x, positions, cfg, rope: tuple | None = ()):
         q, k = _rms_norm(q, lp["q_norm"], cfg.norm_eps), _rms_norm(k, lp["k_norm"], cfg.norm_eps)
     if _cache_heads(cfg) != KV:  # zero heads up to what a cached row holds
         q, k, v = (jnp.pad(a, ((0, 0), (0, 0), (0, _cache_heads(cfg) - KV), (0, 0))) for a in (q, k, v))
-    if rope is None:
-        return q, {"k": k, "v": v}
-    return _rope(q, positions, cfg.rope_theta, rope), {"k": _rope(k, positions, cfg.rope_theta, rope), "v": v}
+    if rope is not None:
+        q, k = _rope(q, positions, cfg.rope_theta, rope), _rope(k, positions, cfg.rope_theta, rope)
+    if _heads_paired(cfg):  # a row of two heads, and each query zeros beside its own head's half
+        zeros = jnp.zeros_like(q)
+        q = jnp.where(_second_of_pair(cfg)[:, None], jnp.concatenate([zeros, q], axis=-1), jnp.concatenate([q, zeros], axis=-1))
+        k, v = (a.reshape(B, T, KV // 2, 2 * Dh) for a in (k, v))
+    return q, {"k": k, "v": v}
 
 
 def _latent_kv_up(lp, cfg):
@@ -809,6 +858,31 @@ def _linear_mixer(lp, x, pool, at, acc: _StateAccess, cfg):
     return o * jax.nn.silu(h @ lp["wg_lin"].astype(h.dtype)), pool
 
 
+def _conv_mixer(lp, x, pool, at, acc: _StateAccess, cfg):
+    """A gated short convolution over layer ``at`` of the state group: x [B, q, D]
+    -> (the mixer's output [B, q, D], the pool with the rows' carried rows moved on).
+
+    ``[B | C | u] = h w_in`` of the normed input h; ``v = B * u``; each channel
+    of v through a causal filter over the last ``conv_cache`` tokens (those
+    before the chunk are the slot's carried rows, zeros ahead of a request's
+    first token), no bias and no activation; ``y = C * conv(v)``; ``y wo``. The
+    filter and the gate are float32."""
+    B, q, D = x.shape
+    K = cfg.conv_cache
+    f32 = jnp.float32
+    with jax.named_scope("short_conv_step" if q == 1 else "short_conv_chunk"):
+        h = _rms_norm(x, lp["attn_norm"], cfg.norm_eps)
+        gate_in, gate_out, u = jnp.split(h @ lp["w_in"].astype(h.dtype), 3, axis=-1)
+        # Rounded to its dtype HERE, whatever the compiler fuses the product into: the filter must read of a token
+        # that is the call's own what it will read of it as a carried row of the slot, in the next call.
+        v = lax.reduce_precision(gate_in * u, jnp.finfo(u.dtype).nexp, jnp.finfo(u.dtype).nmant)
+        seen = jnp.concatenate([_slot_rows(pool, "conv", at, acc, B), v], axis=1)  # [B, K - 1 + q, D]: row j + K - 1 is token j
+        taps = lp["conv_w"].astype(f32)
+        y = gate_out.astype(f32) * sum(seen[:, i : i + q].astype(f32) * taps[i] for i in range(K))
+        pool = {**pool, "conv": _put_slot_rows(pool, "conv", at, acc, _rows_to_carry(seen, acc.n_valid, K))}
+        return y.astype(x.dtype) @ lp["wo"].astype(x.dtype), pool
+
+
 def _cached_layers(params, x, cache, positions, access, cfg, key_len=None, valid=None, parts=None):
     """THE layer stack over a cache, dense or paged: x [B, q, D] (``_embed_chunk``'s: under
     hyper-connections the stream, [B, q, hc_mult * D], which the layer scan carries) at
@@ -835,7 +909,8 @@ def _cached_layers(params, x, cache, positions, access, cfg, key_len=None, valid
 
     Which stacks of ``params`` run, in what order and which kind lies where
     is ``_layer_plan``'s to say; a layer's index into its group counts through
-    the segments. A segment without a pattern is one homogeneous scan over
+    the segments (a conv layer among the leading dense layers is the first of
+    its kind's group, whichever stack holds it). A segment without a pattern is one homogeneous scan over
     its layers. One with a pattern is scanned over the PERIODS of its kinds
     (``_period``): the kind of a layer, which decides its mask, its rotary and
     its group, is static inside the body, which runs one period; what is left
@@ -875,15 +950,16 @@ def _cached_layers(params, x, cache, positions, access, cfg, key_len=None, valid
                 out.append(o.reshape(1, -1, o.shape[2] * o.shape[3]))
         return jnp.concatenate(out, axis=1), pool
 
-    def record_choice(pool, chosen, l, first):
+    def record_choice(pool, chosen, l, first, nth):
         """The pool with the experts ``chosen`` [B, q, k] beside the rows' tokens
-        in expert layer ``l - first`` of ``MOE_CHOICE``, where the pool keeps them."""
+        in expert layer ``nth`` (None: ``l - first``) of ``MOE_CHOICE``, where the pool keeps them."""
         if chosen is None or MOE_CHOICE not in pool:
             return pool
+        layer = lambda: l - first if nth is None else nth  # noqa: E731  (where it is used: an accepted program's operations keep their order)
         put = access["table"].write
         if n_words == 1:
             words = jnp.sum(chosen << (_expert_bits(cfg) * jnp.arange(chosen.shape[-1])), axis=-1)
-            return {**pool, MOE_CHOICE: put(pool[MOE_CHOICE], l - first, words)}
+            return {**pool, MOE_CHOICE: put(pool[MOE_CHOICE], layer(), words)}
         # [words, expert layers, ...] written as [words * expert layers, ...]
         leaf = pool[MOE_CHOICE]
         flat = leaf.reshape(-1, *leaf.shape[2:])
@@ -891,14 +967,21 @@ def _cached_layers(params, x, cache, positions, access, cfg, key_len=None, valid
         for w in range(n_words):
             ids = chosen[..., w * per_word : (w + 1) * per_word]
             word = jnp.sum(ids << shifts[: ids.shape[-1]], axis=-1)
-            flat = put(flat, w * leaf.shape[1] + (l - first), word)
+            flat = put(flat, w * leaf.shape[1] + layer(), word)
         return {**pool, MOE_CHOICE: flat.reshape(leaf.shape)}
 
-    def run_layer(x, pool, lp, kind, at, l, first, held):
+    def run_layer(x, pool, lp, kind, at, l, first, held, nth=None):
         """One layer of kind ``kind`` (None: no pattern), layer ``at`` of its
-        group, layer ``l - first`` of its stack."""
+        group, layer ``l - first`` of its stack and, where its stack's layers
+        have routed experts, the ``nth`` of the model's layers that have (None:
+        ``l - first`` too, the one stack that has them)."""
         row = kinds_of[kind]
         acc, sfx = access.get(row.reach) if access else None, row.group
+
+        def then_mlp(x, pool):  # the layer's MLP, and the experts it took kept beside the rows' tokens
+            x, sent, chosen = _mlp(lp, x, cfg, valid, layer=l - first if held else None)
+            return x, record_choice(pool, chosen, l, first, nth), sent
+
         if kind == _MAMBA:  # a block that is this mixer and nothing else
             def mamba(u):
                 o, moved = _mamba_mixer(lp, u, pool, at, acc, cfg)
@@ -906,8 +989,7 @@ def _cached_layers(params, x, cache, positions, access, cfg, key_len=None, valid
 
             return *_residual(lp, x, "attn", cfg, mamba), None
         if kind == _EXPERTS:  # a block that is its experts and nothing else
-            x, sent, chosen = _mlp(lp, x, cfg, valid, layer=l - first if held else None)
-            return x, record_choice(pool, chosen, l, first), sent
+            return then_mlp(x, pool)
 
         def post(a):  # ``cfg.post_norms``: the branch is normed once more before it joins the residual
             return _rms_norm(a, lp["attn_post_norm"], cfg.norm_eps) if cfg.post_norms else a
@@ -919,6 +1001,8 @@ def _cached_layers(params, x, cache, positions, access, cfg, key_len=None, valid
 
             x, pool = _residual(lp, x, "attn", cfg, linear)
             return _mlp(lp, x, cfg)[0], pool, None
+        if kind == _CONV:
+            return then_mlp(*_residual(lp, x, "attn", cfg, lambda u: _conv_mixer(lp, u, pool, at, acc, cfg)))
 
         def attention(u, pool=pool):
             project = _project_latent if latent else partial(_project_qkv, rope=layer_rope(cfg, kind))
@@ -940,7 +1024,8 @@ def _cached_layers(params, x, cache, positions, access, cfg, key_len=None, valid
                         if latent:
                             o = _latent_attention(lp, qh, seen, mask, cfg)
                         else:
-                            o = _cache_attention(qh, seen["k"], seen["v"], mask, cfg).reshape(B, q, -1)
+                            o = _cache_attention(qh, seen["k"], seen["v"], mask, cfg)
+                            o = (_paired_half(o, cfg) if _heads_paired(cfg) else o).reshape(B, q, -1)
             if cfg.attn_gate:
                 gate = _rms_norm(u, lp["attn_norm"], cfg.norm_eps) @ lp["wg_attn"].astype(u.dtype)
                 o = o * jax.nn.sigmoid(gate)
@@ -949,8 +1034,7 @@ def _cached_layers(params, x, cache, positions, access, cfg, key_len=None, valid
         x, pool = _residual(lp, x, "attn", cfg, attention)
         if cfg.single_mixer:  # an attention block: no MLP behind it
             return x, pool, None
-        x, sent, chosen = _mlp(lp, x, cfg, valid, layer=l - first if held else None)
-        return x, record_choice(pool, chosen, l, first), sent
+        return then_mlp(x, pool)
 
     def body(first, held, carry, layer):
         x, pool = carry
@@ -958,10 +1042,11 @@ def _cached_layers(params, x, cache, positions, access, cfg, key_len=None, valid
         x, pool, sent = run_layer(x, pool, {**lp, **held}, None, l, l, first, held)
         return (x, pool), sent
 
-    def scan_periods(first, held, stacks, kinds, x, pool):
-        """Layers ``first..`` of the model under a layer pattern, of ``kinds``.
+    def scan_periods(segment, held, stacks, x, pool):
+        """The layers of ``segment``, a run of the model's under a layer pattern.
         ``stacks``: by kind, the stack of leaves that holds the kind's layers:
-        its own, or one that the segment's kinds share (``_Kind.own``). One
+        its own, or one that the segment's kinds share (``_Kind.own``), and
+        ``held`` by kind what of it the scans do not slice. One
         scan over the PERIODS; inside a period a run of layers
         of one kind that lie in a stack of their own is a scan of its own
         (three linear layers: one body, not three), any other layer a call (a
@@ -970,14 +1055,19 @@ def _cached_layers(params, x, cache, positions, access, cfg, key_len=None, valid
         which the scans close over: handed to the outer scan as xs, a period's
         slice of every matrix is copied out before an inner scan may read it
         (15 ms of a 46 ms decode step at Olmo-Hybrid's widths, v5e, PR 41)."""
+        first, kinds, rows = segment.first, segment.kinds, segment.rows
         P = _period(kinds)
         before = {kind: cfg.layer_kinds[:first].count(kind) for kind in set(kinds)}
         a_period = {kind: kinds[:P].count(kind) for kind in set(kinds)}
         runs, j = [], 0  # (first layer, layers) of each run of one kind through one period
         while j < P:
-            n = next((i for i in range(j, P) if kinds[i] != kinds[j]), P) - j if kinds_of[kinds[j]].own else 1
+            n = next((i for i in range(j, P) if kinds[i] != kinds[j]), P) - j if rows[kinds[j]].own else 1
             runs.append((j, n))
             j += n
+        # Stacks of their own of which more than one holds routed experts: a layer's index among the model's layers that
+        # have them (``MOE_CHOICE``, the counters) is then not its index in its stack.
+        routed = [kind for kind in rows if "gate" in stacks[kind] and rows[kind].own]
+        routed = routed if len(routed) > 1 else []
 
         def take(stack, index):
             return {n: lax.dynamic_index_in_dim(leaf, index, 0, keepdims=False) for n, leaf in stack.items()}
@@ -990,11 +1080,14 @@ def _cached_layers(params, x, cache, positions, access, cfg, key_len=None, valid
             rank = lambda: before[kind] + period * a_period[kind] + kinds[:j].count(kind)  # noqa: E731
             # In a stack of its kind's own at its rank among its kind, as in its group of cache leaves; in a shared
             # stack at its position (a shared stack's runs are not scanned: i is 0).
-            own = kinds_of[kind].own
-            s = rank() + i if own else period * P + j
+            own = rows[kind].own
+            at = s = rank() + i if own else period * P + j
+            if own and before[kind]:  # those of its kind ahead of the segment lie in another stack
+                s = s - before[kind]
             lp = take(stacks[kind], s)
-            at = s if own else rank()
-            return run_layer(x, pool, {**lp, **held}, kind, at, first + s, first, held)
+            at = at if own else rank()
+            nth = period * sum(kinds[:P].count(k) for k in routed) + sum(kinds[:j].count(k) for k in routed) + i if kind in routed else None
+            return run_layer(x, pool, {**lp, **held[kind]}, kind, at, first + s, first, held[kind], nth)
 
         def one_period(carry, period):
             sent = []
@@ -1034,13 +1127,13 @@ def _cached_layers(params, x, cache, positions, access, cfg, key_len=None, valid
     hold = experts_run(cfg, x.shape[0] * x.shape[1]) in ("kernel", "ragged_dot")
     for segment in _layer_plan(cfg):
         stacks = {kind: params[row.stack] for kind, row in segment.rows.items()}
-        held = {n: stack[n] for stack in stacks.values() for n in _EXPERT_STACKS if hold and n in stack}
-        stacks = {kind: {n: leaf for n, leaf in stack.items() if n not in held} for kind, stack in stacks.items()}
+        held = {kind: {n: stack[n] for n in _EXPERT_STACKS if hold and n in stack} for kind, stack in stacks.items()}
+        stacks = {kind: {n: leaf for n, leaf in stack.items() if n not in held[kind]} for kind, stack in stacks.items()}
         if segment.kinds:
-            x, pool, sent = scan_periods(segment.first, held, stacks, segment.kinds, x, pool)
+            x, pool, sent = scan_periods(segment, held, stacks, x, pool)
         else:
             layer_ids = jnp.arange(segment.first, segment.first + segment.depth, dtype=jnp.int32)
-            (x, pool), sent = lax.scan(partial(body, segment.first, held), (x, pool), (stacks[None], layer_ids))
+            (x, pool), sent = lax.scan(partial(body, segment.first, held[None]), (x, pool), (stacks[None], layer_ids))
     if counts is not None:  # sent [expert layers, E]: the routed stack's, which runs last
         touched = jnp.sum(sent > 0, axis=-1, keepdims=True)
         if cfg.expert_share[1] > 1:  # E the experts held; a call counts by what it routed, held or not, and says how much

@@ -170,6 +170,21 @@ class TransformerConfig:
     ssm_state: int = 0
     ssm_groups: int = 1
     mamba_conv: int = 4
+    # Gated short-convolution layers (inference only; the ``lfm2`` family): the
+    # kind ``"conv"`` of ``layer_kinds``, a mixer-then-MLP layer whose mixer is
+    # ``[B | C | u] = h W_in``, ``y = C * conv(B * u)`` with a causal depthwise
+    # filter over ``conv_cache`` tokens (no bias, no activation), then ``W_out``:
+    # no softmax and no positions. A serving slot carries the last ``conv_cache -
+    # 1`` rows of ``B * u`` a layer and nothing else (models/generate.py). Its
+    # leaves are its own, so the pattern's layers are stacked by kind
+    # (``_layer_stacks``: ``"conv_layers"`` beside ``"layers"``), behind the
+    # leading dense layers, which are conv layers here, and the MLP of either
+    # kind is whatever the configuration says: dense, or routed experts.
+    conv_cache: int = 0
+    # A pattern's full layers rope their queries and keys plainly at
+    # ``rope_theta`` (``layer_rope``; without it and without ``rope_scaling``
+    # they carry no positions).
+    full_layers_rope: bool = False
     # What an expert computes, routed and shared alike: ``"swiglu"`` (three
     # matrices) or ``"relu2"``, ``relu(x W_up)^2 W_down``, with no gate matrix.
     expert_activation: str = "swiglu"
@@ -231,10 +246,10 @@ class TransformerConfig:
         ):
             if field:
                 raise ValueError(f"{what}: has not run and is not built")
-        if kinds and (len(kinds) != self.n_layers or set(kinds) - {"window", "full", "linear", "mamba", "experts"}):
+        if kinds and (len(kinds) != self.n_layers or set(kinds) - {"window", "full", "linear", "mamba", "experts", "conv"}):
             raise ValueError(
                 f"layer_kinds names {len(kinds)} layers {sorted(set(kinds))}: need "
-                f"n_layers = {self.n_layers} of 'window' / 'full' / 'linear' / 'mamba' / 'experts'"
+                f"n_layers = {self.n_layers} of 'window' / 'full' / 'linear' / 'mamba' / 'experts' / 'conv'"
             )
         if "window" in kinds and not self.sliding_window:
             raise ValueError("layer_kinds has window layers and sliding_window is 0")
@@ -253,6 +268,23 @@ class TransformerConfig:
                 (self.latent_attention, "latent attention (kv_lora_rank > 0) beside linear layers"),
                 (self.num_experts > 0, "experts (num_experts > 0) in a pattern with linear layers"),
                 (self.first_dense_layers > 0, "leading dense layers (first_dense_layers) before linear layers"),
+            ):
+                if field:
+                    raise ValueError(f"{what}: has not run and is not built")
+        if "conv" in kinds:
+            if self.conv_cache < 2:
+                raise ValueError("layer_kinds has conv layers: conv_cache (the filter's tokens) must be at least 2")
+            dense, rest = kinds[: self.first_dense_layers], kinds[self.first_dense_layers :]
+            if set(dense) - {"conv"} or not rest or len(rest) % _period(rest):
+                raise ValueError(
+                    f"layer_kinds with conv layers: the {self.first_dense_layers} leading dense layers must be conv layers "
+                    f"and the {len(rest)} layers behind them whole periods (period {_period(rest) if rest else 0})"
+                )
+            for field, what in (
+                ("window" in kinds, "window layers beside conv layers (layer_kinds)"),
+                ("linear" in kinds, "linear-attention layers beside conv layers (layer_kinds)"),
+                (self.single_mixer, "single-mixer blocks beside conv layers (layer_kinds)"),
+                (self.latent_attention, "latent attention (kv_lora_rank > 0) beside conv layers"),
             ):
                 if field:
                     raise ValueError(f"{what}: has not run and is not built")
@@ -347,6 +379,8 @@ class TransformerConfig:
             missing.append("a state-space block's groups (ssm_groups) have no training block")
         if self.mamba_conv != 4:
             missing.append("a state-space block's convolution (mamba_conv) has no training block")
+        if self.conv_cache:
+            missing.append("gated short-convolution layers (conv_cache) have no training block")
         if self.expert_activation != "swiglu":
             missing.append("experts without a gate matrix (expert_activation) have no training block")
         if self.embed_multiplier != 1.0:
@@ -452,7 +486,8 @@ def _layer_leaves(cfg: TransformerConfig, mlp, mixer="attention") -> dict:
     """One layer's leaves by name. ``mlp`` is ``"dense"``, ``"switch"``,
     ``"routed"`` or None (a block that is a mixer alone); ``mixer`` is
     ``"attention"``, ``"linear"`` (a linear-attention layer's), ``"mamba"`` (a
-    state-space block's) or None (a block that is its experts alone). Stacked,
+    state-space block's), ``"conv"`` (a gated short convolution's) or None (a
+    block that is its experts alone). Stacked,
     every leaf gains a leading layer axis."""
     D, H, KV, Dh, F, E = (
         cfg.d_model, cfg.n_heads, cfg.n_kv_heads, cfg.head_dim, cfg.d_ff, cfg.num_experts
@@ -523,6 +558,15 @@ def _layer_leaves(cfg: TransformerConfig, mlp, mixer="attention") -> dict:
                 "wg_lin": _Leaf(13, (D, Hl * dv), s, ("embed", "heads")),
                 "o_norm": _Leaf(None, (dv,), None, (None,)),
                 "wo": _Leaf(3, (Hl * dv, D), (Hl * dv) ** -0.5 * out, ("heads", "embed")),
+            }
+        )
+    elif mixer == "conv":
+        # ``w_in``'s columns are [B | C | u], D each; the filter one tap a token a channel, no bias.
+        leaves.update(
+            {
+                "w_in": _Leaf(0, (D, 3 * D), s, ("embed", "heads")),
+                "conv_w": _Leaf(1, (cfg.conv_cache, D), cfg.conv_cache**-0.5, (None, "heads")),
+                "wo": _Leaf(3, (D, D), s * out, ("heads", "embed")),
             }
         )
     elif mixer is None:
@@ -609,6 +653,7 @@ def _layer_leaves(cfg: TransformerConfig, mlp, mixer="attention") -> dict:
 LINEAR_LAYERS = "linear_layers"
 MAMBA_LAYERS = "mamba_layers"
 EXPERT_LAYERS = "expert_layers"
+CONV_LAYERS = "conv_layers"
 
 
 class _Stack(NamedTuple):
@@ -631,8 +676,11 @@ def _layer_stacks(cfg: TransformerConfig) -> dict:
     layers', is stacked by kind: its linear layers in ``"linear_layers"`` and
     the others in ``"layers"``. A pattern of single-mixer blocks is three such
     stacks: the state-space blocks (``"mamba_layers"``), the experts blocks
-    (``"expert_layers"``) and the attention blocks (``"layers"``). What a kind
-    of layer keeps in a cache is ``generate._layer_plan``'s to say."""
+    (``"expert_layers"``) and the attention blocks (``"layers"``). A pattern
+    with conv layers is stacked by kind too (``"conv_layers"``, ``"layers"``),
+    behind its leading dense layers, conv layers all, which stay a stack of
+    their own. What a kind of layer keeps in a cache is ``generate._layer_plan``'s
+    to say."""
     mlp = "routed" if cfg.routed_experts else "switch" if cfg.num_experts > 0 else "dense"
     count = cfg.layer_kinds.count
     if cfg.single_mixer:
@@ -640,6 +688,11 @@ def _layer_stacks(cfg: TransformerConfig) -> dict:
                   "layers": _Stack(count("full"), None, "attention", "full")}
         return {name: stack for name, stack in stacks.items() if stack.depth}
     n_dense, n_linear = cfg.first_dense_layers, count("linear")
+    if count("conv"):
+        stacks = {"dense_layers": _Stack(n_dense, "dense", "conv")} if n_dense else {}
+        stacks[CONV_LAYERS] = _Stack(count("conv") - n_dense, mlp, "conv", "conv")
+        stacks["layers"] = _Stack(count("full"), mlp, "attention", "full")
+        return stacks
     stacks = {"dense_layers": _Stack(n_dense, "dense", "attention")} if n_dense else {}
     if n_linear:
         stacks[LINEAR_LAYERS] = _Stack(n_linear, mlp, "linear", "linear")
@@ -659,7 +712,7 @@ def init_params(key, cfg: TransformerConfig) -> dict:
     # stack then splits sixteen keys of its own from ``fold_in(key, stack)``.
     own_keys = (
         cfg.latent_attention or cfg.routed_experts or cfg.first_dense_layers > 0 or cfg.attn_gate
-        or "linear" in cfg.layer_kinds or cfg.single_mixer or cfg.hc_mult > 0
+        or "linear" in cfg.layer_kinds or cfg.single_mixer or cfg.hc_mult > 0 or "conv" in cfg.layer_kinds
     )
 
     # Every drawn leaf is a program of its own to compile (about a second each
@@ -678,8 +731,14 @@ def init_params(key, cfg: TransformerConfig) -> dict:
             for name, leaf in _layer_leaves(cfg, of.mlp, of.mixer).items()
         }
 
+    # A tied table is the head too, and is drawn at the head's scale, D^-1/2: drawn at 1, a tied head's logits have
+    # standard deviation sqrt(D) and a token's OWN logit (E[t] . E[t] = D, carried to the head by the residual path)
+    # stands 30-45 deviations over every other, so that a random tied model repeats its last token whatever its layers
+    # compute (found on the chip, PR 54: a float32 reference agreed with the served tokens at 3072 of 3072 positions,
+    # and would have with any layer broken). The first layer's norm brings the small rows back to order 1.
+    embed_scale = (D**-0.5 if cfg.tie_embeddings else 1.0) / cfg.embed_multiplier
     params = {
-        "embed": drawn.later(ks[8], (V, D), 1.0 / cfg.embed_multiplier, dt),
+        "embed": drawn.later(ks[8], (V, D), embed_scale, dt),
         "norm_f": jnp.ones((D,), dt),
     }
     for i, (name, of) in enumerate(_layer_stacks(cfg).items()):
@@ -836,10 +895,11 @@ def layer_rope(cfg: TransformerConfig, kind):
     None for none, else the ``scaling`` of ``_rope_tables`` at ``rope_theta``
     (() for the plain rotary, ``rope_scaling`` for YaRN's frequencies and
     amplitude). A window layer ropes plainly; a full layer of a pattern by
-    ``rope_scaling`` or, without it, not at all; a model without a pattern by
-    ``rope_scaling`` or, without it, plainly."""
+    ``rope_scaling`` or, without it, plainly under ``full_layers_rope`` and else
+    not at all; a model without a pattern by ``rope_scaling`` or, without it,
+    plainly."""
     if kind == "full":
-        return cfg.rope_scaling or None
+        return cfg.rope_scaling or (() if cfg.full_layers_rope else None)
     return () if kind else cfg.rope_scaling
 
 

@@ -47,6 +47,11 @@ from ray_tpu.serve.llm.engine import LLMEngine, prefix_route_hint  # noqa: F401
 
 
 class LLMDeployment:
+    @staticmethod
+    def serve_concurrency(model_config=None, engine_config=None, **_) -> int:
+        """Queries at once that a replica built from these arguments has rows for (``Deployment.bind`` reads it)."""
+        return int((engine_config or {}).get("num_slots", 0))
+
     def __init__(
         self,
         model_config: dict,

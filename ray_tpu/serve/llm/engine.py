@@ -495,6 +495,14 @@ _SSM_POOL_KV_PAYLOAD = (
 )
 
 
+# And of a gated short convolution's carried rows.
+_CONV_POOL_KV_PAYLOAD = (
+    "{what} needs the KV transfer plane, whose payload is blocks of keys and values; "
+    "gated short-convolution layers (layer_kinds has 'conv') keep the last rows ahead of their filter a slot, "
+    "which no block holds and nothing snapshots at a block's boundary (kv_transfer.py, ROADMAP R5)"
+)
+
+
 def _kv_payload_refusal(cfg) -> Optional[str]:
     """Why this configuration's pool cannot feed the KV transfer plane (None: it can)."""
     if cfg.latent_attention:
@@ -503,6 +511,8 @@ def _kv_payload_refusal(cfg) -> Optional[str]:
         return _STATE_POOL_KV_PAYLOAD
     if "mamba" in cfg.layer_kinds:
         return _SSM_POOL_KV_PAYLOAD
+    if "conv" in cfg.layer_kinds:
+        return _CONV_POOL_KV_PAYLOAD
     return _PATTERN_POOL_KV_PAYLOAD if cfg.layer_kinds else None
 
 
@@ -563,6 +573,7 @@ class LLMEngine:
             latent_kernel_reads,
             pool_reach,
             ring_blocks,
+            state_kind,
             state_slot_bytes,
         )
         from ray_tpu.models.transformer import latent_softmax_scale
@@ -641,6 +652,7 @@ class LLMEngine:
         # a step's last write for a request that ended, leaves a state that
         # the slot's next tenant never reads.
         self.state_slot_bytes = state_slot_bytes(cfg)
+        self._state_kind = state_kind(cfg)
         self._state_cols = _STATE_COLS if "state" in reach else 0
         self._rings = 1 + np.arange(self.num_slots * self.ring_blocks, dtype=np.int32).reshape(
             self.num_slots, self.ring_blocks
@@ -781,11 +793,13 @@ class LLMEngine:
         return_routed_experts: bool = False,
         return_state: bool = False,
     ) -> LLMRequest:
-        """``return_state`` (linear-attention layers): a request that completes
-        leaves in ``req.state`` what its slot's recurrent state is after the
-        last token fed to the model (prompt + generated - 1: a request ends by
-        count and the last token drawn is never fed), [linear layers, heads,
-        dk, dv] in the pool's dtype, read from the pool once, as the request
+        """``return_state`` (layers that keep something a slot): a request that
+        completes leaves in ``req.state`` what its slot's recurrent state is
+        after the last token fed to the model (prompt + generated - 1: a request
+        ends by count and the last token drawn is never fed), [linear layers,
+        heads, dk, dv] in the pool's dtype (gated short convolutions, which keep
+        no state: the rows a slot carries ahead of each filter, [conv layers,
+        conv_cache - 1, d_model]), read from the pool once, as the request
         ends: what the benchmark's check holds to the float32 recurrence, and
         what a prefix cache or a handoff of such a model would have to carry.
 
@@ -844,7 +858,10 @@ class LLMEngine:
             raise ValueError("return_routed_experts needs a model with routed experts")
         req.return_routed_experts = bool(return_routed_experts)
         if return_state and not self.state_slot_bytes:
-            raise ValueError("return_state needs a model with linear-attention layers or Mamba-2 blocks (a recurrent state a slot)")
+            raise ValueError(
+                "return_state needs a model with linear-attention layers, Mamba-2 blocks or gated short convolutions "
+                "(layers that keep something a slot)"
+            )
         req.return_state = bool(return_state)
         req.request_id = str(request_id or "")
         req.t_recv = int(t_recv_ns) / 1e9 if t_recv_ns else None
@@ -949,8 +966,9 @@ class LLMEngine:
         those in use. ``"full"``: the layers whose blocks grow with a row (all
         of them without a layer pattern). ``"window"``: the window layers of a
         pattern, ``ring_blocks`` a running request whatever its length.
-        ``"state"``: the layers that keep a recurrent state, ``bytes_per_slot`` for each of
-        ``num_slots`` for good, of which ``slots_in_use`` hold a request's."""
+        ``"state"``: the layers of ``kind`` (``generate.state_kind``) that keep a recurrent state or
+        a filter's carried rows, ``bytes_per_slot`` for each of ``num_slots`` for good, of which
+        ``slots_in_use`` hold a request's."""
         groups = {
             "full": {
                 "kv_token_bytes": self._group_token_bytes["full"],
@@ -960,6 +978,7 @@ class LLMEngine:
         }
         if self.state_slot_bytes:
             groups["state"] = {
+                "kind": self._state_kind,
                 "bytes_per_slot": self.state_slot_bytes,
                 "num_slots": self.num_slots,
                 "slots_in_use": sum(r is not None for r in self._slots),
@@ -2028,7 +2047,8 @@ class LLMEngine:
         if req.return_routed_experts and error is None and not cancelled and handoff is None:
             req.routed_experts = self._routed_experts(req)
         if req.return_state and error is None and not cancelled and req._sched_slot is not None:
-            req.state = np.asarray(self._cache["state"][:, req._sched_slot])  # the step in flight has no row for it
+            # The step in flight has no row for it. A recurrent state, or (a short convolution keeps none) the carried rows.
+            req.state = np.asarray(self._cache["state" if "state" in self._cache else "conv"][:, req._sched_slot])
         self._release_blocks(req)
         if req._sched_slot is not None and self._slots[req._sched_slot] is req:
             self._slots[req._sched_slot] = None

@@ -156,6 +156,17 @@ _WRONG_SINCE_A_SECOND_ARCHITECTURE = {  # the first four (PR 32)
         "test_bench_setup_stages.py::test_xings_cell_reports_what_it_did_and_the_six_of_its_start holds the rest of it, "
         "and the six behind everything else"
     ),
+    # And since a ninth configuration, whose cell reports the cache pair and whose two metrics come behind PR 52's eight (PR 54):
+    "test_bench_nemotron_h.py::test_trinitys_mix_is_still_the_issues": (
+        "holds the cache pair to Trinity's, Olmo-Hybrid's and Nemotron's cells; the two attention layers of PR 54's cut "
+        "report it too. test_bench_lfm2.py::test_trinitys_mix_is_still_the_issues holds the rest of it"
+    ),
+    "test_bench_setup_stages.py::test_benchmark_json_gains_exactly_the_eight_at_the_end_of_per_layer": (
+        "holds BENCHMARK.json's per_layer to END with PR 52's eight, the cells to be eleven and the six of a replica's "
+        "start to eight cells; PR 54 appends its two metrics behind the eight and its cell to the six's lists. "
+        "test_bench_lfm2.py::test_the_new_entries_are_appended_behind_what_was_there holds the eight together behind the "
+        "49, each as it was declared, and PR 54's two behind them, without a pin on the END"
+    ),
 }
 
 

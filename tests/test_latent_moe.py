@@ -379,7 +379,9 @@ def test_a_chunks_experts_run_the_kernel_on_a_tpu_and_a_decode_steps_run_as_befo
     ``benchmarks/configs/``: on a TPU a prefill chunk's rows (24-32 a group) run
     the grouped kernel and a decode step's (0.5-3 a group) what they ran before
     there was one, which is also what every shape runs off a TPU. The decode
-    programs are found by name and shape by the benchmark's ``moe_experts_*``."""
+    programs are found by name and shape by the benchmark's ``moe_experts_*``.
+    One cell's STEP is as wide as the kernel's threshold (PR 54: 128 rows x 4 of
+    64 experts, 8 rows a group) and runs the kernel too, as its ``trace_ops`` say."""
     import importlib
 
     from ray_tpu.parallel.moe import experts_run, grouped_matmul_tiles
@@ -392,10 +394,12 @@ def test_a_chunks_experts_run_the_kernel_on_a_tpu_and_a_decode_steps_run_as_befo
     widths = cfg.num_experts, cfg.d_model, cfg.d_expert
     before = "ragged_dot" if grouped_matmul_tiles(cfg.d_model, cfg.d_expert) else "every_expert"
     step, chunk = (rows * cfg.experts_per_token for rows in (engine["num_slots"], engine["prefill_chunk"]))
-    assert step / cfg.num_experts <= 3 and chunk / cfg.num_experts >= 24
+    wide = step / cfg.num_experts >= 8  # a step of as many rows a group as ``moe._KERNEL_ROWS_A_GROUP``
+    assert (step / cfg.num_experts <= 3 or wide) and chunk / cfg.num_experts >= 24
+    assert wide == (cell_name == "lfm9.rollout-wide") == ("gmm" in cell["config"]["trace_ops"]["moe_experts"])
     assert experts_run(step, *widths) == experts_run(chunk, *widths) == before  # here, on a CPU
     monkeypatch.setattr(importlib.import_module("ray_tpu.ops.attention"), "_on_tpu", lambda: True)
-    assert experts_run(step, *widths) == before
+    assert experts_run(step, *widths) == ("kernel" if wide else before)
     assert experts_run(chunk, *widths) == "kernel"
 
 

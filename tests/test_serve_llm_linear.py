@@ -286,7 +286,7 @@ def test_the_engine_serves_the_references_tokens_rows_of_unequal_length_at_once(
         assert set(groups) == {"full", "state"} and "window" not in groups
         assert groups["full"]["kv_token_bytes"] == 2 * 2 * 4 * 16 * 4  # two full layers, k and v, float32 here
         assert groups["state"] == dict(
-            bytes_per_slot=6 * (4 * 8 * 16 * 4 + 3 * 4 * (8 + 8 + 16) * 4), num_slots=3, slots_in_use=0
+            bytes_per_slot=6 * (4 * 8 * 16 * 4 + 3 * 4 * (8 + 8 + 16) * 4), num_slots=3, slots_in_use=0, kind="linear"
         )
         assert st["kv_token_bytes"] == groups["full"]["kv_token_bytes"]
     finally:

@@ -272,7 +272,7 @@ def test_the_engine_serves_the_references_tokens_and_counts_what_it_holds(model,
         assert set(groups) == {"full", "state"}
         assert groups["full"]["kv_token_bytes"] == 2 * 2 * 2 * 16 * 4  # two attention blocks, k and v, two KV heads, float32 here
         assert groups["state"] == dict(
-            bytes_per_slot=4 * (8 * 8 * 16 * 4 + 3 * (8 * 8 + 2 * 2 * 16) * 4), num_slots=3, slots_in_use=0
+            bytes_per_slot=4 * (8 * 8 * 16 * 4 + 3 * (8 * 8 + 2 * 2 * 16) * 4), num_slots=3, slots_in_use=0, kind="mamba"
         )
         moe = st["moe"]
         for kind, tokens in (("decode", 5 * 23), ("prefill", sum(map(len, prompts)))):

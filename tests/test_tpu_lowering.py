@@ -964,6 +964,48 @@ def test_the_hyper_connection_programs_keep_the_stream_on_the_lanes_and_copy_no_
         assert stats.temp_size_in_bytes + stats.argument_size_in_bytes < 11.5e9  # 10.35 GB of arguments
 
 
+def test_the_conv_pattern_programs_cache_two_heads_a_row_and_copy_no_pool(one_v5e_chip, monkeypatch):
+    """LFM2-24B-A2B's decode program at 128 rows and the widest rung (2048 tokens)
+    and its prefill chunk at the benchmark's widths, as a TPU backend gets them,
+    compiled for the v5e (PR 54). What has been seen to fail on the way: cached a
+    row a head, ``[2, 16385, 16, 8, 64]``, a leaf whose minor axis fills half the
+    lanes is laid out one way round for the gather and another for the scatter
+    and the whole pool is copied between them, six copies of 1.07 GB a step and
+    4.4 GB of temporaries (``generate._heads_paired`` caches two heads a row of
+    128 lanes, nothing padded: a token is 4,096 B over the two attention
+    layers). The step's 512 assignments are 8 rows a group: it runs
+    ``ops/grouped_matmul.py``'s kernel over the two stacks of experts held
+    WHOLE (six calls: three in the scan over a period's conv layers, three for
+    its attention layer), as the chunk's 2048 do, and no stack is copied. The
+    K/V pool and the words of the experts taken are updated in place; the
+    carried rows, 7.3 MB, are relaid on the way in and out (two rows a slot tile
+    (2, 128) as an argument and (8, 128) inside)."""
+    import importlib
+    import re
+
+    import jax
+
+    for module in ("ray_tpu.ops.attention", "ray_tpu.ops.grouped_matmul"):
+        monkeypatch.setattr(importlib.import_module(module), "_on_tpu", lambda: True)
+    monkeypatch.setattr(importlib.import_module("ray_tpu.serve.llm.engine"), "_JIT_CACHE", {})
+    decode, prefill, args = _cell_programs("lfm9.rollout-wide")
+    describe = lambda a: jax.tree.map(lambda x: jax.ShapeDtypeStruct(x.shape, x.dtype, sharding=one_v5e_chip), a)  # noqa: E731
+    pool_bytes = 2 * 2 * 16385 * 16 * 4 * 128 * 2 + 7 * 128 * 2 * 2048 * 2 + 8 * 16385 * 16 * 4
+    for program, given, rows, temp in ((decode, args(128), 512, 0.35e9), (prefill, args(None), 2048, 0.2e9)):
+        compiled = program.lower(*describe(given)).compile()
+        text = compiled.as_text()
+        assert "ragged-dot" not in text and len(re.findall(rf"%gmm\S* = bf16\[{rows},(?:1536|2048)\]\S* custom-call\(", text)) == 6
+        for leaf in ("bf16[2,16385,16,4,128]", "s32[8,16385,16]", "bf16[6,64,2048,1536]", "bf16[2,64,1536,2048]"):
+            assert leaf in text and not re.search(rf"= {re.escape(leaf)}\S* copy\(", text), leaf
+        assert "bf16[2,16385,16,8,64]" not in text  # no leaf whose minor axis fills half the lanes
+        assert not re.search(r"= bf16\[64,(2048,1536|1536,2048)\]\S* (fusion|copy)\(", text)  # no layer's experts materialised
+        stats = compiled.memory_analysis()
+        assert stats.alias_size_in_bytes >= pool_bytes  # 1.09 GB updated in place
+        # a step: one attention layer's view of 128 x 2048 tokens at a time (0.27 GB, keys then values); a chunk 0.15 GB
+        assert stats.temp_size_in_bytes < temp, stats.temp_size_in_bytes
+        assert stats.temp_size_in_bytes + stats.argument_size_in_bytes < 12.0e9  # 11.45 GB of arguments: 68 % of the chip's 16.9
+
+
 @pytest.mark.parametrize("cell_name", sorted(_PROGRAMS_OF_PR_34))
 def test_the_new_fields_at_their_defaults_are_the_configuration_that_states_neither(cell_name):
     """PR 47 sends every join of the cached layer through ``generate._residual``
