@@ -28,9 +28,17 @@ TPU-first choices:
   backward pass reads (``(H + KV) x Dh`` more a token: ~6x for Mellum's). An
   eighth or less of what ``remat=False`` keeps. The backward pass then runs the
   two flash backward kernels on them; the forward kernel, the q/k/v
-  projections, their norms and their rotary run once a step. The block's other
-  norms, ``wo`` and the MLP or the experts (the d_ff-wide rows) are recomputed.
-  One policy for every configuration, sized by the shapes alone.
+  projections, their norms and their rotary run once a step. A layer whose
+  routed experts run the bounded path (a trained share, ``moe.held_rows``) also
+  keeps what ``parallel/moe.py`` names there (``moe.KEPT_OF_A_BOUNDED_BLOCK``):
+  the router's scores, its choice and the chosen experts' scores, the sort's
+  order and group sizes, and, of the sorted rows under the bound, the two hidden
+  products and the down projection's result (``rows x (2 d_expert + d_model)``
+  values of ``dtype``: 336 MB a layer for Mellum's share), so that the router,
+  the sort and the three grouped matmuls run once a step. The block's other
+  norms, ``wo``, a dense MLP (the d_ff-wide rows) and, of the experts, the
+  gather of the sorted rows, the matrices' casts and the SiLU product are
+  recomputed. One policy for every configuration, sized by the shapes alone.
 
 (The reference has no in-tree model zoo for LLMs — its Train/RLlib models are
 torch modules; SURVEY.md §2.3/§5.7. This module is the TPU-native equivalent
@@ -51,6 +59,7 @@ from jax import lax
 from jax.ad_checkpoint import checkpoint_name
 
 from ray_tpu.ops.attention import FLASH_LSE, FLASH_OUT, flash_attention, repeat_kv
+from ray_tpu.parallel.moe import KEPT_OF_A_BOUNDED_BLOCK
 
 
 @dataclasses.dataclass(frozen=True)
@@ -904,10 +913,13 @@ def layer_rope(cfg: TransformerConfig, kind):
 
 
 # What a layer under ``cfg.remat`` keeps for its backward pass beside its input (the module's docstring): the
-# attention core's inputs, named in ``_attention_block``, and the flash kernel's results, named in its forward rule.
+# attention core's inputs, named in ``_attention_block``, the flash kernel's results, named in its forward rule, and
+# what ``moe.routed_experts`` names on the path a trained share takes.
 _KEPT_INPUTS = ("attention_q", "attention_k", "attention_v")
 _KEPT_NORM_INPUTS = ("attention_q_projected", "attention_k_projected")
-_KEPT_UNDER_REMAT = jax.checkpoint_policies.save_only_these_names(*_KEPT_INPUTS, *_KEPT_NORM_INPUTS, FLASH_OUT, FLASH_LSE)
+_KEPT_UNDER_REMAT = jax.checkpoint_policies.save_only_these_names(
+    *_KEPT_INPUTS, *_KEPT_NORM_INPUTS, FLASH_OUT, FLASH_LSE, *KEPT_OF_A_BOUNDED_BLOCK
+)
 
 
 def _attention_block(lp, x, rope_cs, cfg: TransformerConfig, mesh, attn_impl: str, kind=None):

@@ -14,6 +14,7 @@ from functools import partial
 
 import jax
 import jax.numpy as jnp
+from jax.ad_checkpoint import checkpoint_name
 
 from ray_tpu.ops import attention as _attention_ops
 from ray_tpu.ops import grouped_matmul
@@ -186,6 +187,26 @@ def _count(ids, n: int):
     return jnp.sum(ids[:, None] == jnp.arange(n, dtype=ids.dtype), axis=0, dtype=jnp.int32)
 
 
+# What ``routed_experts(rows=)`` names for a checkpoint policy to keep (``transformer._KEPT_UNDER_REMAT``), so that a
+# layer's backward pass runs neither the router, nor the sort, nor a grouped matmul of the forward pass again: the
+# router's scores, its choice and the chosen experts' scores as gathered (the weights before they are normalised: the
+# gather of 131,072 scalars is the router's one slow operation, 1.34 ms on the v5e), the sort's order and the held
+# experts' rows, and, of the piece every step runs, the two hidden products and the down projection's result (which
+# the weights' gradient reads). Nothing else of the block is named, and a policy over names keeps nothing else: the
+# gathered rows (189 MB a layer at Mellum's share for a gather of 1.4 ms) and the sorted rows' weights are gathered
+# again (with the weights kept the compiler split the rows' gather from its select: 374.3 -> 377.7 ms a step, PR 55).
+ROUTER_SCORES, ROUTER_CHOSEN, ROUTER_TAKEN = "moe_router_scores", "moe_router_chosen", "moe_router_taken"
+SORT_ORDER, SORT_SIZES = "moe_sort_order", "moe_sort_sizes"
+HIDDEN_GATE, HIDDEN_UP, DOWN_RESULT = "moe_hidden_gate", "moe_hidden_up", "moe_down_result"
+KEPT_OF_A_BOUNDED_BLOCK = (ROUTER_SCORES, ROUTER_CHOSEN, ROUTER_TAKEN, SORT_ORDER, SORT_SIZES, HIDDEN_GATE, HIDDEN_UP, DOWN_RESULT)
+
+
+def _named(keep: bool):
+    """``checkpoint_name`` where ``keep``, else the value as it is: the paths that serve get no name (their programs'
+    texts are pinned), nor do the pieces behind a bound."""
+    return checkpoint_name if keep else lambda a, name: a
+
+
 def held_rows(assignments: int, share) -> int | None:
     """The static bound ``routed_experts(rows=)`` takes from a caller that
     trains ONE share of the experts, None where every expert is held. A chip
@@ -255,11 +276,16 @@ def routed_experts(params, x, *, k: int, scale: float = 1.0, valid=None, layer=N
     N, D = x.shape
     E = params["gate"].shape[-1]
     held = E // share[1]
+    how = experts_run(N * k, E, D, params["wo_e"].shape[-2])
+    # A training step's share: ``_held_rows`` gathers its own rows, and what the backward pass reads has a name.
+    bounded = rows is not None and rows < N * k and how != "every_expert"
+    named = _named(bounded)
     with jax.named_scope("moe_router"):
-        s = router_scores(x.astype(jnp.float32) @ params["gate"].astype(jnp.float32), score)  # [N, E]
+        # Each is named before the next reads it: a gradient that came through an unnamed one would run it again.
+        s = named(router_scores(x.astype(jnp.float32) @ params["gate"].astype(jnp.float32), score), ROUTER_SCORES)  # [N, E]
         bias = params.get("gate_bias")
-        _, chosen = jax.lax.top_k(s if bias is None else s + bias.astype(jnp.float32), k)  # [N, k]
-        w = jnp.take_along_axis(s, chosen, axis=-1)
+        chosen = named(jax.lax.top_k(s if bias is None else s + bias.astype(jnp.float32), k)[1], ROUTER_CHOSEN)  # [N, k]
+        w = named(jnp.take_along_axis(s, chosen, axis=-1), ROUTER_TAKEN)  # (the gather: 1.34 ms at [16384, 64], v5e)
         w = scale * w / jnp.sum(w, axis=-1, keepdims=True)
     expert = chosen.reshape(N * k)
     if held != E:  # by its rank among the experts held; one that is not held: past the last
@@ -268,26 +294,26 @@ def routed_experts(params, x, *, k: int, scale: float = 1.0, valid=None, layer=N
     if valid is not None:
         # Past the last expert: sorted behind every group, in none of them.
         expert = jnp.where(jnp.repeat(valid, k), expert, held)
-    how = experts_run(N * k, E, D, params["wo_e"].shape[-2])
     if how == "every_expert":
         sizes = jnp.zeros((held,), jnp.int32).at[expert].add(1, mode="drop")
         rows_of = jnp.arange(N, dtype=jnp.int32)[:, None]
         by_expert = jnp.zeros((N, held), jnp.float32).at[rows_of, expert.reshape(N, k)].add(w, mode="drop")
         with jax.named_scope("moe_experts"):
             return _every_expert(params, x, by_expert, layer).astype(x.dtype), sizes, chosen, s
-    bounded = rows is not None and rows < N * k  # a training step's share: ``_held_rows`` gathers its own rows
     with jax.named_scope("moe_dispatch"):
         order = jnp.argsort(expert)  # stable: assignment ids grouped by expert
         # (a decode step's programs keep the scatter they were built with: their text is held to the parent's)
         sizes = _count(expert, held) if bounded else jnp.zeros((held,), jnp.int32).at[expert].add(1, mode="drop")
         xs = None if bounded else x[order // k]  # [N * k, D]
+        order, sizes = named(order, SORT_ORDER), named(sizes, SORT_SIZES)
     groups = sizes
     if layer is not None:
         stacked = params["wi_e"].shape[0]
         groups = jax.lax.dynamic_update_slice(jnp.zeros((stacked * held,), jnp.int32), sizes, (layer * held,))
 
-    def experts(xs, groups, cast=None):
-        """``cast``: the matrices in x's dtype by leaf name, made once outside the caller's loop (``_held_rows``)."""
+    def experts(xs, groups, cast=None, keep=False):
+        """``cast``: the matrices in x's dtype by leaf name, made once outside the caller's loop (``_held_rows``);
+        ``keep``: the two hidden products get their names (the piece of ``_held_rows`` that every step runs)."""
 
         def grouped(a, name):
             w_e = params[name].reshape(-1, *params[name].shape[-2:])  # [L, E, in, out] -> [L * E, in, out]: no copy
@@ -297,7 +323,8 @@ def routed_experts(params, x, *, k: int, scale: float = 1.0, valid=None, layer=N
 
         with jax.named_scope("moe_experts"):
             if "wg_e" in params:
-                h = jax.nn.silu(grouped(xs, "wg_e")) * grouped(xs, "wi_e")
+                named = _named(keep)
+                h = jax.nn.silu(named(grouped(xs, "wg_e"), HIDDEN_GATE)) * named(grouped(xs, "wi_e"), HIDDEN_UP)
             else:
                 h = jnp.square(jax.nn.relu(grouped(xs, "wi_e")))
             return grouped(h, "wo_e")  # a row of no group holds whatever the kernel left there
@@ -341,29 +368,36 @@ def _held_rows(experts, x, w, order, groups, rows: int, k: int):
     routing falls, and a step whose held rows fit the bound pays the others
     nothing (a scan that runs and skips its iterations still sums a zero
     gradient of every matrix an iteration: 28 ms a step at the benchmark's
-    widths, v5e, PR 50). A piece is checkpointed, and so is the scan: neither
-    keeps residuals of its own (a cond saves both branches', zeros for the one
-    not taken). Under a layer's own checkpoint a piece does not run once more
-    for that: nothing keeps the result of an expert block as a residual."""
+    widths, v5e, PR 50). A piece is checkpointed, and so is the scan: by
+    itself neither keeps residuals (a cond saves both branches', zeros for the
+    one not taken). Under a layer's own checkpoint a piece does not run once
+    more for that, and the layer's policy reaches through a piece's: what the
+    FIRST piece names (its two hidden products, ``experts(keep=True)``, and the
+    down projection's result) is kept where the layer's policy lists the names
+    (``KEPT_OF_A_BOUNDED_BLOCK``), and its backward pass then gathers its rows
+    and multiplies the SiLU product out again, and runs no grouped matmul of the
+    forward pass. The pieces behind the bound name nothing and are recomputed
+    whole."""
     N, D = x.shape
     pieces = -(-N * k // rows)
     order = jnp.pad(order, (0, pieces * rows - N * k))
     ends = jnp.cumsum(groups)
     starts, sent = ends - groups, ends[-1]
 
-    @jax.checkpoint
-    def run(out, x, w, first):
+    def a_piece(out, x, w, first, keep):
         with jax.named_scope("moe_dispatch"):
             at = jax.lax.dynamic_slice(order, (first,), (rows,))
             live = (first + jnp.arange(rows, dtype=jnp.int32) < sent)[:, None]
             token = jnp.where(live[:, 0], at // k, 0)
             xs = jnp.where(live, x[token], 0)
             inside = jnp.clip(ends, first, first + rows) - jnp.clip(starts, first, first + rows)
-        ys = experts(xs, inside)
+        ys = _named(keep)(experts(xs, inside, keep=keep), DOWN_RESULT)
         with jax.named_scope("moe_combine"):
             # Selected BEFORE the product: what a grouped matmul leaves in a row of no group may not be finite, and
             # the product's gradient in the weight is the cotangent TIMES that row, where 0 x NaN is NaN.
             return out.at[token].add(jnp.where(live, ys, 0).astype(jnp.float32) * w.reshape(-1)[at][:, None])
+
+    run = jax.checkpoint(partial(a_piece, keep=False))
 
     @jax.checkpoint
     def behind(out, x, w):
@@ -372,5 +406,5 @@ def _held_rows(experts, x, w, order, groups, rows: int, k: int):
 
         return jax.lax.scan(piece, out, jnp.arange(1, pieces, dtype=jnp.int32) * rows)[0]
 
-    out = run(jnp.zeros((N, D), jnp.float32), x, w, jnp.int32(0))
+    out = jax.checkpoint(partial(a_piece, keep=True))(jnp.zeros((N, D), jnp.float32), x, w, jnp.int32(0))
     return jax.lax.cond(sent > rows, behind, lambda out, x, w: out, out, x, w).astype(x.dtype)

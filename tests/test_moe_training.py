@@ -277,6 +277,82 @@ def test_the_scopes_are_in_the_steps_lowered_text():
     assert [e.params["length"] for e in scans][:1] == [1]
 
 
+# -- what a layer under remat keeps of its experts' block (PR 55) --------------------------------------------------
+
+
+def _calls(jaxpr, counts):
+    """How often each primitive stands in a jaxpr and every jaxpr inside it, the
+    branches of a ``cond`` left out: what a step runs however its routing falls
+    (``_held_rows``' pieces behind the bound are one cond)."""
+    for eqn in jaxpr.eqns:
+        counts[eqn.primitive.name] = counts.get(eqn.primitive.name, 0) + 1
+        if eqn.primitive.name != "cond":
+            for inner in jax.core.jaxprs_in_params(eqn.params):
+                _calls(inner, counts)
+    return counts
+
+
+def _policy_without_the_experts_names():
+    attention_only = (*transformer._KEPT_INPUTS, *transformer._KEPT_NORM_INPUTS, transformer.FLASH_OUT, transformer.FLASH_LSE)
+    return jax.checkpoint_policies.save_only_these_names(*attention_only)
+
+
+def test_the_backward_pass_of_a_layer_under_remat_runs_neither_the_router_nor_the_sort_nor_a_grouped_matmul_again(monkeypatch):
+    """The mechanism's counter, on the Mellum-shaped toy (2 x 128 tokens: 2,048
+    assignments under a bound of 640, so every layer takes the bounded path):
+    the train step holds, a scanned layer and outside the cond, ONE ``top_k``,
+    ONE sort and nine grouped matmuls: the forward's three and the six of the
+    gradients. With the experts' names taken out of the policy (the parent's)
+    the backward pass starts from the layer's input: two routers, two sorts,
+    twelve grouped matmuls, and the gather of the chosen experts' scores
+    (131,072 scalars at the cell's size) once more."""
+    _, _, cfg = _cell(1, "bfloat16")
+    cfg = dataclasses.replace(cfg, remat=True)
+    assert moe.held_rows(2 * 128 * cfg.experts_per_token, cfg.expert_share) == 640
+    params = jax.eval_shape(lambda: init_params(jax.random.PRNGKey(0), cfg))
+    opt = optax.adamw(1e-4)
+    batch = {"tokens": jax.ShapeDtypeStruct((2, 129), jnp.int32)}
+
+    def a_layer():
+        step = jax.jit(transformer.make_train_step(cfg, opt)).trace(params, jax.eval_shape(opt.init, params), batch)
+        assert "moe_experts" in step.lower(lowering_platforms=("tpu",)).as_text(debug_info=True)
+        counts = _calls(step.jaxpr.jaxpr, {})
+        return {name: counts[name] / cfg.n_layers for name in ("top_k", "sort", "ragged_dot_general", "gather")}
+
+    kept = a_layer()
+    monkeypatch.setattr(transformer, "_KEPT_UNDER_REMAT", _policy_without_the_experts_names())
+    parents = a_layer()
+    assert {name: kept[name] for name in ("top_k", "sort", "ragged_dot_general")} == {"top_k": 1, "sort": 1, "ragged_dot_general": 9}, (
+        "kept: " + ", ".join(moe.KEPT_OF_A_BOUNDED_BLOCK))
+    assert {name: parents[name] for name in ("top_k", "sort", "ragged_dot_general")} == {"top_k": 2, "sort": 2, "ragged_dot_general": 12}
+    assert parents["gather"] - kept["gather"] == 1, (kept, parents)
+
+
+@pytest.mark.parametrize("rows", [96, 16], ids=["the share fits its bound", "pieces behind the bound run"])
+@pytest.mark.parametrize("dtype", [jnp.float32, jnp.bfloat16], ids=["float32", "bfloat16"])
+def test_what_the_policy_keeps_changes_no_bit_of_the_loss_or_of_a_gradient(dtype, rows, monkeypatch):
+    """A policy changes what is STORED: a step's loss and every leaf's gradient
+    with the experts' names kept equal those without them bit for bit, where
+    the held rows fit the bound (~64 of 128 assignments under 96) and where the
+    bound is so tight (16) that the pieces behind it run. Four scanned layers of
+    a half share at toy widths: the Mellum-shaped toy compiles four times as
+    long and runs the same block."""
+    cfg = TransformerConfig(**{**TRAINS, "dtype": dtype}, num_experts=8, experts_per_token=2, d_expert=16, router_score="softmax",
+                            router_bias=False, expert_share=(1, 2), remat=True)
+    monkeypatch.setattr(moe, "held_rows", lambda assignments, share: rows)
+    params = init_params(jax.random.PRNGKey(3), cfg)
+    batch = {"tokens": jnp.asarray(np.random.default_rng(3).integers(0, 64, (2, 33), dtype=np.int32))}
+    step = lambda: jax.jit(jax.value_and_grad(lambda p: transformer.loss_fn(p, batch, cfg)))(params)  # noqa: E731
+    held = np.asarray(transformer.moe_stats(params, batch, cfg)["assignments"])[:, 4:].sum(axis=1)
+    assert (held > rows).all() if rows == 16 else (held <= rows).all()
+    loss, grads = step()
+    monkeypatch.setattr(transformer, "_KEPT_UNDER_REMAT", _policy_without_the_experts_names())
+    want, want_grads = step()
+    assert np.isfinite(float(loss)) and float(loss) == float(want)
+    for (path, got), ref in zip(jax.tree_util.tree_leaves_with_path(grads), jax.tree.leaves(want_grads)):
+        assert np.array_equal(np.asarray(got), np.asarray(ref)), jax.tree_util.keystr(path)
+
+
 # -- what trains now and what is still refused --------------------------------------------------------------------
 
 YARN = dict(factor=4, original_max_position_embeddings=8, beta_fast=32, beta_slow=1, mscale=1, mscale_all_dim=0)
