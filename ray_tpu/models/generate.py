@@ -615,18 +615,25 @@ def _embed_chunk(params, tokens, pos, cfg):
     return x, pos_b[:, None] + offs[None, :]
 
 
-def _cache_mask(positions, n_keys: int, window: int, key_len=None, key_pos=None):
+def _cache_mask(positions, n_keys: int, window: int, key_len=None, key_pos=None, block: int = 0):
     """[B, q, n_keys], True = attend. Causal against the cache: the query at
     positions[b, j] sees rows at positions <= its own — under ``key_len[b]``
     where given (a ragged prompt's padding) and within the sliding window.
     View row i holds position i, or ``key_pos[b, i]`` where given (a ring's
-    rows, ``_ring_access``; negative: a row nothing was written to yet)."""
+    rows, ``_ring_access``; negative: a row nothing was written to yet).
+    ``block`` (``cfg.block_diffusion``): causal BETWEEN blocks of that many
+    aligned positions and two-way INSIDE one: the query at position p sees the
+    rows under the end of p's own block, ``(p // block + 1) * block``, in a
+    prompt's chunk and in a block's pass alike."""
     if key_pos is None:
         k_pos = jnp.arange(n_keys, dtype=jnp.int32)
         keys = lambda: k_pos[None, None, :]  # noqa: E731
     else:
         keys = lambda: key_pos[:, None, :]  # noqa: E731
-    mask = keys() <= positions[:, :, None]
+    if block:
+        mask = keys() < ((positions // block + 1) * block)[:, :, None]
+    else:
+        mask = keys() <= positions[:, :, None]
     if key_len is not None:
         mask = mask & (keys() < key_len[:, None, None])
     if window:
@@ -883,7 +890,7 @@ def _conv_mixer(lp, x, pool, at, acc: _StateAccess, cfg):
         return y.astype(x.dtype) @ lp["wo"].astype(x.dtype), pool
 
 
-def _cached_layers(params, x, cache, positions, access, cfg, key_len=None, valid=None, parts=None):
+def _cached_layers(params, x, cache, positions, access, cfg, key_len=None, valid=None, parts=None, step=None):
     """THE layer stack over a cache, dense or paged: x [B, q, D] (``_embed_chunk``'s: under
     hyper-connections the stream, [B, q, hc_mult * D], which the layer scan carries) at
     ``positions`` [B, q] -> (final normed hidden states [B, q, D], cache).
@@ -905,7 +912,11 @@ def _cached_layers(params, x, cache, positions, access, cfg, key_len=None, valid
     donates ``cache`` gets it updated in place.
     Masked (p == 0) entries contribute nothing, so stale rows past a
     position, padding and null-block garbage stay invisible. ``valid`` [B, q]:
-    the rows that are real tokens (read by routed experts only).
+    the rows that are real tokens (read by routed experts only). ``step``:
+    whether the expert counters (``MOE_COUNTS``) take this call for a step or
+    for a chunk, told by the caller where q does not say (None: a step feeds
+    one token a row; a pass over a block of ``cfg.block_diffusion`` positions
+    a row is a step too).
 
     Which stacks of ``params`` run, in what order and which kind lies where
     is ``_layer_plan``'s to say; a layer's index into its group counts through
@@ -945,7 +956,7 @@ def _cached_layers(params, x, cache, positions, access, cfg, key_len=None, valid
                     o = _cache_attention_in_place(of(part, qh), pool["k"], pool["v"], at, acc.tables, part.positions, cfg)
                 else:
                     ck, cv = (acc.view(pool[name], at) for name in ("k", "v"))
-                    mask = _cache_mask(part.positions, ck.shape[1], cfg.sliding_window, None, acc.key_pos)
+                    mask = _cache_mask(part.positions, ck.shape[1], cfg.sliding_window, None, acc.key_pos, cfg.block_diffusion)
                     o = _cache_attention(of(part, qh), ck, cv, mask, cfg)  # the query heads: not the zero heads a cached row may carry
                 out.append(o.reshape(1, -1, o.shape[2] * o.shape[3]))
         return jnp.concatenate(out, axis=1), pool
@@ -1020,7 +1031,7 @@ def _cached_layers(params, x, cache, positions, access, cfg, key_len=None, valid
                         seen = {name: acc.view(pool[name + sfx], at) for name in rows}
                         n_keys = next(iter(seen.values())).shape[1]
                         window = 0 if kind == _FULL else cfg.sliding_window
-                        mask = _cache_mask(positions, n_keys, window, key_len, acc.key_pos)
+                        mask = _cache_mask(positions, n_keys, window, key_len, acc.key_pos, cfg.block_diffusion)
                         if latent:
                             o = _latent_attention(lp, qh, seen, mask, cfg)
                         else:
@@ -1142,8 +1153,8 @@ def _cached_layers(params, x, cache, positions, access, cfg, key_len=None, valid
             columns = [sent, touched, jnp.max(sent, axis=-1, keepdims=True), jnp.minimum(routed, 1), routed]
         else:
             columns = [sent, touched, jnp.max(sent, axis=-1, keepdims=True), jnp.minimum(touched, 1)]
-        step = jnp.concatenate(columns, axis=-1)
-        pool[MOE_COUNTS] = counts.at[0 if q == 1 else 1].add(step.astype(counts.dtype))
+        routed_now = jnp.concatenate(columns, axis=-1)
+        pool[MOE_COUNTS] = counts.at[0 if (q == 1 if step is None else step) else 1].add(routed_now.astype(counts.dtype))
     if cfg.hc_mult:  # the stream ends as the sum of its rows
         x = hyper_connection.collapse(x, cfg.hc_mult)
     return _rms_norm(x, params["norm_f"], cfg.norm_eps), pool
@@ -1353,7 +1364,7 @@ def _ring_access(ring_tables, positions, valid_to, block_size: int, n_view: int)
 
 def paged_decode_chunk_hidden(
     params, tokens, cache, block_tables, pos, cfg: TransformerConfig, valid_to=None, ring_tables=None,
-    state_slots=None, state_fresh=None,
+    state_slots=None, state_fresh=None, step=None,
 ):
     """``paged_decode_chunk`` without the head projection: returns the final
     normed hidden states [B, q, D] + cache. Chunked prefill consumes logits
@@ -1364,7 +1375,8 @@ def paged_decode_chunk_hidden(
     their group (None: row b is slot b, B the group's slots), and
     ``state_fresh`` [B] bool, the rows whose state starts from zero (None:
     none). A row moves its slot's state by the tokens that are real: those
-    under ``valid_to``, of a live row (one whose table starts at a real block)."""
+    under ``valid_to``, of a live row (one whose table starts at a real block).
+    ``step``: ``_cached_layers``' (a pass over a block a row says True)."""
     pos = jnp.asarray(pos, jnp.int32)
     block_tables = jnp.asarray(block_tables, jnp.int32)
     x, positions = _embed_chunk(params, tokens, pos, cfg)
@@ -1392,7 +1404,7 @@ def paged_decode_chunk_hidden(
         valid = jnp.broadcast_to(block_tables[:, :1] != 0, positions.shape)
         if valid_to is not None:
             valid &= positions < jnp.asarray(valid_to, jnp.int32)[:, None]
-    return _cached_layers(params, x, cache, positions, access, cfg, valid=valid)
+    return _cached_layers(params, x, cache, positions, access, cfg, valid=valid, step=step)
 
 
 def paged_decode_chunk(
@@ -1494,7 +1506,7 @@ def _kth_largest(x, k):
     return lax.bitcast_convert_type(u, jnp.float32)
 
 
-def draw_tokens(logits, temperature, top_k, seed, counter):
+def draw_tokens(logits, temperature, top_k, seed, counter, with_prob: bool = False):
     """The next token of each row, drawn inside the program that computed
     ``logits`` [B, V] float32, every row by its own rule (all traced, so one
     compiled program serves every mix of rows):
@@ -1512,7 +1524,10 @@ def draw_tokens(logits, temperature, top_k, seed, counter):
     64-bit seed, which ARE the threefry key, and ``counter`` [B] int32 is the
     index of the token drawn. Nothing is carried or split, so a row draws the
     same token alone or in a batch, in any row, from any program, on any host.
-    Returns [B] int32."""
+    Returns [B] int32; ``with_prob``: (that, [B] float32: the probability the
+    distribution a row drew from gave its token: of ``softmax(logits)`` for a
+    greedy row, of the tempered and ``top_k``-restricted softmax for a sampled
+    one), what generation by diffusion over blocks calls a position's confidence."""
     greedy = jnp.argmax(logits, axis=-1).astype(jnp.int32)
     sampled = temperature > 0.0
     scaled = logits / jnp.where(sampled, temperature, 1.0)[:, None]
@@ -1531,7 +1546,30 @@ def draw_tokens(logits, temperature, top_k, seed, counter):
         return jax.random.categorical(jax.random.fold_in(key, n), row)
 
     drawn = jax.vmap(draw_row)(seed, counter, scaled).astype(jnp.int32)
-    return jnp.where(sampled, drawn, greedy)
+    ids = jnp.where(sampled, drawn, greedy)
+    if not with_prob:
+        return ids
+    taken = jnp.take_along_axis(scaled, ids[:, None], axis=-1)[:, 0]
+    return ids, jnp.exp(taken - jax.nn.logsumexp(scaled, axis=-1))
+
+
+# In a block's ids (generation by diffusion over blocks): a position still masked. The bitmap of what is masked IS
+# ``ids < 0``: the model is fed ``cfg.mask_token_id`` there, and a position that drew that very id is not masked again.
+BLOCK_MASKED = -1
+
+
+def transfer_block(ids, drawn, confidence, n_transfer):
+    """One denoising pass's transfer: ``ids`` [S, B] int32, ``BLOCK_MASKED`` where a position is still masked,
+    ``drawn`` [S, B] what the pass drew at every position and ``confidence`` [S, B] float32 the probability it drew
+    it with -> the block's ids after the pass. Only masked positions take what they drew, and a position that did is
+    never masked or drawn again. Row s takes the ``n_transfer[s]`` masked positions of largest confidence (the
+    leftmost of equals; all that are left where fewer are; 0: none, a commit pass)."""
+    masked = ids < 0
+    c = jnp.where(masked, confidence, -jnp.inf)
+    at = jnp.arange(ids.shape[1])
+    ahead = (c[:, None, :] > c[:, :, None]) | ((c[:, None, :] == c[:, :, None]) & (at[None, None, :] < at[None, :, None]))
+    rank = jnp.sum(ahead, axis=-1)  # [S, B]: positions of the row ahead of this one, by confidence and then from the left
+    return jnp.where(masked & (rank < n_transfer[:, None]), drawn, ids)
 
 
 def _sample(logits, key, temperature: float, top_k: int):

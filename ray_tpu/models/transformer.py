@@ -227,6 +227,13 @@ class TransformerConfig:
     # too, ``latent_softmax_scale``), of every layer of a model without a
     # pattern, and of a pattern's full layers (``layer_rope``).
     rope_scaling: tuple = ()
+    # Generation by diffusion over blocks (inference only; the ``sdar`` family): the length of a block, 0 for an
+    # autoregressive model. Attention is causal BETWEEN blocks of ``block_diffusion`` aligned positions and two-way
+    # INSIDE one (``generate._cache_mask``); a block is generated from ``mask_token_id`` at every unknown position by
+    # denoising passes that each feed the whole block and keep some of what they draw, then committed to the cache by
+    # one more pass (serve/llm/engine.py). The logits at a position predict that position's own token.
+    block_diffusion: int = 0
+    mask_token_id: int = 0
     # Fuse the LM-head projection into a chunked cross-entropy
     # (ops/losses.fused_lm_loss) so the [B*T, V] f32 logits tensor never
     # hits HBM — loss_fn only; forward() still returns full logits for
@@ -252,9 +259,16 @@ class TransformerConfig:
             (self.hc_mult and not self.latent_attention, "hyper-connections (hc_mult > 0) without latent attention (kv_lora_rank > 0)"),
             (self.hc_mult and kinds, "hyper-connections (hc_mult > 0) under a layer pattern (layer_kinds)"),
             (self.rope_scaling and kinds and "full" not in kinds and not self.latent_attention, "rope_scaling under a layer pattern without full layers, the ones it scales"),
+            (self.block_diffusion and set(kinds) & {"linear", "mamba", "conv"}, "generation by diffusion over blocks (block_diffusion > 0) beside layers that keep a state a slot (layer_kinds has 'linear', 'mamba' or 'conv'), which a pass over a block would move before the block is final"),
+            (self.block_diffusion and kinds, "generation by diffusion over blocks (block_diffusion > 0) under a layer pattern (layer_kinds)"),
+            (self.block_diffusion and self.latent_attention, "generation by diffusion over blocks (block_diffusion > 0) over a latent pool (kv_lora_rank > 0)"),
+            (self.block_diffusion and self.sliding_window, "generation by diffusion over blocks (block_diffusion > 0) under a sliding window"),
+            (self.block_diffusion and self.hc_mult, "generation by diffusion over blocks (block_diffusion > 0) over hyper-connections (hc_mult > 0)"),
         ):
             if field:
                 raise ValueError(f"{what}: has not run and is not built")
+        if self.block_diffusion < 0 or (self.block_diffusion and not 0 <= self.mask_token_id < self.vocab_size):
+            raise ValueError(f"block_diffusion {self.block_diffusion} with mask_token_id {self.mask_token_id}: a block of at least one position and a mask id of the vocabulary")
         if kinds and (len(kinds) != self.n_layers or set(kinds) - {"window", "full", "linear", "mamba", "experts", "conv"}):
             raise ValueError(
                 f"layer_kinds names {len(kinds)} layers {sorted(set(kinds))}: need "
@@ -396,6 +410,8 @@ class TransformerConfig:
             missing.append("an embedding multiplier (embed_multiplier) has no training block")
         if self.hc_mult:
             missing.append("a residual path of several streams (hc_mult) has no training block")
+        if self.block_diffusion:
+            missing.append("generation by diffusion over blocks (block_diffusion) trains on a doubled sequence, noisy blocks beside clean ones, which has no training block")
         return "; ".join(missing)
 
 

@@ -107,7 +107,13 @@ _KERNEL_ROWS_A_GROUP = 8
 def grouped_matmul_tiles(d_model: int, d_expert: int) -> bool:
     """Whether ``jax.lax.ragged_dot``'s kernel tiles an expert's two widths:
     both are multiples of its tile, or smaller than one (nothing to divide: the
-    sizes tests run at)."""
+    sizes tests run at). A width of 768 (SDAR-30B-A3B's experts) is neither: on
+    a TPU its calls never come here, since a pass over 128 blocks of 4 and a
+    chunk of 512 are both 4,096 assignments, 32 rows a group, which
+    ``experts_run`` sends through ``ops/grouped_matmul.py``'s kernel (whose
+    strips are whole lanes, and 768 is six); on the CPU, at the published
+    width, it is ``"every_expert"``; the toy sizes' 32 is under a tile and
+    runs ``ragged_dot``."""
     return all(n % _GROUPED_TILE == 0 or n < _GROUPED_TILE for n in (d_model, d_expert))
 
 
@@ -131,6 +137,12 @@ def experts_run(assignments: int, experts: int, d_model: int, d_expert: int) -> 
     experts anyway, the same bytes read at 90 % of the HBM peak (1.7 ms for both
     matrices of 64 experts at 64 rows, 3.5 ms at 512 rows, where ``ragged_dot``
     takes 15 and 20).
+
+    So a width of 768 (SDAR-30B-A3B: 128 experts, 8 a token) takes the kernel
+    on a TPU from 1,024 assignments on, in multiples of its row tile (the
+    engine's passes and chunks: 4,096, 32 rows a group), and ``"every_expert"``
+    everywhere else: on the CPU, and for a lone call of fewer rows on a TPU
+    (768 is no multiple of ``ragged_dot``'s 512-wide tile).
 
     ``generate._cached_layers`` asks it (``generate.experts_run``) for how a
     program holds the expert stacks (whole for a grouped matmul, sliced by the

@@ -65,6 +65,32 @@ vLLM-lineage iteration-level scheduler on top of the paged KV cache:
   ``rows``, ``view_blocks``, ``context_tokens`` the step it lands;
   ``stats()`` counts ``decode_steps``, ``decode_steps_run_ahead`` and
   ``decode_rows_dropped``.
+- **generation by diffusion over blocks** (``cfg.block_diffusion = B > 0``): a
+  running row owns a BLOCK of B aligned positions, not a position. A pass feeds
+  all B of every row (``generate.paged_decode_chunk_hidden`` at q = B under the
+  mask "causal between blocks, two-way inside one"), writes their keys, values
+  and expert choices over what the pass before left, draws every position and
+  keeps (transfers) some of what it drew at positions still masked
+  (``generate.transfer_block``); when none is masked one more pass, the COMMIT,
+  feeds the final ids, and only then does the row's position move by B and are
+  the block's tokens emitted, up to B of them into one stream under one
+  ``llm.emit`` stamp. Rows admitted at different times are in different passes
+  of their blocks inside one program. The schedule is static (pass s of
+  ``denoising_steps`` transfers ``B // S`` (+ 1 for the first ``B mod S``)
+  positions of largest confidence), so the host knows BY COUNT which pass of its
+  block every row is in and when it commits, and the loop stays one pass ahead
+  exactly as above: pass N + 1 takes its blocks from pass N's ``[num_slots, B]``
+  output on the device (a row that committed in N starts from ``MASK``), and N
+  is fetched and emitted after N + 1 is dispatched. Only the passes of a request
+  that asked for them one by one (``submit(return_block_passes=True)``, the
+  benchmark's check) are fetched before the next is dispatched
+  (``stats()["block_passes_synced"]``). A schedule that moves a row by what a
+  pass finds (every position over a confidence) needs "how far each row moved"
+  decided on the device and is ROADMAP R7's. A prompt
+  of n tokens is prefilled to the last block's edge, ``n - n mod B``; the rest
+  starts its first block. A request ends by count of TOKENS, inside a block if
+  need be: the block's leading tokens only are emitted. The record's ``rows`` stays
+  the rows fed; ``block_commits`` and ``tokens_unmasked`` say what a pass did.
 - **streaming**: each request carries a queue the scheduler feeds token by
   token; ``LLMRequest`` iterates it — the replica's ``StreamingResponse``
   pump drains that iterator straight onto the HTTP socket. Each id carries
@@ -167,6 +193,9 @@ class LLMRequest:
         # submit(return_state=True): filled as the request completes.
         self.return_state = False
         self.state: Optional[np.ndarray] = None
+        # submit(return_block_passes=True) (generation by diffusion over blocks): one record a pass of each block.
+        self.return_block_passes = False
+        self.block_passes: list = []
         self.t_recv: Optional[float] = None  # the proxy's stamp, same clock
         self.t_submit = time.monotonic()
         self.t_admit: Optional[float] = None  # first admission
@@ -187,6 +216,14 @@ class LLMRequest:
         self._sched_registered_bids: set[int] = set()
         self._sched_hashes: list[bytes] = []
         self._sched_admit_seq = -1
+        # Generation by diffusion over blocks (``LLMEngine._block_begin``), all AS DISPATCHED, a pass ahead of what
+        # has landed: the start of the row's block, the index of its next denoising pass (0: the pass takes the block
+        # from the host, its known tokens and MASK; later ones from the pass before on the device), the positions still
+        # masked, and the tokens the request will have emitted once every dispatched pass has landed.
+        self._sched_bstart = 0
+        self._sched_bpass = 0
+        self._sched_bmasks = 0
+        self._sched_emit_ahead = 0
         # Fetched KV import awaiting admission-time scatter: (host payload
         # [2, L, n_blocks, Bs, KV, Dh], kv_pos tokens it covers). Set by the
         # SUBMIT thread (the network pull must not stall the scheduler);
@@ -291,6 +328,16 @@ _STATE_COLS = 2
 # this slot, still on the device (``decode``'s ``ids`` argument).
 _ID_IN_FLIGHT = -1
 
+# Generation by diffusion over blocks: a block pass's row carries, between the
+# head and the table, its block's ``cfg.block_diffusion`` ids: a token's id,
+# ``generate.BLOCK_MASKED`` (-1) for a position still masked, or this one in
+# every column for "the block as the pass before left it", still on the device
+# (``block_pass``'s ``carry`` argument). Column ``_ROW_TOKEN`` holds how many
+# positions the pass transfers (0: a commit pass), ``_ROW_POS`` the block's
+# start, ``_ROW_COUNTER`` ``(start - prompt length) * B + the pass's index``: the
+# noise of position j is ``fold_in(key(seed), (its token's index) * B + pass)``.
+_BLOCK_CARRIED = -2
+
 # In a decode row's token column of a step that carries a prompt's LAST chunk,
 # on the (otherwise inactive) row of the prefilling request's slot: the id the
 # step returns for this slot is the one drawn from the chunk, the request's
@@ -336,8 +383,8 @@ def _view_rungs(n_max: int) -> tuple:
     return (*rungs, n_max)
 
 
-def _draw_row_tokens(logits, rows):
-    """``draw_tokens`` from the draw columns of the programs' int32 rows."""
+def _draw_row_tokens(logits, rows, **how):
+    """``draw_tokens`` from the draw columns of the programs' int32 rows (``how``: its keywords)."""
     import jax.numpy as jnp
     from jax import lax
 
@@ -350,6 +397,7 @@ def _draw_row_tokens(logits, rows):
         draw[:, 1],
         lax.bitcast_convert_type(draw[:, 2:4], jnp.uint32),
         rows[:, _ROW_COUNTER],
+        **how,
     )
 
 
@@ -463,6 +511,48 @@ def _compiled_fns(cfg, ring: int = 0):
         return fns
 
 
+def _block_pass_fn(cfg):
+    """``block_pass(params, rows [num_slots, 7 + B + w], pool, carry [num_slots, B])
+    -> (blocks [num_slots, B] int32, pool)``: one pass of generation by
+    diffusion over blocks, every row's block of ``B = cfg.block_diffusion``
+    positions at once (the row's columns: ``_BLOCK_CARRIED``). The program ends
+    in the draw (scope ``block_draw``): the head over all ``num_slots * B`` rows,
+    ``draw_tokens`` with each position's own noise, the confidence, and the
+    transfer by each row's count (``generate.transfer_block``). The host fetches the
+    blocks, ``generate.BLOCK_MASKED`` where a position is still masked, and
+    never logits."""
+    with _JIT_LOCK:
+        fn = _JIT_CACHE.get((cfg, "block"))
+        if fn is None:
+            import jax
+            import jax.numpy as jnp
+
+            from ray_tpu.models.generate import paged_decode_chunk_hidden, transfer_block
+            from ray_tpu.models.transformer import _logits
+
+            B = cfg.block_diffusion
+            table_at = _ROW_TABLE + B
+
+            def block_pass(p, rows, c, carry):
+                S = rows.shape[0]
+                given = rows[:, _ROW_TABLE:table_at]
+                ids = jnp.where(given == _BLOCK_CARRIED, carry, given)
+                fed = jnp.where(ids < 0, cfg.mask_token_id, ids)
+                x, c = paged_decode_chunk_hidden(p, fed, c, rows[:, table_at:], rows[:, _ROW_POS], cfg, step=True)
+                with jax.named_scope("block_draw"):
+                    logits = _logits(p, x.reshape(S * B, -1))
+                    # A row's head once a position, each with its own counter (under 0: a prompt's token inside the first block, never drawn).
+                    counter = rows[:, _ROW_COUNTER, None] + B * jnp.arange(B, dtype=jnp.int32)[None]
+                    heads = jnp.repeat(rows[:, :_ROW_TABLE], B, axis=0).at[:, _ROW_COUNTER].set(jnp.maximum(counter.reshape(-1), 0))
+                    drawn, confidence = _draw_row_tokens(logits, heads, with_prob=True)
+                    return transfer_block(ids, drawn.reshape(S, B), confidence.reshape(S, B), rows[:, _ROW_TOKEN]), c
+
+            # The name is how the benchmark's trace_programs pattern finds the pass in a trace: keep it.
+            fn = jax.jit(block_pass, donate_argnums=2)
+            _JIT_CACHE[(cfg, "block")] = fn
+        return fn
+
+
 # The transfer plane's payload is [2, L, blocks, Bs, KV, Dh]: keys and values
 # (kv_transfer.seal_kv_payload, LLMEngine._scatter_import; ROADMAP D5).
 _LATENT_POOL_KV_PAYLOAD = (
@@ -503,8 +593,20 @@ _CONV_POOL_KV_PAYLOAD = (
 )
 
 
+# And of a prefix that ends where the next token is drawn. Under generation by
+# diffusion over blocks a prompt is cached up to its last block's edge and the
+# first token comes from a block pass, not from the prompt's last chunk.
+_BLOCK_POOL_KV_PAYLOAD = (
+    "{what} needs the KV transfer plane, whose hand-off carries a prompt's rows and the first token drawn from its "
+    "last chunk; under generation by diffusion over blocks (block_diffusion > 0) a prompt is cached to its last "
+    "block's edge and tokens come from block passes (serve/llm/engine.py, ROADMAP R7)"
+)
+
+
 def _kv_payload_refusal(cfg) -> Optional[str]:
     """Why this configuration's pool cannot feed the KV transfer plane (None: it can)."""
+    if cfg.block_diffusion:
+        return _BLOCK_POOL_KV_PAYLOAD
     if cfg.latent_attention:
         return _LATENT_POOL_KV_PAYLOAD
     if "linear" in cfg.layer_kinds:
@@ -534,9 +636,9 @@ class _Step:
     of a prompt, that request: its last token's row rode in the step too, and
     the step draws its first token into its slot's id."""
 
-    __slots__ = ("ids", "reqs", "slots", "rows", "width", "context_tokens", "window_tokens")
+    __slots__ = ("ids", "reqs", "slots", "rows", "width", "context_tokens", "window_tokens", "passes")
 
-    def __init__(self, ids, active: list, first, width: int, context_tokens: int, window_tokens: int):
+    def __init__(self, ids, active: list, first, width: int, context_tokens: int, window_tokens: int, passes=None):
         self.ids = ids
         self.reqs = active + [first] if first is not None else active
         self.slots = [r._sched_slot for r in self.reqs]
@@ -544,6 +646,9 @@ class _Step:
         self.width = width
         self.context_tokens = context_tokens
         self.window_tokens = window_tokens
+        # A block pass (generation by diffusion over blocks): per request (the block's start, the index of this
+        # pass in the block, the positions masked before it, whether it is the commit pass).
+        self.passes = passes
 
 
 class LLMEngine:
@@ -561,7 +666,10 @@ class LLMEngine:
         cluster_prefix: bool = False,
         cluster_prefix_max: int = 16,
         handoff_ttl_s: float = 120.0,
+        denoising_steps: int = 0,
     ):
+        """``denoising_steps``: read only where ``cfg.block_diffusion`` (the module
+        docstring's paragraph): denoising passes a block (0: one a position)."""
         from ray_tpu.models.generate import (
             MOE_CHOICE,
             MOE_COUNTS,
@@ -616,7 +724,8 @@ class LLMEngine:
         # (``generate.kernel_reads``: a latent pool, or one group of key and
         # value leaves, on a TPU), stops at each row's length whatever the
         # table's width: the whole table, one program, no ladder.
-        self._reads_in_place = kernel_reads(cfg, paged=True, q=1)
+        self._block = int(cfg.block_diffusion)
+        self._reads_in_place = kernel_reads(cfg, paged=True, q=self._block or 1)
         # A latent pool's prefill program asks the same predicate at the chunk's width, for ``latent_kernel_chunks``.
         self._chunk_reads_in_place = latent_kernel_reads(cfg, paged=True, q=int(prefill_chunk))
         self._latent_scale = latent_softmax_scale(cfg) if cfg.latent_attention else None
@@ -627,6 +736,17 @@ class LLMEngine:
         # — preemption-free unless the caller sizes the pool down.
         self.num_blocks = int(num_blocks or self.num_slots * self.n_max + 1)
         self.prefill_chunk = int(prefill_chunk)
+        if self._block:
+            # Then a cached block's rows depend on that block's tokens and its predecessors' only, a chunk starts and
+            # ends on a block's edge, and the chain hash of the prefix cache stays what it is.
+            if self.block_size % self._block or self.prefill_chunk % self._block:
+                raise ValueError(
+                    f"block_diffusion = {self._block} must divide block_size = {self.block_size} and "
+                    f"prefill_chunk = {self.prefill_chunk}"
+                )
+            self.denoising_steps = int(denoising_steps) or self._block
+            if not 1 <= self.denoising_steps <= self._block:
+                raise ValueError(f"denoising_steps {denoising_steps}: between 1 and the block's {self._block} positions")
         listen_for_compiles()
         listen_for_gc()
         self.spans = EngineSpans()
@@ -749,10 +869,17 @@ class LLMEngine:
             # (linear-attention layers: every admission, a re-admission too).
             "state_resets": 0,
         }
+        if self._block:
+            # Generation by diffusion over blocks: passes dispatched (each is a decode step too), rows of them
+            # that committed a block, tokens those commits emitted, and passes fetched before the next was
+            # dispatched (a request that asked for its passes one by one).
+            self._counts.update(block_passes=0, block_commits=0, block_tokens_emitted=0, block_passes_synced=0)
         # The decode step in flight: dispatched, its ids not fetched.
         self._inflight: Optional[_Step] = None
         with stage(self.spans.stages, "jit_build"):
             self._decode_fn, self._prefill_fn, self._fused_fn = _compiled_fns(cfg, self.ring_blocks)
+            if self._block:
+                self._decode_fn = _block_pass_fn(cfg)
         _with_room(self._build_programs)
         # Prefill passes whose routed experts' matmuls run the grouped kernel whose row tile fits a group: the
         # prefill program's own predicate (``generate.experts_run``) asked at the chunk's rows, all of them or none.
@@ -792,8 +919,20 @@ class LLMEngine:
         t_recv_ns: int = 0,
         return_routed_experts: bool = False,
         return_state: bool = False,
+        return_block_passes: bool = False,
     ) -> LLMRequest:
-        """``return_state`` (layers that keep something a slot): a request that
+        """``return_block_passes`` (generation by diffusion over blocks): ``req.block_passes``
+        gains one record a pass of every block the request generates, in order:
+        ``{"start", "pass", "commit", "ids", "experts"}``: the block's first
+        position, the index of the pass in its block, whether it was the commit
+        pass, the block's B ids AFTER the pass (``generate.BLOCK_MASKED`` where
+        still masked; a position holds a token from the pass that transferred it
+        on) and, with routed experts, the experts each position took IN the pass,
+        int [B, expert layers, k]. Such a request's passes are fetched one by one
+        (``stats()["block_passes_synced"]``): what the benchmark's float32
+        reference needs to rebuild each pass's input, and nothing traffic asks for.
+
+        ``return_state`` (layers that keep something a slot): a request that
         completes leaves in ``req.state`` what its slot's recurrent state is
         after the last token fed to the model (prompt + generated - 1: a request
         ends by count and the last token drawn is never fed), [linear layers,
@@ -863,6 +1002,9 @@ class LLMEngine:
                 "(layers that keep something a slot)"
             )
         req.return_state = bool(return_state)
+        if return_block_passes and not self._block:
+            raise ValueError("return_block_passes needs a model generated by diffusion over blocks (block_diffusion > 0)")
+        req.return_block_passes = bool(return_block_passes)
         req.request_id = str(request_id or "")
         req.t_recv = int(t_recv_ns) / 1e9 if t_recv_ns else None
         ctx = tracing.get_current_span_context()
@@ -958,6 +1100,8 @@ class LLMEngine:
             "pending_exports": len(self._exports),
             **self._counts,
             "decode_width_steps": dict(self._width_steps),
+            **({"block_length": self._block, "denoising_steps": self.denoising_steps}
+               if self._block else {}),
             **self.spans.totals(),
         }
 
@@ -1441,6 +1585,9 @@ class LLMEngine:
         """A pass's programs: the chunk inside the decode step where the
         engine's shape fuses and a row decodes, else the chunk, then the step."""
         chunk = self._next_prefill()
+        if self._block:
+            busy = self._prefill_tick(chunk)
+            return self._block_tick() or busy
         if chunk is not None and self._fuses and self._decoding(self._inflight):
             return self._decode_tick(chunk)
         busy = self._prefill_tick(chunk)
@@ -1553,7 +1700,9 @@ class LLMEngine:
                 _flight.record("llm_prefix_hit", f"{req.id}:{cached}blk")
             req._sched_table = table
             req._sched_pos = cached * self.block_size
-            req._sched_target = target
+            # Under generation by diffusion over blocks the chunks fill up to the last block's edge; what lies
+            # behind it (of a fresh request: the prompt's last ``n mod B`` tokens) starts the row's first block.
+            req._sched_target = target - target % self._block if self._block else target
             if req._sched_kv_import is not None:
                 self._scatter_import(req, cached)
             if req.t_admit is None:
@@ -1561,6 +1710,8 @@ class LLMEngine:
                 req.cached_tokens = req._sched_pos
             admitted += 1
             req._sched_state = "prefill"
+            if self._block and req._sched_pos >= req._sched_target:  # nothing to prefill: a prompt inside one block, or all of it cached
+                self._block_begin(req)
             req._sched_slot = slot
             req._sched_admit_seq = next(self._admit_seq)
             self._slots[slot] = req
@@ -1658,7 +1809,11 @@ class LLMEngine:
         self._counts["grouped_kernel_chunks"] += self._chunk_experts_in_kernel
         with spans.span("llm.prefill.dispatch", rid=req.id):
             drawn = self._run_donated(self._prefill_fn, *inputs)
-        if self._chunk_dispatched(req):
+        if self._block:
+            # No token comes from a prompt's chunk (a position predicts itself: the first comes from a block pass), so nothing is fetched.
+            if self._chunk_dispatched(req):
+                self._block_begin(req)
+        elif self._chunk_dispatched(req):
             with spans.span("llm.prefill.fetch", rid=req.id):
                 # Waits for the step in flight too: it runs ahead of the chunk.
                 drawn = np.asarray(drawn)
@@ -1671,11 +1826,12 @@ class LLMEngine:
                 sp.set(finished=int(req._finished))
         return True
 
-    def _program_rows(self, n: int, width: int) -> np.ndarray:
+    def _program_rows(self, n: int, width: int, block: int = 0) -> np.ndarray:
         """``n`` all-zero rows of a program's int32 input, their block table
-        ``width`` blocks wide (behind the ring, under a layer pattern): an
+        ``width`` blocks wide (behind the ring, under a layer pattern; behind
+        the ``block`` ids of a block pass's row, ``_BLOCK_CARRIED``): an
         inactive slot (token 0 at position 0 of the null block, drawn greedily)."""
-        return np.zeros((n, _ROW_TABLE + self.ring_blocks + self._state_cols + width), np.int32)
+        return np.zeros((n, _ROW_TABLE + self.ring_blocks + self._state_cols + block + width), np.int32)
 
     def _fill_row(self, row: np.ndarray, req: LLMRequest, first: int, pos: int, ahead: int = 0):
         """``first``: column 0, the token fed (decode) or valid_to (prefill);
@@ -1700,7 +1856,7 @@ class LLMEngine:
         drawn, self._cache = fn(self.params, tokens, pool, *rest)
         if not all(leaf.is_deleted() for leaf in pool.values()):
             self._counts["kv_pool_not_donated"] += 1
-        if drawn.ndim != 1:
+        if drawn.ndim != (2 if self._block and fn is self._decode_fn else 1):  # a block pass hands back [num_slots, B] ids
             # The draw belongs inside the program: one that hands back
             # ``[rows, V]`` logits is refused, there being nothing left on the
             # host to draw from them (nor a token column for the next step).
@@ -1727,6 +1883,7 @@ class LLMEngine:
             and self.num_slots + self.prefill_chunk <= _MXU_TILE_ROWS
             and one_kv_group(self.cfg)
             and not self.cfg.routed_experts
+            and not self.cfg.block_diffusion
         )
 
     def _build_programs(self):
@@ -1752,7 +1909,7 @@ class LLMEngine:
         import jax.numpy as jnp
 
         stages, threads0 = self.spans.stages, thread_cpu_ns()
-        ids = jnp.zeros((self.num_slots,), jnp.int32)
+        ids = jnp.zeros((self.num_slots, self._block) if self._block else (self.num_slots,), jnp.int32)
         chunk = jnp.zeros((1, self.prefill_chunk), jnp.int32)
         # Traced, lowered and compiled apart from the call, and all three
         # held until it returns: the call then finds the trace and the
@@ -1766,7 +1923,7 @@ class LLMEngine:
         # model takes it 8 s, seven of them in a row more than Serve gives a
         # replica to become ready (PERF.md, PR 35).
         calls = [
-            (f"decode@{w}", self._decode_fn, jnp.asarray(self._program_rows(self.num_slots, w)), ids)
+            (f"decode@{w}", self._decode_fn, jnp.asarray(self._program_rows(self.num_slots, w, self._block)), ids)
             for w in self._view_rungs
         ]
         if self._fuses:
@@ -1795,7 +1952,7 @@ class LLMEngine:
         del held
         # What a step with no step before it is given as ``ids`` (none of its
         # rows reads them): a program's own output, like every other step's.
-        self._no_ids = ids
+        self._no_ids = self._block_carry = ids
         self.spans.build_threads = busiest_threads(threads0, thread_cpu_ns())
 
     def _register_prefix_blocks(self, req: LLMRequest):
@@ -1834,6 +1991,11 @@ class LLMEngine:
         ``ahead_of`` is the step in flight, if any: a request of it is one
         token further on than the host has seen, and has no row if that token
         is its last: a request ends by count."""
+        if self._block:  # counted as dispatched, whatever is in flight (``_launch_block``)
+            return [
+                r for r in self._slots
+                if r is not None and r._sched_state == "decode" and r._sched_emit_ahead < r.max_new_tokens
+            ]
         riding = ahead_of.reqs if ahead_of is not None else ()
         return [
             r
@@ -1842,6 +2004,41 @@ class LLMEngine:
             and r._sched_state == "decode"
             and len(r._sched_generated) + (r in riding) < r.max_new_tokens
         ]
+
+    def _back_writes(self, active: list, writes_at):
+        """Every active sequence needs the last position it writes, ``writes_at(req)``,
+        backed by a physical block before the step; exhaustion preempts the
+        youngest (whatever a victim has in flight is dropped at its fetch)."""
+        bs = self.block_size
+        for req in active:
+            if req._sched_slot is None or self._slots[req._sched_slot] is not req:
+                continue  # preempted by an earlier needy sequence this tick
+            while writes_at(req) // bs >= len(req._sched_table):
+                bid = self._alloc_block()
+                if bid is not None:
+                    req._sched_table.append(bid)
+                    continue
+                # Youngest-victim policy over ALL running sequences — the
+                # needy one included: when req itself is the youngest it is
+                # the one preempted (minimal recompute), not an older
+                # sequence carrying more progress.
+                running = [r for r in self._slots if r is not None]
+                victim = max(running, key=lambda r: r._sched_admit_seq)
+                if victim is req:
+                    if len(running) == 1:
+                        # Nobody else holds blocks: preempting req would just
+                        # readmit it into the same dry pool forever.
+                        self._finish(
+                            req,
+                            error=(
+                                "KV block pool exhausted with a single "
+                                "running sequence; raise num_blocks"
+                            ),
+                        )
+                    else:
+                        self._preempt(req)
+                    break  # req left its slot; its alloc loop is moot
+                self._preempt(victim)
 
     def _launch_step(self, ahead_of: Optional[_Step], chunk: Optional[LLMRequest] = None) -> Optional[_Step]:
         """Build and dispatch one decode step over every row that decodes;
@@ -1868,38 +2065,7 @@ class LLMEngine:
             return None
         spans = self.spans
         with spans.span("llm.decode.build") as sp:
-            # Every active sequence needs its next write position backed by a
-            # physical block before the step; exhaustion preempts the youngest
-            # (the id a victim has in flight is dropped at its fetch).
-            for req in active:
-                if req._sched_slot is None or self._slots[req._sched_slot] is not req:
-                    continue  # preempted by an earlier needy sequence this tick
-                while writes_at(req) // bs >= len(req._sched_table):
-                    bid = self._alloc_block()
-                    if bid is not None:
-                        req._sched_table.append(bid)
-                        continue
-                    # Youngest-victim policy over ALL running sequences — the
-                    # needy one included: when req itself is the youngest it is
-                    # the one preempted (minimal recompute), not an older
-                    # sequence carrying more progress.
-                    running = [r for r in self._slots if r is not None]
-                    victim = max(running, key=lambda r: r._sched_admit_seq)
-                    if victim is req:
-                        if len(running) == 1:
-                            # Nobody else holds blocks: preempting req would just
-                            # readmit it into the same dry pool forever.
-                            self._finish(
-                                req,
-                                error=(
-                                    "KV block pool exhausted with a single "
-                                    "running sequence; raise num_blocks"
-                                ),
-                            )
-                        else:
-                            self._preempt(req)
-                        break  # req left its slot; its alloc loop is moot
-                    self._preempt(victim)
+            self._back_writes(active, writes_at)
             # Re-derive the step batch: preemption/failure above may have
             # removed sequences from their slots.
             active = [r for r in self._decoding(ahead_of) if writes_at(r) // bs < len(r._sched_table)]
@@ -1979,6 +2145,156 @@ class LLMEngine:
                 req._sched_pos += 1
                 self._emit_token(req, tok, sp.t0)
             sp.set(finished=sum(req._finished for req, _ in live))
+
+    # --- generation by diffusion over blocks (``cfg.block_diffusion``; the module docstring's paragraph) ---
+
+    def _block_begin(self, req: LLMRequest):
+        """``req`` is cached up to a block's edge, ``_sched_target`` (a fresh
+        prompt, a re-admission's teacher-forced tokens): from here on it is a
+        row of the block passes, its first block what lies behind the edge (a
+        prompt's last ``n mod B`` tokens) and ``MASK``, its passes counted from 0:
+        the same noise as the first time."""
+        req._sched_state = "decode"
+        req._sched_pos = req._sched_bstart = req._sched_target
+        req._sched_bpass = 0
+        req._sched_bmasks = self._block - (len(req.prompt) + len(req._sched_generated) - req._sched_target)
+        req._sched_emit_ahead = len(req._sched_generated)
+        req.block_passes = [rec for rec in req.block_passes if rec["start"] < req._sched_target]
+
+    def _block_synced(self) -> bool:
+        """Whether a pass is fetched before the next is dispatched: a request
+        in a slot asked for its passes one by one."""
+        return any(r is not None and r.return_block_passes for r in self._slots)
+
+    def _block_tick(self) -> bool:
+        """``_decode_tick`` of block passes: one pass ahead, or, where
+        ``_block_synced``, each pass landed before the next is built."""
+        step, self._inflight = self._inflight, None
+        busy = step is not None or bool(self._decoding(None))
+        if step is None:
+            step = self._launch_block(False)
+        if step is not None:
+            if self._block_synced():
+                self._counts["block_passes_synced"] += 1
+            else:
+                self._inflight = self._launch_block(True)
+            self._land_block(step)
+        return busy
+
+    def _transfers(self, req: LLMRequest) -> int:
+        """Positions ``req``'s next pass transfers: ``B // S``, one more in the first ``B mod S`` passes, no more
+        than are masked; 0 is the commit pass."""
+        S = self.denoising_steps
+        return min(self._block // S + (req._sched_bpass < self._block % S), req._sched_bmasks)
+
+    def _launch_block(self, ahead: bool) -> Optional[_Step]:
+        """Build and dispatch one pass over the block of every row that
+        decodes; None if there is none. ``ahead``: the pass before is still
+        unfetched. Everything a row is told is known by count (``_sched_b*``,
+        as dispatched): its block's start, which pass of the block this is
+        (the first takes the block from the host, the prompt's tail and
+        ``MASK``; later ones from the pass before on the device) and how many
+        positions it transfers. A commit pass moves the row on to its next block
+        here, at dispatch; its tokens are emitted when it lands."""
+        B, bs = self._block, self.block_size
+        active = self._decoding(None)
+        if not active:
+            return None
+        spans = self.spans
+        with spans.span("llm.decode.build") as sp:
+            self._back_writes(active, lambda r: r._sched_bstart + B - 1)
+            active = [r for r in self._decoding(None) if (r._sched_bstart + B - 1) // bs < len(r._sched_table)]
+            sp.set(rows=len(active))
+            if not active:
+                return None
+            import jax.numpy as jnp
+
+            from ray_tpu.models.generate import BLOCK_MASKED
+
+            longest = max(len(r._sched_table) for r in active)
+            width = next(w for w in self._view_rungs if w >= longest)
+            rows = self._program_rows(self.num_slots, width, B)
+            context_tokens, passes = 0, []
+            for req in active:
+                row, start, n = rows[req._sched_slot], req._sched_bstart, len(req.prompt)
+                commit = req._sched_bmasks == 0
+                row[_ROW_TOKEN] = moved = self._transfers(req)
+                row[_ROW_POS] = start
+                row[_ROW_DRAW] = req._sched_draw
+                row[_ROW_COUNTER] = (start - n) * B + req._sched_bpass
+                if req._sched_bpass == 0:  # a block's first pass: what is known of it, from the host
+                    known = (req.prompt + req._sched_generated)[start : start + B]
+                    row[_ROW_TABLE : _ROW_TABLE + B] = known + [BLOCK_MASKED] * (B - len(known))
+                else:
+                    row[_ROW_TABLE : _ROW_TABLE + B] = _BLOCK_CARRIED
+                row[_ROW_TABLE + B : _ROW_TABLE + B + len(req._sched_table)] = req._sched_table
+                context_tokens += start + B
+                passes.append((start, req._sched_bpass, moved, commit))
+                if commit:
+                    req._sched_emit_ahead = min(req.max_new_tokens, start + B - n)
+                    req._sched_bstart, req._sched_bpass, req._sched_bmasks = start + B, 0, B
+                else:
+                    req._sched_bmasks -= moved
+                    req._sched_bpass += 1
+            inputs = [jnp.asarray(rows), self._block_carry]
+        self._width_steps[width] += 1
+        self._counts["decode_steps"] += 1
+        self._counts["block_passes"] += 1
+        self._counts["decode_steps_run_ahead"] += ahead
+        with spans.span("llm.decode.dispatch"):
+            self._block_carry = self._run_donated(self._decode_fn, *inputs)
+        return _Step(self._block_carry, active, None, width, context_tokens, context_tokens, passes)
+
+    def _land_block(self, step: _Step):
+        """Fetch a dispatched pass's blocks; of each row that still holds its
+        slot: a commit pass's tokens, those of the block that the request has
+        not emitted and still wants, go out in order under one ``llm.emit``
+        stamp and the row's position moves on by the block."""
+        B, spans = self._block, self.spans
+        with spans.span("llm.decode.fetch"):
+            blocks = np.asarray(step.ids)  # [num_slots, B]
+        # The draw and the transfer ran inside the program; the host's share is the blocks as Python ints.
+        with spans.span("llm.sample", rows=len(step.reqs), sampled=sum(r.temperature > 0.0 for r in step.reqs), top_k=0):
+            live = [
+                (req, blocks[slot].tolist(), at)
+                for req, slot, at in zip(step.reqs, step.slots, step.passes)
+                if self._slots[slot] is req
+            ]
+        self._counts["decode_rows_dropped"] += len(step.reqs) - len(live)
+        commits = sum(commit for _, _, (_, _, _, commit) in live)
+        unmasked = sum(moved for _, _, (_, _, moved, _) in live)
+        spans.carried(
+            rows=step.rows, view_blocks=step.width, context_tokens=step.context_tokens,
+            window_tokens=step.window_tokens, block_commits=commits, tokens_unmasked=unmasked,
+        )
+        self._counts["block_commits"] += commits
+        emitted = 0
+        with spans.span("llm.emit", tokens=0) as sp:
+            for req, block, (start, nth, _, commit) in live:
+                if req.return_block_passes:
+                    rec = {"start": start, "pass": nth, "commit": commit, "ids": block}
+                    if self.cfg.routed_experts:
+                        rec["experts"] = self._block_experts(req, start)
+                    req.block_passes.append(rec)
+                if not commit:
+                    continue
+                req._sched_pos = start + B
+                first = start - len(req.prompt)  # the index among the request's tokens of the block's first position
+                for j, tok in enumerate(block):
+                    if len(req._sched_generated) <= first + j < req.max_new_tokens:
+                        emitted += 1
+                        self._emit_token(req, tok, sp.t0)
+            sp.set(tokens=emitted, finished=sum(req._finished for req, _, _ in live))
+        self._counts["block_tokens_emitted"] += emitted
+
+    def _block_experts(self, req: LLMRequest, start: int) -> np.ndarray:
+        """The experts the block at ``start`` took in the pass that ran last, from the words beside its rows:
+        [B, expert layers, k]. Read before another pass is dispatched (``_block_synced``): every pass writes them anew."""
+        from ray_tpu.models.generate import MOE_CHOICE, unpack_experts
+
+        bid, at = req._sched_table[start // self.block_size], start % self.block_size
+        words = np.asarray(self._cache[MOE_CHOICE][..., bid, at : at + self._block])  # [(words,) expert layers, B]
+        return unpack_experts(np.swapaxes(words, -1, -2), self.cfg)
 
     def _drawn_tokens(self, reqs: list, ids: np.ndarray, at: list) -> list[int]:
         """One ``llm.sample`` span a step over the host's share of its draws:

@@ -86,16 +86,20 @@ SPAN_NAMES = (
 )
 ITERATION_KINDS = ("decode", "prefill", "mixed")
 # One record per pass of the scheduler loop that dispatched a program: the
-# start stamp, what the pass carried, and the nanoseconds of each span.
+# start stamp, what the pass carried, and the nanoseconds of each span; behind
+# them what a pass of generation by diffusion over blocks did (0 in every other
+# engine's records): the rows of it that committed a block, and the positions
+# it transferred. ``rows`` is the rows FED; the tokens a pass yields are these.
 ITERATION_FIELDS = (
     "t_start_ns", "rows", "prefill_tokens", "waiting", "running", "view_blocks", "context_tokens",
     "window_tokens", "chunk_tokens", "chunk_context_tokens",
-) + SPAN_NAMES
+) + SPAN_NAMES + ("block_commits", "tokens_unmasked")
 (
     _T_START, _ROWS, _PREFILL_TOKENS, _WAITING, _RUNNING, _VIEW_BLOCKS, _CONTEXT_TOKENS, _WINDOW_TOKENS,
     _CHUNK_TOKENS, _CHUNK_CONTEXT_TOKENS,
 ) = range(10)
-_FIRST_SPAN = len(ITERATION_FIELDS) - len(SPAN_NAMES)
+_FIRST_SPAN = _CHUNK_CONTEXT_TOKENS + 1
+_BLOCK_COMMITS, _TOKENS_UNMASKED = _FIRST_SPAN + len(SPAN_NAMES), _FIRST_SPAN + len(SPAN_NAMES) + 1
 _SPAN_FIELD = {name: _FIRST_SPAN + i for i, name in enumerate(SPAN_NAMES)}
 # One record per request that ended; stamps are CLOCK_MONOTONIC nanoseconds
 # (0 = never reached), a clock every process of a host shares.
@@ -560,7 +564,7 @@ class EngineSpans:
 
     def carried(self, rows: int = 0, prefill_tokens: int = 0, view_blocks: int = 0,
                 context_tokens: int = 0, window_tokens: int = 0, chunk_tokens: int = 0,
-                chunk_context_tokens: int = 0):
+                chunk_context_tokens: int = 0, block_commits: int = 0, tokens_unmasked: int = 0):
         """What this pass dispatched: decode rows, the width in blocks of
         their step's block table, the tokens of context they hold between
         them (each row's length, the token fed included) and how many of
@@ -569,7 +573,11 @@ class EngineSpans:
         and how many of those rode inside the decode step it dispatched
         (``chunk_tokens``: all or none), and how many tokens the chunk's row
         held before it (``chunk_context_tokens``: what the chunk's attention
-        reads beside the chunk itself, whatever the width of its view)."""
+        reads beside the chunk itself, whatever the width of its view); of a
+        pass over blocks (generation by diffusion), the rows that committed
+        theirs and the positions the pass transferred."""
+        self._cur[_BLOCK_COMMITS] += block_commits
+        self._cur[_TOKENS_UNMASKED] += tokens_unmasked
         self._cur[_CHUNK_TOKENS] += chunk_tokens
         self._cur[_CHUNK_CONTEXT_TOKENS] += chunk_context_tokens
         self._cur[_ROWS] += rows

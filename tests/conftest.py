@@ -167,6 +167,13 @@ _WRONG_SINCE_A_SECOND_ARCHITECTURE = {  # the first four (PR 32)
         "test_bench_lfm2.py::test_the_new_entries_are_appended_behind_what_was_there holds the eight together behind the "
         "49, each as it was declared, and PR 54's two behind them, without a pin on the END"
     ),
+    # And since a tenth configuration, whose cell joins every list LFM2's is in but the conv mixer's two (PR 56):
+    "test_bench_lfm2.py::test_the_new_entries_are_appended_behind_what_was_there": (
+        "holds the six of a replica's start to the eight serving cells it knew and its own, and a traced line of LFM2's cell "
+        "to one set of metrics among twelve cells; PR 56 appends its cell to the six's lists and to every other list LFM2's cell "
+        "is in but short_conv_*. test_bench_sdar.py::test_the_new_entries_are_appended_behind_what_was_there holds PR 52's eight "
+        "behind the 49, PR 54's two behind them, PR 56's three behind those and every list's order, without a pin on the END"
+    ),
 }
 
 

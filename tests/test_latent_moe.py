@@ -381,7 +381,9 @@ def test_a_chunks_experts_run_the_kernel_on_a_tpu_and_a_decode_steps_run_as_befo
     there was one, which is also what every shape runs off a TPU. The decode
     programs are found by name and shape by the benchmark's ``moe_experts_*``.
     One cell's STEP is as wide as the kernel's threshold (PR 54: 128 rows x 4 of
-    64 experts, 8 rows a group) and runs the kernel too, as its ``trace_ops`` say."""
+    64 experts, 8 rows a group) and runs the kernel too, as its ``trace_ops`` say;
+    so does the pass over 128 blocks of 4 positions (PR 56: 4,096 assignments among
+    128 experts, 32 rows a group, as many as its chunk's)."""
     import importlib
 
     from ray_tpu.parallel.moe import experts_run, grouped_matmul_tiles
@@ -393,10 +395,14 @@ def test_a_chunks_experts_run_the_kernel_on_a_tpu_and_a_decode_steps_run_as_befo
     cfg = TransformerConfig(**model)
     widths = cfg.num_experts, cfg.d_model, cfg.d_expert
     before = "ragged_dot" if grouped_matmul_tiles(cfg.d_model, cfg.d_expert) else "every_expert"
-    step, chunk = (rows * cfg.experts_per_token for rows in (engine["num_slots"], engine["prefill_chunk"]))
+    # A step feeds a slot one position, or (PR 56: generation by diffusion over blocks) its block's.
+    step, chunk = (rows * cfg.experts_per_token for rows in (engine["num_slots"] * (cfg.block_diffusion or 1), engine["prefill_chunk"]))
     wide = step / cfg.num_experts >= 8  # a step of as many rows a group as ``moe._KERNEL_ROWS_A_GROUP``
     assert (step / cfg.num_experts <= 3 or wide) and chunk / cfg.num_experts >= 24
-    assert wide == (cell_name == "lfm9.rollout-wide") == ("gmm" in cell["config"]["trace_ops"]["moe_experts"])
+    assert wide == (cell_name in ("lfm9.rollout-wide", "sdar6.rollout-block"))
+    # The block pass's calls bear its chunk's names and shapes: that cell names no pattern (its ``trace_ops.why``).
+    named = cell["config"]["trace_ops"].get("moe_experts")
+    assert (named is None) == (cell_name == "sdar6.rollout-block") and (named is None or wide == ("gmm" in named))
     assert experts_run(step, *widths) == experts_run(chunk, *widths) == before  # here, on a CPU
     monkeypatch.setattr(importlib.import_module("ray_tpu.ops.attention"), "_on_tpu", lambda: True)
     assert experts_run(step, *widths) == ("kernel" if wide else before)

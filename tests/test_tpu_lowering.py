@@ -1006,6 +1006,66 @@ def test_the_conv_pattern_programs_cache_two_heads_a_row_and_copy_no_pool(one_v5
         assert stats.temp_size_in_bytes + stats.argument_size_in_bytes < 12.0e9  # 11.45 GB of arguments: 68 % of the chip's 16.9
 
 
+def test_the_block_pass_and_its_chunk_copy_no_pool_and_run_the_grouped_kernel(one_v5e_chip, monkeypatch):
+    """SDAR-30B-A3B-Chat's pass over 128 blocks of 4 positions at the widest rung
+    (2048 tokens) and its prefill chunk at the benchmark's widths, as a TPU
+    backend gets them, compiled for the v5e (PR 56) before any chip run. The
+    pool ``[6, 16385, 16, 4, 128]`` a leaf (a KV head fills the 128 lanes: no
+    pairing, no padding) and the two words a token a layer of the experts taken
+    are updated in place, never copied; both programs' 4,096 assignments are 32
+    rows a group and run ``ops/grouped_matmul.py``'s kernel over the stacks of
+    experts held WHOLE (three calls in the layer scan), at the new width 768;
+    the pass keeps the gathered view (``kernel_reads`` at q = 4: no kernel is
+    written for it) and ends in the head over ``[512, 151936]``. A pass's
+    temporaries: a layer's views of keys and of values at the rung (2 x 0.27 GB)
+    and the float32 logits with what the draw holds beside them: 0.78 GB as
+    built; the chunk's 0.14 GB."""
+    import dataclasses
+    import importlib
+    import re
+
+    import jax
+    import jax.numpy as jnp
+
+    from ray_tpu.models.generate import MOE_CHOICE, MOE_COUNTS, init_moe_choice, init_moe_counts, init_paged_cache, kernel_reads
+    from ray_tpu.models.transformer import init_params
+
+    for module in ("ray_tpu.ops.attention", "ray_tpu.ops.grouped_matmul"):
+        monkeypatch.setattr(importlib.import_module(module), "_on_tpu", lambda: True)
+    engine_module = importlib.import_module("ray_tpu.serve.llm.engine")
+    monkeypatch.setattr(engine_module, "_JIT_CACHE", {})
+    cfg, engine = _cell_config("sdar6.rollout-block")
+    slots, chunk, bs, B = engine["num_slots"], engine["prefill_chunk"], engine["block_size"], cfg.block_diffusion
+    assert not kernel_reads(cfg, paged=True, q=B) and kernel_reads(dataclasses.replace(cfg, block_diffusion=0), paged=True, q=1)
+
+    def pool():
+        leaves = init_paged_cache(cfg, engine["num_blocks"], bs)
+        leaves.update({MOE_COUNTS: init_moe_counts(cfg), MOE_CHOICE: init_moe_choice(cfg, engine["num_blocks"], bs)})
+        return leaves
+
+    params = jax.eval_shape(lambda: init_params(jax.random.PRNGKey(0), cfg))
+    ints = lambda *shape: jax.ShapeDtypeStruct(shape, jnp.int32)  # noqa: E731
+    describe = lambda a: jax.tree.map(lambda x: jax.ShapeDtypeStruct(x.shape, x.dtype, sharding=one_v5e_chip), a)  # noqa: E731
+    table = engine_module._ROW_TABLE
+    block_pass = engine_module._block_pass_fn(cfg)
+    prefill = engine_module._compiled_fns(cfg, 0)[1]
+    pool_bytes = 2 * 6 * 16385 * 16 * 4 * 128 * 2 + 2 * 6 * 16385 * 16 * 4
+    for program, given, temp in (
+        (block_pass, (params, ints(slots, table + B + 128), jax.eval_shape(pool), ints(slots, B)), 0.9e9),
+        (prefill, (params, ints(1, chunk), jax.eval_shape(pool), ints(1, table + 128)), 0.2e9),
+    ):
+        compiled = program.lower(*describe(given)).compile()
+        text = compiled.as_text()
+        assert "ragged-dot" not in text and len(re.findall(r"%gmm\S* = bf16\[4096,(?:768|2048)\]\S* custom-call\(", text)) == 3
+        for leaf in ("bf16[6,16385,16,4,128]", "s32[2,6,16385,16]", "bf16[6,128,2048,768]", "bf16[6,128,768,2048]"):
+            assert leaf in text and not re.search(rf"= {re.escape(leaf)}\S* copy\(", text), leaf
+        assert not re.search(r"= bf16\[128,(2048,768|768,2048)\]\S* (fusion|copy)\(", text)  # no layer's experts materialised
+        stats = compiled.memory_analysis()
+        assert stats.alias_size_in_bytes >= pool_bytes  # 3.23 GB updated in place
+        assert stats.temp_size_in_bytes < temp, stats.temp_size_in_bytes
+        assert stats.temp_size_in_bytes + stats.argument_size_in_bytes < 13.0e9  # 11.96 GB of arguments: the chip reports 16.9
+
+
 @pytest.mark.parametrize("cell_name", sorted(_PROGRAMS_OF_PR_34))
 def test_the_new_fields_at_their_defaults_are_the_configuration_that_states_neither(cell_name):
     """PR 47 sends every join of the cached layer through ``generate._residual``
