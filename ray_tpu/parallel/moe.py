@@ -17,7 +17,7 @@ import jax.numpy as jnp
 from jax.ad_checkpoint import checkpoint_name
 
 from ray_tpu.ops import attention as _attention_ops
-from ray_tpu.ops import grouped_matmul
+from ray_tpu.ops import grouped_matmul, rows_to_tokens
 
 
 def top_k_routing(gate_logits, num_experts: int, capacity: int, k: int = 1):
@@ -207,6 +207,9 @@ def _count(ids, n: int):
 # the weights' gradient reads). Nothing else of the block is named, and a policy over names keeps nothing else: the
 # gathered rows (189 MB a layer at Mellum's share for a gather of 1.4 ms) and the sorted rows' weights are gathered
 # again (with the weights kept the compiler split the rows' gather from its select: 374.3 -> 377.7 ms a step, PR 55).
+# The two sums of the sorted rows to their tokens (``ops/rows_to_tokens.py``: the combine forward, the gather's gradient
+# backward) keep nothing either: what the kernel walks by is a few hundred scalars counted from the sort's order and
+# sizes, and is counted again.
 ROUTER_SCORES, ROUTER_CHOSEN, ROUTER_TAKEN = "moe_router_scores", "moe_router_chosen", "moe_router_taken"
 SORT_ORDER, SORT_SIZES = "moe_sort_order", "moe_sort_sizes"
 HIDDEN_GATE, HIDDEN_UP, DOWN_RESULT = "moe_hidden_gate", "moe_hidden_up", "moe_down_result"
@@ -372,9 +375,15 @@ def _held_rows(experts, x, w, order, groups, rows: int, k: int):
     expert last, ``groups`` the held experts' rows -> this share's part of the
     result [N, D].
 
-    A sorted row is gathered from its token and its weighted result added back
-    to its token (a scatter-add of ``rows`` rows: the un-sort by gather that a
-    decode step uses would gather all ``N k``, dead ones too). The first piece
+    A sorted row is gathered from its token and its weighted result summed back
+    to its token, ``rows`` rows a piece (the un-sort by gather that a decode step
+    uses would gather all ``N k``, dead ones too): ``ops/rows_to_tokens.py``'s
+    ``gather_rows`` and ``sum_rows``, each the other's transpose, so the sum of
+    sorted rows to their tokens runs twice a layer, forward as the combine and
+    backward as the gather's gradient. Inside an expert's run the tokens ascend
+    (the sort is stable over ``token * k + slot``, and a token chooses an expert
+    once), which on a TPU makes either sum one pass of a kernel over the rows;
+    elsewhere it is the scatter-add ``.at[token].add``. The first piece
     runs as it stands; the pieces behind the bound are the iterations of one
     scan inside one cond, so a step holds one piece's temporaries however the
     routing falls, and a step whose held rows fit the bound pays the others
@@ -394,29 +403,30 @@ def _held_rows(experts, x, w, order, groups, rows: int, k: int):
     pieces = -(-N * k // rows)
     order = jnp.pad(order, (0, pieces * rows - N * k))
     ends = jnp.cumsum(groups)
-    starts, sent = ends - groups, ends[-1]
+    sent = ends[-1]
 
-    def a_piece(out, x, w, first, keep):
+    def a_piece(x, w, first, keep):
+        """The piece's part of the result, [N, D] float32."""
         with jax.named_scope("moe_dispatch"):
             at = jax.lax.dynamic_slice(order, (first,), (rows,))
-            live = (first + jnp.arange(rows, dtype=jnp.int32) < sent)[:, None]
-            token = jnp.where(live[:, 0], at // k, 0)
-            xs = jnp.where(live, x[token], 0)
-            inside = jnp.clip(ends, first, first + rows) - jnp.clip(starts, first, first + rows)
+            token = jnp.where(first + jnp.arange(rows, dtype=jnp.int32) < sent, at // k, 0)
+            run_ends = jnp.clip(ends, first, first + rows) - first  # where each run ends in the piece; dead rows behind
+            xs = rows_to_tokens.gather_rows(x, token, run_ends)
+            inside = jnp.diff(run_ends, prepend=0)
         ys = _named(keep)(experts(xs, inside, keep=keep), DOWN_RESULT)
         with jax.named_scope("moe_combine"):
-            # Selected BEFORE the product: what a grouped matmul leaves in a row of no group may not be finite, and
-            # the product's gradient in the weight is the cotangent TIMES that row, where 0 x NaN is NaN.
-            return out.at[token].add(jnp.where(live, ys, 0).astype(jnp.float32) * w.reshape(-1)[at][:, None])
+            # (what a grouped matmul leaves in a row of no group may not be finite: ``sum_rows`` selects it out BEFORE
+            # any product, forward and in the weights' gradient)
+            return rows_to_tokens.sum_rows(ys, w.reshape(-1)[at], token, run_ends, N)
 
     run = jax.checkpoint(partial(a_piece, keep=False))
 
     @jax.checkpoint
     def behind(out, x, w):
         def piece(out, first):  # a piece behind the last held row leaves the sum as it is
-            return jax.lax.cond(first < sent, run, lambda out, x, w, first: out, out, x, w, first), None
+            return jax.lax.cond(first < sent, lambda out, x, w, first: out + run(x, w, first), lambda out, x, w, first: out, out, x, w, first), None
 
         return jax.lax.scan(piece, out, jnp.arange(1, pieces, dtype=jnp.int32) * rows)[0]
 
-    out = jax.checkpoint(partial(a_piece, keep=True))(jnp.zeros((N, D), jnp.float32), x, w, jnp.int32(0))
+    out = jax.checkpoint(partial(a_piece, keep=True))(x, w, jnp.int32(0))
     return jax.lax.cond(sent > rows, behind, lambda out, x, w: out, out, x, w).astype(x.dtype)

@@ -461,8 +461,8 @@ def test_a_scanned_slice_of_an_expert_stack_is_copied_and_a_whole_stack_is_not(o
     assert copied[sliced] == (True, True) and copied[whole] == (False, False)
 
 
-def _train_step_for_the_v5e(cfg, batch, one_chip, monkeypatch):
-    """``make_train_step`` (AdamW, donated) with the flash kernels, for the described chip: (lowered, compiled)."""
+def _lowered_train_step_for_the_v5e(cfg, batch, one_chip, monkeypatch):
+    """``make_train_step`` (AdamW, donated) with the flash kernels, lowered for the described chip."""
     import importlib
 
     import jax
@@ -476,7 +476,12 @@ def _train_step_for_the_v5e(cfg, batch, one_chip, monkeypatch):
     params = on(jax.eval_shape(lambda: init_params(jax.random.PRNGKey(0), cfg)))
     opt = optax.adamw(1e-4)
     tokens = jax.ShapeDtypeStruct((batch, cfg.max_seq_len + 1), jnp.int32, sharding=one_chip)
-    lowered = jax.jit(make_train_step(cfg, opt), donate_argnums=(0, 1)).lower(params, on(jax.eval_shape(opt.init, params)), {"tokens": tokens})
+    return jax.jit(make_train_step(cfg, opt), donate_argnums=(0, 1)).lower(params, on(jax.eval_shape(opt.init, params)), {"tokens": tokens})
+
+
+def _train_step_for_the_v5e(cfg, batch, one_chip, monkeypatch):
+    """The same, (lowered, compiled)."""
+    lowered = _lowered_train_step_for_the_v5e(cfg, batch, one_chip, monkeypatch)
     return lowered, lowered.compile()
 
 
@@ -508,6 +513,20 @@ def _moved_whole(compiled_text: str, shapes: set) -> list:
         if m and tuple(sorted(int(d) for d in m.group(1).split(",") if d and d != "1")) in wanted:
             found.append(line.strip()[:240])
     return found
+
+
+def _mellum_shaped(H, KV, T, Dh, **more):
+    """A toy of Mellum's shape: a pattern of window and full layers, norms of q and k, YaRN, softmax top-2-of-8 experts."""
+    import jax.numpy as jnp
+
+    from ray_tpu.models.transformer import TransformerConfig
+
+    return TransformerConfig(
+        vocab_size=1024, d_model=384, n_layers=4, n_heads=H, n_kv_heads=KV, head_dim=Dh, d_ff=256, max_seq_len=T, sliding_window=256,
+        layer_kinds=("window", "full") * 2, qk_norm=True, dtype=jnp.bfloat16, remat=True, fused_loss=True,
+        rope_scaling=(("factor", 4.0), ("original_max_position_embeddings", 128.0), ("beta_fast", 32.0), ("beta_slow", 1.0), ("mscale", 1.0), ("mscale_all_dim", 0.0)),
+        num_experts=8, experts_per_token=2, d_expert=128, router_score="softmax", router_bias=False, **more,
+    )
 
 
 # What ``_attention_block`` hands the three flash kernels (PR 53), at Mistral-7B's widths (``train2``'s configuration
@@ -546,12 +565,7 @@ def test_the_train_step_hands_the_flash_kernels_q_k_v_where_the_layer_holds_them
             model[key] = jnp.dtype(model[key]).type
         cfg = TransformerConfig(**model, remat=True, fused_loss=True)
     else:
-        cfg = TransformerConfig(
-            vocab_size=1024, d_model=384, n_layers=4, n_heads=H, n_kv_heads=KV, head_dim=Dh, d_ff=256, max_seq_len=T, sliding_window=256,
-            layer_kinds=("window", "full") * 2, qk_norm=True, dtype=jnp.bfloat16, remat=True, fused_loss=True,
-            rope_scaling=(("factor", 4.0), ("original_max_position_embeddings", 128.0), ("beta_fast", 32.0), ("beta_slow", 1.0), ("mscale", 1.0), ("mscale_all_dim", 0.0)),
-            num_experts=8, experts_per_token=2, d_expert=128, router_score="softmax", router_bias=False,
-        )
+        cfg = _mellum_shaped(H, KV, T, Dh)
     assert (cfg.n_heads, cfg.n_kv_heads, cfg.head_dim) == (H, KV, Dh)
     lowered, compiled = _train_step_for_the_v5e(cfg, B, one_v5e_chip, monkeypatch)
     text = lowered.as_text()
@@ -1107,6 +1121,51 @@ _TPU_PROGRAMS_OF_PR_49 = {
     "xing6.longdoc-12k": ("bf88be242df72adabf5f9c274ad0be66a09e2431", "f065f095363487da4fcdb34196e8dd95831a9d7f"),
 }
 # And of Xing4.0's two programs as a CPU backend gets them, which ``_PROGRAMS_OF_PR_34`` lacks.
+def test_a_trained_share_sums_its_sorted_rows_to_their_tokens_by_the_kernel_and_scatters_none(one_v5e_chip, monkeypatch):
+    """The counter that says PR 58's change engaged: the Mellum-shaped step that
+    trains HALF its experts (2 x 512 tokens: 2,048 assignments under a bound of
+    1,280 rows of 384), compiled for the v5e, holds a layer and outside the cond
+    (the pieces behind the bound) ``ops/rows_to_tokens.py``'s kernel TWICE: once
+    forward under ``moe_combine`` (the weighted rows to their tokens, float32)
+    and once in the backward pass under ``moe_dispatch`` (the gradient of the
+    rows' gather, in the model's dtype); and under neither scope, in the cond or
+    out of it, a ``scatter`` over anything as wide as a row (what is left there
+    scatters scalars: the gradient of the sorted rows' weights). With the
+    kernel taken out the same step lowers to such scatters, two a layer and two
+    more in the cond: the parent's program, and the proof that the search
+    finds them."""
+    import importlib
+    import re
+
+    B, T, D, layers_a_body = 2, 512, 384, 2
+    cfg = _mellum_shaped(8, 1, T, 128, expert_share=(0, 2))
+    moe, rows_to_tokens = importlib.import_module("ray_tpu.parallel.moe"), importlib.import_module("ray_tpu.ops.rows_to_tokens")
+    for module in ("ray_tpu.ops.grouped_matmul", "ray_tpu.ops.rows_to_tokens"):  # compiled for the chip, not interpreted
+        monkeypatch.setattr(importlib.import_module(module), "_on_tpu", lambda: True)
+    lowered, compiled = _train_step_for_the_v5e(cfg, B, one_v5e_chip, monkeypatch)
+    sorted_rows = f"tensor<{moe.held_rows(B * T * cfg.experts_per_token, cfg.expert_share)}x{D}x"  # 1280 rows
+
+    def scatters(lowered_text):  # (a scatter's types stand behind its region, some lines below its name)
+        return [m.group(0) for m in re.finditer(r'"?stablehlo\.scatter"?\(.*?\) -> tensor<[^>]*>', lowered_text, re.S) if sorted_rows in m.group(0)]
+
+    assert scatters(lowered.as_text()) == []
+    calls, scattered = {}, []
+    for line in compiled.as_text().splitlines():
+        name = re.search(r'op_name="([^"]*)"', line)
+        if name is None or not ("moe_combine" in name.group(1) or "moe_dispatch" in name.group(1)):
+            continue
+        if re.search(r"= \S+ scatter\(", line) and re.search(rf"\[\d+,{D}\]", line):
+            scattered.append(line.strip()[:200])
+        if "custom-call(" in line and name.group(1).endswith("rows_to_tokens/pallas_call") and "/cond/" not in name.group(1):
+            scope = "moe_combine" if "moe_combine" in name.group(1) else "moe_dispatch"
+            key = (scope, "backward" if "transpose(jvp())" in name.group(1) else "forward", re.search(r"= (\w+)\[", line).group(1))
+            calls[key] = calls.get(key, 0) + 1
+    assert scattered == []
+    assert calls == {("moe_combine", "forward", "f32"): layers_a_body, ("moe_dispatch", "backward", "bf16"): layers_a_body}
+    monkeypatch.setattr(rows_to_tokens, "kernel_sums", lambda *a: False)
+    assert len(scatters(_lowered_train_step_for_the_v5e(cfg, B, one_v5e_chip, monkeypatch).as_text())) == 4 * layers_a_body
+
+
 _PROGRAMS_OF_PR_49 = {"xing6.longdoc-12k": ("338740d7603432f4faf41e8dec89afe6446efa2d", "4b7743fb4648293d40309ee3c41b4c2191bc95d7")}
 # The train step of ``train2.dense-4k`` (Mistral-7B at 2 layers, T = 4096, batch 1, AdamW, donated) with the flash
 # kernels, and as a CPU backend lowers it; the Switch layer's at a toy size. PR 53 moved all three on purpose (the test's
