@@ -60,7 +60,7 @@ class Config:
     # Raw-frame wire path for chunk transfer (rpc.py RAW_*): headers+payload
     # straight from/into the arena, no msgpack encode of multi-MiB bytes.
     # Negotiated per session; disabling forces the msgpack fallback
-    # everywhere (A/B lever for microbench --transfer).
+    # everywhere (what a peer that advertises no raw frames gets).
     transfer_raw_frames: bool = True
 
     # --- scheduling / raylet ---
@@ -88,13 +88,11 @@ class Config:
     worker_zygote_enabled: bool = True
 
     # --- scheduling: data locality (reference: the Ray paper's
-    # data-locality-aware placement claim; locality_aware_scheduling in
-    # scheduling_policy.h) ---
-    # Prefer nodes already holding a task's reference (plasma-sized) args —
-    # inline args are below max_direct_call_object_size by construction, so
-    # reference args ARE the large ones. Off = the measured no-locality
-    # baseline arm.
-    locality_aware_scheduling: bool = True
+    # data-locality-aware placement claim; scheduling_policy.h) ---
+    # The raylet prefers nodes already holding a task's reference
+    # (plasma-sized) args — inline args are below
+    # max_direct_call_object_size by construction, so reference args ARE the
+    # large ones. A task in a placement group skips the step.
     # Raylet-side object-location cache for locality lookups (bounded, TTL):
     # one GCS round trip per arg per TTL window, not per task.
     locality_cache_ttl_s: float = 3.0
@@ -105,22 +103,11 @@ class Config:
     heartbeat_interval_s: float = 0.5
     node_death_timeout_s: float = 5.0
     health_check_failure_threshold: int = 5
-    # Versioned delta cluster-view sync on heartbeat replies: raylets send
-    # their last seen view version and receive only changed rows + removal
-    # tombstones (full O(N) view only on resync). Off = legacy full-view
-    # replies — the measured "before" arm for the scale bench.
-    heartbeat_delta_sync: bool = True
     # Jittered exponential backoff before a raylet re-registers in _rejoin:
     # a GCS restart or mass partition-heal otherwise makes every raylet
     # re-register in the same heartbeat interval (thundering herd).
     rejoin_backoff_base_s: float = 0.05
     rejoin_backoff_max_s: float = 2.0
-
-    # --- GCS fan-in hardening ---
-    # Per-node reverse index over object locations: node death touches only
-    # that node's rows instead of scanning the whole directory. Off = legacy
-    # full scan (bench baseline arm).
-    gcs_location_index: bool = True
 
     # After a GCS restart, wait this long for in-flight actor creations on
     # surviving raylets to land before re-driving PENDING creations.
@@ -170,7 +157,7 @@ class Config:
     # (owner submit -> ship -> [raylet] -> worker recv -> exec -> reply ->
     # owner recv -> future wake) in the existing msgpack frames; the owner
     # aggregates them into a per-hop latency budget (util/tracing.py
-    # summarize_hop_records, microbench.py --hop-budget). Off by default:
+    # summarize_hop_records). Off by default:
     # the stamps are cheap but non-zero on the 1k+/s dispatch hot path.
     hop_timing: bool = False
     # Always-on production sampling: 1-in-N submissions carry hop stamps even

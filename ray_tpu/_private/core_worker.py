@@ -249,7 +249,7 @@ class CoreWorker:
         self._sync_waiters: dict[str, list] = {}
         # Hop-level dispatch records (config.hop_timing): per-task stage
         # timestamp dicts, merged owner+worker sides at completion. Ring
-        # buffer; microbench --hop-budget and util/tracing read it.
+        # buffer; util/tracing reads it.
         self._hop_log: collections.deque = collections.deque(maxlen=4096)
         self._hop_by_task: dict[str, dict] = {}
         self._owner_client_cache: dict[tuple, RpcClient] = {}
@@ -456,8 +456,8 @@ class CoreWorker:
     def _hop_stamp_start(self) -> dict:
         """Initial hop-stamp dict for a submission: every task under full
         hop timing, 1-in-``hop_sample_n`` otherwise (always-on production
-        sampling — makes the PR 2 hop budget a live metric instead of an
-        opt-in microbench artifact). Empty dict = unstamped."""
+        sampling — makes the hop budget a live metric instead of an
+        opt-in one). Empty dict = unstamped."""
         if self.cfg.hop_timing:
             return {"submit": time.monotonic()}
         n = self.cfg.hop_sample_n

@@ -86,9 +86,9 @@ class GcsServer:
         # resync (also covers a GCS restart: versions restart at 0, so a
         # client arriving "from the future" falls back to full view).
         self._removals_floor = 0
-        # Heartbeat reply accounting for the scale bench (rows/bytes per
-        # reply). Payload measurement costs one msgpack encode per reply, so
-        # it is off unless the sim harness turns it on.
+        # Heartbeat reply accounting (rows/bytes per reply). Payload
+        # measurement costs one msgpack encode per reply, so it is off
+        # unless a test of the sim harness turns it on.
         self.hb_account = False
         self.hb_stats = {"replies": 0, "rows": 0, "full_replies": 0, "view_bytes": 0}
         # Bumped by mutating handlers; the persist loop skips unchanged state.
@@ -193,12 +193,7 @@ class GcsServer:
         # Return the cluster resource view: this doubles as the resource
         # syncer (reference: src/ray/common/ray_syncer/ray_syncer.h:86).
         resp = {"ok": True, "tracing": bool(self.kv.get("tracing:enabled"))}
-        client_ver = req.get("view_version")
-        if client_ver is None:
-            # Legacy client: full view every interval (O(N) per reply).
-            resp["nodes"] = self._cluster_view()
-            self._account_hb(resp["nodes"], full=True)
-            return resp
+        client_ver = req.get("view_version", 0)
         if (
             client_ver == 0
             or client_ver > self._view_version
@@ -324,19 +319,10 @@ class GcsServer:
         logger.warning("GCS: node %s declared dead", node_id[:8])
         self._bump_view(node_id, removed=True)
         # Drop its object copies from the directory — via the per-node
-        # reverse index: O(rows on the dead node), not O(all rows). The
-        # legacy full scan is kept behind the config toggle as the measured
-        # baseline arm for the scale bench.
-        if self.cfg.gcs_location_index:
-            for oid in self._locations_by_node.pop(node_id, set()):
-                locs = self.object_locations.get(oid)
-                if locs is not None:
-                    locs.discard(node_id)
-                    if not locs:
-                        del self.object_locations[oid]
-        else:
-            self._locations_by_node.pop(node_id, None)
-            for oid, locs in list(self.object_locations.items()):
+        # reverse index: O(rows on the dead node), not O(all rows).
+        for oid in self._locations_by_node.pop(node_id, set()):
+            locs = self.object_locations.get(oid)
+            if locs is not None:
                 locs.discard(node_id)
                 if not locs:
                     del self.object_locations[oid]

@@ -129,28 +129,23 @@ def apply_heartbeat_view(resp: dict, node) -> None:
     SimNode shell: anything with ``cluster_view``/``_view_version``/
     ``_sched``/``node_id``/``_synced_peers``).
 
-    Three reply shapes: legacy full view under ``"nodes"``, delta-sync full
-    resync (``view_full``), and a delta (changed rows + removal tombstones).
+    Two reply shapes: a full resync (``view_full``) and a delta (changed
+    rows + removal tombstones).
     Peers are mirrored into the local sched_core ledger — NEVER self: the
     local ledger is authoritative, and a stale heartbeat echo (a delta row
     for this node carrying pre-acquire availability) must not clobber
     in-flight acquires."""
-    if "view" in resp:
-        node._view_version = resp.get("view_version", 0)
-        removed = resp.get("view_removed", ())
-        if resp.get("view_full"):
-            node.cluster_view = dict(resp["view"])
-        else:
-            for nid in removed:
-                node.cluster_view.pop(nid, None)
-            node.cluster_view.update(resp["view"])
-        changed = resp["view"]
-    elif "nodes" in resp:
-        node.cluster_view = resp.get("nodes", {})
-        changed = node.cluster_view
-        removed = ()
-    else:
+    if "view" not in resp:
         return
+    node._view_version = resp.get("view_version", 0)
+    removed = resp.get("view_removed", ())
+    if resp.get("view_full"):
+        node.cluster_view = dict(resp["view"])
+    else:
+        for nid in removed:
+            node.cluster_view.pop(nid, None)
+        node.cluster_view.update(resp["view"])
+    changed = resp["view"]
     for nid in changed:
         if nid == node.node_id:
             continue
@@ -501,13 +496,12 @@ class Raylet:
                         for w in self.workers.values()
                         if w.state in ("busy", "actor")
                     ),
-                }
-                if self.cfg.heartbeat_delta_sync:
                     # Versioned delta sync: carry the last view generation
                     # seen; the reply holds only newer rows + tombstones
                     # (full view only on resync) instead of the O(N) full
                     # view every interval.
-                    hb["view_version"] = self._view_version
+                    "view_version": self._view_version,
+                }
                 resp = await self.gcs.acall("heartbeat", hb)
                 if resp.get("dead"):
                     if self._exit_on_dead:
@@ -1382,9 +1376,9 @@ class Raylet:
 
     async def _locality_prefs(self, spec: TaskSpec) -> list | None:
         """Holder nodes of the task's reference args, most-args-held first;
-        None when locality doesn't apply (disabled, constrained strategy,
-        single-node view, or no reference args)."""
-        if not self.cfg.locality_aware_scheduling or spec.placement_group_id:
+        None when locality doesn't apply (a placement group, a constrained
+        strategy, a single-node view, or no reference args)."""
+        if spec.placement_group_id:
             return None
         if (spec.scheduling_strategy or "DEFAULT") != "DEFAULT":
             return None

@@ -21,7 +21,7 @@ only the data/execution plane:
 That trade buys 1k nodes on one box: enough to drive GCS fan-in (heartbeat
 reply bytes, node-death directory scans, task-event ingest) and the chaos
 matrix at a scale where O(N^2) control-plane behavior is measurable, not
-theoretical. See ``microbench.py --sim`` and ``tests/chaos_matrix.py``.
+theoretical. See ``tests/test_simnode.py`` and ``tests/chaos_matrix.py``.
 """
 
 from __future__ import annotations
@@ -179,9 +179,8 @@ class SimNode:
                     hb = {
                         "node_id": self.node_id,
                         "resources_available": self.resources_available,
+                        "view_version": self._view_version,
                     }
-                    if self.cfg.heartbeat_delta_sync:
-                        hb["view_version"] = self._view_version
                     resp = await self.gcs.acall("heartbeat", hb, timeout=5, retries=0)
                     if resp.get("dead") or resp.get("unknown"):
                         # Declared dead (partition outlived the death timeout)
@@ -386,8 +385,6 @@ class SimNode:
         flight_recorder.record("locality_hit", f"{spec.task_id[:8]}->{nid[:8]}")
 
     async def _locality_prefs(self, spec: TaskSpec) -> list | None:
-        if not self.cfg.locality_aware_scheduling:
-            return None
         if (spec.scheduling_strategy or "DEFAULT") != "DEFAULT":
             return None
         if len(self.cluster_view) <= 1:

@@ -623,7 +623,7 @@ class WorkerExecutor:
     async def rpc_channel_loop_stats(self, req):
         """Per-stage stall/busy/resolve split of a resident loop — the
         driver-side bubble-fraction measurement reads it (parallel/
-        mpmd_pipeline.py, microbench --pipeline)."""
+        mpmd_pipeline.py)."""
         loop = self._channel_loops.get(req["loop_id"])
         if loop is None:
             return {"found": False, "stages": []}
@@ -740,6 +740,10 @@ def main():
             print(f"[boot-trace {os.getpid()}] {label} +{(_time.monotonic() - _boot_t0) * 1e3:.1f}ms",
                   file=sys.stderr, flush=True)
 
+    # Stdout is a file the raylet's log monitor tails. Python buffers a file
+    # by the block: a task's prints would reach the driver when 8 KiB had
+    # gathered or the worker exited, not when they were made.
+    sys.stdout.reconfigure(line_buffering=True)
     logging.basicConfig(
         level=logging.INFO,
         format=f"[worker %(process)d] %(levelname)s %(name)s: %(message)s",

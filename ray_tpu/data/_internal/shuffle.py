@@ -135,8 +135,7 @@ def random_shuffle(bundles, num_outputs: Optional[int] = None, seed: Optional[in
     # Default OFF, like the reference (RAY_DATA_PUSH_BASED_SHUFFLE): the
     # merge stage adds R*N tasks, which only pays for itself when reducer
     # fan-in would otherwise pressure the object store / network — i.e.
-    # wide multi-node shuffles, not single-host runs (microbench tracks the
-    # crossover as shuffle_{pull,push}_rows_per_s).
+    # wide multi-node shuffles, not single-host runs.
     if ctx.use_push_based_shuffle:
         return push_based_shuffle(bundles, num_outputs, seed)
     return _shuffle(bundles, _map_random, (n, seed), _reduce_concat, (sub,), n)

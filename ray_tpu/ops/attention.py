@@ -67,8 +67,8 @@ _LSE_LANES = 128
 # step (v5e, B8/H8/T1024/D128); a (1024, 1024) f32 score tile is 4 MiB of
 # the ~16 MiB of VMEM, leaving room for the q/k/v/o tiles at head_dim <= 256.
 # Backward: 512 > 1024 > 256 there (smaller blocks also PRUNE more of a
-# causal or windowed loop). Both A/Bs are in PERF_NOTES.md, at a 168M toy's
-# shapes; the benchmark's cells have run only these values.
+# causal or windowed loop). Both A/Bs: `git show ff32286:PERF_NOTES.md`, at a
+# 168M toy's shapes; the benchmark's cells have run only these values.
 _FWD_BLOCK = 1024
 _BWD_BLOCK = 512
 # Each of the three kernels keeps two operands of a head whole in VMEM (the

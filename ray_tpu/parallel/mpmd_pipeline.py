@@ -19,8 +19,7 @@ each resident loop starts its next microbatch the moment the descriptor
 slot for it lands, so stage k at microbatch m overlaps stage k+1 at m-1 and
 the steady-state bubble fraction approaches ``(S-1)/(M+S-1)``. Per-stage
 stall/busy counters (``channel_loop_stats``) make the bubble measurable
-rather than theoretical (``microbench.py --pipeline``, PIPEBENCH
-artifact).
+rather than theoretical (``stage_stats``, ``bubble_fraction``).
 
 Outputs are bit-exact vs ``pipeline_apply`` on the same stacked params:
 each stage computes the identical ``stage_fn(params_k, x_mb)`` dot, and

@@ -287,41 +287,6 @@ def direct_send(cw, addr: tuple, key: str, data: bytes) -> None:
 
 
 # ---------------------------------------------------------------------------
-# Modeled egress link (bench-only)
-# ---------------------------------------------------------------------------
-
-# When set, every outbound payload chunk on the group plane (root fan-out,
-# relay forwards, reduce up-pushes) serializes through ONE per-process
-# asyncio.Lock and sleeps bytes/bandwidth. This is the PR 10 convention
-# (PERF_NOTES.md): loopback has no per-NIC budget, so an unthrottled A/B
-# cannot show what a relay tree buys — the modeled link is the honest
-# stand-in for the per-host egress bandwidth the tree divides on a real
-# fleet. Off (None) outside the bench.
-_EGRESS_BPS: float | None = None
-_EGRESS_LOCK = None  # created lazily on the IO loop
-
-
-@any_thread
-def set_modeled_egress(mib_per_s: float | None) -> None:
-    """Install (or clear, with None) the modeled per-process egress link."""
-    global _EGRESS_BPS
-    _EGRESS_BPS = None if not mib_per_s else float(mib_per_s) * 1024 * 1024
-
-
-async def _gate_egress(nbytes: int) -> None:
-    global _EGRESS_LOCK
-    bps = _EGRESS_BPS
-    if not bps:
-        return
-    import asyncio
-
-    if _EGRESS_LOCK is None:
-        _EGRESS_LOCK = asyncio.Lock()
-    async with _EGRESS_LOCK:
-        await asyncio.sleep(nbytes / bps)
-
-
-# ---------------------------------------------------------------------------
 # Binomial relay tree
 # ---------------------------------------------------------------------------
 
@@ -431,7 +396,6 @@ async def _relay_forward(cw, table: RelayTable, st: _RelaySession, child: dict) 
             payload = {"key": st.key, "idx": idx, "total": st.total, "data": data}
             if relay is not None:
                 payload["relay"] = relay
-            await _gate_egress(len(data))
             await client.apush("p2p_data", payload)
             st.bytes_forwarded += len(data)
             COLL.relay_bytes += len(data)
@@ -1062,8 +1026,7 @@ def group_bcast_send(
 
     async def _push_direct(rank: int):
         client = cw._owner_client(tuple(member_addrs[rank]))
-        for i, frame in enumerate(frames):
-            await _gate_egress(len(chunks[i]))
+        for frame in frames:
             await client.apush_packed("p2p_data", frame)
         result["root_egress_bytes"] += len(data)
 
@@ -1088,15 +1051,13 @@ def group_bcast_send(
             # opens the session, so loss/reorder of any one frame cannot
             # stall the whole subtree.
             for i in range(total):
-                await _gate_egress(len(chunks[i]))
                 await client.apush(
                     "p2p_data",
                     {"key": key, "idx": i, "total": total,
                      "data": chunks[i], "relay": relay},
                 )
         else:
-            for i, frame in enumerate(frames):
-                await _gate_egress(len(chunks[i]))
+            for frame in frames:
                 await client.apush_packed("p2p_data", frame)
         result["root_egress_bytes"] += len(data)
         await _ack(spec["rank"], ack_wait)
@@ -1438,7 +1399,6 @@ def reduce_key(group_name: str, tag: str, src_rank: int) -> str:
 
 
 async def _push_reduce_chunk(client, key: str, idx: int, total: int, data: bytes):
-    await _gate_egress(len(data))
     await client.apush(
         "p2p_data", {"key": key, "idx": idx, "total": total, "data": data}
     )

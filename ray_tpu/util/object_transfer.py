@@ -11,8 +11,7 @@ The tree is CUT-THROUGH (ISSUE 10): the relay subtree rides inside each
 `push_begin`, and every level starts forwarding chunks downstream as they
 arrive rather than after its local copy seals, so end-to-end latency is
 O(size + depth × chunk) instead of O(depth × size); chunks ride raw frames
-(zero msgpack encode/copies) whenever both ends negotiated them. See
-TRANSFER_r10.json for the measured 3.8× aggregate over the r5 plane.
+(zero msgpack encode/copies) whenever both ends negotiated them.
 
 Usage:
     ref = ray_tpu.put(big_array)

@@ -87,8 +87,8 @@ def reset_task_context(token):
 #
 # Each completed dispatch leaves a record of monotonic stage timestamps on
 # the owner (CoreWorker.hop_records()); the stages chain differently per
-# transport path. summarize_hop_records() turns the raw records into the
-# per-hop latency budget that microbench.py --hop-budget emits.
+# transport path. summarize_hop_records() turns the raw records into a
+# per-path, per-hop latency budget.
 
 # Ordered stage transitions per path. A "hop" that crosses a process
 # boundary is a wire frame; the rest are in-process thread/loop handoffs.

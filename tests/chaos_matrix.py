@@ -1,7 +1,6 @@
 """Chaos matrix harness — N workloads x M seeded fault cells.
 
-The library behind tests/test_chaos_matrix.py (and `microbench.py --chaos`):
-each CELL runs one small workload under one seeded fault plan injected at
+The library behind tests/test_chaos_matrix.py: each CELL runs one small workload under one seeded fault plan injected at
 the RPC frame seam (chaos.py) and asserts the availability contract:
 
 (a) the workload COMPLETES, or raises/returns the documented *typed*

@@ -45,6 +45,14 @@ def coll_cluster():
     ray_tpu.shutdown()
 
 
+def _store_objects() -> int:
+    """Objects in this node's shm store: a payload that rode the direct
+    mailboxes leaves the count where it was."""
+    from ray_tpu._private import worker_context
+
+    return worker_context.get_core_worker().raylet.call("get_state")["store"]["num_objects"]
+
+
 def _sharded(n=64):
     import jax
     import jax.numpy as jnp
@@ -275,6 +283,38 @@ def test_broadcast_resolution_same_group_rides_inbox(coll_cluster):
     gc.collect()
 
 
+def test_group_broadcast_mints_no_store_object_and_residents_drain(coll_cluster):
+    """A learner's weight sync, twice over: the holder makes a payload, one
+    group broadcast lands it at both consumers' inboxes, both apply it. The
+    node's store gains no object (the payload rode the direct mailboxes),
+    and once each sync's ref is dropped the holder's residents drain to 0."""
+    from ray_tpu.experimental import device_object
+
+    holder = Holder.remote()
+    consumers = [Member.remote() for _ in range(2)]
+    ray_tpu.get(
+        [holder.init_collective.remote(3, 0, "cpu", "wsync3")]
+        + [c.init_collective.remote(3, i + 1, "cpu", "wsync3") for i, c in enumerate(consumers)],
+        timeout=60,
+    )
+    store0 = _store_objects()
+    for _ in range(2):
+        ref = holder.make.remote(64 * 1024)
+        info = device_object.broadcast(ref, "wsync3", timeout=60)
+        assert sorted(info["ok_ranks"]) == [1, 2] and info["failed"] == {}, info
+        vals = ray_tpu.get([c.consume.remote(ref) for c in consumers], timeout=60)
+        assert vals == [(0.0, 64 * 1024)] * 2
+        del ref, info
+    gc.collect()
+    assert _store_objects() == store0
+    deadline = time.monotonic() + 30
+    while ray_tpu.get(holder.residents.remote(), timeout=30) > 0 and time.monotonic() < deadline:
+        time.sleep(0.2)
+    assert ray_tpu.get(holder.residents.remote(), timeout=30) == 0
+    for a in [holder] + consumers:
+        ray_tpu.kill(a)
+
+
 def test_broadcast_resolution_host_fallback(coll_cluster):
     """A consumer OUTSIDE the group resolves the same broadcast ref over the
     host path; and the no-group broadcast() seals an arena copy the whole
@@ -330,6 +370,7 @@ def test_tree_broadcast_topology_and_sub_o_k_root_egress(coll_cluster):
             timeout=60,
         )
         payload = jnp.arange(448 * 1024, dtype=jnp.float32)  # 1.75 MiB -> 4 chunks
+        store0 = _store_objects()
         info = col.get_group(group).bcast_send_payload(payload, "t16", timeout=60)
         assert info["topology"] == "tree", info
         assert info["root_children"] == [1, 2, 4], info
@@ -346,6 +387,8 @@ def test_tree_broadcast_topology_and_sub_o_k_root_egress(coll_cluster):
         stats1 = ray_tpu.get(members[0].coll_stats.remote(), timeout=30)
         assert stats1["relay_forwards"] >= 1, stats1
         assert stats1["relay_bytes"] >= info["bytes"], stats1
+        # Root fan-out and relay forwards alike rode the direct mailboxes.
+        assert _store_objects() == store0
     finally:
         col.destroy_collective_group(group)
 

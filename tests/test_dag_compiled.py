@@ -91,6 +91,32 @@ def test_compiled_linear_pipeline_zero_control_plane(compiled_cluster):
         compiled.teardown()
 
 
+def test_compiled_hop_records_name_no_raylet_stage(compiled_cluster):
+    """Under hop timing every compiled iteration leaves a ``path="compiled"``
+    record stamped by the driver and by each stage, and by no raylet: the
+    budget that ``summarize_hop_records`` builds from them has none on it."""
+    from ray_tpu._private import worker_context
+    from ray_tpu.util import tracing
+
+    cw = worker_context.get_core_worker()
+    d, _ = _linear_dag(3)
+    compiled = d.experimental_compile()
+    cw.cfg.hop_timing = True
+    try:
+        tracing.drain_hop_records()
+        for i in range(5):
+            assert compiled.execute(i).get() == i + 3
+        budget = tracing.summarize_hop_records(tracing.drain_hop_records())["compiled"]
+    finally:
+        cw.cfg.hop_timing = False
+        compiled.teardown()
+    assert budget["count"] == 5, budget
+    assert budget["raylet_rpcs_per_call"] == 0
+    stages = budget["stages_us"]
+    assert stages and not any("raylet" in s for s in stages), stages
+    assert any("s2_" in s for s in stages), stages  # the last stage stamped too
+
+
 def test_compiled_out_of_order_get_and_pipelining(compiled_cluster):
     d, _ = _linear_dag(2)
     compiled = d.experimental_compile()

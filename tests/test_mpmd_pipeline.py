@@ -224,14 +224,18 @@ def _stage_fn(w, h):
 def test_mpmd_parity_bitexact_vs_pipeline_apply(pipeline_cluster):
     """Acceptance oracle: the MPMD pipeline's outputs are BIT-EXACT vs the
     single-controller pipeline_apply on identical stacked params/inputs —
-    at M == S and at M > S — and the per-stage loop stats expose the
-    measured bubble."""
+    at M == S and at M > S — its steady state leaves the raylet, the
+    ownership table and the node's store alone, and the per-stage loop
+    stats expose the measured bubble."""
     import jax
 
+    from ray_tpu._private import worker_context
+    from ray_tpu.experimental.device_object import device_object_stats
     from ray_tpu.parallel.mesh import MeshConfig, create_mesh
     from ray_tpu.parallel.mpmd_pipeline import mpmd_pipeline
     from ray_tpu.parallel.pipeline import pipeline_apply
 
+    cw = worker_context.get_core_worker()
     n_stages, d = 4, 8
     ws = jax.random.normal(jax.random.PRNGKey(1), (n_stages, d, d)) * 0.3
     mesh = create_mesh(MeshConfig(pp=4, dp=2))
@@ -244,6 +248,28 @@ def test_mpmd_parity_bitexact_vs_pipeline_apply(pipeline_cluster):
             )
             out = np.asarray(pipe.apply(x, num_microbatches=M))
             assert np.array_equal(out, ref), f"M={M}: MPMD != pipeline_apply"
+        # Steady state of the schedule just warmed: an apply() sends the
+        # raylet nothing and mints no ObjectRef, no activation lands in the
+        # node's store, nothing resolves over the host path, and every hop
+        # is a descriptor whose payload went out of band. Control-plane
+        # baselines LAST: the probes before them are classic calls.
+        store0 = cw.raylet.call("get_state")["store"]["num_objects"]
+        stages0, driver0 = pipe.stage_devobj_stats(), device_object_stats()
+        raylet_seq0, owned0 = cw.raylet._seq, len(cw.owned)
+        for _ in range(3):
+            pipe.apply(x, num_microbatches=8)
+        assert cw.raylet._seq == raylet_seq0
+        assert len(cw.owned) <= owned0
+        assert cw.raylet.call("get_state")["store"]["num_objects"] == store0
+        stages1 = pipe.stage_devobj_stats()
+        assert [s["transfers_host"] for s in stages1] == [
+            s["transfers_host"] for s in stages0
+        ], (stages0, stages1)
+        assert device_object_stats()["transfers_host"] == driver0["transfers_host"]
+        assert all(
+            s1["chan_sends"] - s0["chan_sends"] >= 3 * 8
+            for s0, s1 in zip(stages0, stages1)
+        ), (stages0, stages1)
         # Non-divisible batches fail loudly, like pipeline_apply.
         bad = jax.random.normal(jax.random.PRNGKey(9), (10, d))
         with pytest.raises(AssertionError, match="not divisible"):

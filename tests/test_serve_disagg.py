@@ -161,6 +161,18 @@ def _flight_events(cluster, kind, since_wall):
     ]
 
 
+def _handoffs_failed():
+    return sum(
+        s.get("handoff_failed", 0) for dep in ("llm", "llm--prefill") for s in _replica_stats(dep)
+    )
+
+
+def _store_objects():
+    from ray_tpu._private import worker_context
+
+    return worker_context.get_core_worker().raylet.call("get_state")["store"]["num_objects"]
+
+
 def _wait_kv_restored(deps=("llm", "llm--prefill")):
     """Leak oracle: every live replica's KV pool back to full once idle."""
     deadline = time.monotonic() + 30
@@ -188,6 +200,8 @@ def _run_handoff_oracle(cluster, prompt, n, sampling, kill=False):
     t_wall0 = time.time()
     handoffs0 = sum(s.get("handoffs", 0) for s in _replica_stats("llm"))
     exports0 = sum(s.get("handoff_exports", 0) for s in _replica_stats("llm--prefill"))
+    failed0 = _handoffs_failed()
+    store0 = _store_objects()
     hint = prefix_route_hint(prompt, ENGINE["block_size"])
     assert hint
     if kill:
@@ -223,6 +237,11 @@ def _run_handoff_oracle(cluster, prompt, n, sampling, kill=False):
     )
     if not kill:
         assert sum(s.get("handoffs", 0) for s in _replica_stats("llm")) > handoffs0
+        assert _handoffs_failed() == failed0
+        # The descriptor rode an actor call and the KV payload the direct
+        # mailboxes: the hand-off minted nothing in the node's store (the
+        # proxy frees its stream buffers asynchronously: bounded settle).
+        _wait(lambda: _store_objects() <= store0, msg="store objects back to baseline")
     assert _flight_events(cluster, "llm_kv_handoff", t_wall0), "no handoff recorded"
     if kill:
         assert _flight_events(cluster, "llm_migrate", t_wall0), "no migration"
@@ -338,6 +357,10 @@ def test_prefix_import_bit_identical_and_local_seed(disagg_cluster):
         st = b.stats()
         assert st["prefix_import_hits"] == 1, st
         assert st["prefix_import_errors"] == 0, st
+        from ray_tpu._private import flight_recorder
+
+        events = (flight_recorder.dump() or {}).get("events", [])
+        assert any(e["type"] == "llm_prefix_import" for e in events), "import left no flight event"
         # Second same-prefix prompt: the import registered the blocks in
         # B's local cache, so the probe short-circuits (hits stay at 1)
         # and the output is still oracle-exact.

@@ -97,6 +97,49 @@ def test_sim_closed_loop_traffic_no_untyped_failures(sim_cluster):
     assert stats["failures"] == {}, stats
 
 
+def test_sim_ref_arg_tasks_land_on_their_holder(sim_cluster):
+    """Every task whose reference arg lives on one shell is placed ON that
+    shell, through whichever entry shell it was submitted to, and each such
+    placement is counted (``locality_hits``) by the shell that made it."""
+    c = sim_cluster
+    holders = c.nodes[32:40]
+    pairs = []
+    for i, h in enumerate(holders):
+        oid = f"b{i:055x}"
+        c.seed_object(h, oid)
+        pairs.append((oid, h.node_id))
+    time.sleep(0.5)  # holder rows settle into the entry shells' caches
+    hits0 = sum(n.locality_hits for n in c.nodes)
+    n_tasks = 64
+
+    async def _ref_burst():
+        futs = []
+        for i in range(n_tasks):
+            oid, holder = pairs[i % len(pairs)]
+            spec = c.make_spec(args=[("r", oid, None)], sim_ms=2.0)
+            fut = c.register_waiter(spec.task_id)
+            await c.asubmit(spec)
+            futs.append((fut, holder))
+        return [(await asyncio.wait_for(fut, 30), holder) for fut, holder in futs]
+
+    landed = c._io.run(_ref_burst(), timeout=120)
+    assert all(ran_on == holder for ran_on, holder in landed), landed
+    assert sum(n.locality_hits for n in c.nodes) - hits0 > 0
+
+
+@pytest.mark.parametrize(
+    "key", ["heartbeat_delta_sync", "gcs_location_index", "locality_aware_scheduling"]
+)
+def test_a_removed_baseline_arm_is_an_unknown_config_key(key):
+    """The full-view heartbeat, the full-scan node death and the no-locality
+    placement are gone with the options that selected them: naming one is
+    refused like any other unknown key."""
+    from ray_tpu._private.config import Config
+
+    with pytest.raises(ValueError, match="Unknown system config key"):
+        Config().apply_overrides({key: False})
+
+
 # ---------------------------------------------------------------------------
 # Delta-sync protocol edges (satellite 3)
 # ---------------------------------------------------------------------------

@@ -264,7 +264,7 @@ def test_cut_through_relay_forwards_before_seal(transfer_cluster):
 def test_broadcast_sweep_many_nodes():
     """Wider cut-through sweep: 8 nodes, 32 MiB, every copy bit-exact and
     aggregate throughput recorded. Slow-marked: tier-1 is past its wall
-    budget; microbench --transfer covers the perf number."""
+    budget."""
     from ray_tpu.cluster_utils import Cluster
     from ray_tpu.util.object_transfer import broadcast_object
 
