@@ -14,10 +14,10 @@ Design notes (per /opt/skills/guides/pallas_guide.md):
 - the kernels read q ``[B, H, T, D]`` and k, v ``[B, KV, T, D]`` (heads before
   tokens: a head's ``[T, D]`` is contiguous and tiled as a kernel wants it) in
   place: a KV head is block ``h // rep`` of its axis, so nothing repeats k and v.
-- block sizes default to (128, 128): MXU-shaped, and multiples of the
-  (8,128)/f32, (16,128)/bf16 tile constraints.
-- causal masking prunes fully-masked k-blocks via the loop upper bound
-  (no wasted MXU work past the diagonal).
+- block edges are multiples of 128 (the (8,128)/f32, (16,128)/bf16 tile
+  constraints), chosen a kernel and a mask by ``_blocks``.
+- causal and windowed masking prune the blocks the mask hides whole via the
+  loop's bounds (``_key_span`` / ``_query_span``; ``tile_schedule`` counts).
 """
 
 from __future__ import annotations
@@ -60,17 +60,16 @@ def _xla_attention(q, k, v, causal: bool, sm_scale: float, bias=None, window: in
 # Mellum's widths), and sliced after.
 _LSE_LANES = 128
 
-# Preferred block edge of the forward kernel and of the two backward kernels
-# (``_fit_block`` clamps both to the sequence, so short sequences degrade to
-# block == seq). Forward: bigger blocks mean fewer grid steps and less
-# per-block overhead, (128,128) << (256,512) < (1024,1024) in a full train
-# step (v5e, B8/H8/T1024/D128); a (1024, 1024) f32 score tile is 4 MiB of
-# the ~16 MiB of VMEM, leaving room for the q/k/v/o tiles at head_dim <= 256.
-# Backward: 512 > 1024 > 256 there (smaller blocks also PRUNE more of a
-# causal or windowed loop). Both A/Bs: `git show ff32286:PERF_NOTES.md`, at a
-# 168M toy's shapes; the benchmark's cells have run only these values.
-_FWD_BLOCK = 1024
-_BWD_BLOCK = 512
+# What a kernel pays for a score of a tile it covers, by the tile's (block_q, block_k), in picoseconds: each kernel
+# alone on the v5e over the covered tiles of a full causal T = 8192 (B 2, H 32, KV 4, D 128; PERF.md section 6, PR
+# 60 holds the whole table, fourteen shapes a kernel a mask). A larger tile is cheaper a score (fewer loop steps,
+# longer matmuls) and covers more of what the mask hides; ``_blocks`` weighs the two. Shapes left out lost under
+# every mask measured: an edge of 256 or less in a backward kernel, of 2048 anywhere.
+_PS_A_COVERED_SCORE = {
+    "fwd": {(512, 512): 4.387, (256, 1024): 4.117, (512, 1024): 4.232, (1024, 1024): 4.278},
+    "dkv": {(512, 512): 6.843, (1024, 512): 6.383, (512, 1024): 6.606, (1024, 1024): 6.256},
+    "dq": {(512, 512): 5.206, (1024, 512): 4.650, (512, 1024): 4.474, (1024, 1024): 4.492},
+}
 # Each of the three kernels keeps two operands of a head whole in VMEM (the
 # forward and dq its keys and values, dkv its queries and their cotangent), two
 # buffers each. Up to this many bytes of them the compiler's own scoped limit
@@ -113,6 +112,80 @@ def _swap(x):
     return x.transpose(0, 2, 1, 3)
 
 
+def _int_clip(x, lo, hi):
+    return min(max(x, lo), hi)
+
+
+def _key_span(first_q_pos, block_q: int, block_k: int, num_kb, window: int, clip=jnp.clip):
+    """The key blocks ``[from, to)`` a block of queries walks under the causal mask: from the block of the first
+    row's oldest visible key (0 without a window) to the block of the last row's own. ``first_q_pos`` is the
+    POSITION of the block's first row (its index plus ``Tk - Tq``: the bottom-right alignment). Blocks outside are
+    never walked: under a window that is what makes the cost O(T * window), not O(T^2). The same arithmetic serves
+    a kernel (traced scalars, ``jnp.clip``) and ``tile_schedule`` (ints, ``_int_clip``)."""
+    end = clip((first_q_pos + block_q - 1) // block_k + 1, 0, num_kb)
+    start = clip((first_q_pos - window + 1) // block_k, 0, end) if window > 0 else 0
+    return start, end
+
+
+def _query_span(first_key_row, block_q: int, block_k: int, num_qb, window: int, clip=jnp.clip):
+    """``_key_span`` seen from a block of keys (the dK/dV kernel's loop): from the first query block whose LAST row
+    reaches the first key to the last whose FIRST row still has the last key in its window (the last block without
+    one). ``first_key_row`` is the first key's position less ``Tk - Tq``: the index of the row whose own key it is."""
+    start = clip(first_key_row // block_q, 0, num_qb)
+    end = clip((first_key_row + block_k + window - 2) // block_q + 1, start, num_qb) if window > 0 else num_qb
+    return start, end
+
+
+def tile_schedule(Tq: int, Tk: int, window: int, block_q: int, block_k: int) -> tuple:
+    """What the three kernels' loops do for ONE head under the causal mask (``window`` 0: full causal), counted:
+    (tiles covered, tiles an edge of the mask crosses, scores the covered tiles hold, scores the mask lets through).
+    The three kernels cover the same tiles, those the band of visible scores touches (the dK/dV kernel walks them by
+    key block, the other two by query block), and mask every one: only the crossed tiles need it, but on the v5e
+    the mask costs nothing that can be measured and a second loop for the tiles between costs 0.3-0.8 us a grid
+    step (PERF.md section 6, PR 60). What a kernel's time follows is the third number."""
+    offset = Tk - Tq
+    covered = crossed = 0
+    for qb in range(Tq // block_q):
+        first, last = qb * block_q + offset, (qb + 1) * block_q - 1 + offset
+        start, end = _key_span(first, block_q, block_k, Tk // block_k, window, _int_clip)
+        covered += end - start
+        # whole tiles: every key at or before the first row's own, and (a window) the last row still sees the tile's first
+        crossed += sum(not (first >= (kb + 1) * block_k - 1 and (window == 0 or last - kb * block_k < window)) for kb in range(start, end))
+    visible = sum(max(0, min(p, Tk - 1) - (max(0, p - window + 1) if window > 0 else 0) + 1) for p in range(offset, Tk))
+    return covered, crossed, covered * block_q * block_k, visible
+
+
+def _fwd_tile(q, k_blk, v_blk, carry, q_pos0, k_pos0, masked: bool, sm_scale: float, window: int):
+    """The forward kernel's inner body: one [block_q, block_k] score tile folded into the running (max, sum,
+    accumulator). ``masked`` False: no causal mask, every score visible."""
+    m_prev, l_prev, acc_prev = carry
+    s = jax.lax.dot_general(
+        q, k_blk, (((1,), (1,)), ((), ())), preferred_element_type=jnp.float32
+    ) * sm_scale  # [block_q, block_k]
+    if masked:
+        q_pos = q_pos0 + jax.lax.broadcasted_iota(jnp.int32, s.shape, 0)
+        k_pos = k_pos0 + jax.lax.broadcasted_iota(jnp.int32, s.shape, 1)
+        visible = q_pos >= k_pos
+        if window > 0:
+            visible &= q_pos - k_pos < window
+        s = jnp.where(visible, s, -jnp.inf)
+    m_cur = jnp.maximum(m_prev, s.max(axis=-1, keepdims=True))
+    # Fully-masked-so-far rows (possible under a sliding window: early
+    # k-blocks can be entirely outside a late row's window) have
+    # m_cur = -inf; exp(-inf - -inf) would be NaN. Substituting 0 for
+    # the max keeps correction = p = exp(-inf) = 0 — the correct
+    # "contributes nothing" behavior.
+    safe_m = jnp.where(jnp.isneginf(m_cur), 0.0, m_cur)
+    correction = jnp.exp(m_prev - safe_m)
+    p = jnp.exp(s - safe_m)
+    l_cur = l_prev * correction + p.sum(axis=-1, keepdims=True)
+    pv = jax.lax.dot_general(
+        p.astype(v_blk.dtype), v_blk, (((1,), (0,)), ((), ())),
+        preferred_element_type=jnp.float32,
+    )
+    return m_cur, l_cur, acc_prev * correction + pv
+
+
 def _flash_kernel(q_ref, k_ref, v_ref, o_ref, lse_ref=None, *, block_k: int, causal: bool, sm_scale: float, seq_k: int, block_q: int, window: int = 0):
     from jax.experimental import pallas as pl
 
@@ -124,74 +197,21 @@ def _flash_kernel(q_ref, k_ref, v_ref, o_ref, lse_ref=None, *, block_k: int, cau
     l0 = jnp.zeros((q.shape[0], 1), dtype=jnp.float32)
     acc0 = jnp.zeros((q.shape[0], d), dtype=jnp.float32)
 
-    num_k_blocks = pl.cdiv(seq_k, block_k)
     # Bottom-right-aligned causal mask (matches _xla_attention's
     # tril(k=Tk-Tq)): query row i sees keys 0..i+(Tk-Tq). Identical to the
     # usual mask when Tq == Tk; for Tq < Tk (decode with cache) the tail of
     # the keys is what's visible.
     causal_offset = seq_k - block_q * pl.num_programs(2)
-    start_block = 0
-    if causal:
-        # K blocks strictly after this Q block's last visible key are masked.
-        last_q_row = (q_idx + 1) * block_q - 1 + causal_offset
-        num_k_blocks = jnp.minimum(num_k_blocks, (last_q_row // block_k) + 1)
-        num_k_blocks = jnp.maximum(num_k_blocks, 0)
-        if window > 0:
-            # Sliding window: K blocks entirely before the FIRST q row's
-            # window are skipped — the FLOPs saving that makes long-context
-            # windowed attention O(T*W) instead of O(T^2).
-            first_q_row = q_idx * block_q + causal_offset
-            start_block = jnp.maximum(0, (first_q_row - window + 1) // block_k)
+    first_q_pos = q_idx * block_q + causal_offset
 
-    def make_body(masked: bool):
-        def body(kb, carry):
-            m_prev, l_prev, acc_prev = carry
-            k_blk = k_ref[pl.ds(kb * block_k, block_k), :]
-            v_blk = v_ref[pl.ds(kb * block_k, block_k), :]
-            s = jax.lax.dot_general(
-                q, k_blk, (((1,), (1,)), ((), ())), preferred_element_type=jnp.float32
-            ) * sm_scale  # [block_q, block_k]
-            if masked:
-                q_pos = q_idx * block_q + causal_offset + jax.lax.broadcasted_iota(jnp.int32, s.shape, 0)
-                k_pos = kb * block_k + jax.lax.broadcasted_iota(jnp.int32, s.shape, 1)
-                visible = q_pos >= k_pos
-                if window > 0:
-                    visible &= q_pos - k_pos < window
-                s = jnp.where(visible, s, -jnp.inf)
-            m_cur = jnp.maximum(m_prev, s.max(axis=-1, keepdims=True))
-            # Fully-masked-so-far rows (possible under a sliding window: early
-            # k-blocks can be entirely outside a late row's window) have
-            # m_cur = -inf; exp(-inf - -inf) would be NaN. Substituting 0 for
-            # the max keeps correction = p = exp(-inf) = 0 — the correct
-            # "contributes nothing" behavior.
-            safe_m = jnp.where(jnp.isneginf(m_cur), 0.0, m_cur)
-            correction = jnp.exp(m_prev - safe_m)
-            p = jnp.exp(s - safe_m)
-            l_cur = l_prev * correction + p.sum(axis=-1, keepdims=True)
-            pv = jax.lax.dot_general(
-                p.astype(v_blk.dtype), v_blk, (((1,), (0,)), ((), ())),
-                preferred_element_type=jnp.float32,
-            )
-            acc_cur = acc_prev * correction + pv
-            return m_cur, l_cur, acc_cur
+    def body(kb, carry):
+        k_blk = k_ref[pl.ds(kb * block_k, block_k), :]
+        v_blk = v_ref[pl.ds(kb * block_k, block_k), :]
+        return _fwd_tile(q, k_blk, v_blk, carry, first_q_pos, kb * block_k, causal, sm_scale, window)
 
-        return body
-
-    if causal and window == 0:
-        # Split the k-loop at the diagonal: blocks entirely below it (every
-        # k_pos visible to every row of this q block) skip the iota/compare/
-        # select mask — pure VPU work that at (1024,1024)-class tiles costs
-        # the same order as the score matmul itself. Only diagonal-crossing
-        # blocks pay for masking. (Windowed attention keeps the uniform
-        # masked loop: its left edge re-masks early blocks too.)
-        first_q_row = q_idx * block_q + causal_offset
-        full_end = jnp.clip((first_q_row + 1) // block_k, start_block, num_k_blocks)
-        carry = jax.lax.fori_loop(start_block, full_end, make_body(False), (m0, l0, acc0))
-        m, l, acc = jax.lax.fori_loop(full_end, num_k_blocks, make_body(True), carry)
-    else:
-        m, l, acc = jax.lax.fori_loop(
-            start_block, num_k_blocks, make_body(causal), (m0, l0, acc0)
-        )
+    # ONE loop over the key blocks the mask's band touches, every tile masked alike (``tile_schedule``).
+    start, end = _key_span(first_q_pos, block_q, block_k, pl.cdiv(seq_k, block_k), window) if causal else (0, pl.cdiv(seq_k, block_k))
+    m, l, acc = jax.lax.fori_loop(start, end, body, (m0, l0, acc0))
     o_ref[...] = (acc / l).astype(o_ref.dtype)
     if lse_ref is not None:
         # Log-sum-exp per row: the residual the backward pass needs to
@@ -241,8 +261,10 @@ def _pallas_flash_with_lse(q, k, v, causal: bool, sm_scale: float, block_q: int,
 
 
 @functools.partial(jax.custom_vjp, nondiff_argnums=(3, 4, 5, 6, 7, 8))
-def _pallas_flash(q, k, v, causal: bool, sm_scale: float, block_q: int, block_k: int, interpret: bool, window: int = 0):
-    out, _ = _pallas_flash_with_lse(q, k, v, causal, sm_scale, block_q, block_k, interpret, save_lse=False, window=window)
+def _pallas_flash(q, k, v, causal: bool, sm_scale: float, block_q: int | None, block_k: int | None, interpret: bool, window: int = 0):
+    """``block_q`` / ``block_k``: a caller's block edges for all three kernels, or None for ``_blocks``'s."""
+    bq, bk = _edges("fwd", q, k, window, block_q, block_k)
+    out, _ = _pallas_flash_with_lse(q, k, v, causal, sm_scale, bq, bk, interpret, save_lse=False, window=window)
     return out
 
 
@@ -253,7 +275,8 @@ FLASH_OUT, FLASH_LSE = "flash_out", "flash_lse"
 
 
 def _pallas_flash_fwd(q, k, v, causal, sm_scale, block_q, block_k, interpret, window=0):
-    out, lse = _pallas_flash_with_lse(q, k, v, causal, sm_scale, block_q, block_k, interpret, window=window)
+    bq, bk = _edges("fwd", q, k, window, block_q, block_k)
+    out, lse = _pallas_flash_with_lse(q, k, v, causal, sm_scale, bq, bk, interpret, window=window)
     out, lse = checkpoint_name(out, FLASH_OUT), checkpoint_name(lse, FLASH_LSE)
     return out, (q, k, v, out, lse)
 
@@ -351,13 +374,14 @@ def _xla_blockwise_bwd(causal, sm_scale, block_q, block_k, window, res, dout):
 # tensor in HBM: measured 90.1k -> 109k tok/s on the v5e single-chip bench).
 
 
-def _bwd_tile(q_blk, do_blk, k_blk, v_blk, lse_row, delta_row, q_pos0, k_pos0, causal, sm_scale, window):
+def _bwd_tile(q_blk, do_blk, k_blk, v_blk, lse_row, delta_row, q_pos0, k_pos0, masked, sm_scale, window):
     """Shared inner body: one (k-block, q-block) score tile, transposed
-    orientation. Returns (p, ds) as [block_k, block_q] f32."""
+    orientation. Returns (p, ds) as [block_k, block_q] f32. ``masked`` False:
+    no causal mask, every score visible."""
     s = jax.lax.dot_general(
         k_blk, q_blk, (((1,), (1,)), ((), ())), preferred_element_type=jnp.float32
     ) * sm_scale  # [bk, bq]
-    if causal:
+    if masked:
         k_pos = k_pos0 + jax.lax.broadcasted_iota(jnp.int32, s.shape, 0)
         q_pos = q_pos0 + jax.lax.broadcasted_iota(jnp.int32, s.shape, 1)
         visible = q_pos >= k_pos
@@ -380,17 +404,6 @@ def _flash_bwd_dkv_kernel(q_ref, do_ref, k_ref, v_ref, lse_ref, delta_ref, dk_re
     v_blk = v_ref[...]
     offset = seq_k - seq_q  # bottom-right causal alignment
     num_qb = pl.cdiv(seq_q, block_q)
-    qb_start = 0
-    qb_end = num_qb
-    if causal:
-        # First q block whose LAST row reaches this k block's first key.
-        qb_start = jnp.maximum(0, (kb * block_k - offset) // block_q)
-        if window > 0:
-            # Last q block whose FIRST row is still inside the window of
-            # this k block's last key.
-            kmax = kb * block_k + block_k - 1
-            qb_end = jnp.minimum(num_qb, (kmax + window - 1 - offset) // block_q + 1)
-            qb_end = jnp.maximum(qb_end, qb_start)
 
     def body(qb, carry):
         dk_acc, dv_acc = carry
@@ -412,6 +425,7 @@ def _flash_bwd_dkv_kernel(q_ref, do_ref, k_ref, v_ref, lse_ref, delta_ref, dk_re
         )
         return dk_acc, dv_acc
 
+    qb_start, qb_end = _query_span(kb * block_k - offset, block_q, block_k, num_qb, window) if causal else (0, num_qb)
     z = jnp.zeros((k_blk.shape[0], k_blk.shape[1]), jnp.float32)
     dk, dv = jax.lax.fori_loop(qb_start, qb_end, body, (z, z))
     dk_ref[...] = dk.astype(dk_ref.dtype)
@@ -428,14 +442,6 @@ def _flash_bwd_dq_kernel(q_ref, do_ref, k_ref, v_ref, lse_ref, delta_ref, dq_ref
     delta_row = delta_ref[...]
     offset = seq_k - seq_q
     num_kb = pl.cdiv(seq_k, block_k)
-    kb_start = 0
-    kb_end = num_kb
-    if causal:
-        last_q_row = (qb + 1) * block_q - 1 + offset
-        kb_end = jnp.clip((last_q_row // block_k) + 1, 0, num_kb)
-        if window > 0:
-            first_q_row = qb * block_q + offset
-            kb_start = jnp.maximum(0, (first_q_row - window + 1) // block_k)
 
     def body(kb, dq_acc):
         k_blk = k_ref[pl.ds(kb * block_k, block_k), :]
@@ -450,15 +456,17 @@ def _flash_bwd_dq_kernel(q_ref, do_ref, k_ref, v_ref, lse_ref, delta_ref, dq_ref
             preferred_element_type=jnp.float32,
         )
 
+    kb_start, kb_end = _key_span(qb * block_q + offset, block_q, block_k, num_kb, window) if causal else (0, num_kb)
     z = jnp.zeros((q_blk.shape[0], q_blk.shape[1]), jnp.float32)
     dq = jax.lax.fori_loop(kb_start, kb_end, body, z)
     dq_ref[...] = dq.astype(dq_ref.dtype)
 
 
-def _pallas_bwd_impl(q, k, v, out, lse, dout, causal, sm_scale, block_q, block_k, interpret, window):
+def _pallas_bwd_impl(q, k, v, out, lse, dout, causal, sm_scale, dkv_edges, dq_edges, interpret, window):
     """Operands as the forward call's -> (dq ``[B, H, Tq, D]``, dk and dv ``[B, KV, Tk, D]``). The dkv kernel writes
     a query head's dk and dv each, ``[B, H, Tk, D]``, and ONE reduction in float32 sums a KV head's over its
-    ``rep`` query heads (``_sum_groups``), as the cotangent of a repeat would."""
+    ``rep`` query heads (``_sum_groups``), as the cotangent of a repeat would. Each kernel has its own
+    ``(block_q, block_k)``: its grid's axis sets what it covers, its loop's axis only the tile."""
     from jax.experimental import pallas as pl
 
     B, H, Tq, D = q.shape
@@ -469,14 +477,14 @@ def _pallas_bwd_impl(q, k, v, out, lse, dout, causal, sm_scale, block_q, block_k
     delta = jnp.sum(dout.astype(jnp.float32) * out.astype(jnp.float32), axis=-1)[:, :, None, :]
     operands = (q, dout, k, v, lse[:, :, None, :], delta)
 
-    kw = dict(block_q=block_q, block_k=block_k, causal=causal,
-              sm_scale=sm_scale, seq_q=Tq, seq_k=Tk, window=window)
+    kw = dict(causal=causal, sm_scale=sm_scale, seq_q=Tq, seq_k=Tk, window=window)
+    block_q, block_k = dkv_edges
     q_whole = pl.BlockSpec((None, None, Tq, D), lambda b, h, kb: (b, h, 0, 0))
     kv_block = pl.BlockSpec((None, None, block_k, D), lambda b, h, kb: (b, h // rep, kb, 0))
     dkv_block = pl.BlockSpec((None, None, block_k, D), lambda b, h, kb: (b, h, kb, 0))
     row_whole = pl.BlockSpec((None, None, 1, Tq), lambda b, h, kb: (b, h, 0, 0))
     dk, dv = pl.pallas_call(
-        functools.partial(_flash_bwd_dkv_kernel, **kw),
+        functools.partial(_flash_bwd_dkv_kernel, block_q=block_q, block_k=block_k, **kw),
         grid=(B, H, pl.cdiv(Tk, block_k)),
         in_specs=[q_whole, q_whole, kv_block, kv_block, row_whole, row_whole],
         out_specs=[dkv_block, dkv_block],
@@ -484,11 +492,12 @@ def _pallas_bwd_impl(q, k, v, out, lse, dout, causal, sm_scale, block_q, block_k
         interpret=interpret,
         **_vmem(Tq, D, q.dtype.itemsize),
     )(*operands)
+    block_q, block_k = dq_edges
     q_block = pl.BlockSpec((None, None, block_q, D), lambda b, h, qb: (b, h, qb, 0))
     kv_whole = pl.BlockSpec((None, None, Tk, D), lambda b, h, qb: (b, h // rep, 0, 0))
     row_block = pl.BlockSpec((None, None, 1, block_q), lambda b, h, qb: (b, h, 0, qb))
     (dq,) = pl.pallas_call(
-        functools.partial(_flash_bwd_dq_kernel, **kw),
+        functools.partial(_flash_bwd_dq_kernel, block_q=block_q, block_k=block_k, **kw),
         grid=(B, H, pl.cdiv(Tq, block_q)),
         in_specs=[q_block, q_block, kv_whole, kv_whole, row_block, row_block],
         out_specs=[q_block],
@@ -502,11 +511,11 @@ def _pallas_bwd_impl(q, k, v, out, lse, dout, causal, sm_scale, block_q, block_k
 def _pallas_flash_bwd(causal, sm_scale, block_q, block_k, interpret, window, res, dout):
     q, k, v, out, lse = res
     Tq, Tk = q.shape[2], k.shape[2]
-    bq, bk = _fit_block(_BWD_BLOCK, Tq), _fit_block(_BWD_BLOCK, Tk)
-    use_pallas = (_on_tpu() or interpret) and Tq % bq == 0 and Tk % bk == 0
+    dkv_edges, dq_edges = (_edges(kernel, q, k, window, block_q, block_k) for kernel in ("dkv", "dq"))
+    use_pallas = (_on_tpu() or interpret) and not any(Tq % bq or Tk % bk for bq, bk in (dkv_edges, dq_edges))
     if not use_pallas:
-        return _xla_blockwise_bwd(causal, sm_scale, block_q, block_k, window, (q, k, v, out, lse), dout)
-    return _pallas_bwd_impl(q, k, v, out, lse, dout, causal, sm_scale, bq, bk, interpret, window)
+        return _xla_blockwise_bwd(causal, sm_scale, *dkv_edges, window, (q, k, v, out, lse), dout)
+    return _pallas_bwd_impl(q, k, v, out, lse, dout, causal, sm_scale, dkv_edges, dq_edges, interpret, window)
 
 
 _pallas_flash.defvjp(_pallas_flash_fwd, _pallas_flash_bwd)
@@ -523,7 +532,7 @@ def flash_attention(
     *,
     causal: bool = False,
     sm_scale: float | None = None,
-    block_q: int | None = None,  # None: _FWD_BLOCK
+    block_q: int | None = None,  # None: ``_blocks``'s, a kernel; given, all three kernels' (the tests' small blocks)
     block_k: int | None = None,
     bias=None,
     force_pallas: bool | None = None,
@@ -539,7 +548,8 @@ def flash_attention(
     does not support yet). ``window`` > 0 (requires causal) is Mistral-style
     sliding-window attention: row i attends keys (i-window, i]; the kernel
     SKIPS k-blocks entirely outside the window, so long-context cost is
-    O(T·window), not O(T²).
+    O(T·window), not O(T²). A window that reaches every key (``window >= Tk``)
+    is none. Each kernel's block edges follow the mask (``_blocks``).
     """
     if window and not causal:
         raise ValueError("sliding window requires causal=True")
@@ -547,17 +557,39 @@ def flash_attention(
         raise ValueError(f"{q.shape[1]} query heads over k {k.shape} and v {v.shape}: not whole groups")
     if sm_scale is None:
         sm_scale = 1.0 / math.sqrt(q.shape[-1])
-    block_q = _FWD_BLOCK if block_q is None else block_q
-    block_k = _FWD_BLOCK if block_k is None else block_k
     use_pallas = force_pallas if force_pallas is not None else (_on_tpu() or interpret)
     Tq, Tk = q.shape[2], k.shape[2]
-    bq = _fit_block(block_q, Tq)
-    bk = _fit_block(block_k, Tk)
+    if window >= Tk:  # binds nothing: the kernels get the full-causal mask's blocks, and no second compare a score
+        window = 0
+    bq, bk = _edges("fwd", q, k, window, block_q, block_k)
     # Block sizes must tile the sequence exactly: a clamped tail slice would
     # read overlapping rows (and the backward would double-count them).
     if bias is not None or not use_pallas or Tq % bq or Tk % bk:
         return _swap(_xla_attention(_swap(q), _swap(k), _swap(v), causal, sm_scale, bias, window=window))
-    return _pallas_flash(q, k, v, causal, sm_scale, bq, bk, interpret, window)
+    return _pallas_flash(q, k, v, causal, sm_scale, block_q, block_k, interpret, window)
+
+
+@functools.lru_cache(maxsize=None)
+def _blocks(kernel: str, Tq: int, Tk: int, D: int, window: int) -> tuple:
+    """``(block_q, block_k)`` of the forward (``"fwd"``), the dK/dV (``"dkv"``) or the dQ (``"dq"``) kernel: of the
+    shapes measured, the one whose covered tiles (``tile_schedule``: what the mask's band touches at these edges)
+    cost least at the shape's measured price a score. The mask decides: under a window of 1024 every kernel takes
+    (512, 512) (a larger block spans more keys outside the window than its cheaper score pays for); a full causal
+    sequence takes the larger tiles once it is long enough that the diagonal's half-hidden tiles are few (the dK/dV
+    kernel (1024, 1024) at 8192 tokens and (512, 512) at 4096). Heads wider than 128 keep (512, 512), as does a
+    sequence that no measured shape divides: nothing wider was measured, and a tile's operands grow with D."""
+    shapes = {edges: ps for edges, ps in _PS_A_COVERED_SCORE[kernel].items() if D <= 128 and Tq % edges[0] == 0 and Tk % edges[1] == 0}
+    if not shapes:  # a short or ragged sequence: ``_fit_block`` brings 512 down to it
+        return 512, 512
+    return min(shapes, key=lambda edges: tile_schedule(Tq, Tk, window, *edges)[2] * shapes[edges])  # ties: the first listed
+
+
+def _edges(kernel: str, q, k, window: int, block_q: int | None, block_k: int | None) -> tuple:
+    """A kernel's block edges for ``q [.., Tq, D]`` over ``k [.., Tk, D]``: the caller's where given, else
+    ``_blocks``'s, fitted to the sequence."""
+    Tq, Tk = q.shape[2], k.shape[2]
+    want_q, want_k = _blocks(kernel, Tq, Tk, q.shape[3], window)
+    return _fit_block(block_q or want_q, Tq), _fit_block(block_k or want_k, Tk)
 
 
 def _fit_block(want: int, t: int) -> int:

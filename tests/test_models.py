@@ -268,7 +268,7 @@ def test_flash_attention_cross_length_causal_alignment():
     assert float(jnp.abs(out - ref).max()) < 1e-5
 
 
-def test_pallas_backward_kernels_vs_oracle(monkeypatch):
+def test_pallas_backward_kernels_vs_oracle():
     """The Pallas dkv/dq backward kernels (transposed-score orientation,
     causal/window loop pruning) must match the XLA attention's autodiff
     exactly — including the Tq != Tk bottom-right alignment and the
@@ -280,7 +280,8 @@ def test_pallas_backward_kernels_vs_oracle(monkeypatch):
     from ray_tpu.ops import attention
     from ray_tpu.ops.attention import _xla_attention
 
-    monkeypatch.setattr(attention, "_BWD_BLOCK", 128)
+    # the call's block_q = block_k = 64 are all three kernels' (``_fit_block`` raises them to a lane tile, 128)
+    assert attention._edges("dkv", jnp.zeros((1, 1, 512, 32)), jnp.zeros((1, 1, 512, 32)), 0, 64, 64) == (128, 128)
 
     rng = np.random.default_rng(7)
     cases = [

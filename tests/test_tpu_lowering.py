@@ -30,12 +30,16 @@ import jax.numpy as jnp
 from ray_tpu.ops.attention import flash_attention
 
 # (B, T, H, D, causal, window): the original gate, then chip_smoke.py's shapes
-# (Mistral-7B heads at T=4096, full causal and the 4096 sliding window).
+# (Mistral-7B heads at T=4096, full causal and the 4096 sliding window), then the
+# 8192-token cell's two kinds of layer (PR 60: each kernel at the blocks
+# ``attention._blocks`` gives that mask).
 CASES = [
     (2, 512, 4, 128, False, 0),
     (2, 512, 4, 128, True, 0),
     (1, 4096, 32, 128, True, 0),
     (1, 4096, 32, 128, True, 4096),
+    (1, 8192, 8, 128, True, 1024),
+    (1, 8192, 8, 128, True, 0),
 ]
 for B, T, H, D, causal, window in CASES:
     q = jax.ShapeDtypeStruct((B, H, T, D), jnp.bfloat16)
@@ -459,6 +463,40 @@ def test_a_scanned_slice_of_an_expert_stack_is_copied_and_a_whole_stack_is_not(o
             compiled.memory_analysis().temp_size_in_bytes >= one_layer,
         )
     assert copied[sliced] == (True, True) and copied[whole] == (False, False)
+
+
+# The attention cores of the benchmark's training cells: (batch a chip, H, KV, T, window) and the (block_q, block_k)
+# ``attention._blocks`` gives the forward, the dK/dV and the dQ kernel there (PR 60: measured on the v5e, PERF.md
+# section 6; a change of the function's answer for a cell is a change of this table, on purpose).
+_FLASH_CELLS = {
+    "mellum4.moe-8k, a window layer": ((2, 32, 4, 8192, 1024), {"fwd": (512, 512), "dkv": (512, 512), "dq": (512, 512)}),
+    "mellum4.moe-8k, the full layer": ((2, 32, 4, 8192, 0), {"fwd": (256, 1024), "dkv": (1024, 1024), "dq": (512, 1024)}),
+    "train2, a window that reaches every key": ((1, 32, 8, 4096, 4096), {"fwd": (512, 512), "dkv": (512, 512), "dq": (512, 1024)}),
+}
+
+
+@pytest.mark.parametrize("which", sorted(_FLASH_CELLS))
+def test_the_flash_kernels_compile_for_the_v5e_at_the_blocks_the_mask_gives(which, one_v5e_chip, monkeypatch):
+    """``flash_attention`` and its gradient at a cell's shapes, each kernel at the block edges ``_blocks`` derives
+    from the mask, compiled by the TPU's compiler for the described chip: the resident operands and the tiles fit
+    what ``_vmem`` asks for (a shape that does not is refused here, as the forward at 8192 tokens was before PR 50
+    raised its limit), and the lowered text names each of the three kernels once."""
+    import jax
+    import jax.numpy as jnp
+
+    from ray_tpu.ops import attention
+
+    (B, H, KV, T, window), want = _FLASH_CELLS[which]
+    seen = 0 if window >= T else window
+    assert {kernel: attention._blocks(kernel, T, T, 128, seen) for kernel in want} == want
+    q = jax.ShapeDtypeStruct((B, H, T, 128), jnp.bfloat16, sharding=one_v5e_chip)
+    kv = jax.ShapeDtypeStruct((B, KV, T, 128), jnp.bfloat16, sharding=one_v5e_chip)
+    monkeypatch.setattr(attention, "_on_tpu", lambda: True)  # the backward rule asks it too
+    attn = lambda q, k, v: attention.flash_attention(q, k, v, causal=True, window=window)
+    lowered = jax.jit(jax.value_and_grad(lambda q, k, v: attn(q, k, v).astype(jnp.float32).sum(), argnums=(0, 1, 2))).lower(q, kv, kv)
+    calls = _flash_calls(lowered.as_text())
+    assert {name: len(of) for name, of in calls.items()} == dict.fromkeys(("_flash_kernel", "_flash_bwd_dkv_kernel", "_flash_bwd_dq_kernel"), 1)
+    lowered.compile()
 
 
 def _lowered_train_step_for_the_v5e(cfg, batch, one_chip, monkeypatch):
@@ -1170,9 +1208,10 @@ _PROGRAMS_OF_PR_49 = {"xing6.longdoc-12k": ("338740d7603432f4faf41e8dec89afe6446
 # The train step of ``train2.dense-4k`` (Mistral-7B at 2 layers, T = 4096, batch 1, AdamW, donated) with the flash
 # kernels, and as a CPU backend lowers it; the Switch layer's at a toy size. PR 53 moved all three on purpose (the test's
 # docstring says by what); until then they were PR 51's: c74918ba..., d5d19381..., 74e29155... (PR 49's before: e13c36b6...,
-# bf81db40..., 354aa964...).
+# bf81db40..., 354aa964...). PR 60 moved Mistral's two on purpose (the docstring says by what; PR 53's were c45250bf...,
+# 11c51b6d...); the Switch layer's is PR 53's still.
 _TRAIN_STEPS_OF_PR_53 = {
-    "train2@tpu": "c45250bf9f96f9448e08bf836c04eb843037d2db", "train2@cpu": "11c51b6d394c41bda0251463dbbc890a78629f13", "switch": "5cf04e44eec22de9c8d7f7a1e642e2a502befc43",
+    "train2@tpu": "588d1850abe65849c1b98262957765a8a4f04afe", "train2@cpu": "3f542ff1684ff1bcd611a17a4aba5c5bdb7df1fb", "switch": "5cf04e44eec22de9c8d7f7a1e642e2a502befc43",
 }
 
 
@@ -1231,8 +1270,15 @@ def test_the_accepted_train_steps_are_the_parents(which, monkeypatch):
     transposed, which the compiler folds into the matmul) to ``wo`` (the rotary
     as a signed permutation on the MXU, K and V never repeated), which is where
     the flash kernels read them: the CPU texts moved with the TPU's because the
-    block is one program for both, only the attention core differs. Later PRs
-    that leave the training block alone keep these texts."""
+    block is one program for both, only the attention core differs. PR 60
+    moved Mistral's two, by ONE thing each: its window of 4096 over 4096 keys
+    binds nothing and reaches the attention core as no window (on a CPU the
+    reference builds no second mask; on a TPU the three kernels are called
+    with ``window = 0``), and on a TPU each kernel's block edges are
+    ``attention._blocks``' for that mask, (512, 512), (512, 512) and (512,
+    1024), where they were (1024, 1024) forward and (512, 512) backward. The
+    Switch layer's 128 tokens take the XLA core and stay. Later PRs that leave
+    the training block alone keep these texts."""
     import importlib
 
     import jax
