@@ -268,8 +268,9 @@ def instruments() -> dict:
                 "ray_tpu_serve_llm_moe_assignments_total",
                 "Assignments of tokens to routed experts, as last read from the "
                 "device's counters (one flush behind), by held: true (to experts "
-                "the program holds) and false (to the others' of an expert-parallel "
-                "deployment, which this program computes nothing of).",
+                "the program holds), false (to the others' of an expert-parallel "
+                "deployment, which this program computes nothing of) and identity "
+                "(picks of a router's identity experts, which reach no matrix).",
                 tag_keys=("held",),
             ),
             "serve_llm_state_slots": m.Gauge(
@@ -698,6 +699,7 @@ def _collect_serve_llm_stats():
         ("state_resets", inst["serve_llm_state_resets"], None),
         ("moe_assignments_held", inst["serve_llm_moe_assignments"], {"held": "true"}),
         ("moe_assignments_elsewhere", inst["serve_llm_moe_assignments"], {"held": "false"}),
+        ("moe_picks_identity", inst["serve_llm_moe_assignments"], {"held": "identity"}),
     ])
     engines = list(ENGINES)
     if not engines and not LLM.admitted:

@@ -36,7 +36,10 @@ its leaves say: dense SwiGLU; dropless routed experts with a shared expert
 (``parallel/moe.routed_experts``: top-k of sigmoid scores, grouped matmuls, no
 capacity, behind ``first_dense_layers`` dense layers stacked apart); or the
 Switch layer the training tests keep (``parallel/moe.moe_layer``, top-1 with a
-capacity, run lossless here).
+capacity, run lossless here). One layer form is not a mixer and then an MLP:
+the shortcut-connected double layer (``_shortcut_layer``), two latent-attention
+sub-layers and two dense FFNs with one branch of routed experts carried past
+the second of each before it joins the residual path.
 """
 
 from __future__ import annotations
@@ -54,6 +57,7 @@ from ray_tpu.models.transformer import (
     EXPERT_LAYERS,
     LINEAR_LAYERS,
     MAMBA_LAYERS,
+    SHORTCUT_BRANCH_LEAVES,
     TransformerConfig,
     _logits,
     _layer_stacks,
@@ -196,7 +200,7 @@ def _layer_plan(cfg: TransformerConfig) -> list:
     ``layer_kinds`` says. A kind's group of cache leaves is as deep as the
     model has layers of the kind, whichever segments they lie in."""
     stacks = _layer_stacks(cfg)
-    layers = lambda kind: cfg.layer_kinds.count(kind) if kind else cfg.n_layers  # noqa: E731
+    layers = lambda kind: cfg.layer_kinds.count(kind) if kind else cfg.n_layers * cfg.attention_sublayers  # noqa: E731
     plan, first = [], 0
     for name, of in stacks.items():
         if of.kind:
@@ -359,10 +363,14 @@ def _project_latent(lp, x, positions, cfg):
     H, R, P = cfg.n_heads, cfg.kv_lora_rank, cfg.qk_rope_head_dim
     h = _rms_norm(x, lp["attn_norm"], cfg.norm_eps)
     c_q = _rms_norm(h @ lp["wq_a"].astype(h.dtype), lp["q_norm"], cfg.norm_eps)
+    if cfg.mla_scale_q_lora:  # on the latent, so on both halves of every head's query
+        c_q = c_q * (cfg.d_model / cfg.q_lora_rank) ** 0.5
     q = (c_q @ lp["wq_b"].astype(h.dtype)).reshape(B, T, H, -1)
     q_nope, q_rope = q[..., : cfg.qk_nope_head_dim], q[..., cfg.qk_nope_head_dim :]
     kv = h @ lp["wkv_a"].astype(h.dtype)
     c = _rms_norm(kv[..., :R], lp["kv_norm"], cfg.norm_eps)
+    if cfg.mla_scale_kv_lora:  # the cached row is the SCALED latent: the absorbed form and both kernels read it as it lies
+        c = c * (cfg.d_model / R) ** 0.5
     k_rope = _rope(kv[..., None, R:], positions, cfg.rope_theta, cfg.rope_scaling)[:, :, 0]
     w_uk, _ = _latent_kv_up(lp, cfg)
     q_abs = jnp.einsum("bthn,rhn->bthr", q_nope, w_uk.astype(h.dtype))
@@ -501,6 +509,36 @@ def _residual(lp, x, sub, cfg, branch):
         return hyper_connection.join(x, out, post, res), rest
 
 
+def _routed(lp, h, cfg, valid, layer):
+    """``moe.routed_experts`` of the normed rows h [B, q, D] as ``cfg`` says: (this
+    program's part of the routed result [B, q, D], the tokens sent to each held
+    expert, behind them two numbers more where the router has identity experts
+    (``_picks_apart``), each row's picks among the router's columns [B * q, k])."""
+    B, q, D = h.shape
+    out, sent, chosen, _ = moe.routed_experts(
+        lp, h.reshape(B * q, D), k=cfg.experts_per_token, scale=cfg.routed_scaling_factor,
+        valid=None if valid is None else valid.reshape(B * q), layer=layer, share=cfg.expert_share,
+        score=cfg.router_score, identity=cfg.zero_experts, normalize=cfg.router_normalize,
+    )
+    if cfg.zero_experts:
+        sent = _picks_apart(sent, chosen, valid, cfg)
+    return out.reshape(B, q, D), sent, chosen
+
+
+def _picks_apart(sent, chosen, valid, cfg):
+    """``sent`` [held experts] with two numbers behind it, for the counters of a
+    router with identity experts (``MOE_COUNTS``): the real rows' picks that were
+    identities, and the real rows none of whose picks was an expert held here
+    (whose branch costs this program the identity term at most). chosen [N, k]
+    among the router's columns, ``valid`` [B, q] or None."""
+    real = jnp.ones(chosen.shape[:1], bool) if valid is None else valid.reshape(-1)
+    first = cfg.expert_share[0] * cfg.held_experts
+    here = (chosen >= first) & (chosen < first + cfg.held_experts)
+    identities = jnp.sum((chosen >= cfg.num_experts) & real[:, None], dtype=sent.dtype)
+    without = jnp.sum(real & ~jnp.any(here, axis=-1), dtype=sent.dtype)
+    return jnp.concatenate([sent, jnp.stack([identities, without])])
+
+
 def _mlp(lp, x, cfg, valid=None, layer=None):
     """(the residual path joined with MLP(norm(.)) (``_residual``), the MLP this
     layer's leaves hold; with routed experts the tokens sent to each expert [E]
@@ -519,13 +557,7 @@ def _mlp(lp, x, cfg, valid=None, layer=None):
         if "gate" not in lp:
             return post(_swiglu(h, lp["wg"], lp["wi"], lp["wo_mlp"])), (None, None)
         if cfg.routed_experts:
-            B, q, D = h.shape
-            out, sent, chosen, _ = moe.routed_experts(
-                lp, h.reshape(B * q, D), k=cfg.experts_per_token, scale=cfg.routed_scaling_factor,
-                valid=None if valid is None else valid.reshape(B * q), layer=layer, share=cfg.expert_share,
-                score=cfg.router_score,
-            )
-            out = out.reshape(B, q, D)
+            out, sent, chosen = _routed(lp, h, cfg, valid, layer)
             if "wg_s" in lp:
                 out = out + _swiglu(h, lp["wg_s"], lp["wi_s"], lp["wo_s"])
             elif "wi_s" in lp:
@@ -545,6 +577,46 @@ def _mlp(lp, x, cfg, valid=None, layer=None):
 
     x, (sent, chosen) = _residual(lp, x, "mlp", cfg, branch)
     return x, sent, None if chosen is None else chosen.reshape(*x.shape[:2], -1)
+
+
+def _shortcut_layer(twice, l, x, pool, cfg, attention_0, attention_1, experts):
+    """THE shortcut-connected double layer (``cfg.shortcut_moe``), one scan body:
+
+        h0 = x  + MLA_0(norm(x))
+        u0 = norm(h0)
+        m  = experts(u0)                    the shortcut branch: read here ...
+        h1 = h0 + FFN_0(u0)
+        h2 = h1 + MLA_1(norm(h1))
+        y  = h2 + FFN_1(norm(h2)) + m       ... and joined here, an attention and an FFN later
+
+    ``twice``: the WHOLE stack of what a sub-layer owns, ``[layers, 2, ...]``
+    (sub-layer 0 first), of which this is layer ``l`` (traced). Each of a
+    sub-layer's leaves is one dynamic slice of its stack that one operation
+    reads: sliced by the layer scan, a layer's ``[2, ...]`` pair has two readers
+    and is materialised first, both FFNs' matrices copied every layer of every
+    step (13 of a decode step's 43 ms at the published widths, v5e, PR 61: the
+    trap of ``_EXPERT_STACKS`` again). ``attention_i(u, pool=, lp=)``: sub-layer
+    i's attention branch over its own layer of the cache -> (out, pool);
+    ``experts(u0, pool)`` -> (m, pool, sent). In a deployment ``m`` is what the
+    exchange between the chips that share the experts brings back while the dense
+    path runs; here it is this program's part (``expert_share``) and the identity
+    term, carried beside the residual path and nothing stands in for the rest."""
+    def own(leaf, i):
+        return lax.dynamic_slice(leaf, (l, i) + (0,) * (leaf.ndim - 2), (1, 1, *leaf.shape[2:])).reshape(leaf.shape[2:])
+
+    sub = [{name: own(leaf, i) for name, leaf in twice.items()} for i in range(2)]
+
+    def ffn(i, u):
+        return _swiglu(u, sub[i]["wg"], sub[i]["wi"], sub[i]["wo_mlp"])
+
+    x, pool = _residual(sub[0], x, "attn", cfg, partial(attention_0, pool=pool, lp=sub[0]))
+    u0 = _rms_norm(x, sub[0]["mlp_norm"], cfg.norm_eps)
+    m, pool, sent = experts(u0, pool)
+    x = x + ffn(0, u0)
+    x, pool = _residual(sub[1], x, "attn", cfg, partial(attention_1, pool=pool, lp=sub[1]))
+    out = ffn(1, _rms_norm(x, sub[1]["mlp_norm"], cfg.norm_eps))
+    with jax.named_scope("moe_shortcut_join"):
+        return x + out + m, pool, sent
 
 
 def _cache_attention(q, ck, cv, pos_mask, cfg):
@@ -672,12 +744,21 @@ def expert_layers(cfg: TransformerConfig) -> int:
     return sum(of.depth for of in _layer_stacks(cfg).values() if of.mlp == "routed")
 
 
+def counts_every_pick(cfg: TransformerConfig) -> bool:
+    """Whether ``MOE_COUNTS`` has a column for every pick the router made: where the held experts' own do not add up
+    to them, a program that holds a share of the experts or whose router has identity experts."""
+    return cfg.expert_share[1] > 1 or cfg.zero_experts > 0
+
+
 def init_moe_counts(cfg: TransformerConfig):
-    return jnp.zeros((2, expert_layers(cfg), cfg.held_experts + 3 + (cfg.expert_share[1] > 1)), jnp.int32)
+    """[steps | chunks, expert layers, columns]: the tokens sent to each held expert, the experts touched, the fullest
+    expert's load, whether the call routed a token, every pick (``counts_every_pick``) and, where the router has
+    identity experts, the picks that were identities and the rows with no pick held here (``_picks_apart``)."""
+    return jnp.zeros((2, expert_layers(cfg), cfg.held_experts + 3 + counts_every_pick(cfg) + 2 * (cfg.zero_experts > 0)), jnp.int32)
 
 
 def _expert_bits(cfg: TransformerConfig) -> int:
-    return max(1, (cfg.num_experts - 1).bit_length())
+    return max(1, (cfg.router_width - 1).bit_length())
 
 
 def _choice_words(cfg: TransformerConfig) -> tuple:
@@ -993,6 +1074,10 @@ def _cached_layers(params, x, cache, positions, access, cfg, key_len=None, valid
             x, sent, chosen = _mlp(lp, x, cfg, valid, layer=l - first if held else None)
             return x, record_choice(pool, chosen, l, first, nth), sent
 
+        def then_branch(u, pool):  # a double layer's routed experts over ``u``, their picks kept beside the rows' tokens
+            m, sent, chosen = _routed(lp, u, cfg, valid, l - first if held else None)
+            return m, record_choice(pool, chosen.reshape(B, q, -1), l, first, nth), sent
+
         if kind == _MAMBA:  # a block that is this mixer and nothing else
             def mamba(u):
                 o, moved = _mamba_mixer(lp, u, pool, at, acc, cfg)
@@ -1015,7 +1100,7 @@ def _cached_layers(params, x, cache, positions, access, cfg, key_len=None, valid
         if kind == _CONV:
             return then_mlp(*_residual(lp, x, "attn", cfg, lambda u: _conv_mixer(lp, u, pool, at, acc, cfg)))
 
-        def attention(u, pool=pool):
+        def attention(u, pool=pool, lp=lp, at=at):
             project = _project_latent if latent else partial(_project_qkv, rope=layer_rope(cfg, kind))
             qh, rows = project(lp, u, positions, cfg)
             if parts is not None:
@@ -1042,6 +1127,8 @@ def _cached_layers(params, x, cache, positions, access, cfg, key_len=None, valid
                 o = o * jax.nn.sigmoid(gate)
             return post(o @ lp["wo"].astype(o.dtype)), pool
 
+        if cfg.shortcut_moe:
+            return _shortcut_layer(twice, l - first, x, pool, cfg, partial(attention, at=2 * at), partial(attention, at=2 * at + 1), then_branch)
         x, pool = _residual(lp, x, "attn", cfg, attention)
         if cfg.single_mixer:  # an attention block: no MLP behind it
             return x, pool, None
@@ -1136,24 +1223,31 @@ def _cached_layers(params, x, cache, positions, access, cfg, key_len=None, valid
     # Nemotron's [6, 64, 2688, 1856] was copied into the kernel's layout every
     # step (3.8 GB, PR 43).
     hold = experts_run(cfg, x.shape[0] * x.shape[1]) in ("kernel", "ragged_dot")
+    twice = {}  # a double layer's stack of what a sub-layer owns, whole too (``_shortcut_layer``); its one segment's
     for segment in _layer_plan(cfg):
         stacks = {kind: params[row.stack] for kind, row in segment.rows.items()}
         held = {kind: {n: stack[n] for n in _EXPERT_STACKS if hold and n in stack} for kind, stack in stacks.items()}
         stacks = {kind: {n: leaf for n, leaf in stack.items() if n not in held[kind]} for kind, stack in stacks.items()}
+        if cfg.shortcut_moe:
+            twice = {n: leaf for n, leaf in stacks[None].items() if n not in SHORTCUT_BRANCH_LEAVES}
+            stacks = {None: {n: leaf for n, leaf in stacks[None].items() if n not in twice}}
         if segment.kinds:
             x, pool, sent = scan_periods(segment, held, stacks, x, pool)
         else:
             layer_ids = jnp.arange(segment.first, segment.first + segment.depth, dtype=jnp.int32)
             (x, pool), sent = lax.scan(partial(body, segment.first, held[None]), (x, pool), (stacks[None], layer_ids))
     if counts is not None:  # sent [expert layers, E]: the routed stack's, which runs last
+        apart = []
+        if cfg.zero_experts:  # the two numbers ``_picks_apart`` laid behind the held experts' rows
+            sent, apart = sent[:, :-2], [sent[:, -2:]]
         touched = jnp.sum(sent > 0, axis=-1, keepdims=True)
-        if cfg.expert_share[1] > 1:  # E the experts held; a call counts by what it routed, held or not, and says how much
+        if counts_every_pick(cfg):  # E the experts held; a call counts by what it routed, held or not, and says how much
             routed = (B * q if valid is None else jnp.sum(valid)) * cfg.experts_per_token
             routed = jnp.broadcast_to(routed, touched.shape).astype(sent.dtype)
             columns = [sent, touched, jnp.max(sent, axis=-1, keepdims=True), jnp.minimum(routed, 1), routed]
         else:
             columns = [sent, touched, jnp.max(sent, axis=-1, keepdims=True), jnp.minimum(touched, 1)]
-        routed_now = jnp.concatenate(columns, axis=-1)
+        routed_now = jnp.concatenate(columns + apart, axis=-1)
         pool[MOE_COUNTS] = counts.at[0 if (q == 1 if step is None else step) else 1].add(routed_now.astype(counts.dtype))
     if cfg.hc_mult:  # the stream ends as the sum of its rows
         x = hyper_connection.collapse(x, cfg.hc_mult)

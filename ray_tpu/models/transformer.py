@@ -234,6 +234,26 @@ class TransformerConfig:
     # one more pass (serve/llm/engine.py). The logits at a position predict that position's own token.
     block_diffusion: int = 0
     mask_token_id: int = 0
+    # The shortcut-connected DOUBLE layer (inference only; the ``longcat_flash`` family), selected by ``shortcut_moe``
+    # over latent attention and routed experts: a layer is TWO latent-attention sub-layers and TWO dense SwiGLU FFNs of
+    # width ``d_ff``, and one branch of routed experts that reads the first FFN's normed input and joins the residual
+    # path a sub-layer LATE, behind the second FFN (``generate._shortcut_layer`` has the equations). ``n_layers`` counts
+    # double layers: the cache is ``2 * n_layers`` layers deep (``attention_sublayers``). A sub-layer's leaves (its two
+    # norms, the attention's matrices, the FFN's) are stacked ``[n_layers, 2, ...]`` under the names a plain layer
+    # gives them, sub-layer 0 first, the branch's ``[n_layers, ...]``: ONE stack and one scan body a layer.
+    shortcut_moe: bool = False
+    # The router is WIDER than the experts it routes to: ``zero_experts`` more columns behind the ``num_experts`` are
+    # identity experts, scored and chosen with the others; a pick of one adds the branch's own input times the pick's
+    # weight and reaches no matrix (``parallel/moe.routed_experts(identity=)``; inference only). ``expert_share``
+    # divides the ``num_experts``. ``router_normalize`` False: the chosen weights are ``routed_scaling_factor * s`` as
+    # they stand, not divided by their sum (inference only).
+    zero_experts: int = 0
+    router_normalize: bool = True
+    # Latent attention's two constants (the ``longcat_flash`` family): the normed query latent is multiplied by
+    # ``sqrt(d_model / q_lora_rank)`` and the normed key-value latent by ``sqrt(d_model / kv_lora_rank)`` (the rotary
+    # key is not), where they are normed: the cache holds the SCALED latent (``generate._project_latent``).
+    mla_scale_q_lora: bool = False
+    mla_scale_kv_lora: bool = False
     # Fuse the LM-head projection into a chunked cross-entropy
     # (ops/losses.fused_lm_loss) so the [B*T, V] f32 logits tensor never
     # hits HBM — loss_fn only; forward() still returns full logits for
@@ -267,6 +287,20 @@ class TransformerConfig:
         ):
             if field:
                 raise ValueError(f"{what}: has not run and is not built")
+        if self.shortcut_moe:
+            for field, what in (
+                (not (self.latent_attention and self.routed_experts), "the shortcut-connected double layer (shortcut_moe) without latent attention (kv_lora_rank > 0) and routed experts (experts_per_token > 0)"),
+                (kinds, "the shortcut-connected double layer (shortcut_moe) under a layer pattern (layer_kinds)"),
+                (self.hc_mult, "the shortcut-connected double layer (shortcut_moe) over hyper-connections (hc_mult > 0)"),
+                (self.first_dense_layers or self.num_shared_experts, "the shortcut-connected double layer (shortcut_moe) with leading dense layers or shared experts"),
+                (self.post_norms or not self.pre_norms, "the shortcut-connected double layer (shortcut_moe) with post_norms or without pre_norms"),
+            ):
+                if field:
+                    raise ValueError(f"{what}: has not run and is not built")
+        if self.zero_experts < 0 or (self.zero_experts and not self.routed_experts):
+            raise ValueError(f"zero_experts {self.zero_experts}: identity experts beside routed experts (experts_per_token > 0), or none")
+        if (self.mla_scale_q_lora or self.mla_scale_kv_lora) and not self.latent_attention:
+            raise ValueError("mla_scale_q_lora / mla_scale_kv_lora without latent attention (kv_lora_rank > 0)")
         if self.block_diffusion < 0 or (self.block_diffusion and not 0 <= self.mask_token_id < self.vocab_size):
             raise ValueError(f"block_diffusion {self.block_diffusion} with mask_token_id {self.mask_token_id}: a block of at least one position and a mask id of the vocabulary")
         if kinds and (len(kinds) != self.n_layers or set(kinds) - {"window", "full", "linear", "mamba", "experts", "conv"}):
@@ -356,6 +390,16 @@ class TransformerConfig:
         return "mamba" in self.layer_kinds or "experts" in self.layer_kinds
 
     @property
+    def attention_sublayers(self) -> int:
+        """Attention sub-layers, each a layer of the cache, in one of ``n_layers`` (2 under ``shortcut_moe``)."""
+        return 2 if self.shortcut_moe else 1
+
+    @property
+    def router_width(self) -> int:
+        """Columns of a router: the experts and, behind them, the identity experts (``zero_experts``)."""
+        return self.num_experts + self.zero_experts
+
+    @property
     def held_experts(self) -> int:
         """Routed experts of a block whose weights this program holds (``expert_share``)."""
         return self.num_experts // self.expert_share[1]
@@ -410,6 +454,14 @@ class TransformerConfig:
             missing.append("an embedding multiplier (embed_multiplier) has no training block")
         if self.hc_mult:
             missing.append("a residual path of several streams (hc_mult) has no training block")
+        if self.shortcut_moe:
+            missing.append("the shortcut-connected double layer (shortcut_moe) has no training block")
+        if self.zero_experts:
+            missing.append("identity experts in the router (zero_experts) have no training block")
+        if not self.router_normalize:
+            missing.append("chosen weights that are not normalised (router_normalize) have no training block")
+        if self.mla_scale_q_lora or self.mla_scale_kv_lora:
+            missing.append("latent attention's constants (mla_scale_q_lora, mla_scale_kv_lora) have no training block")
         if self.block_diffusion:
             missing.append("generation by diffusion over blocks (block_diffusion) trains on a doubled sequence, noisy blocks beside clean ones, which has no training block")
         return "; ".join(missing)
@@ -518,8 +570,8 @@ def _layer_leaves(cfg: TransformerConfig, mlp, mixer="attention") -> dict:
         cfg.d_model, cfg.n_heads, cfg.n_kv_heads, cfg.head_dim, cfg.d_ff, cfg.num_experts
     )
     s = D**-0.5
-    # Residual branches shrink with depth: two a layer, or one a block.
-    out = (cfg.n_layers if cfg.single_mixer else 2 * cfg.n_layers) ** -0.5
+    # Residual branches shrink with depth: two a layer, or one a block, or (the double layer) five.
+    out = (cfg.n_layers if cfg.single_mixer else (5 if cfg.shortcut_moe else 2) * cfg.n_layers) ** -0.5
     leaves = {}
     if cfg.pre_norms:
         leaves = {
@@ -647,11 +699,15 @@ def _layer_leaves(cfg: TransformerConfig, mlp, mixer="attention") -> dict:
         Fs = cfg.num_shared_experts * F
         # The router stays float32 whatever the weights' dtype, as published:
         # a near-tie between two experts must not turn on a rounding.
+        W = cfg.router_width
+        # The bias is small beside the scores it is added to: sigmoids of order 1, or (identity experts: a softmax over
+        # hundreds of columns) of order 1 / W, where neighbours in rank near the k-th lie a fifth of that apart.
+        bias = 1.0 / W if cfg.zero_experts else 0.01
         leaves.update(
             {
-                "gate": _Leaf(4, (D, E), s, ("embed", None), jnp.float32),
+                "gate": _Leaf(4, (D, W), s, ("embed", None), jnp.float32),
                 # A leaf only where the router has one: one that no gradient reaches would still be decayed by AdamW.
-                **({"gate_bias": _Leaf(9, (E,), 0.01, (None,), jnp.float32)} if cfg.router_bias else {}),
+                **({"gate_bias": _Leaf(9, (W,), bias, (None,), jnp.float32)} if cfg.router_bias else {}),
             }
         )
         if Fs:
@@ -672,7 +728,25 @@ def _layer_leaves(cfg: TransformerConfig, mlp, mixer="attention") -> dict:
             "wo_e": _Leaf(7, (E, F, D), F**-0.5 * out, ("expert", "mlp", "embed")),
         }
     )
+    if cfg.shortcut_moe:
+        # What a sub-layer has of its own gains an axis of 2 behind the layers' (the branch's leaves above do not); the
+        # dense FFNs take the keys a shared expert's leaves would (the double layer has none).
+        Fd = cfg.d_ff
+        own = {name: leaf for name, leaf in leaves.items() if name not in SHORTCUT_BRANCH_LEAVES}
+        own.update(
+            {
+                "wi": _Leaf(10, (D, Fd), s, ("embed", "mlp")),
+                "wg": _Leaf(11, (D, Fd), s, ("embed", "mlp")),
+                "wo_mlp": _Leaf(12, (Fd, D), Fd**-0.5 * out, ("mlp", "embed")),
+            }
+        )
+        leaves.update({name: leaf._replace(shape=(2, *leaf.shape), axes=(None, *leaf.axes)) for name, leaf in own.items()})
     return leaves
+
+
+# The leaves of a double layer (``shortcut_moe``) that are its ONE branch of routed experts'; every other leaf is a
+# sub-layer's and is stacked ``[n_layers, 2, ...]``.
+SHORTCUT_BRANCH_LEAVES = ("gate", "gate_bias", "wi_e", "wg_e", "wo_e")
 
 
 LINEAR_LAYERS = "linear_layers"
@@ -704,8 +778,10 @@ def _layer_stacks(cfg: TransformerConfig) -> dict:
     (``"expert_layers"``) and the attention blocks (``"layers"``). A pattern
     with conv layers is stacked by kind too (``"conv_layers"``, ``"layers"``),
     behind its leading dense layers, conv layers all, which stay a stack of
-    their own. What a kind of layer keeps in a cache is ``generate._layer_plan``'s
-    to say."""
+    their own. A configuration of double layers (``shortcut_moe``) is ONE stack,
+    ``"layers"``, whose leaves ``_layer_leaves`` gives an axis of 2 where a
+    sub-layer owns them. What a kind of layer keeps in a cache is
+    ``generate._layer_plan``'s to say."""
     mlp = "routed" if cfg.routed_experts else "switch" if cfg.num_experts > 0 else "dense"
     count = cfg.layer_kinds.count
     if cfg.single_mixer:

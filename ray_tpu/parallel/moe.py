@@ -125,8 +125,8 @@ def experts_run(assignments: int, experts: int, d_model: int, d_expert: int) -> 
 
     ``"kernel"``: grouped, ``ops/grouped_matmul.py``: on a TPU, from
     ``_KERNEL_ROWS_A_GROUP`` rows a group on (a prefill chunk's: 512 rows are
-    24-32 a group in every configuration served) where its row tile divides the
-    rows.
+    24-32 a group in every configuration served but one, whose 12 picks among
+    512 experts are 12) where its row tile divides the rows.
     ``"ragged_dot"``: grouped, ``jax.lax.ragged_dot``: the rest (a decode step's
     0.5-3 rows a group, where the call is the experts' bytes; every CPU run),
     where that kernel tiles the widths (``grouped_matmul_tiles``).
@@ -238,8 +238,20 @@ def held_rows(assignments: int, share) -> int | None:
     return bound if bound < assignments else None
 
 
+def _identity_term(x, w, chosen, experts: int, valid):
+    """What a row's picks of IDENTITY experts add: ``(sum of their weights) x``,
+    [N, D] float32. x [N, D], w [N, k] float32, chosen [N, k] among the router's
+    columns, of which those from ``experts`` on are identities. They reach no
+    matrix, are sorted into no group and cost a row's width of multiplies. A
+    padding row adds zeros by ``valid``, not by a weight of 0."""
+    with jax.named_scope("moe_identity"):
+        w_id = jnp.sum(jnp.where(chosen >= experts, w, 0.0), axis=-1, keepdims=True)
+        term = w_id * x.astype(jnp.float32)
+        return term if valid is None else jnp.where(valid[:, None], term, 0.0)
+
+
 def routed_experts(params, x, *, k: int, scale: float = 1.0, valid=None, layer=None, share=(0, 1),
-                   score: str = "sigmoid", rows: int | None = None):
+                   score: str = "sigmoid", rows: int | None = None, identity: int = 0, normalize: bool = True):
     """Dropless top-``k`` routing over SwiGLU experts: every token reaches its
     ``k`` experts, whatever the load. No capacity, so no ``[tokens, E, C]``
     tensor: assignments are sorted by expert and the three matmuls run
@@ -248,16 +260,27 @@ def routed_experts(params, x, *, k: int, scale: float = 1.0, valid=None, layer=N
     fits a group (``experts_run``).
 
     params: ``gate`` [D, E] and, where the router has one, ``gate_bias`` [E]
-    (float32), ``wg_e`` / ``wi_e`` [E, D, F], ``wo_e`` [E, F, D]; without
+    (float32; E + ``identity`` wide, below), ``wg_e`` / ``wi_e`` [E, D, F], ``wo_e`` [E, F, D]; without
     ``wg_e`` an expert is ``relu(x W_up)^2 W_down``, two matrices. x: [N, D].
     Widths that neither grouped kernel takes at the call's rows run plain
     batched matmuls instead (``experts_run``); routing, counts and result are
     the same. The router runs in float32: ``s = score(x gate)``
     (``router_scores``: ``"sigmoid"``, or ``"softmax"`` over all E); the ``k``
     experts with the largest ``s + gate_bias`` are chosen (the bias chooses, it
-    does not weigh), weighted ``scale * s / sum(s over the chosen)``. ``valid``
+    does not weigh), weighted ``scale * s / sum(s over the chosen)``, or (``normalize``
+    False) ``scale * s`` as it stands. ``valid``
     [N] bool (optional): rows that are padding; they reach no expert, add
     zeros, and are not counted.
+
+    ``identity``: the router is WIDER than the experts it routes to. Its last
+    ``identity`` columns are identity experts: they are scored and chosen with
+    the others (a softmax runs over all the columns), have no matrix, and a
+    pick of one adds ``w x`` (``_identity_term``). E, here and below, is the
+    count of the experts that have matrices, the router's width less
+    ``identity``. A pick of an identity lies past the last group like one of
+    an expert that is not held, and what the grouped matmul leaves in its row
+    is selected away before anything multiplies it. The term is the layer's,
+    not a share's: every share's ``out`` holds it whole.
 
     ``layer`` (a traced int32, optional): the three expert leaves are then
     whole STACKS ``[L, E, ...]`` and this call runs layer ``layer`` of them, as
@@ -286,14 +309,17 @@ def routed_experts(params, x, *, k: int, scale: float = 1.0, valid=None, layer=N
     reaches neither the result nor a gradient.
 
     Returns (out [N, D] in x's dtype, assignments [experts held] int32: tokens
-    sent to each, chosen [N, k] int32: each row's experts among all E, padding
-    rows' too, scores [N, E] float32: the router's, for a balance term)."""
+    sent to each, chosen [N, k] int32: each row's picks among all the router's
+    E + ``identity`` columns, padding rows' too, scores [N, E + ``identity``]
+    float32: the router's, for a balance term)."""
     N, D = x.shape
-    E = params["gate"].shape[-1]
+    E = params["gate"].shape[-1] - identity
     held = E // share[1]
     how = experts_run(N * k, E, D, params["wo_e"].shape[-2])
     # A training step's share: ``_held_rows`` gathers its own rows, and what the backward pass reads has a name.
     bounded = rows is not None and rows < N * k and how != "every_expert"
+    if identity and rows is not None:
+        raise ValueError("rows= is a training step's bound: identity experts (identity=) are served, not trained")
     named = _named(bounded)
     with jax.named_scope("moe_router"):
         # Each is named before the next reads it: a gradient that came through an unnamed one would run it again.
@@ -301,9 +327,10 @@ def routed_experts(params, x, *, k: int, scale: float = 1.0, valid=None, layer=N
         bias = params.get("gate_bias")
         chosen = named(jax.lax.top_k(s if bias is None else s + bias.astype(jnp.float32), k)[1], ROUTER_CHOSEN)  # [N, k]
         w = named(jnp.take_along_axis(s, chosen, axis=-1), ROUTER_TAKEN)  # (the gather: 1.34 ms at [16384, 64], v5e)
-        w = scale * w / jnp.sum(w, axis=-1, keepdims=True)
+        w = scale * w / jnp.sum(w, axis=-1, keepdims=True) if normalize else scale * w
     expert = chosen.reshape(N * k)
-    if held != E:  # by its rank among the experts held; one that is not held: past the last
+    elsewhere = held != E or identity > 0  # some picks reach no matrix here
+    if elsewhere:  # by its rank among the experts held; one that is not held, an identity: past the last
         expert = expert - share[0] * held
         expert = jnp.where((expert >= 0) & (expert < held), expert, held)
     if valid is not None:
@@ -314,7 +341,10 @@ def routed_experts(params, x, *, k: int, scale: float = 1.0, valid=None, layer=N
         rows_of = jnp.arange(N, dtype=jnp.int32)[:, None]
         by_expert = jnp.zeros((N, held), jnp.float32).at[rows_of, expert.reshape(N, k)].add(w, mode="drop")
         with jax.named_scope("moe_experts"):
-            return _every_expert(params, x, by_expert, layer).astype(x.dtype), sizes, chosen, s
+            out = _every_expert(params, x, by_expert, layer)
+            if identity:
+                out = out + _identity_term(x, w, chosen, E, valid)
+            return out.astype(x.dtype), sizes, chosen, s
     with jax.named_scope("moe_dispatch"):
         order = jnp.argsort(expert)  # stable: assignment ids grouped by expert
         # (a decode step's programs keep the scatter they were built with: their text is held to the parent's)
@@ -354,7 +384,7 @@ def routed_experts(params, x, *, k: int, scale: float = 1.0, valid=None, layer=N
         # Un-sort by gather (assignment a sits at sorted row inverse[a]), combine in float32.
         inverse = jnp.zeros((N * k,), jnp.int32).at[order].set(jnp.arange(N * k, dtype=jnp.int32))
         y = ys[inverse].reshape(N, k, D).astype(jnp.float32)
-        if held != E:  # whatever the grouped matmul left in a row of no group
+        if elsewhere:  # whatever the grouped matmul left in a row of no group
             first = share[0] * held
             y = jnp.where(((chosen >= first) & (chosen < first + held))[..., None], y, 0.0)
         if valid is not None:
@@ -364,7 +394,10 @@ def routed_experts(params, x, *, k: int, scale: float = 1.0, valid=None, layer=N
             # row lands in the null block, which every gathered view holds behind its mask, where 0 x NaN is NaN.
             y = jnp.where(valid[:, None, None], y, 0.0)
             w = jnp.where(valid[:, None], w, 0.0)
-        return jnp.einsum("nk,nkd->nd", w, y).astype(x.dtype), sizes, chosen, s
+        out = jnp.einsum("nk,nkd->nd", w, y)
+        if identity:
+            out = out + _identity_term(x, w, chosen, E, valid)
+        return out.astype(x.dtype), sizes, chosen, s
 
 
 def _held_rows(experts, x, w, order, groups, rows: int, k: int):

@@ -802,7 +802,7 @@ class LLMEngine:
             # builds the copy's program before the replica is ready: the first
             # ``stats()`` of a serving engine compiles nothing inside a stream.
             self._moe_read = np.asarray(self._copy_moe_counts()) if cfg.routed_experts else None
-            self._moe_folded = (0, 0)  # assignments (held, all) of it that ``LLM`` has
+            self._moe_folded = (0, 0, 0)  # assignments (held, all, identity picks) of it that ``LLM`` has
         # Block 0 is the reserved null block — never handed out.
         self._free: list[int] = list(range(self.num_blocks - 1, 0, -1))
         self._prefix: dict[bytes, _PrefixEntry] = {}
@@ -1146,7 +1146,14 @@ class LLMEngine:
         the program holds a share of the experts (``cfg.expert_share``) all of
         these count the experts HELD, E of them, and ``assignments_all``, a
         number an expert layer, every assignment the router made, held or
-        not (without a share: the sum of ``assignments``). Only the scheduler
+        not (without a share: the sum of ``assignments``). Where the router
+        has identity experts (``cfg.zero_experts``) two numbers an expert
+        layer more, what the device alone knows: ``picks_identity``, the picks
+        that were identities (they cost a row's width of multiplies; the picks
+        of other chips' experts are ``assignments_all`` less these and the sum
+        of ``assignments``), and ``rows_without_held``, the rows none of whose
+        picks was held here (of ``assignments_all / experts_per_token`` rows).
+        Only the scheduler
         thread may read the pool, which every dispatch donates: it is asked,
         and answers between two passes."""
         if not self.cfg.routed_experts:
@@ -1166,6 +1173,11 @@ class LLMEngine:
                 "assignments_all": self._moe_assignments_all(of).tolist(),
                 "experts_touched": of[:, E].tolist(),
                 "fullest_expert_load": of[:, E + 1].tolist(),
+                **(
+                    {"picks_identity": of[:, -2].tolist(), "rows_without_held": of[:, -1].tolist()}
+                    if self.cfg.zero_experts  # the last two columns: ``generate._picks_apart``
+                    else {}
+                ),
             }
             for kind, of in zip(("decode", "prefill"), read)
         }
@@ -1173,9 +1185,12 @@ class LLMEngine:
     def _moe_assignments_all(self, counts: np.ndarray) -> np.ndarray:
         """Every assignment the router made, a number an expert layer, from
         counters [..., expert layers, columns]: a column of its own where the
-        program holds a share of the experts, else the held ones' sum."""
+        program holds a share of the experts or routes over identity experts
+        (``generate.counts_every_pick``), else the held ones' sum."""
+        from ray_tpu.models.generate import counts_every_pick
+
         E = self.cfg.held_experts
-        return counts[..., E + 3] if self.cfg.expert_share[1] > 1 else counts[..., :E].sum(axis=-1)
+        return counts[..., E + 3] if counts_every_pick(self.cfg) else counts[..., :E].sum(axis=-1)
 
     @any_thread
     def refresh_moe_counts(self):
@@ -1191,9 +1206,12 @@ class LLMEngine:
         process's ``LLM`` stats (``ray_tpu_serve_llm_moe_assignments_total``)."""
         held = int(self._moe_read[..., : self.cfg.held_experts].sum())
         routed = int(self._moe_assignments_all(self._moe_read).sum())
-        LLM.moe_assignments_held += held - self._moe_folded[0]
-        LLM.moe_assignments_elsewhere += (routed - held) - (self._moe_folded[1] - self._moe_folded[0])
-        self._moe_folded = (held, routed)
+        identity = int(self._moe_read[..., -2].sum()) if self.cfg.zero_experts else 0
+        was_held, was_routed, was_identity = self._moe_folded
+        LLM.moe_assignments_held += held - was_held
+        LLM.moe_assignments_elsewhere += (routed - held - identity) - (was_routed - was_held - was_identity)
+        LLM.moe_picks_identity += identity - was_identity
+        self._moe_folded = (held, routed, identity)
 
     def _copy_moe_counts(self):
         """The expert counters, copied on the device: the host's view of the

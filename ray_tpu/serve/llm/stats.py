@@ -173,9 +173,11 @@ class _LLMStats:
         "state_resets",
         # Assignments of tokens to routed experts, as the engines last read
         # them from the device: to experts the program holds, and (a program
-        # that holds a share of them: ``TransformerConfig.expert_share``) to the others.
+        # that holds a share of them: ``TransformerConfig.expert_share``) to the others;
+        # and (a router with identity experts: ``zero_experts``) the picks that were identities.
         "moe_assignments_held",
         "moe_assignments_elsewhere",
+        "moe_picks_identity",
         # Scheduler-loop nanoseconds by span and iterations by kind: lists
         # of plain ints, indexed like SPAN_NAMES / ITERATION_KINDS.
         "span_ns",

@@ -174,6 +174,20 @@ _WRONG_SINCE_A_SECOND_ARCHITECTURE = {  # the first four (PR 32)
         "is in but short_conv_*. test_bench_sdar.py::test_the_new_entries_are_appended_behind_what_was_there holds PR 52's eight "
         "behind the 49, PR 54's two behind them, PR 56's three behind those and every list's order, without a pin on the END"
     ),
+    # And since an eleventh configuration, whose cell joins every list LFM2's is in but the conv mixer's and the K/V cache's
+    # pairs, and the latent pair's and the held share's (PR 61):
+    "test_bench_sdar.py::test_the_new_entries_are_appended_behind_what_was_there": (
+        "holds the six of a replica's start to the nine serving cells it knew and its own, and the cells to thirteen with "
+        "SDAR's the last; PR 61 appends its cell to the six's lists and to every other list LFM2's cell is in but short_conv_* "
+        "and cache_attention_*. test_bench_longcat.py::test_the_new_entries_are_appended_behind_what_was_there holds PR 52's "
+        "eight behind the 49, PR 54's two, PR 56's three and PR 61's two behind them and every list's order, without a pin on the END"
+    ),
+    "test_bench_setup_stages.py::test_xings_cell_reports_what_it_did_and_the_six_of_its_start": (
+        "holds moe_held_share_pct to Nemotron's cell alone and the latent pair to GLM's and Xing's; PR 61's cell holds a share "
+        "of its experts and attends over a latent pool, and is appended to both. test_bench_longcat.py::"
+        "test_the_new_entries_are_appended_behind_what_was_there holds those lists with the new cell last, and what Xing's "
+        "cell reports"
+    ),
 }
 
 

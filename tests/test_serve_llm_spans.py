@@ -123,7 +123,7 @@ def test_request_stamps_are_ordered(model, scenario, engine_kw):
     stamps = [rec[k] for k in ("t_recv_ns", "t_submit_ns", "t_admit_ns", "t_first_ns", "t_done_ns")]
     assert all(isinstance(s, int) and s > 0 for s in stamps), rec
     assert stamps == sorted(stamps), rec
-    assert rec["t_admit_ns"] == int(req.t_admit * 1e9)  # the FIRST admission's
+    assert rec["t_admit_ns"] == round(req.t_admit * 1e9)  # the FIRST admission's (rounded as ``end_request`` rounds it: ``int`` is a nanosecond short now and then)
     for key, value in want.items():
         assert rec[key] == value, (key, rec)
     if want.get("preemptions"):

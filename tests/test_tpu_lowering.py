@@ -1118,6 +1118,46 @@ def test_the_block_pass_and_its_chunk_copy_no_pool_and_run_the_grouped_kernel(on
         assert stats.temp_size_in_bytes + stats.argument_size_in_bytes < 13.0e9  # 11.96 GB of arguments: the chip reports 16.9
 
 
+def test_the_double_layers_programs_walk_two_cached_layers_a_layer_and_copy_neither_pool_nor_experts(one_v5e_chip, monkeypatch):
+    """LongCat-Flash-Omni's decode program (128 rows, the whole table: the one a
+    TPU backend gets) and its prefill chunk at the benchmark's widths, compiled
+    for the v5e (PR 61). A double layer is ONE scan body that walks TWO layers of
+    the latent pool in place (64 heads over 640-wide rows, a shape neither
+    kernel of ``ops/latent_attention.py`` had run) and runs the branch's grouped
+    matmuls over the WHOLE stacks of the 16 held experts: the step's 1,536
+    picks ``ragged_dot``, the chunk's 6,144 the kernel whose row tile fits. The
+    pool (8 cached layers), the words of the picks and no layer's experts are
+    copied; the arguments are the 13.1 GB a chip holds."""
+    import importlib
+    import re
+
+    import jax
+
+    for module in ("ray_tpu.ops.attention", "ray_tpu.ops.latent_attention", "ray_tpu.ops.grouped_matmul"):
+        monkeypatch.setattr(importlib.import_module(module), "_on_tpu", lambda: True)
+    monkeypatch.setattr(importlib.import_module("ray_tpu.serve.llm.engine"), "_JIT_CACHE", {})
+    decode, prefill, args = _cell_programs("longcat4.rollout-wide")
+    describe = lambda a: jax.tree.map(lambda x: jax.ShapeDtypeStruct(x.shape, x.dtype, sharding=one_v5e_chip), a)  # noqa: E731
+    pool_bytes = 8 * 16385 * 16 * 640 * 2 + 4 * 4 * 16385 * 16 * 4
+    for program, given, step in ((decode, args(128), True), (prefill, args(None), False)):
+        compiled = program.lower(*describe(given)).compile()
+        text = compiled.as_text()
+        assert bool(re.search(r"%ragged-dot\S* = bf16\[1536,", text)) == step and bool(re.search(r"%gmm\S* = bf16\[6144,", text)) == (not step)
+        walked = "128,64,1,640" if step else "1,64,512,640"  # the walk over the pool, a row's query or a chunk's tiles
+        walks = re.findall(rf"= bf16\[{walked}\]\S* custom-call\(.*tpu_custom_call", text)
+        assert len(walks) == 2, len(walks)  # one a sub-layer, inside the one body
+        assert not re.search(r"= bf16\[\d+,16,640\]\S* fusion\(", text)  # and no view of a table is gathered
+        for leaf in ("bf16[8,16385,16,640]", "s32[4,4,16385,16]"):
+            assert leaf in text and not re.search(rf"= {re.escape(leaf)}\S* copy\(", text), leaf
+        assert not re.search(r"= bf16\[16,(6144,2048|2048,6144)\]\S* (fusion|copy)\(", text)  # no layer's experts materialised
+        # nor a layer's pair of FFNs (sliced by the scan a pair was, 13 ms a step on the chip: ``generate._shortcut_layer``)
+        assert not re.search(r"bf16\[(1,)?2,(6144,12288|12288,6144)\]", text)
+        stats = compiled.memory_analysis()
+        # (0.44 GB of it the two small projections' stacks, ``wq_b`` and ``wkv_b``, laid out by head once a call)
+        assert stats.alias_size_in_bytes >= pool_bytes and stats.temp_size_in_bytes < 0.9e9, stats.temp_size_in_bytes
+        assert 13.0e9 < stats.argument_size_in_bytes < 13.2e9  # 10.38 GB of weights, a 2.68 GB pool
+
+
 @pytest.mark.parametrize("cell_name", sorted(_PROGRAMS_OF_PR_34))
 def test_the_new_fields_at_their_defaults_are_the_configuration_that_states_neither(cell_name):
     """PR 47 sends every join of the cached layer through ``generate._residual``
