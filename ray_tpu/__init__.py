@@ -240,6 +240,17 @@ def put(value, *, tensor_transport: str | None = None) -> ObjectRef:
 
 
 def wait(refs, *, num_returns: int = 1, timeout: float | None = None, fetch_local: bool = True):
+    """Return ``(ready, not_ready)`` once ``num_returns`` of ``refs`` are ready
+    or ``timeout`` seconds have passed (reference: ``ray.wait``,
+    python/ray/_private/worker.py:2587); ``ready`` holds at most ``num_returns``.
+
+    An object is ready once it exists: its value is held in this process, or
+    its owner knows it sealed in some node's object store. ``fetch_local=True``
+    (the default) also pulls an object that lies in another node's store into
+    this node's and counts it ready when it has arrived, so that a ``get``
+    behind the ``wait`` does not block; ``fetch_local=False`` moves nothing and
+    counts the object ready where it lies.
+    """
     from ray_tpu._private import worker_context
 
     return worker_context.get_core_worker().wait(

@@ -68,6 +68,11 @@ _exec_ctx: contextvars.ContextVar = contextvars.ContextVar("ray_tpu_exec_ctx", d
 DRIVER = "driver"
 WORKER = "worker"
 
+# What shutdown() gives its last flushes to the GCS, all together. A GCS that
+# is up takes milliseconds; against one that is gone the client's own ladder
+# (four attempts of 10 s to connect, for each of four calls) took ~160 s.
+_SHUTDOWN_FLUSH_S = 2.0
+
 
 def _maybe_jax_array(obj) -> bool:
     """True iff obj is a jax.Array — without importing jax for non-jax
@@ -1443,13 +1448,24 @@ class CoreWorker:
 
     @blocking
     def wait(self, refs, num_returns=1, timeout=None, fetch_local=True):
+        """Split ``refs`` into (ready, not ready), returning once ``num_returns``
+        are ready or ``timeout`` seconds have passed.
+
+        An object is ready once it exists: its value lies in this process, or
+        its owner knows it sealed in some node's store. With ``fetch_local``
+        (the default, as in the reference's ``ray.wait``, worker.py:2587) an
+        object sealed in ANOTHER node's store is also pulled into this node's
+        store, by the call ``get`` makes, and is ready when it has arrived; with
+        ``fetch_local=False`` nothing is moved and it is ready where it lies.
+        """
         deadline = None if timeout is None else time.monotonic() + timeout
         pending = list(refs)
         ready: list = []
+        pulls: dict = {}  # object id -> the pull this call started for it
         while True:
             still = []
             for ref in pending:
-                if self._is_ready(ref, fetch_local):
+                if self._is_ready(ref, fetch_local, pulls):
                     ready.append(ref)
                 else:
                     still.append(ref)
@@ -1463,7 +1479,7 @@ class CoreWorker:
         # ready list; ready-but-surplus refs stay in the remaining list
         return ready[:num_returns], ready[num_returns:] + pending
 
-    def _is_ready(self, ref, fetch_local: bool) -> bool:
+    def _is_ready(self, ref, fetch_local: bool, pulls: dict) -> bool:
         oid_hex = ref.hex()
         with self._lock:
             if oid_hex in self.in_process_store:
@@ -1472,20 +1488,25 @@ class CoreWorker:
             if task_id in self.pending_tasks:
                 return False
             obj = self.owned.get(oid_hex)
-        if obj is not None and obj.in_plasma:
-            if not fetch_local:
-                return True
-            return self.store.contains(oid_hex)
-        if ref.owner_addr is not None and tuple(ref.owner_addr) != tuple(self.address):
+        if obj is None or not obj.in_plasma:  # not ours to know sealed: ask its owner
+            if ref.owner_addr is None or tuple(ref.owner_addr) == tuple(self.address):
+                return False
             if self.store.contains(oid_hex):
                 return True
             try:
                 client = self._owner_client(tuple(ref.owner_addr))
-                resp = client.call("get_inline", {"object_id": oid_hex, "wait": False}, timeout=2)
-                return resp.get("kind") in ("inline", "plasma")
+                kind = client.call("get_inline", {"object_id": oid_hex, "wait": False}, timeout=2).get("kind")
             except Exception:
                 return False
-        return obj is not None and (obj.in_plasma or oid_hex in self.in_process_store)
+            if kind != "plasma":
+                return kind == "inline"
+        # Sealed in some node's store.
+        if not fetch_local or self.store.contains(oid_hex):
+            return True
+        pull = pulls.get(oid_hex)
+        if pull is None or pull.done():  # a pull that ran out is started again
+            pulls[oid_hex] = self._io.spawn(self.store.afetch(oid_hex, timeout=30.0))
+        return False
 
     def as_future(self, ref) -> ConcurrentFuture:
         fut: ConcurrentFuture = ConcurrentFuture()
@@ -3012,10 +3033,28 @@ class CoreWorker:
                 self._lease_mgr.close()
             except Exception:
                 pass
-        try:
-            self.flush_task_events()
-        except Exception:
-            pass
+        # The last words to the GCS are best effort, and the head may be gone
+        # before its driver (a cluster torn down first, a GCS that died): they
+        # get one bound in all, not the client's ladder of attempts for each.
+        last_words = threading.Thread(
+            target=self._flush_to_gcs_at_exit, args=(job_state,), name="shutdown-flush", daemon=True
+        )
+        last_words.start()
+        last_words.join(_SHUTDOWN_FLUSH_S)
+        for c in list(self._actor_clients.values()):
+            c.close()
+        for c in list(self._owner_client_cache.values()):
+            c.close()
+        for c in list(self._devobj_clients.values()):
+            c.close()
+        self.server.stop()
+        self.store.close()
+        self.gcs.close()
+        self.raylet.close()
+        self._executor.shutdown(wait=False)
+
+    def _flush_to_gcs_at_exit(self, job_state: str | None):
+        self.flush_task_events()
         # Final metrics window must not vanish with the process: the periodic
         # flusher runs every metrics_flush_interval_s, and this GCS client is
         # about to close.
@@ -3030,26 +3069,13 @@ class CoreWorker:
             from ray_tpu._private.usage_stats import write_usage_stats
 
             write_usage_stats(self)
-            if job_state is None:
-                job_state = "SUCCEEDED"
             try:
                 self.gcs.call(
                     "mark_job_finished",
-                    {"job_id": self.job_id.hex(), "state": job_state},
+                    {"job_id": self.job_id.hex(), "state": job_state or "SUCCEEDED"},
                 )
             except Exception:
                 pass
-        for c in list(self._actor_clients.values()):
-            c.close()
-        for c in list(self._owner_client_cache.values()):
-            c.close()
-        for c in list(self._devobj_clients.values()):
-            c.close()
-        self.server.stop()
-        self.store.close()
-        self.gcs.close()
-        self.raylet.close()
-        self._executor.shutdown(wait=False)
 
 
 _MISSING = object()

@@ -67,7 +67,7 @@ LEASE_STATS = _LeaseStats()
 class _Lease:
     __slots__ = (
         "lease_id", "worker_id", "address", "client", "shape", "inflight",
-        "last_active", "raylet_addr", "ever_used",
+        "last_active", "raylet_addr", "ever_used", "suspect",
     )
 
     def __init__(self, lease_id, worker_id, address, client, shape, raylet_addr):
@@ -85,6 +85,8 @@ class _Lease:
         # Observability: once a first batch has shipped, later batches count
         # as warm reuses (the hit side of the warm-lease hit ratio).
         self.ever_used = False
+        # Fed nothing while a ping is out (LeaseManager._ping_leases_of).
+        self.suspect = False
 
 
 @dataclass(eq=False)  # identity hash: shapes are collected in sets
@@ -135,7 +137,8 @@ class LeaseManager:
         key = tuple(addr)
         client = self._raylet_clients.get(key)
         if client is None:
-            client = self._raylet_clients[key] = RpcClient(key, label=f"lease-raylet")
+            # A raylet at a known address is listening or dead (as a worker is).
+            client = self._raylet_clients[key] = RpcClient(key, label="lease-raylet", connect_timeout=2.0)
         return client
 
     # ---- entry points ----
@@ -236,7 +239,7 @@ class LeaseManager:
         else:
             depth = 1
         room = depth - len(lease.inflight)
-        if room <= 0 or not shape.queue:
+        if room <= 0 or not shape.queue or lease.suspect:
             return
         chunk = []
         while shape.queue and len(chunk) < room:
@@ -276,13 +279,29 @@ class LeaseManager:
         try:
             await asyncio.wait_for(fut, 15)
         except Exception:
+            self._ping_leases_of(lease.raylet_addr, but=lease)
             await self._lease_failed(lease, "lease_exec failed")
 
     async def _send_exec(self, lease: _Lease, payload: dict):
         try:
             await lease.client.acall("lease_exec", payload, timeout=15)
         except Exception:
+            self._ping_leases_of(lease.raylet_addr, but=lease)
             await self._lease_failed(lease, "lease_exec failed")
+
+    @loop_only
+    def _ping_leases_of(self, raylet_addr, but: _Lease | None = None):
+        """A node is in doubt: one of its leased workers (``but``) does not
+        answer, or its raylet does not. A worker mostly dies with its node, so
+        the node's leased workers are pinged, which costs their tasks no
+        attempt, and fed nothing until they answer: a task retried from one
+        dead worker onto the next warm lease of the same dead node spent its
+        retries there, one connect ladder each."""
+        for shape in self._shapes.values():
+            for lease in shape.leases.values():
+                if lease is not but and lease.raylet_addr == raylet_addr and not lease.suspect:
+                    lease.suspect = True
+                    asyncio.ensure_future(self._probe(lease))
 
     async def _request_lease(self, shape: _Shape):
         lease_id = os.urandom(12).hex()
@@ -336,7 +355,13 @@ class LeaseManager:
                 await asyncio.sleep(0.2)
                 self._pump(shape)
             return
-        client = RpcClient(tuple(resp["address"]), label=f"lease-{resp['worker_id'][:8]}")
+        # Short connect timeout, as for an actor's client: a granted worker is
+        # listening, so an address that refuses is a dead worker, and the task
+        # shipped to it must fail over in seconds (lease_exec against a killed
+        # node ran four connects of 10 s out before the task was retried).
+        client = RpcClient(
+            tuple(resp["address"]), label=f"lease-{resp['worker_id'][:8]}", connect_timeout=2.0
+        )
         lease = _Lease(
             lease_id, resp["worker_id"], tuple(resp["address"]), client, shape,
             tuple(resp.get("raylet_address") or self.cw.raylet.address),
@@ -489,17 +514,24 @@ class LeaseManager:
                     ]
                 try:
                     resp = await self._raylet_for(addr).acall(
-                        "renew_worker_leases", payload, timeout=10
+                        "renew_worker_leases", payload, timeout=10, retries=0
                     )
                     for lid in resp.get("revoked", []):
                         self.on_lease_revoked(lid)
                 except Exception:
-                    pass
+                    # The raylet that holds these leases does not answer: ask
+                    # the workers themselves. (Waiting for 30 s without a
+                    # completion, behind this loop's own retries against a
+                    # dead raylet, found a killed node's tasks after ~85 s.)
+                    self._ping_leases_of(addr)
 
     async def _probe(self, lease: _Lease):
         try:
             await lease.client.acall("lease_ping", {}, timeout=5)
             lease.last_active = time.monotonic()
+            if lease.suspect:
+                lease.suspect = False
+                self._pump(lease.shape)
         except Exception:
             await self._lease_failed(lease, "worker unresponsive")
 

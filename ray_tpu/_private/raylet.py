@@ -591,15 +591,85 @@ class Raylet:
         """Re-route queued tasks that cannot run on this node: PG tasks whose
         bundle lives elsewhere, locally-infeasible tasks awaiting spillback
         (the cluster view may have been empty at submit), and strict
-        node-affinity tasks targeting another node."""
-        stuck = [s for s in self.task_queue if self._must_reroute(s)]
-        for spec in stuck:
-            self.task_queue.remove(spec)
+        node-affinity tasks targeting another node; and tasks that fit here
+        by totals but not NOW, while a peer has room now."""
+        full: set = set()  # shapes that no peer has room for, as this pass found
+        for spec in list(self.task_queue):
+            if not (self._must_reroute(spec) or self._parked_beside_room(spec, full)):
+                continue
+            if spec.lease_id:
+                self._reroute_lease_request(spec)
+                continue
+            try:
+                self.task_queue.remove(spec)
+            except ValueError:
+                continue  # dispatched while this pass awaited
             self._forwarding.add(spec.task_id)
             try:
                 await self._queue_and_schedule(spec)
             finally:
                 self._forwarding.discard(spec.task_id)
+
+    def _parked_beside_room(self, spec: TaskSpec, full: set) -> bool:
+        """A task this raylet queues itself (an actor's creation; a lease
+        request spills as it arrives and is its requester's to ask again) that
+        fits here by totals but not now, while a peer's row says it fits THERE
+        now. Placement is decided as a task arrives, against resources that
+        are taken only when a worker is up: a burst lands on one node (the GCS
+        scores one stale row for every actor of Serve's N replicas), and what
+        did not fit waited here for good, beside idle nodes, since an actor
+        holds its resources for life. _queue_and_schedule debits the peer's
+        mirrored row as it forwards, so a pass moves no more than fits."""
+        if spec.lease_id or spec.placement_group_id or len(self.cluster_view) <= 1:
+            return False
+        if (spec.scheduling_strategy or "DEFAULT") != "DEFAULT" or self._fits_now(spec):
+            return False
+        shape = tuple(sorted(spec.resources.items()))
+        if shape in full:
+            return False
+        from ray_tpu._private.sched_core import HYBRID
+
+        if self._sched.best_node(spec.resources, HYBRID, self.node_id) in (None, self.node_id):
+            full.add(shape)
+            return False
+        return True
+
+    def _reroute_lease_request(self, spec: TaskSpec):
+        """A lease request is ANSWERED, not handed on: a peer sent the bare
+        spec finds no requester behind it and drops the grant, and the
+        requester waits its whole lease timeout out (30 s for the first task a
+        driver sends for a resource of a node that its raylet's view did not
+        hold yet). Ask the node the view NOW names, and give the requester
+        that node's answer."""
+        target = self._pick_node(spec)
+        node = None if target in (None, self.node_id) else self.cluster_view.get(target)
+        fut = self._lease_futures.get(spec.lease_id)
+        if node is None or fut is None:
+            return  # nowhere yet: the request stays parked until it times out
+        self.task_queue.remove(spec)
+        del self._lease_futures[spec.lease_id]
+        # The owner's backlog goes with the request: the raylet that holds the
+        # lease is the one told when it is returned, and a figure left HERE
+        # read as demand for 30 s after the work was done (and kept the
+        # autoscaler from retiring the node it had launched for it).
+        demand = self._lease_demand.pop(
+            (spec.owner_worker_id, tuple(sorted(spec.resources.items()))), (0, 0.0)
+        )
+
+        async def _ask():
+            try:
+                resp = await self._peer(target, node["address"]).acall(
+                    "request_worker_lease",
+                    {"spec": spec.to_wire(), "backlog": demand[0]},
+                    timeout=self.cfg.worker_lease_timeout_s + 5,
+                    retries=0,
+                )
+            except Exception:
+                resp = {"granted": False}
+            if not fut.done():
+                fut.set_result(resp)
+
+        asyncio.ensure_future(_ask())
 
     def _must_reroute(self, spec: TaskSpec) -> bool:
         if spec.placement_group_id:
@@ -1589,10 +1659,16 @@ class Raylet:
             node = self.cluster_view.get(target)
             if node is not None:
                 try:
+                    # One attempt: a peer that dies with the request in hand is
+                    # not asked again at an address that now refuses (four
+                    # connects of 10 s, while the owner's pending request kept
+                    # it from asking anyone else); the request is queued here
+                    # and goes to a live node with the next view.
                     return await self._peer(target, node["address"]).acall(
                         "request_worker_lease",
                         req,
                         timeout=self.cfg.worker_lease_timeout_s + 5,
+                        retries=0,
                     )
                 except Exception:
                     pass
@@ -1929,6 +2005,21 @@ class Raylet:
             chips_freed = False
             for worker in list(self.workers.values()):
                 if worker.state == "dead" and not worker.tpu_chips:
+                    continue
+                if (
+                    worker.state == "starting"
+                    and time.monotonic() - worker.last_idle > self.cfg.worker_startup_timeout_s
+                ):
+                    # Spawned (last_idle is its handle's birth) and never
+                    # registered: a fork that hung, a child stuck before its
+                    # first line. _dispatch counts it as coming and starts no
+                    # other, so the tasks behind it waited without a limit.
+                    if worker.proc is not None:
+                        worker.proc.kill()
+                    await self._on_worker_death(
+                        worker,
+                        f"worker did not register within {self.cfg.worker_startup_timeout_s:g} s of its spawn",
+                    )
                     continue
                 if worker.proc is None or worker.proc.poll() is None:
                     continue

@@ -349,6 +349,15 @@ class StoreCore:
         self.arena.close(unlink=True)
 
 
+def _past(timeout: float | None) -> float | None:
+    """The client's limit for a raylet call that waits ``timeout`` itself: a
+    little past it, so that the raylet's own answer ends the wait. At the same
+    limit the client's fired first as often as not, and a client's timeout is
+    RETRIED (RpcClient.acall): a get_view of 5 s on an object whose node had
+    died took 21 s, four waits of 5, before lineage reconstruction was tried."""
+    return None if timeout is None else timeout + 2.0
+
+
 class StoreClient:
     """Client-side view: direct arena mapping + RPC metadata ops to raylet.
 
@@ -418,11 +427,20 @@ class StoreClient:
                     self._pins.setdefault(object_id_hex, []).append(("idx", token))
                 return self.arena.read(offset, size).toreadonly()
         resp = self.raylet.call(
-            "store_get", {"object_id": object_id_hex, "timeout": timeout}, timeout=timeout
+            "store_get", {"object_id": object_id_hex, "timeout": timeout}, timeout=_past(timeout)
         )
         with self._pins_lock:
             self._pins.setdefault(object_id_hex, []).append(("rpc", None))
         return self.arena.read(resp["offset"], resp["size"]).toreadonly()
+
+    async def afetch(self, object_id_hex: str, timeout: float) -> None:
+        """Bring the object into this node's store without reading it (for
+        wait(fetch_local=True)): get_view's raylet call, which pulls an object
+        sealed on another node, and the pin it took given back at once."""
+        await self.raylet.acall(
+            "store_get", {"object_id": object_id_hex, "timeout": timeout}, timeout=_past(timeout)
+        )
+        await self.raylet.apush("store_release", {"object_id": object_id_hex})
 
     def contains(self, object_id_hex: str) -> bool:
         if self.index is not None:

@@ -289,9 +289,17 @@ class ZygoteClient:
                 if _time.monotonic() > deadline:
                     raise RuntimeError("zygote did not come up within 30s")
                 await asyncio.sleep(0.02)
-        ready = await _aread_frame(reader)
-        if not ready.get("ready"):
-            raise RuntimeError(f"unexpected zygote handshake: {ready!r}")
+        try:
+            # Bounded like the connect above: this runs under spawn()'s lock,
+            # so a fork-server that accepts and then says nothing would hold
+            # every later spawn of the node, and the tasks behind them, for good.
+            ready = await asyncio.wait_for(_aread_frame(reader), max(deadline - _time.monotonic(), 1.0))
+            if not ready.get("ready"):
+                raise RuntimeError(f"unexpected zygote handshake: {ready!r}")
+        except BaseException:
+            writer.close()
+            self.proc.kill()
+            raise
         self._writer = writer
         self._read_task = asyncio.ensure_future(self._read_loop(reader))
 

@@ -691,8 +691,9 @@ class CompiledDAG:
         # arena block would corrupt an unrelated object for every reader on
         # the node — leaking the rings is the safe failure.
         confirmed = True
+        gone = set(self._dead_actors)
         for actor_id in actor_ids:
-            if actor_id in self._dead_actors:
+            if actor_id in gone:
                 continue  # loop died with the process; endpoints are gone
             try:
                 resp = cw._owner_client(self._actor_addrs[actor_id]).call(
@@ -701,8 +702,14 @@ class CompiledDAG:
                 if not resp.get("ok"):
                     confirmed = False
             except Exception:
-                if not self._actor_gone(actor_id):
+                if self._actor_gone(actor_id):
+                    gone.add(actor_id)
+                else:
                     confirmed = False
+        # A dead reader's gate died with it, and its address answers nobody:
+        # a close sent there ran the client's whole ladder of attempts out
+        # (four connects of 10 s) for every channel the dead stage read.
+        gone_addrs = {self._actor_addrs[a] for a in gone}
         # 2. Close: shm rings get their closed word set (any still-blocked
         # local endpoint observes it within a poll); every reader gate is
         # closed so remote-mode endpoints unblock too.
@@ -716,7 +723,7 @@ class CompiledDAG:
             reader_addr = tuple(desc["reader_addr"])
             if reader_addr == tuple(cw.address):
                 local_cids.append(desc["cid"])
-            else:
+            elif reader_addr not in gone_addrs:
                 try:
                     cw._owner_client(reader_addr).call(
                         "channel_close", {"cid": desc["cid"]}, timeout=5

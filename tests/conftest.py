@@ -9,8 +9,13 @@ JAX is forced onto a virtual 8-device CPU mesh BEFORE first import so sharding
 tests exercise real multi-device paths without TPU hardware.
 """
 
+import faulthandler
 import os
+import shutil
+import signal
 import sys
+import tempfile
+import threading
 
 os.environ["JAX_PLATFORMS"] = "cpu"
 _flags = os.environ.get("XLA_FLAGS", "")
@@ -26,9 +31,30 @@ os.environ.setdefault("RAY_TPU_NUM_TPUS", "0")
 # also run inside every spawned worker's IO loop and exec thread.
 os.environ.setdefault("RAY_TPU_DEBUG_AFFINITY", "1")
 
+# One compile cache for the whole run, in the run's temp directory: the six
+# xdist workers and the worker processes of every test's cluster share it. The
+# suite is bound by the CPU's compiles, not by waits: the plain references run
+# op by op, some 450 little programs a sequence length at ~40 ms each, the same
+# ones in test after test, and the toy engines of one file compile alike. With
+# the cache a file's second run takes 0.4 of its first (test_serve_llm_pattern,
+# PERF.md section 7). A caller's own directory is left alone; ours is removed
+# as the session ends.
+_OUR_JAX_CACHE = os.path.join(
+    tempfile.gettempdir(),
+    "ray_tpu_tests_jax_cache_%d" % (os.getppid() if "PYTEST_XDIST_WORKER" in os.environ else os.getpid()),
+)
+if os.environ.setdefault("JAX_COMPILATION_CACHE_DIR", _OUR_JAX_CACHE) == _OUR_JAX_CACHE:
+    os.environ.setdefault("JAX_PERSISTENT_CACHE_MIN_COMPILE_TIME_SECS", "0")
+    os.environ.setdefault("JAX_PERSISTENT_CACHE_MIN_ENTRY_SIZE_BYTES", "-1")
+
 sys.path.insert(0, os.path.dirname(os.path.dirname(os.path.abspath(__file__))))
 
 import pytest  # noqa: E402
+
+
+def pytest_sessionfinish(session):
+    if "PYTEST_XDIST_WORKER" not in os.environ:
+        shutil.rmtree(_OUR_JAX_CACHE, ignore_errors=True)
 
 
 def pytest_configure(config):
@@ -196,6 +222,41 @@ def pytest_collection_modifyitems(config, items):
         for name, why in _WRONG_SINCE_A_SECOND_ARCHITECTURE.items():
             if item.nodeid.endswith(name):
                 item.add_marker(pytest.mark.xfail(reason=why, strict=True))
+
+
+# A test that hangs fails by its name. The driver cuts the whole run at its own
+# limit and a cut run names nothing (`failed: []`), so no one call may outlast
+# this: the longest tier-1 call is under 70 s alone and ~2.3x that with six
+# workers on eight cores. xdist's workers run their tests on the main thread
+# (execnet's `main_thread_only`), where a signal's handler may raise.
+_TEST_LIMIT_S = 300.0
+
+
+@pytest.hookimpl(hookwrapper=True)
+def pytest_runtest_call(item):
+    if threading.current_thread() is not threading.main_thread():
+        yield  # no handler can raise here; the driver's limit is all there is
+        return
+
+    def _expired(signum, frame):
+        with tempfile.TemporaryFile(mode="w+") as f:
+            faulthandler.dump_traceback(file=f, all_threads=True)
+            f.seek(0)
+            stacks = f.read()
+        # Failed is a BaseException: no `except Exception` of a retry loop
+        # that the test hangs in can swallow it.
+        pytest.fail(
+            f"{item.nodeid} ran past its limit of {_TEST_LIMIT_S:g} s; every thread's stack:\n{stacks}",
+            pytrace=False,
+        )
+
+    before = signal.signal(signal.SIGALRM, _expired)
+    signal.setitimer(signal.ITIMER_REAL, _TEST_LIMIT_S)
+    try:
+        yield
+    finally:
+        signal.setitimer(signal.ITIMER_REAL, 0)
+        signal.signal(signal.SIGALRM, before)
 
 
 @pytest.fixture

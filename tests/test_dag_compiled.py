@@ -294,7 +294,10 @@ def test_compiled_actor_death_chaos(compiled_cluster):
     with pytest.raises(ActorDiedError):
         compiled.execute(2)
 
+    t0 = time.monotonic()
     compiled.teardown()
+    # no close is sent to the dead stage's address (each ran four connects of 10 s out)
+    assert time.monotonic() - t0 < 10.0
     store1 = cw.raylet.call("get_state")["store"]
     assert store1["num_channels"] == store0["num_channels"]
     assert store1["used"] <= store0["used"]

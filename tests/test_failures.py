@@ -75,13 +75,84 @@ def test_lineage_reconstruction(ray_start_cluster):
         return np.ones((512, 512), dtype=np.float32)  # 1MB -> plasma on victim
 
     ref = produce.remote()
-    ray_tpu.wait([ref], timeout=60)
+    # The object exists before its node dies, and fetch_local=False leaves the
+    # only copy there.
+    assert ray_tpu.wait([ref], timeout=60, fetch_local=False) == ([ref], [])
     # Kill the node holding the only copy.
     cluster.remove_node(victim)
     cluster.add_node(num_cpus=1, resources={"victim": 1})
     time.sleep(1.0)
     out = ray_tpu.get(ref, timeout=120)
     assert out.shape == (512, 512)
+
+
+def test_a_task_running_on_a_node_that_dies_is_retried_in_seconds(ray_start_cluster, tmp_path):
+    """The owner learns that a leased worker died with its node from the lease's
+    renewal: the raylet that holds the lease no longer answers, so the worker
+    is pinged, and the task in flight on it goes to another node. (Found only
+    after 30 s without a completion, behind the renewal's own retries against
+    the dead raylet: 40-85 s.)"""
+    cluster = ray_start_cluster
+    cluster.add_node(num_cpus=1, resources={"head": 1})
+    victim = cluster.add_node(num_cpus=1, resources={"victim": 1})
+    cluster.connect()
+    cluster.wait_for_nodes()
+    started = str(tmp_path / "started")
+
+    @ray_tpu.remote(resources={"victim": 1}, max_retries=2)
+    def work(path):
+        import os as _os
+        import time as _time
+
+        if not _os.path.exists(path):  # the first attempt: in flight when its node dies
+            open(path, "w").close()
+            _time.sleep(300)
+        return "retried"
+
+    ref = work.remote(started)
+    deadline = time.monotonic() + 60
+    while not os.path.exists(started) and time.monotonic() < deadline:
+        time.sleep(0.05)
+    assert os.path.exists(started)
+    cluster.remove_node(victim)
+    cluster.add_node(num_cpus=1, resources={"victim": 1})
+    t0 = time.monotonic()
+    assert ray_tpu.get(ref, timeout=120) == "retried"
+    assert time.monotonic() - t0 < 30.0
+
+
+def test_a_worker_that_never_registers_is_given_up_and_another_is_started():
+    """worker_startup_timeout_s: a spawned worker that does not register within
+    it (a fork that hung, a child stuck before its first line) is killed and
+    counted dead. Until then dispatch counts it as coming and starts no other,
+    so without the limit the tasks behind it waited for good."""
+    import sys
+
+    from ray_tpu.cluster_utils import Cluster
+
+    cluster = Cluster(_system_config={"worker_startup_timeout_s": 1.0, "worker_zygote_enabled": False})
+    try:
+        raylet = cluster.add_node(num_cpus=1)
+        spawn, stuck = raylet._popen_worker, []
+
+        def first_one_never_registers(handle, delta, log_path, argv=None):
+            if stuck:
+                return spawn(handle, delta, log_path, argv=argv)
+            stuck.append(handle)
+            return spawn(handle, delta, log_path, argv=[sys.executable, "-c", "import time; time.sleep(600)"])
+
+        raylet._popen_worker = first_one_never_registers
+        cluster.connect()
+
+        @ray_tpu.remote
+        def f():
+            return "ran"
+
+        assert ray_tpu.get(f.remote(), timeout=30) == "ran"
+        assert stuck and stuck[0].state == "dead"
+        assert stuck[0].proc.wait(timeout=5) is not None  # killed, not left behind
+    finally:
+        cluster.shutdown()
 
 
 def test_node_death_detected(ray_start_cluster):

@@ -132,6 +132,28 @@ def _restart_gcs(gcs, persist):
     return GcsServer(host=host, port=port, persist_path=persist)
 
 
+def test_a_driver_whose_gcs_is_gone_shuts_down_at_once(tmp_path):
+    """shutdown()'s last flushes to the GCS (task events, metrics, usage, the
+    job's end) are best effort and share one bound of 2 s: a driver that
+    outlives its head does not wait out the client's retries for each."""
+    gcs, raylet, cw, _ = _boot(tmp_path)
+    try:
+
+        @ray_tpu.remote
+        def f():
+            return 1
+
+        assert ray_tpu.get(f.remote(), timeout=60) == 1  # task events to flush
+        gcs.stop()
+    finally:
+        worker_context.set_core_worker(None)
+        t0 = time.monotonic()
+        cw.shutdown()
+        took = time.monotonic() - t0
+        raylet.stop()
+    assert took < 5.0, f"shutdown against a stopped GCS took {took:.1f} s"
+
+
 def test_gcs_restart_under_running_tasks(tmp_path):
     """Tasks submitted before, DURING, and after a GCS restart all complete:
     the data plane (leases + direct transport) rides out the control-plane

@@ -174,7 +174,7 @@ def test_lease_demand_reaches_autoscaler_load(ray_start_regular):
 
     @ray_tpu.remote
     def slow():
-        time.sleep(0.5)
+        time.sleep(0.2)  # 200 of them on four CPUs: a backlog for ~10 s
         return 1
 
     refs = [slow.remote() for _ in range(200)]

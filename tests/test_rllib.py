@@ -116,7 +116,6 @@ def test_ppo_learns_cartpole(ray_cluster):
         .debugging(seed=0)
     )
     algo = cfg.build()
-    algo.setup(cfg.to_dict())
     best = 0.0
     try:
         for _ in range(20):
@@ -144,7 +143,6 @@ def test_ppo_checkpoint_restore(ray_cluster):
         .training(train_batch_size=256, sgd_minibatch_size=64, num_sgd_iter=2)
     )
     algo = cfg.build()
-    algo.setup(cfg.to_dict())
     algo.step()
     ckpt = algo.save_checkpoint()
     w_before = algo.get_policy_weights()
@@ -179,7 +177,6 @@ def test_dqn_learns_cartpole(ray_cluster):
         .debugging(seed=0)
     )
     algo = cfg.build()
-    algo.setup(cfg.to_dict())
     best = 0.0
     try:
         for _ in range(20):
@@ -321,7 +318,6 @@ def test_bc_imitates_expert(ray_cluster, tmp_path):
     )
     cfg.offline_data(input_=str(tmp_path))
     algo = cfg.build()
-    algo.setup(cfg.to_dict())
     try:
         first = None
         for _ in range(60):
@@ -372,7 +368,6 @@ def test_marwil_prefers_high_return_actions(ray_cluster, tmp_path):
     )
     cfg.offline_data(input_=str(tmp_path))
     algo = cfg.build()
-    algo.setup(cfg.to_dict())
     try:
         for _ in range(80):
             algo.step()
@@ -405,7 +400,6 @@ def test_impala_learns_cartpole(ray_cluster):
         .debugging(seed=0)
     )
     algo = cfg.build()
-    algo.setup(cfg.to_dict())
     best = 0.0
     try:
         for _ in range(40):

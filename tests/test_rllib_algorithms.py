@@ -33,7 +33,6 @@ def test_a2c_learns_cartpole(ray_cluster):
         .debugging(seed=0)
     )
     algo = cfg.build()
-    algo.setup(cfg.to_dict())
     best = 0.0
     try:
         for _ in range(40):
@@ -46,6 +45,7 @@ def test_a2c_learns_cartpole(ray_cluster):
         algo.cleanup()
 
 
+@pytest.mark.slow  # a learning curve: up to 40 iterations, ~25 s; the next test is its quick case
 def test_appo_learns_cartpole(ray_cluster):
     import jax
 
@@ -74,6 +74,30 @@ def test_appo_learns_cartpole(ray_cluster):
         algo.cleanup()
 
 
+def test_appo_two_iterations_report_and_restore(ray_cluster):
+    """The learning run's stand-in in tier-1: two asynchronous iterations report
+    finite losses over the batches they took, and a checkpoint restores the
+    policy's actions."""
+    import jax
+
+    jax.config.update("jax_platforms", "cpu")
+    from rllib_quick import two_iterations_then_a_restored_twin
+
+    from ray_tpu.rllib import APPOConfig
+
+    cfg = (
+        APPOConfig()
+        .environment("CartPole-v1")
+        .rollouts(num_rollout_workers=2, num_envs_per_worker=4)
+        .training(lr=1e-3, train_batch_size=2048, entropy_coeff=0.01, num_sgd_iter=2, kl_coeff=0.0)
+        .debugging(seed=0)
+    )
+    keys = ("policy_loss", "vf_loss", "total_loss", "entropy", "grad_norm", "episode_reward_mean")
+    with two_iterations_then_a_restored_twin(cfg, keys, 4) as (r, algo, _):
+        assert r["timesteps_total"] == 2 * 2048
+        assert algo.compute_single_action(np.zeros(4, np.float32)) in (0, 1)
+
+
 def test_sac_pendulum_smoke(ray_cluster):
     import jax
 
@@ -90,7 +114,6 @@ def test_sac_pendulum_smoke(ray_cluster):
         .debugging(seed=0)
     )
     algo = cfg.build()
-    algo.setup(cfg.to_dict())
     try:
         for _ in range(3):
             r = algo.step()
@@ -120,7 +143,6 @@ def test_sac_discrete_smoke(ray_cluster):
         .debugging(seed=0)
     )
     algo = cfg.build()
-    algo.setup(cfg.to_dict())
     try:
         r = algo.step()
         assert np.isfinite(r["critic_loss"])
@@ -146,7 +168,6 @@ def test_td3_pendulum_smoke(ray_cluster):
     )
     assert cfg.twin_q and cfg.policy_delay == 2 and cfg.smooth_target_policy
     algo = cfg.build()
-    algo.setup(cfg.to_dict())
     try:
         for _ in range(2):
             r = algo.step()
@@ -176,7 +197,6 @@ def test_es_improves_cartpole(ray_cluster):
         .debugging(seed=0)
     )
     algo = cfg.build()
-    algo.setup(cfg.to_dict())
     try:
         rewards = []
         for _ in range(6):
@@ -236,7 +256,6 @@ def test_cql_offline_smoke(ray_cluster, tmp_path):
         .debugging(seed=0)
     )
     algo = cfg.build()
-    algo.setup(cfg.to_dict())
     try:
         r = algo.step()
         assert np.isfinite(r["bellman_loss"])
@@ -264,7 +283,6 @@ def test_pg_learns_cartpole(ray_cluster):
         .debugging(seed=0)
     )
     algo = cfg.build()
-    algo.setup(cfg.to_dict())
     best = 0.0
     try:
         for _ in range(40):

@@ -23,6 +23,7 @@ def ray_cluster():
         ray_tpu.shutdown()
 
 
+@pytest.mark.slow  # a learning curve: 22 iterations of self-play, ~2 minutes; its quick case is the round trip below
 def test_alpha_zero_learns_cartpole(ray_cluster):
     from ray_tpu.rllib import AlphaZeroConfig
 
@@ -67,7 +68,13 @@ def test_alpha_zero_learns_cartpole(ray_cluster):
         algo.cleanup()
 
 
-def test_alpha_zero_checkpoint_roundtrip(ray_cluster):
+@pytest.mark.parametrize("use_mcts", [False, True], ids=["the net's own action", "the search's action"])
+def test_alpha_zero_checkpoint_roundtrip(ray_cluster, use_mcts):
+    """The learning run's stand-in in tier-1: two iterations of self-play report
+    finite numbers, and a second instance restored from the checkpoint holds the
+    same weights and picks the same actions, by the net alone or by the search."""
+    from rllib_quick import two_iterations_then_a_restored_twin
+
     from ray_tpu.rllib import AlphaZeroConfig
 
     cfg = (
@@ -76,22 +83,21 @@ def test_alpha_zero_checkpoint_roundtrip(ray_cluster):
         .training(num_sims=8, episodes_per_iter=1, updates_per_iter=3, horizon=50)
         .debugging(seed=0)
     )
-    algo = cfg.build()
-    algo.setup(cfg.to_dict())
-    algo.step()
-    ckpt = algo.save_checkpoint()
-    algo2 = cfg.build()
-    algo2.setup(cfg.to_dict())
-    algo2.load_checkpoint(ckpt)
-    assert algo2._timesteps_total == algo._timesteps_total
-    import jax
 
-    jax.tree_util.tree_map(
-        lambda a, b: np.testing.assert_allclose(np.asarray(a), np.asarray(b)),
-        algo.params, algo2.params,
-    )
-    algo.cleanup()
-    algo2.cleanup()
+    def act(algo, obs):
+        if use_mcts:  # the search steps the env from its state: the same start for both
+            algo.env.reset(seed=7)
+        return algo.compute_single_action(obs, use_mcts=use_mcts)
+
+    keys = ("pi_loss", "v_loss", "total_loss", "episode_reward_mean")
+    with two_iterations_then_a_restored_twin(cfg, keys, 4, act=act) as (_, algo, algo2):
+        assert algo2._timesteps_total == algo._timesteps_total
+        import jax
+
+        jax.tree_util.tree_map(
+            lambda a, b: np.testing.assert_allclose(np.asarray(a), np.asarray(b)),
+            algo.params, algo2.params,
+        )
 
 
 def test_state_clone_wrapper_restores_exactly(ray_cluster):

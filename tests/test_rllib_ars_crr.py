@@ -46,7 +46,6 @@ def test_ars_learns_cartpole(ray_cluster):
         .debugging(seed=0)
     )
     algo = cfg.build()
-    algo.setup(cfg.to_dict())
     best = 0.0
     try:
         for _ in range(30):
@@ -67,6 +66,7 @@ def _expert_action(obs) -> int:
     return int(obs[2] + 0.3 * obs[3] > 0)
 
 
+@pytest.mark.slow  # a learning curve: 3000 offline updates and five evaluation episodes, ~25 s; its quick case is the smoke test's "exp" case
 def test_crr_learns_cartpole_offline(ray_cluster, tmp_path):
     from ray_tpu.rllib import CRRConfig
     from ray_tpu.rllib.offline import JsonWriter
@@ -133,7 +133,14 @@ def test_crr_learns_cartpole_offline(ray_cluster, tmp_path):
         algo.cleanup()
 
 
-def test_crr_binary_weights_smoke(ray_cluster, tmp_path):
+@pytest.mark.parametrize("weight_type", ["binary", "exp"])
+def test_crr_weights_smoke(ray_cluster, tmp_path, weight_type):
+    """Two iterations over a small random dataset report finite losses and
+    weights of their kind, and a second instance restored from the checkpoint
+    takes the same actions; "exp" is the learning run's kind, whose stand-in in
+    tier-1 this is."""
+    from rllib_quick import two_iterations_then_a_restored_twin
+
     from ray_tpu.rllib import CRRConfig
     from ray_tpu.rllib.offline import JsonWriter
     from ray_tpu.rllib.policy.sample_batch import (
@@ -169,16 +176,10 @@ def test_crr_binary_weights_smoke(ray_cluster, tmp_path):
         CRRConfig()
         .environment("CartPole-v1")
         .offline_data(input_=str(tmp_path / "crr_bin"))
-        .training(updates_per_iter=50, weight_type="binary")
+        .training(updates_per_iter=50, weight_type=weight_type)
         .debugging(seed=0)
     )
-    algo = cfg.build()
-    algo.setup(cfg.to_dict())
-    r = algo.step()
-    assert np.isfinite(r["total_loss"])
-    assert 0.0 <= r["mean_weight"] <= 1.0  # binary weights are indicators
-    ckpt = algo.save_checkpoint()
-    algo2 = cfg.build()
-    algo2.setup(cfg.to_dict())
-    algo2.load_checkpoint(ckpt)
-    assert algo2._timesteps_total == algo._timesteps_total
+    with two_iterations_then_a_restored_twin(cfg, ("total_loss", "mean_weight"), 4) as (r, algo, algo2):
+        if weight_type == "binary":
+            assert 0.0 <= r["mean_weight"] <= 1.0  # binary weights are indicators
+        assert algo2._timesteps_total == algo._timesteps_total

@@ -113,6 +113,7 @@ def test_agent_connector_pipeline_shapes_observations():
         w.stop()
 
 
+@pytest.mark.slow  # a learning curve: up to 80 iterations, ~25 s; the next test is its quick case
 def test_impala_async_learns_cartpole(ray_cluster):
     import jax
 
@@ -139,3 +140,27 @@ def test_impala_async_learns_cartpole(ray_cluster):
         assert best >= 100, f"async IMPALA failed to learn CartPole (best={best})"
     finally:
         algo.cleanup()
+
+
+def test_impala_async_two_iterations_report_and_restore(ray_cluster):
+    """The learning run's stand-in in tier-1: two iterations fed by the
+    background samplers report finite losses and importance ratios, and a
+    checkpoint restores the policy's actions."""
+    import jax
+
+    jax.config.update("jax_platforms", "cpu")
+    from rllib_quick import two_iterations_then_a_restored_twin
+
+    from ray_tpu.rllib import IMPALAConfig
+
+    cfg = (
+        IMPALAConfig()
+        .environment("CartPole-v1")
+        .rollouts(num_rollout_workers=2, num_envs_per_worker=4, rollout_fragment_length=128)
+        .training(lr=1e-3, train_batch_size=2048, entropy_coeff=0.01, async_sampling=True)
+        .debugging(seed=0)
+    )
+    keys = ("policy_loss", "vf_loss", "total_loss", "entropy", "mean_rho", "grad_norm", "episode_reward_mean")
+    with two_iterations_then_a_restored_twin(cfg, keys, 4) as (r, algo, _):
+        assert r["timesteps_total"] >= 2 * 2048
+        assert algo.compute_single_action(np.zeros(4, np.float32)) in (0, 1)

@@ -139,7 +139,6 @@ def test_algorithms_sample_through_pipelines(ray_start_regular, algo_name):
             .evaluation(evaluation_interval=1, evaluation_duration=2)
         )
     algo = cfg.build()
-    algo.setup(cfg.to_dict())
     try:
         r = algo.step()
         assert r.get("timesteps_total", r.get("num_env_steps_sampled", 1)) > 0

@@ -38,6 +38,7 @@ class Reach1D(gym.Env):
         return np.array([self.pos, self.target], np.float32), r, False, self.t >= 20, {}
 
 
+@pytest.mark.slow  # a learning curve: up to 25 iterations, ~40 s; its quick case is the smoke test's Reach1D case
 def test_dreamerv3_learns_reach1d():
     import jax
 
@@ -73,15 +74,21 @@ def test_dreamerv3_learns_reach1d():
         algo.cleanup()
 
 
-def test_dreamerv3_pendulum_smoke_and_checkpoint():
+@pytest.mark.parametrize("env, obs_dim, bound", [("Pendulum-v1", 3, 2.0), (Reach1D, 2, 1.0)], ids=["pendulum", "reach1d"])
+def test_dreamerv3_smoke_and_checkpoint(env, obs_dim, bound):
+    """Two iterations report finite losses, and a second instance restored from
+    the checkpoint takes the same actions: on Pendulum, and on the learning
+    run's own task, whose stand-in in tier-1 this is."""
     import jax
 
     jax.config.update("jax_platforms", "cpu")
+    from rllib_quick import two_iterations_then_a_restored_twin
+
     from ray_tpu.rllib import DreamerV3Config
 
     cfg = (
         DreamerV3Config()
-        .environment("Pendulum-v1")
+        .environment(env)
         .training(
             learning_starts=200, rollout_steps_per_iter=250, train_intensity=25,
             batch_size=4, batch_length=12, deter_size=64, model_hiddens=(64,),
@@ -89,20 +96,17 @@ def test_dreamerv3_pendulum_smoke_and_checkpoint():
         )
         .debugging(seed=0)
     )
-    algo = cfg.build()
-    try:
-        for _ in range(2):
-            r = algo.step()
-        for key in ("model_loss", "recon_loss", "reward_loss", "actor_loss", "critic_loss"):
-            assert np.isfinite(r[key]), key
-        a = algo.compute_single_action(np.zeros(3, np.float32))
-        assert a.shape == (1,) and -2.0 <= float(a[0]) <= 2.0
-        ckpt = algo.save_checkpoint()
-        w0 = np.asarray(algo.params["reward"][0]["w"])
-        algo.load_checkpoint(ckpt)
-        np.testing.assert_allclose(np.asarray(algo.params["reward"][0]["w"]), w0)
-    finally:
-        algo.cleanup()
+    def act(algo, obs):  # the posterior's latent is SAMPLED, from a key no checkpoint holds: the same key for both
+        algo._key = jax.random.PRNGKey(0)
+        return algo.compute_single_action(obs)
+
+    keys = ("model_loss", "recon_loss", "reward_loss", "actor_loss", "critic_loss")
+    with two_iterations_then_a_restored_twin(cfg, keys, obs_dim, act=act) as (_, algo, twin):
+        a = algo.compute_single_action(np.zeros(obs_dim, np.float32))
+        assert a.shape == (1,) and -bound <= float(a[0]) <= bound
+        np.testing.assert_allclose(
+            np.asarray(twin.params["reward"][0]["w"]), np.asarray(algo.params["reward"][0]["w"])
+        )
 
 
 def test_dreamerv3_discrete_smoke():

@@ -121,7 +121,6 @@ def test_ppo_learns_tiny_vision_env(ray_cluster):
         .debugging(seed=0)
     )
     algo = cfg.build()
-    algo.setup(cfg.to_dict())
     best = 0.0
     try:
         for _ in range(15):
@@ -184,7 +183,6 @@ def test_ppo_learns_multi_agent_cartpole(ray_cluster):
         .debugging(seed=0)
     )
     algo = cfg.build()
-    algo.setup(cfg.to_dict())
     best = 0.0
     try:
         for _ in range(20):

@@ -79,7 +79,6 @@ def test_marwil_trains_from_external_clients(ray_cluster):
         )
         cfg.offline_data(input_=server)
         algo = cfg.build()
-        algo.setup(cfg.to_dict())
         try:
             m = algo.step()
             assert np.isfinite(m.get("loss", m.get("total_loss", np.nan))), m
@@ -108,7 +107,6 @@ def test_input_reader_kwargs_reach_the_reader(ray_cluster):
             input_reader_kwargs={"timeout_s": 5.0, "min_episodes": 1, "window_rows": 256},
         )
         algo = cfg.build()
-        algo.setup(cfg.to_dict())
         try:
             assert algo.reader._timeout == 5.0
             assert algo.reader._window.capacity == 256
@@ -132,7 +130,6 @@ def test_dqn_serves_actions_and_trains_on_external_episodes(ray_cluster):
         .debugging(seed=0)
     )
     algo = cfg.build()
-    algo.setup(cfg.to_dict())
     server = PolicyServerInput(
         compute_action=lambda obs, explore: int(
             algo.compute_single_action(np.asarray(obs, np.float32))

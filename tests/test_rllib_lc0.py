@@ -67,7 +67,6 @@ def test_lc0_self_play_trains_and_beats_random():
         .debugging(seed=0)
     )
     algo = cfg.build()
-    algo.setup(cfg.to_dict())
     try:
         v_losses = []
         for _ in range(6):

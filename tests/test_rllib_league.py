@@ -40,6 +40,7 @@ def test_two_player_env_zero_sum():
     assert ob[2] == 1.0 and ob[3 + 0] == 1.0
 
 
+@pytest.mark.slow  # a learning curve: 30 league iterations, ~40 s; the next test is its quick case
 def test_alpha_star_league_learns_and_grows(ray_cluster):
     import jax
 
@@ -81,3 +82,33 @@ def test_alpha_star_league_learns_and_grows(ray_cluster):
         assert algo.winrate_vs("rocky", "main", episodes=10) >= 0.7
     finally:
         algo.cleanup()
+
+
+def test_alpha_star_two_iterations_train_every_slot_and_restore(ray_cluster):
+    """The learning run's stand-in in tier-1: after two league iterations every
+    kind of slot has trained (finite losses, win-rates logged), the league still
+    holds its scripted seed alone, and a checkpoint restores main's actions."""
+    import jax
+
+    jax.config.update("jax_platforms", "cpu")
+    from rllib_quick import two_iterations_then_a_restored_twin
+
+    from ray_tpu.rllib import AlphaStarConfig
+
+    rocky = scripted_biased_policy(3, favorite=0, p=0.8, seed=1)
+    cfg = (
+        AlphaStarConfig()
+        .environment(TwoPlayerMatrixEnv, env_config={"rounds": 24})
+        .training(
+            lr=5e-3, entropy_coeff=0.003, episodes_per_slot=6,
+            self_play_fraction=0.2, snapshot_interval=8,
+            snapshot_min_winrate=0.55, model_hiddens=(32,),
+            scripted_league_seeds=[("rocky", rocky)],
+        )
+        .debugging(seed=0)
+    )
+    slots = ("main", "main_exploiter_0", "league_exploiter_0")
+    with two_iterations_then_a_restored_twin(cfg, [f"{s}/loss" for s in slots], 6) as (r, algo, twin):
+        assert all(0.0 <= r[f"{s}/winrate"] <= 1.0 for s in slots)
+        assert r["league_size"] == 1  # no snapshot before snapshot_interval
+        assert 0.0 <= twin.winrate_vs("rocky", "main", episodes=4) <= 1.0

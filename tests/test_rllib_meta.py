@@ -38,6 +38,7 @@ def test_point_goal_env_task_api():
     assert total == env.horizon
 
 
+@pytest.mark.slow  # a learning curve: 15 meta-iterations, ~40 s; the next test is its quick case
 def test_maml_learns_to_adapt(ray_cluster):
     import jax
 
@@ -79,6 +80,37 @@ def test_maml_learns_to_adapt(ray_cluster):
         algo.cleanup()
 
 
+def test_maml_two_iterations_report_adapt_and_restore(ray_cluster):
+    """The learning run's stand-in in tier-1: two meta-iterations report finite
+    numbers before and after adaptation, the deploy-time adaptation API gives a
+    whole set of weights, and a checkpoint restores the policy's actions."""
+    import jax
+
+    jax.config.update("jax_platforms", "cpu")
+    from rllib_quick import two_iterations_then_a_restored_twin
+
+    from ray_tpu.rllib import MAMLConfig
+
+    cfg = (
+        MAMLConfig()
+        .environment(PointGoalEnv, env_config={"seed": 0})
+        .rollouts(num_rollout_workers=2)
+        .training(
+            lr=5e-3, inner_lr=0.3, meta_batch_size=4, episodes_per_task=4,
+            maml_optimizer_steps=2, model_hiddens=(32, 32),
+        )
+        .debugging(seed=0)
+    )
+    keys = ("meta_loss", "pre_adaptation_reward_mean", "post_adaptation_reward_mean", "adaptation_delta")
+    with two_iterations_then_a_restored_twin(cfg, keys, 2) as (r, algo, _):
+        assert r["adaptation_delta"] == pytest.approx(
+            r["post_adaptation_reward_mean"] - r["pre_adaptation_reward_mean"]
+        )
+        task = algo._task_env.sample_tasks(1)[0]
+        adapted = algo.adapt_to_task(task)
+        assert set(adapted.keys()) == set(algo.get_policy_weights().keys())
+
+
 def test_mbmpo_model_based_progress(ray_cluster):
     import jax
 
@@ -97,7 +129,6 @@ def test_mbmpo_model_based_progress(ray_cluster):
         .debugging(seed=0)
     )
     algo = cfg.build()
-    algo.setup(cfg.to_dict())
     try:
         results = [algo.step() for _ in range(8)]
         dyn_losses = [r["dynamics_loss"] for r in results]
@@ -138,7 +169,6 @@ def test_mbmpo_learned_dynamics_match_truth(ray_cluster):
         .debugging(seed=1)
     )
     algo = cfg.build()
-    algo.setup(cfg.to_dict())
     try:
         algo.step()
         algo.step()  # two rounds of real data + ensemble fitting

@@ -39,7 +39,6 @@ def test_apex_dqn_learns_cartpole(ray_cluster):
         .debugging(seed=0)
     )
     algo = cfg.build()
-    algo.setup(cfg.to_dict())
     best = 0.0
     try:
         for _ in range(30):
@@ -132,7 +131,6 @@ def test_qmix_learns_two_step_game():
         .debugging(seed=0)
     )
     algo = cfg.build()
-    algo.setup(cfg.to_dict())
     best = -1e9
     try:
         for _ in range(15):

@@ -38,7 +38,6 @@ def test_simple_q_learns_cartpole(ray_cluster):
     )
     assert not cfg.double_q and not cfg.prioritized_replay
     algo = cfg.build()
-    algo.setup(cfg.to_dict())
     best = 0.0
     try:
         for _ in range(16):
@@ -75,7 +74,6 @@ def test_a3c_learns_cartpole(ray_cluster):
         .debugging(seed=0)
     )
     algo = cfg.build()
-    algo.setup(cfg.to_dict())
     best = 0.0
     try:
         for _ in range(40):
@@ -92,6 +90,7 @@ def test_a3c_learns_cartpole(ray_cluster):
         algo.cleanup()
 
 
+@pytest.mark.slow  # a learning curve: up to 40 iterations, ~40 s; the next test is its quick case
 def test_ddppo_learns_cartpole_in_lockstep(ray_cluster):
     import jax
 
@@ -123,6 +122,30 @@ def test_ddppo_learns_cartpole_in_lockstep(ray_cluster):
         algo.cleanup()
 
 
+def test_ddppo_two_iterations_stay_in_lockstep_and_restore(ray_cluster):
+    """The learning run's stand-in in tier-1: two decentralized iterations (each
+    asserts in training_step that the workers' weight digests agree) report
+    finite losses, and a checkpoint restores the policy's actions."""
+    import jax
+
+    jax.config.update("jax_platforms", "cpu")
+    from rllib_quick import two_iterations_then_a_restored_twin
+
+    from ray_tpu.rllib import DDPPOConfig
+
+    cfg = (
+        DDPPOConfig()
+        .environment("CartPole-v1")
+        .rollouts(num_rollout_workers=2, num_envs_per_worker=8, rollout_fragment_length=60)
+        .training(lr=1e-3, entropy_coeff=0.005, num_sgd_iter=4, sgd_minibatch_size=120)
+        .debugging(seed=0)
+    )
+    keys = ("policy_loss", "vf_loss", "total_loss", "kl", "entropy", "episode_reward_mean")
+    with two_iterations_then_a_restored_twin(cfg, keys, 4) as (r, algo, _):
+        assert r["timesteps_total"] == 2 * 2 * 8 * 60
+        assert algo.compute_single_action(np.zeros(4, np.float32)) in (0, 1)
+
+
 def test_apex_ddpg_pendulum_smoke(ray_cluster):
     import jax
 
@@ -141,7 +164,6 @@ def test_apex_ddpg_pendulum_smoke(ray_cluster):
         .debugging(seed=0)
     )
     algo = cfg.build()
-    algo.setup(cfg.to_dict())
     try:
         for _ in range(2):
             r = algo.step()

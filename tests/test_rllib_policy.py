@@ -141,7 +141,6 @@ def test_algorithm_get_policy_end_to_end():
             .debugging(seed=0)
         )
         algo = cfg.build()
-        algo.setup(cfg.to_dict())
         try:
             algo.step()
             policy = algo.get_policy()

@@ -71,6 +71,7 @@ class Spread1D(MultiAgentEnv):
         )
 
 
+@pytest.mark.slow  # a learning curve: up to 30 iterations, ~20 s; its quick case is the round trip's recurrent case
 def test_r2d2_learns_cartpole():
     from ray_tpu.rllib.algorithms.r2d2 import R2D2Config
 
@@ -107,7 +108,14 @@ def test_r2d2_learns_cartpole():
         algo.cleanup()
 
 
-def test_r2d2_checkpoint_roundtrip(tmp_path):
+@pytest.mark.parametrize("recurrent", [False, True], ids=["from a zero state", "state in, state out"])
+def test_r2d2_checkpoint_roundtrip(recurrent):
+    """Two iterations report a finite loss once learning has started, and a
+    second instance restored from the checkpoint holds the same weights and takes
+    the same actions, also through the recurrent API that hands the hidden state
+    on (the learning run's last assertion, whose stand-in in tier-1 this is)."""
+    from rllib_quick import two_iterations_then_a_restored_twin
+
     from ray_tpu.rllib.algorithms.r2d2 import R2D2Config
 
     cfg = (
@@ -116,23 +124,22 @@ def test_r2d2_checkpoint_roundtrip(tmp_path):
         .training(rollout_steps_per_iter=200, learning_starts=100, train_intensity=20)
         .debugging(seed=0)
     )
-    algo = cfg.build()
-    algo.setup(cfg.to_dict())
-    algo.step()
-    ckpt = algo.save_checkpoint()
-    ts = algo._timesteps_total
-    algo2 = cfg.build()
-    algo2.setup(cfg.to_dict())
-    algo2.load_checkpoint(ckpt)
-    assert algo2._timesteps_total == ts
-    import jax
 
-    jax.tree_util.tree_map(
-        lambda a, b: np.testing.assert_allclose(np.asarray(a), np.asarray(b)),
-        algo.params, algo2.params,
-    )
-    algo.cleanup()
-    algo2.cleanup()
+    def act(algo, obs):
+        if not recurrent:
+            return algo.compute_single_action(obs)
+        a, h = algo.compute_single_action(obs, state=np.full((1, cfg.hidden_size), 0.5, np.float32))
+        assert a in (0, 1) and h.shape == (1, cfg.hidden_size)
+        return np.concatenate([[a], h.ravel()])
+
+    with two_iterations_then_a_restored_twin(cfg, ("total_loss", "td_abs", "epsilon"), 4, act=act) as (_, algo, algo2):
+        assert algo2._timesteps_total == algo._timesteps_total
+        import jax
+
+        jax.tree_util.tree_map(
+            lambda a, b: np.testing.assert_allclose(np.asarray(a), np.asarray(b)),
+            algo.params, algo2.params,
+        )
 
 
 def test_maddpg_learns_cooperative_spread():
@@ -150,7 +157,6 @@ def test_maddpg_learns_cooperative_spread():
         .debugging(seed=0)
     )
     algo = cfg.build()
-    algo.setup(cfg.to_dict())
     best = -1e9
     try:
         for _ in range(24):
@@ -204,7 +210,6 @@ def test_external_env_drives_dqn():
         .debugging(seed=0)
     )
     algo = cfg.build()
-    algo.setup(cfg.to_dict())
     ext = CartPoleExternal()
     runner = ExternalEnvRunner(ext, algo)
     best = 0.0

@@ -38,7 +38,6 @@ def test_slateq_learns_interest_evolution(ray_cluster):
         .debugging(seed=0)
     )
     algo = cfg.build()
-    algo.setup(cfg.to_dict())
     best = -1e9
     try:
         for _ in range(25):
@@ -71,11 +70,9 @@ def test_slateq_checkpoint_roundtrip(ray_cluster):
         .debugging(seed=0)
     )
     algo = cfg.build()
-    algo.setup(cfg.to_dict())
     algo.step()
     ckpt = algo.save_checkpoint()
     algo2 = cfg.build()
-    algo2.setup(cfg.to_dict())
     algo2.load_checkpoint(ckpt)
     assert algo2._timesteps_total == algo._timesteps_total
     import jax

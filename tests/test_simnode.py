@@ -406,7 +406,9 @@ def test_locality_task_lands_on_holder_and_spills_when_saturated():
         big = produce.options(
             scheduling_strategy=NodeAffinitySchedulingStrategy(node_id=n2.node_id)
         ).remote()
-        ray_tpu.wait([big], timeout=60)
+        # fetch_local=False: the holder keeps the ONLY copy, or locality would
+        # have two nodes to choose from.
+        assert ray_tpu.wait([big], timeout=60, fetch_local=False) == ([big], [])
         # Deterministic settle: the head's MIRROR of the holder must show a
         # free CPU again (produce released it; the delta takes ~2 heartbeat
         # intervals to propagate) or locality would correctly refuse a
