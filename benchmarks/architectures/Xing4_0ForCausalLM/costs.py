@@ -147,7 +147,7 @@ def decode_step_bytes(m: dict, context_tokens: int, itemsize: int = 2) -> int:
     their ``phi`` (``hyper_connection_bytes``; the program steps every slot's
     row, a token or not).
 
-    ``context_tokens`` is what ``decode_roofline``'s reader hands over: every
+    ``context_tokens`` is what ``decode_mfu_roofline``'s reader hands over: every
     HELD slot at the mix's mean length. A held slot is not a row that steps:
     the one whose prompt the prefill lane is working through, and those that
     wait for the lane, hold their slots and take no part in the step. So the

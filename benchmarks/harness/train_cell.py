@@ -123,7 +123,7 @@ def train_loop(config: dict):
         params, opt_state, batch
     )
     kernels_in_step = [
-        k for k in dep["mosaic_kernels"] if f'kernel_name = "{k}"' in lowered.as_text()
+        k for k in dep["mosaic_kernels"] if f'kernel_name = "{k}' in lowered.as_text()
     ] if config["platform"] == "tpu" else []
     step = lowered.compile()
     clock["compile_s"] = time.monotonic() - clock["t_loop"] - clock["params_s"] - clock["check_s"]

@@ -2,7 +2,13 @@
 time it took. The least: every matmul weight read once plus the cached keys and
 values of the tokens in context, at the HBM peak (memory bounds a decode step:
 2 operations a weight byte). Context is taken as the mean running slots times
-the mean of prompt plus half the output of the mix, from the mix's own file."""
+the mean of prompt plus half the output of the mix, from the mix's own file.
+
+It is the share of the HBM roofline that the WHOLE decode step reaches, the
+serving cells' counterpart of the training cells' ``mfu_pct`` (hence ``mfu`` in
+its name): the bytes are the step's work (``costs.decode_step_bytes``), whatever
+implements it, so this is the bound that stays when a kernel replaces the
+operations that a per-kernel roofline names and that roofline falls silent."""
 
 from benchmarks.harness import registry
 from benchmarks.harness.peaks import peaks

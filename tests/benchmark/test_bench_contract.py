@@ -120,7 +120,7 @@ GOOD = {
 }
 TRACED = {
     "engine_host_gap_ms": "ms", "slot_occupancy_pct": "%", "decode_step_ms": "ms",
-    "decode_roofline": "%", "replica_ready_s": "s", "device_idle_pct.serve": "%",
+    "decode_mfu_roofline": "%", "replica_ready_s": "s", "device_idle_pct.serve": "%",
 }
 
 
@@ -160,7 +160,7 @@ def _edit(line, path, value="__delete__"):
         (True, ("metrics", "decode_step_ms", "value"), float("nan"), "value"),
         (True, ("metrics", "itl_p95_ms"), "__delete__", "missing"),
         (True, ("metrics", "decode_step_ms"), None, "not {value, unit}"),
-        (True, ("metrics", "decode_roofline", "value"), 131.0, "share of a peak"),
+        (True, ("metrics", "decode_mfu_roofline", "value"), 131.0, "share of a peak"),
         (True, ("breakdown", "device_ops"), [["op", 1.0]] * 11, "at most 10"),
         (False, ("metrics", "itl_p95_ms", "value"), float("inf"), "value"),
         (False, ("metrics", "itl_p95_ms", "value"), None, "value"),

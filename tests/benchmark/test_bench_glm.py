@@ -91,7 +91,7 @@ def test_the_two_mixes_are_the_issues(manifest):
     six = {"moe_experts_touched_mean", "moe_expert_load_max_mean", "moe_experts_ms", "moe_experts_roofline",
            "latent_attention_ms", "latent_attention_roofline"}
     assert six <= want(ROLLOUT, True) and not six & want(LONG_PROMPT, True)
-    assert {"decode_roofline", "decode_step_ms", "engine_iteration_ms"} <= want(ROLLOUT, True) & want(LONG_PROMPT, True)
+    assert {"decode_mfu_roofline", "decode_step_ms", "engine_iteration_ms"} <= want(ROLLOUT, True) & want(LONG_PROMPT, True)
 
 
 def test_the_span_metrics_stand_and_new_cells_are_only_appended(manifest):

@@ -300,8 +300,8 @@ def test_the_readers_on_a_result_written_by_hand(manifest):
     mix = result["cell"]["traffic"]
     context = int(128 * (traffic.mean_length(mix["prompt_len"]) + traffic.mean_length(mix["output_len"]) / 2))
     least = _part("costs").decode_step_bytes(result["cell"]["config"], context) / 819e9
-    assert read("decode_roofline") == pytest.approx(100 * least / 0.020)
-    for name in ("moe_experts_roofline", "latent_attention_roofline", "latent_prefill_roofline", "decode_roofline"):
+    assert read("decode_mfu_roofline") == pytest.approx(100 * least / 0.020)
+    for name in ("moe_experts_roofline", "latent_attention_roofline", "latent_prefill_roofline", "decode_mfu_roofline"):
         assert 0 < read(name) <= 100, name
 
 

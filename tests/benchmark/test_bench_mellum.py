@@ -183,7 +183,7 @@ def test_the_job_is_the_issues_and_nothing_but_files_and_appended_entries_came(m
     cells = [w["name"] for w in manifest["workloads"]]
     assert cells.index(CELL) > cells.index("xing6.longdoc-12k") and sum(w["chips"] == 4 for w in manifest["workloads"]) == 1
     cfg = cell["config"]
-    assert cfg["deployment"]["mosaic_kernels"] == ["_flash_kernel", "_flash_bwd_dkv_kernel", "_flash_bwd_dq_kernel", "kernel"]
+    assert cfg["deployment"]["mosaic_kernels"] == ["_flash_kernel", "_flash_bwd", "kernel"]
     assert cfg["trace_programs"] == {"train_step": "^jit_train_step"} and "trace_ops" not in cfg
 
 
@@ -195,8 +195,8 @@ def test_each_training_configuration_states_its_deployment(manifest):
             continue
         dep, check = cell["config"]["deployment"], cell["config"]["check"]
         assert dep["param_dtype"] == "float32" and dep["remat"] is True and dep["fused_loss"] is True and dep["learning_rate"] == 1e-4
-        assert check["loss_abs_tol"] > 0 and 0 < check["grad_rel_l2_tol"] < 1 and dep["mosaic_kernels"][:3] == [
-            "_flash_kernel", "_flash_bwd_dkv_kernel", "_flash_bwd_dq_kernel"]
+        assert check["loss_abs_tol"] > 0 and 0 < check["grad_rel_l2_tol"] < 1 and dep["mosaic_kernels"][:2] == [
+            "_flash_kernel", "_flash_bwd"]
         costs = registry.load_architecture(cell, "costs")
         tokens = cell["traffic"]["seq_len"] * cell["traffic"]["batch_per_chip"] * cell["chips"]
         flops = costs.train_step_flops(cell["config"], cell["traffic"]["seq_len"], cell["traffic"]["batch_per_chip"] * cell["chips"])

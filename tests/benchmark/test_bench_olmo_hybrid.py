@@ -42,7 +42,7 @@ SHARED_METRICS = {
     "replica_ready_s", "replica_params_s", "serve_path_overhead_ms", "serve_ingress_p90_ms", "queue_wait_p90_ms",
     "prefill_span_p90_ms", "engine_host_gap_ms", "engine_iteration_ms", "engine_fetch_ms", "engine_sample_ms",
     "engine_build_ms", "engine_admit_ms", "engine_emit_ms", "slot_occupancy_pct", "decode_rows_mean", "decode_step_ms",
-    "decode_roofline", "prefill_chunk_ms", "compiles_in_window", "device_idle_pct.serve", "cache_attention_ms",
+    "decode_mfu_roofline", "prefill_chunk_ms", "compiles_in_window", "device_idle_pct.serve", "cache_attention_ms",
     "cache_attention_roofline",
     # a token's way back (PR 38's seven): the cell streams through the same proxy, and eight streams were chosen so
     # that the proxy's poll round does not make up ``itl_p95_ms``: these say whether it does

@@ -19,6 +19,7 @@ from bench_toy import toy_cell
 
 from benchmarks import run as bench_run
 from benchmarks.harness import contract, registry, traffic
+from benchmarks.harness.spans import decode_iterations
 
 SDAR, CELL, MIX, ARCH = "sdar-30b-a3b-chat-serve6", "sdar6.rollout-block", "rollout-block", "SDARMoeForCausalLM"
 LFM_CELL = "lfm9.rollout-wide"
@@ -258,8 +259,8 @@ def test_the_readers_on_a_result_written_by_hand(manifest):
     mix = result["cell"]["traffic"]
     context = int(128 * (traffic.mean_length(mix["prompt_len"]) + traffic.mean_length(mix["output_len"]) / 2))
     least = _part("costs").decode_step_bytes(result["cell"]["config"], context) / 819e9
-    assert read("decode_roofline") == pytest.approx(100 * least / 0.040)
-    for name in ("moe_experts_roofline", "cache_attention_roofline", "decode_roofline"):
+    assert read("decode_mfu_roofline") == pytest.approx(100 * least / 0.040)
+    for name in ("moe_experts_roofline", "cache_attention_roofline", "decode_mfu_roofline"):
         assert 0 < read(name) <= 100, name
 
 
@@ -317,8 +318,14 @@ def test_the_new_cell_runs_at_a_toy_size_against_its_reference(manifest, fake_ch
     assert counters["block_tokens_emitted"] >= result["client"]["tokens_in_window"] > 0
     assert set(counters["kv_groups"]) == {"full"}
     moe = counters["moe"]["decode"]
-    # the counters are copied between two passes: the one in flight is not in them yet
-    assert 0 <= counters["block_passes"] - moe["steps"] <= 2 and len(moe["assignments"]) == 2 and len(moe["assignments"][0]) == toy["num_experts"]
+    # The device's counters are copied between two passes and the host's counts are read behind them, any number of
+    # passes later on a loaded machine (four, with six workers: the driver's run of PR 62). By count and not by the
+    # clock: they hold every pass of the window's iterations, which all began before the copy was asked for, and no
+    # pass that was not dispatched. The window's iterations are ~0.85 of all passes here, so a counter that stepped
+    # once a block (a third of the passes) falls under them, and one that stepped once a position passes the upper side.
+    in_window = len(decode_iterations(result))
+    assert 0 < in_window <= moe["steps"] <= counters["block_passes"]
+    assert len(moe["assignments"]) == 2 and len(moe["assignments"][0]) == toy["num_experts"]
     read = lambda name: registry.load_metric("per_layer", name)(result)  # noqa: E731
     assert 1.0 < read("block_tokens_per_pass") < 4 / 3 + 0.05 and 30.0 < read("block_commit_share_pct") < 40.0
     assert read("block_draw_ms") is None and read("prefill_pass_share_pct") > 0.0

@@ -303,8 +303,8 @@ def test_the_readers_on_a_result_written_by_hand(manifest):
     assert read("prefill_chunk_ms") == pytest.approx(80.0) and read("decode_step_ms") == pytest.approx(10.0)
     mix = result["cell"]["traffic"]
     held = int(7 * (traffic.mean_length(mix["prompt_len"]) + traffic.mean_length(mix["output_len"]) / 2))
-    assert read("decode_roofline") == pytest.approx(100 * costs.decode_step_bytes(cfg, held) / 819e9 / 0.010)
-    for name in ("latent_prefill_roofline", "moe_experts_roofline", "latent_attention_roofline", "decode_roofline"):
+    assert read("decode_mfu_roofline") == pytest.approx(100 * costs.decode_step_bytes(cfg, held) / 819e9 / 0.010)
+    for name in ("latent_prefill_roofline", "moe_experts_roofline", "latent_attention_roofline", "decode_mfu_roofline"):
         assert 0 < read(name) <= 100, name
 
 
